@@ -1,0 +1,233 @@
+"""chip_smoke.py — the quickest proof that the trainer still starts on the chip.
+
+Drives the main path once, in THIS process, through the function the CLI
+calls (`distributed_ddpg_tpu.train.train(DDPGConfig.from_flags([...]))`) at
+the full width of the model the repo benchmarks: DDPG, HalfCheetah-v4
+(obs 17, act 6), 2x256 actor and critic, batch 64, host actor processes
+feeding the HBM replay ring, every default left alone (mesh over all
+visible chips, learner_chunk, fused_chunk=auto). Two legs back to back:
+the default leg (Pallas megakernel; fused-mesh on several chips) and
+`--fused_chunk=off` (the XLA scan chunk every other feature rides). Before
+them, the kernel is checked against the scan step on one small chunk
+(tests/fused_parity_util.py, the body tests/test_tpu.py runs).
+
+Exit 0 and a last stdout line `{"ok": true, "device": {...}, ...}` only if
+every check held; otherwise one line on stderr saying why and a non-zero
+exit. Nothing is caught and downgraded. Without a TPU (JAX_PLATFORMS=cpu,
+or no chip) it exits 2 in seconds, before any training.
+
+    python chip_smoke.py            # on the chip, from the repo root
+
+All JAX work sits under the __main__ guard: ActorPool spawns its workers,
+each re-imports this module, and a worker must never import JAX or reach
+the chip (one process per chip).
+"""
+
+import json
+import math
+import multiprocessing as mp
+import os
+import sys
+import time
+
+# README quick-start flags plus sizes that make this a smoke, not a
+# benchmark. No mesh, chunk, kernel or platform flag: the defaults are what
+# is being proven. 60k env steps: the learner free-runs until the actors
+# have delivered the budget, and with a warm compile cache the first chunk
+# is done in ~1.4 s, before the ring-insert programs (each under the
+# cache's 1 s floor, so compiled every run) have stopped holding the
+# dispatch lock — at 30k that left 4 chunks where 3 are required (my chip
+# run, PR 21). watchdog_s turns a wedged device call into stacks and exit
+# 70 instead of the caller's timeout.
+FLAGS = [
+    "--backend=jax_tpu",
+    "--env_id=HalfCheetah-v4",
+    "--num_actors=4",
+    "--replay_min_size=1000",
+    "--replay_capacity=100000",
+    "--total_env_steps=60000",
+    "--eval_every=0",
+    "--watchdog_s=120",
+]
+LEGS = (("default", []), ("scan", ["--fused_chunk=off"]))
+OBS, ACT = 17, 6  # HalfCheetah-v4
+INGEST_BLOCK = 1024  # train_jax's DeviceReplay block: one padded flush at most
+
+
+class SmokeFailure(Exception):
+    pass
+
+
+def check(cond, why):
+    if not cond:
+        raise SmokeFailure(why)
+
+
+def cache_entries(path):
+    return len(os.listdir(path)) if path and os.path.isdir(path) else 0
+
+
+def run_parity():
+    """Kernel vs scan step on one 8-step chunk at full width, natively
+    compiled — the repo's own parity body at its on-chip tolerances."""
+    sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), "tests"))
+    from fused_parity_util import assert_fused_matches_scan
+
+    from distributed_ddpg_tpu.config import DDPGConfig
+
+    cfg = DDPGConfig(
+        actor_hidden=(256, 256), critic_hidden=(256, 256), batch_size=64, seed=3
+    )
+    metrics = assert_fused_matches_scan(
+        cfg, OBS, ACT, 8, 1.0, 0.0, interpret=None, rtol=2e-2, atol=1e-2
+    )
+    return {"critic_loss": round(float(metrics["critic_loss"]), 6)}
+
+
+def kernel_lowering(chunk):
+    """What the default leg's kernel lowers to on this backend, read from
+    the lowered program text: 'tpu_custom_call' is Mosaic, anything else
+    (interpret mode lowers to plain HLO) is not the compiled kernel."""
+    import jax
+    import jax.numpy as jnp
+
+    from distributed_ddpg_tpu.config import DDPGConfig
+    from distributed_ddpg_tpu.learner import init_train_state
+    from distributed_ddpg_tpu.ops import fused_chunk
+    from distributed_ddpg_tpu.types import packed_width
+
+    cfg = DDPGConfig.from_flags(FLAGS)
+    run = fused_chunk.make_fused_chunk_fn(cfg, OBS, ACT, 1.0, chunk_size=chunk)
+    state = init_train_state(cfg, OBS, ACT, cfg.seed)
+    batches = jnp.zeros((chunk, cfg.batch_size, packed_width(OBS, ACT)), jnp.float32)
+    text = jax.jit(run).lower(state, batches).as_text()
+    return "tpu_custom_call" if "tpu_custom_call" in text else "not-mosaic"
+
+
+def run_leg(name, extra, n_devices):
+    from distributed_ddpg_tpu.config import DDPGConfig
+    from distributed_ddpg_tpu.train import train
+
+    t0 = time.monotonic()
+    s = train(DDPGConfig.from_flags(FLAGS + extra))
+    wall = time.monotonic() - t0
+
+    check(s["platform"] == "tpu", f"{name}: trainer ran on {s['platform']!r}")
+    check(s["n_devices"] == n_devices, f"{name}: trainer saw {s['n_devices']} devices")
+    kernel = s["fused_chunk_active"]
+    if name == "default":
+        check(kernel, "default leg: the megakernel was not selected")
+        leg = "kernel" if n_devices == 1 else "fused-mesh"
+    else:
+        check(not kernel, "scan leg: the megakernel ran under --fused_chunk=off")
+        leg = "scan"
+    chunk = s["learner_chunk"]
+    check(
+        s["learner_steps"] >= 3 * chunk,
+        f"{name}: {s['learner_steps']} learner steps < 3 chunks of {chunk}",
+    )
+    for key in ("critic_loss", "actor_loss"):
+        check(
+            key in s and math.isfinite(s[key]),
+            f"{name}: last chunk's {key} is {s.get(key)!r}",
+        )
+    check(
+        s["param_checksum"] != s["param_checksum_start"]
+        and math.isfinite(s["param_checksum"]),
+        f"{name}: actor params did not move ({s['param_checksum']})",
+    )
+    # Every env step handed to the replay is in the ring or still staged on
+    # the host; the only surplus is the warmup flush's padding.
+    surplus = s["buffer_fill"] + s["ingest_queue_rows"] - s["env_steps"]
+    check(
+        0 <= surplus < 2 * INGEST_BLOCK,
+        f"{name}: ring {s['buffer_fill']} + staged {s['ingest_queue_rows']} "
+        f"vs {s['env_steps']} env steps",
+    )
+    check(s["actor_respawns"] == 0, f"{name}: {s['actor_respawns']} actor respawns")
+    for flag in ("preempted", "pod_degraded", "numeric_failed"):
+        check(not s[flag], f"{name}: run reported {flag}")
+    # Placement from sharding metadata: state and ring on every chip.
+    check(s["mesh_data_axis"] == n_devices, f"{name}: mesh_data_axis {s['mesh_data_axis']}")
+    for what in ("state_devices", "replay_devices"):
+        check(s[what] == n_devices, f"{name}: {what} {s[what]} of {n_devices}")
+    return {
+        "leg": leg,
+        "fused_chunk_active": kernel,
+        "chunk": chunk,
+        "learner_steps": s["learner_steps"],
+        "env_steps": s["env_steps"],
+        "buffer_fill": s["buffer_fill"],
+        "critic_loss": round(s["critic_loss"], 6),
+        "actor_loss": round(s["actor_loss"], 6),
+        "first_chunk_s": round(s["first_chunk_s"], 3),
+        "steady_s": round(s["steady_s"], 3),
+        "wall_s": round(wall, 1),
+        "mesh": [s["mesh_data_axis"], s["mesh_model_axis"]],
+        "state_devices": s["state_devices"],
+        "replay_devices": s["replay_devices"],
+    }
+
+
+def main():
+    import jax
+
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        print(
+            f"chip_smoke: needs the chip — JAX resolved platform "
+            f"{dev.platform!r} (jax_platforms={jax.config.jax_platforms!r})",
+            file=sys.stderr,
+        )
+        return 2
+    n_devices = len(jax.devices())
+
+    import jaxlib
+    import libtpu
+
+    # Importing the mesh module places the compile cache (env var, or
+    # <checkout>/.jax_cache); count entries before anything compiles.
+    from distributed_ddpg_tpu import native
+    from distributed_ddpg_tpu.parallel import mesh  # noqa: F401
+
+    cache_dir = jax.config.jax_compilation_cache_dir
+    facts = {
+        "device": {"platform": dev.platform, "kind": dev.device_kind, "count": n_devices},
+        "versions": {
+            "jax": jax.__version__,
+            "jaxlib": jaxlib.__version__,
+            "libtpu": libtpu.__version__,
+        },
+        "compile_cache": {
+            "dir": cache_dir,
+            "from_env": bool(os.environ.get("JAX_COMPILATION_CACHE_DIR")),
+            "entries_before": cache_entries(cache_dir),
+        },
+        "native_replay_core": native.available(),
+    }
+    check(facts["native_replay_core"], "the C++ replay core did not build (g++?)")
+
+    t0 = time.monotonic()
+    facts["parity"] = run_parity()
+    facts["legs"] = {name: run_leg(name, extra, n_devices) for name, extra in LEGS}
+    facts["kernel_lowering"] = kernel_lowering(facts["legs"]["default"]["chunk"])
+    check(
+        facts["kernel_lowering"] == "tpu_custom_call",
+        "the kernel did not lower to a Mosaic custom call",
+    )
+    facts["compile_cache"]["entries_after"] = cache_entries(cache_dir)
+    left = mp.active_children()
+    for p in left:
+        p.terminate()
+    check(not left, f"{len(left)} child process(es) outlived the trainer")
+    facts["wall_s"] = round(time.monotonic() - t0, 1)
+    print(json.dumps({"ok": True, **facts}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    try:
+        sys.exit(main())
+    except SmokeFailure as e:
+        print(f"chip_smoke: FAIL: {e}", file=sys.stderr)
+        sys.exit(1)

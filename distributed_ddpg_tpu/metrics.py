@@ -26,7 +26,13 @@ from distributed_ddpg_tpu import trace
 
 
 class MetricsLogger:
-    def __init__(self, path: str = "", echo: bool = True, tb_dir: str = ""):
+    def __init__(
+        self,
+        path: str = "",
+        echo: bool = True,
+        tb_dir: str = "",
+        header: Optional[Dict[str, Any]] = None,
+    ):
         self._file = open(path, "a", buffering=1) if path else None
         self._echo = echo
         self._t0 = time.time()
@@ -52,9 +58,13 @@ class MetricsLogger:
         # creation, so without this a pod's N per-process JSONL files (or
         # two runs of one config) cannot be joined on time at all —
         # merge tooling computes absolute event time as
-        # t_unix_base + wall_time (docs/OBSERVABILITY.md §1).
+        # t_unix_base + wall_time (docs/OBSERVABILITY.md §1). `header`
+        # adds the caller's run facts (train_jax: device + learner leg).
         self.t_unix_base = round(self._t0, 6)
-        self.log("header", 0, t_unix_base=self.t_unix_base, pid=os.getpid())
+        self.log(
+            "header", 0, t_unix_base=self.t_unix_base, pid=os.getpid(),
+            **(header or {}),
+        )
 
     def log(self, kind: str, step: int, **fields: Any) -> Dict[str, Any]:
         rec = {
@@ -162,8 +172,8 @@ class PhaseTimers:
       t_<name>_p50 / t_<name>_p95 / t_<name>_max
                      reservoir percentiles + exact max, ms
 
-    The percentiles are the point: the 8-device ingest regression in
-    BENCH_r05 hid behind a healthy MEAN — a per-interval p95/max puts a
+    The percentiles are the point: the round-5 8-device ingest
+    regression hid behind a healthy MEAN — a per-interval p95/max puts a
     one-in-fifty 600ms dispatch straight into the JSONL record instead of
     averaging it into noise. Every phase bracket also emits a flight-
     recorder span (trace.py) under the phase's name, so the same bracket

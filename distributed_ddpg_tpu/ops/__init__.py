@@ -1,5 +1,4 @@
-from distributed_ddpg_tpu.ops.optim import adam_update
-from distributed_ddpg_tpu.ops.polyak import polyak_update
-from distributed_ddpg_tpu.ops import losses
-
-__all__ = ["adam_update", "polyak_update", "losses"]
+"""Learner math (losses, Adam, Polyak, kernels) and the numpy-only pieces
+actor workers use (noise, support_auto). Import the submodule you need:
+this package imports nothing itself, so a worker that takes `ops.noise`
+does not pull JAX in with it."""

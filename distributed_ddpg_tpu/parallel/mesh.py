@@ -57,26 +57,37 @@ from distributed_ddpg_tpu.types import Batch
 # it. An explicit JAX_THREEFRY_PARTITIONABLE in the environment wins:
 # that is the embedder's escape hatch back to the legacy scheme.
 import os as _os
+import pathlib as _pathlib
 
 if _os.environ.get("JAX_THREEFRY_PARTITIONABLE", "") == "":
     jax.config.update("jax_threefry_partitionable", True)
 
+# Persistent compile cache, placed from outside. One rule, here, next to
+# the other process-wide JAX setting every device-program owner inherits
+# by importing this module: when JAX_COMPILATION_CACHE_DIR is set the
+# code assigns nothing and JAX uses that directory; otherwise the cache
+# is <checkout>/.jax_cache, derived from this package's own path (the
+# directory is part of what makes an entry hit, so it must not move
+# between processes or working directories of one checkout). A process
+# that asked for the CPU gets no default: the cache is there for the
+# chip's compiles, and this installation's XLA:CPU loader logs a
+# machine-feature error on every hit and would trust an entry carried
+# over from another host.
+if (
+    not _os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    and (jax.config.jax_platforms or "").split(",")[0] != "cpu"
+):
+    jax.config.update(
+        "jax_compilation_cache_dir",
+        str(_pathlib.Path(__file__).resolve().parents[2] / ".jax_cache"),
+    )
+
 
 def shard_map(f, mesh: Mesh, in_specs, out_specs, check: bool = False):
-    """Version-portable shard_map: jax >= 0.6 exposes `jax.shard_map` with
-    `check_vma`; older jaxes (0.4.x here) only have
-    `jax.experimental.shard_map.shard_map` with the equivalent flag spelled
-    `check_rep`. Same semantics either way — per-shard body, explicit
-    collectives, specs name this module's (data, model) axes."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check,
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=check
+    """Per-shard body with explicit collectives; specs name this module's
+    (data, model) axes. `check` is jax.shard_map's check_vma."""
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check
     )
 
 

@@ -305,48 +305,30 @@ def initialize(
 
     try:
         # Stretch the JAX coordination service's OWN death detection
-        # (default 10s x 10 missed = ~100s, after which the C++ client
-        # LOG(FATAL)s the process — a SIGABRT with no emergency
+        # (default heartbeat_timeout_seconds=100, after which the C++
+        # client LOG(FATAL)s the process — a SIGABRT with no emergency
         # checkpoint). The pod layer's collective deadline must WIN that
         # race so survivors abort cleanly with exit 76: train_jax passes
         # a value derived from pod_collective_timeout_s +
         # pod_startup_grace_s; POD_RUNTIME_HEARTBEAT_TIMEOUT_S overrides.
-        # The knob rides the internal initializer (the public API does
-        # not expose heartbeats in this jax version); any signature
-        # drift falls back to the public path — detection then just
-        # stays at the runtime's defaults.
         hb_env = os.environ.get("POD_RUNTIME_HEARTBEAT_TIMEOUT_S")
         hb = float(hb_env) if hb_env else runtime_heartbeat_timeout_s
-        if hb and hb > 0:
-            try:
-                from jax._src.distributed import global_state as _gs
-
-                interval = max(1, int(round(float(hb) / 10.0)))
-                _gs.initialize(
-                    coordinator_address=coordinator_address,
-                    num_processes=num_processes,
-                    process_id=process_id,
-                    service_heartbeat_interval_seconds=interval,
-                    service_max_missing_heartbeats=10,
-                    client_heartbeat_interval_seconds=interval,
-                    client_max_missing_heartbeats=10,
-                )
-                return jax.process_count() > 1
-            except (ImportError, TypeError):
-                pass  # private initializer moved: public path below
+        stretch = (
+            {"heartbeat_timeout_seconds": max(1, int(round(hb)))}
+            if hb and hb > 0
+            else {}
+        )
         jax.distributed.initialize(
             coordinator_address=coordinator_address,
             num_processes=num_processes,
             process_id=process_id,
+            **stretch,
         )
         return jax.process_count() > 1
     except RuntimeError as e:
         msg = str(e)
-        # "already initialized": the public API's idempotent-re-entry
-        # message; "only be called once": the internal initializer's
-        # (POD_RUNTIME_HEARTBEAT_TIMEOUT_S path) wording for the same
-        # condition.
-        if "already initialized" in msg or "only be called once" in msg:
+        # Idempotent re-entry ("...should only be called once").
+        if "only be called once" in msg:
             return jax.process_count() > 1
         if "must be called before" in msg and jax.process_count() > 1:
             # Backend already live AND already multi-process: a legitimate

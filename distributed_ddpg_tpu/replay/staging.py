@@ -2,7 +2,7 @@
 
 The seed's `DeviceReplay.add_packed` staged pending rows in a growing
 numpy array via `np.concatenate([pending, block])` — every actor batch
-re-copied ALL pending rows, an O(n^2) pattern that BENCH_r05 put on the
+re-copied ALL pending rows, an O(n^2) pattern that the round-5 bench put on the
 learner's critical path (t_ingest_ms = 1347 vs t_dispatch_ms = 670 at 8
 virtual devices). This module replaces it with a preallocated [capacity,
 D] float32 ring: push is one bounded memcpy into the tail, pop is one

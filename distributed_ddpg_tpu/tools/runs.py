@@ -1,8 +1,8 @@
 """Run-analysis CLI for the repo's JSONL/JSON artifacts.
 
 `runs/` holds ~100 train/eval/bench files and until this module the only
-tooling was hand-diffing them (how the 8-device ingest regression in
-BENCH_r05 was found). Four subcommands over the schemas the repo already
+tooling was hand-diffing them (how the round-5 8-device ingest
+regression was found). Four subcommands over the schemas the repo already
 produces (metrics.MetricsLogger records; bench.py result JSON — both
 documented in docs/OBSERVABILITY.md):
 
@@ -220,10 +220,10 @@ def _drop_probe_failures(
     records: List[Dict[str, Any]], path: str
 ) -> List[Dict[str, Any]]:
     """Drop records carrying a TPU-probe failure tail (`probe_error` /
-    `tpu_error` — the BENCH_r04/r05 shape: the harness recorded a CPU
-    fallback after the TPU probe died). Their rates are fallback numbers,
-    not the run's, and silently averaging them in would poison every A/B
-    against a healthy baseline (BENCH_r03). Warns once per file so the
+    `tpu_error` — the harness's TPU probe or accelerator phase died; older
+    records of this shape also carry a CPU fallback's rates). Those are
+    not the run's numbers, and silently averaging them in would poison
+    every A/B against a healthy baseline. Warns once per file so the
     exclusion is visible, never manual."""
     kept = [
         r for r in records

@@ -7,10 +7,9 @@
 # and, since PR 6, a structured `probe_error` field in the bench object;
 # gating against the former would SKIP every key and silently pass any
 # regression, and the latter's value is a CPU fallback that would poison
-# the baseline — both are skipped with a logged reason, e.g. BENCH_r04/
-# r05) — and exit 2 on regression past the threshold, so the driver's
-# round loop can fail fast on a perf-regressing change. Exits 1 if no
-# baseline qualifies.
+# the baseline — both are skipped with a logged reason) — and exit 2 on
+# regression past the threshold, so the driver's round loop can fail
+# fast on a perf-regressing change. Exits 1 if no baseline qualifies.
 #
 # Usage:
 #   scripts/ci_gate.sh <candidate.json> [baseline.json]
@@ -161,7 +160,7 @@ def usable(path, why=None):
         for k in keys
     ):
         # Typically a driver wrapper whose tail is a truncated failure
-        # dump instead of a bench object (BENCH_r04/r05).
+        # dump instead of a bench object.
         return skip(f"resolves none of the gate keys {keys} (failure tail "
                     "or no bench object)")
     return True

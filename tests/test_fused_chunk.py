@@ -223,10 +223,12 @@ def test_sharded_learner_fused_path_matches_scan_path():
         )
 
 
-def test_auto_mode_falls_back_on_kernel_failure(monkeypatch):
-    """fused_chunk='auto': a megakernel that dies at first dispatch (the
-    round-2 Mosaic BlockSpec failure mode) must degrade to the XLA scan
-    path with a warning — and keep training — instead of raising."""
+def test_auto_mode_kernel_failure_raises(monkeypatch):
+    """fused_chunk='auto': whether the kernel runs is decided before
+    tracing by stated rules (supported / fits_vmem / runs_native). A
+    selected kernel that then dies at first dispatch is an ERROR — the
+    learner must not rebind itself to the scan program and carry on
+    looking healthy."""
     from distributed_ddpg_tpu.ops import fused_chunk as fc
     from distributed_ddpg_tpu.parallel.learner import ShardedLearner
     from distributed_ddpg_tpu.parallel.mesh import make_mesh
@@ -254,12 +256,9 @@ def test_auto_mode_falls_back_on_kernel_failure(monkeypatch):
         capacity=64, obs_dim=OBS, act_dim=ACT, mesh=lrn.mesh, block_size=64
     )
     rep.add_packed(_batches(np.random.default_rng(3), 4).reshape(-1, rep.width))
-    with pytest.warns(UserWarning, match="falling back"):
-        out = lrn.run_sample_chunk(rep)
-    assert not lrn.fused_chunk_active
-    assert np.isfinite(float(out.metrics["critic_loss"]))
-    out2 = lrn.run_sample_chunk(rep)  # steady state keeps working
-    assert np.isfinite(float(out2.metrics["critic_loss"]))
+    with pytest.raises(RuntimeError, match="mosaic boom"):
+        lrn.run_sample_chunk(rep)
+    assert lrn.fused_chunk_active  # still the selected program
 
 
 def test_fused_chunk_on_requires_envelope():

@@ -641,9 +641,9 @@ def test_summarize_skips_probe_failure_tails(tmp_path, capsys):
     path.write_text(
         json.dumps(good) + "\n"
         + json.dumps({**good, "step": 200, "wall_time": 2.0}) + "\n"
-        # The BENCH_r04/r05 shape: a CPU-fallback record with the failure
-        # recorded as a structured field — its numbers must not poison
-        # the digest or any A/B against a healthy baseline.
+        # A probe-failure record (older ones carry CPU-fallback numbers),
+        # the failure recorded as a structured field — its numbers must
+        # not poison the digest or any A/B against a healthy baseline.
         + json.dumps({"kind": "train", "step": 300, "wall_time": 3.0,
                       "learner_steps_per_sec": 1.0,
                       "tpu_error": "probe timeout"}) + "\n"
@@ -804,6 +804,17 @@ def test_train_run_keys_are_documented(tmp_path):
     records = [json.loads(ln) for ln in log_path.read_text().splitlines()]
     kinds = {r["kind"] for r in records}
     assert "header" in kinds and "train" in kinds and "final" in kinds
+    # Run facts (train.run_facts): the header and final records and the
+    # returned summary all say what the run ran on and which learner leg.
+    for rec in (records[0], records[-1], out):
+        assert rec["platform"] == "cpu" and rec["device_kind"] == "cpu"
+        assert rec["n_devices"] == rec["state_devices"] == 8
+        assert rec["replay_devices"] == 8
+        assert rec["learner_chunk"] == 8
+        assert rec["fused_chunk_active"] is False
+    assert records[0]["kind"] == "header" and records[-1]["kind"] == "final"
+    assert np.isfinite(records[-1]["critic_loss"])
+    assert records[-1]["first_chunk_s"] > 0 and records[-1]["steady_s"] > 0
     undocumented = sorted({
         key
         for r in records

@@ -146,6 +146,49 @@ def test_prefetcher_feeds_chunks():
         pf.stop()
 
 
+def test_compile_cache_is_placed_from_outside(tmp_path):
+    """One rule (parallel/mesh.py import): JAX_COMPILATION_CACHE_DIR set ->
+    the code assigns nothing and JAX uses that directory; unset -> the
+    same <checkout>/.jax_cache from any process and working directory; a
+    process that asked for the CPU gets no default. Importing the module
+    initialises no backend, so the children need no chip."""
+    import os
+    import subprocess
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    code = (
+        "import jax; from distributed_ddpg_tpu.parallel import mesh; "
+        "print(jax.config.jax_compilation_cache_dir)"
+    )
+    base = {
+        k: v for k, v in os.environ.items()
+        if k not in ("JAX_PLATFORMS", "JAX_COMPILATION_CACHE_DIR")
+    }
+    base["PYTHONPATH"] = root
+    cases = {
+        "env": ({**base, "JAX_COMPILATION_CACHE_DIR": str(tmp_path / "c")}, root),
+        "here": (base, root),
+        "elsewhere": (base, str(tmp_path)),
+        "cpu": ({**base, "JAX_PLATFORMS": "cpu"}, root),
+    }
+    procs = {
+        name: subprocess.Popen(
+            [sys.executable, "-c", code], env=env, cwd=cwd,
+            stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True,
+        )
+        for name, (env, cwd) in cases.items()
+    }
+    got = {}
+    for name, proc in procs.items():
+        out, err = proc.communicate(timeout=120)
+        assert proc.returncode == 0, (name, err)
+        got[name] = out.strip().splitlines()[-1]
+    assert got["env"] == str(tmp_path / "c")
+    assert got["here"] == got["elsewhere"] == os.path.join(root, ".jax_cache")
+    assert got["cpu"] == "None"
+
+
 def test_multihost_noop_single_process():
     from distributed_ddpg_tpu.parallel import multihost
 

@@ -19,7 +19,9 @@ compiles or executes one.
 """
 
 import json
+import os
 import subprocess
+import sys
 import time
 from pathlib import Path
 
@@ -437,7 +439,10 @@ def test_proganalyze_gate_script_fails_on_findings(tmp_path):
         ["bash", str(REPO / "scripts" / "proganalyze_gate.sh"),
          "--specs", f"{FIXMOD}:broken_donation_specs",
          "--golden", str(tmp_path / "g"), "--update-golden"],
-        env={"PATH": "/usr/bin:/bin:/usr/local/bin",
+        # The script runs `python`: put THIS interpreter's directory (the
+        # one that has jax) first, wherever the installation keeps it.
+        env={"PATH": os.pathsep.join(
+                 [os.path.dirname(sys.executable), "/usr/bin", "/bin"]),
              "PROGRAM_JSON": str(json_path)},
         capture_output=True, text=True, timeout=300,
     )

@@ -21,6 +21,8 @@ _TIME_KEYS = (
     # wall-clock like ingest_ship_ms; the placement COUNT fields
     # (replay_ingest_bytes*, shard count/fill) stay in the contract.
     "replay_exchange_ms_p50", "replay_exchange_ms_p95",
+    # The final record's compile / steady split of the loop's wall time.
+    "first_chunk_s", "steady_s",
 )
 
 

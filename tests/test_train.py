@@ -30,6 +30,66 @@ def test_train_native_runs_and_reports_rate():
     assert out["learner_steps_per_sec"] > 10
 
 
+def test_jax_backends_refuse_a_cpu_nobody_asked_for():
+    """The jax backends run on the CPU only when the CPU was ASKED for
+    (jax_platforms leads with 'cpu' — conftest.py and the tier-1 command
+    both do). With nothing asked, or the chip asked, a resolved CPU means
+    no usable TPU was found: train() raises before any work instead of
+    carrying on silently."""
+    import jax
+
+    from distributed_ddpg_tpu.train import require_platform, train
+
+    assert require_platform() == "cpu"  # asked-for CPU runs
+    try:
+        for asked in ("", "tpu,cpu"):
+            jax.config.update("jax_platforms", asked)
+            with pytest.raises(RuntimeError, match="did not ask for it"):
+                train(DDPGConfig(backend="jax_tpu"))
+    finally:
+        jax.config.update("jax_platforms", "cpu")
+
+
+def test_chip_smoke_refuses_without_the_chip():
+    """`python chip_smoke.py` with no TPU (here: the CPU asked for) exits
+    non-zero in seconds, before any training, and prints no result."""
+    import subprocess
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    proc = subprocess.run(
+        [sys.executable, os.path.join(root, "chip_smoke.py")],
+        env={**os.environ, "JAX_PLATFORMS": "cpu"},
+        capture_output=True, text=True, timeout=120,
+    )
+    assert proc.returncode == 2, (proc.stdout, proc.stderr)
+    assert proc.stdout == ""
+    assert "needs the chip" in proc.stderr
+
+
+def test_entry_modules_import_without_jax():
+    """ActorPool spawns its workers and a spawned worker re-imports the
+    parent's main module: whatever can be that module (train.py under
+    `python -m`, chip_smoke.py) and whatever the worker imports itself must
+    not import JAX — one process per chip, and N workers must not each pay
+    the jax+orbax import."""
+    import subprocess
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    code = (
+        "import sys, chip_smoke, distributed_ddpg_tpu.train, "
+        "distributed_ddpg_tpu.actors.worker, distributed_ddpg_tpu.ops.noise; "
+        "bad = [m for m in ('jax', 'orbax') if m in sys.modules]; "
+        "assert not bad, bad"
+    )
+    proc = subprocess.run(
+        [sys.executable, "-c", code], cwd=root,
+        capture_output=True, text=True, timeout=120,
+    )
+    assert proc.returncode == 0, proc.stderr
+
+
 def test_learner_chunk_resolution():
     """config.learner_chunk: explicit value wins; 0 = auto (8 on the CPU
     test platform, 800 only on kernel-native TPU backends)."""
