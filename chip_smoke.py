@@ -11,10 +11,14 @@ the default leg (Pallas megakernel; fused-mesh on several chips) and
 them, the kernel is checked against the scan step on one small chunk
 (tests/fused_parity_util.py, the body tests/test_tpu.py runs).
 
-Exit 0 and a last stdout line `{"ok": true, "device": {...}, ...}` only if
-every check held; otherwise one line on stderr saying why and a non-zero
-exit. Nothing is caught and downgraded. Without a TPU (JAX_PLATFORMS=cpu,
-or no chip) it exits 2 in seconds, before any training.
+Exit 0 only if every check held. Stdout then ends with two JSON lines: the
+facts of the run (`{"facts": {...}}`: versions, legs, compile cache, ...)
+and, last, the verdict with exactly these keys, the device as JAX reports
+it: `{"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}`.
+A failed check is one line on stderr saying why, the verdict with
+`"ok": false`, and exit 1. Nothing is caught and downgraded. Without a TPU
+(JAX_PLATFORMS=cpu, or no chip) it prints no verdict and exits 2 in
+seconds, before any training.
 
     python chip_smoke.py            # on the chip, from the repo root
 
@@ -61,6 +65,22 @@ class SmokeFailure(Exception):
 def check(cond, why):
     if not cond:
         raise SmokeFailure(why)
+
+
+def verdict_line(ok, device):
+    """The last stdout line: exactly `ok` and `device` (platform, kind,
+    count) — what the chip check parses; everything else is in the facts
+    line before it."""
+    return json.dumps(
+        {
+            "ok": bool(ok),
+            "device": {
+                "platform": str(device["platform"]),
+                "kind": str(device["kind"]),
+                "count": int(device["count"]),
+            },
+        }
+    )
 
 
 def cache_entries(path):
@@ -169,30 +189,16 @@ def run_leg(name, extra, n_devices):
     }
 
 
-def main():
+def run_checks(device, cache_dir):
     import jax
-
-    dev = jax.devices()[0]
-    if dev.platform != "tpu":
-        print(
-            f"chip_smoke: needs the chip — JAX resolved platform "
-            f"{dev.platform!r} (jax_platforms={jax.config.jax_platforms!r})",
-            file=sys.stderr,
-        )
-        return 2
-    n_devices = len(jax.devices())
-
     import jaxlib
     import libtpu
 
-    # Importing the mesh module places the compile cache (env var, or
-    # <checkout>/.jax_cache); count entries before anything compiles.
     from distributed_ddpg_tpu import native
-    from distributed_ddpg_tpu.parallel import mesh  # noqa: F401
 
-    cache_dir = jax.config.jax_compilation_cache_dir
+    n_devices = device["count"]
     facts = {
-        "device": {"platform": dev.platform, "kind": dev.device_kind, "count": n_devices},
+        "device": device,
         "versions": {
             "jax": jax.__version__,
             "jaxlib": jaxlib.__version__,
@@ -221,13 +227,38 @@ def main():
         p.terminate()
     check(not left, f"{len(left)} child process(es) outlived the trainer")
     facts["wall_s"] = round(time.monotonic() - t0, 1)
-    print(json.dumps({"ok": True, **facts}), flush=True)
+    return facts
+
+
+def main():
+    import jax
+
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        print(
+            f"chip_smoke: needs the chip — JAX resolved platform "
+            f"{dev.platform!r} (jax_platforms={jax.config.jax_platforms!r})",
+            file=sys.stderr,
+        )
+        return 2
+    n_devices = len(jax.devices())
+
+    # Importing the mesh module places the compile cache (env var, or
+    # <checkout>/.jax_cache); count entries before anything compiles.
+    from distributed_ddpg_tpu.parallel import mesh  # noqa: F401
+
+    cache_dir = jax.config.jax_compilation_cache_dir
+    device = {"platform": dev.platform, "kind": dev.device_kind, "count": n_devices}
+    try:
+        facts = run_checks(device, cache_dir)
+    except SmokeFailure as e:
+        print(f"chip_smoke: FAIL: {e}", file=sys.stderr)
+        print(verdict_line(False, device), flush=True)
+        return 1
+    print(json.dumps({"facts": facts}), flush=True)
+    print(verdict_line(True, device), flush=True)
     return 0
 
 
 if __name__ == "__main__":
-    try:
-        sys.exit(main())
-    except SmokeFailure as e:
-        print(f"chip_smoke: FAIL: {e}", file=sys.stderr)
-        sys.exit(1)
+    sys.exit(main())

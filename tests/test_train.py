@@ -67,6 +67,26 @@ def test_chip_smoke_refuses_without_the_chip():
     assert "needs the chip" in proc.stderr
 
 
+def test_chip_smoke_verdict_line_is_exactly_ok_and_device():
+    """The chip check parses the last stdout line and refuses any key
+    beyond `ok` and `device` {platform, kind, count}; the run's facts go on
+    the line before it."""
+    import json
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sys.path.insert(0, root)
+    try:
+        import chip_smoke
+    finally:
+        sys.path.remove(root)
+    device = {"platform": "tpu", "kind": "TPU v5 lite", "count": 4}
+    for ok in (True, False):
+        line = chip_smoke.verdict_line(ok, device)
+        assert "\n" not in line
+        assert json.loads(line) == {"ok": ok, "device": device}
+
+
 def test_entry_modules_import_without_jax():
     """ActorPool spawns its workers and a spawned worker re-imports the
     parent's main module: whatever can be that module (train.py under
