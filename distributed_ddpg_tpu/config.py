@@ -421,7 +421,6 @@ class DDPGConfig:
     checkpoint_keep: int = 3
     resume: bool = True              # auto-restore latest checkpoint_dir state
     log_path: str = ""               # JSONL metrics path ("" = stdout only)
-    tb_dir: str = ""                 # TensorBoard summary dir ("" = off)
     profile_dir: str = ""            # jax.profiler trace dir ("" = off)
     # Flight-recorder tracing (trace.py): when set, train_jax records
     # thread-tagged spans from every hot component (learner phases, ingest

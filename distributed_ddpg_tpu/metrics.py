@@ -1,12 +1,10 @@
-"""Structured metrics: JSONL + stdout + optional TensorBoard (SURVEY.md §5
-'Metrics / logging').
+"""Structured metrics: JSONL + stdout (SURVEY.md §5 'Metrics / logging').
 
 The reference's only observability was TensorBoard scalar summaries
-[RECALL]; here the primary sink is append-only JSONL (one object per event,
-machine-parseable by the bench harness) plus optional human lines, with a
-TensorBoard sink (`tb_dir`) kept for parity — scalars land under
-`<kind>/<field>`. Tracked quantities follow SURVEY.md §5: episode return,
-losses, mean Q, grad norms, buffer fill, actor/learner steps/sec, staleness.
+[RECALL]; here the sink is append-only JSONL (one object per event,
+machine-parseable by the bench harness) plus optional human lines.
+Tracked quantities follow SURVEY.md §5: episode return, losses, mean Q,
+grad norms, buffer fill, actor/learner steps/sec, staleness.
 """
 
 from __future__ import annotations
@@ -17,8 +15,8 @@ import random
 import sys
 import threading
 import time
-import warnings
 import zlib
+from collections import deque
 from contextlib import contextmanager
 from typing import Any, Dict, List, Optional
 
@@ -30,7 +28,6 @@ class MetricsLogger:
         self,
         path: str = "",
         echo: bool = True,
-        tb_dir: str = "",
         header: Optional[Dict[str, Any]] = None,
     ):
         self._file = open(path, "a", buffering=1) if path else None
@@ -42,17 +39,6 @@ class MetricsLogger:
         # Latest record per kind: the live /metrics endpoint's source
         # (obs/exporter.py) — a scrape must never replay the file.
         self._latest: Dict[str, Dict[str, Any]] = {}
-        self._tb = None
-        if tb_dir:
-            try:
-                # torch (CPU) is a baked-in dependency; its pure-Python event
-                # writer needs no torch tensors for scalars.
-                from torch.utils.tensorboard import SummaryWriter
-
-                self._tb = SummaryWriter(tb_dir)
-            except Exception as e:  # degrade to JSONL-only, loudly once
-                warnings.warn(f"tb_dir={tb_dir!r} requested but TensorBoard "
-                              f"writer unavailable: {e}")
         # Every stream opens with ONE header record carrying the absolute
         # wall-clock base: `wall_time` below is seconds since logger
         # creation, so without this a pod's N per-process JSONL files (or
@@ -80,11 +66,6 @@ class MetricsLogger:
                 self._file.write(line + "\n")
             if self._echo:
                 print(line, file=sys.stdout, flush=True)
-            if self._tb is not None:
-                for k, v in rec.items():
-                    if k in ("kind", "step") or not isinstance(v, (int, float)):
-                        continue
-                    self._tb.add_scalar(f"{kind}/{k}", v, step)
         return rec
 
     def latest(self) -> Dict[str, Dict[str, Any]]:
@@ -97,8 +78,6 @@ class MetricsLogger:
     def close(self) -> None:
         if self._file:
             self._file.close()
-        if self._tb is not None:
-            self._tb.close()
 
 
 def _jsonable(v):
@@ -177,7 +156,10 @@ class PhaseTimers:
     one-in-fifty 600ms dispatch straight into the JSONL record instead of
     averaging it into noise. Every phase bracket also emits a flight-
     recorder span (trace.py) under the phase's name, so the same bracket
-    feeds both the scalar record and the Perfetto timeline."""
+    feeds the scalar record, the Perfetto timeline and — through
+    trace.py's annotator sink — the profiler's host plane. `**args` are
+    the span's cause (chunk index, learner step): they go to the sinks,
+    not to the record."""
 
     # Reservoir size: 256 doubles/phase bounds memory; p95 over a typical
     # 50-call interval is exact (reservoir bigger than the population).
@@ -190,9 +172,9 @@ class PhaseTimers:
         self._seed = seed
 
     @contextmanager
-    def phase(self, name: str):
+    def phase(self, name: str, **args):
         t0 = time.perf_counter()
-        with trace.span(name):
+        with trace.span(name, **args):
             try:
                 yield
             finally:
@@ -229,6 +211,155 @@ class PhaseTimers:
             self._n.clear()
             self._res.clear()
         return out
+
+
+class LaunchQueue:
+    """Launches the device has not finished, counted from the host with
+    no sync and no d2h: the learner loop keeps one small output leaf of
+    every dispatched chunk, and before each dispatch drops the finished
+    ones (`is_ready()`) from the old end. What is left is what a
+    parameter refresh has to wait out and what policy lag is made of;
+    a dispatch that finds nothing left found the device idle.
+
+      poll()          before a dispatch: settle(), and note a starved
+                      dispatch; returns the updates that finished
+      add(leaf, n)    after it: the launch's leaf and its n updates
+      steps_done      updates of finished launches since the run began
+                      (dispatched - in flight)
+
+    snapshot() emits per interval and resets: launches_in_flight_mean /
+    _max (depth seen by each dispatch, itself included) and
+    n_dispatch_starved. Learner thread only: no lock."""
+
+    def __init__(self):
+        self._q = deque()  # (leaf, updates), oldest launch first
+        self.n_dispatched = 0
+        self.steps_done = 0
+        self._reset()
+
+    def _reset(self) -> None:
+        self._n = self._sum = self._max = self._starved = 0
+
+    def __len__(self) -> int:
+        return len(self._q)
+
+    def settle(self) -> int:
+        """Drop the finished launches from the old end; returns the
+        updates they held. Also called outside a dispatch, before a
+        record or the final rate is written."""
+        q, done = self._q, 0
+        while q and q[0][0].is_ready():
+            done += q.popleft()[1]
+        self.steps_done += done
+        return done
+
+    def poll(self) -> int:
+        done = self.settle()
+        if not self._q and self.n_dispatched:
+            self._starved += 1
+        return done
+
+    def add(self, leaf, updates: int) -> None:
+        self._q.append((leaf, updates))
+        self.n_dispatched += 1
+        depth = len(self._q)
+        self._n += 1
+        self._sum += depth
+        if depth > self._max:
+            self._max = depth
+
+    def snapshot(self) -> Dict[str, float]:
+        out = {
+            "launches_in_flight_mean": (
+                round(self._sum / self._n, 3) if self._n else 0.0
+            ),
+            "launches_in_flight_max": self._max,
+            "n_dispatch_starved": self._starved,
+        }
+        self._reset()
+        return out
+
+
+class SetupStages:
+    """The stages a job waits through before its first useful step, as
+    disjoint spans on the thread that calls `stage()`: each stage ends
+    where the next begins. Every stage is a `trace.span` (ring and
+    profiler, where installed) and is timed on the monotonic clock into
+    `spans` {name: seconds}; a name staged twice accumulates."""
+
+    def __init__(self):
+        self.spans: Dict[str, float] = {}
+        self._open = None  # (name, span context, start_s)
+
+    def stage(self, name: str) -> None:
+        """End the open stage and begin `name`."""
+        self.end()
+        span = trace.span(name)
+        span.__enter__()
+        self._open = (name, span, time.perf_counter())
+
+    def end(self) -> None:
+        if self._open is None:
+            return
+        name, span, t0 = self._open
+        self._open = None
+        t1 = time.perf_counter()
+        span.__exit__(None, None, None)
+        self.add(name, t1 - t0)
+
+    def add(self, name: str, seconds: float) -> None:
+        """A stretch measured before anything could bracket it (the
+        module's own import, the backend start in train())."""
+        self.spans[name] = self.spans.get(name, 0.0) + seconds
+
+
+class CompileCounter:
+    """Programs this process compiled — builds that were NOT loads from
+    the persistent cache — and their backend-compile seconds, from
+    `jax.monitoring` between install() and uninstall(). JAX reports a
+    cache hit as its own event right before the build's duration event,
+    on the compiling thread; a build with no hit before it compiled."""
+
+    BUILD = "/jax/core/compile/backend_compile_duration"
+    HIT = "/jax/compilation_cache/cache_hits"
+
+    def __init__(self):
+        self.seconds = 0.0
+        self.programs = 0
+        self._hit = threading.local()
+        self._lock = threading.Lock()
+        self._installed = False
+
+    def install(self) -> "CompileCounter":
+        import jax.monitoring as m
+
+        m.register_event_listener(self._on_event)
+        m.register_event_duration_secs_listener(self._on_duration)
+        self._installed = True
+        return self
+
+    def uninstall(self) -> None:
+        if not self._installed:
+            return
+        import jax.monitoring as m
+
+        self._installed = False
+        m.unregister_event_listener(self._on_event)
+        m.unregister_event_duration_listener(self._on_duration)
+
+    def _on_event(self, event, **kw) -> None:
+        if event == self.HIT:
+            self._hit.pending = True
+
+    def _on_duration(self, event, seconds, **kw) -> None:
+        if event != self.BUILD:
+            return
+        if getattr(self._hit, "pending", False):
+            self._hit.pending = False
+            return
+        with self._lock:
+            self.seconds += seconds
+            self.programs += 1
 
 
 class IngestStats:

@@ -35,6 +35,15 @@ when `config.trace_dir` is set; actor worker processes (separate
 interpreters) enable their own recorder and export per-process files that
 Perfetto merges by pid.
 
+Second sink: `set_annotator(factory)` installs a context-manager factory
+that every `span()` ALSO enters. The learner process installs
+`jax.profiler.TraceAnnotation` (train.py, once the backend is up), which
+puts the program's spans on the profiler's host plane — the device
+trace's clock — and costs well under a microsecond while no profiler
+session runs. This module itself never imports JAX: actor workers import
+it and must not load JAX or reach the chip. `instant()`/`complete()` stay
+ring-only.
+
 Consistency note: the ring index is advanced atomically but slot writes
 are not fenced against concurrent export — an export racing a writer can
 see a slot from either side of the wrap. Exports sort by timestamp and
@@ -94,6 +103,27 @@ class _NullSpan:
 
 
 _NULL_SPAN = _NullSpan()
+
+
+class _BothSpan:
+    """Ring span + annotator context, entered together: the bracket
+    records once in each sink."""
+
+    __slots__ = ("_ring", "_ann")
+
+    def __init__(self, ring: _Span, ann):
+        self._ring = ring
+        self._ann = ann
+
+    def __enter__(self):
+        self._ann.__enter__()
+        self._ring.__enter__()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        self._ring.__exit__(exc_type, exc, tb)
+        self._ann.__exit__(exc_type, exc, tb)
+        return False
 
 
 class TraceRecorder:
@@ -232,6 +262,15 @@ class TraceRecorder:
 # ---------------------------------------------------------------------------
 
 _recorder: Optional[TraceRecorder] = None
+# Annotator sink: `factory(name, **args)` -> context manager, or None.
+_annotator = None
+
+
+def set_annotator(factory) -> None:
+    """Install (or, with None, remove) the second sink every `span()`
+    enters beside the ring. Process-wide, like the ring."""
+    global _annotator
+    _annotator = factory
 
 
 def configure(capacity: int = 65_536) -> TraceRecorder:
@@ -256,10 +295,12 @@ def get() -> Optional[TraceRecorder]:
 
 
 def span(name: str, **args):
-    r = _recorder
+    r, a = _recorder, _annotator
+    if a is None:
+        return _NULL_SPAN if r is None else r.span(name, **args)
     if r is None:
-        return _NULL_SPAN
-    return r.span(name, **args)
+        return a(name, **args)
+    return _BothSpan(r.span(name, **args), a(name, **args))
 
 
 def instant(name: str, **args) -> None:

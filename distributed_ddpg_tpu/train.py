@@ -16,11 +16,16 @@ Usage:
 
 from __future__ import annotations
 
+import time
+
+# Set-up span `setup_import` (metrics.SetupStages) starts here: what
+# importing the trainer pulls in that the process had not loaded yet.
+_IMPORT_T0 = time.perf_counter()
+
 import contextlib
 import os
 import sys
 import threading
-import time
 from typing import Dict, Optional
 
 import numpy as np
@@ -33,11 +38,14 @@ from distributed_ddpg_tpu import trace
 from distributed_ddpg_tpu.config import DDPGConfig
 from distributed_ddpg_tpu.envs import make, spec_of
 from distributed_ddpg_tpu.metrics import (
+    CompileCounter,
     GuardrailStats,
+    LaunchQueue,
     MeshStats,
     MetricsLogger,
     PhaseTimers,
     PodStats,
+    SetupStages,
     Timer,
 )
 from distributed_ddpg_tpu.ops import support_auto
@@ -55,6 +63,8 @@ from distributed_ddpg_tpu.exits import (  # noqa: F401  (re-export)
     EXIT_POD_SHRINK,
     EXIT_PREEMPTED,
 )
+
+_IMPORT_S = time.perf_counter() - _IMPORT_T0
 
 # Shutdown reap bound for the async eval thread: evals run whole episodes,
 # so teardown grants them real time to finish, but a wedged env must not
@@ -121,13 +131,14 @@ def device_facts() -> Dict[str, object]:
 
 
 def train(config: DDPGConfig) -> Dict[str, float]:
+    entered_at = time.perf_counter()
     _enable_faulthandler()
     if config.backend == "native":
         return train_native(config)
     require_platform()
     if config.backend == "jax_ondevice":
         return train_ondevice(config)
-    return train_jax(config)
+    return train_jax(config, entered_at=entered_at)
 
 
 # ---------------------------------------------------------------------------
@@ -155,7 +166,7 @@ def train_native(config: DDPGConfig) -> Dict[str, float]:
         seed=config.seed + 1,
     )
     nstep = NStepAccumulator(config.n_step, config.gamma)
-    log = MetricsLogger(config.log_path, tb_dir=config.tb_dir)
+    log = MetricsLogger(config.log_path)
     learn_timer = Timer()
     learn_steps = 0
     metrics: Dict[str, float] = {}
@@ -244,7 +255,7 @@ def train_ondevice(config: DDPGConfig) -> Dict[str, float]:
 
     multihost.initialize()
     trainer = OnDeviceDDPG(config)
-    log = MetricsLogger(config.log_path, tb_dir=config.tb_dir)
+    log = MetricsLogger(config.log_path)
 
     # Resume: the checkpoint contract matches the other backends (TrainState
     # + replay contents + env-step offset), via a thin adapter for the
@@ -393,7 +404,11 @@ def _jax_env_spec(trainer):
 # ---------------------------------------------------------------------------
 
 
-def train_jax(config: DDPGConfig) -> Dict[str, float]:
+def train_jax(
+    config: DDPGConfig, entered_at: Optional[float] = None
+) -> Dict[str, float]:
+    # `entered_at`: train()'s perf_counter reading at entry, so the
+    # `setup_backend` span covers the backend start train() paid for.
     # Flight recorder (trace.py): armed for the whole device lifetime so
     # the watchdog's stall path below can ship the last-N-seconds
     # timeline with its stack dump. Exported on clean exit and on demand
@@ -435,9 +450,14 @@ def train_jax(config: DDPGConfig) -> Dict[str, float]:
         if watchdog is not None:
             watchdog.grant(extra_s)
 
+    # Set-up counters (docs/OBSERVABILITY.md §2): programs compiled, not
+    # loaded from the cache, until the first chunk is read back.
+    compiles = CompileCounter().install()
     try:
-        return _train_jax_impl(config, _beat, _grant)
+        return _train_jax_impl(config, _beat, _grant, entered_at, compiles)
     finally:
+        compiles.uninstall()
+        trace.set_annotator(None)
         if watchdog is not None:
             watchdog.stop()
         if trace_path:
@@ -458,9 +478,25 @@ def train_jax(config: DDPGConfig) -> Dict[str, float]:
                 trace.disable()
 
 
-def _train_jax_impl(config: DDPGConfig, _beat, _grant=lambda extra_s: None) -> Dict[str, float]:
+def _train_jax_impl(
+    config: DDPGConfig, _beat, _grant, entered_at, compiles
+) -> Dict[str, float]:
     import jax
 
+    # The bracket's second sink (trace.py): from here on every
+    # trace.span in this process is also a profiler annotation, on the
+    # device trace's clock; inert (well under a microsecond) while no
+    # profiler session runs. Only the learner process does this: actor
+    # workers import trace.py and must never load JAX.
+    trace.set_annotator(jax.profiler.TraceAnnotation)
+    # Set-up spans: disjoint stages on this thread, each ending where the
+    # next begins; the last ends when the first chunk is read back.
+    setup = SetupStages()
+    setup.add("setup_import", _IMPORT_S)
+    if entered_at is not None:
+        # train() started the backend before anything could bracket it.
+        setup.add("setup_backend", time.perf_counter() - entered_at)
+    setup.stage("setup_import")
     from distributed_ddpg_tpu import checkpoint as ckpt_lib
     from distributed_ddpg_tpu.actors.policy import NumpyPolicy, actor_head_dim, flatten_params, param_layout
     from distributed_ddpg_tpu.actors.pool import ActorPool
@@ -477,6 +513,7 @@ def _train_jax_impl(config: DDPGConfig, _beat, _grant=lambda extra_s: None) -> D
     )
     from distributed_ddpg_tpu.types import pack_batch_np
 
+    setup.stage("setup_backend")
     # The JAX runtime's own heartbeat killer must stay SLOWER than the
     # pod layer's worst-case detection (deadline + grace), or a peer
     # death during a granted window LOG(FATAL)s survivors before the
@@ -491,6 +528,8 @@ def _train_jax_impl(config: DDPGConfig, _beat, _grant=lambda extra_s: None) -> D
             else None
         )
     )
+    jax.devices()  # devices known: a no-op where train() started the backend
+    setup.stage("setup_build")
     # --- chaos harness + preemption (docs/RESILIENCE.md) ---
     # The fault plan is parsed once; each recoverable component gets its
     # own call-site injector. SIGTERM flips a flag the loop polls at chunk
@@ -1273,9 +1312,7 @@ def _train_jax_impl(config: DDPGConfig, _beat, _grant=lambda extra_s: None) -> D
             )
         return facts
 
-    log = MetricsLogger(
-        config.log_path, tb_dir=config.tb_dir, header=run_facts()
-    )
+    log = MetricsLogger(config.log_path, header=run_facts())
 
     # --- live telemetry ingress (obs/exporter.py; docs/OBSERVABILITY.md
     # §4) --- config.obs_port > 0: a stdlib HTTP thread serves /metrics
@@ -1316,6 +1353,9 @@ def _train_jax_impl(config: DDPGConfig, _beat, _grant=lambda extra_s: None) -> D
 
     learn_timer, env_timer = Timer(), Timer()
     phases = PhaseTimers()
+    # Launches the device has not finished (metrics.LaunchQueue): what a
+    # refresh waits out, and what learner_steps_per_sec must not count.
+    launches = LaunchQueue()
     saver = ckpt_lib.AsyncSaver()
     last_ckpt = learn_steps
 
@@ -1969,7 +2009,7 @@ def _train_jax_impl(config: DDPGConfig, _beat, _grant=lambda extra_s: None) -> D
         nonlocal last_refresh_t, last_log_t
         last_out[0] = out
         learn_steps += chunk * beats
-        learn_timer.tick(chunk * beats)
+        launches.add(jax.tree.leaves(out.metrics)[0], chunk * beats)
         if device_pool is not None:
             # Device-actor param refresh: pointer swap to the LIVE params,
             # re-done every chunk because the dispatch above DONATED the
@@ -2021,7 +2061,7 @@ def _train_jax_impl(config: DDPGConfig, _beat, _grant=lambda extra_s: None) -> D
             config.strict_sync
             or now - last_refresh_t >= config.param_refresh_interval_s
         ):
-            with phases.phase("refresh"):
+            with phases.phase("refresh", learner_step=learn_steps):
                 pool.broadcast(learner.actor_params_to_host(), learn_steps)
             next_refresh = learn_steps + config.param_refresh_every
             last_refresh_t = time.perf_counter()
@@ -2054,7 +2094,7 @@ def _train_jax_impl(config: DDPGConfig, _beat, _grant=lambda extra_s: None) -> D
             # only and fork the mesh. Each expansion costs one XLA
             # recompile at the next dispatch, granted to the watchdog like
             # the initial compile.
-            with phases.phase("sync"):
+            with phases.phase("sync", learner_step=learn_steps):
                 chunk_metrics = learner.metrics_to_host(out)
             # data_bounds_fn: re-derive the rule-1 bound from the replay's
             # CURRENT rewards so a diverging critic can't drag the support
@@ -2134,8 +2174,9 @@ def _train_jax_impl(config: DDPGConfig, _beat, _grant=lambda extra_s: None) -> D
                 float(np.mean([e[1] for e in episodes])) if episodes else None
             )
             if chunk_metrics is None:
-                with phases.phase("sync"):
+                with phases.phase("sync", learner_step=learn_steps):
                     chunk_metrics = learner.metrics_to_host(out)
+            learn_timer.tick(launches.settle())
             log.log(
                 "train", env_steps(),
                 learner_steps=learn_steps,
@@ -2148,6 +2189,7 @@ def _train_jax_impl(config: DDPGConfig, _beat, _grant=lambda extra_s: None) -> D
                 **chunk_metrics,
                 **support_metrics,
                 **phases.snapshot(),
+                **launches.snapshot(),
                 # Ingest pipeline observability (replay/device.py
                 # IngestStats): rows/sec shipped to HBM, coalesce factor,
                 # staging-queue depth, producer stall time.
@@ -2217,6 +2259,18 @@ def _train_jax_impl(config: DDPGConfig, _beat, _grant=lambda extra_s: None) -> D
                 # identical, so the slice sets line up by construction.
                 write_replay_slices(learn_steps)
             last_ckpt = learn_steps
+
+    def dispatch(run, *args, **kwargs):
+        """One launch of the chunk program: every dispatch site goes
+        through here. The span carries the launch's index since the run
+        began, so the k-th `dispatch` on the profiler's host plane lies
+        beside the k-th launch on the device plane, and the launches
+        the device had not finished as this one was made."""
+        learn_timer.tick(launches.poll())
+        with phases.phase(
+            "dispatch", chunk=launches.n_dispatched, in_flight=len(launches)
+        ):
+            return run(*args, **kwargs)
 
     def _host_per_update(out, indices) -> None:
         tds = np.asarray(out.td_errors).reshape(-1)
@@ -2319,6 +2373,7 @@ def _train_jax_impl(config: DDPGConfig, _beat, _grant=lambda extra_s: None) -> D
                     "instead of burning the wall-clock budget"
                 )
 
+        setup.stage("setup_fill")
         warm_it = 0
         while buffer_fill() < min_fill and not preempt.is_set():
             # Lockstep warmup ingest: loop count is driven by the
@@ -2426,6 +2481,7 @@ def _train_jax_impl(config: DDPGConfig, _beat, _grant=lambda extra_s: None) -> D
             last_budget = -1
             first_dispatch_done = False
             t_loop0 = time.monotonic()
+            setup.stage("setup_first_chunk")
             while not preempt.is_set() and not numeric_failed[0]:
                 _beat()
                 # Wall-clock fleet supervision (see last_monitor_t note):
@@ -2514,8 +2570,7 @@ def _train_jax_impl(config: DDPGConfig, _beat, _grant=lambda extra_s: None) -> D
                                 config, budget_now, megastep.beats,
                                 device_pool.rows_per_chunk,
                             )
-                        with phases.phase("dispatch"):
-                            out = megastep.run_superstep(betas=betas)
+                        out = dispatch(megastep.run_superstep, betas=betas)
                         after_chunk(
                             out, None, fused=True, beats=megastep.beats
                         )
@@ -2531,8 +2586,7 @@ def _train_jax_impl(config: DDPGConfig, _beat, _grant=lambda extra_s: None) -> D
                             beta = config.per_beta + frac * (
                                 config.per_beta_final - config.per_beta
                             )
-                        with phases.phase("dispatch"):
-                            out = megastep.run_beat(beta=beta)
+                        out = dispatch(megastep.run_beat, beta=beta)
                         # NOT the shared after_chunk call below: the beat
                         # already ran the rollout+insert, and running
                         # after_chunk twice would double every cadence.
@@ -2548,20 +2602,19 @@ def _train_jax_impl(config: DDPGConfig, _beat, _grant=lambda extra_s: None) -> D
                         beta = config.per_beta + frac * (
                             config.per_beta_final - config.per_beta
                         )
-                        with phases.phase("dispatch"):
-                            out = learner.run_sample_chunk_per(
-                                device_replay, beta
-                            )
+                        out = dispatch(
+                            learner.run_sample_chunk_per, device_replay, beta
+                        )
                         after_chunk(out, None)
                     else:
-                        with phases.phase("dispatch"):
-                            out = learner.run_sample_chunk(device_replay)
+                        out = dispatch(
+                            learner.run_sample_chunk, device_replay
+                        )
                         after_chunk(out, None)
                 else:
                     with phases.phase("sample_wait"):
                         device_chunk, indices = prefetch.next()
-                    with phases.phase("dispatch"):
-                        out = learner.run_chunk_async(device_chunk)
+                    out = dispatch(learner.run_chunk_async, device_chunk)
                     after_chunk(out, indices)
                 if not first_dispatch_done:
                     # The first dispatch blocks on the chunk program's XLA
@@ -2569,8 +2622,14 @@ def _train_jax_impl(config: DDPGConfig, _beat, _grant=lambda extra_s: None) -> D
                     # count against the actor-stall clock.
                     first_dispatch_done = True
                     last_moved_t = time.monotonic()
-                    loop_times["first_chunk_s"] = last_moved_t - t_loop0
+                    # Set-up ends here, and its compile counters with it.
+                    setup.end()
+                    compiles.uninstall()
+                    loop_times["first_chunk_s"] = setup.spans[
+                        "setup_first_chunk"
+                    ]
                 it += 1
+            setup.end()  # a run that never dispatched (preempted in warm-up)
             if first_dispatch_done:
                 loop_times["steady_s"] = (
                     time.monotonic() - t_loop0 - loop_times["first_chunk_s"]
@@ -2712,7 +2771,13 @@ def _train_jax_impl(config: DDPGConfig, _beat, _grant=lambda extra_s: None) -> D
         final_return = _eval_numpy(eval_policy, config, spec)
         if last_out[0] is not None:
             last_metrics = learner.metrics_to_host(last_out[0])
+    learn_timer.tick(launches.settle())
     rate = learn_timer.rate()
+    setup_fields = {
+        "setup_spans": {k: round(v, 3) for k, v in setup.spans.items()},
+        "setup_compile_s": round(compiles.seconds, 3),
+        "setup_programs_compiled": compiles.programs,
+    }
     # ONE serve/devactor snapshot shared by the final record and the
     # returned summary: both stats reset their interval reservoirs at
     # snapshot, so a second call would report zeroed tails.
@@ -2737,10 +2802,12 @@ def _train_jax_impl(config: DDPGConfig, _beat, _grant=lambda extra_s: None) -> D
         final_return=final_return,
         **facts_final,
         **loop_times,
+        **setup_fields,
         **last_metrics,
         **recovery_fields(),
         **ingest_final,
         **phases.snapshot(),
+        **launches.snapshot(),
         **transfer_fields(),
         **pod_fields(),
         **guardrail_fields(),
@@ -2761,8 +2828,11 @@ def _train_jax_impl(config: DDPGConfig, _beat, _grant=lambda extra_s: None) -> D
         # The same sum over the params the run STARTED from (fresh init
         # or restore): differs from param_checksum iff the actor moved.
         "param_checksum_start": checksum_start,
+        # Where this run's records went ("" = stdout only).
+        "log_path": config.log_path,
         **facts_final,
         **loop_times,
+        **setup_fields,
         **last_metrics,
         # Row accounting at exit: every env step handed to the replay is
         # either in the ring (buffer_fill, capacity permitting) or still

@@ -1,6 +1,7 @@
 """MetricsLogger tests (SURVEY.md §5 'Metrics / logging'): JSONL records,
-field-type preservation, PhaseTimers tail latencies, and the TensorBoard
-parity sink."""
+field-type preservation, PhaseTimers tail latencies, and the counters the
+learner loop keeps from inside: launches in flight, set-up stages, programs
+compiled."""
 
 import json
 import os
@@ -9,7 +10,16 @@ import time
 import numpy as np
 import pytest
 
-from distributed_ddpg_tpu.metrics import MetricsLogger, PhaseTimers, Timer, _jsonable
+from distributed_ddpg_tpu import trace
+from distributed_ddpg_tpu.metrics import (
+    CompileCounter,
+    LaunchQueue,
+    MetricsLogger,
+    PhaseTimers,
+    SetupStages,
+    Timer,
+    _jsonable,
+)
 
 
 def test_jsonl_records(tmp_path):
@@ -25,23 +35,6 @@ def test_jsonl_records(tmp_path):
     assert recs[1]["critic_loss"] == 0.5
     assert recs[1]["note"] == "hi"          # non-numeric passes through
     assert recs[2]["step"] == 20
-
-
-@pytest.mark.slow
-def test_tensorboard_sink(tmp_path):
-    tb_dir = tmp_path / "tb"
-    log = MetricsLogger(echo=False, tb_dir=str(tb_dir))
-    assert log._tb is not None, "torch TB writer should be available here"
-    log.log("train", 1, critic_loss=1.25, episode_return=None)
-    log.close()
-    events = [
-        os.path.join(r, f)
-        for r, _, fs in os.walk(tb_dir)
-        for f in fs
-        if "tfevents" in f
-    ]
-    assert events, "no TensorBoard event file written"
-    assert os.path.getsize(events[0]) > 0
 
 
 def test_jsonable_preserves_bool_and_int_types(tmp_path):
@@ -125,3 +118,125 @@ def test_phase_timers_emit_trace_spans():
         assert any(e["name"] == "ckpt" for e in spans)
     finally:
         trace.disable()
+
+
+# --------------------------------------------------------------------------
+# LaunchQueue: launches in flight, counted without a sync
+# --------------------------------------------------------------------------
+
+class _Leaf:
+    """A chunk's output leaf whose readiness the test controls."""
+
+    def __init__(self):
+        self.ready = False
+
+    def is_ready(self):
+        return self.ready
+
+
+def _dispatch(q, leaf, updates=800):
+    done = q.poll()
+    q.add(leaf, updates)
+    return done
+
+
+def test_launch_queue_depth_max_and_finished_updates():
+    q = LaunchQueue()
+    a, b, c, d = _Leaf(), _Leaf(), _Leaf(), _Leaf()
+    assert _dispatch(q, a) == 0 and len(q) == 1
+    assert _dispatch(q, b) == 0 and len(q) == 2
+    assert _dispatch(q, c) == 0 and len(q) == 3
+    a.ready = b.ready = True  # the device finished the two oldest
+    assert _dispatch(q, d) == 1600 and len(q) == 2
+    assert q.steps_done == 1600 and q.n_dispatched == 4
+    snap = q.snapshot()
+    # depths seen by the four dispatches, each counting itself: 1, 2, 3, 2
+    assert snap == {
+        "launches_in_flight_mean": 2.0, "launches_in_flight_max": 3,
+        "n_dispatch_starved": 0,
+    }
+    assert q.snapshot() == {
+        "launches_in_flight_mean": 0.0, "launches_in_flight_max": 0,
+        "n_dispatch_starved": 0,
+    }  # interval-scoped; the queue itself is not reset
+    assert len(q) == 2 and q.steps_done == 1600
+
+
+def test_launch_queue_finishes_in_order_only():
+    q = LaunchQueue()
+    a, b = _Leaf(), _Leaf()
+    _dispatch(q, a)
+    _dispatch(q, b)
+    b.ready = True  # launches finish in dispatch order: b cannot be done before a
+    assert q.settle() == 0 and len(q) == 2
+    a.ready = True
+    assert q.settle() == 1600 and len(q) == 0 and q.steps_done == 1600
+
+
+def test_launch_queue_counts_a_dispatch_that_found_the_device_idle():
+    q = LaunchQueue()
+    a, b, c = _Leaf(), _Leaf(), _Leaf()
+    _dispatch(q, a, updates=8)  # the run's first dispatch is set-up, not starvation
+    a.ready = True
+    assert _dispatch(q, b, updates=8) == 8  # nothing was queued when the host came back
+    assert _dispatch(q, c, updates=8) == 0  # b still running: not starved
+    snap = q.snapshot()
+    assert snap["n_dispatch_starved"] == 1 and snap["launches_in_flight_max"] == 2
+    b.ready = c.ready = True
+    assert q.settle() == 16 and q.steps_done == 24  # settle() outside a dispatch starves nothing
+    assert q.snapshot()["n_dispatch_starved"] == 0
+
+
+# --------------------------------------------------------------------------
+# SetupStages, CompileCounter: set-up measured from inside
+# --------------------------------------------------------------------------
+
+def test_setup_stages_accumulate_by_name_in_the_order_first_staged():
+    s = SetupStages()
+    s.add("setup_import", 0.5)  # measured before anything could bracket it
+    t0 = time.perf_counter()
+    s.stage("setup_import")
+    time.sleep(0.01)
+    s.stage("setup_build")
+    time.sleep(0.02)
+    s.stage("setup_fill")
+    s.end()
+    s.end()  # idempotent
+    wall = time.perf_counter() - t0
+    assert list(s.spans) == ["setup_import", "setup_build", "setup_fill"]
+    assert s.spans["setup_import"] >= 0.51 and s.spans["setup_build"] >= 0.02
+    assert sum(s.spans.values()) - 0.5 <= wall  # disjoint: together no longer than the stretch they cover
+
+
+def test_setup_stages_are_disjoint_spans_in_the_ring_each_ending_where_the_next_begins():
+    rec = trace.configure(capacity=64)
+    try:
+        s = SetupStages()
+        s.stage("setup_build")
+        time.sleep(0.005)
+        s.stage("setup_fill")
+        s.stage("setup_first_chunk")
+        s.end()
+        spans = sorted((e for e in rec.events() if e["ph"] == "X"), key=lambda e: e["ts"])
+    finally:
+        trace.disable()
+    assert [e["name"] for e in spans] == ["setup_build", "setup_fill", "setup_first_chunk"]
+    for a, b in zip(spans, spans[1:]):
+        assert a["ts"] + a["dur"] <= b["ts"] + 1.0  # microseconds
+
+
+def test_compile_counter_counts_builds_that_were_not_cache_hits():
+    import jax.monitoring as m
+
+    c = CompileCounter().install()
+    try:
+        m.record_event_duration_secs(CompileCounter.BUILD, 2.5)  # compiled
+        m.record_event(CompileCounter.HIT)  # JAX: the hit, then its build's duration
+        m.record_event_duration_secs("/jax/compilation_cache/cache_retrieval_time_sec", 0.01)
+        m.record_event_duration_secs(CompileCounter.BUILD, 0.02)  # loaded from the cache
+        m.record_event_duration_secs(CompileCounter.BUILD, 0.5)  # compiled
+    finally:
+        c.uninstall()
+    c.uninstall()  # idempotent
+    m.record_event_duration_secs(CompileCounter.BUILD, 9.0)  # after set-up: not counted
+    assert c.programs == 2 and c.seconds == pytest.approx(3.0)

@@ -23,6 +23,11 @@ _TIME_KEYS = (
     "replay_exchange_ms_p50", "replay_exchange_ms_p95",
     # The final record's compile / steady split of the loop's wall time.
     "first_chunk_s", "steady_s",
+    # Set-up spans and compile seconds; and the launch queue's depth, which
+    # is how far the device happened to lag the host at each dispatch.
+    "setup_spans", "setup_compile_s", "setup_programs_compiled",
+    "launches_in_flight_mean", "launches_in_flight_max",
+    "n_dispatch_starved",
 )
 
 

@@ -5,6 +5,8 @@ CPU run must produce spans from >=3 distinct threads and JSONL records
 carrying t_dispatch_p95 (the PR's acceptance criteria)."""
 
 import json
+import subprocess
+import sys
 import threading
 import time
 
@@ -21,6 +23,7 @@ def _clean_singleton():
     tests' hot paths (span() goes from no-op to recording)."""
     yield
     trace.disable()
+    trace.set_annotator(None)
 
 
 # --------------------------------------------------------------------------
@@ -82,10 +85,13 @@ def test_complete_records_explicit_interval():
 
 def test_threads_get_distinct_tids():
     rec = TraceRecorder(capacity=256)
+    # All three alive at once: a thread that has finished before the next
+    # starts can hand it its ident, and two tracks would share a tid.
+    alive = threading.Barrier(3)
 
     def work(tag):
         with rec.span(tag):
-            time.sleep(0.01)
+            alive.wait(timeout=30)
 
     threads = [
         threading.Thread(target=work, args=(f"w{i}",), name=f"tracer-{i}")
@@ -94,7 +100,8 @@ def test_threads_get_distinct_tids():
     for t in threads:
         t.start()
     for t in threads:
-        t.join()
+        t.join(timeout=30)
+    assert not any(t.is_alive() for t in threads)
     with rec.span("main"):
         pass
     events = rec.events()
@@ -136,6 +143,119 @@ def test_stall_report_artifacts(tmp_path):
     assert any(
         e.get("name") == "pre_stall_work" for e in tr["traceEvents"]
     )
+
+
+# --------------------------------------------------------------------------
+# the annotator sink: the same bracket on the profiler's clock
+# --------------------------------------------------------------------------
+
+class _Annotation:
+    """What the learner installs, minus JAX: a context manager per span."""
+
+    seen: list = []
+
+    def __init__(self, name, **args):
+        self.name, self.args = name, args
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        _Annotation.seen.append((self.name, self.args))
+        return False
+
+
+def test_import_trace_leaves_jax_out_of_the_process():
+    # Actor workers import trace.py; a worker must never load JAX.
+    code = (
+        "import sys; import distributed_ddpg_tpu.trace as t; "
+        "t.set_annotator(None); "
+        "sys.exit(int(any(m == 'jax' or m.startswith('jax.') for m in sys.modules)))"
+    )
+    p = subprocess.run([sys.executable, "-c", code], capture_output=True, timeout=120)
+    assert p.returncode == 0, p.stderr[-500:]
+
+
+def test_span_with_neither_sink_is_the_shared_noop():
+    trace.disable()
+    trace.set_annotator(None)
+    assert trace.span("a") is trace.span("b", n=1) is trace._NULL_SPAN
+
+
+def test_annotator_alone_gets_the_span_and_its_arguments():
+    _Annotation.seen = []
+    trace.set_annotator(_Annotation)
+    with trace.span("dispatch", chunk=7):
+        pass
+    trace.instant("ring_only")  # instants and complete() never reach it
+    trace.complete("ring_only", time.perf_counter(), 0.001)
+    assert _Annotation.seen == [("dispatch", {"chunk": 7})]
+    assert not trace.enabled()
+
+
+def test_ring_and_annotator_record_the_span_once_each():
+    _Annotation.seen = []
+    rec = trace.configure(capacity=64)
+    trace.set_annotator(_Annotation)
+    with trace.span("refresh", learner_step=1600):
+        time.sleep(0.001)
+    ring = [e for e in rec.events() if e["ph"] == "X"]
+    assert [(e["name"], e["args"]) for e in ring] == [("refresh", {"learner_step": 1600})]
+    assert ring[0]["dur"] >= 1000
+    assert _Annotation.seen == [("refresh", {"learner_step": 1600})]
+
+
+def test_phase_timers_bracket_reaches_both_sinks_with_its_cause():
+    from distributed_ddpg_tpu.metrics import PhaseTimers
+
+    _Annotation.seen = []
+    rec = trace.configure(capacity=64)
+    trace.set_annotator(_Annotation)
+    phases = PhaseTimers()
+    with phases.phase("dispatch", chunk=3):
+        pass
+    assert _Annotation.seen == [("dispatch", {"chunk": 3})]
+    assert [e["args"] for e in rec.events() if e["ph"] == "X"] == [{"chunk": 3}]
+    snap = phases.snapshot()
+    assert snap["n_dispatch"] == 1 and "chunk" not in snap  # the cause is the span's, not the record's
+
+
+def test_annotated_spans_land_on_the_profilers_host_plane(tmp_path):
+    """End to end with the real sink: profile a tiny jitted loop the way the
+    benchmark's tracer does (python_tracer_level 0), read the .xplane.pb
+    back, and find every `dispatch` with its `chunk` on the host plane, on
+    the clock the device events share."""
+    import glob
+
+    import jax
+    import jax.numpy as jnp
+    from jax.profiler import ProfileData
+
+    step = jax.jit(lambda x: x * 2.0 + 1.0)
+    x = step(jnp.ones(256)).block_until_ready()
+    trace.set_annotator(jax.profiler.TraceAnnotation)
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        for i in range(4):
+            with trace.span("dispatch", chunk=i):
+                x = step(x)
+        x.block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*" / "*.xplane.pb"))
+    host = [p for p in ProfileData.from_file(path).planes if p.name.startswith("/host:")]
+    found = [
+        (dict(e.stats).get("chunk"), e.start_ns, e.duration_ns, line.name)
+        for plane in host for line in plane.lines for e in line.events
+        if e.name == "dispatch"
+    ]
+    assert [c for c, *_ in found] == [0, 1, 2, 3]
+    assert len({line for *_, line in found}) == 1  # one thread, one line
+    ends = [s + d for _, s, d, _ in found]
+    assert all(d > 0 for _, _, d, _ in found)
+    assert all(found[i + 1][1] >= ends[i] for i in range(3))  # in order, disjoint
 
 
 # --------------------------------------------------------------------------

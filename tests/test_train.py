@@ -478,3 +478,70 @@ def test_restore_rejects_incompatible_config(tmp_path):
         ckpt_lib.restore(
             str(tmp_path), init_train_state(bad, 4, 2, seed=1), config=bad
         )
+
+
+def test_train_returns_setup_spans_and_counts_finished_updates(tmp_path):
+    """What a job waits through before its first useful step, measured from
+    inside (ISSUE 25): five disjoint stages in order, no longer together
+    than the call; the launch queue's fields on every train record; and a
+    summary that says where its records went."""
+    import json
+    import time
+
+    from distributed_ddpg_tpu import train as train_mod
+
+    log_path = tmp_path / "metrics.jsonl"
+    cfg = DDPGConfig(
+        actor_hidden=(16, 16),
+        critic_hidden=(16, 16),
+        num_actors=1,
+        total_env_steps=4_000,
+        replay_min_size=1_500,
+        replay_capacity=16_384,
+        max_ingest_ratio=6.0,  # paces ingest past the 50-chunk record cadence (test_trace.py)
+        eval_every=0,
+        trace_dir=str(tmp_path),
+        log_path=str(log_path),
+    )
+    t0 = time.perf_counter()
+    out = train_mod.train(cfg)
+    wall_s = time.perf_counter() - t0
+
+    spans = out["setup_spans"]
+    assert list(spans) == [
+        "setup_import", "setup_backend", "setup_build", "setup_fill", "setup_first_chunk",
+    ]
+    assert all(v >= 0 for v in spans.values())
+    assert spans["setup_first_chunk"] == pytest.approx(out["first_chunk_s"], abs=1e-3)
+    # The module's own import was paid before the call; the rest lies inside it.
+    assert sum(spans.values()) - train_mod._IMPORT_S <= wall_s
+    assert out["setup_programs_compiled"] >= 1 and out["setup_compile_s"] > 0
+    assert out["log_path"] == str(log_path)
+
+    # The stages as the flight recorder saw them: disjoint, in order, on one thread.
+    events = json.loads((tmp_path / "trace.json").read_text())["traceEvents"]
+    stages = sorted(
+        (e for e in events if e.get("ph") == "X" and e["name"].startswith("setup_")),
+        key=lambda e: e["ts"],
+    )
+    assert [e["name"] for e in stages] == [
+        "setup_import", "setup_backend", "setup_build", "setup_fill", "setup_first_chunk",
+    ]
+    assert len({e["tid"] for e in stages}) == 1
+    for a, b in zip(stages, stages[1:]):
+        assert a["ts"] + a["dur"] <= b["ts"] + 1.0  # microseconds
+    # dispatch spans carry the launch's index; refresh its learner step
+    chunks = [e["args"]["chunk"] for e in events if e.get("name") == "dispatch"]
+    assert chunks == list(range(len(chunks))) and len(chunks) == out["learner_steps"] // 8
+    assert all("learner_step" in e["args"] for e in events if e.get("name") == "refresh")
+
+    records = [json.loads(line) for line in log_path.read_text().splitlines()]
+    train_recs = [r for r in records if r["kind"] == "train"]
+    assert train_recs
+    for r in train_recs:
+        assert 1 <= r["launches_in_flight_mean"] <= r["launches_in_flight_max"]
+        assert 0 <= r["n_dispatch_starved"] <= r["n_dispatch"]
+    final = records[-1]
+    assert final["setup_spans"] == spans and final["kind"] == "final"
+    # After a finished run every update dispatched is an update done.
+    assert final["learner_steps_per_sec"] > 0
