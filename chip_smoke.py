@@ -9,7 +9,10 @@ visible chips, learner_chunk, fused_chunk=auto). Two legs back to back:
 the default leg (Pallas megakernel; fused-mesh on several chips) and
 `--fused_chunk=off` (the XLA scan chunk every other feature rides). Before
 them, the kernel is checked against the scan step on one small chunk
-(tests/fused_parity_util.py, the body tests/test_tpu.py runs).
+(tests/fused_parity_util.py, the body tests/test_tpu.py runs). After them,
+the replay ring at the scan leg's benchmark size (SAC, Humanoid-v4 width,
+1.4e6 rows): the two programs that take it must hold no ring-sized copy
+(tests/ring_layout_util.py, the body tests/test_ring_layout.py runs).
 
 Exit 0 only if every check held. Stdout then ends with two JSON lines: the
 facts of the run (`{"facts": {...}}`: versions, legs, compile cache, ...)
@@ -56,6 +59,7 @@ FLAGS = [
 LEGS = (("default", []), ("scan", ["--fused_chunk=off"]))
 OBS, ACT = 17, 6  # HalfCheetah-v4
 INGEST_BLOCK = 1024  # train_jax's DeviceReplay block: one padded flush at most
+RING_ROWS = 1_400_000  # the sac-humanoid cell's ring
 
 
 class SmokeFailure(Exception):
@@ -90,7 +94,6 @@ def cache_entries(path):
 def run_parity():
     """Kernel vs scan step on one 8-step chunk at full width, natively
     compiled — the repo's own parity body at its on-chip tolerances."""
-    sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), "tests"))
     from fused_parity_util import assert_fused_matches_scan
 
     from distributed_ddpg_tpu.config import DDPGConfig
@@ -102,6 +105,36 @@ def run_parity():
         cfg, OBS, ACT, 8, 1.0, 0.0, interpret=None, rtol=2e-2, atol=1e-2
     )
     return {"critic_loss": round(float(metrics["critic_loss"]), 6)}
+
+
+def run_ring_layout():
+    """The Humanoid-wide ring at the benchmark's size, in the layout its
+    owner picks for it: compile `jit_ring_insert` and the scan
+    `sample_chunk_fn` for it and refuse a `copy` or `transpose` with the
+    ring's shape in either (replay/device.py ring_format)."""
+    from ring_layout_util import humanoid_ring_programs
+
+    from distributed_ddpg_tpu.config import DDPGConfig
+    from distributed_ddpg_tpu.parallel.learner import resolve_learner_chunk
+
+    chunk = resolve_learner_chunk(DDPGConfig())
+    replay, copies = humanoid_ring_programs(RING_ROWS, chunk)
+    for program, found in copies.items():
+        check(not found, f"ring: {program} copies the whole ring: {found}")
+    snap = replay.ingest_snapshot()
+    check(
+        snap["replay_ring_layout"] == "row_major"
+        and snap["replay_row_bytes_device"] == 3584,
+        f"ring: {snap['replay_ring_layout']}, {snap['replay_row_bytes_device']} B a row",
+    )
+    return {
+        "shape": list(replay.storage.shape),
+        "format": str(replay.storage.format.layout),
+        "layout": snap["replay_ring_layout"],
+        "row_bytes_device": snap["replay_row_bytes_device"],
+        "programs": sorted(copies),
+        "chunk": chunk,
+    }
 
 
 def kernel_lowering(chunk):
@@ -213,6 +246,8 @@ def run_checks(device, cache_dir):
     }
     check(facts["native_replay_core"], "the C++ replay core did not build (g++?)")
 
+    # The parity and ring bodies are the tests' own (tests/*_util.py).
+    sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), "tests"))
     t0 = time.monotonic()
     facts["parity"] = run_parity()
     facts["legs"] = {name: run_leg(name, extra, n_devices) for name, extra in LEGS}
@@ -221,6 +256,7 @@ def run_checks(device, cache_dir):
         facts["kernel_lowering"] == "tpu_custom_call",
         "the kernel did not lower to a Mosaic custom call",
     )
+    facts["ring"] = run_ring_layout()
     facts["compile_cache"]["entries_after"] = cache_entries(cache_dir)
     left = mp.active_children()
     for p in left:

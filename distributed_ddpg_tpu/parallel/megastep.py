@@ -94,9 +94,10 @@ def build_beat_body(learner, pool, replay, per: bool, guard: bool,
     sample_fn = L.pure_scan_sample_fn(per)
 
     replicated = NamedSharding(mesh, P())
-    storage_sharding = NamedSharding(
-        mesh, P("data", None) if replay.sharded else P(None, None)
-    )
+    # The ring goes in and comes back in the Format its owner holds it in
+    # (replay/device.py ring_format): the sharding, plus on the TPU the
+    # row-major layout where the row's width picks it.
+    storage_sharding = replay.storage_format
     prio_sharding = NamedSharding(
         mesh, P("data") if replay.sharded else P(None)
     )
@@ -215,11 +216,14 @@ class FusedMegastep:
             self.learner, self.pool, self.replay, self.per, self.guard,
             self.rows_per_beat,
         )
-        self._beat = jax.jit(
-            beat,
-            in_shardings=in_sh,
-            out_shardings=out_sh,
-            donate_argnums=donate,
+        # ring_program: the beat hands the ring back (replay/device.py).
+        self._beat = self.replay.ring_program(
+            jax.jit(
+                beat,
+                in_shardings=in_sh,
+                out_shardings=out_sh,
+                donate_argnums=donate,
+            )
         )
         self._donate = donate
         self._learner_version = self.learner.programs_version

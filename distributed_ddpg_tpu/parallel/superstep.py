@@ -248,11 +248,14 @@ class FusedSuperstep:
         sup_in = in_sh
         sup_out = out_sh
 
-        self._superstep = jax.jit(
-            superstep,
-            in_shardings=sup_in,
-            out_shardings=sup_out,
-            donate_argnums=donate,
+        # ring_program: the superstep hands the ring back (replay/device.py).
+        self._superstep = self.replay.ring_program(
+            jax.jit(
+                superstep,
+                in_shardings=sup_in,
+                out_shardings=sup_out,
+                donate_argnums=donate,
+            )
         )
         self._donate = donate
         self._learner_version = self.learner.programs_version

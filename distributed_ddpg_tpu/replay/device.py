@@ -80,6 +80,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.layout import Format, Layout
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from distributed_ddpg_tpu import trace
@@ -87,6 +88,106 @@ from distributed_ddpg_tpu.metrics import IngestStats, ReplayShardStats
 from distributed_ddpg_tpu.replay.staging import HostStagingRing
 from distributed_ddpg_tpu.transfer import AdaptiveCoalesce, HostBufferPool
 from distributed_ddpg_tpu.types import packed_width
+
+
+# --- the ring's physical layout in HBM (docs/INGEST.md, "The ring's
+# device layout") ---
+# The TPU tiles a 2-D f32 array 8 sublanes x 128 lanes over its two minor
+# dimensions. For f32[capacity, width] the runtime's own choice puts the
+# ROWS minor (XLA `{0,1:T(8,128)}`: feature-major, width padded to 8), which
+# wastes nothing but leaves a row as `width` strided words. At 64 floats a
+# row and more, XLA will neither gather from nor scatter into that: it
+# transposes the whole ring to row-major first, once per sampling launch and
+# twice per insert, 14 ms each on a 4.35 GB ring (PERF.md PR 26). Holding the
+# ring row-major (`{1,0:T(8,128)}`, width padded to 128 lanes) ends the
+# copies at the price of the padding, so the width decides: row-major where
+# it costs at most ROW_MAJOR_MAX_PAD times the compact row.
+_SUBLANES, _LANES = 8, 128
+ROW_MAJOR_MAX_PAD = 1.25
+
+
+def _ceil_to(n: int, multiple: int) -> int:
+    return -(-n // multiple) * multiple
+
+
+def ring_layout(width: int) -> str:
+    """'row_major' or 'compact' (the runtime's own layout) for a ring of
+    `width` floats a row on the TPU: row-major where the 128-lane padding
+    stays within ROW_MAJOR_MAX_PAD of the compact row (Humanoid's 772 ->
+    896 is in; HalfCheetah's 43 -> 128 would be x2.67 and is out)."""
+    if _ceil_to(width, _LANES) <= ROW_MAJOR_MAX_PAD * _ceil_to(width, _SUBLANES):
+        return "row_major"
+    return "compact"
+
+
+def ring_row_bytes(width: int, layout: str) -> int:
+    """Bytes one ring row holds in HBM under `layout`, padding included."""
+    return 4 * _ceil_to(width, _LANES if layout == "row_major" else _SUBLANES)
+
+
+def ring_format(sharding, width: int):
+    """What every program that creates, restores or returns the ring names
+    for it: `sharding` plus the row-major device layout where ring_layout
+    picks it, and the plain `sharding` otherwise — compact rows, no mesh,
+    or devices that are not TPUs (there the programs, and so CPU runs, are
+    what they were). Programs that only READ the ring name no layout: jit
+    adopts a committed argument's."""
+    if (
+        sharding is None
+        or next(iter(sharding.device_set)).platform != "tpu"
+        or ring_layout(width) == "compact"
+    ):
+        return sharding
+    return Format(
+        Layout(major_to_minor=(0, 1), tiling=((_SUBLANES, _LANES),)), sharding
+    )
+
+
+_UNCACHED_COMPILE = threading.Lock()
+
+
+class _RingProgram:
+    """A jitted program that RETURNS the row-major ring, compiled once per
+    argument shapes with the persistent compile cache out of the way and
+    dispatched through that executable. Such a program must never be loaded
+    from the cache: this runtime (jax 0.9.0, libtpu 0.0.34) labels every
+    output of a deserialized executable with the DEFAULT layout, whatever
+    it was compiled for. The buffer is row-major, `.format` says
+    feature-major, and the next program either refuses the ring ("Layout
+    passed to jit does not match") or is compiled for a layout the buffer
+    does not have (my chip runs, PR 26; PERF.md §6). The switch is
+    process-wide, hence the lock; a compile on another thread meanwhile
+    misses the cache once and nothing else. `lower` is the jit's own."""
+
+    def __init__(self, jitted):
+        self._jitted = jitted
+        self._compiled = {}
+        self.lower = jitted.lower
+
+    def __call__(self, *args):
+        return self.compiled(*args)(*args)
+
+    def compiled(self, *args):
+        """The executable for `args` (arrays or ShapeDtypeStructs), built
+        on first sight of their shapes."""
+        shapes = tuple(np.shape(a) for a in jax.tree.leaves(args))
+        if shapes not in self._compiled:
+            self._compiled[shapes] = self._compile(self._jitted.lower(*args))
+        return self._compiled[shapes]
+
+    @staticmethod
+    def _compile(lowered):
+        from jax.experimental.compilation_cache import compilation_cache
+
+        with _UNCACHED_COMPILE:
+            was = jax.config.jax_enable_compilation_cache
+            jax.config.update("jax_enable_compilation_cache", False)
+            compilation_cache.reset_cache()  # the decision is latched
+            try:
+                return lowered.compile()
+            finally:
+                jax.config.update("jax_enable_compilation_cache", was)
+                compilation_cache.reset_cache()
 
 
 class IngestError(RuntimeError):
@@ -220,12 +321,28 @@ class DeviceReplay:
             else None
         )
         scalar_sharding = NamedSharding(mesh, P()) if mesh is not None else None
-        self._storage_sharding = sharding
-        self.storage = jnp.zeros((self.capacity, self.width), jnp.float32)
+        # The ring's Format (ring_format): the plain sharding (None without
+        # a mesh), or sharding plus the row-major layout. Every program that
+        # returns the ring names it, here and in the fused beat and the
+        # superstep, or a donated insert would hand the ring back in the
+        # default layout and silently undo it.
+        self.storage_format = ring_format(sharding, self.width)
+        self.storage = self._place_storage(None)
+        # What says it engaged (ingest_snapshot's replay_ring_layout /
+        # replay_row_bytes_device): the layout the ring is held in and the
+        # bytes a row takes there — tiled and padded on the TPU, the bare
+        # packed row elsewhere.
+        self.ring_layout = (
+            "row_major" if isinstance(self.storage_format, Format) else "compact"
+        )
+        self.row_bytes_device = (
+            ring_row_bytes(self.width, self.ring_layout)
+            if next(iter(self.storage.devices())).platform == "tpu"
+            else 4 * self.width
+        )
         self.ptr = jnp.zeros((), jnp.int32)
         self.size = jnp.zeros((), jnp.int32)
         if sharding is not None:
-            self.storage = jax.device_put(self.storage, sharding)
             self.ptr = jax.device_put(self.ptr, scalar_sharding)
             self.size = jax.device_put(self.size, scalar_sharding)
         # Placement-layer observability (metrics.ReplayShardStats): landed
@@ -269,8 +386,13 @@ class DeviceReplay:
             donate_argnums=(0,),
             **(
                 dict(
-                    in_shardings=(sharding, sharding, scalar_sharding, scalar_sharding),
-                    out_shardings=(sharding, scalar_sharding, scalar_sharding),
+                    in_shardings=(
+                        self.storage_format, sharding, scalar_sharding,
+                        scalar_sharding,
+                    ),
+                    out_shardings=(
+                        self.storage_format, scalar_sharding, scalar_sharding
+                    ),
                 )
                 if sharding is not None
                 else {}
@@ -300,7 +422,24 @@ class DeviceReplay:
         # mode the replicated-storage program is never built — the
         # per-shard scatter caches below replace it (same bounded set of
         # shapes, one program per m).
-        self._insert = None if self.sharded else donate(ring_insert)
+        self._insert = (
+            None if self.sharded else self.ring_program(donate(ring_insert))
+        )
+        if isinstance(self._insert, _RingProgram):  # row-major, not sharded
+            # No cache to load them from in a moment (_RingProgram), so the
+            # super-block shapes compile here, in set-up, and not under the
+            # dispatch lock at each one's first ship.
+            k = 1
+            while k <= self._max_coalesce:
+                self._insert.compiled(
+                    self.storage,
+                    jax.ShapeDtypeStruct(
+                        (k * self.block_size, self.width), jnp.float32,
+                        sharding=sharding,
+                    ),
+                    self.ptr, self.size,
+                )
+                k *= 2
         if self.sharded:
             self._block_sharding_sharded = NamedSharding(mesh, P("data", None))
             self._scalar_sharding = scalar_sharding
@@ -325,10 +464,11 @@ class DeviceReplay:
                 )
             self._block_sharding = NamedSharding(mesh, P("data", None))
             self._global_in_shardings = (
-                sharding, self._block_sharding, scalar_sharding, scalar_sharding
+                self.storage_format, self._block_sharding, scalar_sharding,
+                scalar_sharding,
             )
             self._global_out_shardings = (
-                sharding, scalar_sharding, scalar_sharding
+                self.storage_format, scalar_sharding, scalar_sharding
             )
             self._insert_global_cache = {}
             self._insert_global_sharded_cache = {}
@@ -386,6 +526,32 @@ class DeviceReplay:
 
     def __len__(self) -> int:
         return int(jax.device_get(self.size))
+
+    def _place_storage(self, rows: Optional[np.ndarray]):
+        """The ring on the device in its Format (ring_format): zeros when
+        `rows` is None, else the restored PHYSICAL rows. Row-major zeros
+        are made in place by a program whose output names the Format —
+        allocating in the default layout and converting would hold two
+        rings in HBM. (A restore does hold two while its rows are relaid:
+        they land in the default layout first.)"""
+        fmt = self.storage_format
+        if isinstance(fmt, Format):
+            if rows is not None:
+                relay = self.ring_program(jax.jit(lambda x: x, out_shardings=fmt))
+                return relay(rows)
+            zeros = self.ring_program(
+                jax.jit(
+                    partial(jnp.zeros, (self.capacity, self.width), jnp.float32),
+                    out_shardings=fmt,
+                )
+            )
+            return zeros()
+        storage = (
+            jnp.zeros((self.capacity, self.width), jnp.float32)
+            if rows is None
+            else jnp.asarray(rows)
+        )
+        return storage if fmt is None else jax.device_put(storage, fmt)
 
     def reward_sample(self, max_n: int = 100_000):
         """(reward, discount) columns, up to max_n rows, pulled to host —
@@ -463,17 +629,19 @@ class DeviceReplay:
         out = self._stats.snapshot(pending_rows=self.pending_rows)
         out["ingest_shipper_restarts"] = self._shipper_restarts
         # Placement-layer fields (replay_* family, docs/REPLAY_SHARDING.md):
-        # measured landed bytes/row, per-device storage bytes, per-shard
-        # fill, exchange-dispatch tails.
+        # measured landed bytes/row, per-device storage bytes (rows as the
+        # device pads them), per-shard fill, exchange-dispatch tails.
         out.update(
             self._shard_stats.snapshot(
                 n_shards=self._n_shards,
                 device_storage_bytes=(
-                    self.capacity * self.width * 4 // self._n_shards
+                    self.capacity * self.row_bytes_device // self._n_shards
                 ),
                 fill=len(self),
             )
         )
+        out["replay_ring_layout"] = self.ring_layout
+        out["replay_row_bytes_device"] = self.row_bytes_device
         return out
 
     def transfer_snapshot(self) -> dict:
@@ -1090,7 +1258,7 @@ class DeviceReplay:
                 st = st.at[slots].set(bl)
                 return st, (ptr + m) % cap, jnp.minimum(size + m, cap)
 
-            fn = jax.jit(
+            fn = self.ring_program(jax.jit(
                 mesh_lib.shard_map(
                     ring_insert_grouped, self._mesh,
                     in_specs=(P("data", None), P("data", None), P(), P()),
@@ -1098,14 +1266,14 @@ class DeviceReplay:
                 ),
                 donate_argnums=(0,),
                 in_shardings=(
-                    self._storage_sharding, self._block_sharding_sharded,
+                    self.storage_format, self._block_sharding_sharded,
                     self._scalar_sharding, self._scalar_sharding,
                 ),
                 out_shardings=(
-                    self._storage_sharding, self._scalar_sharding,
+                    self.storage_format, self._scalar_sharding,
                     self._scalar_sharding,
                 ),
-            )
+            ))
             self._insert_grouped_cache[m] = fn
         return fn
 
@@ -1164,19 +1332,19 @@ class DeviceReplay:
         wrapper over _make_insert_replrows_body."""
         fn = self._insert_replrows_cache.get(m)
         if fn is None:
-            fn = jax.jit(
+            fn = self.ring_program(jax.jit(
                 self._make_insert_replrows_body(m),
                 donate_argnums=(0,),
                 in_shardings=(
-                    self._storage_sharding,
+                    self.storage_format,
                     NamedSharding(self._mesh, P(None, None)),
                     self._scalar_sharding, self._scalar_sharding,
                 ),
                 out_shardings=(
-                    self._storage_sharding, self._scalar_sharding,
+                    self.storage_format, self._scalar_sharding,
                     self._scalar_sharding,
                 ),
-            )
+            ))
             self._insert_replrows_cache[m] = fn
         return fn
 
@@ -1216,11 +1384,11 @@ class DeviceReplay:
             )
         fn = self._reshard_cache.get("rows")
         if fn is None:
-            fn = jax.jit(
+            fn = self.ring_program(jax.jit(
                 self._make_reshard_body(),
                 in_shardings=(NamedSharding(self._mesh, P(None, None)),),
-                out_shardings=self._storage_sharding,
-            )
+                out_shardings=self.storage_format,
+            ))
             self._reshard_cache["rows"] = fn
         return fn
 
@@ -1258,7 +1426,7 @@ class DeviceReplay:
                 st = st.at[loc].set(full, mode="drop")
                 return st, (ptr + m) % cap, jnp.minimum(size + m, cap)
 
-            fn = jax.jit(
+            fn = self.ring_program(jax.jit(
                 mesh_lib.shard_map(
                     ring_insert_global_sharded, self._mesh,
                     in_specs=(P("data", None), P("data", None), P(), P()),
@@ -1266,14 +1434,14 @@ class DeviceReplay:
                 ),
                 donate_argnums=(0,),
                 in_shardings=(
-                    self._storage_sharding, self._block_sharding,
+                    self.storage_format, self._block_sharding,
                     self._scalar_sharding, self._scalar_sharding,
                 ),
                 out_shardings=(
-                    self._storage_sharding, self._scalar_sharding,
+                    self.storage_format, self._scalar_sharding,
                     self._scalar_sharding,
                 ),
-            )
+            ))
             self._insert_global_sharded_cache[k] = fn
         return fn
 
@@ -1309,12 +1477,12 @@ class DeviceReplay:
                 new_size = jnp.minimum(size + m, self.capacity)
                 return storage, new_ptr, new_size
 
-            fn = jax.jit(
+            fn = self.ring_program(jax.jit(
                 ring_insert_global,
                 donate_argnums=(0,),
                 in_shardings=self._global_in_shardings,
                 out_shardings=self._global_out_shardings,
-            )
+            ))
             self._insert_global_cache[k] = fn
         return fn
 
@@ -1384,6 +1552,14 @@ class DeviceReplay:
 
     def device_state(self):
         return self.storage, self.size
+
+    def ring_program(self, jitted):
+        """`jitted`, a program whose output names storage_format, made safe
+        to dispatch: behind _RingProgram where the ring is held row-major,
+        itself otherwise."""
+        if isinstance(self.storage_format, Format):
+            return _RingProgram(jitted)
+        return jitted
 
     # --- checkpoint support (same contract as host buffers) ---
 
@@ -1530,12 +1706,7 @@ class DeviceReplay:
             else:
                 storage = np.array(jax.device_get(self.storage))  # writable copy
                 storage[:n] = state["packed"]
-            sharding = self._storage_sharding
-            self.storage = (
-                jax.device_put(jnp.asarray(storage), sharding)
-                if sharding is not None
-                else jnp.asarray(storage)
-            )
+            self.storage = self._place_storage(storage)
             self.ptr = jnp.asarray(int(state["ptr"]) % self.capacity, jnp.int32)
             self.size = jnp.asarray(n, jnp.int32)
             if self._mesh is not None:

@@ -1,0 +1,82 @@
+"""Shared check that no program copies the replay ring, used by BOTH tiers:
+
+- tests/test_ring_layout.py compiles the programs at Humanoid width and a
+  small capacity (on the CPU a guard on the plumbing; for a described v5e,
+  the guard on the layout itself), and
+- chip_smoke.py runs the same body on the chip at the benchmark's 1.4e6
+  rows.
+
+A ring-sized `copy` or `transpose` in a program that takes the ring is XLA
+re-laying the whole ring before it gathers from or scatters into it: one
+pass over gigabytes per launch (replay/device.py ring_format; PERF.md PR 26).
+"""
+
+import re
+
+# SAC at Humanoid-v4 shapes: outside fits_vmem, so the learner picks the
+# scan chunk by itself (the benchmark's sac-humanoid configuration).
+HUMANOID_OBS, HUMANOID_ACT, HUMANOID_SCALE = 376, 17, 0.4
+SAC_HUMANOID_FLAGS = [
+    "--backend=jax_tpu",
+    "--env_id=Humanoid-v4",
+    "--sac=true",
+    "--batch_size=256",
+    "--actor_lr=3e-4",
+    "--critic_lr=3e-4",
+    "--tau=0.005",
+]
+
+
+def ring_sized_copies(hlo_text: str, shape) -> list:
+    """The `copy` and `transpose` instructions of an optimised HLO module
+    whose result has the ring's shape, as 'name = f32[..]{layout} op'."""
+    rows, width = shape
+    pat = re.compile(
+        r"\s*(?:ROOT )?%?([\w.\-]+) = (f32\[" + f"{rows},{width}"
+        + r"\]\{[^}]*\}) (copy|transpose)\("
+    )
+    return [
+        f"{m.group(1)} = {m.group(2)} {m.group(3)}"
+        for m in map(pat.match, hlo_text.splitlines())
+        if m
+    ]
+
+
+def humanoid_ring_programs(capacity: int, chunk: int):
+    """Build the SAC/Humanoid scan learner and its ring, and compile the two
+    programs that take the ring: `jit_ring_insert` and the scan
+    `sample_chunk_fn`. Returns (replay, {program: ring-sized copies})."""
+    import jax
+    import numpy as np
+
+    from distributed_ddpg_tpu.config import DDPGConfig
+    from distributed_ddpg_tpu.parallel.learner import ShardedLearner
+    from distributed_ddpg_tpu.replay.device import DeviceReplay
+
+    cfg = DDPGConfig.from_flags(
+        SAC_HUMANOID_FLAGS + [f"--replay_capacity={capacity}"]
+    )
+    learner = ShardedLearner(
+        cfg, HUMANOID_OBS, HUMANOID_ACT, HUMANOID_SCALE, 0.0, chunk_size=chunk
+    )
+    assert not learner.fused_chunk_active, "Humanoid SAC must ride the scan leg"
+    replay = DeviceReplay(
+        capacity, HUMANOID_OBS, HUMANOID_ACT, mesh=learner.mesh, block_size=1024
+    )
+    shape = replay.storage.shape
+    block = jax.device_put(
+        np.zeros((replay.block_size, replay.width), np.float32),
+        jax.sharding.NamedSharding(learner.mesh, jax.sharding.PartitionSpec()),
+    )
+    programs = {
+        "jit_ring_insert": replay._insert.lower(
+            replay.storage, block, replay.ptr, replay.size
+        ),
+        "jit_sample_chunk_fn": learner._sample_chunk_step.lower(
+            learner.state, learner._key, *replay.device_state()
+        ),
+    }
+    return replay, {
+        name: ring_sized_copies(lowered.compile().as_text(), shape)
+        for name, lowered in programs.items()
+    }

@@ -1,0 +1,252 @@
+"""The replay ring's device layout (replay/device.py ring_format): the width
+rule, that nothing changes off the TPU, and that no program that takes the
+ring copies it — compiled here for the CPU and, through the TPU compiler the
+sandbox carries, for a described v5e."""
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.experimental.layout import Format
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from distributed_ddpg_tpu.replay.device import (
+    DeviceReplay,
+    _RingProgram,
+    ring_format,
+    ring_layout,
+    ring_row_bytes,
+)
+from ring_layout_util import humanoid_ring_programs, ring_sized_copies
+
+
+@pytest.mark.parametrize(
+    "width,layout,row_bytes",
+    [
+        (43, "compact", 192),      # HalfCheetah: 43 -> 128 lanes would be x2.67
+        (65, "compact", 288),      # Ant
+        (120, "row_major", 512),
+        (128, "row_major", 512),
+        (772, "row_major", 3584),  # Humanoid: 772 -> 896, +15.5%
+        (1027, "row_major", 4608),
+    ],
+)
+def test_width_rule(width, layout, row_bytes):
+    assert ring_layout(width) == layout
+    assert ring_row_bytes(width, layout) == row_bytes
+    # The compact row pads to 8 sublanes, whatever the rule picked.
+    assert ring_row_bytes(width, "compact") == 4 * (-(-width // 8) * 8)
+
+
+def test_plain_sharding_off_the_tpu():
+    mesh = Mesh(np.array(jax.devices()[:1]).reshape(1, 1), ("data", "model"))
+    sharding = NamedSharding(mesh, P(None, None))
+    # Humanoid's width picks row-major on the TPU; here the helper hands the
+    # sharding back untouched, and no mesh stays no sharding.
+    assert ring_format(sharding, 772) is sharding
+    assert ring_format(sharding, 43) is sharding
+    assert ring_format(None, 772) is None
+    r = DeviceReplay(64, 376, 17, mesh=mesh, block_size=16)
+    assert r.storage_format == sharding and not isinstance(r.storage_format, Format)
+    assert r.storage.sharding == sharding
+    snap = r.ingest_snapshot()
+    assert snap["replay_ring_layout"] == "compact"
+    assert snap["replay_row_bytes_device"] == 4 * 772
+    assert snap["replay_device_storage_bytes"] == 64 * 4 * 772
+
+
+@pytest.mark.parametrize("with_mesh", [False, True])
+def test_fill_wrap_save_restore_rows(with_mesh):
+    """Built, filled, wrapped, saved and restored: the rows the ring holds
+    are the rows a plain numpy ring holds, bit for bit."""
+    mesh = (
+        Mesh(np.array(jax.devices()[:1]).reshape(1, 1), ("data", "model"))
+        if with_mesh
+        else None
+    )
+    cap, block = 96, 16
+    r = DeviceReplay(cap, 376, 17, mesh=mesh, block_size=block, max_coalesce=1)
+    rng = np.random.default_rng(0)
+    rows = rng.standard_normal((cap + 3 * block, r.width)).astype(np.float32)
+    want = np.zeros((cap, r.width), np.float32)
+    for at in range(0, len(rows), block):
+        r.add_packed(rows[at : at + block])
+        want[(at + np.arange(block)) % cap] = rows[at : at + block]
+    r.drain_pending()
+    assert r.storage.shape == (cap, r.width)
+    np.testing.assert_array_equal(np.asarray(r.device_state()[0]), want)
+    state = r.state_dict()
+    assert int(state["ptr"]) == (3 * block) % cap and int(state["size"]) == cap
+    r2 = DeviceReplay(cap, 376, 17, mesh=mesh, block_size=block, max_coalesce=1)
+    r2.load_state_dict(state)
+    np.testing.assert_array_equal(np.asarray(r2.storage), want)
+    assert r2.storage.sharding == r.storage.sharding
+    # The restored ring goes on taking inserts where the saved one stopped.
+    more = rng.standard_normal((block, r.width)).astype(np.float32)
+    r2.add_packed(more)
+    r2.drain_pending()
+    want[(3 * block + np.arange(block)) % cap] = more
+    np.testing.assert_array_equal(np.asarray(r2.storage), want)
+
+
+def test_no_ring_sized_copy_in_the_programs_that_take_the_ring():
+    """`jit_ring_insert` and the scan `sample_chunk_fn` at Humanoid width:
+    no `copy` or `transpose` with the ring's shape in the optimised HLO
+    (chip_smoke.py asserts the same on the v5e at 1.4e6 rows)."""
+    replay, copies = humanoid_ring_programs(capacity=4096, chunk=2)
+    assert replay.storage.shape == (4096, 772)
+    assert set(copies) == {"jit_ring_insert", "jit_sample_chunk_fn"}
+    assert copies == {"jit_ring_insert": [], "jit_sample_chunk_fn": []}
+
+
+def test_ring_sized_copies_reads_hlo_text():
+    text = "\n".join(
+        [
+            "  %copy.3 = f32[4096,772]{1,0:T(8,128)} copy(%storage.1), sharding={replicated}",
+            "  ROOT %transpose.9 = f32[4096,772]{0,1:T(8,128)} transpose(%fusion)",
+            "  %copy.4 = f32[1024,772]{1,0:T(8,128)} copy(%block.1)",
+            "  %fusion = f32[4096,772]{1,0:T(8,128)} fusion(%copy.3, %copy.4), kind=kCustom",
+        ]
+    )
+    assert ring_sized_copies(text, (4096, 772)) == [
+        "copy.3 = f32[4096,772]{1,0:T(8,128)} copy",
+        "transpose.9 = f32[4096,772]{0,1:T(8,128)} transpose",
+    ]
+
+
+@pytest.fixture
+def persistent_cache(tmp_path):
+    """A persistent compile cache of this test's own that keeps every
+    program, as the benchmark harness's does."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    names = (
+        "jax_compilation_cache_dir",
+        "jax_persistent_cache_min_compile_time_secs",
+        "jax_persistent_cache_min_entry_size_bytes",
+        "jax_enable_compilation_cache",
+    )
+    before = {name: getattr(jax.config, name) for name in names}
+    for name, value in zip(names, (str(tmp_path), 0, -1, True)):
+        jax.config.update(name, value)
+    compilation_cache.reset_cache()
+    yield tmp_path
+    for name, value in before.items():
+        jax.config.update(name, value)
+    compilation_cache.reset_cache()
+
+
+def test_ring_program_never_touches_the_persistent_cache(persistent_cache):
+    """Where the ring is row-major, the programs that return it go through
+    _RingProgram: compiled per argument shapes with the persistent cache
+    switched off around the compile (an executable loaded from it labels its
+    outputs with the default layout) and switched back on after."""
+
+    def bump(ring, block, by):
+        return ring.at[: block.shape[0]].set(block + by), ring.sum()
+
+    def entries():
+        return sorted(f.name for f in persistent_cache.iterdir() if "bump" in f.name)
+
+    jitted = jax.jit(bump, donate_argnums=(0,))
+    program = _RingProgram(jitted)
+    assert program.lower == jitted.lower
+    for rows in (2, 4, 2):  # one executable per block shape, reused after
+        ring, total = program(jnp.ones((8, 3)), jnp.zeros((rows, 3)), 2.0)
+        want = np.ones((8, 3), np.float32)
+        want[:rows] = 2.0
+        np.testing.assert_array_equal(np.asarray(ring), want)
+        assert float(total) == 24.0
+    assert len(program._compiled) == 2
+    assert entries() == []
+    assert jax.config.jax_enable_compilation_cache is True
+    # A program of another shape through the plain jit does land in the
+    # cache: the switch was put back, and the cache was live all along.
+    jitted(jnp.ones((16, 3)), jnp.zeros((2, 3)), 2.0)
+    assert len(entries()) >= 1
+
+
+def test_ring_program_only_where_the_ring_is_row_major():
+    r = DeviceReplay(64, 376, 17, block_size=16)
+    jitted = jax.jit(lambda x: x)
+    assert r.ring_program(jitted) is jitted  # off the TPU: the jit itself
+    assert not isinstance(r._insert, _RingProgram)
+
+
+# --- compiled for a described v5e: the layout itself. The TPU's compiler is
+# installed in the sandbox and compiles for a chip that is described, not
+# attached; nothing runs. The topology is described inside a fixture (never
+# at import: one process at a time may load libtpu), and this is the one
+# test file that does so. ---
+
+CAPACITY = 1_000_000  # rows, the papers' ring: too large for XLA to move into faster memory
+
+
+@pytest.fixture(scope="module")
+def v5e_sharding():
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler here: nothing to compile for
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "model"))
+    return NamedSharding(mesh, P(None, None))
+
+
+def _compiled_for(sharding, width, fmt):
+    """(insert, gather) executables for a ring of CAPACITY x width whose
+    parameter and result are held in `fmt`. The insert is the ring's own
+    scatter (DeviceReplay's ring_insert), written out here so that no
+    CAPACITY-row ring is allocated on the CPU to borrow it from."""
+
+    def ring_insert(storage, block, ptr, size):
+        m = block.shape[0]
+        idx = (ptr + jnp.arange(m, dtype=jnp.int32)) % CAPACITY
+        storage = storage.at[idx].set(block)
+        return storage, (ptr + m) % CAPACITY, jnp.minimum(size + m, CAPACITY)
+
+    insert = ring_insert
+    replicated = NamedSharding(sharding.mesh, P())
+    ring = jax.ShapeDtypeStruct((CAPACITY, width), jnp.float32, sharding=fmt)
+    block = jax.ShapeDtypeStruct((1024, width), jnp.float32, sharding=sharding)
+    scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=replicated)
+    idx = jax.ShapeDtypeStruct((8, 256), jnp.int32, sharding=replicated)
+    inserted = jax.jit(
+        insert, donate_argnums=(0,), out_shardings=(fmt, replicated, replicated)
+    ).lower(ring, block, scalar, scalar).compile()
+    gathered = jax.jit(lambda storage, i: storage[i]).lower(ring, idx).compile()
+    return inserted, gathered
+
+
+@pytest.mark.parametrize("width", [43, 772])
+def test_v5e_programs_hold_no_ring_sized_copy(v5e_sharding, width):
+    fmt = ring_format(v5e_sharding, width)
+    if width == 772:
+        assert isinstance(fmt, Format) and fmt.sharding is v5e_sharding
+        assert fmt.layout.major_to_minor == (0, 1)
+        assert fmt.layout.tiling == ((8, 128),)
+    else:
+        assert fmt is v5e_sharding  # narrow rows keep the runtime's layout
+    inserted, gathered = _compiled_for(v5e_sharding, width, fmt)
+    shape = (CAPACITY, width)
+    assert ring_sized_copies(inserted.as_text(), shape) == []
+    assert ring_sized_copies(gathered.as_text(), shape) == []
+    # The donated insert hands the ring back in the layout it came in.
+    assert inserted.output_formats[0].layout == inserted.input_formats[0][0].layout
+    if width == 772:
+        assert inserted.output_formats[0].layout == fmt.layout
+        # One ring in HBM while the insert runs, not two.
+        assert inserted.memory_analysis().temp_size_in_bytes < CAPACITY * 4 * width // 8
+
+
+def test_v5e_default_layout_is_what_the_rule_replaces(v5e_sharding):
+    """The finding the rule rests on: left to the runtime, the Humanoid-wide
+    ring lies feature-major and XLA re-lays it whole, twice an insert and
+    once a gather. If a later compiler stops, the rule can go."""
+    inserted, gathered = _compiled_for(v5e_sharding, 772, v5e_sharding)
+    assert inserted.input_formats[0][0].layout.major_to_minor == (1, 0)
+    shape = (CAPACITY, 772)
+    assert len(ring_sized_copies(inserted.as_text(), shape)) == 2
+    assert len(ring_sized_copies(gathered.as_text(), shape)) == 1
