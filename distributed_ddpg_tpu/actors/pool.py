@@ -54,6 +54,7 @@ from distributed_ddpg_tpu.actors.policy import (
 from distributed_ddpg_tpu.actors.worker import run_worker
 from distributed_ddpg_tpu.config import DDPGConfig
 from distributed_ddpg_tpu.envs.registry import EnvSpec
+from distributed_ddpg_tpu.metrics import nstep_counters
 
 # Reap bound for a worker we just terminate()d: long enough for the OS to
 # deliver SIGTERM and tear the process down, short enough that a zombie
@@ -145,6 +146,13 @@ class ActorPool:
             self._serve_fallbacks = self._ctx.Array(
                 "l", self.num_actors, lock=False
             )
+        # n-step row counters (replay/nstep.py): [rows, short rows] per
+        # worker slot, written by the worker at each flush, read here.
+        self._nstep_counts = (
+            self._ctx.Array("l", 2 * self.num_actors, lock=False)
+            if config.n_step > 1
+            else None
+        )
         self._episodes = self._ctx.Queue(maxsize=16 * self.num_actors)
         self._heartbeat = self._ctx.Array("d", self.num_actors, lock=False)
         self._stop = self._ctx.Value("b", 0)
@@ -256,6 +264,7 @@ class ActorPool:
                 serve_fallbacks=self._serve_fallbacks,
                 serve_timeout_s=self.config.serve_timeout_s,
                 serve_fallback_s=self.config.serve_fallback_s,
+                nstep_counts=self._nstep_counts,
                 # Flight recorder: workers are separate processes, so each
                 # keeps its OWN ring and exports trace_actor<k>.json on
                 # clean exit; Perfetto merges the files by pid.
@@ -322,6 +331,13 @@ class ActorPool:
         return {
             "serve_client_fallbacks": int(sum(self._serve_fallbacks)),
         }
+
+    def nstep_counters(self) -> Dict[str, int]:
+        """Rows the workers' n-step accumulators emitted and how many of
+        them carry fewer than n steps (episode ends, truncation flushes),
+        summed over workers since the run began; empty at n_step 1, where
+        every row is one step."""
+        return {} if self._nstep_counts is None else nstep_counters(self._nstep_counts)
 
     # --- param broadcast (learner -> workers) ---
 

@@ -34,6 +34,7 @@ from distributed_ddpg_tpu.actors.policy import (
 from distributed_ddpg_tpu.config import DDPGConfig
 from distributed_ddpg_tpu.envs import make
 from distributed_ddpg_tpu.envs.registry import EnvSpec
+from distributed_ddpg_tpu.metrics import nstep_counters
 from distributed_ddpg_tpu.ops.noise import OUNoise
 from distributed_ddpg_tpu.replay.nstep import NStepAccumulator
 
@@ -100,7 +101,7 @@ class SyncActorPool:
     """Drop-in ActorPool replacement with deterministic inline stepping.
     Same driver-facing surface (train.py uses: start/stop/broadcast/
     drain_batches/drain_into/steps_received/monitor/episode_stats/
-    staleness/env_steps_offset)."""
+    staleness/nstep_counters/env_steps_offset)."""
 
     def __init__(self, config: DDPGConfig, spec: EnvSpec,
                  num_actors: Optional[int] = None):
@@ -159,6 +160,14 @@ class SyncActorPool:
         # Lockstep: experience is produced synchronously under the latest
         # broadcast params — staleness is zero by construction.
         return {"staleness_mean": 0.0, "staleness_max": 0}
+
+    def nstep_counters(self) -> Dict[str, int]:
+        """ActorPool.nstep_counters, over the inline actors."""
+        if self.config.n_step <= 1:
+            return {}
+        return nstep_counters(
+            [n for a in self._actors for n in (a.nstep.rows, a.nstep.short_rows)]
+        )
 
     # --- experience ---
 

@@ -60,6 +60,7 @@ def run_worker(
     serve_fallbacks=None,       # mp.Array('l'): local-act fallback counters
     serve_timeout_s: float = 1.0,
     serve_fallback_s: float = 5.0,
+    nstep_counts=None,          # mp.Array('l', 2 * num_workers), or None
 ) -> None:
     # Workers are CPU-only by construction; make BLAS behave in many procs.
     os.environ.setdefault("OMP_NUM_THREADS", "1")
@@ -109,6 +110,10 @@ def run_worker(
         seed=seed,
     )
     nstep = NStepAccumulator(n_step, gamma)
+    if nstep_counts is not None:
+        # This slot's (rows, short rows) so far: a respawned worker counts on.
+        nstep.rows = nstep_counts[2 * worker_id]
+        nstep.short_rows = nstep_counts[2 * worker_id + 1]
     warmup_rng = np.random.default_rng(seed + 7919)  # uniform-warmup draws
     flat_view = np.frombuffer(shared_params, dtype=np.float32)
     flat_scratch = np.empty_like(flat_view)
@@ -147,6 +152,9 @@ def run_worker(
         # 'params-staleness per actor').
         with trace.span("actor_flush", rows=len(pending)):
             _flush_impl()
+        if nstep_counts is not None:
+            nstep_counts[2 * worker_id] = nstep.rows
+            nstep_counts[2 * worker_id + 1] = nstep.short_rows
 
     def _flush_impl():
         nonlocal carry

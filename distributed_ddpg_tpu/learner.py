@@ -55,6 +55,30 @@ METRIC_KEYS = (
 )
 
 
+def metric_keys(config: DDPGConfig) -> tuple:
+    """The exact keys of StepOutput.metrics for `config`'s family, in the
+    order the megakernel stacks them. The categorical (D4PG) branch reports
+    `c51_edge_mass` besides: the share of the projected target's mass on the
+    support's two end atoms, the number a user sets v_min / v_max by; a
+    chunk reports its last update's (chunk_metrics). Only that branch has
+    the key, so every other family's programs and records are what they
+    were."""
+    if config.distributional:  # config.py: never with twin_critic or sac
+        return METRIC_KEYS + ("c51_edge_mass",)
+    return METRIC_KEYS
+
+
+def chunk_metrics(ms: dict) -> dict:
+    """[K]-stacked per-update metrics of a scan chunk -> the chunk's: each
+    key's mean over the K updates, except `c51_edge_mass`, which is the
+    chunk's last update's, as the megakernel computes it on its last grid
+    step only. For every other family this is the tree.map it replaces."""
+    out = jax.tree.map(lambda x: jnp.mean(x), ms)
+    if "c51_edge_mass" in ms:
+        out["c51_edge_mass"] = ms["c51_edge_mass"][-1]
+    return out
+
+
 def _maybe_psum_mean(tree, axis_name: Optional[str]):
     if axis_name is None:
         return tree
@@ -157,6 +181,7 @@ def make_learner_step(
     # and f32 master params/opt state. Default f32 keeps the native-backend
     # bit-comparability oracle exact (BASELINE.json:5).
     mm = jnp.bfloat16 if config.compute_dtype == "bfloat16" else None
+    keys = metric_keys(config)
     support = (
         losses.categorical_support(config.v_min, config.v_max, config.num_atoms)
         if config.distributional
@@ -342,6 +367,9 @@ def make_learner_step(
         (closs, td), cgrads = jax.value_and_grad(critic_loss_fn, has_aux=True)(
             state.critic_params
         )
+        c51_metrics = ()
+        if config.distributional:
+            td, *c51_metrics = td  # (td, edge_mass)
         cgrads = _maybe_psum_mean(cgrads, axis_name)
 
         # --- actor update (pre-update critic: both grads from the same state) ---
@@ -446,7 +474,7 @@ def make_learner_step(
 
         metrics = dict(
             zip(
-                METRIC_KEYS,
+                keys,
                 (
                     closs,
                     aloss,
@@ -454,6 +482,7 @@ def make_learner_step(
                     jnp.mean(jnp.abs(td)),
                     optree_norm(cgrads),
                     actor_grad_norm,
+                    *c51_metrics,
                 ),
             )
         )

@@ -565,6 +565,26 @@ class MeshStats:
         }
 
 
+def nstep_counters(counts) -> Dict[str, int]:
+    """What the actors' n-step accumulators emitted (replay/nstep.py) — the
+    `nstep_*` pair on every `train` record of a run with `n_step > 1`, both
+    cumulative since the run began:
+
+      nstep_rows        rows emitted, summed over actors
+      nstep_short_rows  how many of them carry fewer than n steps: the
+                        last n - 1 windows of an episode (flushed at a
+                        termination with discount 0, at a truncation
+                        with gamma^k, k < n)
+
+    `counts` is a flat [rows, short rows] pair per actor: the pool's
+    shared array, which each worker writes at its flushes. No lock: one
+    writer a slot, and a torn read is one flush behind."""
+    return {
+        "nstep_rows": int(sum(counts[0::2])),
+        "nstep_short_rows": int(sum(counts[1::2])),
+    }
+
+
 class DevActorStats:
     """Counters for the device-actor subsystem (actors/device_pool.py;
     docs/DEVICE_ACTORS.md) — the `devactor_*` family every train/final

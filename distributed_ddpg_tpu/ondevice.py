@@ -49,9 +49,9 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from distributed_ddpg_tpu.config import DDPGConfig
 from distributed_ddpg_tpu.envs.jax_envs import make_jax_env
 from distributed_ddpg_tpu.learner import (
-    METRIC_KEYS,
     init_train_state,
     make_learner_step,
+    metric_keys,
 )
 from distributed_ddpg_tpu.ops.exploration import vector_env_step
 from distributed_ddpg_tpu.parallel import mesh as mesh_lib
@@ -109,6 +109,7 @@ class OnDeviceDDPG:
                 "uniformly forever"
             )
         self.config = config
+        keys = metric_keys(config)
         self.env = make_jax_env(config.env_id)
         self.num_envs = int(config.num_actors)
         self.chunk_size = int(chunk_size)
@@ -192,7 +193,7 @@ class OnDeviceDDPG:
                 done_returns,
             )
 
-        zero_metrics = {k: jnp.zeros((), jnp.float32) for k in METRIC_KEYS}
+        zero_metrics = {k: jnp.zeros((), jnp.float32) for k in keys}
 
         global_batch = self.global_batch
 
@@ -266,7 +267,7 @@ class OnDeviceDDPG:
         )
         self._carry_sharding = mesh_lib.to_named(self.mesh, carry_spec)
         stats_spec = ChunkStats(
-            metrics={k: P() for k in METRIC_KEYS},
+            metrics={k: P() for k in keys},
             learn_steps=P(),
             dones=P(None, env_axis),
             ep_returns=P(None, env_axis),
@@ -317,7 +318,7 @@ class OnDeviceDDPG:
                 return jax.lax.fori_loop(0, B, body, (carry, stacked))
 
             stacked_spec = ChunkStats(
-                metrics={k: P(None) for k in METRIC_KEYS},
+                metrics={k: P(None) for k in keys},
                 learn_steps=P(None),
                 dones=P(None, None, env_axis),
                 ep_returns=P(None, None, env_axis),

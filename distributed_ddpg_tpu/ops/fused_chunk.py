@@ -160,9 +160,18 @@ def state_vmem_bytes(config: DDPGConfig, obs_dim: int, act_dim: int) -> int:
 # What has met the compiler (TPU v5e, PR 21): at 2x256, obs 17 / act 6,
 # chunk 800, every family — DDPG 2.2 MiB of state, C51 2.4, TD3 3.3, SAC
 # 3.3, and bf16 — compiles under Mosaic's default scoped-VMEM limit with no
-# vmem_limit_bytes. Nothing between 3.3 MiB and this budget has been
-# compiled; if such a net is refused, derive the limit from
-# state_vmem_bytes() rather than lowering the budget by guesswork.
+# vmem_limit_bytes. PR 27: C51 at batch 256 x 51 atoms (the batch's blocks
+# 256 x 43 floats a step) compiles and runs through train() under the same
+# default limit, no vmem_limit_bytes, at 2x256 (2,511,760 B of state, 2.4
+# MiB: 10.4 ms of kernel for 800 updates) and at 400-300, widths that are
+# no multiple of 128 lanes (4,383,312 B, 4.2 MiB: 14.0 ms). That last one
+# takes 14.58 MiB of the default limit's 16 (3.5 x state: in and out blocks
+# of every tensor, double-buffered); the same kernel with the edge mass
+# computed on every grid step takes 16.12 and is REFUSED (compiled for a
+# described v5e, tests/test_ring_layout.py keeps the guard). So this budget
+# is not reachable under the default limit: past about 4.4 MiB of state a
+# net needs vmem_limit_bytes, derived from state_vmem_bytes() (17 MiB lets
+# the 16.12 through), rather than a budget lowered by guesswork.
 VMEM_STATE_BUDGET = 6 * 1024 * 1024
 
 
@@ -413,7 +422,7 @@ def _make_kernel(
 
         def emit(td, step_metrics):
             """Write the per-step TD block and accumulate the chunk-MEAN
-            metrics into the revisited (1, len(METRIC_KEYS)) block — see
+            metrics into the revisited (1, len(metric_keys)) block — see
             the layout rationale in the DDPG tail below."""
             td_out[0] = td
             assert len(step_metrics) == met_out.shape[-1]
@@ -646,6 +655,18 @@ def _make_kernel(
             # PER proxy (losses.py docstring): E[Z_target] - E[Z].
             mean_q_b = jnp.sum(p_q * z, axis=-1, keepdims=True)
             td = jnp.sum(proj * z, axis=-1, keepdims=True) - mean_q_b
+            # c51_edge_mass (learner.metric_keys): the projected
+            # target's mass on the support's two end atoms, batch mean, of
+            # the launch's LAST update only (learner.chunk_metrics): the
+            # other grid steps pay a compare for it, not two reductions.
+            # emit() scales every slot by 1/K, hence the K here.
+            edge_mass = jax.lax.cond(
+                k == chunk - 1,
+                lambda: (
+                    jnp.sum(proj[:, :1]) + jnp.sum(proj[:, num_atoms - 1 :])
+                ) * (inv_b * float(chunk)),
+                lambda: jnp.float32(0.0),
+            )
             # d(mean(w * ce))/dlogits = w/B * (softmax(logits) - proj)
             dq = (p_q - proj) * (wgt * inv_b)
         elif not twin:
@@ -735,8 +756,9 @@ def _make_kernel(
                   count_ref[0])
 
         # ---- outputs -----------------------------------------------------
-        # Order must match learner.METRIC_KEYS; the wrapper sizes the metric
-        # block from len(METRIC_KEYS) and emit() asserts this stack agrees.
+        # Order must match learner.metric_keys(config); the wrapper sizes
+        # the metric block from its length and emit() asserts this stack
+        # agrees (6 scalars, 7 on the C51 branch).
         # The chunk MEAN is accumulated in-kernel into a (1, 6) output whose
         # block IS the whole array (constant index map) — a per-step (K, 6)
         # output would need a (1, 6) block over K rows, which violates
@@ -759,7 +781,8 @@ def _make_kernel(
                 jnp.sum(jnp.abs(td)) * inv_b,
                 jnp.sqrt(_sq(c_grads)),
                 a_norm,
-            ],
+            ]
+            + ([edge_mass] if distributional else []),
         )
 
     return kernel
@@ -889,7 +912,9 @@ def make_fused_chunk_fn(
     else:
         tgt_h = None
 
-    from distributed_ddpg_tpu.learner import METRIC_KEYS
+    from distributed_ddpg_tpu.learner import metric_keys
+
+    keys = metric_keys(config)
 
     def run(state: TrainState, batches, eps=None):
         n_actor = len(state.actor_params)
@@ -971,7 +996,7 @@ def make_fused_chunk_fn(
                 # (constant index map, accumulated across grid steps in the
                 # kernel) — Mosaic-legal, unlike a (1, 6) block over (K, 6).
                 pl.BlockSpec(
-                    (1, len(METRIC_KEYS)), lambda k: (0, 0),
+                    (1, len(keys)), lambda k: (0, 0),
                     memory_space=pltpu.VMEM,
                 ),
             ]
@@ -980,7 +1005,7 @@ def make_fused_chunk_fn(
         out_shape = (
             [
                 jax.ShapeDtypeStruct((K, B, 1), jnp.float32),
-                jax.ShapeDtypeStruct((1, len(METRIC_KEYS)), jnp.float32),
+                jax.ShapeDtypeStruct((1, len(keys)), jnp.float32),
             ]
             + [jax.ShapeDtypeStruct(x.shape, x.dtype) for x in state_flat]
         )
@@ -1055,7 +1080,7 @@ def make_fused_chunk_fn(
             log_alpha=new_log_alpha,
             alpha_opt=new_alpha_opt,
         )
-        metrics = {k_: met[j] for j, k_ in enumerate(METRIC_KEYS)}
+        metrics = {k_: met[j] for j, k_ in enumerate(keys)}
         return new_state, td, metrics
 
     return run

@@ -330,8 +330,11 @@ def distributional_critic_loss(
 ):
     """Categorical TD loss (cross-entropy vs projected target distribution).
 
-    Returns (loss, td_error_proxy[B]) where the proxy is |E[Z] - E[Z_target]|
-    (used for PER priorities, as in D4PG follow-ups)."""
+    Returns (loss, (td_error_proxy[B], edge_mass)): the proxy is the signed
+    E[Z_target] - E[Z] (PER priorities, as in D4PG follow-ups); edge_mass is
+    the batch-mean share of the projected target's mass on the support's two
+    end atoms (the `c51_edge_mass` metric: how much of the return
+    distribution v_min / v_max clip)."""
     next_action = actor_apply(
         target_actor_params, batch.next_obs, action_scale, action_offset, mm_dtype
     )
@@ -350,7 +353,8 @@ def distributional_critic_loss(
     loss = jnp.mean(batch.weight * ce)
     mean_q = jnp.sum(jax.nn.softmax(logits, axis=-1) * support[None, :], axis=-1)
     mean_target = jnp.sum(proj * support[None, :], axis=-1)
-    return loss, mean_target - mean_q
+    edge_mass = jnp.mean(proj[:, 0] + proj[:, -1])
+    return loss, (mean_target - mean_q, edge_mass)
 
 
 def distributional_actor_loss(

@@ -34,10 +34,11 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from distributed_ddpg_tpu import trace
 from distributed_ddpg_tpu.config import DDPGConfig
 from distributed_ddpg_tpu.learner import (
-    METRIC_KEYS,
     StepOutput,
+    chunk_metrics,
     init_train_state,
     make_learner_step,
+    metric_keys,
 )
 from distributed_ddpg_tpu.parallel import mesh as mesh_lib
 from distributed_ddpg_tpu.types import (
@@ -217,6 +218,7 @@ class ShardedLearner:
         action_scale = self._action_scale
         action_offset = self._action_offset
         state = self.state
+        keys = metric_keys(config)
 
         if mode == "auto":
             step = make_learner_step(config, action_scale, action_offset=action_offset)
@@ -235,7 +237,7 @@ class ShardedLearner:
                     out_specs=StepOutput(
                         state=state_spec,
                         td_errors=P("data"),
-                        metrics={k: P() for k in METRIC_KEYS},
+                        metrics={k: P() for k in keys},
                     ),
                 )(s, b)
 
@@ -251,13 +253,13 @@ class ShardedLearner:
             out_shardings=StepOutput(
                 state=self._state_sharding,
                 td_errors=td_sharding,
-                metrics={k: replicated for k in METRIC_KEYS},
+                metrics={k: replicated for k in keys},
             ),
             donate_argnums=(0,),
         )
 
         # Shared scan body: one step over a [K, B, ...] Batch pytree, metrics
-        # averaged over the chunk (used by both the host-fed and the
+        # reduced over the chunk (used by both the host-fed and the
         # fused-sampling chunk paths).
         def scan_steps(s: TrainState, batches: Batch) -> StepOutput:
             def body(carry, b):
@@ -268,7 +270,7 @@ class ShardedLearner:
             return StepOutput(
                 state=s,
                 td_errors=tds,
-                metrics=jax.tree.map(lambda x: jnp.mean(x), ms),
+                metrics=chunk_metrics(ms),
             )
 
         # K-steps-per-dispatch scan over host-fed packed batches.
@@ -282,7 +284,7 @@ class ShardedLearner:
             out_shardings=StepOutput(
                 state=self._state_sharding,
                 td_errors=td_chunk_sharding,
-                metrics={k: replicated for k in METRIC_KEYS},
+                metrics={k: replicated for k in keys},
             ),
             donate_argnums=(0,),
         )
@@ -501,7 +503,7 @@ class ShardedLearner:
                     StepOutput(
                         state=self._state_sharding,
                         td_errors=NamedSharding(self.mesh, P(None, "data")),
-                        metrics={k: replicated for k in METRIC_KEYS},
+                        metrics={k: replicated for k in keys},
                     ),
                     replicated,
                     prio_sharding,
@@ -554,7 +556,7 @@ class ShardedLearner:
                     StepOutput(
                         state=self._state_sharding,
                         td_errors=td_chunk_sharding,
-                        metrics={k: replicated for k in METRIC_KEYS},
+                        metrics={k: replicated for k in keys},
                     ),
                     replicated,
                 ),
@@ -595,7 +597,7 @@ class ShardedLearner:
                 return StepOutput(
                     state=s,
                     td_errors=tds,
-                    metrics=jax.tree.map(lambda x: jnp.mean(x), ms),
+                    metrics=chunk_metrics(ms),
                 ), g
 
             def guard_chunk_fn(s: TrainState, packed, g):
@@ -619,7 +621,7 @@ class ShardedLearner:
                     StepOutput(
                         state=self._state_sharding,
                         td_errors=td_chunk_sharding,
-                        metrics={k: replicated for k in METRIC_KEYS},
+                        metrics={k: replicated for k in keys},
                     ),
                     replicated,
                     replicated,
@@ -646,7 +648,7 @@ class ShardedLearner:
                 StepOutput(
                     state=self._state_sharding,
                     td_errors=td_chunk_sharding,
-                    metrics={k: replicated for k in METRIC_KEYS},
+                    metrics={k: replicated for k in keys},
                 ),
                 replicated,  # key
                 replicated,  # guard state
@@ -710,7 +712,7 @@ class ShardedLearner:
                     StepOutput(
                         state=self._state_sharding,
                         td_errors=NamedSharding(self.mesh, P(None, "data")),
-                        metrics={k: replicated for k in METRIC_KEYS},
+                        metrics={k: replicated for k in keys},
                     ),
                     replicated,
                     prio_sharding,
@@ -766,6 +768,7 @@ class ShardedLearner:
         )
         mesh = self.mesh
         state_spec = mesh_lib.state_pspec(self.state, mesh)
+        keys = metric_keys(self.config)
 
         twin_noise = self.config.twin_critic and self.config.target_noise > 0
         sac = self.config.sac
@@ -832,7 +835,7 @@ class ShardedLearner:
             out_specs=(
                 state_spec,
                 P(None, "data"),
-                {k: P() for k in METRIC_KEYS},
+                {k: P() for k in keys},
             ),
         )
 

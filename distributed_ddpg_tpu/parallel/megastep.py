@@ -72,7 +72,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from distributed_ddpg_tpu import trace
 from distributed_ddpg_tpu.config import DDPGConfig
-from distributed_ddpg_tpu.learner import METRIC_KEYS, StepOutput
+from distributed_ddpg_tpu.learner import StepOutput, metric_keys
 from distributed_ddpg_tpu.metrics import FusedBeatStats
 
 
@@ -105,7 +105,7 @@ def build_beat_body(learner, pool, replay, per: bool, guard: bool,
     out_step = StepOutput(
         state=L._state_sharding,
         td_errors=NamedSharding(mesh, P(None, "data")),
-        metrics={k: replicated for k in METRIC_KEYS},
+        metrics={k: replicated for k in metric_keys(L.config)},
     )
 
     # The beat bodies below are the loop iteration verbatim: learn on

@@ -25,6 +25,11 @@ class NStepAccumulator:
         self.num_envs = int(num_envs)
         # Per-env deque of (obs, action, reward) awaiting their bootstrap.
         self._pending = [deque() for _ in range(self.num_envs)]
+        # Rows emitted since construction, and how many of them carry fewer
+        # than n steps (episode ends, truncation flushes): the actors'
+        # `nstep_rows` / `nstep_short_rows` counters. reset() keeps them.
+        self.rows = 0
+        self.short_rows = 0
 
     def push(
         self, obs, action, reward, done, next_obs
@@ -49,6 +54,8 @@ class NStepAccumulator:
                     pend.popleft()
 
     def _emit(self, pend, bootstrap_obs, terminal: bool, length: int):
+        self.rows += 1
+        self.short_rows += length < self.n
         r = 0.0
         for k in range(length):
             r += (self.gamma ** k) * pend[k][2]

@@ -158,6 +158,9 @@ KEY_METRICS = (
     "staleness_mean",
     "critic_loss",
     "mean_q",
+    # Categorical (D4PG) runs only: the projected target's mass on the
+    # support's two end atoms (how much v_min / v_max clip).
+    "c51_edge_mass",
 )
 
 # Cumulative recovery counters (train.py recovery_fields; docs/RESILIENCE.md)
@@ -416,6 +419,21 @@ def summarize_run(path: str) -> Dict[str, Any]:
             mesh[key] = {"last": vals[-1]}
     digest["mesh"] = mesh
 
+    # n-step rows digest (metrics.nstep_counters; n_step > 1 runs only): both
+    # counters are cumulative, so the last record holds the totals; the
+    # short share is what the actors' episode ends cost in n-step rows.
+    nstep = {}
+    for key in ("nstep_rows", "nstep_short_rows"):
+        vals = _col(train + final, key)
+        if vals:
+            nstep[key] = {"last": vals[-1]}
+    if nstep.get("nstep_rows", {}).get("last"):
+        nstep["nstep_short_share"] = {
+            "last": nstep["nstep_short_rows"]["last"]
+            / nstep["nstep_rows"]["last"]
+        }
+    digest["nstep"] = nstep
+
     # Replay-placement digest (replay/device.py ReplayShardStats;
     # docs/REPLAY_SHARDING.md): measured ingest bytes/row, per-device
     # storage bytes, per-shard fill, exchange-dispatch tails.
@@ -573,6 +591,12 @@ def render_summary(digest: Dict[str, Any]) -> str:
         out.append(render_table(
             ["field", "value"],
             [[k, v["last"]] for k, v in digest["mesh"].items()],
+        ))
+    if digest.get("nstep"):
+        out.append("\n-- n-step rows (actors)")
+        out.append(render_table(
+            ["field", "value"],
+            [[k, v["last"]] for k, v in digest["nstep"].items()],
         ))
     if digest.get("replay_sharding"):
         out.append("\n-- replay placement (docs/REPLAY_SHARDING.md)")

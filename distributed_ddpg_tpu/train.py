@@ -2185,6 +2185,7 @@ def _train_jax_impl(
                 buffer_fill=buffer_fill(),
                 episode_return=mean_ret,
                 **pool.staleness(),
+                **pool.nstep_counters(),
                 **recovery_fields(),
                 **chunk_metrics,
                 **support_metrics,

@@ -14,7 +14,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from distributed_ddpg_tpu.learner import init_train_state, make_learner_step
+from distributed_ddpg_tpu.learner import (
+    chunk_metrics,
+    init_train_state,
+    make_learner_step,
+)
 from distributed_ddpg_tpu.ops import fused_chunk
 from distributed_ddpg_tpu.types import pack_batch_np, unpack_batch
 
@@ -45,7 +49,7 @@ def assert_fused_matches_scan(
     metric_rtol: float | None = None,
 ):
     """Run the megakernel chunk and K sequential scan-path steps on the same
-    batches; assert end state, TD errors, and chunk-mean metrics agree.
+    batches; assert end state, TD errors, and the chunk's metrics agree.
     Returns the kernel's metrics dict."""
     state = init_train_state(cfg, obs, act, seed=cfg.seed)
     packed = make_packed_batches(
@@ -98,9 +102,13 @@ def assert_fused_matches_scan(
         np.asarray(td), np.stack(ref_tds), rtol=rtol, atol=atol
     )
     m_rtol = metric_rtol if metric_rtol is not None else rtol
+    # the chunk's reduction of its K updates' metrics, as the scan chunk
+    # makes it (mean; `c51_edge_mass` is the last update's)
+    want = chunk_metrics(
+        {k: jnp.stack([m[k] for m in ref_ms]) for k in ref_ms[0]}
+    )
     for name in metrics:
-        want = float(np.mean([float(m[name]) for m in ref_ms]))
         np.testing.assert_allclose(
-            float(metrics[name]), want, rtol=m_rtol, atol=atol
+            float(metrics[name]), float(want[name]), rtol=m_rtol, atol=atol
         )
     return metrics

@@ -168,3 +168,89 @@ def test_pool_streams_transitions_and_respawns(transport):
         assert pool.episode_stats() is not None
     finally:
         pool.stop()
+
+
+def test_nstep_counters_sum_over_actors_and_are_absent_at_one_step():
+    """`nstep_rows` / `nstep_short_rows` (metrics.nstep_counters) through a
+    pool: two inline actors on Pendulum (never terminates, truncated at 200
+    steps) with n = 3 emit one row per env step, and the last n - 1 = 2
+    windows of every finished episode are short; at n = 1 a pool reports
+    neither key. The process pool shares the accumulator, the counts' layout
+    and the function that sums them; its workers write a shared array at each
+    flush."""
+    import jax
+
+    from distributed_ddpg_tpu.actors.sync_pool import SyncActorPool
+    from distributed_ddpg_tpu.metrics import nstep_counters
+
+    cfg, spec, state = _setup(num_actors=2, n_step=3)
+    pool = SyncActorPool(cfg, spec).start(jax.device_get(state.actor_params))
+    try:
+        rows = sum(len(b["reward"]) for b in pool.drain_batches(max_rows=2 * 450))
+        got = pool.nstep_counters()
+    finally:
+        pool.stop()
+    # each actor took 450 steps: two whole episodes (200 rows each, 2 of them
+    # short) and 50 steps of a third, whose last 2 windows are still pending
+    assert got == {"nstep_rows": rows, "nstep_short_rows": 8}
+    assert rows == 2 * (450 - 2)
+
+    cfg1, spec1, _ = _setup(num_actors=2)
+    assert SyncActorPool(cfg1, spec1).nstep_counters() == {}
+    assert ActorPool(cfg1, spec1).nstep_counters() == {}
+    counted = ActorPool(cfg, spec)
+    assert counted.nstep_counters() == {"nstep_rows": 0, "nstep_short_rows": 0}
+    assert nstep_counters([5, 1, 7, 2]) == {"nstep_rows": 12, "nstep_short_rows": 3}
+
+
+def test_pool_rows_are_the_five_step_fold_of_the_raw_env_steps():
+    """What reaches the ring against what the envs gave: two inline actors
+    with n = 5 step Pendulum (truncated at 200 steps; one step of actor 0 is
+    made to terminate), every raw env step is logged at the env's own
+    `step`, and every row the pool delivers is recomputed from that log:
+    R = sum_k gamma^k r_{t+k} over the steps the window holds, d = gamma^steps
+    (0 where the window ends in the termination), next_obs = the observation
+    `steps` on, never one from across a reset. The benchmark's on-chip check
+    starts from rows the actors already folded, so this is where the fold
+    itself is held."""
+    import jax
+
+    from distributed_ddpg_tpu.actors.sync_pool import SyncActorPool
+
+    n, die_at = 5, 137
+    cfg, spec, state = _setup(num_actors=2, n_step=n)
+    pool = SyncActorPool(cfg, spec).start(jax.device_get(state.actor_params))
+    logs = []
+    for i, actor in enumerate(pool._actors):
+        log, raw_step = [], actor.env.step
+
+        def step(action, actor=actor, log=log, raw_step=raw_step, dies=i == 0):
+            obs = actor.obs
+            next_obs, reward, terminated, truncated, info = raw_step(action)
+            terminated = terminated or (dies and len(log) == die_at)
+            log.append((obs, float(reward), bool(terminated), bool(terminated or truncated), next_obs))
+            return next_obs, reward, terminated, truncated, info
+
+        actor.env.step = step
+        logs.append(log)
+    try:
+        batch, = pool.drain_batches(max_rows=2 * 450)
+    finally:
+        pool.stop()
+    first = {}  # raw obs -> (log, index): Pendulum's float observations do not repeat
+    for log in logs:
+        for t, (obs, *_rest) in enumerate(log):
+            first[obs.tobytes()] = (log, t)
+    g, short, ended_dead = cfg.gamma, 0, 0
+    for obs, ret, disc, nobs in zip(batch["obs"], batch["reward"], batch["discount"], batch["next_obs"]):
+        log, t = first[obs.tobytes()]
+        steps = next(k + 1 for k in range(n) if k + 1 == n or log[t + k][3])
+        dead = log[t + steps - 1][2]
+        assert ret == pytest.approx(sum(g**k * log[t + k][1] for k in range(steps)), rel=1e-5, abs=1e-6)
+        assert disc == pytest.approx(0.0 if dead else g**steps, rel=1e-6)
+        np.testing.assert_array_equal(nobs, log[t + steps - 1][4])
+        short += steps < n
+        ended_dead += dead
+    # actor 0: 138 steps to its death, then 200 and 112 more; actor 1: 200, 200, 50.
+    # Every finished episode's last n - 1 windows are short, the death's n windows carry d = 0
+    assert len(batch["reward"]) == 2 * 450 - 2 * (n - 1) and short == 4 * (n - 1) and ended_dead == n

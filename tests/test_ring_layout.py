@@ -250,3 +250,60 @@ def test_v5e_default_layout_is_what_the_rule_replaces(v5e_sharding):
     shape = (CAPACITY, 772)
     assert len(ring_sized_copies(inserted.as_text(), shape)) == 2
     assert len(ring_sized_copies(gathered.as_text(), shape)) == 1
+
+
+# --- the megakernel's chunk, compiled for the same described v5e (this is
+# the one file that describes the topology, so the guard lives here): a
+# configuration's net either fits Mosaic's default scoped-VMEM limit at its
+# committed widths and batch, or a later PR learns so here and not on the
+# chip. ---
+
+MIB = 1024 * 1024
+
+
+@pytest.mark.parametrize(
+    "name,limit_mib",
+    [
+        ("ddpg-halfcheetah", None),
+        ("d4pg-halfcheetah", None),  # the program as train() builds it: Mosaic's default, 16 MiB
+        # 400-300 at batch 256 x 51 atoms takes 14.58 MiB of scoped VMEM (3.5
+        # times state_vmem_bytes); without the last-grid-step cond around the
+        # edge mass the same kernel takes 16.12 MiB and is refused. A MiB of
+        # room is kept.
+        ("d4pg-halfcheetah", 15),
+    ],
+)
+def test_v5e_megakernel_chunk_fits_scoped_vmem(v5e_sharding, monkeypatch, name, limit_mib):
+    import json
+    import os
+
+    from jax.experimental.pallas import tpu as pltpu
+
+    from distributed_ddpg_tpu.config import DDPGConfig
+    from distributed_ddpg_tpu.learner import init_train_state
+    from distributed_ddpg_tpu.ops import fused_chunk
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    conf = json.load(open(os.path.join(root, "benchmarks", "configs", name + ".json")))
+    cfg = DDPGConfig.from_flags([f for f in conf["flags"] if not f.startswith("--replay_capacity")])
+    env, chunk = conf["env"], 800
+    assert fused_chunk.supported(cfg) and fused_chunk.fits_vmem(cfg, env["obs_dim"], env["act_dim"])
+    if limit_mib is not None:
+        real = fused_chunk.pl.pallas_call
+        monkeypatch.setattr(
+            fused_chunk.pl, "pallas_call",
+            lambda *a, **kw: real(*a, compiler_params=pltpu.CompilerParams(vmem_limit_bytes=limit_mib * MIB), **kw),
+        )
+    run = fused_chunk.make_fused_chunk_fn(
+        cfg, env["obs_dim"], env["act_dim"], env["action_scale"], env["action_offset"],
+        chunk_size=chunk, interpret=False,
+    )
+    replicated = NamedSharding(v5e_sharding.mesh, P())
+    state = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=replicated),
+        jax.eval_shape(lambda: init_train_state(cfg, env["obs_dim"], env["act_dim"], 0)),
+    )
+    width = 2 * env["obs_dim"] + env["act_dim"] + 3
+    batches = jax.ShapeDtypeStruct((chunk, cfg.batch_size, width), jnp.float32, sharding=replicated)
+    compiled = jax.jit(run).lower(state, batches).compile()  # raises what the chip's compiler would
+    assert "tpu_custom_call" in compiled.as_text()

@@ -75,6 +75,33 @@ def test_summarize_digest_and_render(tmp_path):
     assert runs.summarize_run(str(noisy))["records"]["train"] == 8
 
 
+def test_summarize_nstep_rows_and_edge_mass(tmp_path):
+    """A categorical n-step run's records: the cumulative `nstep_*` pair
+    (metrics.nstep_counters) gets its own section with the short share, and
+    `c51_edge_mass` sits among the headline metrics; a run without the
+    keys (every other family) gets neither."""
+    path = tmp_path / "d4pg.jsonl"
+    records = _fixture_run(path)
+    for i, r in enumerate(r for r in records if r["kind"] == "train"):
+        r.update(nstep_rows=1000 * (i + 1), nstep_short_rows=4 * (i + 1),
+                 c51_edge_mass=0.002 * (i + 1))
+    _write_jsonl(path, records)
+    digest = runs.summarize_run(str(path))
+    assert digest["nstep"]["nstep_rows"]["last"] == 8000
+    assert digest["nstep"]["nstep_short_rows"]["last"] == 32
+    assert digest["nstep"]["nstep_short_share"]["last"] == pytest.approx(0.004)
+    assert digest["metrics"]["c51_edge_mass"]["last"] == pytest.approx(0.016)
+    text = runs.render_summary(digest)
+    assert "n-step rows" in text and "nstep_short_share" in text
+    assert "c51_edge_mass" in text
+
+    plain = tmp_path / "plain.jsonl"
+    _fixture_run(plain)
+    digest = runs.summarize_run(str(plain))
+    assert digest["nstep"] == {} and "c51_edge_mass" not in digest["metrics"]
+    assert "n-step rows" not in runs.render_summary(digest)
+
+
 def test_summarize_recovery_counters(tmp_path, capsys):
     """Fault history (docs/RESILIENCE.md): the cumulative recovery
     counters train.py logs must surface in the digest and the rendered
