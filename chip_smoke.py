@@ -12,7 +12,11 @@ them, the kernel is checked against the scan step on one small chunk
 (tests/fused_parity_util.py, the body tests/test_tpu.py runs). After them,
 the replay ring at the scan leg's benchmark size (SAC, Humanoid-v4 width,
 1.4e6 rows): the two programs that take it must hold no ring-sized copy
-(tests/ring_layout_util.py, the body tests/test_ring_layout.py runs).
+(tests/ring_layout_util.py, the body tests/test_ring_layout.py runs). And a
+HalfCheetah-wide ring of the papers' 1e6 rows, packed two rows to a
+128-lane line, filled through the ingest path past one wrap: every row
+must read back identical to the host's copy (the body
+tests/test_packed_ring.py runs), with no ring-sized copy in its programs.
 
 Exit 0 only if every check held. Stdout then ends with two JSON lines: the
 facts of the run (`{"facts": {...}}`: versions, legs, compile cache, ...)
@@ -60,6 +64,7 @@ LEGS = (("default", []), ("scan", ["--fused_chunk=off"]))
 OBS, ACT = 17, 6  # HalfCheetah-v4
 INGEST_BLOCK = 1024  # train_jax's DeviceReplay block: one padded flush at most
 RING_ROWS = 1_400_000  # the sac-humanoid cell's ring
+PACKED_RING_ROWS = 1_000_000  # the papers' ring, at HalfCheetah's 43 floats a row
 
 
 class SmokeFailure(Exception):
@@ -134,6 +139,56 @@ def run_ring_layout():
         "row_bytes_device": snap["replay_row_bytes_device"],
         "programs": sorted(copies),
         "chunk": chunk,
+    }
+
+
+def run_packed_ring():
+    """The HalfCheetah-wide ring as its owner holds it, two rows to a line:
+    1.25 rings' worth of seeded rows through the ingest path (staging ring,
+    super-blocks of 1 to 8 blocks, the donated whole-line insert), then
+    every row against the host's copy, through `device_state()[0][idx]`
+    inside a jit and outside; and no `copy` or `transpose` of the ring's
+    size in the insert or in a read of one chunk's rows."""
+    import jax
+    import numpy as np
+    from ring_layout_util import assert_reads_back, fill_past_a_wrap, ring_sized_copies
+
+    from distributed_ddpg_tpu.parallel.mesh import make_mesh
+    from distributed_ddpg_tpu.replay.device import DeviceReplay, PackedRing
+
+    replay = DeviceReplay(
+        PACKED_RING_ROWS, OBS, ACT, mesh=make_mesh(-1, 1), block_size=INGEST_BLOCK
+    )
+    storage = replay.storage
+    check(isinstance(storage, PackedRing), f"packed ring: held as {type(storage).__name__}")
+    lines = storage.lines.shape
+    block = np.zeros((INGEST_BLOCK, replay.width), np.float32)
+    idx = np.zeros((800, 64), np.int32)
+    programs = {
+        "jit_ring_insert": replay._insert.lower(storage, block, replay.ptr, replay.size),
+        "read": jax.jit(lambda s, i: s[i]).lower(storage, idx),
+    }
+    for name, lowered in programs.items():
+        found = ring_sized_copies(lowered.compile().as_text(), lines)
+        check(not found, f"packed ring: {name} copies the whole ring: {found}")
+    n_rows = 1221 * INGEST_BLOCK  # 1.25 rings
+    want = fill_past_a_wrap(replay, n_rows, 7 * INGEST_BLOCK)
+    try:
+        assert_reads_back(replay, want, n_idx=51_200)
+    except AssertionError as e:
+        raise SmokeFailure(f"packed ring: a row read back differs from the host's: {e}")
+    snap = replay.ingest_snapshot()
+    check(
+        snap["replay_ring_layout"] == "packed" and snap["replay_row_bytes_device"] == 256,
+        f"packed ring: {snap['replay_ring_layout']}, {snap['replay_row_bytes_device']} B a row",
+    )
+    return {
+        "lines": list(lines),
+        "format": str(replay.storage.lines.format.layout),
+        "layout": snap["replay_ring_layout"],
+        "row_bytes_device": snap["replay_row_bytes_device"],
+        "rows_ingested": n_rows,
+        "coalesce_mean": snap["ingest_coalesce_mean"],
     }
 
 
@@ -257,6 +312,7 @@ def run_checks(device, cache_dir):
         "the kernel did not lower to a Mosaic custom call",
     )
     facts["ring"] = run_ring_layout()
+    facts["packed_ring"] = run_packed_ring()
     facts["compile_cache"]["entries_after"] = cache_entries(cache_dir)
     left = mp.active_children()
     for p in left:

@@ -16,7 +16,10 @@ packed [capacity, D] ring in device memory:
     sample_chunk path) — jax.random indices + gather per scan step, so a
     K-step chunk needs ZERO transfers in and only td/metrics out.
 
-ptr/size/PRNG key live on device; nothing round-trips.
+ptr/size/PRNG key live on device; nothing round-trips. How the ring lies
+in HBM follows from its row's width (ring_layout below): narrow rows share
+128-lane lines (PackedRing), Humanoid-wide ones are held row-major, and
+either way `storage.shape` and `storage[idx]` speak of logical rows.
 
 Ingest pipeline (docs/INGEST.md): pending actor rows stage in a
 preallocated host ring (replay/staging.py — one memcpy per push, killing
@@ -95,47 +98,191 @@ from distributed_ddpg_tpu.types import packed_width
 # The TPU tiles a 2-D f32 array 8 sublanes x 128 lanes over its two minor
 # dimensions. For f32[capacity, width] the runtime's own choice puts the
 # ROWS minor (XLA `{0,1:T(8,128)}`: feature-major, width padded to 8), which
-# wastes nothing but leaves a row as `width` strided words. At 64 floats a
-# row and more, XLA will neither gather from nor scatter into that: it
-# transposes the whole ring to row-major first, once per sampling launch and
-# twice per insert, 14 ms each on a 4.35 GB ring (PERF.md PR 26). Holding the
-# ring row-major (`{1,0:T(8,128)}`, width padded to 128 lanes) ends the
-# copies at the price of the padding, so the width decides: row-major where
-# it costs at most ROW_MAJOR_MAX_PAD times the compact row.
+# wastes nothing but leaves a row as `width` strided words: a gather from it
+# is built per element, 55 ns a 43-float row, and at 64 floats a row and more
+# XLA will neither gather from nor scatter into it at all: it transposes the
+# whole ring to row-major first, once per sampling launch and twice per
+# insert, 14 ms each on a 4.35 GB ring (PERF.md PRs 26 and 28). So a row is
+# held contiguous, one of two ways, and the width decides which:
+#   packed    — at most PACKED_MAX_WIDTH floats: G = 128 // width rows share
+#               one 128-lane line of an f32[ceil(capacity / G), 128] array
+#               (PackedRing), whose default layout is already row-major.
+#   row_major — wider, and the 128-lane padding costs at most
+#               ROW_MAJOR_MAX_PAD times the compact row: the [capacity,
+#               width] array in the layout `{1,0:T(8,128)}` (ring_format).
+#   compact   — everything else, the runtime's own layout: widths in
+#               between, and every row-sharded narrow ring.
 _SUBLANES, _LANES = 8, 128
 ROW_MAJOR_MAX_PAD = 1.25
+PACKED_MAX_WIDTH = _LANES // 2
 
 
 def _ceil_to(n: int, multiple: int) -> int:
     return -(-n // multiple) * multiple
 
 
-def ring_layout(width: int) -> str:
-    """'row_major' or 'compact' (the runtime's own layout) for a ring of
-    `width` floats a row on the TPU: row-major where the 128-lane padding
-    stays within ROW_MAJOR_MAX_PAD of the compact row (Humanoid's 772 ->
-    896 is in; HalfCheetah's 43 -> 128 would be x2.67 and is out)."""
+def ring_layout(width: int, sharded: bool = False) -> str:
+    """'packed', 'row_major' or 'compact' for a ring of `width` floats a
+    row. Packed where two rows or more fit a 128-lane line and the ring is
+    not row-sharded (HalfCheetah's 43: two to a line, 256 B a row where
+    compact holds 192), on every platform. Row-major, on the TPU alone,
+    where the 128-lane padding stays within ROW_MAJOR_MAX_PAD of the
+    compact row (Humanoid's 772 -> 896 is in; Ant's 65 -> 128 is out)."""
+    if width <= PACKED_MAX_WIDTH and not sharded:
+        return "packed"
     if _ceil_to(width, _LANES) <= ROW_MAJOR_MAX_PAD * _ceil_to(width, _SUBLANES):
         return "row_major"
     return "compact"
 
 
+def _packing(width: int):
+    """(G, stride) of a packed ring: rows to a 128-lane line, and the lanes
+    from one row's start to the next."""
+    g = _LANES // width
+    return g, _LANES // g
+
+
 def ring_row_bytes(width: int, layout: str) -> int:
-    """Bytes one ring row holds in HBM under `layout`, padding included."""
+    """Bytes one ring row holds in HBM under `layout`, padding included
+    (packed: a line's 512 over its rows, rounded down)."""
+    if layout == "packed":
+        return 4 * _LANES // _packing(width)[0]
     return 4 * _ceil_to(width, _LANES if layout == "row_major" else _SUBLANES)
+
+
+@jax.tree_util.register_pytree_node_class
+class PackedRing:
+    """A ring of `capacity` logical rows of `width` <= 64 floats, held as
+    f32[ceil(capacity / G), 128] lines with G = 128 // width rows to a line:
+    row r lies at lanes (r % G) * stride .. + width of line r // G, stride =
+    128 // G. One leaf (the lines) and static (width, capacity), so it
+    passes through jit, donation, shard_map and device_put as the array it
+    stands for, answers `.shape` and `ring[idx]` with logical rows, and a
+    program that says `storage[idx]` takes it or a plain [capacity, width]
+    array alike. `np.asarray(ring)` is the logical rows."""
+
+    def __init__(self, lines, width: int, capacity: int):
+        self.lines, self.width, self.capacity = lines, int(width), int(capacity)
+
+    def tree_flatten(self):
+        return (self.lines,), (self.width, self.capacity)
+
+    @classmethod
+    def tree_unflatten(cls, aux, leaves):
+        return cls(leaves[0], *aux)
+
+    @staticmethod
+    def n_lines(width: int, capacity: int) -> int:
+        return -(-capacity // _packing(width)[0])
+
+    @classmethod
+    def from_rows(cls, rows: np.ndarray) -> "PackedRing":
+        """Host rows [capacity, width] as host lines."""
+        capacity, width = rows.shape
+        (g, stride), n = _packing(width), cls.n_lines(width, capacity)
+        slots = np.zeros((n * g, stride), np.float32)
+        slots[:capacity, :width] = rows
+        lines = np.zeros((n, _LANES), np.float32)
+        lines[:, : g * stride] = slots.reshape(n, g * stride)
+        return cls(lines, width, capacity)
+
+    def __array__(self, dtype=None, copy=None):
+        g, stride = _packing(self.width)
+        slots = np.asarray(self.lines)[:, : g * stride].reshape(-1, stride)
+        return np.ascontiguousarray(
+            slots[: self.capacity, : self.width], dtype=dtype
+        )
+
+    @property
+    def shape(self):
+        return self.capacity, self.width
+
+    @property
+    def sharding(self):
+        return self.lines.sharding
+
+    def devices(self):
+        return self.lines.devices()
+
+    def __getitem__(self, key):
+        """Logical rows `key` (an integer array of any shape, an int or a
+        slice), and optionally a slice of their columns: one gather of
+        whole lines and a select on the slot. jnp.where, not
+        take_along_axis, which lowers to a second gather."""
+        cols = slice(None)
+        if isinstance(key, tuple):
+            key, cols = key
+        if isinstance(key, slice):
+            key = np.arange(*key.indices(self.capacity), dtype=np.int32)
+        idx = jnp.asarray(key)
+        g, stride = _packing(self.width)
+        per_line = jnp.asarray(g, idx.dtype)
+        lines = self.lines[jax.lax.div(idx, per_line)]
+        slot = jax.lax.rem(idx, per_line)[..., None]
+        rows = lines[..., : self.width]
+        for k in range(1, g):
+            at = k * stride
+            rows = jnp.where(slot == k, lines[..., at : at + self.width], rows)
+        return rows[..., cols]
+
+    def write(self, block, ptr, offset=None) -> "PackedRing":
+        """The ring with block row j at logical row (ptr + offset[j]) %
+        capacity, `offset` a permutation of arange(m) (default: itself) and
+        m <= capacity. Whole lines: the at most m // G + 3 lines the run
+        touches (an unaligned start, and the ring's last line where G does
+        not divide the capacity) are read, overlaid and written back, one
+        gather and one scatter, so `ptr` needs no alignment. A `[1, width]`
+        window scattered at (line, lane) would be plainer and compiles, on
+        the TPU, to a loop of m dynamic-update-slices."""
+        m = block.shape[0]
+        (g, stride), cap = _packing(self.width), self.capacity
+        n = self.lines.shape[0]
+        touched = min(n, m // g + 3)
+        line = (ptr // g + jnp.arange(touched, dtype=jnp.int32)) % n
+        src = (
+            None if offset is None
+            else jnp.zeros(m, jnp.int32).at[offset].set(jnp.arange(m, dtype=jnp.int32))
+        )
+        lane = jnp.arange(stride, dtype=jnp.int32)[None, :] < self.width
+        new, fresh = [], []
+        for k in range(g):
+            row = line * g + k
+            j = (row - ptr) % cap
+            fresh.append(((row < cap) & (j < m))[:, None] & lane)
+            j = jnp.minimum(j, m - 1)
+            rows = block[j if src is None else src[j]]
+            new.append(jnp.pad(rows, ((0, 0), (0, stride - self.width))))
+        pad = ((0, 0), (0, _LANES - g * stride))
+        overlaid = jnp.where(
+            jnp.pad(jnp.concatenate(fresh, axis=1), pad),
+            jnp.pad(jnp.concatenate(new, axis=1), pad),
+            self.lines[line],
+        )
+        return PackedRing(self.lines.at[line].set(overlaid), self.width, cap)
+
+
+def ring_write(storage, block, ptr, offset=None):
+    """The one ring insert: `storage` with block row j at logical row (ptr +
+    offset[j]) % capacity (offset: a permutation of arange(m), default
+    itself), for a PackedRing and for a plain [capacity, width] array."""
+    if isinstance(storage, PackedRing):
+        return storage.write(block, ptr, offset)
+    if offset is None:
+        offset = jnp.arange(block.shape[0], dtype=jnp.int32)
+    return storage.at[(ptr + offset) % storage.shape[0]].set(block)
 
 
 def ring_format(sharding, width: int):
     """What every program that creates, restores or returns the ring names
     for it: `sharding` plus the row-major device layout where ring_layout
-    picks it, and the plain `sharding` otherwise — compact rows, no mesh,
-    or devices that are not TPUs (there the programs, and so CPU runs, are
-    what they were). Programs that only READ the ring name no layout: jit
+    picks it, and the plain `sharding` otherwise — packed lines (whose
+    default layout is the row-major one), compact rows, no mesh, or devices
+    that are not TPUs. Programs that only READ the ring name no layout: jit
     adopts a committed argument's."""
     if (
         sharding is None
         or next(iter(sharding.device_set)).platform != "tpu"
-        or ring_layout(width) == "compact"
+        or ring_layout(width) != "row_major"
     ):
         return sharding
     return Format(
@@ -327,17 +474,21 @@ class DeviceReplay:
         # superstep, or a donated insert would hand the ring back in the
         # default layout and silently undo it.
         self.storage_format = ring_format(sharding, self.width)
-        self.storage = self._place_storage(None)
         # What says it engaged (ingest_snapshot's replay_ring_layout /
         # replay_row_bytes_device): the layout the ring is held in and the
-        # bytes a row takes there — tiled and padded on the TPU, the bare
-        # packed row elsewhere.
+        # bytes a row takes there — a packed line's share everywhere, tiled
+        # and padded on the TPU, the bare row elsewhere.
+        layout = ring_layout(self.width, self.sharded)
         self.ring_layout = (
-            "row_major" if isinstance(self.storage_format, Format) else "compact"
+            layout
+            if layout == "packed" or isinstance(self.storage_format, Format)
+            else "compact"
         )
+        self.storage = self._place_storage(None)
         self.row_bytes_device = (
             ring_row_bytes(self.width, self.ring_layout)
-            if next(iter(self.storage.devices())).platform == "tpu"
+            if self.ring_layout == "packed"
+            or next(iter(self.storage.devices())).platform == "tpu"
             else 4 * self.width
         )
         self.ptr = jnp.zeros((), jnp.int32)
@@ -405,8 +556,7 @@ class DeviceReplay:
         # find them by it.
         def ring_insert(storage, block, ptr, size):
             m = block.shape[0]
-            idx = (ptr + jnp.arange(m, dtype=jnp.int32)) % self.capacity
-            storage = storage.at[idx].set(block)
+            storage = ring_write(storage, block, ptr)
             new_ptr = (ptr + m) % self.capacity
             new_size = jnp.minimum(size + m, self.capacity)
             return storage, new_ptr, new_size
@@ -535,6 +685,19 @@ class DeviceReplay:
         rings in HBM. (A restore does hold two while its rows are relaid:
         they land in the default layout first.)"""
         fmt = self.storage_format
+        if self.ring_layout == "packed":
+            storage = (
+                PackedRing(
+                    jnp.zeros(
+                        (PackedRing.n_lines(self.width, self.capacity), _LANES),
+                        jnp.float32,
+                    ),
+                    self.width, self.capacity,
+                )
+                if rows is None
+                else PackedRing.from_rows(rows)
+            )
+            return jax.device_put(storage, fmt)
         if isinstance(fmt, Format):
             if rows is not None:
                 relay = self.ring_program(jax.jit(lambda x: x, out_shardings=fmt))
@@ -591,21 +754,16 @@ class DeviceReplay:
                         )
                     )
                 )
-            elif n == size:
-                cols = np.asarray(
-                    jax.device_get(self.storage[:n, col : col + 2])
-                )
             else:
-                # Evenly strided over the live region, not the [:n] prefix
-                # — a 1M-ring prefix can be ~900k insertions stale, and the
-                # round-5 corroboration gate would refuse legitimate
-                # expansions against long-gone rewards. Deterministic
-                # stride: replicas and strict_sync replays see identical
-                # samples.
-                idx = np.linspace(0, size - 1, n).astype(np.int64)
+                # All of a young ring; of a larger one, rows evenly strided
+                # over the live region, not the [:n] prefix — a 1M-ring
+                # prefix can be ~900k insertions stale, and the round-5
+                # corroboration gate would refuse legitimate expansions
+                # against long-gone rewards. Deterministic stride: replicas
+                # and strict_sync replays see identical samples.
+                idx = np.linspace(0, size - 1, n).astype(np.int32)
                 cols = np.asarray(
-                    jax.device_get(jnp.take(self.storage[:, col : col + 2],
-                                            jnp.asarray(idx), axis=0))
+                    jax.device_get(self.storage[idx, col : col + 2])
                 )
         if self._procs == 1:
             with self._staging:
@@ -635,7 +793,9 @@ class DeviceReplay:
             self._shard_stats.snapshot(
                 n_shards=self._n_shards,
                 device_storage_bytes=(
-                    self.capacity * self.row_bytes_device // self._n_shards
+                    4 * _LANES * PackedRing.n_lines(self.width, self.capacity)
+                    if self.ring_layout == "packed"
+                    else self.capacity * self.row_bytes_device // self._n_shards
                 ),
                 fill=len(self),
             )
@@ -1470,9 +1630,8 @@ class DeviceReplay:
                     r = g % bs
                     offset = j * (procs * bs) + p * bs + r
                 else:
-                    offset = g
-                idx = (ptr + offset) % self.capacity
-                storage = storage.at[idx].set(block)
+                    offset = None
+                storage = ring_write(storage, block, ptr, offset)
                 new_ptr = (ptr + m) % self.capacity
                 new_size = jnp.minimum(size + m, self.capacity)
                 return storage, new_ptr, new_size
@@ -2192,8 +2351,9 @@ class DevicePrioritizedReplay(DeviceReplay):
 
 def program_specs():
     """The donated insert/scatter/stamp program family, built over tiny
-    rings (capacity 64, blocks of 8) — replicated and sharded placement
-    both. The multi-host global inserts (all-gather beats) need a real
+    rings (capacity 64, blocks of 8) — replicated (compact rows at width
+    65, packed lines at width 10) and sharded placement. The multi-host
+    global inserts (all-gather beats) need a real
     multi-process pod and are exercised by the gloo chaos tests instead;
     this registry holds what one process can trace."""
     from distributed_ddpg_tpu.analysis.programs import (
@@ -2205,10 +2365,16 @@ def program_specs():
     OWNER = "replay/device.py"
     M = 8  # rows per probe ship (one block)
 
-    def insert():
-        r = DeviceReplay(64, 3, 1, block_size=M, async_ship=False)
-        block = np.zeros((M, r.width), np.float32)
-        return BuiltProgram(r._insert, (r.storage, block, r.ptr, r.size), (0,))
+    def insert(obs_dim, layout):
+        def build():
+            r = DeviceReplay(64, obs_dim, 1, block_size=M, async_ship=False)
+            assert r.ring_layout == layout
+            block = np.zeros((M, r.width), np.float32)
+            return BuiltProgram(
+                r._insert, (r.storage, block, r.ptr, r.size), (0,)
+            )
+
+        return build
 
     def insert_sharded():
         r = DeviceReplay(
@@ -2277,7 +2443,8 @@ def program_specs():
         return BuiltProgram(r._get_prio_reshard(), (prios,), ())
 
     return [
-        ProgramSpec("replay.insert", OWNER, insert),
+        ProgramSpec("replay.insert", OWNER, insert(31, "compact")),
+        ProgramSpec("replay.insert.packed", OWNER, insert(3, "packed")),
         ProgramSpec("replay.insert.sharded", OWNER, insert_sharded),
         ProgramSpec(
             "replay.insert.devrows.sharded", OWNER, insert_devrows_sharded

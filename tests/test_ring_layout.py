@@ -1,7 +1,10 @@
-"""The replay ring's device layout (replay/device.py ring_format): the width
-rule, that nothing changes off the TPU, and that no program that takes the
-ring copies it — compiled here for the CPU and, through the TPU compiler the
-sandbox carries, for a described v5e."""
+"""The replay ring's device layout (replay/device.py ring_layout, PackedRing,
+ring_format): the width rule, that the row-major Format is the TPU's alone,
+and that no program that takes the ring copies it — compiled here for the
+CPU and, through the TPU compiler the sandbox carries, for a described v5e.
+What a packed ring holds and reads back: tests/test_packed_ring.py."""
+
+import re
 
 import numpy as np
 import pytest
@@ -13,10 +16,12 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from distributed_ddpg_tpu.replay.device import (
     DeviceReplay,
+    PackedRing,
     _RingProgram,
     ring_format,
     ring_layout,
     ring_row_bytes,
+    ring_write,
 )
 from ring_layout_util import humanoid_ring_programs, ring_sized_copies
 
@@ -24,8 +29,11 @@ from ring_layout_util import humanoid_ring_programs, ring_sized_copies
 @pytest.mark.parametrize(
     "width,layout,row_bytes",
     [
-        (43, "compact", 192),      # HalfCheetah: 43 -> 128 lanes would be x2.67
-        (65, "compact", 288),      # Ant
+        (10, "packed", 42),        # Pendulum: 12 rows to a line
+        (33, "packed", 170),       # three to a line, x1.07 of compact's 160
+        (43, "packed", 256),       # HalfCheetah: two to a line, x1.33 of compact's 192, the worst case
+        (64, "packed", 256),       # two to a line, nothing wasted
+        (65, "compact", 288),      # Ant: one row a line would be x1.78
         (120, "row_major", 512),
         (128, "row_major", 512),
         (772, "row_major", 3584),  # Humanoid: 772 -> 896, +15.5%
@@ -35,6 +43,8 @@ from ring_layout_util import humanoid_ring_programs, ring_sized_copies
 def test_width_rule(width, layout, row_bytes):
     assert ring_layout(width) == layout
     assert ring_row_bytes(width, layout) == row_bytes
+    # A row-sharded ring is never packed: its narrow rows stay compact.
+    assert ring_layout(width, sharded=True) == ("compact" if layout == "packed" else layout)
     # The compact row pads to 8 sublanes, whatever the rule picked.
     assert ring_row_bytes(width, "compact") == 4 * (-(-width // 8) * 8)
 
@@ -45,7 +55,7 @@ def test_plain_sharding_off_the_tpu():
     # Humanoid's width picks row-major on the TPU; here the helper hands the
     # sharding back untouched, and no mesh stays no sharding.
     assert ring_format(sharding, 772) is sharding
-    assert ring_format(sharding, 43) is sharding
+    assert ring_format(sharding, 43) is sharding  # packed lines: the default layout, on the TPU too
     assert ring_format(None, 772) is None
     r = DeviceReplay(64, 376, 17, mesh=mesh, block_size=16)
     assert r.storage_format == sharding and not isinstance(r.storage_format, Format)
@@ -172,6 +182,9 @@ def test_ring_program_only_where_the_ring_is_row_major():
     jitted = jax.jit(lambda x: x)
     assert r.ring_program(jitted) is jitted  # off the TPU: the jit itself
     assert not isinstance(r._insert, _RingProgram)
+    packed = DeviceReplay(64, 17, 6, block_size=16)
+    assert packed.ring_layout == "packed" and packed.storage_format is None
+    assert packed.ring_program(jitted) is jitted  # packed lines: the jit itself everywhere
 
 
 # --- compiled for a described v5e: the layout itself. The TPU's compiler is
@@ -197,19 +210,23 @@ def v5e_sharding():
 
 def _compiled_for(sharding, width, fmt):
     """(insert, gather) executables for a ring of CAPACITY x width whose
-    parameter and result are held in `fmt`. The insert is the ring's own
-    scatter (DeviceReplay's ring_insert), written out here so that no
-    CAPACITY-row ring is allocated on the CPU to borrow it from."""
+    parameter and result are held in `fmt`: packed lines where the rule packs,
+    the plain array otherwise. The insert is the ring's own (DeviceReplay's
+    ring_insert over ring_write), written out here so that no CAPACITY-row
+    ring is allocated on the CPU to borrow it from."""
 
     def ring_insert(storage, block, ptr, size):
         m = block.shape[0]
-        idx = (ptr + jnp.arange(m, dtype=jnp.int32)) % CAPACITY
-        storage = storage.at[idx].set(block)
+        storage = ring_write(storage, block, ptr)
         return storage, (ptr + m) % CAPACITY, jnp.minimum(size + m, CAPACITY)
 
     insert = ring_insert
     replicated = NamedSharding(sharding.mesh, P())
-    ring = jax.ShapeDtypeStruct((CAPACITY, width), jnp.float32, sharding=fmt)
+    if ring_layout(width) == "packed":
+        lines = (PackedRing.n_lines(width, CAPACITY), 128)
+        ring = PackedRing(jax.ShapeDtypeStruct(lines, jnp.float32, sharding=fmt), width, CAPACITY)
+    else:
+        ring = jax.ShapeDtypeStruct((CAPACITY, width), jnp.float32, sharding=fmt)
     block = jax.ShapeDtypeStruct((1024, width), jnp.float32, sharding=sharding)
     scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=replicated)
     idx = jax.ShapeDtypeStruct((8, 256), jnp.int32, sharding=replicated)
@@ -220,6 +237,15 @@ def _compiled_for(sharding, width, fmt):
     return inserted, gathered
 
 
+def _ring_sized_ops(hlo_text, shape):
+    """Opcodes of the instructions of the ENTRY computation whose result has
+    the ring's shape."""
+    entry = hlo_text.split("ENTRY ", 1)[1]
+    rows, width = shape
+    pat = re.compile(r"\s*(?:ROOT )?%?[\w.\-]+ = f32\[" + f"{rows},{width}" + r"\]\{[^}]*\} ([\w\-]+)\(")
+    return sorted(m.group(1) for m in map(pat.match, entry.splitlines()) if m)
+
+
 @pytest.mark.parametrize("width", [43, 772])
 def test_v5e_programs_hold_no_ring_sized_copy(v5e_sharding, width):
     fmt = ring_format(v5e_sharding, width)
@@ -228,17 +254,33 @@ def test_v5e_programs_hold_no_ring_sized_copy(v5e_sharding, width):
         assert fmt.layout.major_to_minor == (0, 1)
         assert fmt.layout.tiling == ((8, 128),)
     else:
-        assert fmt is v5e_sharding  # narrow rows keep the runtime's layout
+        # Packed lines and compact rows name no layout: the runtime's own, so
+        # their programs are plain jits (ring_program) and go through the
+        # persistent cache like any other.
+        assert fmt is v5e_sharding
     inserted, gathered = _compiled_for(v5e_sharding, width, fmt)
-    shape = (CAPACITY, width)
+    shape = (CAPACITY // 2, 128) if width == 43 else (CAPACITY, width)
     assert ring_sized_copies(inserted.as_text(), shape) == []
     assert ring_sized_copies(gathered.as_text(), shape) == []
-    # The donated insert hands the ring back in the layout it came in.
-    assert inserted.output_formats[0].layout == inserted.input_formats[0][0].layout
+    # The donated insert hands the ring back in the layout it came in (the
+    # ring is the first leaf of the arguments and of the results).
+    ring_in = jax.tree.leaves(inserted.input_formats)[0].layout
+    assert jax.tree.leaves(inserted.output_formats)[0].layout == ring_in
+    # One ring in HBM while the insert runs, not two.
+    assert inserted.memory_analysis().temp_size_in_bytes < CAPACITY * 4 * width // 8
     if width == 772:
-        assert inserted.output_formats[0].layout == fmt.layout
-        # One ring in HBM while the insert runs, not two.
-        assert inserted.memory_analysis().temp_size_in_bytes < CAPACITY * 4 * width // 8
+        assert ring_in == fmt.layout
+    if width == 43:
+        # The runtime's own layout of [CAPACITY // 2, 128] is the row-major
+        # one. If a later compiler changes that, the rule must name a Format.
+        assert ring_in.major_to_minor == (0, 1) and ring_in.tiling == ((8, 128),)
+        assert jax.tree.leaves(gathered.input_formats)[0].layout == ring_in
+        # No op of the ring's size but the insert's one in-place scatter: the
+        # lines touched are gathered, overlaid and scattered back whole.
+        assert _ring_sized_ops(inserted.as_text(), shape) == ["fusion", "parameter"]
+        assert _ring_sized_ops(gathered.as_text(), shape) == ["parameter"]
+        assert inserted.memory_analysis().temp_size_in_bytes < 2**20
+        assert gathered.memory_analysis().temp_size_in_bytes < 2**21  # the [8 x 256, 128] lines gathered
 
 
 def test_v5e_default_layout_is_what_the_rule_replaces(v5e_sharding):
