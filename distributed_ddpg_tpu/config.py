@@ -117,9 +117,9 @@ class DDPGConfig:
     # logical ring partitioned over the mesh's 'data' axis (strided
     # ownership — position p on shard p % N), so per-device storage is
     # capacity/N rows (~N× aggregate capacity at fixed HBM) and each
-    # staged row is shipped only to its owner (~1/N landed ingest bytes,
-    # the BENCH_SHARDED_REPLAY A/B headline). Sampling draws replica-
-    # identical indices and reassembles the minibatch with an owner-masked
+    # staged row is shipped only to its owner (~1/N landed ingest bytes:
+    # replay_ingest_bytes_per_row, not measured on the chip). Sampling
+    # draws replica-identical indices and reassembles the minibatch with an owner-masked
     # gather + psum inside the jitted chunk; sampled minibatches are
     # bit-identical to replicated mode. Forces the XLA scan path (the
     # megakernel reads replicated storage whole) and composes with
@@ -224,8 +224,8 @@ class DDPGConfig:
     # bucketing — replayable, not random).
     front_canary_fraction: float = 0.1
     # The live gate needs this many latency samples on BOTH stable and
-    # candidate before it can promote (ci_gate's arm-on-first-capture
-    # discipline applied to live traffic: never promote on thin data).
+    # candidate before it can promote (arm on first capture: never
+    # promote on thin data).
     front_canary_min_requests: int = 50
     # Allowed relative p95-latency regression of candidate vs stable;
     # past it the canary auto-rolls-back (THRESHOLD's live twin).
@@ -372,9 +372,10 @@ class DDPGConfig:
     param_refresh_interval_s: float = 0.1
     prefetch_depth: int = 2          # host->HBM double-buffer depth
     # Learner steps per dispatch (lax.scan / megakernel chunk length) in
-    # train_jax. 0 = auto: 800 on kernel-native TPU backends (measured —
-    # the rate saturates around 800 while one dispatch stays ~4 ms, see
-    # BENCH_r*.json), 8 elsewhere (CPU dev/test dispatches stay snappy).
+    # train_jax. 0 = auto: 800 on kernel-native TPU backends — the length
+    # all three benchmark cells run (PERF.md §5: 4.2, 17.5 and 48.5 ms a
+    # launch); it has not been swept on the chip — 8 elsewhere (CPU
+    # dev/test dispatches stay snappy).
     # Ingest, param refresh, and the env-step budget check all run once per
     # chunk, so the chunk also bounds ingest latency and budget overshoot.
     learner_chunk: int = 0

@@ -35,8 +35,8 @@ _COMMON = dict(actor_hidden=(256, 256), critic_hidden=(256, 256))
 # The jax rungs pin ~1 grad step per env step from BOTH sides
 # (config.py: ratio product >= 1 is livelock-free): that is the
 # reference's sync replay ratio, which the equal-return gate compares
-# against. Free-running async (the throughput mode bench.py measures)
-# is a flag away: --max_learn_ratio=0 --max_ingest_ratio=0.
+# against. Free-running async (the throughput mode the benchmark's
+# `free` traffic measures) is a flag away: --max_learn_ratio=0 --max_ingest_ratio=0.
 # watchdog_s: ladder runs are driver-managed wall-clock budgets — a wedged
 # device must crash loudly (watchdog.py, exit 70) instead of eating the
 # budget as a silent hang.

@@ -2,7 +2,7 @@
 
 The reference's only observability was TensorBoard scalar summaries
 [RECALL]; here the sink is append-only JSONL (one object per event,
-machine-parseable by the bench harness) plus optional human lines.
+machine-parseable by the benchmark's harness) plus optional human lines.
 Tracked quantities follow SURVEY.md §5: episode return, losses, mean Q,
 grad norms, buffer fill, actor/learner steps/sec, staleness.
 """
@@ -369,7 +369,7 @@ class IngestStats:
     Producers call record_push (rows staged + time spent stalled on a full
     staging ring); the shipper calls record_ship (rows/blocks moved to HBM
     per device call + the dispatch wall time). snapshot() emits the
-    `ingest_*` fields each train/bench record carries and resets the
+    `ingest_*` fields each train record carries and resets the
     interval, so every JSONL line describes its own window:
 
       ingest_rows_per_sec   rows landed in HBM over the interval
@@ -438,18 +438,18 @@ class IngestStats:
 class ReplayShardStats:
     """Thread-safe counters for the device-replay placement layer
     (replay/device.py; docs/REPLAY_SHARDING.md) — the `replay_*` family
-    every train/bench record carries on the device-replay path, and the
-    BENCH_SHARDED_REPLAY A/B's raw input. Byte counters are MEASURED from
-    the device_put result's addressable shards (one copy per replica in
-    replicated mode, exactly one owner copy in sharded mode), so the
-    bytes-per-row headline is an observation, not arithmetic:
+    every train record carries on the device-replay path. Byte counters
+    are MEASURED from the device_put result's addressable shards (one
+    copy per replica in replicated mode, exactly one owner copy in
+    sharded mode), so the bytes-per-row headline is an observation, not
+    arithmetic:
 
       replay_ingest_bytes          h2d bytes landed on devices this
                                    interval (sum over device copies)
       replay_ingest_bytes_per_row  interval mean landed bytes per row —
                                    ~width*4*N replicated, ~width*4
-                                   sharded (the 1/N ingest claim; the
-                                   ci_gate lower-is-better key)
+                                   sharded (the 1/N ingest claim;
+                                   lower is better)
       replay_shard_count           gauge: storage shards (1 = replicated)
       replay_device_storage_bytes  gauge: storage bytes ONE device holds
                                    (capacity*width*4/N sharded — the N×
@@ -671,7 +671,6 @@ class FusedBeatStats:
 
       fused_beats           fused beat dispatches in the interval
       fused_steps_per_s     learner grad steps retired over the interval
-                            (the BENCH_FUSED headline / ci_gate key)
       fused_rows_per_s      rollout transition rows landed over the
                             interval (the beat's in-program insert)
       fused_beat_ms         mean wall time per beat dispatch (enqueue +
@@ -683,8 +682,8 @@ class FusedBeatStats:
       fused_supersteps      superstep DISPATCHES in the interval — equals
                             fused_beats for the plain megastep, and
                             fused_beats / B for a B-beat superstep
-                            (parallel/superstep.py): the host-overhead
-                            amortization the BENCH_SUPERSTEP row measures
+                            (parallel/superstep.py): the host overhead
+                            a superstep amortizes
       fused_superstep_beats beats per dispatch over the interval (B; 1.0
                             for the plain megastep)
     """
@@ -763,7 +762,7 @@ class TransferStats:
     ingest / prefetch / d2h) it tracks items dispatched, bytes moved, and
     dispatch wall time with a deterministic reservoir for tails; queue
     depths ride in at snapshot time as gauges. snapshot() emits the
-    `transfer_*` fields each train/bench record carries and resets the
+    `transfer_*` fields each train record carries and resets the
     interval (restart count and queue depths are cumulative/gauge):
 
       transfer_dispatches        scheduled items dispatched this interval
@@ -1178,7 +1177,7 @@ class ServeStats:
     """Thread-safe counters for the batched policy-inference service
     (serve/; docs/SERVING.md) — the `serve_*` family every train/final
     JSONL record carries when serving is armed, and the digest
-    tools.serve_bench / bench.py BENCH_SERVE emit.
+    tools.serve_bench emits.
 
     COUNTERS are cumulative (requests/batches/overloads/errors/refreshes:
     the run's serving history; a nonzero overload anywhere matters even if
@@ -1200,11 +1199,9 @@ class ServeStats:
       serve_fill_p50/p95    interval batch-fill fraction tails
       serve_p50_ms/p95_ms/max_ms
                             interval request latency tails, enqueue ->
-                            response delivered (the ci_gate -serve_p95_ms
-                            key pins the p95)
+                            response delivered
       serve_queue_depth     request-queue depth at snapshot (gauge)
       serve_queue_depth_p95 interval p95 of the depth seen at each submit
-                            (the ci_gate -serve_queue_depth_p95 key)
     """
 
     def __init__(self, seed: int = 0, max_batch: int = 1):
@@ -1316,8 +1313,7 @@ class FrontStats:
                             (cumulative)
       front_wire_p50_ms/front_wire_p95_ms/front_wire_max_ms
                             interval wire latency tails, frame decoded ->
-                            response queued (the ci_gate
-                            -front_wire_p95_ms key pins the p95)
+                            response queued
     """
 
     def __init__(self, seed: int = 0):

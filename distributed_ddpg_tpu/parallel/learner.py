@@ -62,12 +62,12 @@ def _ingest_lock(device_replay):
 
 def resolve_learner_chunk(config: DDPGConfig) -> int:
     """Production learner steps-per-dispatch: config.learner_chunk when set,
-    else measured defaults — 800 on kernel-native TPU backends (the rate
-    saturates around chunk 800 while one dispatch stays ~4 ms; see the
-    latest BENCH_r*.json chunk sweep), 8 elsewhere (CPU scan dispatches in
-    dev/test stay snappy). train_jax and bench.py both resolve through
-    here so the trainer and the benchmark run the same program
-    (VERDICT.md round-2 Weak #3)."""
+    else the defaults — 800 on kernel-native TPU backends (the length all
+    three benchmark cells run, 4.2, 17.5 and 48.5 ms a launch, PERF.md
+    §5; not swept on the chip), 8 elsewhere (CPU scan dispatches in
+    dev/test stay snappy). Every caller resolves through here, so the
+    trainer and whatever measures it run the same program (VERDICT.md
+    round-2 Weak #3)."""
     if config.learner_chunk > 0:
         return config.learner_chunk
     from distributed_ddpg_tpu.ops.fused_chunk import runs_native

@@ -53,7 +53,7 @@ from distributed_ddpg_tpu.types import Batch
 # (tests/test_partition.py). Set at import of THIS module — every
 # device-program owner imports it before building programs, so all
 # programs in a process trace under one consistent scheme regardless of
-# which entry point (train/bench/proganalyze/multihost child) started
+# which entry point (train/benchmark/proganalyze/multihost child) started
 # it. An explicit JAX_THREEFRY_PARTITIONABLE in the environment wins:
 # that is the embedder's escape hatch back to the legacy scheme.
 import os as _os

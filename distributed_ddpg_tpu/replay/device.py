@@ -782,7 +782,7 @@ class DeviceReplay:
     def ingest_snapshot(self) -> dict:
         """Interval ingest observability fields (metrics.IngestStats):
         rows/sec shipped, ship calls, coalesce factor, producer stall
-        time, queue depth — emitted into train/bench records. The shipper
+        time, queue depth — emitted into train records. The shipper
         restart count (cumulative, recovery path) rides along."""
         out = self._stats.snapshot(pending_rows=self.pending_rows)
         out["ingest_shipper_restarts"] = self._shipper_restarts
@@ -1165,7 +1165,7 @@ class DeviceReplay:
 
     def drain_pending(self) -> int:
         """Ship all staged full blocks and block until the inserts have
-        executed — the barrier bench/tests use before reading storage.
+        executed — the barrier tests use before reading storage.
         Single-process only (multi-host draining IS sync_ship)."""
         if self._procs > 1:
             raise ReplayUsageError("drain_pending() is per-process; use "
@@ -1678,8 +1678,8 @@ class DeviceReplay:
         if self.sharded:
             # Group rows by owner shard (owner of ptr+j is j % N — ptr is
             # N-aligned) so the sharded device_put lands each row ONLY on
-            # its owner: 1/N of the replicated path's landed bytes, the
-            # measured claim behind BENCH_SHARDED_REPLAY.
+            # its owner: 1/N of the replicated path's landed bytes
+            # (counted by ReplayShardStats: replay_ingest_bytes_per_row).
             n = self._n_shards
             grouped = np.ascontiguousarray(
                 np.asarray(chunk, np.float32)

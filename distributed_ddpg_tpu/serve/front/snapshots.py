@@ -7,7 +7,7 @@ serving `fraction` of traffic through a deterministic canary split —
 crc32("tenant:request_id") bucketing, so the same request replays to the
 same version and the split is auditable, not random.
 
-`CanaryGate` is the ci_gate pattern applied to live traffic: the
+`CanaryGate` is a regression gate applied to live traffic: the
 candidate promotes only after BOTH arms have `min_requests` latency
 samples (arm-on-first-capture: never promote on thin data) and its p95
 is within `threshold` relative regression of stable's — and it

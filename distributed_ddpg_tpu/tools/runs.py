@@ -1,10 +1,11 @@
 """Run-analysis CLI for the repo's JSONL/JSON artifacts.
 
-`runs/` holds ~100 train/eval/bench files and until this module the only
+`runs/` holds ~70 train/eval record files and until this module the only
 tooling was hand-diffing them (how the round-5 8-device ingest
-regression was found). Four subcommands over the schemas the repo already
-produces (metrics.MetricsLogger records; bench.py result JSON — both
-documented in docs/OBSERVABILITY.md):
+regression was found). Subcommands over the schemas the repo already
+produces (metrics.MetricsLogger records, documented in
+docs/OBSERVABILITY.md; the findings JSONs of the two analysis gates) and
+one comparer of any two JSON objects:
 
   summarize <run.jsonl> [...]    per-run digest: record counts, steady-
                                  state rates, per-phase breakdown table
@@ -13,16 +14,22 @@ documented in docs/OBSERVABILITY.md):
   compare  <a.jsonl> <b.jsonl>   side-by-side key metrics with % deltas —
                                  the A/B view for "did this PR move
                                  dispatch p95".
-  gate <base.json> <cand.json>   CI regression gate over two bench.py
-                                 JSONs: exit 2 when any gated key of the
-                                 candidate falls more than --threshold
-                                 below the baseline (or above, for
-                                 lower-is-better keys prefixed '-').
+  gate <base.json> <cand.json> --keys k1,-k2
+                                 regression gate over two JSON objects,
+                                 one to a file (e.g. two result lines of
+                                 benchmarks/run.py, keys such as
+                                 metrics.grad_steps_per_s.value,
+                                 -metrics.setup_s.value): exit 2 when any
+                                 named key of the candidate falls more
+                                 than --threshold below the baseline (or
+                                 above, for lower-is-better keys prefixed
+                                 '-'). Dotted keys descend into nested
+                                 objects.
   lint [findings.json]           pretty-print the invariant lint engine's
                                  findings JSON (scripts/lint_gate.sh
                                  artifact; docs/ANALYSIS.md) as the same
                                  digest tables; exit 2 on unsuppressed
-                                 findings — the bench gate's contract.
+                                 findings — the same contract as gate.
   merge-trace <t0.json> ...      fuse N per-host flight-recorder traces
                                  into ONE Perfetto timeline (process
                                  track per host, clocks aligned by the
@@ -62,23 +69,6 @@ def load_jsonl(path: str) -> List[Dict[str, Any]]:
             if isinstance(rec, dict):
                 records.append(rec)
     return records
-
-
-def load_bench(path: str) -> Dict[str, Any]:
-    """A bench.py result: one JSON object. Driver wrappers (BENCH_r*.json)
-    embed the object in a 'tail' string; unwrap when present so both
-    shapes gate/compare identically."""
-    with open(path) as f:
-        obj = json.load(f)
-    if "value" not in obj and isinstance(obj.get("tail"), str):
-        tail = obj["tail"]
-        start = tail.find('{"metric"')
-        if start >= 0:
-            try:
-                obj = json.loads(tail[start:])
-            except json.JSONDecodeError:
-                pass
-    return obj
 
 
 def by_kind(records: Sequence[Dict[str, Any]]) -> Dict[str, List[Dict[str, Any]]]:
@@ -843,12 +833,9 @@ def compare_runs(path_a: str, path_b: str) -> Tuple[str, List[List[Any]]]:
 # gate
 # ---------------------------------------------------------------------------
 
-DEFAULT_GATE_KEYS = ("value",)
-
-
 def _lookup(obj: Dict[str, Any], dotted: str):
-    """Resolve 'scaling_cpu_virtual.scaled_batch.8.rows_per_sec' style
-    paths into nested bench JSON."""
+    """Resolve 'metrics.grad_steps_per_s.value' style paths into a
+    nested JSON object."""
     cur: Any = obj
     for part in dotted.split("."):
         if not isinstance(cur, dict) or part not in cur:
@@ -857,11 +844,11 @@ def _lookup(obj: Dict[str, Any], dotted: str):
     return cur
 
 
-def gate_bench(
+def gate_objects(
     baseline: Dict[str, Any],
     candidate: Dict[str, Any],
     threshold: float,
-    keys: Sequence[str] = DEFAULT_GATE_KEYS,
+    keys: Sequence[str],
 ) -> Tuple[bool, List[str]]:
     """True = pass. A key prefixed '-' is lower-is-better (latencies);
     otherwise higher-is-better (rates). A key missing from the CANDIDATE
@@ -887,10 +874,9 @@ def gate_bench(
                 # -guardrail_rollbacks) is a real pin: any nonzero
                 # candidate is a regression from "never happened", which
                 # no relative threshold can express. Int-typed only:
-                # latency keys (-ingest_ship_ms, -transfer_*_p95) emit
-                # FLOAT 0.0 when their reservoir saw no samples, and
-                # "no samples" must keep SKIPping, not fail the first
-                # candidate that records any.
+                # a latency reads FLOAT 0.0 when its reservoir saw no
+                # samples, and "no samples" must keep SKIPping, not fail
+                # the first candidate that records any.
                 bad = cand > 0
                 lines.append(
                     f"{'FAIL' if bad else 'ok':4s} {key}: baseline=0 "
@@ -901,13 +887,8 @@ def gate_bench(
             else:
                 lines.append(f"SKIP {key}: baseline is 0")
             continue
-        ratio = cand / base
-        if lower_better:
-            bad = ratio > 1.0 + threshold
-            rel = ratio - 1.0
-        else:
-            bad = ratio < 1.0 - threshold
-            rel = ratio - 1.0
+        rel = cand / base - 1.0
+        bad = rel > threshold if lower_better else rel < -threshold
         verdict = "FAIL" if bad else "ok"
         lines.append(
             f"{verdict:4s} {key}: baseline={base:g} candidate={cand:g} "
@@ -1081,23 +1062,24 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     p_cmp.add_argument("path_b")
 
     p_gate = sub.add_parser(
-        "gate", help="CI regression gate over two bench JSONs "
-        "(exit 2 on regression)",
+        "gate", help="regression gate over two JSON objects by dotted "
+        "keys (exit 2 on regression)",
     )
     p_gate.add_argument("baseline")
     p_gate.add_argument("candidate")
     p_gate.add_argument("--threshold", type=float, default=0.1,
                         help="allowed relative regression (default 0.10)")
     p_gate.add_argument(
-        "--keys", default=",".join(DEFAULT_GATE_KEYS),
-        help="comma-separated bench keys; prefix '-' for lower-is-better "
-        "(e.g. value,-t_dispatch_ms,ingest_rows_per_sec); dotted paths "
-        "descend into nested objects",
+        "--keys", required=True,
+        help="comma-separated keys; prefix '-' for lower-is-better (e.g. "
+        "metrics.grad_steps_per_s.value,-metrics.setup_s.value over two "
+        "result lines of benchmarks/run.py); dotted paths descend into "
+        "nested objects",
     )
     p_lint = sub.add_parser(
         "lint", help="pretty-print an invariant-lint findings JSON "
         "(the scripts/lint_gate.sh artifact; exit 2 on unsuppressed "
-        "findings, same contract as the bench gate)",
+        "findings, same contract as gate)",
     )
     p_lint.add_argument(
         "path", nargs="?", default="runs/lint_findings.json",
@@ -1153,13 +1135,15 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
 
     if args.cmd == "gate":
         try:
-            base = load_bench(args.baseline)
-            cand = load_bench(args.candidate)
+            with open(args.baseline) as f:
+                base = json.load(f)
+            with open(args.candidate) as f:
+                cand = json.load(f)
         except (OSError, json.JSONDecodeError) as e:
             print(f"error: {e}", file=sys.stderr)
             return 1
         keys = [k for k in args.keys.split(",") if k]
-        ok, lines = gate_bench(base, cand, args.threshold, keys)
+        ok, lines = gate_objects(base, cand, args.threshold, keys)
         for line in lines:
             print(line)
         print("GATE PASS" if ok else "GATE FAIL")

@@ -9,8 +9,7 @@ stack by itself:
 Prints ONE JSON line: the serve_* digest (metrics.ServeStats) plus the
 client-side view (served requests/sec, sheds) and an A/B against the
 per-worker local act() path at the same thread count — the "what does
-dynamic batching buy/cost on this box" number bench.py's BENCH_SERVE=1
-mode embeds in its scaling curves.
+dynamic batching buy/cost on this box" number.
 
 numpy + stdlib only on the default backend (--backend=jax jits the padded
 batch apply instead — the device-serving path).
@@ -20,7 +19,7 @@ docs/SERVING.md 'Network front'): each client thread opens its own
 framed-TCP FrontClient connection against a local FrontServer and the
 digest gains the front_*/tenant_* families plus wire_p50_ms/wire_p95_ms
 — client-measured round-trip tails over the real socket, the
-BENCH_SERVE row that covers the external ingress path.
+external ingress path.
 """
 
 from __future__ import annotations

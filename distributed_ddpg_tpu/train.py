@@ -76,8 +76,7 @@ _EVAL_JOIN_S = 60.0
 def _enable_faulthandler() -> None:
     """Stack dumps on demand (kill -USR1 <pid>) and on hard faults — a
     wedged driver must be debuggable without a debugger attached. Called
-    from train() (CLI and ladder entries) and from bench.py's phase
-    bootstrap (its subprocesses never enter train())."""
+    from train() (CLI and ladder entries)."""
     import faulthandler
     import signal
 
@@ -93,7 +92,7 @@ def require_platform() -> str:
     JAX_PLATFORMS=cpu env var, or tests/conftest.py's config update).
     With nothing asked for, JAX itself drops to the CPU when no TPU
     initializes; a run that carried on from there would look healthy and
-    measure nothing. Shared by train() and bench.py's phases."""
+    measure nothing."""
     import jax
 
     asked = (jax.config.jax_platforms or "").split(",")[0]

@@ -5,7 +5,7 @@
 # the digest/quarantine layer in test_chaos.py, the {1,2,4}^2 reshard
 # matrix in test_replay_sharding.py, and (with ELASTIC_FULL=1) the slow
 # 2-process kill-one -> survivor-shrinks -> rejoin-grows pod drill in
-# test_pod.py. Invoked by scripts/ci_gate.sh --elastic.
+# test_pod.py.
 #
 # Environment:
 #   ELASTIC_FULL=1  also run the slow 2-process shrink/grow drill

@@ -1,9 +1,8 @@
 #!/usr/bin/env bash
 # Invariant lint gate (docs/ANALYSIS.md): run the stdlib-ast rule engine
-# over the package and exit 2 on any unsuppressed finding — the static
-# twin of the bench gate. Pure stdlib (no jax import), finishes in < 5 s
-# on any CI box, so it runs BEFORE the expensive bench comparison
-# (scripts/ci_gate.sh --lint).
+# over the package and exit 2 on any unsuppressed finding. Pure stdlib
+# (no jax import), finishes in < 5 s on any CI box, so it runs before
+# anything that compiles.
 #
 # SKIP semantics: a checkout without the analysis package (old baselines
 # the driver replays) exits 0 with a logged SKIP — absence of the linter

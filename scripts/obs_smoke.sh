@@ -6,8 +6,7 @@
 # pins the docs tables to the emitted key set. With OBS_FULL=1 it also
 # runs the slow 2-process pod drill: scrape /metrics live, inject a
 # faults.py peer loss, assert /healthz flips healthy->degraded on the
-# survivor, and validate the merged two-host Perfetto timeline. Invoked
-# by scripts/ci_gate.sh --obs.
+# survivor, and validate the merged two-host Perfetto timeline.
 #
 # Environment:
 #   OBS_FULL=1  also run the slow 2-process ingress/peer-loss/merge drill

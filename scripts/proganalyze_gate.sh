@@ -4,8 +4,7 @@
 # compile or execution) and exit 2 on any finding — unaliasable
 # donation, collective-order drift vs tests/golden_programs/, beat-group
 # divergence, host-callback leak, or a static recompile-hazard. The
-# dynamic twin of scripts/lint_gate.sh; runs as the `ci_gate.sh
-# --programs` pre-step, before the expensive bench comparison.
+# dynamic twin of scripts/lint_gate.sh.
 #
 # SKIP semantics: a checkout without the program analyzer (old baselines
 # the driver replays) exits 0 with a logged SKIP — absence of the

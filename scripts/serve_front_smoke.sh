@@ -7,8 +7,7 @@
 # canary-regress) — then proves the closed loop by running
 # tools.serve_bench --transport socket against a real TCP front. SKIPs
 # (exit 0) when the front package is absent, so the gate composes with
-# pre-front baselines (the elastic/obs smoke pattern). Invoked by
-# scripts/ci_gate.sh --serve-front.
+# pre-front baselines (the elastic/obs smoke pattern).
 #
 # Environment:
 #   FRONT_FULL=1  also run the slow end-to-end train drill (spawns a

@@ -11,8 +11,7 @@
 # auto-shrink to a degraded singleton -> the prober sees the lost slot
 # healthy again -> auto-grow back to 2 -> clean completion, zero
 # operator actions (the known gloo SIGABRT infra flake retries inside
-# the test, docs/RESILIENCE.md). Invoked by scripts/ci_gate.sh
-# --supervise.
+# the test, docs/RESILIENCE.md).
 #
 # Environment:
 #   SUPERVISE_FULL=1  also run the slow 2-process supervised drill

@@ -2,8 +2,8 @@
 seed-fixed transition parity against a host-stepped JaxPendulum reference
 loop, the devactor: fault grammar + bounded-restart supervisor contract,
 config validation, the tier-1 train smoke (devactor_* in records, ZERO
-transfer_ingest_items from the device source), the bench A/B phase, the
-ci_gate key semantics, and the tools.runs digest."""
+transfer_ingest_items from the device source), and the tools.runs
+digest."""
 
 import json
 
@@ -311,53 +311,6 @@ def test_side_by_side_host_and_device_actors(tmp_path):
     # final["step"] is host + device env steps; strictly more than the
     # device share means the host pool contributed real rows.
     assert final["step"] > out["devactor_env_steps"]
-
-
-def test_bench_devactor_phase_smoke(monkeypatch):
-    """bench.py BENCH_DEVACTOR phase: the A/B JSON carries the scaling
-    curve and the top-level devactor_rows_per_s the gate key pins, and
-    the compiled rollout beats the python host loop at this env count."""
-    import bench
-
-    monkeypatch.setenv("BENCH_SECONDS", "0.25")
-    monkeypatch.setenv("BENCH_DEVACTOR_ENVS", "16")
-    monkeypatch.setenv("BENCH_DEVACTOR_CHUNK", "8")
-    r = bench.phase_devactor()
-    assert "devactor_scaling" in r and "16" in r["devactor_scaling"]
-    point = r["devactor_scaling"]["16"]
-    assert point["devactor_rows_per_s"] > 0
-    assert point["host_rows_per_s"] > 0
-    assert r["devactor_rows_per_s"] == point["devactor_rows_per_s"]
-    assert r["devactor_vs_host"] == point["devactor_vs_host"]
-
-
-def test_ci_gate_devactor_key_semantics():
-    """devactor_rows_per_s: SKIP against pre-devactor baselines (arms on
-    the first BENCH_DEVACTOR capture), FAIL on a real throughput drop."""
-    from distributed_ddpg_tpu.tools.runs import gate_bench
-
-    keys = ("value", "devactor_rows_per_s")
-    ok, lines = gate_bench(
-        {"value": 100.0}, {"value": 100.0, "devactor_rows_per_s": 5e5},
-        0.1, keys,
-    )
-    assert ok and any(
-        l.startswith("SKIP devactor_rows_per_s") for l in lines
-    )
-    ok, lines = gate_bench(
-        {"value": 100.0, "devactor_rows_per_s": 5e5},
-        {"value": 100.0, "devactor_rows_per_s": 2e5},
-        0.1, keys,
-    )
-    assert not ok and any(
-        l.startswith("FAIL devactor_rows_per_s") for l in lines
-    )
-    ok, _ = gate_bench(
-        {"value": 100.0, "devactor_rows_per_s": 5e5},
-        {"value": 100.0, "devactor_rows_per_s": 5.2e5},
-        0.1, keys,
-    )
-    assert ok
 
 
 def test_tools_runs_devactor_digest(tmp_path):

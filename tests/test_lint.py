@@ -602,19 +602,6 @@ def test_lint_gate_script_skips_without_analysis_package(tmp_path):
     assert "SKIP" in proc.stderr
 
 
-def test_ci_gate_lint_prestep_runs_before_usage_check():
-    # `ci_gate.sh --lint` with no candidate: the lint pre-step runs (on
-    # the real package — this is the wiring pin) and the usage error
-    # afterwards exits 1, not the lint gate's 2.
-    proc = subprocess.run(
-        ["bash", str(REPO / "scripts" / "ci_gate.sh"), "--lint"],
-        env={"PATH": "/usr/bin:/bin:/usr/local/bin"},
-        cwd=REPO, capture_output=True, text=True, timeout=120,
-    )
-    assert proc.returncode == 1, (proc.stdout, proc.stderr)
-    assert "files," in proc.stdout  # the lint summary line ran first
-
-
 # ---------------------------------------------------------------------------
 # --changed-only (the sub-second pre-commit mode; docs/ANALYSIS.md)
 # ---------------------------------------------------------------------------

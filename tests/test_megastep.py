@@ -285,21 +285,6 @@ def test_train_fused_vs_unfused_identical_end_state(tmp_path):
     assert outs["on"]["param_checksum"] == outs["off"]["param_checksum"]
 
 
-def test_fused_bench_phase_and_gate_key_registered():
-    """The BENCH_FUSED wiring exists end to end: bench.py registers the
-    fused phase, and scripts/ci_gate.sh's default keys pin the
-    higher-is-better fused_steps_per_s (SKIP-vs-old-baselines semantics
-    come free from the shared gate machinery)."""
-    import pathlib
-
-    import bench
-
-    assert "fused" in bench._PHASES
-    gate = pathlib.Path(__file__).parent.parent / "scripts" / "ci_gate.sh"
-    text = gate.read_text(encoding="utf-8")
-    assert ",fused_steps_per_s" in text  # no '-' prefix: higher is better
-
-
 def test_train_fused_beat_off_keeps_dispatch_per_phase(tmp_path):
     """fused_beat='off' pins the dispatch-per-phase loop; the summary
     reports the gating fact and no fused_* fields ride the records."""

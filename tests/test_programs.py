@@ -474,20 +474,6 @@ def test_proganalyze_gate_script_skips_without_analyzer(tmp_path):
     assert "SKIP" in proc.stderr
 
 
-@pytest.mark.slow
-def test_ci_gate_programs_prestep_runs_before_usage_check():
-    # `ci_gate.sh --programs` with no candidate: the program gate runs on
-    # the real tree (the wiring pin), then the usage error exits 1 — not
-    # the gate's 2 (the live tree is clean).
-    proc = subprocess.run(
-        ["bash", str(REPO / "scripts" / "ci_gate.sh"), "--programs"],
-        env={"PATH": "/usr/bin:/bin:/usr/local/bin"},
-        cwd=REPO, capture_output=True, text=True, timeout=300,
-    )
-    assert proc.returncode == 1, (proc.stdout, proc.stderr)
-    assert "programs," in proc.stdout  # the analyzer summary ran first
-
-
 def test_changed_only_composes_with_programs_glob(fake_repo, capsys):
     # A glob that matches programs of UNCHANGED modules must say so, not
     # analyze zero programs and read green silently.

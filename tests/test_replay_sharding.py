@@ -5,8 +5,8 @@ Replicated mode is the bit-exact oracle: the sharded placement must land
 the same logical ring (same ptr/size/contents), draw the same sample
 stream from the same key, and produce bit-identical learner chunks —
 while measurably landing ~1/N ingest bytes per row and holding ~1/N
-storage bytes per device (the BENCH_SHARDED_REPLAY claims, asserted here
-against the same measured counters the bench reads)."""
+storage bytes per device (asserted here against the measured
+`replay_*` counters the train records carry)."""
 
 import threading
 
@@ -422,38 +422,8 @@ def test_sharded_beats_submit_as_shard_exchange():
 
 
 # --------------------------------------------------------------------------
-# CI gate + tools.runs rendering
+# tools.runs rendering
 # --------------------------------------------------------------------------
-
-
-def test_ci_gate_replay_bytes_key_semantics():
-    """-replay_ingest_bytes_per_row is lower-is-better, SKIPs against
-    pre-sharded baselines, and FAILS a candidate landing more bytes/row."""
-    from distributed_ddpg_tpu.tools.runs import gate_bench
-
-    keys = ["value", "-replay_ingest_bytes_per_row"]
-    ok, lines = gate_bench(
-        {"value": 100.0},  # old baseline: key absent -> SKIP
-        {"value": 100.0, "replay_ingest_bytes_per_row": 172.0},
-        0.1, keys,
-    )
-    assert ok and any(
-        l.startswith("SKIP replay_ingest_bytes_per_row") for l in lines
-    )
-    ok, lines = gate_bench(
-        {"value": 100.0, "replay_ingest_bytes_per_row": 172.0},
-        {"value": 100.0, "replay_ingest_bytes_per_row": 400.0},
-        0.1, keys,
-    )
-    assert not ok and any(
-        l.startswith("FAIL replay_ingest_bytes_per_row") for l in lines
-    )
-    ok, _ = gate_bench(
-        {"value": 100.0, "replay_ingest_bytes_per_row": 172.0},
-        {"value": 100.0, "replay_ingest_bytes_per_row": 171.0},
-        0.1, keys,
-    )
-    assert ok
 
 
 def test_tools_runs_replay_sharding_digest(tmp_path):

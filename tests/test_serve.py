@@ -415,7 +415,7 @@ def test_config_validation():
 
 
 # ---------------------------------------------------------------------------
-# tools: serve_bench + runs digest + gate keys
+# tools: serve_bench + runs digest
 # ---------------------------------------------------------------------------
 
 
@@ -457,23 +457,6 @@ def test_runs_summarize_and_compare_render_serve_digest(tmp_path):
     assert "serve_p95_ms" in text
     _, rows = runs.compare_runs(str(path), str(path))
     assert any(r[0] == "serve_p95_ms" for r in rows)
-
-
-def test_gate_serve_keys_skip_and_fail_semantics():
-    """The ci_gate serve keys: SKIP against a pre-serve baseline, FAIL a
-    latency regression once a serve-carrying bench is the baseline."""
-    from distributed_ddpg_tpu.tools.runs import gate_bench
-
-    keys = ("-serve_p95_ms", "-serve_queue_depth_p95")
-    ok, lines = gate_bench({"value": 1.0}, {"value": 1.0}, 0.1, keys)
-    assert ok and all("SKIP" in ln for ln in lines)
-    base = {"serve_p95_ms": 5.0, "serve_queue_depth_p95": 4.0}
-    good = {"serve_p95_ms": 5.2, "serve_queue_depth_p95": 4.0}
-    bad = {"serve_p95_ms": 9.0, "serve_queue_depth_p95": 4.0}
-    assert gate_bench(base, good, 0.1, keys)[0]
-    assert not gate_bench(base, bad, 0.1, keys)[0]
-    # A candidate that DROPS the metric the baseline had must fail.
-    assert not gate_bench(base, {"serve_queue_depth_p95": 4.0}, 0.1, keys)[0]
 
 
 # ---------------------------------------------------------------------------

@@ -6,8 +6,7 @@ Pins the PR-20 acceptance contract: the wire framing + typed error codes
 STRICTLY lowest-priority-first overload shedding, versioned snapshots
 with canary promote / gated rollback / re-promote (the tier-1 drill,
 driven by the injected `front:canary:regress` chaos), the SAC serve
-head's per-client sampling parity, and the front_*/tenant_* digest +
-ci_gate key plumbing."""
+head's per-client sampling parity, and the front_*/tenant_* digest."""
 
 import hashlib
 import http.client
@@ -801,7 +800,7 @@ def test_config_front_validation():
 
 
 # ---------------------------------------------------------------------------
-# tools: socket bench + runs digest + gate key
+# tools: socket bench + runs digest
 # ---------------------------------------------------------------------------
 
 
@@ -843,21 +842,6 @@ def test_runs_summarize_and_compare_render_front_digest(tmp_path):
     assert "front_wire_p95_ms" in text
     _, rows = runs.compare_runs(str(path), str(path))
     assert any(r[0] == "front_wire_p95_ms" for r in rows)
-
-
-def test_gate_front_key_skip_and_fail_semantics():
-    """-front_wire_p95_ms: SKIP against pre-front baselines, FAIL a wire
-    latency regression once a socket bench is the baseline."""
-    from distributed_ddpg_tpu.tools.runs import gate_bench
-
-    keys = ("-front_wire_p95_ms",)
-    ok, lines = gate_bench({"value": 1.0}, {"value": 1.0}, 0.1, keys)
-    assert ok and all("SKIP" in ln for ln in lines)
-    base = {"front_wire_p95_ms": 5.0}
-    assert gate_bench(base, {"front_wire_p95_ms": 5.2}, 0.1, keys)[0]
-    assert not gate_bench(base, {"front_wire_p95_ms": 9.0}, 0.1, keys)[0]
-    # Dropping the key the baseline had must FAIL, not skip.
-    assert not gate_bench(base, {"value": 1.0}, 0.1, keys)[0]
 
 
 # ---------------------------------------------------------------------------

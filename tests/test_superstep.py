@@ -17,7 +17,7 @@ docs/FUSED_BEAT.md §superstep):
 - **quarantine mid-superstep**: the chaos vector fires INSIDE the loop,
   the stacked health carry reports WHICH beat went bad
   (first_bad_beat), and the drop semantics match the per-beat path.
-- **config validation** and **train/bench/gate integration**.
+- **config validation** and **train integration**.
 """
 
 import json
@@ -379,17 +379,3 @@ def test_train_superstep_guarded_smoke(tmp_path):
     assert out["fused_beat_active"] is True
     assert out["learner_steps"] > 0
     assert out["guardrail_skipped_updates"] == 0
-
-
-def test_superstep_bench_phase_and_gate_key_registered():
-    """The BENCH_SUPERSTEP wiring exists end to end: bench.py registers
-    the superstep phase, and scripts/ci_gate.sh's default keys pin the
-    higher-is-better superstep_steps_per_s."""
-    import pathlib
-
-    import bench
-
-    assert "superstep" in bench._PHASES
-    gate = pathlib.Path(__file__).parent.parent / "scripts" / "ci_gate.sh"
-    text = gate.read_text(encoding="utf-8")
-    assert ",superstep_steps_per_s" in text  # no '-' prefix: higher is better

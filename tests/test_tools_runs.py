@@ -1,8 +1,8 @@
 """tools.runs CLI tests (the tier-1 smoke the ISSUE's CI satellite asks
 for): summarize + compare over fixture JSONL in the exact schema
-metrics.MetricsLogger emits, and the bench-JSON regression gate — which
-must exit nonzero on a synthetic 20% grad_steps_per_sec regression (the
-PR's acceptance criterion)."""
+metrics.MetricsLogger emits, and the regression gate over two JSON
+objects — which must exit nonzero on a synthetic 20% grad-steps/s
+regression."""
 
 import json
 import subprocess
@@ -162,81 +162,99 @@ def test_compare_flags_regressions(tmp_path, capsys):
 
 
 # --------------------------------------------------------------------------
-# gate (CI): exit nonzero on a synthetic 20% regression
+# gate: two JSON objects (e.g. two result lines of benchmarks/run.py)
+# compared by dotted keys; exit nonzero on a synthetic 20% regression
 # --------------------------------------------------------------------------
 
-def _bench_json(path, value, dispatch_ms=1.0):
+_RATE = "metrics.grad_steps_per_s.value"
+_SETUP = "metrics.setup_s.value"
+
+
+def _result_line(path, rate, setup_s=60.0):
+    """One object in the shape of a benchmarks/run.py result line."""
     path.write_text(json.dumps({
-        "metric": "learner_grad_steps_per_sec",
-        "unit": "grad_steps/s",
-        "value": value,
-        "t_dispatch_ms": dispatch_ms,
-        "ingest_rows_per_sec": 8000.0,
-        "scaling_cpu_virtual": {
-            "scaled_batch": {"8": {"rows_per_sec": value * 64}}
+        "correct": True, "attempted": 1, "failed": 0,
+        "metrics": {
+            "grad_steps_per_s": {"value": rate, "unit": "steps/s"},
+            "setup_s": {"value": setup_s, "unit": "s"},
         },
+        "device": {"platform": "tpu", "kind": "TPU v5 lite", "count": 1},
     }))
 
 
-def test_gate_passes_within_threshold(tmp_path):
-    _bench_json(tmp_path / "base.json", 100.0)
-    _bench_json(tmp_path / "cand.json", 95.0)  # -5% < 10% threshold
-    assert runs.main([
+def _gate(tmp_path, *extra):
+    return runs.main([
         "gate", str(tmp_path / "base.json"), str(tmp_path / "cand.json"),
-    ]) == 0
+        *extra,
+    ])
+
+
+def test_gate_passes_within_threshold(tmp_path):
+    _result_line(tmp_path / "base.json", 100.0)
+    _result_line(tmp_path / "cand.json", 95.0)  # -5% < 10% threshold
+    assert _gate(tmp_path, "--keys", _RATE) == 0
+    # No default key: the comparer knows nothing of what it is handed.
+    with pytest.raises(SystemExit):
+        _gate(tmp_path)
 
 
 def test_gate_fails_on_20pct_grad_steps_regression(tmp_path, capsys):
-    """THE acceptance criterion: a synthetic 20% grad_steps_per_sec
-    (bench 'value') regression must exit nonzero at the default 10%
-    threshold."""
-    _bench_json(tmp_path / "base.json", 100.0)
-    _bench_json(tmp_path / "cand.json", 80.0)
-    rc = runs.main([
-        "gate", str(tmp_path / "base.json"), str(tmp_path / "cand.json"),
-    ])
-    assert rc == 2
+    """A synthetic 20% grad-steps/s regression must exit nonzero at the
+    default 10% threshold."""
+    _result_line(tmp_path / "base.json", 100.0)
+    _result_line(tmp_path / "cand.json", 80.0)
+    assert _gate(tmp_path, "--keys", _RATE) == 2
     out = capsys.readouterr().out
-    assert "FAIL value" in out and "GATE FAIL" in out
+    assert f"FAIL {_RATE}" in out and "GATE FAIL" in out
 
 
 def test_gate_lower_is_better_and_dotted_keys(tmp_path):
-    _bench_json(tmp_path / "base.json", 100.0, dispatch_ms=1.0)
-    _bench_json(tmp_path / "cand.json", 100.0, dispatch_ms=1.5)
-    # dispatch latency +50%: fails only when gated lower-is-better.
-    assert runs.main([
-        "gate", str(tmp_path / "base.json"), str(tmp_path / "cand.json"),
-        "--keys", "value,-t_dispatch_ms",
-    ]) == 2
-    # Dotted path into the scaling curve gates nested values.
-    assert runs.main([
-        "gate", str(tmp_path / "base.json"), str(tmp_path / "cand.json"),
-        "--keys", "scaling_cpu_virtual.scaled_batch.8.rows_per_sec",
-    ]) == 0
+    _result_line(tmp_path / "base.json", 100.0, setup_s=60.0)
+    _result_line(tmp_path / "cand.json", 100.0, setup_s=90.0)
+    # set-up +50%: fails only when gated lower-is-better; the dotted
+    # rate key beside it resolves into the nested object and holds.
+    assert _gate(tmp_path, "--keys", f"{_RATE},-{_SETUP}") == 2
+    assert _gate(tmp_path, "--keys", f"{_RATE},{_SETUP}") == 0
 
 
 def test_gate_missing_candidate_key_fails(tmp_path):
     """A metric that vanished from the candidate must FAIL (a silently
     dropped field reading as healthy is how regressions hide)."""
-    _bench_json(tmp_path / "base.json", 100.0)
-    (tmp_path / "cand.json").write_text(json.dumps({"metric": "x"}))
-    assert runs.main([
-        "gate", str(tmp_path / "base.json"), str(tmp_path / "cand.json"),
-    ]) == 2
+    _result_line(tmp_path / "base.json", 100.0)
+    (tmp_path / "cand.json").write_text(json.dumps({"metrics": {}}))
+    assert _gate(tmp_path, "--keys", _RATE) == 2
 
 
-def test_gate_unwraps_driver_bench_wrapper(tmp_path):
-    """BENCH_r*.json driver records embed the bench JSON in a 'tail'
-    string; gate must read through the wrapper."""
-    inner = {"metric": "x", "unit": "grad_steps/s", "value": 50.0}
-    (tmp_path / "base.json").write_text(json.dumps(
-        {"n": 5, "cmd": "python bench.py", "rc": 0,
-         "tail": "noise | more noise " + json.dumps(inner)}
-    ))
-    _bench_json(tmp_path / "cand.json", 49.0)
-    assert runs.main([
-        "gate", str(tmp_path / "base.json"), str(tmp_path / "cand.json"),
-    ]) == 0
+@pytest.mark.parametrize(
+    "key, base, good, bad, skip_base",
+    [
+        # higher-is-better rate (device actors' rows/s)
+        ("devactor_rows_per_s", 5e5, 5.2e5, 2e5, {}),
+        # lower-is-better bytes landed per ingested row (sharded replay)
+        ("-replay_ingest_bytes_per_row", 172.0, 171.0, 400.0, {}),
+        # two lower-is-better latency tails (serving, network front)
+        ("-serve_p95_ms", 5.0, 5.2, 9.0, {}),
+        ("-front_wire_p95_ms", 5.0, 5.2, 9.0, {}),
+        # A zero baseline on a lower-is-better integer COUNTER is a pin:
+        # any nonzero candidate regressed from "never happened", which no
+        # relative threshold can express. A FLOAT 0.0 baseline is a tail
+        # that saw no samples and must keep SKIPping, not fail the first
+        # candidate that records any.
+        ("-guardrail_rollbacks", 0, 0, 2, {"guardrail_rollbacks": 0.0}),
+    ],
+)
+def test_gate_key_semantics(key, base, good, bad, skip_base):
+    """SKIP when the baseline has nothing to hold the key to; FAIL past
+    the threshold or when the candidate drops the key; pass inside it."""
+    name = key.lstrip("-")
+    ok, lines = runs.gate_objects(skip_base, {name: bad}, 0.1, (key,))
+    assert ok and lines[0].startswith(f"SKIP {name}")
+    ok, lines = runs.gate_objects({name: base}, {name: bad}, 0.1, (key,))
+    assert not ok and lines[0].startswith(f"FAIL {name}")
+    ok, lines = runs.gate_objects({name: base}, {}, 0.1, (key,))
+    assert not ok and lines[0].startswith(f"FAIL {name}: missing")
+    ok, lines = runs.gate_objects({name: base}, {name: good}, 0.1, (key,))
+    assert ok and lines[0].startswith("ok")
 
 
 def test_module_entrypoint_runs_without_jax_import(tmp_path):
