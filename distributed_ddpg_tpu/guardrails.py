@@ -166,17 +166,18 @@ def make_guarded_step(
     warmup: int,
     inject: Optional[Dict[str, Tuple[int, ...]]] = None,
 ):
-    """Wrap a pure learner step (state, batch) -> StepOutput with the
-    health probe. Returns
+    """Wrap a pure learner step (state, batch, noise) -> StepOutput with
+    the health probe. Returns
 
-        guarded(state, gstate, batch, pre_bad) ->
+        guarded(state, gstate, batch, pre_bad, noise=None) ->
             (new_state, new_gstate, td_errors, metrics)
 
     where `pre_bad` is this step's raw-row screen from batch_row_health
     (a scalar bool; pass False when rows were screened elsewhere). The
     update is dropped when the step is bad; the TrainState step counter
     still advances so the fold_in(seed, step) noise streams never
-    re-draw. `inject` maps 'grad'/'loss' to guarded-step ordinals
+    re-draw (and a chunk's pre-drawn `noise`, handed on to the step, stays
+    aligned). `inject` maps 'grad'/'loss' to guarded-step ordinals
     (faults.numeric_steps) and is baked into the traced program — absent
     (the production case) the injection code does not exist."""
     inject = inject or {}
@@ -189,7 +190,7 @@ def make_guarded_step(
             fire = jnp.logical_or(fire, ordinal == jnp.int32(at))
         return fire
 
-    def guarded(state, g: GuardState, batch, pre_bad):
+    def guarded(state, g: GuardState, batch, pre_bad, noise=None):
         ordinal = g.total + 1
         if inject.get("grad"):
             fire = _fires(ordinal, inject["grad"])
@@ -202,7 +203,7 @@ def make_guarded_step(
                 reward=batch.reward * jnp.where(fire, SPIKE_SCALE, 1.0)
             )
 
-        out = step_fn(state, batch)
+        out = step_fn(state, batch, noise)
         m = out.metrics
         closs = m["critic_loss"]
         gnorm = m["critic_grad_norm"]

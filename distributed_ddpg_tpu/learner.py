@@ -88,6 +88,75 @@ def _maybe_psum_mean(tree, axis_name: Optional[str]):
     return jax.lax.pmean(tree, axis_name)
 
 
+# --- the learner's noise streams ---
+# TD3's target-smoothing noise and SAC's sampling noise are keyed by
+# fold_in(seed-derived base, state.step): no key threads through the step
+# signature, the stream is deterministic/replayable, and every data-parallel
+# replica derives the identical key from the replicated state.step, so
+# replicas cannot fork. One definition of each stream: a single step draws
+# its own (`step_noise`), and every chunk program, on the scan leg and the
+# kernel leg alike, pre-draws its K steps' worth in front of its loop
+# (`chunk_noise`) — the same bits, because the draw does not depend on the
+# parameters (SAC: u = mean + std * eps).
+
+
+def draws_noise(config: DDPGConfig) -> bool:
+    """Whether `config`'s learner step draws noise at all."""
+    return bool(
+        config.sac or (config.twin_critic and config.target_noise > 0.0)
+    )
+
+
+def noise_base_key(config: DDPGConfig):
+    """The base key of `config`'s one noise stream (None: it has none)."""
+    if config.sac:
+        return jax.random.PRNGKey(config.seed ^ 0x5AC0)
+    if config.twin_critic:
+        return jax.random.PRNGKey(config.seed ^ 0x7D3AF)
+    return None
+
+
+def step_noise(config: DDPGConfig, base, step, batch: int, act_dim: int,
+               device_fold=None):
+    """The noise of learner step `step`, what make_learner_step's step takes
+    as its third argument. SAC: the standard normals (eps_next, eps_cur),
+    each [B, act], the critic-target draw at s' first, then the actor draw
+    at s. TD3: the target-smoothing noise [B, act], scaled and clipped. None
+    where the algorithm draws none (DDPG, D4PG, TD3 without smoothing).
+    `device_fold` (lax.axis_index under shard_map) folds a per-device term
+    AFTER the step fold, so that each shard of a global batch draws its own
+    rows: without it every shard would draw the identical matrix and a
+    global batch of B*D rows would get only B unique perturbations."""
+    if not draws_noise(config):
+        return None
+    key = jax.random.fold_in(base, step)
+    if device_fold is not None:
+        key = jax.random.fold_in(key, device_fold)
+    if config.sac:
+        k_next, k_cur = jax.random.split(key)
+        return (
+            jax.random.normal(k_next, (batch, act_dim)),
+            jax.random.normal(k_cur, (batch, act_dim)),
+        )
+    return jnp.clip(
+        config.target_noise * jax.random.normal(key, (batch, act_dim)),
+        -config.target_noise_clip,
+        config.target_noise_clip,
+    )
+
+
+def chunk_noise(config: DDPGConfig, step0, chunk: int, batch: int,
+                act_dim: int, device_fold=None):
+    """step_noise for the K steps from `step0`, stacked [K, ...]: drawn once
+    a launch, in front of the loop that scans over it."""
+    if not draws_noise(config):
+        return None
+    base = noise_base_key(config)
+    return jax.vmap(
+        lambda s: step_noise(config, base, s, batch, act_dim, device_fold)
+    )(step0 + jnp.arange(chunk))
+
+
 def init_train_state(config: DDPGConfig, obs_dim: int, act_dim: int, seed: int) -> TrainState:
     """Build initial params + hard-copied targets (SURVEY.md §3.4) + Adam state."""
     key = jax.random.PRNGKey(seed)
@@ -171,9 +240,13 @@ def make_learner_step(
     axis_name: Optional[str] = None,
     action_offset=0.0,
 ):
-    """Returns the pure (state, batch) -> StepOutput function. Not jitted here:
-    callers wrap it in jit-with-shardings, shard_map, or call it under
-    interpretation for tests (parallel/learner.py owns device placement)."""
+    """Returns the pure (state, batch, noise=None) -> StepOutput function.
+    Not jitted here: callers wrap it in jit-with-shardings, shard_map, or call
+    it under interpretation for tests (parallel/learner.py owns device
+    placement). `noise` is the step's own slice of chunk_noise (SAC: the pair
+    (eps_next, eps_cur); TD3: the scaled and clipped smoothing noise), which
+    every chunk program draws once before its scan; a single step passes
+    none and draws the same bits itself."""
     ail = config.action_insert_layer
     scale = jnp.asarray(action_scale, jnp.float32)
     offset = jnp.asarray(action_offset, jnp.float32)
@@ -187,37 +260,29 @@ def make_learner_step(
         if config.distributional
         else None
     )
-    # TD3 target-smoothing noise: keyed by fold_in(seed-derived base, step)
-    # — no key threads through the step signature, the stream is
-    # deterministic/replayable, and every data-parallel replica derives the
-    # identical key (replicated state.step), so replicas cannot fork.
-    td3_base_key = (
-        jax.random.PRNGKey(config.seed ^ 0x7D3AF)
-        if config.twin_critic
-        else None
-    )
-    # SAC sampling noise: same fold_in(base, step) discipline as TD3 —
-    # deterministic, replayable, replica-identical (then axis-folded per
-    # shard so a global batch gets globally-unique draws).
-    sac_base_key = (
-        jax.random.PRNGKey(config.seed ^ 0x5AC0) if config.sac else None
-    )
+    # Made here, so that a step that draws for itself holds it as a constant.
+    base_key = noise_base_key(config)
 
-    def sac_step(state: TrainState, batch: Batch) -> StepOutput:
+    def own_noise(state: TrainState, batch: Batch):
+        """What a step handed no noise draws for itself; under shard_map
+        (explicit mode) each shard for its OWN batch slice."""
+        return step_noise(
+            config, base_key, state.step, *batch.action.shape,
+            None if axis_name is None else jax.lax.axis_index(axis_name),
+        )
+
+    def sac_step(state: TrainState, batch: Batch, noise=None) -> StepOutput:
         """SAC: entropy-regularized twin-critic TD + reparameterized actor
         + (optionally) the learned temperature. Kept as its own body — the
         actor loss carries an aux (mean log-prob -> alpha update) that the
         shared branch structure below has no slot for."""
-        key = jax.random.fold_in(sac_base_key, state.step)
-        if axis_name is not None:
-            key = jax.random.fold_in(key, jax.lax.axis_index(axis_name))
-        k_next, k_cur = jax.random.split(key)
+        eps_next, eps_cur = own_noise(state, batch) if noise is None else noise
         alpha = jnp.exp(state.log_alpha)
 
         def critic_loss_fn(cp):
             return losses.sac_critic_loss(
                 cp, state.actor_params, state.target_critic_params, batch,
-                scale, k_next, alpha,
+                scale, eps_next, alpha,
                 config.sac_log_std_min, config.sac_log_std_max,
                 ail, config.critic_l2, offset, mm,
             )
@@ -230,7 +295,7 @@ def make_learner_step(
         # Actor gradient against the pre-update critic (file convention).
         def actor_loss_fn(ap):
             return losses.sac_actor_loss(
-                ap, state.critic_params, batch, scale, k_cur, alpha,
+                ap, state.critic_params, batch, scale, eps_cur, alpha,
                 config.sac_log_std_min, config.sac_log_std_max,
                 ail, offset, mm,
             )
@@ -309,18 +374,11 @@ def make_learner_step(
     if config.sac:
         return sac_step
 
-    def step(state: TrainState, batch: Batch) -> StepOutput:
+    def step(state: TrainState, batch: Batch, noise=None) -> StepOutput:
         # --- critic update ---
         if config.twin_critic:
-            noise_key = jax.random.fold_in(td3_base_key, state.step)
-            if axis_name is not None:
-                # Explicit shard_map mode: each shard smooths its OWN batch
-                # slice — without this fold every shard would draw the
-                # identical eps matrix and a global batch of B*D rows would
-                # get only B unique perturbations.
-                noise_key = jax.random.fold_in(
-                    noise_key, jax.lax.axis_index(axis_name)
-                )
+            if noise is None:
+                noise = own_noise(state, batch)
 
             def critic_loss_fn(cp):
                 return losses.td3_critic_loss(
@@ -329,9 +387,7 @@ def make_learner_step(
                     state.target_critic_params,
                     batch,
                     scale,
-                    noise_key,
-                    config.target_noise,
-                    config.target_noise_clip,
+                    noise,
                     ail,
                     config.critic_l2,
                     offset,
@@ -554,7 +610,9 @@ def make_sample_fn(config: DDPGConfig, action_scale, action_offset=0.0):
         mean, log_std = actor_gaussian_apply(
             actor_params, obs, config.sac_log_std_min, config.sac_log_std_max
         )
-        action, _ = losses_lib.sac_sample(mean, log_std, key, scale, offset)
+        action, _ = losses_lib.sac_sample(
+            mean, log_std, jax.random.normal(key, mean.shape), scale, offset
+        )
         return action
 
     return sample

@@ -67,7 +67,7 @@ def vector_env_step(
             params, obs, cfg.sac_log_std_min, cfg.sac_log_std_max
         )
         sampled, _ = losses_lib.sac_sample(
-            mean, log_std, k_ou, scale, offset
+            mean, log_std, jax.random.normal(k_ou, mean.shape), scale, offset
         )
         action = jnp.clip(sampled, low, high)
         new_ou = ou

@@ -36,12 +36,13 @@ for the actor's expected-value head).
 SAC (ops/losses.py sac_critic_loss / sac_actor_loss semantics) runs in the
 same kernel too: the Gaussian head's [mean | log_std] split, the tanh
 soft-clamp of log_std, reparameterized sampling (the per-step standard
-normals stream in pre-drawn from the scan path's exact fold_in key stream,
-like TD3's smoothing noise), the tanh-squash log-prob, the entropy-
-corrected twin-critic TD target, and the learned temperature's scalar Adam
-all execute in-kernel; the hand-written actor backward routes the min-Q
-gate with reduce_min's tie-splitting vjp and chains d(log pi)/du =
-2*scale*t*(1-t^2)/g through the squash correction.
+normals stream in pre-drawn by learner.chunk_noise, the one helper both
+legs' chunks draw their launch's noise from, like TD3's smoothing noise),
+the tanh-squash log-prob, the entropy-corrected twin-critic TD target, and
+the learned temperature's scalar Adam all execute in-kernel; the
+hand-written actor backward routes the min-Q gate with reduce_min's
+tie-splitting vjp and chains d(log pi)/du = 2*scale*t*(1-t^2)/g through the
+squash correction.
 
 Mixed precision (config.compute_dtype='bfloat16') casts matmul operands to
 bf16 with f32 accumulation (`preferred_element_type`), forward AND backward,
@@ -67,6 +68,7 @@ from jax.experimental.pallas import tpu as pltpu
 import math
 
 from distributed_ddpg_tpu.config import DDPGConfig
+from distributed_ddpg_tpu.learner import chunk_noise, metric_keys
 from distributed_ddpg_tpu.ops.optim import B1, B2, EPS
 from distributed_ddpg_tpu.types import TrainState, OptState
 
@@ -796,68 +798,6 @@ def runs_native() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def td3_noise_base_key(config: DDPGConfig):
-    """The TD3 smoothing-noise base key. MUST stay identical to
-    learner.make_learner_step's td3_base_key — the kernel wrapper and the
-    fused-mesh path pre-draw from this stream to stay bit-comparable with
-    the scan path."""
-    return jax.random.PRNGKey(config.seed ^ 0x7D3AF)
-
-
-def td3_noise_eps(config: DDPGConfig, step0, chunk: int, batch: int,
-                  act_dim: int, device_fold=None):
-    """Pre-draw a chunk's target-smoothing noise [K, B, act], scaled and
-    clipped, from fold_in(base, global_step) — the scan path's exact
-    stream. `device_fold` (e.g. lax.axis_index under shard_map) folds a
-    per-device term AFTER the step fold, matching the scan path's
-    axis_name handling so sharded chunks draw iid noise per replica."""
-    base = td3_noise_base_key(config)
-    keys = jax.vmap(lambda s_: jax.random.fold_in(base, s_))(
-        step0 + jnp.arange(chunk)
-    )
-    if device_fold is not None:
-        keys = jax.vmap(lambda kk: jax.random.fold_in(kk, device_fold))(keys)
-    return jax.vmap(
-        lambda kk: jnp.clip(
-            config.target_noise * jax.random.normal(kk, (batch, act_dim)),
-            -config.target_noise_clip,
-            config.target_noise_clip,
-        )
-    )(keys)
-
-
-def sac_noise_base_key(config: DDPGConfig):
-    """The SAC sampling-noise base key. MUST stay identical to
-    learner.make_learner_step's sac_base_key for bit-comparability."""
-    return jax.random.PRNGKey(config.seed ^ 0x5AC0)
-
-
-def sac_noise_eps(config: DDPGConfig, step0, chunk: int, batch: int,
-                  act_dim: int, device_fold=None):
-    """Pre-draw a chunk's SAC standard normals: (eps_next, eps_cur), each
-    [K, B, act], from the scan path's exact stream — key =
-    fold_in(base, global_step) (then the device fold, mirroring the
-    axis_name fold in learner.sac_step), split into the critic-target draw
-    and the actor draw, `normal(key, (B, act))` each. Because
-    u = mean + std*eps with eps independent of params, streaming the
-    pre-drawn eps is exactly equivalent to sampling inside the step."""
-    base = sac_noise_base_key(config)
-    keys = jax.vmap(lambda s_: jax.random.fold_in(base, s_))(
-        step0 + jnp.arange(chunk)
-    )
-    if device_fold is not None:
-        keys = jax.vmap(lambda kk: jax.random.fold_in(kk, device_fold))(keys)
-
-    def draw(kk):
-        k_next, k_cur = jax.random.split(kk)
-        return (
-            jax.random.normal(k_next, (batch, act_dim)),
-            jax.random.normal(k_cur, (batch, act_dim)),
-        )
-
-    return jax.vmap(draw)(keys)
-
-
 def make_fused_chunk_fn(
     config: DDPGConfig,
     obs_dim: int,
@@ -912,8 +852,6 @@ def make_fused_chunk_fn(
     else:
         tgt_h = None
 
-    from distributed_ddpg_tpu.learner import metric_keys
-
     keys = metric_keys(config)
 
     def run(state: TrainState, batches, eps=None):
@@ -949,17 +887,14 @@ def make_fused_chunk_fn(
                     state.alpha_opt.nu.reshape(1, 1),
                 ]
 
-        if has_noise and eps is None:
-            # Pre-draw the whole chunk's smoothing noise [K, B, act] from
-            # the scan path's exact key stream (fold_in per global step),
-            # pre-scaled and pre-clipped; it streams into the kernel like
-            # the minibatches (~KB per step). Callers with a device axis
-            # (fused-mesh) pass their own axis-folded eps instead.
-            eps = td3_noise_eps(config, state.step, K, B, a)
-        elif sac and eps is None:
-            # SAC: (eps_next, eps_cur) standard-normal streams, same
-            # fold_in discipline (sac_noise_eps docstring).
-            eps = sac_noise_eps(config, state.step, K, B, a)
+        if eps is None:
+            # The whole chunk's noise [K, B, act] (TD3: smoothing noise,
+            # pre-scaled and pre-clipped; SAC: the (eps_next, eps_cur)
+            # standard normals), from the stream the scan leg's chunks
+            # pre-draw from too (learner.chunk_noise); it streams into the
+            # kernel like the minibatches (~KB per step). Callers with a
+            # device axis (fused-mesh) pass their own axis-folded eps instead.
+            eps = chunk_noise(config, state.step, K, B, a)
         elif not (has_noise or sac):
             eps = None
 

@@ -81,18 +81,17 @@ def td3_critic_loss(
     target_critic_params,
     batch: Batch,
     action_scale,
-    noise_key,
-    noise_std: float,
-    noise_clip: float,
+    noise=None,
     action_insert_layer: int = 1,
     l2: float = 0.0,
     action_offset=0.0,
     mm_dtype=None,
 ):
     """Clipped double-Q TD loss: min-over-ensemble Bellman target with
-    target-policy smoothing. `critic_params` leaves carry a leading
-    ensemble axis of 2 (learner.init_train_state stacks them); the apply
-    is vmapped over it — one batched program on the MXU, not two
+    target-policy smoothing by `noise` (f32[B, act], already scaled and
+    clipped: learner.step_noise; None smooths nothing). `critic_params`
+    leaves carry a leading ensemble axis of 2 (learner.init_train_state
+    stacks them); the apply is vmapped over it — one batched program on the MXU, not two
     sequential critics. Loss is the MEAN of the two critics' weighted
     MSEs (lr-invariant vs the sum the paper writes), plus `l2` weight
     decay over both ensemble members (matching critic_loss). Returns
@@ -101,15 +100,10 @@ def td3_critic_loss(
     next_action = actor_apply(
         target_actor_params, batch.next_obs, action_scale, action_offset, mm_dtype
     )
-    if noise_std > 0.0:
-        eps = jnp.clip(
-            noise_std * jax.random.normal(noise_key, next_action.shape),
-            -noise_clip,
-            noise_clip,
-        )
+    if noise is not None:
         lo = action_offset - action_scale
         hi = action_offset + action_scale
-        next_action = jnp.clip(next_action + eps, lo, hi)
+        next_action = jnp.clip(next_action + noise, lo, hi)
     ensemble = lambda p, o, a: jax.vmap(
         lambda cp: critic_apply(cp, o, a, action_insert_layer, mm_dtype)
     )(p)
@@ -150,8 +144,9 @@ def td3_actor_loss(
 _TANH_EPS = 1e-6
 
 
-def sac_sample(mean, log_std, key, action_scale, action_offset=0.0):
-    """Reparameterized tanh-Gaussian sample mapped onto the action box.
+def sac_sample(mean, log_std, eps, action_scale, action_offset=0.0):
+    """Reparameterized tanh-Gaussian sample mapped onto the action box, from
+    the standard normals `eps` (mean's shape; the draw is the caller's).
 
     Returns (action[B, A], log_prob[B]). log_prob folds the standard tanh
     change-of-variables correction PLUS the box scaling's -log(scale) per
@@ -161,7 +156,7 @@ def sac_sample(mean, log_std, key, action_scale, action_offset=0.0):
     `log_std` (reparameterization); callers stop-gradient where the
     pathwise term is unwanted."""
     std = jnp.exp(log_std)
-    u = mean + std * jax.random.normal(key, mean.shape)
+    u = mean + std * eps
     tanh_u = jnp.tanh(u)
     action = tanh_u * action_scale + action_offset
     # N(u; mean, std) log-density, summed over action dims.
@@ -180,7 +175,7 @@ def sac_critic_loss(
     target_critic_params,
     batch: Batch,
     action_scale,
-    key,
+    eps,
     alpha,
     log_std_min: float,
     log_std_max: float,
@@ -191,7 +186,8 @@ def sac_critic_loss(
 ):
     """Entropy-regularized clipped double-Q TD loss:
     y = r + discount * (min_i Q'_i(s', a') - alpha * log pi(a'|s')),
-    a' ~ pi(.|s') drawn from the CURRENT actor (SAC has no target actor).
+    a' ~ pi(.|s') drawn from the CURRENT actor (SAC has no target actor)
+    with the standard normals `eps` (f32[B, act]).
     `critic_params` leaves carry the same leading ensemble axis of 2 as
     TD3's (learner.init_train_state). Returns (loss, td_proxy[B]) with the
     ensemble-mean TD error as the PER priority proxy."""
@@ -200,7 +196,7 @@ def sac_critic_loss(
     mean, log_std = actor_gaussian_apply(
         actor_params, batch.next_obs, log_std_min, log_std_max, mm_dtype
     )
-    next_action, next_lp = sac_sample(mean, log_std, key, action_scale, action_offset)
+    next_action, next_lp = sac_sample(mean, log_std, eps, action_scale, action_offset)
     ensemble = lambda p, o, a: jax.vmap(
         lambda cp: critic_apply(cp, o, a, action_insert_layer, mm_dtype)
     )(p)
@@ -224,7 +220,7 @@ def sac_actor_loss(
     critic_params,
     batch: Batch,
     action_scale,
-    key,
+    eps,
     alpha,
     log_std_min: float,
     log_std_max: float,
@@ -232,7 +228,8 @@ def sac_actor_loss(
     action_offset=0.0,
     mm_dtype=None,
 ):
-    """Reparameterized actor objective E[alpha * log pi(a|s) - min_i Q_i(s, a)].
+    """Reparameterized actor objective E[alpha * log pi(a|s) - min_i Q_i(s, a)],
+    a drawn with the standard normals `eps` (f32[B, act]).
 
     Unlike TD3 (critic 0 only), SAC minimizes against the ensemble MIN —
     the 1812.05905 convention. Returns (loss, mean_log_prob) — the aux
@@ -242,7 +239,7 @@ def sac_actor_loss(
     mean, log_std = actor_gaussian_apply(
         actor_params, batch.obs, log_std_min, log_std_max, mm_dtype
     )
-    action, lp = sac_sample(mean, log_std, key, action_scale, action_offset)
+    action, lp = sac_sample(mean, log_std, eps, action_scale, action_offset)
     q = jnp.min(
         jax.vmap(
             lambda cp: critic_apply(cp, batch.obs, action, action_insert_layer, mm_dtype)

@@ -36,6 +36,8 @@ from distributed_ddpg_tpu.config import DDPGConfig
 from distributed_ddpg_tpu.learner import (
     StepOutput,
     chunk_metrics,
+    chunk_noise,
+    draws_noise,
     init_train_state,
     make_learner_step,
     metric_keys,
@@ -73,6 +75,20 @@ def resolve_learner_chunk(config: DDPGConfig) -> int:
     from distributed_ddpg_tpu.ops.fused_chunk import runs_native
 
     return 800 if runs_native() else 8
+
+
+def scan_chunk(step, s: TrainState, batches: Batch, noise, unroll: int):
+    """K steps of `step` in one lax.scan over a [K, B, ...] Batch pytree and
+    the chunk's pre-drawn `noise` (learner.chunk_noise; None, an empty
+    pytree, where the algorithm draws none: the scan's operands are then
+    the batches alone), metrics reduced over the chunk."""
+
+    def body(carry, x):
+        out = step(carry, *x)
+        return out.state, (out.td_errors, out.metrics)
+
+    s, (tds, ms) = jax.lax.scan(body, s, (batches, noise), unroll=unroll)
+    return StepOutput(state=s, td_errors=tds, metrics=chunk_metrics(ms))
 
 
 class ShardedLearner:
@@ -229,17 +245,20 @@ class ShardedLearner:
             state_spec = mesh_lib.state_pspec(state, self.mesh)
             bspec = mesh_lib.batch_pspec()
 
-            def step(s: TrainState, b: Batch) -> StepOutput:
+            def step(s: TrainState, b: Batch, noise=None) -> StepOutput:
+                # `noise` (a scan chunk's, below) rides sharded like the
+                # batch: each shard takes the rows it drew.
+                noise_spec = jax.tree.map(lambda _: P("data", None), noise)
                 return mesh_lib.shard_map(
                     inner,
                     mesh=self.mesh,
-                    in_specs=(state_spec, bspec),
+                    in_specs=(state_spec, bspec, noise_spec),
                     out_specs=StepOutput(
                         state=state_spec,
                         td_errors=P("data"),
                         metrics={k: P() for k in keys},
                     ),
-                )(s, b)
+                )(s, b, noise)
 
         replicated = NamedSharding(self.mesh, P())
         td_sharding = NamedSharding(self.mesh, P("data"))
@@ -258,19 +277,40 @@ class ShardedLearner:
             donate_argnums=(0,),
         )
 
-        # Shared scan body: one step over a [K, B, ...] Batch pytree, metrics
-        # reduced over the chunk (used by both the host-fed and the
-        # fused-sampling chunk paths).
-        def scan_steps(s: TrainState, batches: Batch) -> StepOutput:
-            def body(carry, b):
-                out = step(carry, b)
-                return out.state, (out.td_errors, out.metrics)
+        # The launch's noise, drawn ONCE before the scan from the stream a
+        # single step draws from (learner.chunk_noise: the same bits) and
+        # scanned over beside the batches, so no threefry runs inside the
+        # K-update loop; None (an empty pytree: the scan's operands are
+        # the batches alone) where the algorithm draws none. Inside the
+        # chunk's own jitted program: the launch pays for its draw.
+        def draw_chunk_noise(s: TrainState, batches: Batch):
+            if not draws_noise(config):
+                return None
+            K, B, _ = batches.action.shape
+            if mode == "auto":
+                # On a mesh each chip draws its own rows of the global
+                # [K, B, act]; with the partitionable threefry the values
+                # do not depend on the sharding.
+                return jax.tree.map(
+                    lambda x: jax.lax.with_sharding_constraint(
+                        x, self._chunk_sharding
+                    ),
+                    chunk_noise(config, s.step, K, B, act_dim),
+                )
+            # Explicit mode folds the shard's index into the key, as the
+            # step under shard_map does when it draws for itself.
+            return mesh_lib.shard_map(
+                lambda step0: chunk_noise(
+                    config, step0, K, B // self.data_size, act_dim,
+                    device_fold=jax.lax.axis_index("data"),
+                ),
+                mesh=self.mesh, in_specs=P(), out_specs=P(None, "data", None),
+            )(s.step)
 
-            s, (tds, ms) = jax.lax.scan(body, s, batches, unroll=self.unroll)
-            return StepOutput(
-                state=s,
-                td_errors=tds,
-                metrics=chunk_metrics(ms),
+        # The shared chunk body of the host-fed and the fused-sampling paths.
+        def scan_steps(s: TrainState, batches: Batch) -> StepOutput:
+            return scan_chunk(
+                step, s, batches, draw_chunk_noise(s, batches), self.unroll
             )
 
         # K-steps-per-dispatch scan over host-fed packed batches.
@@ -585,14 +625,16 @@ class ShardedLearner:
             )
 
             def guarded_scan(s, g, batches, pre_bad):
+                # A dropped update still advances state.step, so the
+                # pre-drawn noise stays aligned with the steps.
                 def body(carry, x):
-                    cs, cg = carry
-                    b, pb = x
-                    ns, ng, td, ms = gstep(cs, cg, b, pb)
+                    ns, ng, td, ms = gstep(*carry, *x)
                     return (ns, ng), (td, ms)
 
                 (s, g), (tds, ms) = jax.lax.scan(
-                    body, (s, g), (batches, pre_bad), unroll=self.unroll
+                    body, (s, g),
+                    (batches, pre_bad, draw_chunk_noise(s, batches)),
+                    unroll=self.unroll,
                 )
                 return StepOutput(
                     state=s,
@@ -770,30 +812,19 @@ class ShardedLearner:
         state_spec = mesh_lib.state_pspec(self.state, mesh)
         keys = metric_keys(self.config)
 
-        twin_noise = self.config.twin_critic and self.config.target_noise > 0
-        sac = self.config.sac
-
         def local_chunk(s, sub, storage, size):
             axis_idx = jax.lax.axis_index("data")
             dkey = jax.random.fold_in(sub, axis_idx)
             idx = jax.random.randint(
                 dkey, (K, b_local), 0, jnp.maximum(size, 1)
             )
-            eps = None
-            if twin_noise:
-                # Per-device iid smoothing noise: the scan path's
-                # fold_in(seed, step) stream with the device index folded
-                # on top (mirrors make_learner_step's axis_name handling).
-                eps = fused_chunk_lib.td3_noise_eps(
-                    self.config, s.step, K, b_local, self.act_dim,
-                    device_fold=axis_idx,
-                )
-            elif sac:
-                # Same discipline for SAC's two sampling streams.
-                eps = fused_chunk_lib.sac_noise_eps(
-                    self.config, s.step, K, b_local, self.act_dim,
-                    device_fold=axis_idx,
-                )
+            # Per-device iid noise: the fold_in(seed, step) stream with the
+            # device index folded on top, as the step under shard_map
+            # folds it (learner.chunk_noise).
+            eps = chunk_noise(
+                self.config, s.step, K, b_local, self.act_dim,
+                device_fold=axis_idx,
+            )
             new_s, tds, ms = run_fused(s, storage[idx], eps=eps)
             avg = lambda x: jax.lax.pmean(x, "data")
             favg = lambda tree: jax.tree.map(avg, tree)

@@ -349,3 +349,83 @@ def test_v5e_megakernel_chunk_fits_scoped_vmem(v5e_sharding, monkeypatch, name, 
     batches = jax.ShapeDtypeStruct((chunk, cfg.batch_size, width), jnp.float32, sharding=replicated)
     compiled = jax.jit(run).lower(state, batches).compile()  # raises what the chip's compiler would
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# --- the scan leg's chunk, compiled for the same described v5e: the
+# launch's noise is drawn before the 800-update loop, so the while body the
+# chip runs holds no threefry (tests/test_learner_noise.py holds the same of
+# every chunk program's jaxpr, and the bits). ---
+
+
+def _computations(hlo_text):
+    """{computation: its instruction lines} of an optimised HLO module."""
+    comps, cur = {}, None
+    for line in hlo_text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$", line)
+        if head:
+            cur = comps.setdefault(head.group(1), [])
+        elif line.startswith("}"):
+            cur = None
+        elif cur is not None and " = " in line:
+            cur.append(line)
+    return comps
+
+
+def _called_from(comps, name):
+    """The computations `name`'s instructions call, and theirs."""
+    seen, stack = set(), [name]
+    while stack:
+        for line in comps.get(stack.pop(), []):
+            for callee in re.findall(r"(?:calls|to_apply|body|condition)=%?([\w.\-]+)", line):
+                if callee not in seen:
+                    seen.add(callee)
+                    stack.append(callee)
+    return seen
+
+
+def test_v5e_scan_chunk_draws_its_noise_before_the_loop(v5e_sharding):
+    """`sac-humanoid`'s scan chunk at the configuration's own sizes (batch
+    256, obs 376, act 17, K 800, unroll 4), built as ShardedLearner's
+    scan_steps builds it (scan_chunk over learner.chunk_noise). With the draw
+    in the step the body was 1,539 instructions, 106 of them `xor` and 80
+    `shift-left` of key arithmetic, and its two sampling fusions held a
+    threefry each, 335 and 512 instructions."""
+    import json
+    import os
+
+    from distributed_ddpg_tpu import learner as learner_lib
+    from distributed_ddpg_tpu.config import DDPGConfig
+    from distributed_ddpg_tpu.parallel.learner import scan_chunk
+    from distributed_ddpg_tpu.types import unpack_batch
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    conf = json.load(open(os.path.join(root, "benchmarks", "configs", "sac-humanoid.json")))
+    cfg = DDPGConfig.from_flags([f for f in conf["flags"] if not f.startswith("--replay_capacity")])
+    env, chunk = conf["env"], 800
+    obs, act = env["obs_dim"], env["act_dim"]
+    assert (cfg.batch_size, obs, act) == (256, 376, 17) and cfg.sac
+    step = learner_lib.make_learner_step(cfg, env["action_scale"], action_offset=env["action_offset"])
+
+    def run(s, packed):
+        noise = learner_lib.chunk_noise(cfg, s.step, chunk, cfg.batch_size, act)
+        return scan_chunk(step, s, unpack_batch(packed, obs, act), noise, unroll=4)
+
+    replicated = NamedSharding(v5e_sharding.mesh, P())
+    state = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=replicated),
+        jax.eval_shape(lambda: learner_lib.init_train_state(cfg, obs, act, 0)),
+    )
+    packed = jax.ShapeDtypeStruct((chunk, cfg.batch_size, 2 * obs + act + 3), jnp.float32, sharding=replicated)
+    text = jax.jit(run, donate_argnums=(0,)).lower(state, packed).compile().as_text()
+
+    comps = _computations(text)
+    whiles = [line for lines in comps.values() for line in lines if re.search(r"\bwhile\(", line)]
+    assert len(whiles) == 1
+    body = re.search(r"body=%?([\w.\-]+)", whiles[0]).group(1)
+    assert 500 < len(comps[body]) < 1100
+    assert not [line for line in comps[body] if re.search(r"\b(xor|shift-left)\(", line)]
+    drawn = re.compile(r'op_name="[^"]*(threefry|_normal)')
+    inside = [body, *_called_from(comps, body)]
+    assert not [line for name in inside for line in comps[name] if drawn.search(line)]
+    # The draw is in the program all the same, in front of the loop.
+    assert [line for line in text.splitlines() if drawn.search(line)]

@@ -69,8 +69,8 @@ def test_sac_log_prob_matches_torch_oracle():
     log_std = rng.uniform(-2.0, 0.5, (B, ACT)).astype(np.float32)
     scale, offset = 1.7, 0.3
     action, lp = losses.sac_sample(
-        jnp.asarray(mean), jnp.asarray(log_std), jax.random.PRNGKey(1),
-        scale, offset,
+        jnp.asarray(mean), jnp.asarray(log_std),
+        jax.random.normal(jax.random.PRNGKey(1), mean.shape), scale, offset,
     )
     dist = torch.distributions.TransformedDistribution(
         torch.distributions.Normal(
@@ -96,9 +96,9 @@ def test_sac_entropy_target_in_env_units():
     rng = np.random.default_rng(2)
     mean = jnp.asarray(rng.standard_normal((B, ACT)), jnp.float32)
     log_std = jnp.asarray(rng.uniform(-1, 0, (B, ACT)), jnp.float32)
-    k = jax.random.PRNGKey(3)
-    _, lp1 = losses.sac_sample(mean, log_std, k, 1.0)
-    _, lp4 = losses.sac_sample(mean, log_std, k, 4.0)
+    eps = jax.random.normal(jax.random.PRNGKey(3), mean.shape)
+    _, lp1 = losses.sac_sample(mean, log_std, eps, 1.0)
+    _, lp4 = losses.sac_sample(mean, log_std, eps, 4.0)
     # Exact up to the _TANH_EPS regularizer inside log(scale*(1-t^2)+eps).
     np.testing.assert_allclose(
         np.asarray(lp4), np.asarray(lp1) - ACT * np.log(4.0), atol=1e-4
@@ -117,11 +117,11 @@ def test_sac_min_over_ensemble_target():
     target_critic = tuple(biased)
 
     batch = _batch(np.random.default_rng(0))
-    key = jax.random.PRNGKey(0)
+    eps = jax.random.normal(jax.random.PRNGKey(0), (B, ACT))
     alpha = 0.2
     _, td = losses.sac_critic_loss(
         s.critic_params, s.actor_params, target_critic, batch,
-        1.0, key, alpha, cfg.sac_log_std_min, cfg.sac_log_std_max,
+        1.0, eps, alpha, cfg.sac_log_std_min, cfg.sac_log_std_max,
     )
     from distributed_ddpg_tpu.models.mlp import (
         actor_gaussian_apply,
@@ -131,7 +131,7 @@ def test_sac_min_over_ensemble_target():
     mean, log_std = actor_gaussian_apply(
         s.actor_params, batch.next_obs, cfg.sac_log_std_min, cfg.sac_log_std_max
     )
-    na, nlp = losses.sac_sample(mean, log_std, key, 1.0)
+    na, nlp = losses.sac_sample(mean, log_std, eps, 1.0)
     q0 = critic_apply(
         jax.tree.map(lambda x: x[0], target_critic), batch.next_obs, na, 1
     )
@@ -163,7 +163,8 @@ def test_sac_alpha_autotune_direction_and_determinism():
     key = jax.random.fold_in(jax.random.PRNGKey(cfg.seed ^ 0x5AC0), s.step)
     _, k_cur = jax.random.split(key)
     _, mean_lp = losses.sac_actor_loss(
-        s.actor_params, s.critic_params, batch, 1.0, k_cur,
+        s.actor_params, s.critic_params, batch, 1.0,
+        jax.random.normal(k_cur, (B, ACT)),
         float(jnp.exp(s.log_alpha)), cfg.sac_log_std_min, cfg.sac_log_std_max,
     )
     tgt_h = -float(ACT)

@@ -60,10 +60,8 @@ def test_min_over_ensemble_target():
     target_critic = tuple(biased)
 
     batch = _batch(np.random.default_rng(0))
-    key = jax.random.PRNGKey(0)
     _, td = losses.td3_critic_loss(
-        s.critic_params, s.target_actor_params, target_critic, batch,
-        1.0, key, 0.0, 0.5,
+        s.critic_params, s.target_actor_params, target_critic, batch, 1.0,
     )
     # Hand-compute y from member 0 only (the min, since member 1 is +100).
     from distributed_ddpg_tpu.models.mlp import actor_apply, critic_apply
