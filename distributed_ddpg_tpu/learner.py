@@ -59,24 +59,42 @@ def metric_keys(config: DDPGConfig) -> tuple:
     """The exact keys of StepOutput.metrics for `config`'s family, in the
     order the megakernel stacks them. The categorical (D4PG) branch reports
     `c51_edge_mass` besides: the share of the projected target's mass on the
-    support's two end atoms, the number a user sets v_min / v_max by; a
-    chunk reports its last update's (chunk_metrics). Only that branch has
-    the key, so every other family's programs and records are what they
-    were."""
+    support's two end atoms, the number a user sets v_min / v_max by. The
+    twin-critic (TD3) branch reports `td3_twin_gap` besides: the batch mean
+    of |Q'_1 - Q'_2| at the smoothed target action, how much the clipped
+    minimum bites. A chunk reports its last update's of either
+    (chunk_metrics). Only those branches have the keys, so every other
+    family's programs and records are what they were."""
     if config.distributional:  # config.py: never with twin_critic or sac
         return METRIC_KEYS + ("c51_edge_mass",)
+    if config.twin_critic:
+        return METRIC_KEYS + ("td3_twin_gap",)
     return METRIC_KEYS
+
+
+# Metrics a chunk reports for its LAST update, not as a mean over the K.
+LAST_UPDATE_KEYS = ("c51_edge_mass", "td3_twin_gap")
 
 
 def chunk_metrics(ms: dict) -> dict:
     """[K]-stacked per-update metrics of a scan chunk -> the chunk's: each
-    key's mean over the K updates, except `c51_edge_mass`, which is the
-    chunk's last update's, as the megakernel computes it on its last grid
+    key's mean over the K updates, except LAST_UPDATE_KEYS, which are the
+    chunk's last update's, as the megakernel computes them on its last grid
     step only. For every other family this is the tree.map it replaces."""
     out = jax.tree.map(lambda x: jnp.mean(x), ms)
-    if "c51_edge_mass" in ms:
-        out["c51_edge_mass"] = ms["c51_edge_mass"][-1]
+    for k in LAST_UPDATE_KEYS:
+        if k in ms:
+            out[k] = ms[k][-1]
     return out
+
+
+def delayed_updates(steps, delay: int):
+    """How many of the learner steps 0 .. steps-1 move the TD3 actor and the
+    targets: the multiples of `delay` below `steps`. The one rule behind the
+    scan step's cond (state.step % delay == 0), the kernel's schedule and
+    actor Adam count, and the record's `td3_actor_updates`. Works on ints
+    and on traced scalars."""
+    return (steps + delay - 1) // delay
 
 
 def _maybe_psum_mean(tree, axis_name: Optional[str]):
@@ -423,9 +441,9 @@ def make_learner_step(
         (closs, td), cgrads = jax.value_and_grad(critic_loss_fn, has_aux=True)(
             state.critic_params
         )
-        c51_metrics = ()
-        if config.distributional:
-            td, *c51_metrics = td  # (td, edge_mass)
+        branch_metrics = ()
+        if config.distributional or config.twin_critic:
+            td, *branch_metrics = td  # (td, edge_mass) / (td, twin_gap)
         cgrads = _maybe_psum_mean(cgrads, axis_name)
 
         # --- actor update (pre-update critic: both grads from the same state) ---
@@ -538,7 +556,7 @@ def make_learner_step(
                     jnp.mean(jnp.abs(td)),
                     optree_norm(cgrads),
                     actor_grad_norm,
-                    *c51_metrics,
+                    *branch_metrics,
                 ),
             )
         )

@@ -68,7 +68,7 @@ from jax.experimental.pallas import tpu as pltpu
 import math
 
 from distributed_ddpg_tpu.config import DDPGConfig
-from distributed_ddpg_tpu.learner import chunk_noise, metric_keys
+from distributed_ddpg_tpu.learner import chunk_noise, delayed_updates, metric_keys
 from distributed_ddpg_tpu.ops.optim import B1, B2, EPS
 from distributed_ddpg_tpu.types import TrainState, OptState
 
@@ -133,8 +133,9 @@ def _unflatten_twin(flat: Sequence[Any], like) -> Tuple:
 
 def state_vmem_bytes(config: DDPGConfig, obs_dim: int, act_dim: int) -> int:
     """f32 bytes of the kernel's VMEM-resident state: 8 copies of each net's
-    tensors (params, targets, mu, nu for actor+critic). The pipeline holds
-    input AND output blocks for each, so callers should budget ~2x this."""
+    tensors (params, targets, mu, nu for actor+critic), unpadded. What
+    Mosaic allocates is this about once plus temporaries that grow with the
+    batch: the table above VMEM_STATE_BUDGET has the measured pairs."""
 
     def net(dims, extra_in=0):
         total = 0
@@ -157,23 +158,36 @@ def state_vmem_bytes(config: DDPGConfig, obs_dim: int, act_dim: int) -> int:
     return 4 * (4 * a + 4 * c)
 
 
-# Conservative VMEM budget for the resident state (of ~16 MB/core): leaves
-# room for the doubled in/out blocks, batch stream buffers, and activations.
-# What has met the compiler (TPU v5e, PR 21): at 2x256, obs 17 / act 6,
-# chunk 800, every family — DDPG 2.2 MiB of state, C51 2.4, TD3 3.3, SAC
-# 3.3, and bf16 — compiles under Mosaic's default scoped-VMEM limit with no
-# vmem_limit_bytes. PR 27: C51 at batch 256 x 51 atoms (the batch's blocks
-# 256 x 43 floats a step) compiles and runs through train() under the same
-# default limit, no vmem_limit_bytes, at 2x256 (2,511,760 B of state, 2.4
-# MiB: 10.4 ms of kernel for 800 updates) and at 400-300, widths that are
-# no multiple of 128 lanes (4,383,312 B, 4.2 MiB: 14.0 ms). That last one
-# takes 14.58 MiB of the default limit's 16 (3.5 x state: in and out blocks
-# of every tensor, double-buffered); the same kernel with the edge mass
-# computed on every grid step takes 16.12 and is REFUSED (compiled for a
-# described v5e, tests/test_ring_layout.py keeps the guard). So this budget
-# is not reachable under the default limit: past about 4.4 MiB of state a
-# net needs vmem_limit_bytes, derived from state_vmem_bytes() (17 MiB lets
-# the 16.12 through), rather than a budget lowered by guesswork.
+# VMEM budget for the resident state. It decides the leg (fits_vmem); it is
+# not what Mosaic counts. What has met the compiler (libtpu 0.0.34), as the
+# smallest vmem_limit_bytes each kernel compiles under for a described v5e,
+# bisected to 1/16 MiB (PR 31; obs 17 / act 6, chunk 800; Mosaic's default
+# limit is 16 MiB and no caller passes another):
+#
+#   family, widths, batch        state_vmem_bytes   scoped VMEM
+#   DDPG  2x256    64            2.20 MiB            3.51 MiB
+#   TD3   2x256    64            3.30                4.08
+#   SAC   2x256   256            3.32                5.57
+#   C51   2x256   256            2.40               13.19
+#   C51   400-300 100            4.18                8.01
+#   C51   400-300 256            4.18               14.60
+#   TD3   400-300 100            5.93                9.23
+#   TD3   400-300 256            5.93               15.40
+#
+# So the scoped allocation is the state about once plus the body's
+# temporaries, and those grow with the batch (C51 and TD3 at 400-300: 42 KiB
+# a row) and with what the branch keeps alive (C51's unrolled projection
+# most), not with the state: PR 27 read 14.58 MiB against 4.18 MiB of state
+# as "3.5 x state" from that one point, and the same C51 at 2x256 takes 13.19
+# with 2.40. All four benchmark configurations run through train() under the
+# default (DDPG 2x256 batch 64, C51 400-300 batch 256, TD3 400-300 batch 100;
+# SAC at Humanoid's 376 / 17 is over this budget and takes the scan leg). The
+# C51 kernel with its edge mass computed on every grid step takes 16.12 and
+# is REFUSED (tests/test_ring_layout.py keeps the guard and these compiles).
+# What would be refused today though fits_vmem says yes: a batch past about
+# 270 rows at 400-300 on the C51 or TD3 branch. The repair then is
+# vmem_limit_bytes (17 MiB lets the 16.12 through), set from a fit to the
+# table above, batch term first; no configuration anyone runs needs it.
 VMEM_STATE_BUDGET = 6 * 1024 * 1024
 
 
@@ -603,6 +617,17 @@ def _make_kernel(
             qt0, _ = critic_fwd(cm(t_critic_o, 0), nobs, na)
             qt1, _ = critic_fwd(cm(t_critic_o, 1), nobs, na)
             y = rew + disc * jnp.minimum(qt0, qt1)
+            # td3_twin_gap (learner.metric_keys): how far the two targets
+            # lie apart, batch mean, of the launch's LAST update only
+            # (learner.chunk_metrics). A sum over [B, 1] on every grid step
+            # and a select, not c51_edge_mass's cond: on the chip the cond
+            # cost this branch 1.5% of the launch, the select 0.2% (PR 31).
+            # emit() scales every slot by 1/K, hence the K here.
+            twin_gap = jnp.where(
+                k == chunk - 1,
+                jnp.sum(jnp.abs(qt0 - qt1)) * (inv_b * float(chunk)),
+                0.0,
+            )
             q0, acts0 = critic_fwd(cm(critic_o, 0), obs, action)
             q1_, acts1 = critic_fwd(cm(critic_o, 1), obs, action)
             td0 = y - q0
@@ -728,9 +753,10 @@ def _make_kernel(
             # nets step on the TD3 delay schedule (matches the scan path's
             # lax.cond at state.step % delay == 0, with state.step = step0
             # + k pre-increment). Actor Adam bias correction follows the
-            # number of REAL actor updates: with f(n) = ceil(n / delay)
-            # counting multiples of delay below n, updates inside the chunk
-            # before grid step k number f(step0+k) - f(step0).
+            # number of REAL actor updates: learner.delayed_updates(n) counts
+            # the multiples of delay below n, so the updates inside the
+            # chunk before grid step k number its difference between
+            # step0 + k and step0.
             c_t = (count_ref[1] + k + 1).astype(jnp.float32)
             adam_only(nc2, cm(critic_o, 0), cm(cmu_o, 0), cm(cnu_o, 0),
                       c_grads0, lr_c, c_t)
@@ -739,11 +765,9 @@ def _make_kernel(
             step0 = count_ref[2]
             do_update = ((step0 + k) % policy_delay) == 0
 
-            def f_updates(n):
-                return (n + policy_delay - 1) // policy_delay
-
             a_t = (
-                count_ref[0] + f_updates(step0 + k) - f_updates(step0) + 1
+                count_ref[0] + delayed_updates(step0 + k, policy_delay)
+                - delayed_updates(step0, policy_delay) + 1
             ).astype(jnp.float32)
 
             @pl.when(do_update)
@@ -760,7 +784,7 @@ def _make_kernel(
         # ---- outputs -----------------------------------------------------
         # Order must match learner.metric_keys(config); the wrapper sizes
         # the metric block from its length and emit() asserts this stack
-        # agrees (6 scalars, 7 on the C51 branch).
+        # agrees (6 scalars, 7 on the C51 and twin-critic branches).
         # The chunk MEAN is accumulated in-kernel into a (1, 6) output whose
         # block IS the whole array (constant index map) — a per-step (K, 6)
         # output would need a (1, 6) block over K rows, which violates
@@ -784,7 +808,8 @@ def _make_kernel(
                 jnp.sqrt(_sq(c_grads)),
                 a_norm,
             ]
-            + ([edge_mass] if distributional else []),
+            + ([edge_mass] if distributional else [])
+            + ([twin_gap] if twin else []),
         )
 
     return kernel
@@ -998,8 +1023,9 @@ def make_fused_chunk_fn(
             # Actor count advances only on real updates: multiples of
             # policy_delay in [step0, step0 + K).
             d = config.policy_delay
-            f = lambda n: (n + d - 1) // d  # noqa: E731
-            a_inc = f(state.step + K) - f(state.step)
+            a_inc = delayed_updates(state.step + K, d) - delayed_updates(
+                state.step, d
+            )
         else:
             a_inc = K
         new_state = TrainState(

@@ -95,8 +95,10 @@ def td3_critic_loss(
     sequential critics. Loss is the MEAN of the two critics' weighted
     MSEs (lr-invariant vs the sum the paper writes), plus `l2` weight
     decay over both ensemble members (matching critic_loss). Returns
-    (loss, td_proxy[B]) where the proxy is the ensemble-mean TD error
-    (PER priorities)."""
+    (loss, (td_proxy[B], twin_gap)) where the proxy is the ensemble-mean TD
+    error (PER priorities) and twin_gap the batch mean of |Q'_1 - Q'_2| at
+    the target action (the `td3_twin_gap` metric: how much the clipped
+    minimum bites)."""
     next_action = actor_apply(
         target_actor_params, batch.next_obs, action_scale, action_offset, mm_dtype
     )
@@ -116,7 +118,8 @@ def td3_critic_loss(
         loss = loss + l2 * sum(
             jnp.sum(jnp.square(layer["w"])) for layer in critic_params
         )
-    return loss, jnp.mean(td, axis=0)
+    twin_gap = jnp.mean(jnp.abs(next_q[0] - next_q[1]))
+    return loss, (jnp.mean(td, axis=0), twin_gap)
 
 
 def td3_actor_loss(

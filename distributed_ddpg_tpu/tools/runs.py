@@ -151,6 +151,9 @@ KEY_METRICS = (
     # Categorical (D4PG) runs only: the projected target's mass on the
     # support's two end atoms (how much v_min / v_max clip).
     "c51_edge_mass",
+    # Twin-critic (TD3) runs only: how far apart the two target critics lie
+    # where the target takes their minimum.
+    "td3_twin_gap",
 )
 
 # Cumulative recovery counters (train.py recovery_fields; docs/RESILIENCE.md)

@@ -499,6 +499,7 @@ def _train_jax_impl(
     from distributed_ddpg_tpu import checkpoint as ckpt_lib
     from distributed_ddpg_tpu.actors.policy import NumpyPolicy, actor_head_dim, flatten_params, param_layout
     from distributed_ddpg_tpu.actors.pool import ActorPool
+    from distributed_ddpg_tpu.learner import delayed_updates
     from distributed_ddpg_tpu.parallel import multihost
     from distributed_ddpg_tpu.parallel.learner import (
         ShardedLearner,
@@ -1528,6 +1529,22 @@ def _train_jax_impl(
         dispatch-per-phase loop."""
         return megastep.snapshot() if megastep is not None else {}
 
+    def td3_fields() -> Dict[str, int]:
+        """`td3_actor_updates` beside `learner_steps` on every train/final
+        record of a twin-critic run (docs/OBSERVABILITY.md): how many of
+        the learner's updates moved the actor and the targets, cumulative
+        from step 0. Host arithmetic on the step count the branch already
+        carries (learner.delayed_updates, the rule the step's cond, the
+        kernel's schedule and the actor's Adam count follow): no update
+        pays for it. No other family's records have the key."""
+        if not config.twin_critic:
+            return {}
+        return {
+            "td3_actor_updates": delayed_updates(
+                learn_steps, config.policy_delay
+            )
+        }
+
     mesh_stats = MeshStats(
         learner.mesh.shape["data"], learner.mesh.shape["model"]
     )
@@ -2179,6 +2196,7 @@ def _train_jax_impl(
             log.log(
                 "train", env_steps(),
                 learner_steps=learn_steps,
+                **td3_fields(),
                 learner_steps_per_sec=learn_timer.rate(),
                 actor_steps_per_sec=env_timer.rate(),
                 buffer_fill=buffer_fill(),
@@ -2798,6 +2816,7 @@ def _train_jax_impl(
     log.log(
         "final", env_steps(),
         learner_steps=learn_steps,
+        **td3_fields(),
         learner_steps_per_sec=rate,
         final_return=final_return,
         **facts_final,
@@ -2823,6 +2842,7 @@ def _train_jax_impl(
     return {
         "learner_steps_per_sec": rate,
         "learner_steps": learn_steps,
+        **td3_fields(),
         "final_return": final_return,
         "param_checksum": _param_checksum(learner.actor_params_to_host()),
         # The same sum over the params the run STARTED from (fresh init

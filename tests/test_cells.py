@@ -5,7 +5,10 @@ are data the driver runs on the TPU after a PR exits; nothing else in tier-1
 reads them. So a PR that deletes a flag a cell passes, moves the kernel's
 envelope, or changes what a ring row costs would learn it from the driver's
 chip run. One case per `workloads` entry: a cell added to the file is
-covered by itself (its ring layout has to be entered below).
+covered by itself (its ring layout has to be entered below). Two cells may
+share a configuration's file (`sac-humanoid.free.x4` runs `sac-humanoid`
+under a traffic file of its own, on four chips): each is a case, so the
+shared file is held twice and the cell's own traffic file once.
 
 Read only: this file edits none of what it reads and imports nothing under
 `benchmarks/`.
@@ -32,6 +35,7 @@ RING_LAYOUT = {
     "ddpg-halfcheetah": "packed",
     "d4pg-halfcheetah": "packed",
     "sac-humanoid": "row_major",
+    "td3-halfcheetah": "packed",
 }
 
 

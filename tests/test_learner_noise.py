@@ -119,7 +119,12 @@ def _k_single_steps(ref, rows):
         ref.state = out.state
         tds.append(np.asarray(out.td_errors))
         ms.append(jax.device_get(out.metrics))
-    metrics = {k: np.mean([m[k] for m in ms]) for k in ms[0]}
+    # as learner.chunk_metrics: means, but the last update's td3_twin_gap
+    metrics = {
+        k: ms[-1][k] if k in learner_lib.LAST_UPDATE_KEYS
+        else np.mean([m[k] for m in ms])
+        for k in ms[0]
+    }
     return jax.device_get(ref.state), np.stack(tds), metrics
 
 

@@ -60,9 +60,10 @@ def test_min_over_ensemble_target():
     target_critic = tuple(biased)
 
     batch = _batch(np.random.default_rng(0))
-    _, td = losses.td3_critic_loss(
+    _, (td, gap) = losses.td3_critic_loss(
         s.critic_params, s.target_actor_params, target_critic, batch, 1.0,
     )
+    assert float(gap) == pytest.approx(100.0, abs=0.1)  # td3_twin_gap: the members' offset
     # Hand-compute y from member 0 only (the min, since member 1 is +100).
     from distributed_ddpg_tpu.models.mlp import actor_apply, critic_apply
 
