@@ -26,12 +26,15 @@ kernel to the XLA scan path over a whole chunk.
 
 D4PG (C51, ops/losses.py:111-160 semantics) runs in the same kernel: the
 critic head emits num_atoms logits, the categorical projection is computed
-in-kernel as an unrolled accumulation over atoms — proj += p'[:, i:i+1] *
-relu(1 - |tz[:, i:i+1] - z|/dz), the triangular-kernel form of the
-lower/upper-neighbor mass split, rank-2 throughout so Mosaic never sees a
-3D tensor — and the hand-written backward uses the closed-form categorical
-cotangents (softmax(logits) - proj for the critic CE; -p * (z - E[Z]) / B
-for the actor's expected-value head).
+in-kernel as an unrolled accumulation over the source atoms in the
+triangular-kernel form of the lower/upper-neighbor mass split,
+proj^T += p'^T[i] * relu(1 - |tz^T[i] - z|/dz), with ATOMS ON SUBLANES AND
+BATCH ON LANES so that row i spreads over the sublanes on the VPU
+(kernel_projection says what the other orientation costs), one transpose in
+and one out, rank-2 throughout so Mosaic never sees a 3D tensor; reward and
+discount stream in lane-major, [K, 2, B], for it. The hand-written backward
+uses the closed-form categorical cotangents (softmax(logits) - proj for the
+critic CE; -p * (z - E[Z]) / B for the actor's expected-value head).
 
 SAC (ops/losses.py sac_critic_loss / sac_actor_loss semantics) runs in the
 same kernel too: the Gaussian head's [mean | log_std] split, the tanh
@@ -161,33 +164,34 @@ def state_vmem_bytes(config: DDPGConfig, obs_dim: int, act_dim: int) -> int:
 # VMEM budget for the resident state. It decides the leg (fits_vmem); it is
 # not what Mosaic counts. What has met the compiler (libtpu 0.0.34), as the
 # smallest vmem_limit_bytes each kernel compiles under for a described v5e,
-# bisected to 1/16 MiB (PR 31; obs 17 / act 6, chunk 800; Mosaic's default
-# limit is 16 MiB and no caller passes another):
+# bisected to 1/16 MiB (PRs 31 and 32; obs 17 / act 6, chunk 800; Mosaic's
+# default limit is 16 MiB and no caller passes another):
 #
 #   family, widths, batch        state_vmem_bytes   scoped VMEM
 #   DDPG  2x256    64            2.20 MiB            3.51 MiB
 #   TD3   2x256    64            3.30                4.08
 #   SAC   2x256   256            3.32                5.57
-#   C51   2x256   256            2.40               13.19
-#   C51   400-300 100            4.18                8.01
-#   C51   400-300 256            4.18               14.60
+#   C51   2x256   256            2.40                5.25
+#   C51   400-300 100            4.18                5.31
+#   C51   400-300 256            4.18                7.88
 #   TD3   400-300 100            5.93                9.23
 #   TD3   400-300 256            5.93               15.40
 #
 # So the scoped allocation is the state about once plus the body's
-# temporaries, and those grow with the batch (C51 and TD3 at 400-300: 42 KiB
-# a row) and with what the branch keeps alive (C51's unrolled projection
-# most), not with the state: PR 27 read 14.58 MiB against 4.18 MiB of state
-# as "3.5 x state" from that one point, and the same C51 at 2x256 takes 13.19
-# with 2.40. All four benchmark configurations run through train() under the
-# default (DDPG 2x256 batch 64, C51 400-300 batch 256, TD3 400-300 batch 100;
-# SAC at Humanoid's 376 / 17 is over this budget and takes the scan leg). The
-# C51 kernel with its edge mass computed on every grid step takes 16.12 and
-# is REFUSED (tests/test_ring_layout.py keeps the guard and these compiles).
-# What would be refused today though fits_vmem says yes: a batch past about
-# 270 rows at 400-300 on the C51 or TD3 branch. The repair then is
-# vmem_limit_bytes (17 MiB lets the 16.12 through), set from a fit to the
-# table above, batch term first; no configuration anyone runs needs it.
+# temporaries, and those grow with the batch (at 400-300: TD3 42 KiB a row,
+# C51 17) and with what the branch keeps alive, not with the state. A loop
+# that spills shows here first: with the projection's operands batch-on-
+# sublanes the C51 rows read 13.19 / 8.01 / 14.60 (kernel_projection). All
+# four benchmark configurations run through train() under the default (DDPG
+# 2x256 batch 64, C51 400-300 batch 256, TD3 400-300 batch 100; SAC at
+# Humanoid's 376 / 17 is over this budget and takes the scan leg);
+# tests/test_ring_layout.py compiles the cells' kernels, each under the
+# default and under its own figure plus a MiB. The C51 kernel with its edge
+# mass summed on every grid step and selected, as td3_twin_gap is, takes
+# 7.75. What would be refused today though fits_vmem says yes: a batch past
+# about 270 rows at 400-300 on the TD3 branch. The repair then is
+# vmem_limit_bytes, set from a fit to the table above, batch term first; no
+# configuration anyone runs needs it.
 VMEM_STATE_BUDGET = 6 * 1024 * 1024
 
 
@@ -215,6 +219,36 @@ def _sq(tree_leaves) -> Any:
     return sum(jnp.sum(x * x) for x in tree_leaves)
 
 
+def kernel_projection(p_t, rew_row, disc_row, z_col, v_min, v_max):
+    """The C51 branch's projection of the Bellman-shifted target distribution
+    onto the support: p_t [B, A] (target softmax), rew_row / disc_row [1, B]
+    (lane-major), z_col [A, 1] (the support down the sublanes) -> [B, A].
+
+    proj[b, j] = sum_i p_t[b, i] * relu(1 - |tz[b, i] - z_j| / dz), summed
+    over the source atom i in order: the triangular kernel IS the lower/upper-
+    neighbour mass split of the classic projection (exact also when tz lands
+    on an atom: weight 1 there, 0 elsewhere; a clipped row puts its mass on
+    the end atom). The loop's operands lie with ATOMS ON SUBLANES AND BATCH ON
+    LANES: row i of tz and of p_t^T spreads over the sublanes (a VPU sublane
+    select, shared by every sublane tile of the atoms), where [B, A] operands
+    need column i of each spread over 128 lanes, an XLU permute a row tile,
+    twice an atom (3,264 an update at batch 256 and 51 atoms, 36% of the
+    kernel's bundles: PERF.md, PR 32). One transpose in, one out; the same
+    float32 VPU ops on the same operands in the same order, so the same bits."""
+    num_atoms = z_col.shape[0]
+    dz_atom = (v_max - v_min) / (num_atoms - 1)
+    p_T = p_t.T  # [A, B]
+    z_b = jnp.broadcast_to(z_col, p_T.shape)
+    tz_T = jnp.clip(rew_row + disc_row * z_b, v_min, v_max)  # row i: atom i
+    proj_T = jnp.zeros_like(p_T)
+    for i in range(num_atoms):
+        tri = jnp.maximum(
+            0.0, 1.0 - jnp.abs(tz_T[i : i + 1, :] - z_b) / dz_atom
+        )
+        proj_T = proj_T + p_T[i : i + 1, :] * tri
+    return proj_T.T
+
+
 def _make_kernel(
     n_actor: int, n_critic: int, batch: int, chunk: int, config,
     sac_target_entropy: float | None = None,
@@ -231,7 +265,6 @@ def _make_kernel(
     distributional = bool(config.distributional)
     num_atoms = int(config.num_atoms)
     v_min, v_max = float(config.v_min), float(config.v_max)
-    dz_atom = (v_max - v_min) / (num_atoms - 1)
     twin = bool(config.twin_critic)
     policy_delay = int(config.policy_delay)
     has_noise = twin and config.target_noise > 0.0
@@ -279,9 +312,17 @@ def _make_kernel(
             return [refs[next(it)] for _ in range(n)]
 
         (count_ref,) = take(1)
-        obs_r, act_r, rew_r, disc_r, nobs_r, wgt_r, scale_r, off_r = take(8)
         if distributional:
-            (z_ref,) = take(1)  # categorical support, (1, num_atoms)
+            # Reward and discount ride lane-major, one (2, B) block an update
+            # (kernel_projection reads them as rows), and the support
+            # twice: (1, num_atoms) along the lanes, (num_atoms, 1) down the
+            # sublanes.
+            obs_r, act_r, rd_r, nobs_r, wgt_r, scale_r, off_r = take(7)
+            z_ref, zc_ref = take(2)
+        else:
+            obs_r, act_r, rew_r, disc_r, nobs_r, wgt_r, scale_r, off_r = (
+                take(8)
+            )
         if has_noise:
             (eps_r,) = take(1)  # target-smoothing noise stream, [K, B, act]
         if sac:
@@ -335,8 +376,9 @@ def _make_kernel(
 
         obs = obs_r[0]
         action = act_r[0]
-        rew = rew_r[0]
-        disc = disc_r[0]
+        if not distributional:
+            rew = rew_r[0]
+            disc = disc_r[0]
         nobs = nobs_r[0]
         wgt = wgt_r[0]
         scale = scale_r[...]
@@ -658,20 +700,12 @@ def _make_kernel(
             m_t = jnp.max(q_t, axis=-1, keepdims=True)
             e_t = jnp.exp(q_t - m_t)
             p_t = e_t / jnp.sum(e_t, axis=-1, keepdims=True)
-            # Projection of the Bellman-shifted target distribution onto
-            # the support, accumulated atom-by-atom (unrolled, rank-2):
-            # the triangular kernel relu(1 - |tz_i - z_j|/dz) IS the
-            # lower/upper-neighbor mass split of the classic projection
-            # (exact also when tz lands on an atom: weight 1 there, 0
-            # elsewhere). proj is constant w.r.t. online params — the
-            # target path carries no gradient, so forward-only is enough.
-            tz = jnp.clip(rew + disc * z, v_min, v_max)  # [B, A]
-            proj = jnp.zeros_like(q)
-            for i in range(num_atoms):
-                tri = jnp.maximum(
-                    0.0, 1.0 - jnp.abs(tz[:, i : i + 1] - z) / dz_atom
-                )
-                proj = proj + p_t[:, i : i + 1] * tri
+            # proj is constant w.r.t. online params — the target path
+            # carries no gradient, so forward-only is enough.
+            rd = rd_r[0]  # (2, B): reward row, discount row
+            proj = kernel_projection(
+                p_t, rd[0:1, :], rd[1:2, :], zc_ref[...], v_min, v_max
+            )
             m_q = jnp.max(q, axis=-1, keepdims=True)
             e_q = jnp.exp(q - m_q)
             sum_q = jnp.sum(e_q, axis=-1, keepdims=True)
@@ -859,13 +893,15 @@ def make_fused_chunk_fn(
     offset = jnp.broadcast_to(
         jnp.asarray(action_offset, jnp.float32), (1, a)
     )
-    z_row = (
-        jnp.linspace(
+    # The categorical support, twice: (1, A) along the lanes, (A, 1) down the
+    # sublanes (kernel_projection).
+    if config.distributional:
+        z = jnp.linspace(
             config.v_min, config.v_max, config.num_atoms, dtype=jnp.float32
-        ).reshape(1, -1)
-        if config.distributional
-        else None
-    )
+        )
+        support_args = (z.reshape(1, -1), z.reshape(-1, 1))
+    else:
+        support_args = ()
     twin = bool(config.twin_critic)
     has_noise = twin and config.target_noise > 0.0
     sac = bool(config.sac)
@@ -886,10 +922,18 @@ def make_fused_chunk_fn(
 
         obs = batches[..., :o]
         act = batches[..., o : o + a]
-        rew = batches[..., o + a : o + a + 1]
-        disc = batches[..., o + a + 1 : o + a + 2]
+        if config.distributional:
+            # The categorical branch takes reward and discount lane-major,
+            # [K, 2, B], cut from the same gathered rows.
+            rew_disc = (jnp.swapaxes(batches[..., o + a : o + a + 2], 1, 2),)
+        else:
+            rew_disc = (
+                batches[..., o + a : o + a + 1],
+                batches[..., o + a + 1 : o + a + 2],
+            )
         nobs = batches[..., o + a + 2 : 2 * o + a + 2]
         wgt = batches[..., 2 * o + a + 2 : 2 * o + a + 3]
+        streams = (obs, act, *rew_disc, nobs, wgt)
 
         flat_c = _flatten_twin if (twin or sac) else _flatten
         state_flat = (
@@ -923,9 +967,16 @@ def make_fused_chunk_fn(
         elif not (has_noise or sac):
             eps = None
 
-        def stream_spec(d):
+        if sac:
+            eps_args = tuple(eps)  # (eps_next, eps_cur)
+        else:
+            eps_args = (eps,) if eps is not None else ()
+
+        def stream_spec(arr):
+            # One update's block of a [K, ...] stream.
             return pl.BlockSpec(
-                (1, B, d), lambda k: (k, 0, 0), memory_space=pltpu.VMEM
+                (1, *arr.shape[1:]), lambda k: (k, 0, 0),
+                memory_space=pltpu.VMEM,
             )
 
         def pinned_spec(arr):
@@ -936,15 +987,9 @@ def make_fused_chunk_fn(
 
         in_specs = (
             [pl.BlockSpec(memory_space=pltpu.SMEM)]
-            + [stream_spec(o), stream_spec(a), stream_spec(1), stream_spec(1),
-               stream_spec(o), stream_spec(1)]
-            + [pinned_spec(scale), pinned_spec(offset)]
-            + ([pinned_spec(z_row)] if z_row is not None else [])
-            + (
-                [stream_spec(a), stream_spec(a)]
-                if sac
-                else ([stream_spec(a)] if eps is not None else [])
-            )
+            + [stream_spec(x) for x in streams]
+            + [pinned_spec(x) for x in (scale, offset, *support_args)]
+            + [stream_spec(x) for x in eps_args]
             + [pinned_spec(x) for x in state_flat]
         )
         out_specs = (
@@ -977,11 +1022,6 @@ def make_fused_chunk_fn(
         if autotune:
             counts.append(state.alpha_opt.count)
         count0 = jnp.stack(counts).astype(jnp.int32)
-        support_args = (z_row,) if z_row is not None else ()
-        if sac:
-            eps_args = tuple(eps)  # (eps_next, eps_cur)
-        else:
-            eps_args = (eps,) if eps is not None else ()
         outs = pl.pallas_call(
             kernel,
             grid=(K,),
@@ -990,7 +1030,7 @@ def make_fused_chunk_fn(
             out_shape=out_shape,
             interpret=interp,
         )(
-            count0, obs, act, rew, disc, nobs, wgt, scale, offset,
+            count0, *streams, scale, offset,
             *support_args, *eps_args, *state_flat,
         )
 

@@ -377,3 +377,65 @@ def test_fused_chunk_sac_step_offset_continuity():
     )
     _assert_tree_close(fused.actor_params, ref.actor_params, rtol=5e-4, atol=1e-5)
     assert int(fused.step) == int(ref.step) == 6
+
+
+def _spreads(jaxpr, found):
+    """(operand shape, result shape) wherever an equation's operand has fewer
+    elements than its result: broadcast_in_dim, and the elementwise ops jnp
+    hands a size-1 axis to (it leaves that broadcast to the lowering)."""
+    for eqn in jaxpr.eqns:
+        out = tuple(eqn.outvars[0].aval.shape) if eqn.outvars else None
+        if out and eqn.primitive.name not in ("dot_general", "concatenate", "pallas_call"):
+            for v in eqn.invars:
+                shape = tuple(getattr(v.aval, "shape", ()))
+                if len(shape) == len(out) == 2 and shape != out and all(a in (1, b) for a, b in zip(shape, out)):
+                    found.append((shape, out))
+        for val in eqn.params.values():
+            for sub in val if isinstance(val, (list, tuple)) else (val,):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    _spreads(inner, found)
+    return found
+
+
+# the target's softmax 2, the online one's 3, the loss's weights 1, the
+# actor pass's softmax and expected value 3: at 21 atoms as at 51
+COLUMN_SPREADS_OUTSIDE_THE_LOOP = 9
+
+
+@pytest.mark.parametrize("atoms", [21, 51])
+def test_c51_kernel_body_spreads_no_column_over_the_lanes_per_atom(atoms):
+    """The projection's loop over atoms spreads no [B, 1] column over [B, A]:
+    on the TPU that is an XLU permute a row tile, and with batch on sublanes
+    the loop made two an atom (102 at 51 atoms; 3,264 permutes an update at
+    batch 256). With atoms on sublanes it spreads ROWS, [1, B] over [A, B],
+    two an atom, and what is left of column spreads in the whole kernel body
+    (the three softmaxes' max and sum, the weights) does not grow with the
+    atoms."""
+    import jax.numpy as jnp
+
+    from distributed_ddpg_tpu.learner import init_train_state
+
+    cfg = DDPGConfig(
+        actor_hidden=(32, 32), critic_hidden=(32, 32), batch_size=B,
+        distributional=True, num_atoms=atoms, v_min=-5.0, v_max=5.0, seed=3,
+    )
+    column, row = ((B, 1), (B, atoms)), ((1, B), (atoms, B))
+    z_col = jnp.zeros((atoms, 1))
+    alone = _spreads(
+        jax.make_jaxpr(
+            lambda p, r: fused_chunk.kernel_projection(p, r, r, z_col, -5.0, 5.0)
+        )(jnp.zeros((B, atoms)), jnp.zeros((1, B))).jaxpr,
+        [],
+    )
+    assert alone.count(column) == 0
+    assert alone.count(row) == 2 * atoms + 2  # the loop's, and tz from its two rows
+
+    run = fused_chunk.make_fused_chunk_fn(cfg, OBS, ACT, 1.0, chunk_size=2, interpret=True)
+    state = init_train_state(cfg, OBS, ACT, seed=3)
+    packed = jnp.asarray(_batches(np.random.default_rng(0), 2))
+    eqns = [e for e in jax.make_jaxpr(run)(state, packed).jaxpr.eqns if e.primitive.name == "pallas_call"]
+    assert len(eqns) == 1
+    body = _spreads(eqns[0].params["jaxpr"], [])
+    assert body.count(row) == 2 * atoms + 2
+    assert body.count(column) == COLUMN_SPREADS_OUTSIDE_THE_LOOP

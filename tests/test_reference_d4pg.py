@@ -189,6 +189,75 @@ def test_projection_against_the_dense_form(d4pg):
     assert top[-1] == pytest.approx(1.0, abs=1e-6) and float(jnp.sum(top[:-1])) == pytest.approx(0.0, abs=1e-6)
 
 
+# --- the megakernel's projection (ops/fused_chunk.kernel_projection: atoms on
+# sublanes, batch on lanes, one transpose in and one out), run as a Pallas
+# kernel in interpret mode, against the scan leg's floor/ceil form
+# (ops/losses.py) and the reference's dense one. Cases counted singly. ---
+
+KINDS = ("returns", "on_an_atom", "below_v_min", "above_v_max", "terminal")
+
+
+def projection_rows(kind, batch, z):
+    """(ret, disc) [B]: what a ring of 5-step rows holds, and its corners."""
+    atoms = z.shape[0]
+    k = jax.random.split(jax.random.PRNGKey(batch + atoms), 3)
+    ret = 40.0 * jax.random.normal(k[0], (batch,))
+    disc = jnp.full((batch,), 0.99**5)
+    if kind == "on_an_atom":  # d = 0 and R = z_k: weight 1 on atom k, 0 elsewhere
+        ret = z[jax.random.randint(k[1], (batch,), 0, atoms)]
+        disc = jnp.zeros((batch,))
+    elif kind == "below_v_min":  # every atom clips to v_min: what c51_edge_mass reads
+        ret = jnp.full((batch,), -1e4)
+    elif kind == "above_v_max":
+        ret = jnp.full((batch,), 1e4)
+    elif kind == "terminal":  # d = 0 rows among the others: all mass round R
+        disc = disc * (jax.random.uniform(k[2], (batch,)) > 0.5)
+    return ret.astype(jnp.float32), disc.astype(jnp.float32)
+
+
+@pytest.mark.parametrize("kind", KINDS)
+@pytest.mark.parametrize("atoms", [51, 21])
+@pytest.mark.parametrize("batch", [64, 100, 256])
+def test_kernel_projection_against_both_dense_forms(d4pg, batch, atoms, kind):
+    from jax.experimental import pallas as pl
+
+    from distributed_ddpg_tpu.ops import fused_chunk, losses
+
+    hp = {**HP, "num_atoms": atoms}
+    v_min, v_max = HP["v_min"], HP["v_max"]
+    dz = (v_max - v_min) / (atoms - 1)
+    z = losses.categorical_support(v_min, v_max, atoms)
+    probs = jax.nn.softmax(jax.random.normal(jax.random.PRNGKey(atoms), (batch, atoms)), axis=-1)
+    ret, disc = projection_rows(kind, batch, z)
+
+    def body(p_ref, rd_ref, zc_ref, out_ref):
+        rd = rd_ref[...]
+        out_ref[...] = fused_chunk.kernel_projection(p_ref[...], rd[0:1, :], rd[1:2, :], zc_ref[...], v_min, v_max)
+
+    ours = pl.pallas_call(body, out_shape=jax.ShapeDtypeStruct((batch, atoms), jnp.float32), interpret=True)(
+        probs, jnp.stack([ret, disc]), z.reshape(-1, 1)
+    )
+    # float32 on both sides; a sum of `atoms` terms in the atoms' order against
+    # XLA:CPU's own order of the dense sums
+    np.testing.assert_allclose(ours, losses.categorical_projection(z, probs, ret, disc), atol=5e-6, rtol=0)
+    np.testing.assert_allclose(ours, d4pg.project(hp, probs, ret, disc), atol=5e-6, rtol=0)
+    np.testing.assert_allclose(jnp.sum(ours, axis=-1), 1.0, atol=1e-5)
+    total = np.asarray(jnp.sum(probs, axis=-1))
+    if kind == "on_an_atom":
+        # one atom holds the row's mass; its neighbours a millionth at most,
+        # where linspace's atoms lie an ulp off a multiple of dz
+        at = np.asarray(jnp.round((ret - v_min) / dz).astype(jnp.int32))
+        np.testing.assert_allclose(np.asarray(ours)[np.arange(batch), at], total, atol=5e-6, rtol=0)
+        assert (np.count_nonzero(np.asarray(ours) > 5e-6, axis=-1) == 1).all()
+    elif kind in ("below_v_min", "above_v_max"):
+        end = 0 if kind == "below_v_min" else atoms - 1
+        np.testing.assert_allclose(np.asarray(ours)[:, end], total, atol=5e-6, rtol=0)
+        assert np.count_nonzero(np.asarray(ours)) == batch
+    elif kind == "terminal":  # a d = 0 row has at most two atoms, neighbours
+        rows_ = np.asarray(ours)[np.asarray(disc) == 0]
+        assert len(rows_) and (np.count_nonzero(rows_, axis=-1) <= 2).all()
+
+
 def test_five_step_rows_carry_the_hand_sums():
     """One seeded episode of 9 steps that terminates, then one of 7 that is
     truncated, through the actor's accumulator and its truncation flush."""

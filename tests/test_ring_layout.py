@@ -308,11 +308,11 @@ MIB = 1024 * 1024
     [
         ("ddpg-halfcheetah", None),
         ("d4pg-halfcheetah", None),  # the program as train() builds it: Mosaic's default, 16 MiB
-        # 400-300 at batch 256 x 51 atoms takes 14.58 MiB of scoped VMEM (3.5
-        # times state_vmem_bytes); without the last-grid-step cond around the
-        # edge mass the same kernel takes 16.12 MiB and is refused. A MiB of
-        # room is kept.
-        ("d4pg-halfcheetah", 15),
+        # 400-300 at batch 256 x 51 atoms takes 7.88 MiB of scoped VMEM (the
+        # smallest limit it compiles under, bisected to 1/16 MiB). A MiB of
+        # room is kept: a projection loop that spills again (it read 14.60
+        # with its operands batch-on-sublanes) fails here, not on the chip.
+        ("d4pg-halfcheetah", 9),
         # TD3 at the paper's 400-300, twin critics, batch 100 (no multiple of
         # the 8 sublanes: Mosaic takes the blocks and the batch-contracting
         # dots as they are): 5.93 MiB of state, the largest of the cells', and
@@ -330,33 +330,19 @@ def test_v5e_megakernel_chunk_fits_scoped_vmem(v5e_sharding, monkeypatch, name, 
 
     from jax.experimental.pallas import tpu as pltpu
 
-    from distributed_ddpg_tpu.config import DDPGConfig
-    from distributed_ddpg_tpu.learner import init_train_state
     from distributed_ddpg_tpu.ops import fused_chunk
+    from distributed_ddpg_tpu.tools.kernel_bundles import lower_chunk
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     conf = json.load(open(os.path.join(root, "benchmarks", "configs", name + ".json")))
-    cfg = DDPGConfig.from_flags([f for f in conf["flags"] if not f.startswith("--replay_capacity")])
-    env, chunk = conf["env"], 800
-    assert fused_chunk.supported(cfg) and fused_chunk.fits_vmem(cfg, env["obs_dim"], env["act_dim"])
     if limit_mib is not None:
         real = fused_chunk.pl.pallas_call
         monkeypatch.setattr(
             fused_chunk.pl, "pallas_call",
             lambda *a, **kw: real(*a, compiler_params=pltpu.CompilerParams(vmem_limit_bytes=limit_mib * MIB), **kw),
         )
-    run = fused_chunk.make_fused_chunk_fn(
-        cfg, env["obs_dim"], env["act_dim"], env["action_scale"], env["action_offset"],
-        chunk_size=chunk, interpret=False,
-    )
     replicated = NamedSharding(v5e_sharding.mesh, P())
-    state = jax.tree.map(
-        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=replicated),
-        jax.eval_shape(lambda: init_train_state(cfg, env["obs_dim"], env["act_dim"], 0)),
-    )
-    width = 2 * env["obs_dim"] + env["act_dim"] + 3
-    batches = jax.ShapeDtypeStruct((chunk, cfg.batch_size, width), jnp.float32, sharding=replicated)
-    compiled = jax.jit(run).lower(state, batches).compile()  # raises what the chip's compiler would
+    compiled = lower_chunk(conf, replicated).compile()  # raises what the chip's compiler would
     assert "tpu_custom_call" in compiled.as_text()
 
 
