@@ -54,7 +54,9 @@ class DDPGConfig:
     # applied via vmap — one MXU-batched program, not two sequential nets),
     # with min-over-ensemble Bellman targets (clipped double-Q).
     twin_critic: bool = False
-    # Actor + target nets update once per `policy_delay` critic steps.
+    # twin_critic: actor + target nets update once per `policy_delay`
+    # critic steps. sac: the actor and the temperature do; the critics'
+    # targets still move on every update (REDQ, below).
     policy_delay: int = 1
     # Target-policy smoothing: clip(N(0, target_noise), +-clip) added to
     # the target action inside the critic target (0 = off). The noise key
@@ -95,6 +97,19 @@ class DDPGConfig:
     # (replay_min_size when sac, else 0); 0 = off. In the actor pool the
     # budget is split evenly across workers.
     warmup_uniform_steps: int = -1
+
+    # --- REDQ (arXiv 2101.05982, Algorithm 1; sac only) ---
+    # critic_ensemble: N critics, independently seeded, stacked on the
+    # leading axis that sac's and twin_critic's two already have.
+    # target_subset: M distinct critics drawn uniformly for each update's
+    # target, y = R + d * (min over the M drawn Q'_i - alpha * log pi);
+    # M == N is the minimum over all, what sac computes today. When M < N
+    # the actor ascends the ensemble MEAN (Algorithm 1) where sac has the
+    # minimum. With policy_delay G (the paper's update-to-data ratio, as the
+    # structure of the loop) the actor and temperature step on one update
+    # in G. N = M = 2 and policy_delay 1 is plain sac, program for program.
+    critic_ensemble: int = 2
+    target_subset: int = 2
 
     # --- replay (SURVEY.md §2 #5/#7) ---
     replay_capacity: int = 1_000_000
@@ -610,6 +625,18 @@ class DDPGConfig:
         return cls(**args)
 
     @property
+    def redq(self) -> bool:
+        """sac with any of REDQ's departures on: another ensemble size than
+        two, a drawn in-target subset, or a delayed policy. These runs take
+        the scan leg (ops/fused_chunk.supported) and carry `redq_q_spread`
+        and `redq_policy_updates` in their records; plain sac does not."""
+        return self.sac and (
+            self.critic_ensemble != 2
+            or self.target_subset != self.critic_ensemble
+            or self.policy_delay > 1
+        )
+
+    @property
     def v_support_auto(self) -> bool:
         """True when the C51 support is auto-sized (v_min/v_max = nan).
         Consumers must resolve concrete bounds (support_auto.initial_bounds)
@@ -742,12 +769,24 @@ class DDPGConfig:
         if self.target_noise < 0 or self.target_noise_clip < 0:
             raise ValueError("target_noise/target_noise_clip must be >= 0")
         if not self.twin_critic and (
-            self.policy_delay > 1 or self.target_noise > 0
+            (self.policy_delay > 1 and not self.sac) or self.target_noise > 0
         ):
             raise ValueError(
-                "policy_delay/target_noise are TD3 knobs consumed only by "
-                "the twin-critic step — set twin_critic=True or they would "
-                "silently do nothing"
+                "policy_delay is consumed only by the twin-critic and sac "
+                "steps and target_noise only by the twin-critic step — set "
+                "twin_critic=True (or sac=True for policy_delay) or they "
+                "would silently do nothing"
+            )
+        if not 1 <= self.target_subset <= self.critic_ensemble:
+            raise ValueError(
+                f"target_subset ({self.target_subset}) draws distinct "
+                f"critics out of critic_ensemble ({self.critic_ensemble}): "
+                "needs 1 <= target_subset <= critic_ensemble"
+            )
+        if not self.sac and (self.critic_ensemble, self.target_subset) != (2, 2):
+            raise ValueError(
+                "critic_ensemble/target_subset are consumed only by the sac "
+                "step (REDQ) — set sac=True or they would silently do nothing"
             )
         v_min_auto = math.isnan(self.v_min)
         v_max_auto = math.isnan(self.v_max)

@@ -62,18 +62,23 @@ def metric_keys(config: DDPGConfig) -> tuple:
     support's two end atoms, the number a user sets v_min / v_max by. The
     twin-critic (TD3) branch reports `td3_twin_gap` besides: the batch mean
     of |Q'_1 - Q'_2| at the smoothed target action, how much the clipped
-    minimum bites. A chunk reports its last update's of either
+    minimum bites. An ensemble (REDQ) run, config.redq, reports
+    `redq_q_spread` besides: the batch mean of the standard deviation over
+    the N online Q_i(s, a), how far the critics lie apart where the
+    in-target minimum acts. A chunk reports its last update's of each
     (chunk_metrics). Only those branches have the keys, so every other
     family's programs and records are what they were."""
     if config.distributional:  # config.py: never with twin_critic or sac
         return METRIC_KEYS + ("c51_edge_mass",)
     if config.twin_critic:
         return METRIC_KEYS + ("td3_twin_gap",)
+    if config.redq:
+        return METRIC_KEYS + ("redq_q_spread",)
     return METRIC_KEYS
 
 
 # Metrics a chunk reports for its LAST update, not as a mean over the K.
-LAST_UPDATE_KEYS = ("c51_edge_mass", "td3_twin_gap")
+LAST_UPDATE_KEYS = ("c51_edge_mass", "td3_twin_gap", "redq_q_spread")
 
 
 def chunk_metrics(ms: dict) -> dict:
@@ -90,10 +95,11 @@ def chunk_metrics(ms: dict) -> dict:
 
 def delayed_updates(steps, delay: int):
     """How many of the learner steps 0 .. steps-1 move the TD3 actor and the
-    targets: the multiples of `delay` below `steps`. The one rule behind the
-    scan step's cond (state.step % delay == 0), the kernel's schedule and
-    actor Adam count, and the record's `td3_actor_updates`. Works on ints
-    and on traced scalars."""
+    targets (under sac: the actor and the temperature): the multiples of
+    `delay` below `steps`. The one rule behind the scan steps' cond
+    (state.step % delay == 0), the kernel's schedule and actor Adam count,
+    and the records' `td3_actor_updates` / `redq_policy_updates`. Works on
+    ints and on traced scalars."""
     return (steps + delay - 1) // delay
 
 
@@ -115,7 +121,18 @@ def _maybe_psum_mean(tree, axis_name: Optional[str]):
 # its own (`step_noise`), and every chunk program, on the scan leg and the
 # kernel leg alike, pre-draws its K steps' worth in front of its loop
 # (`chunk_noise`) — the same bits, because the draw does not depend on the
-# parameters (SAC: u = mean + std * eps).
+# parameters (SAC: u = mean + std * eps). REDQ's in-target subset is a third
+# member of SAC's pair, from the same step key BEFORE any per-device fold: the
+# critics' gradient is averaged across replicas against one y per row, so
+# every replica must draw the same critics.
+
+_SUBSET_FOLD = 0x5B5E7
+
+
+def draws_subset(config: DDPGConfig) -> bool:
+    """Whether each update's target takes a drawn subset of the ensemble
+    (REDQ's M < N) and not all of it."""
+    return bool(config.sac and config.target_subset < config.critic_ensemble)
 
 
 def draws_noise(config: DDPGConfig) -> bool:
@@ -139,7 +156,10 @@ def step_noise(config: DDPGConfig, base, step, batch: int, act_dim: int,
     """The noise of learner step `step`, what make_learner_step's step takes
     as its third argument. SAC: the standard normals (eps_next, eps_cur),
     each [B, act], the critic-target draw at s' first, then the actor draw
-    at s. TD3: the target-smoothing noise [B, act], scaled and clipped. None
+    at s; with target_subset < critic_ensemble a third member, the update's
+    in-target critics, int32[M], distinct and uniform over the subsets, the
+    same on every device. TD3: the target-smoothing noise [B, act], scaled
+    and clipped. None
     where the algorithm draws none (DDPG, D4PG, TD3 without smoothing).
     `device_fold` (lax.axis_index under shard_map) folds a per-device term
     AFTER the step fold, so that each shard of a global batch draws its own
@@ -147,20 +167,38 @@ def step_noise(config: DDPGConfig, base, step, batch: int, act_dim: int,
     global batch of B*D rows would get only B unique perturbations."""
     if not draws_noise(config):
         return None
-    key = jax.random.fold_in(base, step)
+    step_key = key = jax.random.fold_in(base, step)
     if device_fold is not None:
         key = jax.random.fold_in(key, device_fold)
     if config.sac:
         k_next, k_cur = jax.random.split(key)
-        return (
+        eps = (
             jax.random.normal(k_next, (batch, act_dim)),
             jax.random.normal(k_cur, (batch, act_dim)),
         )
+        if not draws_subset(config):
+            return eps
+        subset = jax.random.choice(
+            jax.random.fold_in(step_key, _SUBSET_FOLD),
+            config.critic_ensemble, (config.target_subset,), replace=False,
+        )
+        return (*eps, subset.astype(jnp.int32))
     return jnp.clip(
         config.target_noise * jax.random.normal(key, (batch, act_dim)),
         -config.target_noise_clip,
         config.target_noise_clip,
     )
+
+
+def noise_per_row(config: DDPGConfig):
+    """step_noise's structure, True where a member has the batch's rows as
+    its first axis (a data mesh shards it like the batch) and False where it
+    is the same on every replica (REDQ's subset)."""
+    if not draws_noise(config):
+        return None
+    if not config.sac:
+        return True
+    return (True, True, False) if draws_subset(config) else (True, True)
 
 
 def chunk_noise(config: DDPGConfig, step0, chunk: int, batch: int,
@@ -192,20 +230,19 @@ def init_train_state(config: DDPGConfig, obs_dim: int, act_dim: int, seed: int) 
         tuple(config.actor_hidden),
     )
     if config.twin_critic or config.sac:
-        # TD3 ensemble: two independently-initialized critics stacked on a
-        # leading axis — the TrainState SHAPE is unchanged (same tree, each
-        # critic leaf just gains a [2, ...] dim), so checkpointing, Adam,
-        # Polyak, and the mesh pspec trees all compose without new cases.
-        k1, k2 = jax.random.split(k_critic)
+        # TD3 / SAC ensemble: independently-initialized critics stacked on a
+        # leading axis (two, or config.critic_ensemble under sac) — the
+        # TrainState SHAPE is unchanged (same tree, each critic leaf just
+        # gains a [N, ...] dim), so checkpointing, Adam, Polyak, and the
+        # mesh pspec trees all compose without new cases.
         critic_params = jax.tree.map(
-            lambda a, b: jnp.stack([a, b]),
-            critic_init(
-                k1, obs_dim, act_dim, tuple(config.critic_hidden),
-                config.action_insert_layer, num_outputs,
-            ),
-            critic_init(
-                k2, obs_dim, act_dim, tuple(config.critic_hidden),
-                config.action_insert_layer, num_outputs,
+            lambda *members: jnp.stack(members),
+            *(
+                critic_init(
+                    k, obs_dim, act_dim, tuple(config.critic_hidden),
+                    config.action_insert_layer, num_outputs,
+                )
+                for k in jax.random.split(k_critic, config.critic_ensemble)
             ),
         )
     else:
@@ -293,8 +330,13 @@ def make_learner_step(
         """SAC: entropy-regularized twin-critic TD + reparameterized actor
         + (optionally) the learned temperature. Kept as its own body — the
         actor loss carries an aux (mean log-prob -> alpha update) that the
-        shared branch structure below has no slot for."""
-        eps_next, eps_cur = own_noise(state, batch) if noise is None else noise
+        shared branch structure below has no slot for. REDQ (config.redq)
+        is this step with N critics, a drawn in-target subset and the
+        policy's half under a cond."""
+        eps_next, eps_cur, *subset = (
+            own_noise(state, batch) if noise is None else noise
+        )
+        subset = subset[0] if subset else None
         alpha = jnp.exp(state.log_alpha)
 
         def critic_loss_fn(cp):
@@ -303,6 +345,7 @@ def make_learner_step(
                 scale, eps_next, alpha,
                 config.sac_log_std_min, config.sac_log_std_max,
                 ail, config.critic_l2, offset, mm,
+                subset=subset, ensemble_stats=config.redq,
             )
 
         (closs, td), cgrads = jax.value_and_grad(critic_loss_fn, has_aux=True)(
@@ -310,39 +353,34 @@ def make_learner_step(
         )
         cgrads = _maybe_psum_mean(cgrads, axis_name)
 
-        # Actor gradient against the pre-update critic (file convention).
+        # Actor gradient against the pre-update critic (file convention):
+        # its ensemble's mean where the target draws a subset (REDQ,
+        # Algorithm 1), the minimum otherwise.
         def actor_loss_fn(ap):
             return losses.sac_actor_loss(
                 ap, state.critic_params, batch, scale, eps_cur, alpha,
                 config.sac_log_std_min, config.sac_log_std_max,
                 ail, offset, mm,
+                reduce=jnp.min if subset is None else jnp.mean,
             )
 
-        (aloss, mean_lp), agrads = jax.value_and_grad(
-            actor_loss_fn, has_aux=True
-        )(state.actor_params)
-        agrads = _maybe_psum_mean(agrads, axis_name)
-        # Global mean log-prob so every shard's alpha update sees the same
-        # scalar (replicas must not fork on log_alpha).
-        mean_lp = _maybe_psum_mean(mean_lp, axis_name)
+        def actor_grads():
+            (aloss, mean_lp), agrads = jax.value_and_grad(
+                actor_loss_fn, has_aux=True
+            )(state.actor_params)
+            agrads = _maybe_psum_mean(agrads, axis_name)
+            # Global mean log-prob so every shard's alpha update sees the same
+            # scalar (replicas must not fork on log_alpha).
+            return aloss, _maybe_psum_mean(mean_lp, axis_name), agrads
 
-        new_critic, critic_opt = adam_update(
-            state.critic_params, cgrads, state.critic_opt, config.critic_lr
-        )
-        new_actor, actor_opt = adam_update(
-            state.actor_params, agrads, state.actor_opt, config.actor_lr
-        )
-        new_target_critic = polyak_update(
-            new_critic, state.target_critic_params, config.tau
-        )
-        # SAC's math has no target actor; the slot still trails the actor
-        # via the same polyak so the TrainState invariants (targets trail
-        # params) and checkpoint shape stay uniform across families.
-        new_target_actor = polyak_update(
-            new_actor, state.target_actor_params, config.tau
-        )
+        def actor_adam(agrads):
+            return adam_update(
+                state.actor_params, agrads, state.actor_opt, config.actor_lr
+            )
 
-        if config.sac_autotune:
+        def temperature_adam(mean_lp):
+            if not config.sac_autotune:
+                return state.log_alpha, state.alpha_opt
             # J(log_alpha) = -log_alpha * (E[log pi] + target_H);
             # d/dlog_alpha = -(E[log pi] + target_H), exact — no autodiff
             # needed for a scalar with a linear objective. The target
@@ -354,24 +392,89 @@ def make_learner_step(
                 config.target_entropy, batch.action.shape[-1], action_scale
             )
             alpha_grad = -(jax.lax.stop_gradient(mean_lp) + tgt_h)
-            new_log_alpha, alpha_opt = adam_update(
+            return adam_update(
                 state.log_alpha, alpha_grad, state.alpha_opt, config.critic_lr
             )
-        else:
-            new_log_alpha, alpha_opt = state.log_alpha, state.alpha_opt
 
-        # mean_q recovered exactly: aloss = E[alpha*lp - minQ]
-        # => E[minQ] = alpha * mean_lp - aloss.
+        if config.policy_delay > 1:
+            # REDQ's one policy step in G: the critics and their targets step
+            # on every update, the actor and the temperature on the updates
+            # whose (pre-increment, replicated) step count is 0, G, 2G, ...,
+            # TD3's rule (delayed_updates), so every replica takes the same
+            # branch. The actor's backward through all N critics and its
+            # pmean live inside the taken branch. A skipped update runs no
+            # pass for the record either (N critic forwards are a third of
+            # an update at N = 10): actor_loss and actor_grad_norm read 0 on
+            # it, as TD3's actor_grad_norm does.
+            new_critic, critic_opt = adam_update(
+                state.critic_params, cgrads, state.critic_opt, config.critic_lr
+            )
+
+            def policy_update():
+                aloss, mean_lp, agrads = actor_grads()
+                return (
+                    *actor_adam(agrads), *temperature_adam(mean_lp),
+                    aloss, optree_norm(agrads),
+                )
+
+            zero = jnp.zeros((), jnp.float32)
+            (
+                new_actor, actor_opt, new_log_alpha, alpha_opt,
+                aloss, actor_grad_norm,
+            ) = jax.lax.cond(
+                state.step % config.policy_delay == 0,
+                policy_update,
+                lambda: (
+                    state.actor_params, state.actor_opt, state.log_alpha,
+                    state.alpha_opt, zero, zero,
+                ),
+            )
+        else:
+            # Plain SAC's sequence, op for op as it was before the delay
+            # existed (actor gradient, both Adams, both Polyaks, then the
+            # temperature, the actor's gradient norm last, in the metrics):
+            # its lowered text is held equal to the parent's.
+            aloss, mean_lp, agrads = actor_grads()
+            new_critic, critic_opt = adam_update(
+                state.critic_params, cgrads, state.critic_opt, config.critic_lr
+            )
+            new_actor, actor_opt = actor_adam(agrads)
+        new_target_critic = polyak_update(
+            new_critic, state.target_critic_params, config.tau
+        )
+        # SAC's math has no target actor; the slot still trails the actor
+        # via the same polyak so the TrainState invariants (targets trail
+        # params) and checkpoint shape stay uniform across families.
+        new_target_actor = polyak_update(
+            new_actor, state.target_actor_params, config.tau
+        )
+        if config.policy_delay == 1:
+            new_log_alpha, alpha_opt = temperature_adam(mean_lp)
+
+        if config.redq:
+            # mean_q from the critic loss's own q, there on every update: the
+            # ensemble's mean Q(s, a) on the replay rows.
+            td, q_spread, mean_q = td
+            branch_metrics = (q_spread,)
+        else:
+            # mean_q recovered exactly: aloss = E[alpha*lp - minQ]
+            # => E[minQ] = alpha * mean_lp - aloss.
+            mean_q = alpha * mean_lp - aloss
+            branch_metrics = ()
         metrics = dict(
             zip(
-                METRIC_KEYS,
+                keys,
                 (
                     closs,
                     aloss,
-                    alpha * mean_lp - aloss,
+                    mean_q,
                     jnp.mean(jnp.abs(td)),
                     optree_norm(cgrads),
-                    optree_norm(agrads),
+                    (
+                        actor_grad_norm if config.policy_delay > 1
+                        else optree_norm(agrads)
+                    ),
+                    *branch_metrics,
                 ),
             )
         )

@@ -150,14 +150,14 @@ def state_vmem_bytes(config: DDPGConfig, obs_dim: int, act_dim: int) -> int:
     # obs/act enter the actor/critic input dims; action rides into critic
     # layer 1 (action_insert_layer == 1 inside the supported envelope).
     # The C51 head widens the critic output to num_atoms logits; the TD3
-    # twin ensemble doubles every critic tensor; SAC doubles both the
-    # actor head ([mean | log_std]) and the critic (its own ensemble).
+    # twin ensemble doubles every critic tensor; SAC doubles the actor head
+    # ([mean | log_std]) and has critic_ensemble critics (2 unless REDQ).
     out = config.num_atoms if config.distributional else 1
     head = 2 * act_dim if config.sac else act_dim
     a = net([obs_dim, *config.actor_hidden, head])
     c = net([obs_dim, *config.critic_hidden, out], extra_in=act_dim)
     if config.twin_critic or config.sac:
-        c *= 2
+        c *= config.critic_ensemble
     return 4 * (4 * a + 4 * c)
 
 
@@ -203,7 +203,7 @@ def supported(config: DDPGConfig) -> bool:
     return (
         config.action_insert_layer == 1
         and config.critic_l2 == 0.0
-        and not config.fused_update
+        and not (config.fused_update or config.redq)  # REDQ: no kernel branch
         and config.compute_dtype in ("float32", "bfloat16")
         # The hand-written backward assumes the action-insert layer (1) is
         # not the critic's output layer, i.e. at least 2 hidden layers.

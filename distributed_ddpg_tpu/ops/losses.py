@@ -186,14 +186,25 @@ def sac_critic_loss(
     l2: float = 0.0,
     action_offset=0.0,
     mm_dtype=None,
+    subset=None,
+    ensemble_stats: bool = False,
 ):
     """Entropy-regularized clipped double-Q TD loss:
     y = r + discount * (min_i Q'_i(s', a') - alpha * log pi(a'|s')),
     a' ~ pi(.|s') drawn from the CURRENT actor (SAC has no target actor)
     with the standard normals `eps` (f32[B, act]).
-    `critic_params` leaves carry the same leading ensemble axis of 2 as
-    TD3's (learner.init_train_state). Returns (loss, td_proxy[B]) with the
-    ensemble-mean TD error as the PER priority proxy."""
+    `critic_params` leaves carry the leading ensemble axis of N (2 as
+    TD3's, unless config.critic_ensemble says otherwise:
+    learner.init_train_state). Returns (loss, td_proxy[B]) with the
+    ensemble-mean TD error as the PER priority proxy.
+
+    REDQ (`subset`, int32[M], distinct members of the ensemble): the minimum
+    runs over the M drawn target critics only, whose leaves are gathered by
+    index, so the target costs M forward passes and not N; every online
+    critic regresses on that one y. With `ensemble_stats` the aux is
+    (td_proxy, q_spread, mean_q): the batch mean of the standard deviation
+    over the N online Q_i(s, a), and their mean, both of the q this loss
+    holds anyway."""
     from distributed_ddpg_tpu.models.mlp import actor_gaussian_apply
 
     mean, log_std = actor_gaussian_apply(
@@ -203,17 +214,25 @@ def sac_critic_loss(
     ensemble = lambda p, o, a: jax.vmap(
         lambda cp: critic_apply(cp, o, a, action_insert_layer, mm_dtype)
     )(p)
+    if subset is not None:
+        target_critic_params = jax.tree.map(
+            lambda x: x[subset], target_critic_params
+        )
     next_q = jnp.min(
         ensemble(target_critic_params, batch.next_obs, next_action), axis=0
     )
     y = jax.lax.stop_gradient(td_targets(batch, next_q - alpha * next_lp))
-    q = ensemble(critic_params, batch.obs, batch.action)  # [2, B]
+    q = ensemble(critic_params, batch.obs, batch.action)  # [N, B]
     td = y[None, :] - q
     loss = jnp.mean(batch.weight[None, :] * jnp.square(td))
     if l2 > 0.0:
         # Weight decay over both ensemble members (matching td3_critic_loss).
         loss = loss + l2 * sum(
             jnp.sum(jnp.square(layer["w"])) for layer in critic_params
+        )
+    if ensemble_stats:
+        return loss, (
+            jnp.mean(td, axis=0), jnp.mean(jnp.std(q, axis=0)), jnp.mean(q)
         )
     return loss, jnp.mean(td, axis=0)
 
@@ -230,20 +249,23 @@ def sac_actor_loss(
     action_insert_layer: int = 1,
     action_offset=0.0,
     mm_dtype=None,
+    reduce=jnp.min,
 ):
     """Reparameterized actor objective E[alpha * log pi(a|s) - min_i Q_i(s, a)],
     a drawn with the standard normals `eps` (f32[B, act]).
 
     Unlike TD3 (critic 0 only), SAC minimizes against the ensemble MIN —
-    the 1812.05905 convention. Returns (loss, mean_log_prob) — the aux
-    feeds the alpha (temperature) update."""
+    the 1812.05905 convention; REDQ, whose target draws a subset, against
+    the ensemble MEAN (`reduce=jnp.mean`; 2101.05982, Algorithm 1).
+    Returns (loss, mean_log_prob) — the aux feeds the alpha (temperature)
+    update."""
     from distributed_ddpg_tpu.models.mlp import actor_gaussian_apply
 
     mean, log_std = actor_gaussian_apply(
         actor_params, batch.obs, log_std_min, log_std_max, mm_dtype
     )
     action, lp = sac_sample(mean, log_std, eps, action_scale, action_offset)
-    q = jnp.min(
+    q = reduce(
         jax.vmap(
             lambda cp: critic_apply(cp, batch.obs, action, action_insert_layer, mm_dtype)
         )(critic_params),

@@ -41,6 +41,7 @@ from distributed_ddpg_tpu.learner import (
     init_train_state,
     make_learner_step,
     metric_keys,
+    noise_per_row,
 )
 from distributed_ddpg_tpu.parallel import mesh as mesh_lib
 from distributed_ddpg_tpu.types import (
@@ -247,8 +248,12 @@ class ShardedLearner:
 
             def step(s: TrainState, b: Batch, noise=None) -> StepOutput:
                 # `noise` (a scan chunk's, below) rides sharded like the
-                # batch: each shard takes the rows it drew.
-                noise_spec = jax.tree.map(lambda _: P("data", None), noise)
+                # batch: each shard takes the rows it drew. REDQ's subset
+                # has no rows and is every shard's alike.
+                noise_spec = None if noise is None else jax.tree.map(
+                    lambda rows: P("data", None) if rows else P(),
+                    noise_per_row(config),
+                )
                 return mesh_lib.shard_map(
                     inner,
                     mesh=self.mesh,
@@ -292,10 +297,11 @@ class ShardedLearner:
                 # [K, B, act]; with the partitionable threefry the values
                 # do not depend on the sharding.
                 return jax.tree.map(
-                    lambda x: jax.lax.with_sharding_constraint(
-                        x, self._chunk_sharding
+                    lambda x, rows: jax.lax.with_sharding_constraint(
+                        x, self._chunk_sharding if rows else replicated
                     ),
                     chunk_noise(config, s.step, K, B, act_dim),
+                    noise_per_row(config),
                 )
             # Explicit mode folds the shard's index into the key, as the
             # step under shard_map does when it draws for itself.
@@ -304,7 +310,11 @@ class ShardedLearner:
                     config, step0, K, B // self.data_size, act_dim,
                     device_fold=jax.lax.axis_index("data"),
                 ),
-                mesh=self.mesh, in_specs=P(), out_specs=P(None, "data", None),
+                mesh=self.mesh, in_specs=P(),
+                out_specs=jax.tree.map(
+                    lambda rows: P(None, "data", None) if rows else P(),
+                    noise_per_row(config),
+                ),
             )(s.step)
 
         # The shared chunk body of the host-fed and the fused-sampling paths.
@@ -1162,19 +1172,31 @@ def program_specs():
     OWNER = "parallel/learner.py"
     cache: Dict[tuple, ShardedLearner] = {}
 
+    # REDQ's chunk (a critic ensemble of 5, a drawn pair, the policy's half
+    # under a cond): the subset rides replicated beside noise sharded like
+    # the batch, and the taken branch holds the actor's gradient pmean.
+    ENSEMBLE = dict(
+        sac=True, critic_ensemble=5, target_subset=2, policy_delay=3
+    )
+
     def learner(
-        guard: bool = False, sharded: bool = False, tp: bool = False
+        guard: bool = False, sharded: bool = False, tp: bool = False,
+        ensemble: bool = False, mode: str = "auto",
     ) -> ShardedLearner:
-        key = (guard, sharded, tp)
+        key = (guard, sharded, tp, ensemble, mode)
         if key not in cache:
             cache[key] = ShardedLearner(
-                probe_config(guardrails=guard, model_axis=2 if tp else 1),
+                probe_config(
+                    guardrails=guard, model_axis=2 if tp else 1,
+                    **(ENSEMBLE if ensemble else {}),
+                ),
                 obs_dim=3,
                 act_dim=1,
                 action_scale=np.ones(1, np.float32),
                 mesh=probe_mesh(2 if tp else 1),
                 chunk_size=2,
                 replay_sharding="sharded" if sharded else "replicated",
+                mode=mode,
             )
         return cache[key]
 
@@ -1201,9 +1223,9 @@ def program_specs():
             return BuiltProgram(L._chunk_step, (L.state, chunk), (0,))
         return build
 
-    def uniform(guard: bool, sharded: bool, tp: bool = False):
+    def uniform(guard: bool, sharded: bool, tp: bool = False, **kw):
         def build():
-            L = learner(guard=guard, sharded=sharded, tp=tp)
+            L = learner(guard=guard, sharded=sharded, tp=tp, **kw)
             storage, size = storage_for(L)
             if guard:
                 return BuiltProgram(
@@ -1288,6 +1310,19 @@ def program_specs():
             "learner.chunk.per.sharded.tp", OWNER,
             per(False, sharded=True, tp=True),
             beat_group="learner-beat-per-sharded",
+        ),
+    ])
+    # The ensemble chunk, as the partitioner shards it and under explicit
+    # shard_map (where the critics', the actor's and the metrics' pmeans are
+    # staged by hand, the actor's inside the cond's taken branch).
+    specs.extend([
+        ProgramSpec(
+            "learner.chunk.uniform.ensemble", OWNER,
+            uniform(False, sharded=False, ensemble=True),
+        ),
+        ProgramSpec(
+            "learner.chunk.uniform.ensemble.explicit", OWNER,
+            uniform(False, sharded=False, ensemble=True, mode="explicit"),
         ),
     ])
     return specs

@@ -154,6 +154,8 @@ KEY_METRICS = (
     # Twin-critic (TD3) runs only: how far apart the two target critics lie
     # where the target takes their minimum.
     "td3_twin_gap",
+    # Ensemble (REDQ) runs only: how far the N online critics lie apart.
+    "redq_q_spread",
 )
 
 # Cumulative recovery counters (train.py recovery_fields; docs/RESILIENCE.md)

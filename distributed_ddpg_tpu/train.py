@@ -1310,6 +1310,11 @@ def _train_jax_impl(
             facts["replay_devices"] = len(
                 device_replay.storage.sharding.device_set
             )
+        if config.redq:
+            # Ensemble runs only: how many critics the state stacks and how
+            # many of them each update's target draws.
+            facts["critic_ensemble"] = config.critic_ensemble
+            facts["target_subset"] = config.target_subset
         return facts
 
     log = MetricsLogger(config.log_path, header=run_facts())
@@ -1529,21 +1534,23 @@ def _train_jax_impl(
         dispatch-per-phase loop."""
         return megastep.snapshot() if megastep is not None else {}
 
-    def td3_fields() -> Dict[str, int]:
-        """`td3_actor_updates` beside `learner_steps` on every train/final
-        record of a twin-critic run (docs/OBSERVABILITY.md): how many of
-        the learner's updates moved the actor and the targets, cumulative
-        from step 0. Host arithmetic on the step count the branch already
-        carries (learner.delayed_updates, the rule the step's cond, the
-        kernel's schedule and the actor's Adam count follow): no update
-        pays for it. No other family's records have the key."""
-        if not config.twin_critic:
+    def delay_fields() -> Dict[str, int]:
+        """`td3_actor_updates` (twin-critic runs) or `redq_policy_updates`
+        (ensemble runs, config.redq) beside `learner_steps` on every
+        train/final record (docs/OBSERVABILITY.md): how many of the
+        learner's updates moved the actor (with TD3's targets, or REDQ's
+        temperature), cumulative from step 0. Host arithmetic on the step
+        count the branch already carries (learner.delayed_updates, the rule
+        the step's cond, the kernel's schedule and the actor's Adam count
+        follow): no update pays for it. No other family's records have
+        either key."""
+        if config.twin_critic:
+            key = "td3_actor_updates"
+        elif config.redq:
+            key = "redq_policy_updates"
+        else:
             return {}
-        return {
-            "td3_actor_updates": delayed_updates(
-                learn_steps, config.policy_delay
-            )
-        }
+        return {key: delayed_updates(learn_steps, config.policy_delay)}
 
     mesh_stats = MeshStats(
         learner.mesh.shape["data"], learner.mesh.shape["model"]
@@ -2196,7 +2203,7 @@ def _train_jax_impl(
             log.log(
                 "train", env_steps(),
                 learner_steps=learn_steps,
-                **td3_fields(),
+                **delay_fields(),
                 learner_steps_per_sec=learn_timer.rate(),
                 actor_steps_per_sec=env_timer.rate(),
                 buffer_fill=buffer_fill(),
@@ -2816,7 +2823,7 @@ def _train_jax_impl(
     log.log(
         "final", env_steps(),
         learner_steps=learn_steps,
-        **td3_fields(),
+        **delay_fields(),
         learner_steps_per_sec=rate,
         final_return=final_return,
         **facts_final,
@@ -2842,7 +2849,7 @@ def _train_jax_impl(
     return {
         "learner_steps_per_sec": rate,
         "learner_steps": learn_steps,
-        **td3_fields(),
+        **delay_fields(),
         "final_return": final_return,
         "param_checksum": _param_checksum(learner.actor_params_to_host()),
         # The same sum over the params the run STARTED from (fresh init

@@ -36,6 +36,7 @@ RING_LAYOUT = {
     "d4pg-halfcheetah": "packed",
     "sac-humanoid": "row_major",
     "td3-halfcheetah": "packed",
+    "redq-humanoid": "row_major",
 }
 
 

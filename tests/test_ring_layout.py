@@ -424,3 +424,51 @@ def test_v5e_scan_chunk_draws_its_noise_before_the_loop(v5e_sharding):
     assert not [line for name in inside for line in comps[name] if drawn.search(line)]
     # The draw is in the program all the same, in front of the loop.
     assert [line for line in text.splitlines() if drawn.search(line)]
+
+
+def test_v5e_redq_chunk_draws_before_the_loop_and_holds_the_policy_under_a_conditional(v5e_sharding):
+    """`redq-humanoid`'s scan chunk at the configuration's own sizes (ten
+    critics, batch 256, obs 376, act 17, K 800, unroll 4), compiled for the
+    described v5e: the chip's compiler takes it; the subset's draw (a
+    shuffle's sort and threefry) sits in front of the loop with the normals,
+    none of it in the while body; and the policy's half is a conditional in
+    the body, not a select over both branches' results."""
+    import json
+    import os
+
+    from distributed_ddpg_tpu import learner as learner_lib
+    from distributed_ddpg_tpu.config import DDPGConfig
+    from distributed_ddpg_tpu.parallel.learner import scan_chunk
+    from distributed_ddpg_tpu.types import unpack_batch
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    conf = json.load(open(os.path.join(root, "benchmarks", "configs", "redq-humanoid.json")))
+    cfg = DDPGConfig.from_flags([f for f in conf["flags"] if not f.startswith("--replay_capacity")])
+    env, chunk = conf["env"], 800
+    obs, act = env["obs_dim"], env["act_dim"]
+    assert (cfg.batch_size, cfg.critic_ensemble, cfg.target_subset, cfg.policy_delay) == (256, 10, 2, 20)
+    step = learner_lib.make_learner_step(cfg, env["action_scale"], action_offset=env["action_offset"])
+
+    def run(s, packed):
+        noise = learner_lib.chunk_noise(cfg, s.step, chunk, cfg.batch_size, act)
+        return scan_chunk(step, s, unpack_batch(packed, obs, act), noise, unroll=4)
+
+    replicated = NamedSharding(v5e_sharding.mesh, P())
+    state = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=replicated),
+        jax.eval_shape(lambda: learner_lib.init_train_state(cfg, obs, act, 0)),
+    )
+    assert state.critic_params[0]["w"].shape == (10, 376, 256)
+    packed = jax.ShapeDtypeStruct((chunk, cfg.batch_size, 2 * obs + act + 3), jnp.float32, sharding=replicated)
+    text = jax.jit(run, donate_argnums=(0,)).lower(state, packed).compile().as_text()
+
+    comps = _computations(text)
+    whiles = [line for lines in comps.values() for line in lines if re.search(r"\bwhile\(", line)]
+    body = re.search(r"body=%?([\w.\-]+)", max(whiles, key=lambda w: len(comps[re.search(r"body=%?([\w.\-]+)", w).group(1)]))).group(1)
+    inside = [body, *_called_from(comps, body)]
+    drawn = re.compile(r'op_name="[^"]*(threefry|_normal|shuffle|choice)')
+    assert not [line for name in inside for line in comps[name] if drawn.search(line)]
+    assert not [line for line in comps[body] if re.search(r"\b(xor|shift-left|sort)\(", line)]
+    assert [line for line in text.splitlines() if drawn.search(line)]
+    conditionals = [line for name in inside for line in comps[name] if re.search(r"\bconditional\(", line)]
+    assert len(conditionals) == 4  # one an update, four unrolled updates a trip
