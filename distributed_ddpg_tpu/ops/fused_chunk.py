@@ -47,6 +47,14 @@ hand-written actor backward routes the min-Q gate with reduce_min's
 tie-splitting vjp and chains d(log pi)/du = 2*scale*t*(1-t^2)/g through the
 squash correction.
 
+Each net's OUTPUT layer is resident lane-major, [out, F] for the
+TrainState's [F, out], where that takes fewer (8, 128) tiles (lane_major; a
+rule on the shape: [256, 1], [256, 6], [300, 51], SAC's [256, 12]), with its
+target and both moments: a one-lane-wide [F, 1] tensor costs the optimiser
+tail a vreg per eight weights. The wrapper transposes once a launch in and
+out (on the TPU a bitcast: the runtime lays those tensors column-major
+anyway); the body swaps the operand forms of the same three MXU products.
+
 Mixed precision (config.compute_dtype='bfloat16') casts matmul operands to
 bf16 with f32 accumulation (`preferred_element_type`), forward AND backward,
 mirroring models/mlp._dense; params, Adam state, and activations stay f32.
@@ -84,22 +92,41 @@ _TANH_EPS = 1e-6
 # Fixed order in which a params tree (tuple of {"w","b"} dicts) is flattened
 # into the kernel's ref list: w0, b0, w1, b1, ...  Biases ride as (1, F) rows
 # so every ref is rank-2 (TPU VMEM wants >= 2D; (F,) -> (1, F) is layout-free).
+# A net's OUTPUT layer rides [out, F] where lane_major says (the module
+# docstring has why); nothing outside make_fused_chunk_fn sees that shape.
+
+
+def _tiles(rows: int, cols: int) -> int:
+    """(8, 128) float32 tiles of a [rows, cols] array resident in VMEM."""
+    return -(-rows // 8) * -(-cols // 128)
+
+
+def lane_major(fan_in: int, out: int) -> bool:
+    """Whether the kernel holds an output layer [fan_in, out] as
+    [out, fan_in]: a rule on the shape alone, the one the wrapper's
+    flatten, the kernel body's matmul forms and state_tiles all ask."""
+    return _tiles(out, fan_in) < _tiles(fan_in, out)
 
 
 def _flatten(params) -> list:
     out = []
-    for layer in params:
-        out.append(layer["w"])
+    for i, layer in enumerate(params):
+        w = layer["w"]
+        if i == len(params) - 1 and lane_major(*w.shape):
+            w = w.T
+        out.append(w)
         out.append(layer["b"].reshape(1, -1))
     return out
 
 
 def _unflatten(flat: Sequence[Any], like) -> Tuple:
+    """The kernel's refs back in the TrainState's shapes (`like`'s)."""
     layers = []
     for i, layer in enumerate(like):
-        layers.append(
-            {"w": flat[2 * i], "b": flat[2 * i + 1].reshape(layer["b"].shape)}
-        )
+        w = flat[2 * i]
+        if i == len(like) - 1 and lane_major(*layer["w"].shape):
+            w = w.T
+        layers.append({"w": w, "b": flat[2 * i + 1].reshape(layer["b"].shape)})
     return tuple(layers)
 
 
@@ -108,44 +135,32 @@ def _flatten_twin(params) -> list:
     layers, every ref rank-2 (Mosaic never sees the ensemble axis)."""
     out = []
     for m in range(2):
-        for layer in params:
-            out.append(layer["w"][m])
-            out.append(layer["b"][m].reshape(1, -1))
+        out += _flatten(jax.tree.map(lambda x: x[m], params))
     return out
 
 
 def _unflatten_twin(flat: Sequence[Any], like) -> Tuple:
-    n = len(like)
-    members = []
-    for m in range(2):
-        layers = []
-        for i in range(n):
-            layers.append(
-                {
-                    "w": flat[m * 2 * n + 2 * i],
-                    "b": flat[m * 2 * n + 2 * i + 1].reshape(
-                        like[i]["b"].shape[1:]
-                    ),
-                }
-            )
-        members.append(tuple(layers))
+    n2 = 2 * len(like)
+    member = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape[1:], x.dtype), like
+    )
+    members = [
+        _unflatten(flat[m * n2 : (m + 1) * n2], member) for m in range(2)
+    ]
     return jax.tree.map(
         lambda a, b: jnp.stack([a, b]), members[0], members[1]
     )
 
 
-def state_vmem_bytes(config: DDPGConfig, obs_dim: int, act_dim: int) -> int:
-    """f32 bytes of the kernel's VMEM-resident state: 8 copies of each net's
-    tensors (params, targets, mu, nu for actor+critic), unpadded. What
-    Mosaic allocates is this about once plus temporaries that grow with the
-    batch: the table above VMEM_STATE_BUDGET has the measured pairs."""
+def _layer_shapes(config: DDPGConfig, obs_dim: int, act_dim: int):
+    """((fan_in, out) of each actor layer, of each layer of one critic,
+    critics in the state)."""
 
     def net(dims, extra_in=0):
-        total = 0
-        for i in range(len(dims) - 1):
-            d_in = dims[i] + (extra_in if i == 1 else 0)
-            total += d_in * dims[i + 1] + dims[i + 1]
-        return total
+        return [
+            (dims[i] + (extra_in if i == 1 else 0), dims[i + 1])
+            for i in range(len(dims) - 1)
+        ]
 
     # obs/act enter the actor/critic input dims; action rides into critic
     # layer 1 (action_insert_layer == 1 inside the supported envelope).
@@ -154,44 +169,83 @@ def state_vmem_bytes(config: DDPGConfig, obs_dim: int, act_dim: int) -> int:
     # ([mean | log_std]) and has critic_ensemble critics (2 unless REDQ).
     out = config.num_atoms if config.distributional else 1
     head = 2 * act_dim if config.sac else act_dim
-    a = net([obs_dim, *config.actor_hidden, head])
-    c = net([obs_dim, *config.critic_hidden, out], extra_in=act_dim)
-    if config.twin_critic or config.sac:
-        c *= config.critic_ensemble
+    members = config.critic_ensemble if (config.twin_critic or config.sac) else 1
+    return (
+        net([obs_dim, *config.actor_hidden, head]),
+        net([obs_dim, *config.critic_hidden, out], extra_in=act_dim),
+        members,
+    )
+
+
+def state_vmem_bytes(config: DDPGConfig, obs_dim: int, act_dim: int) -> int:
+    """f32 bytes of the kernel's VMEM-resident state: 8 copies of each net's
+    tensors (params, targets, mu, nu for actor+critic), unpadded. What
+    Mosaic allocates is this about once plus temporaries that grow with the
+    batch: the table above VMEM_STATE_BUDGET has the measured pairs."""
+    actor, critic, members = _layer_shapes(config, obs_dim, act_dim)
+    a = sum(i * o + o for i, o in actor)
+    c = members * sum(i * o + o for i, o in critic)
     return 4 * (4 * a + 4 * c)
+
+
+def state_tiles(config: DDPGConfig, obs_dim: int, act_dim: int) -> int:
+    """(8, 128) tiles of ONE copy of the resident parameters (actor plus
+    every critic) as the kernel holds them, output layers lane-major where
+    lane_major says; the targets and the two Adam moments are three copies
+    more. What the optimiser tail walks, a vreg a tile: the run fact
+    `kernel_state_tiles` (DDPG 2x256 at 17 / 6: 156, where [F, out] heads
+    made it 216)."""
+    actor, critic, members = _layer_shapes(config, obs_dim, act_dim)
+
+    def net(layers):
+        *body, (f, out) = layers
+        head = _tiles(out, f) if lane_major(f, out) else _tiles(f, out)
+        return head + sum(_tiles(i, o) for i, o in body) + sum(
+            _tiles(1, o) for _, o in layers
+        )
+
+    return net(actor) + members * net(critic)
 
 
 # VMEM budget for the resident state. It decides the leg (fits_vmem); it is
 # not what Mosaic counts. What has met the compiler (libtpu 0.0.34), as the
 # smallest vmem_limit_bytes each kernel compiles under for a described v5e,
-# bisected to 1/16 MiB (PRs 31 and 32; obs 17 / act 6, chunk 800; Mosaic's
-# default limit is 16 MiB and no caller passes another):
+# bisected to 1/16 MiB (obs 17 / act 6, chunk 800; Mosaic's default limit is
+# 16 MiB and no caller passes another). Re-bisected in PR 34 with each net's
+# output layer lane-major; the last column is the same bisection of the tree
+# before it, [F, out] heads (PRs 31 and 32 read the same to 1/16):
 #
-#   family, widths, batch        state_vmem_bytes   scoped VMEM
-#   DDPG  2x256    64            2.20 MiB            3.51 MiB
-#   TD3   2x256    64            3.30                4.08
-#   SAC   2x256   256            3.32                5.57
-#   C51   2x256   256            2.40                5.25
-#   C51   400-300 100            4.18                5.31
-#   C51   400-300 256            4.18                7.88
-#   TD3   400-300 100            5.93                9.23
-#   TD3   400-300 256            5.93               15.40
+#   family, widths, batch        state_vmem_bytes   scoped VMEM   [F, out] heads
+#   DDPG  2x256    64            2.20 MiB            3.31 MiB      3.56
+#   TD3   2x256    64            3.30                3.25          4.06
+#   SAC   2x256   256            3.32                5.13          5.56
+#   C51   2x256   256            2.40                5.44          5.25
+#   C51   400-300 100            4.18                5.50          5.31
+#   C51   400-300 256            4.18                8.38          7.88
+#   TD3   400-300 100            5.93                8.06          9.25
+#   TD3   400-300 256            5.93               11.25         15.50
 #
 # So the scoped allocation is the state about once plus the body's
-# temporaries, and those grow with the batch (at 400-300: TD3 42 KiB a row,
-# C51 17) and with what the branch keeps alive, not with the state. A loop
-# that spills shows here first: with the projection's operands batch-on-
-# sublanes the C51 rows read 13.19 / 8.01 / 14.60 (kernel_projection). All
-# four benchmark configurations run through train() under the default (DDPG
-# 2x256 batch 64, C51 400-300 batch 256, TD3 400-300 batch 100; SAC at
-# Humanoid's 376 / 17 is over this budget and takes the scan leg);
-# tests/test_ring_layout.py compiles the cells' kernels, each under the
-# default and under its own figure plus a MiB. The C51 kernel with its edge
-# mass summed on every grid step and selected, as td3_twin_gap is, takes
-# 7.75. What would be refused today though fits_vmem says yes: a batch past
-# about 270 rows at 400-300 on the TD3 branch. The repair then is
-# vmem_limit_bytes, set from a fit to the table above, batch term first; no
-# configuration anyone runs needs it.
+# temporaries, and those grow with the batch (at 400-300: TD3 21 KiB a row,
+# C51 19) and with what the branch keeps alive, not with the state. The
+# lane-major heads took a quarter of a MiB to four MiB off the scalar-headed
+# families (each [F, 1] or [F, 6] tensor was 128-152 KiB of padding, four
+# copies a net, and TD3's per-row temporaries halved) and ADDED 0.19-0.50
+# MiB to the categorical ones, whose [51, 300] head saves 17 tiles a copy
+# and whose batch-contracting products hold other temporaries: still half
+# the default. A loop that spills shows here first: with the projection's
+# operands batch-on-sublanes the C51 rows read 13.19 / 8.01 / 14.60
+# (kernel_projection, on [F, out] heads). All four benchmark configurations
+# run through train() under the default (DDPG 2x256 batch 64, C51 400-300
+# batch 256, TD3 400-300 batch 100; SAC at Humanoid's 376 / 17 is over this
+# budget and takes the scan leg); tests/test_ring_layout.py compiles the
+# cells' kernels, each under the default and under its own figure plus
+# five eighths of a MiB to a MiB. The C51 kernel with its edge mass summed
+# on every grid step and selected, as td3_twin_gap is, took 7.75 against
+# 7.88 (on [F, out] heads). What would be refused today though fits_vmem says yes: by the
+# table's slopes, a batch past about 480 rows at 400-300 on the TD3 branch
+# (270 before). The repair then is vmem_limit_bytes, set from a fit to the
+# table above, batch term first; no configuration anyone runs needs it.
 VMEM_STATE_BUDGET = 6 * 1024 * 1024
 
 
@@ -374,6 +428,44 @@ def _make_kernel(
         def Bv(group, i):
             return group[2 * i + 1][...]
 
+        # A net's output layer, held [out, F] where lane_major says (the
+        # wrapper's _flatten asks the same function): the same three
+        # bf16-operand MXU products over the same contractions as [F, out]
+        # has, the operand forms swapped round.
+        def head_fwd(group, n, h):
+            """h [B, F] through output layer n - 1 -> [B, out]."""
+            w, b = W(group, n - 1), Bv(group, n - 1)
+            if not lane_major(h.shape[-1], b.shape[-1]):
+                return _mm(h, w) + b
+            if w.shape[0] > 1:
+                return _dx(h, w) + b
+            # The scalar head: h @ w.T through a transposed copy of the
+            # weight, padded to the 8 sublanes (Mosaic refuses w.T of a
+            # [1, F], and _dx(h, w): "Lane broadcast"). The copy depends on
+            # the weights alone, so the XLU makes it off the critical path
+            # and h streams past a stationary parameter as in every other
+            # layer. The one other form Mosaic takes, _dx(w, h).T, makes the
+            # fresh activations the MXU's stationary operand and transposes
+            # the result, behind each of an update's three critic forwards:
+            # 0.33 us of DDPG's 4.2 us update on the chip (PERF.md, PR 34).
+            w8 = jnp.concatenate(
+                [w, jnp.zeros((7, w.shape[1]), w.dtype)], axis=0
+            ).T
+            return _mm(h, w8)[:, :1] + b
+
+        def head_dW(h, dz):
+            """The output layer's weight gradient, in the shape it is held."""
+            if lane_major(h.shape[-1], dz.shape[-1]):
+                return _dW(dz, h)
+            return _dW(h, dz)
+
+        def head_dx(h, dz, w):
+            """dz [B, out] back through the output layer to its input h's
+            [B, F]."""
+            if lane_major(h.shape[-1], dz.shape[-1]):
+                return _mm(dz, w)
+            return _dx(dz, w)
+
         obs = obs_r[0]
         action = act_r[0]
         if not distributional:
@@ -391,8 +483,7 @@ def _make_kernel(
             for i in range(n_actor - 1):
                 z = _mm(acts[-1], W(group, i)) + Bv(group, i)
                 acts.append(jnp.maximum(z, 0.0))
-            z = _mm(acts[-1], W(group, n_actor - 1)) + Bv(group, n_actor - 1)
-            t = jnp.tanh(z)
+            t = jnp.tanh(head_fwd(group, n_actor, acts[-1]))
             return t * scale + offset, (acts, t)
 
         def critic_fwd(group, x, a):
@@ -411,8 +502,7 @@ def _make_kernel(
             for i in range(2, n_critic - 1):
                 z = _mm(acts[-1], W(group, i)) + Bv(group, i)
                 acts.append(jnp.maximum(z, 0.0))
-            q = _mm(acts[-1], W(group, n_critic - 1)) + Bv(group, n_critic - 1)
-            return q, acts  # q: [B, 1]
+            return head_fwd(group, n_critic, acts[-1]), acts  # q: [B, 1]
 
         def critic_bwd(group, acts, a, dq_in, wgrads: bool):
             """Backprop dq through the critic. With wgrads, returns
@@ -422,10 +512,12 @@ def _make_kernel(
             grads = [None] * nc2
             dz = dq_in
             for i in range(n_critic - 1, 1, -1):
+                head = i == n_critic - 1
                 if wgrads:
-                    grads[2 * i] = _dW(acts[i], dz)
+                    grads[2 * i] = (head_dW if head else _dW)(acts[i], dz)
                     grads[2 * i + 1] = jnp.sum(dz, axis=0, keepdims=True)
-                dh = _dx(dz, W(group, i))
+                w = W(group, i)
+                dh = head_dx(acts[i], dz, w) if head else _dx(dz, w)
                 dz = dh * (acts[i] > 0.0)
             # layer 1 (split weights)
             w1 = W(group, 1)
@@ -450,10 +542,14 @@ def _make_kernel(
             Shared by the deterministic actor (after its tanh chain) and
             the SAC Gaussian head (whose output layer is linear)."""
             grads = [None] * na2
-            grads[2 * (n_actor - 1)] = _dW(acts[n_actor - 1], dz)
+            grads[2 * (n_actor - 1)] = head_dW(acts[n_actor - 1], dz)
             grads[2 * (n_actor - 1) + 1] = jnp.sum(dz, axis=0, keepdims=True)
             for i in range(n_actor - 2, -1, -1):
-                dh = _dx(dz, W(group, i + 1))
+                w = W(group, i + 1)
+                if i == n_actor - 2:
+                    dh = head_dx(acts[i + 1], dz, w)
+                else:
+                    dh = _dx(dz, w)
                 dz = dh * (acts[i + 1] > 0.0)
                 grads[2 * i] = _dW(acts[i], dz)
                 grads[2 * i + 1] = jnp.sum(dz, axis=0, keepdims=True)
@@ -509,9 +605,7 @@ def _make_kernel(
                 for i in range(n_actor - 1):
                     z = _mm(acts[-1], W(group, i)) + Bv(group, i)
                     acts.append(jnp.maximum(z, 0.0))
-                zL = _mm(acts[-1], W(group, n_actor - 1)) + Bv(
-                    group, n_actor - 1
-                )
+                zL = head_fwd(group, n_actor, acts[-1])
                 mean = zL[:, :A]
                 tr = jnp.tanh(zL[:, A:])
                 log_std = m0 + hw * (tr + 1.0)

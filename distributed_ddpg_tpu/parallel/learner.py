@@ -444,6 +444,14 @@ class ShardedLearner:
         self.fused_chunk_active = envelope_ok and (
             self.mesh.size == 1 or self.fused_mesh_active
         )
+        # (8, 128) tiles of one copy of the kernel's resident parameters, its
+        # output layers lane-major where the shape rule says (the run fact
+        # `kernel_state_tiles`); None on the scan leg, which holds no tiles.
+        self.kernel_state_tiles = (
+            fused_chunk_lib.state_tiles(config, obs_dim, act_dim)
+            if self.fused_chunk_active
+            else None
+        )
         if config.fused_chunk == "on" and not self.fused_chunk_active:
             raise ValueError(
                 "fused_chunk='on' but the config/mesh is outside the kernel "

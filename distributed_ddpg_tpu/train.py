@@ -1292,15 +1292,17 @@ def _train_jax_impl(
         """Where and how the learner runs, as observed (never as
         configured): device platform/kind/count, the resolved
         steps-per-dispatch, whether the Pallas megakernel is the chunk
-        program, and — from live sharding metadata, zero d2h — how many
-        devices hold the TrainState (the least-spread leaf) and the
-        replay ring. On the header and final records and the returned
+        program and how many (8, 128) tiles its resident parameters take
+        (null on the scan leg), and — from live sharding metadata, zero
+        d2h — how many devices hold the TrainState (the least-spread leaf)
+        and the replay ring. On the header and final records and the returned
         summary — what chip_smoke.py and every benchmark cell read to
         know a number came from the chip and from which learner leg."""
         facts = {
             **device_facts(),
             "learner_chunk": chunk,
             "fused_chunk_active": learner.fused_chunk_active,
+            "kernel_state_tiles": learner.kernel_state_tiles,
             "state_devices": min(
                 len(leaf.sharding.device_set)
                 for leaf in jax.tree.leaves(learner.state)

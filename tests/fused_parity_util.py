@@ -84,6 +84,12 @@ def assert_fused_matches_scan(
     close(new_state.target_critic_params, ref.target_critic_params)
     close(new_state.actor_opt.mu, ref.actor_opt.mu)
     close(new_state.critic_opt.nu, ref.critic_opt.nu)
+    # The other two moment trees: the kernel may hold a net's output layer
+    # lane-major (fused_chunk.lane_major), so its target and both moments
+    # are held to the TrainState's [F, out] (a transposed leaf fails on its
+    # shape) and values too, not only its weights.
+    close(new_state.actor_opt.nu, ref.actor_opt.nu)
+    close(new_state.critic_opt.mu, ref.critic_opt.mu)
     # The reference scan IS the count oracle: TD3's delayed actor updates
     # advance actor_opt.count less often than the critic's.
     assert int(new_state.actor_opt.count) == int(ref.actor_opt.count)

@@ -223,6 +223,121 @@ def test_sharded_learner_fused_path_matches_scan_path():
         )
 
 
+@pytest.mark.parametrize(
+    "fan_in,out,tiles_as_state,tiles_lane_major,held_lane_major",
+    [
+        (256, 1, 32, 2, True),  # DDPG's critic head at 2x256
+        (256, 6, 32, 2, True),  # its actor head
+        (300, 51, 38, 21, True),  # the categorical head at 400-300
+        (256, 12, 32, 4, True),  # SAC's Gaussian head, [mean | log_std]
+        (300, 1, 38, 3, True),  # TD3's critic heads at 400-300
+        (16, 21, 2, 3, False),  # wider than deep: [F, out] is the smaller
+        (128, 128, 16, 16, False),  # a tie stays as the state has it
+    ],
+)
+def test_output_layer_rides_lane_major_where_that_takes_fewer_tiles(
+    fan_in, out, tiles_as_state, tiles_lane_major, held_lane_major
+):
+    """The shape rule (fused_chunk.lane_major) and the wrapper's two ends:
+    _flatten hands the kernel a net's OUTPUT layer as [out, F] where that
+    takes fewer (8, 128) tiles, hidden layers and biases as they are, and
+    _unflatten (and the twin forms over a [2, ...] ensemble) gives back the
+    TrainState's shapes and the same values."""
+    assert fused_chunk._tiles(fan_in, out) == tiles_as_state
+    assert fused_chunk._tiles(out, fan_in) == tiles_lane_major
+    assert fused_chunk.lane_major(fan_in, out) is held_lane_major
+    rng = np.random.default_rng(fan_in + out)
+    dims = [(406, 300), (300, fan_in), (fan_in, out)]  # [406, 300] alone would flip: hidden layers never do
+    params = tuple(
+        {
+            "w": rng.standard_normal(d).astype(np.float32),
+            "b": rng.standard_normal(d[1]).astype(np.float32),
+        }
+        for d in dims
+    )
+    flat = fused_chunk._flatten(params)
+    held = (out, fan_in) if held_lane_major else (fan_in, out)
+    assert [x.shape for x in flat] == [
+        (406, 300), (1, 300), (300, fan_in), (1, fan_in), held, (1, out),
+    ]
+    if held_lane_major:
+        np.testing.assert_array_equal(flat[4], params[2]["w"].T)
+    back = fused_chunk._unflatten(flat, params)
+    assert jax.tree.structure(back) == jax.tree.structure(params)
+    jax.tree.map(np.testing.assert_array_equal, back, params)
+
+    twin = jax.tree.map(lambda x: np.stack([x, -x]), params)
+    flat2 = fused_chunk._flatten_twin(twin)
+    assert [x.shape for x in flat2] == 2 * [x.shape for x in flat]
+    back2 = fused_chunk._unflatten_twin(flat2, twin)
+    assert jax.tree.structure(back2) == jax.tree.structure(twin)
+    jax.tree.map(
+        lambda x, y: np.testing.assert_array_equal(np.asarray(x), y), back2, twin
+    )
+
+
+@pytest.mark.parametrize(
+    "kwargs,tiles",
+    [
+        ({}, 156),  # DDPG 2x256: 216 with [F, out] heads
+        ({"twin_critic": True, "actor_hidden": (400, 300), "critic_hidden": (400, 300)}, 525),  # TD3: 630
+        ({"distributional": True, "num_atoms": 51, "actor_hidden": (400, 300), "critic_hidden": (400, 300)}, 367),  # C51: 419
+    ],
+)
+def test_state_tiles_counts_what_the_kernel_holds(kwargs, tiles):
+    """The run fact `kernel_state_tiles`, at the three kernel cells' shapes
+    (HalfCheetah's 17 / 6): one copy of the parameters, each net's output
+    layer in the orientation lane_major picks."""
+    assert fused_chunk.state_tiles(DDPGConfig(**kwargs), 17, 6) == tiles
+
+
+def test_parent_checkpoint_restores_and_continues_on_the_kernel_leg(tmp_path):
+    """tests/ckpt_fixtures/parent_pr33 was written by checkpoint.save on the
+    tree of commit c3ed97c (before any head rode lane-major): three scan-leg
+    updates of a 16-16 DDPG pair at obs 5 / act 3. It restores into a
+    kernel-leg learner's state, the kernel continues from it as the scan leg
+    does, and what the learner then holds, hands the actors and would
+    checkpoint has the parent's shapes."""
+    import os
+    import shutil
+
+    from distributed_ddpg_tpu import checkpoint as ckpt_lib
+    from distributed_ddpg_tpu.parallel.learner import ShardedLearner
+    from distributed_ddpg_tpu.parallel.mesh import make_mesh
+    from distributed_ddpg_tpu.replay.device import DeviceReplay
+
+    src = os.path.join(os.path.dirname(os.path.abspath(__file__)), "ckpt_fixtures", "parent_pr33")
+    directory = str(tmp_path / "ckpt")
+    shutil.copytree(src, directory)  # restore quarantines in place what fails to verify
+    cfg = DDPGConfig(actor_hidden=(16, 16), critic_hidden=(16, 16), batch_size=B, seed=3)
+    mesh = make_mesh(1, 1, devices=jax.devices()[:1])
+    rows = _batches(np.random.default_rng(11), 16).reshape(-1, 2 * OBS + ACT + 3)
+    end = {}
+    for mode in ("on", "off"):
+        lrn = ShardedLearner(
+            cfg.replace(fused_chunk=mode), OBS, ACT, action_scale=1.5,
+            action_offset=0.25, mesh=mesh, chunk_size=K,
+        )
+        assert lrn.fused_chunk_active == (mode == "on")
+        assert lrn.kernel_state_tiles == (15 if mode == "on" else None)  # 17 with [F, out] heads
+        shapes = jax.tree.map(lambda x: x.shape, lrn.state)
+        restored, step, env_steps = ckpt_lib.restore(directory, lrn.state, config=cfg)
+        assert (step, env_steps) == (3, 48)
+        assert restored.actor_params[-1]["w"].shape == (16, ACT)
+        assert restored.critic_opt.nu[-1]["w"].shape == (16, 1)
+        assert float(np.abs(restored.critic_opt.nu[-1]["w"]).max()) > 0  # moments the parent's updates left
+        lrn.state = jax.device_put(restored, lrn._state_sharding)
+        rep = DeviceReplay(capacity=256, obs_dim=OBS, act_dim=ACT, mesh=mesh, block_size=256)
+        rep.add_packed(rows)
+        lrn.run_sample_chunk(rep)
+        assert jax.tree.map(lambda x: x.shape, lrn.state) == shapes
+        assert lrn.actor_params_to_host()[-1]["w"].shape == (16, ACT)
+        end[mode] = jax.device_get(lrn.state)
+    assert int(end["on"].step) == int(end["off"].step) == 3 + K
+    assert int(end["on"].critic_opt.count) == 3 + K
+    _assert_tree_close(end["on"], end["off"])
+
+
 def test_auto_mode_kernel_failure_raises(monkeypatch):
     """fused_chunk='auto': whether the kernel runs is decided before
     tracing by stated rules (supported / fits_vmem / runs_native). A

@@ -812,6 +812,7 @@ def test_train_run_keys_are_documented(tmp_path):
         assert rec["replay_devices"] == 8
         assert rec["learner_chunk"] == 8
         assert rec["fused_chunk_active"] is False
+        assert rec["kernel_state_tiles"] is None  # the scan leg holds no tiles
     assert records[0]["kind"] == "header" and records[-1]["kind"] == "final"
     assert np.isfinite(records[-1]["critic_loss"])
     assert records[-1]["first_chunk_s"] > 0 and records[-1]["steady_s"] > 0

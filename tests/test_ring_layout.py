@@ -196,6 +196,51 @@ def test_ring_program_only_where_the_ring_is_row_major():
 CAPACITY = 1_000_000  # rows, the papers' ring: too large for XLA to move into faster memory
 
 
+# --- the megakernel's static schedule (tools/kernel_bundles.py): the TPU
+# compiler's own count of an update's VLIW bundles, for each kernel cell's
+# configuration. The dumper aborts the process it runs in, so each compile
+# is a CHILD's, and a child can load the TPU library only while no other
+# process of this run holds it: these cases stand ABOVE the first test that
+# asks for v5e_sharding, which loads the library into this process for good
+# (xdist hands the file to one worker, in file order). ---
+
+_TPU_LIBRARY_HELD = []  # v5e_sharding appends once this process has loaded libtpu
+
+
+@pytest.mark.parametrize(
+    "name,update_ceiling,delayed_ceiling",
+    [
+        # an update's bundles outside any branch / under the largest branch
+        # (TD3's delayed actor and targets; nothing of that size elsewhere).
+        # PR 34 read 4,290, 11,255 + 2,723 and 16,562 with each net's output
+        # layer lane-major; with [F, out] heads 5,280, 12,350 + 3,363, 17,279.
+        ("ddpg-halfcheetah", 4500, 200),
+        ("td3-halfcheetah", 11700, 2900),
+        ("d4pg-halfcheetah", 16800, 200),
+    ],
+)
+def test_megakernel_static_update_stays_under_its_ceiling(name, update_ceiling, delayed_ceiling):
+    """A later PR that pads a head again, or spills a loop, learns so here
+    and not on the chip. Bundles are issue cycles, not time."""
+    import importlib.util
+    import os
+    import tempfile
+
+    from distributed_ddpg_tpu.tools import kernel_bundles as kb
+
+    if importlib.util.find_spec("libtpu") is None:
+        pytest.skip("no TPU compiler here: nothing to compile for")
+    if _TPU_LIBRARY_HELD and not os.environ.get("ALLOW_MULTIPLE_LIBTPU_LOAD"):
+        pytest.skip("this process already holds the TPU library: run the file from its top")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with tempfile.TemporaryDirectory() as directory:  # ~330 MB of compiler text, gone with the case
+        kb.compile_and_dump(os.path.join(root, "benchmarks", "configs", name + ".json"), directory)
+        ph = kb.phases(kb.load_dump(directory).bundles)
+    delayed = max((n for _, n in ph["branched"]), default=0)
+    assert ph["update"] - delayed <= update_ceiling, ph
+    assert delayed <= delayed_ceiling, ph
+
+
 @pytest.fixture(scope="module")
 def v5e_sharding():
     from jax.experimental import topologies
@@ -204,6 +249,7 @@ def v5e_sharding():
         topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     except Exception as e:  # no TPU compiler here: nothing to compile for
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    _TPU_LIBRARY_HELD.append(True)
     mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "model"))
     return NamedSharding(mesh, P(None, None))
 
@@ -308,20 +354,23 @@ MIB = 1024 * 1024
     [
         ("ddpg-halfcheetah", None),
         ("d4pg-halfcheetah", None),  # the program as train() builds it: Mosaic's default, 16 MiB
-        # 400-300 at batch 256 x 51 atoms takes 7.88 MiB of scoped VMEM (the
-        # smallest limit it compiles under, bisected to 1/16 MiB). A MiB of
-        # room is kept: a projection loop that spills again (it read 14.60
+        # 400-300 at batch 256 x 51 atoms takes 8.38 MiB of scoped VMEM (the
+        # smallest limit it compiles under, bisected to 1/16 MiB; 7.88 before
+        # its [300, 51] head rode lane-major, PR 34). Five eighths of a MiB
+        # of room is kept: a projection loop that spills again (it read 14.60
         # with its operands batch-on-sublanes) fails here, not on the chip.
         ("d4pg-halfcheetah", 9),
         # TD3 at the paper's 400-300, twin critics, batch 100 (no multiple of
         # the 8 sublanes: Mosaic takes the blocks and the batch-contracting
         # dots as they are): 5.93 MiB of state, the largest of the cells', and
-        # 9.23 MiB of scoped VMEM (the smallest limit it compiles under,
-        # bisected to 1/16 MiB), since the kernel's temporaries grow with the
-        # batch and not with the state. Under the default as train() builds
-        # it, and with three quarters of a MiB of room kept.
+        # 8.06 MiB of scoped VMEM (bisected to 1/16 MiB; 9.25 with [F, out]
+        # heads), since the kernel's temporaries grow with the batch and not
+        # with the state. Under the default as train() builds it, and with
+        # fifteen sixteenths of a MiB of room kept.
         ("td3-halfcheetah", None),
-        ("td3-halfcheetah", 10),
+        ("td3-halfcheetah", 9),
+        # DDPG 2x256 at batch 64: 3.31 MiB (3.56 with [F, out] heads).
+        ("ddpg-halfcheetah", 4),
     ],
 )
 def test_v5e_megakernel_chunk_fits_scoped_vmem(v5e_sharding, monkeypatch, name, limit_mib):

@@ -480,6 +480,43 @@ def test_restore_rejects_incompatible_config(tmp_path):
         )
 
 
+@pytest.mark.parametrize("leg,tiles", [("on", 15), ("off", None)])
+def test_train_reports_kernel_state_tiles_on_the_kernel_leg_only(tmp_path, leg, tiles):
+    """The run fact that shows the lane-major output layers engaged
+    (ops/fused_chunk.state_tiles): on the header, the final record and the
+    summary of a kernel-leg run through train() (the interpreter here, on
+    the data-only mesh's fused-mesh leg), null on the scan leg's. Pendulum
+    at 16-16: 15 tiles, 17 with [F, out] heads."""
+    import json
+
+    cfg = DDPGConfig(
+        backend="jax_tpu",
+        env_id="Pendulum-v1",
+        actor_hidden=(16, 16),
+        critic_hidden=(16, 16),
+        batch_size=16,
+        num_actors=1,
+        total_env_steps=600,
+        replay_min_size=200,
+        replay_capacity=4_096,
+        learner_chunk=2,
+        max_learn_ratio=0.05,
+        eval_every=0,
+        fused_chunk=leg,
+        log_path=str(tmp_path / "metrics.jsonl"),
+    )
+    out = train_jax(cfg)
+    assert out["learner_steps"] > 0
+    assert out["fused_chunk_active"] is (leg == "on")
+    records = [json.loads(line) for line in open(cfg.log_path)]
+    header, final = records[0], records[-1]
+    assert header["kind"] == "header" and final["kind"] == "final"
+    for rec in (header, final, out):
+        assert "kernel_state_tiles" in rec and rec["kernel_state_tiles"] == tiles
+    # The actors were handed [F, out] throughout: the run learned and ended clean.
+    assert np.isfinite(final["critic_loss"])
+
+
 def test_train_returns_setup_spans_and_counts_finished_updates(tmp_path):
     """What a job waits through before its first useful step, measured from
     inside (ISSUE 25): five disjoint stages in order, no longer together
