@@ -32,6 +32,7 @@ from distributed_ddpg_tpu.config import DDPGConfig
 from distributed_ddpg_tpu.ops import losses
 from distributed_ddpg_tpu.ops.optim import adam_update
 from distributed_ddpg_tpu.ops.polyak import polyak_update
+from distributed_ddpg_tpu.trace import device_scope
 from distributed_ddpg_tpu.types import Batch, OptState, TrainState
 from distributed_ddpg_tpu.models.mlp import actor_init, critic_init
 
@@ -86,10 +87,11 @@ def chunk_metrics(ms: dict) -> dict:
     key's mean over the K updates, except LAST_UPDATE_KEYS, which are the
     chunk's last update's, as the megakernel computes them on its last grid
     step only. For every other family this is the tree.map it replaces."""
-    out = jax.tree.map(lambda x: jnp.mean(x), ms)
-    for k in LAST_UPDATE_KEYS:
-        if k in ms:
-            out[k] = ms[k][-1]
+    with device_scope("metrics"):
+        out = jax.tree.map(lambda x: jnp.mean(x), ms)
+        for k in LAST_UPDATE_KEYS:
+            if k in ms:
+                out[k] = ms[k][-1]
     return out
 
 
@@ -208,9 +210,10 @@ def chunk_noise(config: DDPGConfig, step0, chunk: int, batch: int,
     if not draws_noise(config):
         return None
     base = noise_base_key(config)
-    return jax.vmap(
-        lambda s: step_noise(config, base, s, batch, act_dim, device_fold)
-    )(step0 + jnp.arange(chunk))
+    with device_scope("noise"):
+        return jax.vmap(
+            lambda s: step_noise(config, base, s, batch, act_dim, device_fold)
+        )(step0 + jnp.arange(chunk))
 
 
 def init_train_state(config: DDPGConfig, obs_dim: int, act_dim: int, seed: int) -> TrainState:
@@ -348,10 +351,11 @@ def make_learner_step(
                 subset=subset, ensemble_stats=config.redq,
             )
 
-        (closs, td), cgrads = jax.value_and_grad(critic_loss_fn, has_aux=True)(
-            state.critic_params
-        )
-        cgrads = _maybe_psum_mean(cgrads, axis_name)
+        with device_scope("critic"):
+            (closs, td), cgrads = jax.value_and_grad(
+                critic_loss_fn, has_aux=True
+            )(state.critic_params)
+            cgrads = _maybe_psum_mean(cgrads, axis_name)
 
         # Actor gradient against the pre-update critic (file convention):
         # its ensemble's mean where the target draws a subset (REDQ,
@@ -365,13 +369,14 @@ def make_learner_step(
             )
 
         def actor_grads():
-            (aloss, mean_lp), agrads = jax.value_and_grad(
-                actor_loss_fn, has_aux=True
-            )(state.actor_params)
-            agrads = _maybe_psum_mean(agrads, axis_name)
-            # Global mean log-prob so every shard's alpha update sees the same
-            # scalar (replicas must not fork on log_alpha).
-            return aloss, _maybe_psum_mean(mean_lp, axis_name), agrads
+            with device_scope("actor"):
+                (aloss, mean_lp), agrads = jax.value_and_grad(
+                    actor_loss_fn, has_aux=True
+                )(state.actor_params)
+                agrads = _maybe_psum_mean(agrads, axis_name)
+                # Global mean log-prob so every shard's alpha update sees the
+                # same scalar (replicas must not fork on log_alpha).
+                return aloss, _maybe_psum_mean(mean_lp, axis_name), agrads
 
         def actor_adam(agrads):
             return adam_update(
@@ -541,13 +546,14 @@ def make_learner_step(
                     mm,
                 )
 
-        (closs, td), cgrads = jax.value_and_grad(critic_loss_fn, has_aux=True)(
-            state.critic_params
-        )
+        with device_scope("critic"):
+            (closs, td), cgrads = jax.value_and_grad(
+                critic_loss_fn, has_aux=True
+            )(state.critic_params)
+            cgrads = _maybe_psum_mean(cgrads, axis_name)
         branch_metrics = ()
         if config.distributional or config.twin_critic:
             td, *branch_metrics = td  # (td, edge_mass) / (td, twin_gap)
-        cgrads = _maybe_psum_mean(cgrads, axis_name)
 
         # --- actor update (pre-update critic: both grads from the same state) ---
         if config.twin_critic:
@@ -579,14 +585,16 @@ def make_learner_step(
             # stays aligned. actor_opt.count only advances on real
             # updates, keeping Adam bias correction honest; updates land
             # on critic steps 0, d, 2d, ... (pre-increment step).
-            aloss = actor_loss_fn(state.actor_params)
+            with device_scope("actor"):
+                aloss = actor_loss_fn(state.actor_params)
             new_critic, critic_opt = adam_update(
                 state.critic_params, cgrads, state.critic_opt, config.critic_lr
             )
 
             def _delayed_update(_):
-                agrads = jax.grad(actor_loss_fn)(state.actor_params)
-                agrads = _maybe_psum_mean(agrads, axis_name)
+                with device_scope("actor"):
+                    agrads = jax.grad(actor_loss_fn)(state.actor_params)
+                    agrads = _maybe_psum_mean(agrads, axis_name)
                 na, aopt = adam_update(
                     state.actor_params, agrads, state.actor_opt, config.actor_lr
                 )
@@ -620,8 +628,11 @@ def make_learner_step(
                 operand=None,
             )
         elif config.fused_update:
-            aloss, agrads = jax.value_and_grad(actor_loss_fn)(state.actor_params)
-            agrads = _maybe_psum_mean(agrads, axis_name)
+            with device_scope("actor"):
+                aloss, agrads = jax.value_and_grad(actor_loss_fn)(
+                    state.actor_params
+                )
+                agrads = _maybe_psum_mean(agrads, axis_name)
             actor_grad_norm = optree_norm(agrads)
             # Pallas kernel: Adam + Polyak in one VPU pass (ops/fused_update.py).
             from distributed_ddpg_tpu.ops.fused_update import fused_adam_polyak
@@ -635,8 +646,11 @@ def make_learner_step(
                 state.target_actor_params, config.actor_lr, config.tau,
             )
         else:
-            aloss, agrads = jax.value_and_grad(actor_loss_fn)(state.actor_params)
-            agrads = _maybe_psum_mean(agrads, axis_name)
+            with device_scope("actor"):
+                aloss, agrads = jax.value_and_grad(actor_loss_fn)(
+                    state.actor_params
+                )
+                agrads = _maybe_psum_mean(agrads, axis_name)
             actor_grad_norm = optree_norm(agrads)
             new_critic, critic_opt = adam_update(
                 state.critic_params, cgrads, state.critic_opt, config.critic_lr

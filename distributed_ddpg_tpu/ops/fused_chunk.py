@@ -81,6 +81,7 @@ import math
 from distributed_ddpg_tpu.config import DDPGConfig
 from distributed_ddpg_tpu.learner import chunk_noise, delayed_updates, metric_keys
 from distributed_ddpg_tpu.ops.optim import B1, B2, EPS
+from distributed_ddpg_tpu.trace import device_scope
 from distributed_ddpg_tpu.types import TrainState, OptState
 
 _LOG_B1 = math.log(B1)
@@ -1014,41 +1015,47 @@ def make_fused_chunk_fn(
         n_critic = len(state.critic_params)
         na2, nc2 = 2 * n_actor, 2 * n_critic
 
-        obs = batches[..., :o]
-        act = batches[..., o : o + a]
-        if config.distributional:
-            # The categorical branch takes reward and discount lane-major,
-            # [K, 2, B], cut from the same gathered rows.
-            rew_disc = (jnp.swapaxes(batches[..., o + a : o + a + 2], 1, 2),)
-        else:
-            rew_disc = (
-                batches[..., o + a : o + a + 1],
-                batches[..., o + a + 1 : o + a + 2],
-            )
-        nobs = batches[..., o + a + 2 : 2 * o + a + 2]
-        wgt = batches[..., 2 * o + a + 2 : 2 * o + a + 3]
+        with device_scope("cut"):
+            obs = batches[..., :o]
+            act = batches[..., o : o + a]
+            if config.distributional:
+                # The categorical branch takes reward and discount
+                # lane-major, [K, 2, B], cut from the same gathered rows.
+                rew_disc = (
+                    jnp.swapaxes(batches[..., o + a : o + a + 2], 1, 2),
+                )
+            else:
+                rew_disc = (
+                    batches[..., o + a : o + a + 1],
+                    batches[..., o + a + 1 : o + a + 2],
+                )
+            nobs = batches[..., o + a + 2 : 2 * o + a + 2]
+            wgt = batches[..., 2 * o + a + 2 : 2 * o + a + 3]
         streams = (obs, act, *rew_disc, nobs, wgt)
 
         flat_c = _flatten_twin if (twin or sac) else _flatten
-        state_flat = (
-            _flatten(state.actor_params)
-            + flat_c(state.critic_params)
-            + _flatten(state.target_actor_params)
-            + flat_c(state.target_critic_params)
-            + _flatten(state.actor_opt.mu)
-            + _flatten(state.actor_opt.nu)
-            + flat_c(state.critic_opt.mu)
-            + flat_c(state.critic_opt.nu)
-        )
-        if sac:
-            # Resident temperature: log_alpha (+ its Adam moments when
-            # learned), as (1, 1) VMEM blocks like every other tensor.
-            state_flat = state_flat + [state.log_alpha.reshape(1, 1)]
-            if autotune:
-                state_flat = state_flat + [
-                    state.alpha_opt.mu.reshape(1, 1),
-                    state.alpha_opt.nu.reshape(1, 1),
-                ]
+        # The state as the kernel takes it and, behind the call, back: the
+        # call's own convention, so part of `update`.
+        with device_scope("update"):
+            state_flat = (
+                _flatten(state.actor_params)
+                + flat_c(state.critic_params)
+                + _flatten(state.target_actor_params)
+                + flat_c(state.target_critic_params)
+                + _flatten(state.actor_opt.mu)
+                + _flatten(state.actor_opt.nu)
+                + flat_c(state.critic_opt.mu)
+                + flat_c(state.critic_opt.nu)
+            )
+            if sac:
+                # Resident temperature: log_alpha (+ its Adam moments when
+                # learned), as (1, 1) VMEM blocks like every other tensor.
+                state_flat = state_flat + [state.log_alpha.reshape(1, 1)]
+                if autotune:
+                    state_flat = state_flat + [
+                        state.alpha_opt.mu.reshape(1, 1),
+                        state.alpha_opt.nu.reshape(1, 1),
+                    ]
 
         if eps is None:
             # The whole chunk's noise [K, B, act] (TD3: smoothing noise,
@@ -1115,43 +1122,50 @@ def make_fused_chunk_fn(
         counts = [state.actor_opt.count, state.critic_opt.count, state.step]
         if autotune:
             counts.append(state.alpha_opt.count)
-        count0 = jnp.stack(counts).astype(jnp.int32)
-        outs = pl.pallas_call(
-            kernel,
-            grid=(K,),
-            in_specs=in_specs,
-            out_specs=out_specs,
-            out_shape=out_shape,
-            interpret=interp,
-        )(
-            count0, *streams, scale, offset,
-            *support_args, *eps_args, *state_flat,
-        )
+        # The inner bracket keeps the call's instruction the name every
+        # record of a device trace knows it by (`fused_sample_chunk_fn.1`):
+        # XLA names a custom call after the innermost scope it lies in.
+        with device_scope("update"), jax.named_scope("fused_sample_chunk_fn"):
+            count0 = jnp.stack(counts).astype(jnp.int32)
+            outs = pl.pallas_call(
+                kernel,
+                grid=(K,),
+                in_specs=in_specs,
+                out_specs=out_specs,
+                out_shape=out_shape,
+                interpret=interp,
+            )(
+                count0, *streams, scale, offset,
+                *support_args, *eps_args, *state_flat,
+            )
 
-        td = outs[0][..., 0]
-        met = outs[1][0]
+        with device_scope("metrics"):
+            td = outs[0][..., 0]
+            met = outs[1][0]
+            metrics = {k_: met[j] for j, k_ in enumerate(keys)}
         flat = list(outs[2:])
         unflat_c = _unflatten_twin if (twin or sac) else _unflatten
         nct = nc2 * (2 if (twin or sac) else 1)
         i = 0
-        actor_p = _unflatten(flat[i : i + na2], state.actor_params); i += na2
-        critic_p = unflat_c(flat[i : i + nct], state.critic_params); i += nct
-        t_actor = _unflatten(flat[i : i + na2], state.actor_params); i += na2
-        t_critic = unflat_c(flat[i : i + nct], state.critic_params); i += nct
-        amu = _unflatten(flat[i : i + na2], state.actor_params); i += na2
-        anu = _unflatten(flat[i : i + na2], state.actor_params); i += na2
-        cmu = unflat_c(flat[i : i + nct], state.critic_params); i += nct
-        cnu = unflat_c(flat[i : i + nct], state.critic_params); i += nct
-        new_log_alpha, new_alpha_opt = state.log_alpha, state.alpha_opt
-        if sac:
-            new_log_alpha = flat[i].reshape(()); i += 1
-            if autotune:
-                new_alpha_opt = OptState(
-                    mu=flat[i].reshape(()),
-                    nu=flat[i + 1].reshape(()),
-                    count=state.alpha_opt.count + K,
-                )
-                i += 2
+        with device_scope("update"):
+            actor_p = _unflatten(flat[i : i + na2], state.actor_params); i += na2
+            critic_p = unflat_c(flat[i : i + nct], state.critic_params); i += nct
+            t_actor = _unflatten(flat[i : i + na2], state.actor_params); i += na2
+            t_critic = unflat_c(flat[i : i + nct], state.critic_params); i += nct
+            amu = _unflatten(flat[i : i + na2], state.actor_params); i += na2
+            anu = _unflatten(flat[i : i + na2], state.actor_params); i += na2
+            cmu = unflat_c(flat[i : i + nct], state.critic_params); i += nct
+            cnu = unflat_c(flat[i : i + nct], state.critic_params); i += nct
+            new_log_alpha, new_alpha_opt = state.log_alpha, state.alpha_opt
+            if sac:
+                new_log_alpha = flat[i].reshape(()); i += 1
+                if autotune:
+                    new_alpha_opt = OptState(
+                        mu=flat[i].reshape(()),
+                        nu=flat[i + 1].reshape(()),
+                        count=state.alpha_opt.count + K,
+                    )
+                    i += 2
 
         if twin and config.policy_delay > 1:
             # Actor count advances only on real updates: multiples of
@@ -1175,7 +1189,6 @@ def make_fused_chunk_fn(
             log_alpha=new_log_alpha,
             alpha_opt=new_alpha_opt,
         )
-        metrics = {k_: met[j] for j, k_ in enumerate(keys)}
         return new_state, td, metrics
 
     return run

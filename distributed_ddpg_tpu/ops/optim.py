@@ -19,6 +19,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from distributed_ddpg_tpu.trace import device_scope
 from distributed_ddpg_tpu.types import OptState
 
 B1 = 0.9
@@ -28,16 +29,19 @@ EPS = 1e-8
 
 def adam_update(params, grads, opt: OptState, lr):
     """One Adam step. Returns (new_params, new_opt)."""
-    count = opt.count + 1
-    c = count.astype(jnp.float32)
-    bc1 = 1.0 - B1 ** c
-    bc2 = 1.0 - B2 ** c
-    mu = jax.tree.map(lambda m, g: B1 * m + (1.0 - B1) * g, opt.mu, grads)
-    nu = jax.tree.map(lambda v, g: B2 * v + (1.0 - B2) * (g * g), opt.nu, grads)
-    new_params = jax.tree.map(
-        lambda p, m, v: p - lr * (m / bc1) / (jnp.sqrt(v / bc2) + EPS),
-        params,
-        mu,
-        nu,
-    )
+    with device_scope("optim"):
+        count = opt.count + 1
+        c = count.astype(jnp.float32)
+        bc1 = 1.0 - B1 ** c
+        bc2 = 1.0 - B2 ** c
+        mu = jax.tree.map(lambda m, g: B1 * m + (1.0 - B1) * g, opt.mu, grads)
+        nu = jax.tree.map(
+            lambda v, g: B2 * v + (1.0 - B2) * (g * g), opt.nu, grads
+        )
+        new_params = jax.tree.map(
+            lambda p, m, v: p - lr * (m / bc1) / (jnp.sqrt(v / bc2) + EPS),
+            params,
+            mu,
+            nu,
+        )
     return new_params, OptState(mu=mu, nu=nu, count=count)

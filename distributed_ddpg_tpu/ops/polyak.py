@@ -10,6 +10,11 @@ from __future__ import annotations
 
 import jax
 
+from distributed_ddpg_tpu.trace import device_scope
+
 
 def polyak_update(online, target, tau):
-    return jax.tree.map(lambda o, t: tau * o + (1.0 - tau) * t, online, target)
+    with device_scope("polyak"):
+        return jax.tree.map(
+            lambda o, t: tau * o + (1.0 - tau) * t, online, target
+        )

@@ -78,6 +78,17 @@ def resolve_learner_chunk(config: DDPGConfig) -> int:
     return 800 if runs_native() else 8
 
 
+def _shape_of(x):
+    """What `.lower()` needs of a launch's argument: a device array's shape,
+    dtype, sharding and layout (the ring's may be its own: ring_format);
+    host scalars as they are."""
+    if not isinstance(x, jax.Array):
+        return x
+    return jax.ShapeDtypeStruct(
+        x.shape, x.dtype, sharding=x.format, weak_type=x.weak_type
+    )
+
+
 def scan_chunk(step, s: TrainState, batches: Batch, noise, unroll: int):
     """K steps of `step` in one lax.scan over a [K, B, ...] Batch pytree and
     the chunk's pre-drawn `noise` (learner.chunk_noise; None, an empty
@@ -88,7 +99,8 @@ def scan_chunk(step, s: TrainState, batches: Batch, noise, unroll: int):
         out = step(carry, *x)
         return out.state, (out.td_errors, out.metrics)
 
-    s, (tds, ms) = jax.lax.scan(body, s, (batches, noise), unroll=unroll)
+    with trace.device_scope("update"):
+        s, (tds, ms) = jax.lax.scan(body, s, (batches, noise), unroll=unroll)
     return StepOutput(state=s, td_errors=tds, metrics=chunk_metrics(ms))
 
 
@@ -352,10 +364,11 @@ class ShardedLearner:
         # (v5e-1, chunk=800). Shared by the scan and megakernel paths so
         # their index streams stay bit-identical (parity tests rely on it).
         def draw_chunk_idx(key, size):
-            key, sub = jax.random.split(key)
-            idx = jax.random.randint(
-                sub, (self.chunk_size, batch_size), 0, jnp.maximum(size, 1)
-            )
+            with trace.device_scope("draw"):
+                key, sub = jax.random.split(key)
+                idx = jax.random.randint(
+                    sub, (self.chunk_size, batch_size), 0, jnp.maximum(size, 1)
+                )
             return key, idx
 
         # Row gather behind every sampling path. Replicated storage: a
@@ -373,7 +386,8 @@ class ShardedLearner:
 
         def gather_rows(storage, idx):
             if not self._replay_sharded:
-                return storage[idx]
+                with trace.device_scope("gather"):
+                    return storage[idx]
 
             def body(st, ix):
                 s = jax.lax.axis_index("data")
@@ -383,10 +397,11 @@ class ShardedLearner:
                     jnp.where((owner == s)[..., None], rows, 0.0), "data"
                 )
 
-            return mesh_lib.shard_map(
-                body, self.mesh,
-                in_specs=(P("data", None), P()), out_specs=P(),
-            )(storage, idx)
+            with trace.device_scope("gather"):
+                return mesh_lib.shard_map(
+                    body, self.mesh,
+                    in_specs=(P("data", None), P()), out_specs=P(),
+                )(storage, idx)
 
         def draw_chunk(key, storage, size):
             key, idx = draw_chunk_idx(key, size)
@@ -495,11 +510,25 @@ class ShardedLearner:
         # a replicated top-level sampler replace the full-vector cumsum,
         # and the post-chunk priority scatter routes each update to the
         # owner shard (drop-mode, exactly one owner per index).
-        per_draw = (
+        draw_per = (
             make_sharded_per_draw(self.mesh)
             if self._replay_sharded
             else draw_per_indices
         )
+
+        def per_draw(*args):
+            with trace.device_scope("draw"):
+                return draw_per(*args)
+
+        def write_back(priorities, maxp, idx, td_errors, alpha, eps):
+            """PER's end of a chunk: the sampled rows re-stamped at
+            (|td| + eps)^alpha, and the running maximum."""
+            with trace.device_scope("priority"):
+                new_p = (jnp.abs(td_errors) + eps) ** alpha
+                priorities = scatter_prios(
+                    priorities, idx.reshape(-1), new_p.reshape(-1)
+                )
+                return priorities, jnp.maximum(maxp, new_p.max())
 
         def scatter_prios(priorities, idx_flat, vals_flat):
             if not self._replay_sharded:
@@ -534,11 +563,9 @@ class ShardedLearner:
                 weight=weights
             )
             out = scan_steps(s, batches)
-            new_p = (jnp.abs(out.td_errors) + eps) ** alpha
-            priorities = scatter_prios(
-                priorities, idx.reshape(-1), new_p.reshape(-1)
+            priorities, maxp = write_back(
+                priorities, maxp, idx, out.td_errors, alpha, eps
             )
-            maxp = jnp.maximum(maxp, new_p.max())
             return out, key, priorities, maxp
 
         storage_sharding = NamedSharding(
@@ -585,17 +612,17 @@ class ShardedLearner:
             def fused_per_sample_chunk_fn(s, key, storage, size, priorities,
                                           maxp, beta, alpha, eps):
                 key, sub = jax.random.split(key)
-                idx, weights = draw_per_indices(
+                idx, weights = per_draw(
                     sub, priorities, size, (self.chunk_size, batch_size), beta
                 )
-                packed = storage[idx].at[..., -1].set(weights)
+                packed = gather_rows(storage, idx)
+                with trace.device_scope("cut"):
+                    packed = packed.at[..., -1].set(weights)
                 new_s, tds, ms = fused_run(s, packed)
                 out = StepOutput(state=new_s, td_errors=tds, metrics=ms)
-                new_p = (jnp.abs(tds) + eps) ** alpha
-                priorities = priorities.at[idx.reshape(-1)].set(
-                    new_p.reshape(-1)
+                priorities, maxp = write_back(
+                    priorities, maxp, idx, tds, alpha, eps
                 )
-                maxp = jnp.maximum(maxp, new_p.max())
                 return out, key, priorities, maxp
 
             self._per_sample_chunk_step = _jit_per_chunk(
@@ -649,11 +676,12 @@ class ShardedLearner:
                     ns, ng, td, ms = gstep(*carry, *x)
                     return (ns, ng), (td, ms)
 
-                (s, g), (tds, ms) = jax.lax.scan(
-                    body, (s, g),
-                    (batches, pre_bad, draw_chunk_noise(s, batches)),
-                    unroll=self.unroll,
-                )
+                noise = draw_chunk_noise(s, batches)
+                with trace.device_scope("update"):
+                    (s, g), (tds, ms) = jax.lax.scan(
+                        body, (s, g), (batches, pre_bad, noise),
+                        unroll=self.unroll,
+                    )
                 return StepOutput(
                     state=s,
                     td_errors=tds,
@@ -751,11 +779,9 @@ class ShardedLearner:
                 # sampled rows re-stamp at the (eps)^alpha floor instead
                 # of inheriting NaN priorities that would poison every
                 # later draw.
-                new_p = (jnp.abs(out.td_errors) + eps) ** alpha
-                priorities = scatter_prios(
-                    priorities, idx.reshape(-1), new_p.reshape(-1)
+                priorities, maxp = write_back(
+                    priorities, maxp, idx, out.td_errors, alpha, eps
                 )
-                maxp = jnp.maximum(maxp, new_p.max())
                 return (
                     out, key, priorities, maxp, g,
                     guard_lib.health_vector(g), bad_idx,
@@ -792,6 +818,7 @@ class ShardedLearner:
         # _build_programs call (LR backoff, support expansion), so the
         # version counter below lets the megastep detect staleness and
         # rebuild its beat program in step.
+        self._launched = None  # chunk_ops: no launch of these programs yet
         self._pure_scan_fns = {
             "uniform": scan_sample_chunk_fn,
             "per": per_sample_chunk_fn,
@@ -832,10 +859,11 @@ class ShardedLearner:
 
         def local_chunk(s, sub, storage, size):
             axis_idx = jax.lax.axis_index("data")
-            dkey = jax.random.fold_in(sub, axis_idx)
-            idx = jax.random.randint(
-                dkey, (K, b_local), 0, jnp.maximum(size, 1)
-            )
+            with trace.device_scope("draw"):
+                dkey = jax.random.fold_in(sub, axis_idx)
+                idx = jax.random.randint(
+                    dkey, (K, b_local), 0, jnp.maximum(size, 1)
+                )
             # Per-device iid noise: the fold_in(seed, step) stream with the
             # device index folded on top, as the step under shard_map
             # folds it (learner.chunk_noise).
@@ -843,7 +871,9 @@ class ShardedLearner:
                 self.config, s.step, K, b_local, self.act_dim,
                 device_fold=axis_idx,
             )
-            new_s, tds, ms = run_fused(s, storage[idx], eps=eps)
+            with trace.device_scope("gather"):
+                rows = storage[idx]
+            new_s, tds, ms = run_fused(s, rows, eps=eps)
             avg = lambda x: jax.lax.pmean(x, "data")
             favg = lambda tree: jax.tree.map(avg, tree)
             # SAC temperature state is float — it local-SGDs inside the
@@ -895,6 +925,33 @@ class ShardedLearner:
 
         return fused_mesh_sample_chunk_fn
 
+    # --- the launched chunk program, by part (trace.CHUNK_SCOPES) ---
+
+    def _launch(self, program, *args):
+        """One launch of a chunk program. The first of each build is kept
+        (the program and its arguments' shapes, shardings and layouts) for
+        chunk_ops to ask the executable back."""
+        if self._launched is None:
+            self._launched = (program, jax.tree.map(_shape_of, args))
+        return program(*args)
+
+    def chunk_hlo(self) -> Optional[str]:
+        """The text of the executable behind this learner's chunk launches:
+        lowering a jitted program again with the shapes it was launched
+        with finds lowering and executable in JAX's in-memory caches, so
+        nothing compiles. None before any launch."""
+        if self._launched is None:
+            return None
+        program, shapes = self._launched
+        return program.lower(*shapes).compile().as_text()
+
+    def chunk_ops(self) -> Optional[dict]:
+        """The table from instruction name to scope (trace.chunk_ops_table)
+        of the chunk program this learner launches, from the text of the
+        executable that ran. None before any launch."""
+        text = self.chunk_hlo()
+        return None if text is None else trace.chunk_ops_table(text)
+
     # --- single step ---
 
     def step(self, np_batch: Dict[str, np.ndarray]) -> StepOutput:
@@ -914,13 +971,13 @@ class ShardedLearner:
         (from the prefetch pipeline) and does not block — callers sync on
         the outputs."""
         if self.guard_enabled:
-            out, self._guard, health = self._chunk_step(
-                self.state, device_chunk, self._guard
+            out, self._guard, health = self._launch(
+                self._chunk_step, self.state, device_chunk, self._guard
             )
             self._health_cur = (health, None)
             self.state = out.state
             return out
-        out = self._chunk_step(self.state, device_chunk)
+        out = self._launch(self._chunk_step, self.state, device_chunk)
         self.state = out.state
         return out
 
@@ -942,16 +999,15 @@ class ShardedLearner:
         with _ingest_lock(device_replay):
             storage, size = device_replay.device_state()
             if self.guard_enabled:
-                out, self._key, self._guard, health, bad_idx = (
-                    self._sample_chunk_step(
-                        self.state, self._key, storage, size, self._guard
-                    )
+                out, self._key, self._guard, health, bad_idx = self._launch(
+                    self._sample_chunk_step,
+                    self.state, self._key, storage, size, self._guard,
                 )
                 self._health_cur = (health, bad_idx)
                 self.state = out.state
                 return out
-            out, self._key = self._sample_chunk_step(
-                self.state, self._key, storage, size
+            out, self._key = self._launch(
+                self._sample_chunk_step, self.state, self._key, storage, size
             )
             self.state = out.state
             return out
@@ -970,15 +1026,18 @@ class ShardedLearner:
             )
             if self.guard_enabled:
                 out, self._key, new_p, new_maxp, self._guard, health, bad_idx = (
-                    self._per_sample_chunk_step(
+                    self._launch(
+                        self._per_sample_chunk_step,
                         self.state, self._key, storage, size, priorities,
                         maxp, *args, self._guard,
                     )
                 )
                 self._health_cur = (health, bad_idx)
             else:
-                out, self._key, new_p, new_maxp = self._per_sample_chunk_step(
-                    self.state, self._key, storage, size, priorities, maxp, *args
+                out, self._key, new_p, new_maxp = self._launch(
+                    self._per_sample_chunk_step,
+                    self.state, self._key, storage, size, priorities, maxp,
+                    *args,
                 )
             self.state = out.state
             device_replay.set_per_state(new_p, new_maxp)

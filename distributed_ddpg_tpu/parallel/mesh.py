@@ -81,6 +81,14 @@ if (
         "jax_compilation_cache_dir",
         str(_pathlib.Path(__file__).resolve().parents[2] / ".jax_cache"),
     )
+# The key takes the programs' metadata, wherever the cache lies. JAX leaves
+# it out by default, so a program that differs from a cached one in its
+# `jax.named_scope` brackets alone loads the other's executable, and the
+# text of that executable carries the other's `op_name`s: the table the
+# learner writes from it (ShardedLearner.chunk_ops) would describe a
+# program that was never traced here. With it, an entry only ever answers
+# the source it was compiled from (file and line are metadata too).
+jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
 
 
 def shard_map(f, mesh: Mesh, in_specs, out_specs, check: bool = False):

@@ -55,6 +55,7 @@ from __future__ import annotations
 import itertools
 import json
 import os
+import re
 import sys
 import threading
 import time
@@ -431,3 +432,214 @@ def stall_report(
     except Exception:
         pass
     return paths
+
+
+# ---------------------------------------------------------------------------
+# Device-side scopes: the parts of a chunk program, by name.
+#
+# The spans above are the host's. Inside one launch of a chunk program the
+# device trace names an operation by its HLO instruction (`fusion.39`,
+# `while.3`), which says nothing to whoever reads it. The chunk programs
+# bracket their parts with `jax.named_scope` under this one vocabulary
+# (`device_scope`); a scope is metadata, no operation is added, removed or
+# reordered. The compiled text of the program carries the bracket on every
+# instruction (`metadata={op_name="jit(f)/update/while/body/.../optim/add"}`),
+# fusions, `while` bodies and `conditional` branches included, so the
+# program can write the table from instruction name to scope (`op_scopes`;
+# `ShardedLearner.chunk_ops`, `chunk_ops.json` beside the records) that a
+# reader joins to any profile of the run (docs/OBSERVABILITY.md §5).
+# ---------------------------------------------------------------------------
+
+CHUNK_SCOPES = (
+    "draw",           # the launch's replay indices (uniform or PER)
+    "gather",         # the rows behind them, out of the ring
+    "cut",            # the gathered rows cut into fields / kernel streams
+    "noise",          # the launch's noise; REDQ's subsets
+    "update",         # the K updates: the lax.scan, or the pallas_call
+    "update/critic",  # critic loss, forward and backward
+    "update/actor",   # actor loss, forward and backward
+    "update/optim",   # Adam
+    "update/polyak",  # target updates
+    "metrics",        # the chunk's metrics out of the K updates'
+    "priority",       # PER's priority write-back and maximum
+)
+# What a collective instruction reads as, whatever scope it served.
+COLLECTIVE = "collective"
+CHUNK_OPS_FILE = "chunk_ops.json"
+
+_SCOPE_WORDS = frozenset(w for s in CHUNK_SCOPES for w in s.split("/"))
+_COLLECTIVE_OPCODES = frozenset({
+    "all-reduce", "all-gather", "reduce-scatter", "collective-permute",
+    "all-to-all",
+})
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$")
+_INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = (.*)$")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+# Computations an instruction names: `calls=` and `to_apply=` are inlined
+# into it (a fusion's body, a reducer) unless it is a `call`; the rest run
+# as operations of their own under it.
+_INLINED = re.compile(r"(?:calls|to_apply)=%?([\w.\-]+)")
+_FUSED = re.compile(r"calls=%?([\w.\-]+)")
+# Instructions no device runs: a trace has no event for them.
+_NO_OP = frozenset({"parameter", "constant", "tuple", "get-tuple-element"})
+_RUN = re.compile(
+    r"(?:body|condition|true_computation|false_computation|calls|to_apply)"
+    r"=%?([\w.\-]+)|branch_computations=\{([^}]*)\}"
+)
+
+
+def device_scope(word: str):
+    """`jax.named_scope(word)` for one word of CHUNK_SCOPES: the bracket
+    the chunk programs' parts are traced under. Nested brackets join with
+    `/` (`optim` entered inside `update` reads `update/optim`)."""
+    if word not in _SCOPE_WORDS:
+        raise ValueError(f"{word!r} is no word of trace.CHUNK_SCOPES")
+    import jax  # traced code only: this module's importers may not load JAX
+
+    return jax.named_scope(word)
+
+
+def _instructions(hlo_text: str):
+    """([(name, opcode, scope, in the entry computation?)], fused) of a
+    compiled module's text. The list holds every instruction that runs as
+    an operation of its own: the entry computation's, `while` bodies' and
+    conditions', `conditional` branches' and `call` targets'. What is
+    inlined into another instruction (a fusion's body, a reducer) is left
+    out, and so are parameters, constants and tuples: no trace has an event
+    for them. Names are unique in a module.
+
+    The scope is the CHUNK_SCOPES words on the instruction's `op_name`
+    path, outermost first, joined by `/`. An instruction whose path has
+    none, in a computation that another instruction runs (the copies the
+    compiler puts into a loop's body carry no metadata at all), takes the
+    scope of that instruction: whatever runs inside the scan's `while` is
+    part of `update`, also where XLA cut its path short of the loop's
+    bracket (`critic/jvp()/gather` in a body reads `update/critic`). A
+    fusion the compiler left without an `op_name` takes the scope most of
+    its body's instructions were traced under.
+
+    A fusion is one operation and carries one `op_name`, its root's: XLA
+    fuses across brackets (Adam and Polyak into the epilogue of the
+    weight-gradient matmul that feeds them), and the device's time for the
+    fusion cannot be split. `fused` is {fusion: the other scopes the
+    instructions fused into it were traced under}, so that a reader of
+    `update/critic` can tell what else it paid for."""
+    found, inlined, runs, computation, entry = [], set(), {}, "", False
+    bodies, within = {}, {}  # fusion -> its body; body -> {scope: instructions}
+    for line in hlo_text.splitlines():
+        m = _COMPUTATION.match(line)
+        if m is not None:
+            computation, entry = m.group(1), line.startswith("ENTRY ")
+            continue
+        m = _INSTRUCTION.match(line)
+        if m is None:
+            continue
+        name, rest = m.groups()
+        if rest.startswith("("):  # a tuple type: skip to its closing paren
+            depth = 0
+            for i, ch in enumerate(rest):
+                depth += (ch == "(") - (ch == ")")
+                if depth == 0:
+                    break
+            rest = rest[i + 1:]
+        else:
+            rest = rest.partition(" ")[2]
+        opcode = rest.lstrip().partition("(")[0]
+        if opcode != "call":
+            inlined.update(_INLINED.findall(rest))
+            bodies.update((name, body) for body in _FUSED.findall(rest))
+        for one, several in _RUN.findall(rest):
+            for called in (one, *several.split(",")):
+                runs.setdefault(called.strip().lstrip("%"), name)
+        op_name = _OP_NAME.search(rest)
+        scope = _scope_of(op_name.group(1) if op_name else "")
+        found.append((computation, name, opcode, scope, entry))
+        if scope:
+            counts = within.setdefault(computation, {})
+            counts[scope] = counts.get(scope, 0) + 1
+    own = {name: (comp, scope) for comp, name, _, scope, _ in found}
+
+    def scope_of(name):
+        comp, scope = own[name]
+        inside = within.get(bodies.get(name))
+        if not scope and inside:  # a fusion XLA left nameless: its body's
+            scope = max(inside, key=inside.get)
+        outer = ""
+        while not outer and comp in runs:
+            comp, outer = own[runs[comp]]
+        top = outer.partition("/")[0]
+        if not scope or not top or scope.partition("/")[0] == top:
+            return scope or outer
+        return f"{top}/{scope}"  # a path XLA cut short: `critic/jvp()/gather`
+
+    instructions = [
+        (name, opcode, scope_of(name), entry)
+        for comp, name, opcode, _, entry in found
+        if comp not in inlined and opcode not in _NO_OP
+    ]
+    fused = {}
+    for name, _, scope, _ in instructions:
+        others = set(within.get(bodies.get(name), ())) - {scope}
+        if others:
+            fused[name] = sorted(others)
+    return instructions, fused
+
+
+def _scope_of(op_name: str) -> str:
+    # The path's last component is the primitive (`gather`, `add`): never a
+    # bracket, and one of them shares a word with the vocabulary. Where XLA
+    # made one instruction of several it joins their paths with `;`: the
+    # first speaks.
+    path = op_name.partition(";")[0]
+    return "/".join(
+        part for part in path.split("/")[:-1] if part in _SCOPE_WORDS
+    )
+
+
+def _is_collective(opcode: str) -> bool:
+    for suffix in ("-start", "-done"):
+        if opcode.endswith(suffix):
+            opcode = opcode[: -len(suffix)]
+    return opcode in _COLLECTIVE_OPCODES
+
+
+def _scopes(instructions) -> Dict[str, str]:
+    table = {}
+    for name, opcode, scope, _ in instructions:
+        scope = COLLECTIVE if _is_collective(opcode) else scope
+        if scope:
+            table[name] = scope
+    return table
+
+
+def op_scopes(hlo_text: str) -> Dict[str, str]:
+    """{instruction name: scope} from a compiled module's text
+    (`compiled.as_text()`): the scope `_instructions` reads; COLLECTIVE for
+    a collective instruction, whatever its path (`chunk_ops_table` keeps
+    that as `served`); an instruction under no bracket is absent."""
+    return _scopes(_instructions(hlo_text)[0])
+
+
+def chunk_ops_table(hlo_text: str) -> Dict[str, Any]:
+    """What `chunk_ops.json` holds, from the text of the executable a run
+    launched: the module's name as a device trace names its launches, the
+    vocabulary, `ops` (op_scopes), `served` (each collective's scope),
+    `fused` (what else each fusion holds: _instructions) and `loops`, the
+    `while` instructions: a device trace nests a loop's body under the
+    loop's own event, and a reader tells the loop's time under no body
+    operation by the names here."""
+    module = re.match(r"HloModule ([\w.\-]+)", hlo_text)
+    instructions, fused = _instructions(hlo_text)
+    return {
+        "module": module.group(1) if module else "",
+        "scopes": list(CHUNK_SCOPES),
+        "ops": _scopes(instructions),
+        "served": {
+            name: scope for name, opcode, scope, _ in instructions
+            if _is_collective(opcode)
+        },
+        "fused": fused,
+        "loops": [
+            name for name, opcode, _, _ in instructions if opcode == "while"
+        ],
+    }

@@ -23,6 +23,7 @@ import time
 _IMPORT_T0 = time.perf_counter()
 
 import contextlib
+import json
 import os
 import sys
 import threading
@@ -475,6 +476,41 @@ def train_jax(
                       file=sys.stderr, flush=True)
             finally:
                 trace.disable()
+
+
+def write_chunk_ops(learner, config: DDPGConfig) -> str:
+    """`chunk_ops.json` (trace.CHUNK_OPS_FILE) beside the records, and beside
+    `trace.json` where --trace_dir is set: instruction name -> scope of the
+    chunk program the learner launched (ShardedLearner.chunk_ops), so that
+    whoever reads a device profile of this run reads `fusion.39` as `draw`
+    (docs/OBSERVABILITY.md §5). Written once, after the loop has ended,
+    where no rate and no set-up time looks. Returns the path beside the
+    records, "" where there are none or nothing was launched. Diagnostics
+    never fail a finished run."""
+    if not config.log_path:
+        return ""
+    t0 = time.perf_counter()
+    try:
+        table = learner.chunk_ops()
+        if table is None:
+            return ""
+        dirs = [os.path.dirname(config.log_path) or "."]
+        if config.trace_dir:
+            dirs.append(config.trace_dir)
+        for d in dirs:
+            os.makedirs(d, exist_ok=True)
+            with open(os.path.join(d, trace.CHUNK_OPS_FILE), "w") as f:
+                json.dump(table, f)
+        path = os.path.join(dirs[0], trace.CHUNK_OPS_FILE)
+        print(
+            f"[trace] {len(table['ops'])} ops of {table['module']} by scope "
+            f"-> {path} ({time.perf_counter() - t0:.3f} s)",
+            file=sys.stderr,
+        )
+        return path
+    except Exception as e:
+        print(f"[trace] chunk_ops not written: {e!r}", file=sys.stderr, flush=True)
+        return ""
 
 
 def _train_jax_impl(
@@ -2845,6 +2881,7 @@ def _train_jax_impl(
         **mesh_final,
     )
     log.close()
+    chunk_ops_path = write_chunk_ops(learner, config)
     # Checksum of the final actor params: lets determinism tests (and the
     # multi-host parity test — SPMD replicas must agree bit-for-bit)
     # compare end states without plumbing the whole state out.
@@ -2859,6 +2896,9 @@ def _train_jax_impl(
         "param_checksum_start": checksum_start,
         # Where this run's records went ("" = stdout only).
         "log_path": config.log_path,
+        # The chunk program's parts by instruction name, beside the records
+        # ("": no records file, or the learner launched no chunk program).
+        "chunk_ops_path": chunk_ops_path,
         **facts_final,
         **loop_times,
         **setup_fields,

@@ -13,6 +13,8 @@ from typing import Any, Dict, NamedTuple
 
 import numpy as np
 
+from distributed_ddpg_tpu.trace import device_scope
+
 
 class Batch(NamedTuple):
     """A replay minibatch. `discount` already folds gamma^n * (1 - done) for
@@ -95,14 +97,16 @@ def pack_batch_np(arrays: Dict[str, np.ndarray]) -> np.ndarray:
 
 
 def unpack_batch(packed, obs_dim: int, act_dim: int) -> Batch:
-    """Inverse of pack_batch_np; works on jnp arrays inside jit."""
+    """Inverse of pack_batch_np; works on jnp arrays inside jit, where the
+    slices read as the chunk programs' `cut` (trace.CHUNK_SCOPES)."""
     o = obs_dim
     a = act_dim
-    return Batch(
-        obs=packed[..., :o],
-        action=packed[..., o : o + a],
-        reward=packed[..., o + a],
-        discount=packed[..., o + a + 1],
-        next_obs=packed[..., o + a + 2 : 2 * o + a + 2],
-        weight=packed[..., 2 * o + a + 2],
-    )
+    with device_scope("cut"):
+        return Batch(
+            obs=packed[..., :o],
+            action=packed[..., o : o + a],
+            reward=packed[..., o + a],
+            discount=packed[..., o + a + 1],
+            next_obs=packed[..., o + a + 2 : 2 * o + a + 2],
+            weight=packed[..., 2 * o + a + 2],
+        )

@@ -1,0 +1,350 @@
+"""The chunk programs name their own parts (trace.CHUNK_SCOPES): the table
+from instruction name to scope that `op_scopes` reads out of a compiled
+module's text, the tables of the programs `_build_programs` selects for the
+five families on both legs, the compile cache that must not answer a scoped
+program with an unscoped one's executable, and `chunk_ops.json` beside a
+run's records."""
+
+import contextlib
+import json
+import os
+
+import jax
+import numpy as np
+import pytest
+
+from distributed_ddpg_tpu import trace
+from distributed_ddpg_tpu.config import DDPGConfig
+from distributed_ddpg_tpu.parallel import mesh as mesh_lib
+from distributed_ddpg_tpu.parallel.learner import ShardedLearner
+from distributed_ddpg_tpu.replay.device import DeviceReplay
+
+OBS, ACT, B, K = 5, 2, 8, 8  # K past the scan's unroll of 4: the loop stays a loop
+
+# A compiled module as `compiled.as_text()` prints it, cut to what the table
+# reads: a fusion with its body, a `while` with condition and body, a
+# `conditional` with two branches, an all-reduce with its reducer, a
+# tuple-typed instruction, a copy under no bracket, and a fusion whose body
+# was traced under three brackets.
+HLO = """\
+HloModule jit_sample_chunk_fn, is_scheduled=true, entry_computation_layout={(f32[64,15]{1,0})->f32[4,4]{1,0}}
+
+%region_1.0 (a.1: f32[], b.1: f32[]) -> f32[] {
+  %a.1 = f32[] parameter(0), metadata={op_name="update/while/body/closed_call/critic/psum"}
+  %b.1 = f32[] parameter(1)
+  ROOT %add.3 = f32[] add(%a.1, %b.1), metadata={op_name="critic/add"}
+}
+
+%fused_computation (param_0: f32[64,15], param_1: s32[2,8]) -> f32[2,8,15] {
+  %param_0 = f32[64,15]{1,0} parameter(0)
+  %param_1 = s32[2,8]{1,0} parameter(1)
+  ROOT %gather.9 = f32[2,8,15]{2,1,0} gather(%param_0, %param_1), offset_dims={2}, metadata={op_name="jit(sample_chunk_fn)/gather/gather"}
+}
+
+%fused_computation.3 (param_0.3: f32[4,4]) -> f32[4,4] {
+  %param_0.3 = f32[4,4]{1,0} parameter(0)
+  %convolution.5 = f32[4,4]{1,0} convolution(%param_0.3, %param_0.3), dim_labels=bf_io->bf, metadata={op_name="jit(sample_chunk_fn)/update/while/body/closed_call/critic/transpose(jvp())/dot_general"}
+  %subtract.5 = f32[4,4]{1,0} subtract(%convolution.5, %param_0.3), metadata={op_name="jit(sample_chunk_fn)/update/while/body/closed_call/optim/sub"}
+  ROOT %add.6 = f32[4,4]{1,0} add(%subtract.5, %param_0.3), metadata={op_name="jit(sample_chunk_fn)/update/while/body/closed_call/polyak/add"}
+}
+
+%fused_computation.4 () -> s32[2,8] {
+  %iota.1 = s32[2,8]{1,0} iota(), iota_dimension=0, metadata={op_name="jit(sample_chunk_fn)/draw/jit(_randint)/iota"}
+  %add.1 = s32[2,8]{1,0} add(%iota.1, %iota.1), metadata={op_name="jit(sample_chunk_fn)/gather/add"}
+  ROOT %xor.2 = s32[2,8]{1,0} xor(%iota.1, %iota.1), metadata={op_name="jit(sample_chunk_fn)/draw/jit(_randint)/xor"}
+}
+
+%branch_0 (p.0: (f32[4,4])) -> (f32[4,4]) {
+  ROOT %p.0 = (f32[4,4]{1,0}) parameter(0)
+}
+
+%branch_1 (p.1: (f32[4,4])) -> (f32[4,4]) {
+  %p.1 = (f32[4,4]{1,0}) parameter(0)
+  %gte.5 = f32[4,4]{1,0} get-tuple-element(%p.1), index=0
+  %multiply.7 = f32[4,4]{1,0} multiply(%gte.5, %gte.5), metadata={op_name="jit(sample_chunk_fn)/update/while/body/closed_call/cond/branch_1_fun/actor/mul"}
+  ROOT %tuple.8 = (f32[4,4]{1,0}) tuple(%multiply.7)
+}
+
+%body.2 (w.1: (s32[], f32[4,4], f32[2,8,15])) -> (s32[], f32[4,4], f32[2,8,15]) {
+  %w.1 = (s32[], f32[4,4]{1,0}, f32[2,8,15]{2,1,0}) parameter(0)
+  %dynamic-slice.4 = f32[8,15]{1,0} dynamic-slice(%w.1), dynamic_slice_sizes={1,8,15}, metadata={op_name="jit(sample_chunk_fn)/update/while/body/dynamic_slice"}
+  %convolution_add_fusion.1 = f32[4,4]{1,0} fusion(%dynamic-slice.4), kind=kOutput, calls=%fused_computation.1, metadata={op_name="jit(sample_chunk_fn)/update/while/body/closed_call/critic/dot_general"}
+  %pad_clamp_fusion.8 = s32[8]{0} fusion(%dynamic-slice.4), kind=kLoop, calls=%fused_computation.6, metadata={op_name="critic/jvp()/gather"}
+  %all-reduce.330 = f32[4,4]{1,0} all-reduce(%convolution_add_fusion.1), channel_id=1, replica_groups={{0,1}}, to_apply=%region_1.0, metadata={op_name="jit(sample_chunk_fn)/update/while/body/closed_call/critic/psum"}
+  %conditional.6 = (f32[4,4]{1,0}) conditional(%all-reduce.330, %w.1, %w.1), branch_computations={%branch_0, %branch_1}, metadata={op_name="jit(sample_chunk_fn)/update/while/body/closed_call/cond"}
+  %divide_subtract_fusion.12 = f32[4,4]{1,0} fusion(%conditional.6), kind=kLoop, calls=%fused_computation.2, metadata={op_name="jit(sample_chunk_fn)/update/while/body/closed_call/optim/sub"}
+  %multiply_add_fusion.227 = f32[4,4]{1,0} fusion(%divide_subtract_fusion.12), kind=kLoop, calls=%fused_computation.3, metadata={op_name="jit(sample_chunk_fn)/update/while/body/closed_call/polyak/add"}
+  ROOT %tuple.9 = (s32[], f32[4,4]{1,0}, f32[2,8,15]{2,1,0}) tuple(%w.1, %multiply_add_fusion.227, %w.1)
+}
+
+%cond.2 (w.2: (s32[], f32[4,4], f32[2,8,15])) -> pred[] {
+  %w.2 = (s32[], f32[4,4]{1,0}, f32[2,8,15]{2,1,0}) parameter(0)
+  ROOT %compare.1 = pred[] compare(%w.2, %w.2), direction=LT, metadata={op_name="jit(sample_chunk_fn)/update/while/cond/lt"}
+}
+
+ENTRY %main.1 (Arg_0.1: f32[64,15]) -> f32[4,4] {
+  %Arg_0.1 = f32[64,15]{1,0} parameter(0)
+  %fusion.38 = s32[2,8]{1,0} fusion(), kind=kLoop, calls=%fused_computation.4
+  %fusion.39 = s32[2,8]{1,0} fusion(%fusion.38), kind=kLoop, calls=%fused_computation.5, metadata={op_name="jit(sample_chunk_fn)/draw/jit(_randint)/add"}
+  %fusion = f32[2,8,15]{2,1,0} fusion(%Arg_0.1, %fusion.39), kind=kLoop, calls=%fused_computation, metadata={op_name="jit(sample_chunk_fn)/gather/gather"}
+  %slice.29 = f32[2,8,5]{2,1,0} slice(%fusion), slice={[0:2], [0:8], [0:5]}, metadata={op_name="jit(sample_chunk_fn)/cut/slice;jit(sample_chunk_fn)/cut/squeeze"}
+  %copy.63 = f32[4,4]{0,1} copy(%Arg_0.1)
+  %tuple.2 = (s32[], f32[4,4]{1,0}, f32[2,8,15]{2,1,0}) tuple(%fusion.39, %copy.63, %fusion)
+  %while.3 = (s32[], f32[4,4]{1,0:T(8,128)}, f32[2,8,15]{2,1,0}) while(%tuple.2), condition=%cond.2, body=%body.2, metadata={op_name="jit(sample_chunk_fn)/update/while"}
+  %get-tuple-element.7 = f32[4,4]{1,0} get-tuple-element(%while.3), index=1
+  ROOT %reduce.8 = f32[4,4]{1,0} reduce(%get-tuple-element.7, %get-tuple-element.7), dimensions={}, to_apply=%region_1.0, metadata={op_name="jit(sample_chunk_fn)/metrics/reduce_sum"}
+}
+"""
+
+
+def test_op_scopes_reads_fusions_loop_bodies_branches_and_collectives():
+    assert trace.op_scopes(HLO) == {
+        "fusion.38": "draw",  # nameless itself: the scope of most of its body
+        "fusion.39": "draw",
+        "fusion": "gather",  # the primitive's own name is no bracket
+        "slice.29": "cut",  # two instructions made one: `a;b`, the first speaks
+        "while.3": "update",
+        "dynamic-slice.4": "update",
+        "convolution_add_fusion.1": "update/critic",
+        "pad_clamp_fusion.8": "update/critic",  # a path cut short of the loop's bracket
+        "all-reduce.330": "collective",
+        "conditional.6": "update",
+        "multiply.7": "update/actor",  # inside a branch
+        "divide_subtract_fusion.12": "update/optim",
+        "multiply_add_fusion.227": "update/polyak",
+        "compare.1": "update",  # the loop's condition
+        "reduce.8": "metrics",
+    }  # copy.63 under no bracket; fusion bodies and the reducer inlined; parameters and tuples never run
+    table = trace.chunk_ops_table(HLO)
+    assert table["module"] == "jit_sample_chunk_fn"
+    assert table["scopes"] == list(trace.CHUNK_SCOPES)
+    assert table["served"] == {"all-reduce.330": "update/critic"}
+    assert table["loops"] == ["while.3"]
+    # one operation, one scope, its root's: what else it holds is said apart
+    assert table["fused"] == {
+        "multiply_add_fusion.227": ["update/critic", "update/optim"], "fusion.38": ["gather"],
+    }
+    assert table["ops"] == trace.op_scopes(HLO)
+    assert set(table["ops"].values()) <= set(trace.CHUNK_SCOPES) | {trace.COLLECTIVE}
+
+
+@pytest.mark.parametrize("opcode", [
+    "all-reduce", "all-reduce-start", "all-reduce-done", "all-gather", "all-gather-start",
+    "reduce-scatter", "collective-permute", "collective-permute-done", "all-to-all",
+])
+def test_every_collective_opcode_reads_collective_whatever_it_served(opcode):
+    text = HLO.replace(" all-reduce(", f" {opcode}(")
+    assert trace.op_scopes(text)["all-reduce.330"] == trace.COLLECTIVE
+    assert trace.chunk_ops_table(text)["served"]["all-reduce.330"] == "update/critic"
+
+
+def test_a_bracket_takes_only_the_vocabularys_words():
+    with pytest.raises(ValueError, match="CHUNK_SCOPES"):
+        trace.device_scope("sample")
+    with trace.device_scope("update"), trace.device_scope("optim"):
+        pass
+    assert {w for s in trace.CHUNK_SCOPES for w in s.split("/")} == trace._SCOPE_WORDS
+
+
+# --- the programs `_build_programs` selects ---
+
+FAMILIES = {
+    "ddpg": dict(),
+    "td3": dict(twin_critic=True, policy_delay=2, target_noise=0.2),
+    "c51": dict(distributional=True, num_atoms=11, v_min=-5.0, v_max=5.0),
+    "sac": dict(sac=True),
+    "redq": dict(sac=True, critic_ensemble=4, target_subset=2, policy_delay=2),
+}
+DRAWS_NOISE = {"td3", "sac", "redq"}
+
+
+def launched(family, leg, devices=1):
+    """A learner of `family` on `leg` after one launch through the public
+    path, on a ring it filled."""
+    cfg = DDPGConfig(
+        actor_hidden=(16, 16), critic_hidden=(16, 16), batch_size=B, seed=5,
+        fused_chunk="on" if leg == "kernel" else "off", **FAMILIES[family],
+    )
+    mesh = mesh_lib.make_mesh(devices, 1, devices=jax.devices()[:devices])
+    learner = ShardedLearner(cfg, OBS, ACT, action_scale=1.0, mesh=mesh, chunk_size=K)
+    assert learner.fused_chunk_active == (leg == "kernel")
+    assert learner.chunk_ops() is None  # nothing launched yet
+    replay = DeviceReplay(capacity=64, obs_dim=OBS, act_dim=ACT, mesh=mesh, block_size=64)
+    replay.add_packed(
+        np.random.default_rng(0).standard_normal((64, 2 * OBS + ACT + 3)).astype(np.float32)
+    )
+    learner.run_sample_chunk(replay)
+    return learner
+
+
+def backend_compiles(fn):
+    """(fn(), the backend compiles JAX reported while it ran): a build and a
+    load from the persistent cache both report one."""
+    import jax.monitoring as monitoring
+
+    from distributed_ddpg_tpu.metrics import CompileCounter
+
+    seen = []
+
+    def listener(event, seconds, **kw):
+        if event == CompileCounter.BUILD:
+            seen.append(seconds)
+
+    monitoring.register_event_duration_secs_listener(listener)
+    try:
+        return fn(), len(seen)
+    finally:
+        monitoring.unregister_event_duration_listener(listener)
+
+
+def lacking(text, table):
+    """Of the entry computation's instructions that do work: the share the
+    table lacks though JAX named them (an `op_name`: something the program
+    wrote and no bracket holds), and the opcodes of the nameless rest."""
+    entry = {
+        name: opcode for name, opcode, _, in_entry in trace._instructions(text)[0]
+        if in_entry and opcode != "bitcast"
+    }
+    lines = {
+        m.group(1): m.group(2)
+        for m in map(trace._INSTRUCTION.match, text.splitlines()) if m
+    }
+    lacks = [name for name in entry if name not in table["ops"]]
+    named = [name for name in lacks if "op_name=" in lines[name]]
+    nameless = {entry[name] for name in lacks if name not in named}
+    return len(named) / len(entry), nameless
+
+
+@pytest.mark.parametrize("family,leg", [
+    (f, "scan") for f in FAMILIES
+] + [(f, "kernel") for f in FAMILIES if f != "redq"])  # an ensemble has no kernel branch
+def test_the_selected_program_carries_the_vocabulary(family, leg):
+    learner = launched(family, leg)
+    # the executable is asked back from JAX's in-memory caches: nothing
+    # compiles, nothing is loaded, so the table costs a finished run no set-up
+    text, compiles = backend_compiles(learner.chunk_hlo)
+    assert compiles == 0
+    table = learner.chunk_ops()
+    assert table["module"].startswith(
+        "jit_fused_sample_chunk_fn" if leg == "kernel" else "jit_sample_chunk_fn"
+    )
+    found = set(table["ops"].values())
+    want = {"draw", "gather", "cut", "update"}
+    if family in DRAWS_NOISE:
+        want.add("noise")
+    if leg == "scan":
+        want |= {"update/critic", "update/actor", "update/optim", "update/polyak", "metrics"}
+        # the scan is a loop, and the loop itself reads `update`
+        assert any(table["ops"].get(loop) == "update" for loop in table["loops"])
+    else:
+        # interpret mode: the kernel's body is ordinary instructions here,
+        # all of them under the one bracket round the pallas_call
+        assert not {s for s in found if s.startswith("update/")}
+    assert want <= found, sorted(want - found)
+    assert found <= set(trace.CHUNK_SCOPES)  # one chip: no collective
+    assert not table["served"]
+    # What the table lacks of the entry computation: under a fiftieth of it
+    # is the program's own (the state's counters stepped behind the
+    # kernel); the rest is the compiler's, copies of the state round the
+    # loop and fusions of them that carry no metadata at all.
+    named_share, nameless = lacking(text, table)
+    assert named_share < 0.02 and nameless <= {"copy", "fusion", "call"}
+    json.dumps(table)  # what train() writes
+
+
+def test_on_a_data_mesh_the_scan_programs_all_reduces_read_collective():
+    table = launched("sac", "scan", devices=2).chunk_ops()
+    collectives = [name for name, scope in table["ops"].items() if scope == trace.COLLECTIVE]
+    assert collectives and set(collectives) == set(table["served"])
+    # each keeps the scope it served: a gradient's half of an update (XLA
+    # may combine the two halves' all-reduces under one's name)
+    assert set(table["served"].values()) <= {"update/critic", "update/actor"}
+
+
+# --- the compile cache must answer with the executable of THIS source ---
+
+
+@pytest.fixture
+def cache_dir(tmp_path):
+    """A persistent compile cache of the test's own, taking every program."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    names = ("jax_compilation_cache_dir", "jax_persistent_cache_min_compile_time_secs",
+             "jax_persistent_cache_min_entry_size_bytes", "jax_compilation_cache_include_metadata_in_key")
+    before = {n: getattr(jax.config, n) for n in names}
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path / "cache"))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    cc.reset_cache()
+    yield tmp_path / "cache"
+    for n, v in before.items():
+        jax.config.update(n, v)
+    cc.reset_cache()
+    jax.clear_caches()
+
+
+@pytest.mark.parametrize("metadata_in_key", [True, False])
+def test_a_cache_filled_without_scopes_does_not_answer_the_scoped_program(
+    cache_dir, monkeypatch, metadata_in_key
+):
+    """JAX leaves metadata out of the persistent cache's key by default: an
+    entry compiled before the brackets existed is then loaded for the
+    bracketed program, and its text says nothing of them (the second case
+    shows the trap). `parallel/mesh.py` puts metadata into the key, so the
+    table describes the executable that ran (the first)."""
+    assert jax.config.jax_compilation_cache_include_metadata_in_key  # parallel/mesh.py set it
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", metadata_in_key)
+    with monkeypatch.context() as m:
+        m.setattr(jax, "named_scope", lambda name: contextlib.nullcontext())
+        jax.clear_caches()
+        assert launched("ddpg", "scan").chunk_ops()["ops"] == {}
+    filled = len(os.listdir(cache_dir))
+    assert filled > 0
+    jax.clear_caches()  # a new process: nothing in memory, the directory as it was left
+    table = launched("ddpg", "scan").chunk_ops()
+    if metadata_in_key:
+        assert {"draw", "gather", "cut", "update", "update/optim"} <= set(table["ops"].values())
+        assert len(os.listdir(cache_dir)) > filled  # compiled anew, under another key
+    else:
+        assert table["ops"] == {}  # the unscoped executable, loaded under the same key
+
+
+# --- train() writes the table beside its records ---
+
+
+def run_train(log_path=None, trace_dir=None):
+    from distributed_ddpg_tpu.train import train
+
+    flags = [
+        "--backend=jax_tpu", "--env_id=Pendulum-v1", "--num_actors=2", "--total_env_steps=1200",
+        "--replay_min_size=300", "--eval_every=0", "--actor_hidden=16,16", "--critic_hidden=16,16",
+        "--replay_capacity=4096", "--batch_size=16", "--learner_chunk=8",
+    ]
+    if log_path:
+        flags.append(f"--log_path={log_path}")
+    if trace_dir:
+        flags.append(f"--trace_dir={trace_dir}")
+    return train(DDPGConfig.from_flags(flags))
+
+
+def test_train_writes_chunk_ops_beside_its_records_and_names_it(tmp_path):
+    log = tmp_path / "run" / "records.jsonl"
+    log.parent.mkdir()
+    summary = run_train(log, tmp_path / "tr")
+    assert summary["learner_steps"] > 0 and summary["log_path"] == str(log)
+    assert summary["chunk_ops_path"] == str(log.parent / trace.CHUNK_OPS_FILE)
+    table = json.loads((log.parent / trace.CHUNK_OPS_FILE).read_text())
+    assert table["module"] == "jit_sample_chunk_fn" and table["scopes"] == list(trace.CHUNK_SCOPES)
+    assert {"draw", "gather", "cut", "update", "update/optim", "update/polyak"} <= set(table["ops"].values())
+    assert any(table["ops"].get(loop) == "update" for loop in table["loops"])
+    # with --trace_dir it lies beside trace.json too
+    assert json.loads((tmp_path / "tr" / trace.CHUNK_OPS_FILE).read_text()) == table
+    assert (tmp_path / "tr" / "trace.json").exists()
+
+
+def test_train_writes_no_table_without_a_records_file(tmp_path, monkeypatch):
+    monkeypatch.chdir(tmp_path)
+    summary = run_train()
+    assert summary["learner_steps"] > 0
+    assert summary["log_path"] == "" and summary["chunk_ops_path"] == ""
+    assert not list(tmp_path.rglob(trace.CHUNK_OPS_FILE))
