@@ -25,20 +25,22 @@ half-written latest checkpoint costs one cadence of progress, not the run.
 from __future__ import annotations
 
 import dataclasses
+import importlib
 import json
 import math
 import os
 import re
 import shutil
 import sys
+import threading
 import time
 import zlib
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import numpy as np
-import orbax.checkpoint as ocp
 
+from distributed_ddpg_tpu import trace
 from distributed_ddpg_tpu.config import DDPGConfig
 from distributed_ddpg_tpu.types import TrainState
 
@@ -88,6 +90,96 @@ def _snapshot(
     return ckpt
 
 
+class _OrbaxImport:
+    """`orbax.checkpoint`, imported once and only by a process that
+    checkpoints. The import is the slowest thing a run does before its
+    first step that is not the compiler (`google.api_core`, pulled in by
+    orbax's cloud logger, walks the metadata of every installed
+    distribution: 39-47 s on a chip host), so importing this module does
+    not pay it. One thread owns the import: `warm()`'s daemon thread, or
+    the first caller of `get()` when nobody warmed; every other caller
+    waits for that one."""
+
+    def __init__(self, load=lambda: importlib.import_module("orbax.checkpoint")):
+        self._load = load
+        self._lock = threading.Lock()  # the claim, and waited_s
+        self._claimed = False
+        self._done = threading.Event()
+        self._module = None
+        self._error: Optional[BaseException] = None
+        self.import_s = 0.0  # 0.0: never imported
+        self.waited_s = 0.0  # callers' seconds blocked on another thread's import
+        self.thread = ""  # name of the thread the import ran on
+
+    def _claim(self) -> bool:
+        with self._lock:
+            first, self._claimed = not self._claimed, True
+        return first
+
+    def _import(self) -> None:
+        t0 = time.perf_counter()
+        try:
+            with trace.span("ckpt_import"):
+                self._module = self._load()
+        except BaseException as e:  # re-raised in every caller of get()
+            self._error = e
+        finally:
+            self.import_s = time.perf_counter() - t0
+            self.thread = threading.current_thread().name
+            self._done.set()
+
+    def warm(self) -> None:
+        if self._claim():
+            threading.Thread(
+                target=self._import, name="ckpt-import", daemon=True
+            ).start()
+
+    def get(self):
+        if not self._done.is_set():
+            if self._claim():
+                self._import()
+            else:
+                t0 = time.perf_counter()
+                self._done.wait()
+                with self._lock:
+                    self.waited_s += time.perf_counter() - t0
+        if self._error is not None:
+            raise self._error
+        return self._module
+
+
+_ORBAX = _OrbaxImport()
+
+
+def warm() -> None:
+    """Start importing orbax on a daemon thread, once (idempotent). A run
+    with a checkpoint directory calls this as its imports end, so the
+    import rides beside the backend's start and the first compile (both
+    release the GIL) instead of in front of them, and is not left for the
+    first cadence save's writer thread or, worse, the SIGTERM handler's
+    emergency save, whose grace period is shorter than the import.
+    Whatever needs orbax first joins it through `_orbax()`."""
+    _ORBAX.warm()
+
+
+def _orbax():
+    """The `orbax.checkpoint` module: waits for `warm()`'s import, or
+    imports here when nobody warmed."""
+    return _ORBAX.get()
+
+
+def import_fields() -> Dict[str, float]:
+    """Whether this process paid for orbax, and whether anyone waited:
+    `ckpt_import_s` is the import's seconds on the thread that ran it (0.0:
+    orbax was never loaded), `ckpt_import_waited_s` what callers spent
+    blocked on a background import (0.0: it had finished before anyone
+    needed it, or the caller imported inline)."""
+    return {
+        "ckpt_import_s": round(_ORBAX.import_s, 3),
+        "ckpt_import_waited_s": round(_ORBAX.waited_s, 3),
+    }
+
+
 def _checkpointer() -> "ocp.StandardCheckpointer":
     """A StandardCheckpointer whose cross-process barriers are scoped to
     THIS process only. The repo's checkpoint discipline is single-writer
@@ -104,8 +196,7 @@ def _checkpointer() -> "ocp.StandardCheckpointer":
     keep orbax's atomic-rename machinery intact with zero cross-process
     traffic. Single-process runs keep stock options (every barrier is
     already skipped)."""
-    import jax
-
+    ocp = _orbax()
     if jax.process_count() == 1:
         return ocp.StandardCheckpointer()
     me = jax.process_index()
@@ -349,8 +440,6 @@ def _write(directory: str, step: int, ckpt: Dict[str, Any],
     (path, retries_used). `fault` is a faults.FaultSite ticked once per
     ATTEMPT — retries advance the ordinal, so 'ioerror@2' scripts 'the
     second attempt overall fails'."""
-    from distributed_ddpg_tpu import trace
-
     for attempt in range(retries + 1):
         try:
             if fault is not None:
@@ -424,8 +513,6 @@ class AsyncSaver:
     backlog entry."""
 
     def __init__(self):
-        import threading
-
         self._lock = threading.Lock()
         self._thread: Optional[threading.Thread] = None
         self.skipped = 0
@@ -457,8 +544,6 @@ class AsyncSaver:
         if the previous write is still in flight. `devactor_state` must
         already be host-side numpy (device_pool.carry_state_dict pulls it
         on the caller's thread, same discipline as the state snapshot)."""
-        import threading
-
         with self._lock:
             if self.busy:
                 self.skipped += 1
@@ -466,8 +551,6 @@ class AsyncSaver:
             ckpt = _snapshot(step, state, replay, env_steps, v_bounds=v_bounds)
 
             def _run():
-                from distributed_ddpg_tpu import trace
-
                 try:
                     with trace.span("ckpt_write", step=step):
                         _, used = _write(

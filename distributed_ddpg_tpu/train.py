@@ -34,7 +34,8 @@ import numpy as np
 # Nothing imported at module level may import JAX: ActorPool spawns its
 # workers, a spawned worker re-imports the parent's main module, and under
 # `python -m distributed_ddpg_tpu.train` that is this file (checkpoint.py
-# pulls in jax and orbax, so it is imported where it is used).
+# pulls in jax, so it is imported where it is used; orbax it imports
+# itself, and only in a run that has a checkpoint directory).
 from distributed_ddpg_tpu import trace
 from distributed_ddpg_tpu.config import DDPGConfig
 from distributed_ddpg_tpu.envs import make, spec_of
@@ -253,6 +254,8 @@ def train_ondevice(config: DDPGConfig) -> Dict[str, float]:
     from distributed_ddpg_tpu.ondevice import OnDeviceDDPG
     from distributed_ddpg_tpu.parallel import multihost
 
+    if config.checkpoint_dir:
+        ckpt_lib.warm()
     multihost.initialize()
     trainer = OnDeviceDDPG(config)
     log = MetricsLogger(config.log_path)
@@ -371,12 +374,14 @@ def train_ondevice(config: DDPGConfig) -> Dict[str, float]:
     eval_policy.load_flat(flatten_params(trainer.actor_params_to_host()))
     final_return = _eval_numpy(eval_policy, config, spec)
     rate = env_timer.rate()
+    ckpt_fields = ckpt_lib.import_fields()
     log.log(
         "final", env_steps(),
         learner_steps=trainer.learn_steps,
         env_steps_per_sec=rate,
         learner_steps_per_sec=learn_timer.rate(),
         final_return=final_return,
+        **ckpt_fields,
     )
     log.close()
     return {
@@ -384,6 +389,7 @@ def train_ondevice(config: DDPGConfig) -> Dict[str, float]:
         "learner_steps_per_sec": learn_timer.rate(),
         "learner_steps": trainer.learn_steps,
         "final_return": final_return,
+        **ckpt_fields,
     }
 
 
@@ -549,6 +555,10 @@ def _train_jax_impl(
     )
     from distributed_ddpg_tpu.types import pack_batch_np
 
+    if config.checkpoint_dir:
+        # Beside the backend's start and the first compile, not in front
+        # of them and not inside the first save (checkpoint.warm).
+        ckpt_lib.warm()
     setup.stage("setup_backend")
     # The JAX runtime's own heartbeat killer must stay SLOWER than the
     # pod layer's worst-case detection (deadline + grace), or a peer
@@ -2840,6 +2850,7 @@ def _train_jax_impl(
         "setup_spans": {k: round(v, 3) for k, v in setup.spans.items()},
         "setup_compile_s": round(compiles.seconds, 3),
         "setup_programs_compiled": compiles.programs,
+        **ckpt_lib.import_fields(),
     }
     # ONE serve/devactor snapshot shared by the final record and the
     # returned summary: both stats reset their interval reservoirs at

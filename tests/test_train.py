@@ -110,6 +110,209 @@ def test_entry_modules_import_without_jax():
     assert proc.returncode == 0, proc.stderr
 
 
+# What loading orbax drags in: itself and, through its cloud logger,
+# google.api_core, whose start-up walk of every installed distribution's
+# metadata is most of the 39-47 s the import costs on a chip host.
+_ORBAX_MODULES = ("orbax", "google.api_core")
+
+# One child process per run: the CLI's main() on tiny Pendulum nets, with
+# what the case asks for steered from here (a SIGTERM once a record of a
+# given kind is in the log; an orbax import held open until after it),
+# then one JSON line of what the process ended as.
+_ORBAX_CHILD = r"""
+import json, os, signal, sys, threading, time
+case = json.loads(sys.argv[1])
+import jax
+from distributed_ddpg_tpu import checkpoint as ckpt_lib
+
+gate = threading.Event()
+if case["hold_import"]:
+    load = ckpt_lib._ORBAX._load
+    ckpt_lib._ORBAX._load = lambda: (gate.wait(120), load())[1]
+
+def preempt():
+    needle = '"kind": "%s"' % case["sigterm_at"]
+    while not (os.path.exists(case["log_path"])
+               and needle in open(case["log_path"]).read()):
+        time.sleep(0.05)
+    os.kill(os.getpid(), signal.SIGTERM)
+    time.sleep(1.0)  # the emergency save is under way, the import is not done
+    gate.set()
+
+code = 0
+if case["flags"] is not None:
+    from distributed_ddpg_tpu import train
+    if case["sigterm_at"]:
+        threading.Thread(target=preempt, daemon=True).start()
+    try:
+        train.main([
+            "--env_id=Pendulum-v1", "--actor_hidden=16,16",
+            "--critic_hidden=16,16", "--num_actors=1",
+            "--replay_min_size=256", "--replay_capacity=20000",
+            "--eval_every=0", "--log_path=" + case["log_path"],
+            *case["flags"],
+        ])
+    except SystemExit as e:
+        code = e.code
+print(json.dumps({
+    "exit": code,
+    "loaded": [m for m in case["modules"] if m in sys.modules],
+    "import_thread": ckpt_lib._ORBAX.thread,
+}))
+"""
+
+
+def _orbax_child(tmp_path, name, flags=None, sigterm_at="", hold_import=False):
+    """Run `_ORBAX_CHILD`; returns (its last line, the run's final record,
+    its stdout)."""
+    import json
+    import subprocess
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    log_path = tmp_path / f"{name}.jsonl"
+    case = {
+        "flags": flags, "sigterm_at": sigterm_at, "hold_import": hold_import,
+        "log_path": str(log_path), "modules": _ORBAX_MODULES,
+    }
+    proc = subprocess.run(
+        [sys.executable, "-c", _ORBAX_CHILD, json.dumps(case)], cwd=root,
+        env={**os.environ, "JAX_PLATFORMS": "cpu"},
+        capture_output=True, text=True, timeout=240,
+    )
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    facts = json.loads(proc.stdout.splitlines()[-1])
+    final = None
+    if flags is not None:
+        final = json.loads(log_path.read_text().splitlines()[-1])
+        assert final["kind"] == "final"
+    return facts, final, proc.stdout
+
+
+@pytest.mark.parametrize(
+    "case",
+    ["import_only", "no_dir_to_end", "no_dir_sigterm", "dir_save_then_resume",
+     "dir_sigterm_before_any_save"],
+)
+def test_a_run_pays_for_orbax_only_if_it_checkpoints(tmp_path, case):
+    """ISSUE 37: `import orbax.checkpoint` is off the path to the first step.
+    A process that never writes or reads a checkpoint never loads it (not at
+    import, not at SIGTERM, not at exit: `ckpt_import_s` 0.0); one with a
+    checkpoint directory imports it on the `ckpt-import` thread beside its
+    start-up, and the first save, a resume's restore and the SIGTERM
+    emergency save join that import rather than start one of their own."""
+    from distributed_ddpg_tpu.train import EXIT_PREEMPTED
+
+    ckpt_dir = str(tmp_path / "ckpt")
+    paced = ["--max_learn_ratio=1.0", "--max_ingest_ratio=1.0"]
+    forever = ["--total_env_steps=2000000"]
+    if case == "import_only":
+        facts, _, _ = _orbax_child(tmp_path, "a")
+        assert facts == {"exit": 0, "loaded": [], "import_thread": ""}
+    elif case in ("no_dir_to_end", "no_dir_sigterm"):
+        sigterm = case == "no_dir_sigterm"
+        facts, final, _ = _orbax_child(
+            tmp_path, "a",
+            flags=forever if sigterm else ["--total_env_steps=1500"],
+            sigterm_at="train" if sigterm else "",
+        )
+        assert facts == {
+            "exit": EXIT_PREEMPTED if sigterm else 0,
+            "loaded": [], "import_thread": "",
+        }
+        assert final["ckpt_import_s"] == 0.0
+        assert final["ckpt_import_waited_s"] == 0.0
+        assert final["learner_steps"] > 0
+    elif case == "dir_save_then_resume":
+        dir_flags = [f"--checkpoint_dir={ckpt_dir}", "--checkpoint_every=100"]
+        facts, final, _ = _orbax_child(
+            tmp_path, "a", flags=["--total_env_steps=1200", *paced, *dir_flags]
+        )
+        assert facts["exit"] == 0 and facts["import_thread"] == "ckpt-import"
+        assert final["ckpt_import_s"] > 0
+        saved = ckpt_lib.latest_step(ckpt_dir)
+        assert saved is not None and saved >= 100
+        assert os.path.exists(os.path.join(ckpt_dir, f"manifest_{saved}.json"))
+        assert ckpt_lib.verify_checkpoint(ckpt_dir, saved) == (True, "ok")
+        # A second process resumes: restore() needs orbax before the first
+        # chunk and gets it from the warm import, not one of its own.
+        facts, final, stdout = _orbax_child(
+            tmp_path, "b", flags=["--total_env_steps=1600", *paced, *dir_flags]
+        )
+        assert f"resumed from {ckpt_dir} at learner step {saved}" in stdout
+        assert facts["exit"] == 0 and facts["import_thread"] == "ckpt-import"
+        assert final["ckpt_import_s"] > 0
+        assert final["learner_steps"] > saved
+    else:
+        # The cadence never fires and the import is held open until a
+        # second after the SIGTERM: the emergency save is the first to need
+        # orbax and finds the import still running.
+        facts, final, _ = _orbax_child(
+            tmp_path, "a",
+            flags=[*forever, f"--checkpoint_dir={ckpt_dir}",
+                   "--checkpoint_every=1000000000"],
+            sigterm_at="train", hold_import=True,
+        )
+        assert facts["exit"] == EXIT_PREEMPTED
+        assert facts["import_thread"] == "ckpt-import"
+        assert final["ckpt_import_s"] > 0 and final["ckpt_import_waited_s"] > 0
+        assert final["emergency_ckpt"] == 1
+        saved = ckpt_lib.latest_step(ckpt_dir)
+        assert saved == final["learner_steps"]
+        assert ckpt_lib.verify_checkpoint(ckpt_dir, saved) == (True, "ok")
+
+
+def test_orbax_import_runs_once_whoever_asks():
+    """Two callers racing the accessor, with or without a `warm()` before
+    them, import once: one thread owns the import, every other caller waits
+    for it and is counted as waiting; a failed import fails every caller."""
+    import threading
+    import time
+
+    for warmed in (False, True):
+        calls = []
+
+        def slow_load():
+            calls.append(threading.current_thread().name)
+            time.sleep(0.3)
+            return object()
+
+        imp = ckpt_lib._OrbaxImport(load=slow_load)
+        if warmed:
+            imp.warm()
+            imp.warm()  # idempotent
+        got = []
+        callers = [
+            threading.Thread(target=lambda: got.append(imp.get()), name=f"caller-{i}")
+            for i in range(2)
+        ]
+        for t in callers:
+            t.start()
+        for t in callers:
+            t.join(timeout=10)
+            assert not t.is_alive()
+        assert len(calls) == 1 and len(got) == 2 and got[0] is got[1]
+        assert imp.thread == calls[0]
+        assert (imp.thread == "ckpt-import") == warmed
+        assert imp.import_s >= 0.3
+        # Without a warm import one caller imports inline and one waits; with
+        # one, both wait.
+        assert imp.waited_s > (0.3 if warmed else 0.0)
+        assert imp.get() is got[0]  # and no wait is counted once it is in
+        waited = imp.waited_s
+        imp.get()
+        assert imp.waited_s == waited
+
+    def failing_load():
+        raise ImportError("no orbax here")
+
+    imp = ckpt_lib._OrbaxImport(load=failing_load)
+    imp.warm()
+    for _ in range(2):
+        with pytest.raises(ImportError, match="no orbax here"):
+            imp.get()
+
+
 def test_learner_chunk_resolution():
     """config.learner_chunk: explicit value wins; 0 = auto (8 on the CPU
     test platform, 800 only on kernel-native TPU backends)."""
