@@ -58,6 +58,7 @@ COMPAT_FIELDS = (
     "twin_critic",  # rank-3 ensemble critic leaves vs rank-2 plain ones
     "sac",  # double-width Gaussian head + twin leaves + log_alpha node
     "sac_autotune",  # alpha_opt presence changes the TrainState tree
+    "crossq",  # no target nodes; batch-norm leaves in every layer
     "num_atoms",
     "v_min",
     "v_max",
@@ -586,7 +587,8 @@ def check_config_compatible(directory: str, step: int, config: DDPGConfig) -> No
     if not os.path.exists(path):
         return
     with open(path) as f:
-        saved = json.load(f)
+        # a checkpoint from before the field existed was not a crossq run's
+        saved = {"crossq": False, **json.load(f)}
     current = dataclasses.asdict(config)
     mismatches = [
         f"{k}: checkpoint={saved[k]!r} run={_listify(current[k])!r}"

@@ -111,6 +111,23 @@ class DDPGConfig:
     critic_ensemble: int = 2
     target_subset: int = 2
 
+    # --- CrossQ (arXiv 1902.05605; sac only) ---
+    # crossq: sac without target networks. One field turns its three
+    # changes on together, since they are one algorithm: the TrainState's
+    # target slots are None and no Polyak pass is traced; actor and critics
+    # carry a batch-norm layer in front of every dense layer (models/mlp.py);
+    # and the Bellman target is read from the SAME training-mode forward
+    # pass as the prediction, on the joint batch [(s, a); (s', a')], so one
+    # set of batch statistics normalises both halves. The source's other
+    # settings are plain flags: critic_hidden 2048,2048, policy_delay 3,
+    # adam_b1 0.5, action_insert_layer 0, both learning rates 1e-3.
+    crossq: bool = False
+    # Adam's beta_1, for every net's optimiser and the temperature's
+    # (ops/optim.py; 0.9 there where unset). The megakernel, fused_update
+    # and the native backend hold 0.9 as a constant: another value takes
+    # the scan leg and is refused by the other two.
+    adam_b1: float = 0.9
+
     # --- replay (SURVEY.md §2 #5/#7) ---
     replay_capacity: int = 1_000_000
     replay_min_size: int = 1_000     # warmup before learning starts
@@ -626,14 +643,15 @@ class DDPGConfig:
 
     @property
     def redq(self) -> bool:
-        """sac with any of REDQ's departures on: another ensemble size than
-        two, a drawn in-target subset, or a delayed policy. These runs take
-        the scan leg (ops/fused_chunk.supported) and carry `redq_q_spread`
-        and `redq_policy_updates` in their records; plain sac does not."""
+        """sac with REDQ's ensemble on: another ensemble size than two or a
+        drawn in-target subset, or (without crossq, which has a delay of
+        its own) a delayed policy. These runs carry `redq_q_spread` and
+        `redq_policy_updates` in their records; plain sac and crossq do
+        not."""
         return self.sac and (
             self.critic_ensemble != 2
             or self.target_subset != self.critic_ensemble
-            or self.policy_delay > 1
+            or (self.policy_delay > 1 and not self.crossq)
         )
 
     @property
@@ -826,6 +844,30 @@ class DDPGConfig:
             raise ValueError(
                 "sac is its own algorithm family (it builds its twin-critic "
                 "ensemble internally); disable twin_critic/distributional"
+            )
+        if self.crossq and not self.sac:
+            raise ValueError(
+                "crossq is sac without target networks (joint batch-normalised "
+                "critic pass) — set sac=True or it would silently do nothing"
+            )
+        if self.crossq and self.backend == "native":
+            raise ValueError(
+                "crossq requires a JAX backend: the native numpy learner has "
+                "no batch normalisation and always carries target networks"
+            )
+        if self.crossq and (self.critic_ensemble, self.target_subset) != (2, 2):
+            raise ValueError(
+                "crossq reads its target from the joint pass of its own twin "
+                "critics: there are no target critics to draw an ensemble "
+                "subset from — leave critic_ensemble/target_subset at 2"
+            )
+        if not 0.0 <= self.adam_b1 < 1.0:
+            raise ValueError("adam_b1 must be in [0, 1)")
+        if self.adam_b1 != 0.9 and (self.backend == "native" or self.fused_update):
+            raise ValueError(
+                "adam_b1 is read by the tree-level Adam (ops/optim.py) only: "
+                "the native backend and the fused_update kernel hold 0.9 as "
+                "a constant"
             )
         if self.sac and self.fused_update:
             raise ValueError(

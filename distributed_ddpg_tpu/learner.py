@@ -34,7 +34,7 @@ from distributed_ddpg_tpu.ops.optim import adam_update
 from distributed_ddpg_tpu.ops.polyak import polyak_update
 from distributed_ddpg_tpu.trace import device_scope
 from distributed_ddpg_tpu.types import Batch, OptState, TrainState
-from distributed_ddpg_tpu.models.mlp import actor_init, critic_init
+from distributed_ddpg_tpu.models.mlp import actor_init, critic_init, norm_moved
 
 
 class StepOutput(NamedTuple):
@@ -66,7 +66,11 @@ def metric_keys(config: DDPGConfig) -> tuple:
     minimum bites. An ensemble (REDQ) run, config.redq, reports
     `redq_q_spread` besides: the batch mean of the standard deviation over
     the N online Q_i(s, a), how far the critics lie apart where the
-    in-target minimum acts. A chunk reports its last update's of each
+    in-target minimum acts. A CrossQ run, config.crossq, reports
+    `bn_stat_gap` besides: the mean over the critics' normalised features of
+    |batch mean - running mean| / running standard deviation, how far the
+    evaluation-mode critics the actor climbs are from the training-mode
+    critics the loss fits. A chunk reports its last update's of each
     (chunk_metrics). Only those branches have the keys, so every other
     family's programs and records are what they were."""
     if config.distributional:  # config.py: never with twin_critic or sac
@@ -75,11 +79,13 @@ def metric_keys(config: DDPGConfig) -> tuple:
         return METRIC_KEYS + ("td3_twin_gap",)
     if config.redq:
         return METRIC_KEYS + ("redq_q_spread",)
+    if config.crossq:
+        return METRIC_KEYS + ("bn_stat_gap",)
     return METRIC_KEYS
 
 
 # Metrics a chunk reports for its LAST update, not as a mean over the K.
-LAST_UPDATE_KEYS = ("c51_edge_mass", "td3_twin_gap", "redq_q_spread")
+LAST_UPDATE_KEYS = ("c51_edge_mass", "td3_twin_gap", "redq_q_spread", "bn_stat_gap")
 
 
 def chunk_metrics(ms: dict) -> dict:
@@ -100,7 +106,8 @@ def delayed_updates(steps, delay: int):
     targets (under sac: the actor and the temperature): the multiples of
     `delay` below `steps`. The one rule behind the scan steps' cond
     (state.step % delay == 0), the kernel's schedule and actor Adam count,
-    and the records' `td3_actor_updates` / `redq_policy_updates`. Works on
+    and the records' `td3_actor_updates` / `redq_policy_updates` /
+    `crossq_policy_updates`. Works on
     ints and on traced scalars."""
     return (steps + delay - 1) // delay
 
@@ -217,7 +224,9 @@ def chunk_noise(config: DDPGConfig, step0, chunk: int, batch: int,
 
 
 def init_train_state(config: DDPGConfig, obs_dim: int, act_dim: int, seed: int) -> TrainState:
-    """Build initial params + hard-copied targets (SURVEY.md §3.4) + Adam state."""
+    """Build initial params + hard-copied targets (SURVEY.md §3.4) + Adam
+    state. CrossQ (config.crossq): batch-normalised nets and no targets,
+    the two slots None (empty pytree nodes, as log_alpha is outside sac)."""
     key = jax.random.PRNGKey(seed)
     k_actor, k_critic = jax.random.split(key)
     num_outputs = config.num_atoms if config.distributional else 1
@@ -231,6 +240,7 @@ def init_train_state(config: DDPGConfig, obs_dim: int, act_dim: int, seed: int) 
         obs_dim,
         actor_head_dim(act_dim, config.sac),
         tuple(config.actor_hidden),
+        norm=config.crossq,
     )
     if config.twin_critic or config.sac:
         # TD3 / SAC ensemble: independently-initialized critics stacked on a
@@ -244,6 +254,7 @@ def init_train_state(config: DDPGConfig, obs_dim: int, act_dim: int, seed: int) 
                 critic_init(
                     k, obs_dim, act_dim, tuple(config.critic_hidden),
                     config.action_insert_layer, num_outputs,
+                    norm=config.crossq,
                 )
                 for k in jax.random.split(k_critic, config.critic_ensemble)
             ),
@@ -260,8 +271,12 @@ def init_train_state(config: DDPGConfig, obs_dim: int, act_dim: int, seed: int) 
     return TrainState(
         actor_params=actor_params,
         critic_params=critic_params,
-        target_actor_params=jax.tree.map(jnp.copy, actor_params),
-        target_critic_params=jax.tree.map(jnp.copy, critic_params),
+        target_actor_params=(
+            None if config.crossq else jax.tree.map(jnp.copy, actor_params)
+        ),
+        target_critic_params=(
+            None if config.crossq else jax.tree.map(jnp.copy, critic_params)
+        ),
         actor_opt=OptState(
             mu=jax.tree.map(jnp.zeros_like, actor_params),
             nu=jax.tree.map(jnp.zeros_like, actor_params),
@@ -335,14 +350,23 @@ def make_learner_step(
         actor loss carries an aux (mean log-prob -> alpha update) that the
         shared branch structure below has no slot for. REDQ (config.redq)
         is this step with N critics, a drawn in-target subset and the
-        policy's half under a cond."""
+        policy's half under a cond; CrossQ (config.crossq) this step with
+        the joint batch-normalised critic pass, the same cond, and no
+        target: no polyak_update is traced."""
         eps_next, eps_cur, *subset = (
             own_noise(state, batch) if noise is None else noise
         )
         subset = subset[0] if subset else None
         alpha = jnp.exp(state.log_alpha)
+        crossq, b1 = config.crossq, config.adam_b1
 
         def critic_loss_fn(cp):
+            if crossq:
+                return losses.crossq_critic_loss(
+                    cp, state.actor_params, batch, scale, eps_next, alpha,
+                    config.sac_log_std_min, config.sac_log_std_max,
+                    ail, config.critic_l2, offset, mm, axis_name,
+                )
             return losses.sac_critic_loss(
                 cp, state.actor_params, state.target_critic_params, batch,
                 scale, eps_next, alpha,
@@ -356,6 +380,20 @@ def make_learner_step(
                 critic_loss_fn, has_aux=True
             )(state.critic_params)
             cgrads = _maybe_psum_mean(cgrads, axis_name)
+        if crossq:
+            td, joint_mean_q, stat_gap, critic_moments = td
+
+        def critic_adam():
+            new, opt = adam_update(
+                state.critic_params, cgrads, state.critic_opt,
+                config.critic_lr, b1,
+            )
+            if crossq:
+                # Adam left the running statistics where they were (their
+                # gradient is zero): the joint pass's moments move them.
+                with device_scope("critic"):
+                    new = norm_moved(new, critic_moments)
+            return new, opt
 
         # Actor gradient against the pre-update critic (file convention):
         # its ensemble's mean where the target draws a subset (REDQ,
@@ -366,22 +404,30 @@ def make_learner_step(
                 config.sac_log_std_min, config.sac_log_std_max,
                 ail, offset, mm,
                 reduce=jnp.min if subset is None else jnp.mean,
+                train_norm=crossq, axis_name=axis_name,
             )
 
         def actor_grads():
+            """(loss, mean log-prob, gradient, the actor's batch-norm
+            moments: None outside CrossQ)."""
             with device_scope("actor"):
                 (aloss, mean_lp), agrads = jax.value_and_grad(
                     actor_loss_fn, has_aux=True
                 )(state.actor_params)
+                mean_lp, moments = mean_lp if crossq else (mean_lp, None)
                 agrads = _maybe_psum_mean(agrads, axis_name)
                 # Global mean log-prob so every shard's alpha update sees the
                 # same scalar (replicas must not fork on log_alpha).
-                return aloss, _maybe_psum_mean(mean_lp, axis_name), agrads
+                return aloss, _maybe_psum_mean(mean_lp, axis_name), agrads, moments
 
-        def actor_adam(agrads):
-            return adam_update(
-                state.actor_params, agrads, state.actor_opt, config.actor_lr
+        def actor_adam(agrads, moments):
+            new, opt = adam_update(
+                state.actor_params, agrads, state.actor_opt, config.actor_lr, b1
             )
+            if crossq:
+                with device_scope("actor"):
+                    new = norm_moved(new, moments)
+            return new, opt
 
         def temperature_adam(mean_lp):
             if not config.sac_autotune:
@@ -398,7 +444,7 @@ def make_learner_step(
             )
             alpha_grad = -(jax.lax.stop_gradient(mean_lp) + tgt_h)
             return adam_update(
-                state.log_alpha, alpha_grad, state.alpha_opt, config.critic_lr
+                state.log_alpha, alpha_grad, state.alpha_opt, config.critic_lr, b1
             )
 
         if config.policy_delay > 1:
@@ -411,14 +457,12 @@ def make_learner_step(
             # pass for the record either (N critic forwards are a third of
             # an update at N = 10): actor_loss and actor_grad_norm read 0 on
             # it, as TD3's actor_grad_norm does.
-            new_critic, critic_opt = adam_update(
-                state.critic_params, cgrads, state.critic_opt, config.critic_lr
-            )
+            new_critic, critic_opt = critic_adam()
 
             def policy_update():
-                aloss, mean_lp, agrads = actor_grads()
+                aloss, mean_lp, agrads, moments = actor_grads()
                 return (
-                    *actor_adam(agrads), *temperature_adam(mean_lp),
+                    *actor_adam(agrads, moments), *temperature_adam(mean_lp),
                     aloss, optree_norm(agrads),
                 )
 
@@ -439,20 +483,22 @@ def make_learner_step(
             # existed (actor gradient, both Adams, both Polyaks, then the
             # temperature, the actor's gradient norm last, in the metrics):
             # its lowered text is held equal to the parent's.
-            aloss, mean_lp, agrads = actor_grads()
-            new_critic, critic_opt = adam_update(
-                state.critic_params, cgrads, state.critic_opt, config.critic_lr
+            aloss, mean_lp, agrads, moments = actor_grads()
+            new_critic, critic_opt = critic_adam()
+            new_actor, actor_opt = actor_adam(agrads, moments)
+        if crossq:
+            # No target exists: the slots stay None and no Polyak pass runs.
+            new_target_critic = new_target_actor = None
+        else:
+            new_target_critic = polyak_update(
+                new_critic, state.target_critic_params, config.tau
             )
-            new_actor, actor_opt = actor_adam(agrads)
-        new_target_critic = polyak_update(
-            new_critic, state.target_critic_params, config.tau
-        )
-        # SAC's math has no target actor; the slot still trails the actor
-        # via the same polyak so the TrainState invariants (targets trail
-        # params) and checkpoint shape stay uniform across families.
-        new_target_actor = polyak_update(
-            new_actor, state.target_actor_params, config.tau
-        )
+            # SAC's math has no target actor; the slot still trails the actor
+            # via the same polyak so the TrainState invariants (targets trail
+            # params) and checkpoint shape stay uniform across families.
+            new_target_actor = polyak_update(
+                new_actor, state.target_actor_params, config.tau
+            )
         if config.policy_delay == 1:
             new_log_alpha, alpha_opt = temperature_adam(mean_lp)
 
@@ -461,6 +507,9 @@ def make_learner_step(
             # ensemble's mean Q(s, a) on the replay rows.
             td, q_spread, mean_q = td
             branch_metrics = (q_spread,)
+        elif crossq:
+            # likewise, and the joint pass's statistics gap
+            mean_q, branch_metrics = joint_mean_q, (stat_gap,)
         else:
             # mean_q recovered exactly: aloss = E[alpha*lp - minQ]
             # => E[minQ] = alpha * mean_lp - aloss.
@@ -588,7 +637,8 @@ def make_learner_step(
             with device_scope("actor"):
                 aloss = actor_loss_fn(state.actor_params)
             new_critic, critic_opt = adam_update(
-                state.critic_params, cgrads, state.critic_opt, config.critic_lr
+                state.critic_params, cgrads, state.critic_opt, config.critic_lr,
+                config.adam_b1,
             )
 
             def _delayed_update(_):
@@ -596,7 +646,8 @@ def make_learner_step(
                     agrads = jax.grad(actor_loss_fn)(state.actor_params)
                     agrads = _maybe_psum_mean(agrads, axis_name)
                 na, aopt = adam_update(
-                    state.actor_params, agrads, state.actor_opt, config.actor_lr
+                    state.actor_params, agrads, state.actor_opt, config.actor_lr,
+                    config.adam_b1,
                 )
                 return (
                     na,
@@ -653,10 +704,12 @@ def make_learner_step(
                 agrads = _maybe_psum_mean(agrads, axis_name)
             actor_grad_norm = optree_norm(agrads)
             new_critic, critic_opt = adam_update(
-                state.critic_params, cgrads, state.critic_opt, config.critic_lr
+                state.critic_params, cgrads, state.critic_opt, config.critic_lr,
+                config.adam_b1,
             )
             new_actor, actor_opt = adam_update(
-                state.actor_params, agrads, state.actor_opt, config.actor_lr
+                state.actor_params, agrads, state.actor_opt, config.actor_lr,
+                config.adam_b1,
             )
 
             # --- Polyak target updates, fused in (SURVEY.md §3.4) ---

@@ -27,6 +27,8 @@ from typing import Any, Dict, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
+from distributed_ddpg_tpu.trace import device_scope
+
 Params = Tuple[Dict[str, Any], ...]
 
 FINAL_INIT_SCALE = 3e-3
@@ -55,8 +57,121 @@ def mlp_init(key, dims: Sequence[int], dtype=jnp.float32) -> Params:
     )
 
 
-def actor_init(key, obs_dim: int, act_dim: int, hidden: Sequence[int], dtype=jnp.float32) -> Params:
-    return mlp_init(key, [obs_dim, *hidden, act_dim], dtype)
+def actor_init(
+    key, obs_dim: int, act_dim: int, hidden: Sequence[int], dtype=jnp.float32,
+    norm: bool = False,
+) -> Params:
+    params = mlp_init(key, [obs_dim, *hidden, act_dim], dtype)
+    return with_norm(params) if norm else params
+
+
+# --- batch normalisation (CrossQ, arXiv 1902.05605) ---
+# A normalised net keeps a batch-norm layer IN FRONT of every dense layer
+# (BN_0 on the net's input, BN_l on relu(dense_l)), as four more leaves of
+# that dense layer's dict: `bn_scale` and `bn_shift`, which Adam trains, and
+# the running `bn_mean` and `bn_var`, which the training-mode forward pass
+# writes. The statistics live in the parameter tree so that everything that
+# carries parameters (checkpoints, sharding rules, donation, the on-chip
+# comparison) carries them; no gradient reaches them (training mode does not
+# read them, evaluation mode is never differentiated with respect to them),
+# Adam leaves a leaf with a zero gradient where it is (m = v = 0), and the
+# learner overwrites them after the step (`norm_moved`).
+BN_MOMENTUM = 0.99  # running <- 0.99 * running + 0.01 * batch
+BN_EPS = 1e-3
+
+
+def with_norm(params: Params) -> Params:
+    """`params` with an identity batch-norm layer in front of every dense
+    layer: scale 1, shift 0, running mean 0 and variance 1."""
+    def leaves(layer):
+        n, dtype = layer["w"].shape[-2], layer["w"].dtype
+        return {
+            "bn_scale": jnp.ones((n,), dtype), "bn_shift": jnp.zeros((n,), dtype),
+            "bn_mean": jnp.zeros((n,), dtype), "bn_var": jnp.ones((n,), dtype),
+        }
+
+    return tuple({**layer, **leaves(layer)} for layer in params)
+
+
+def _global_mean(x, axis_name):
+    """A replica's row mean -> the global batch's, under a data axis."""
+    if axis_name is None:
+        return x
+    # lint: ok(collective-discipline): only traced inside the jitted learner
+    # step, whose shard_map builder (parallel/) threads axis_name; never eager
+    return jax.lax.pmean(x, axis_name)
+
+
+def _norm(x, layer, train: bool, axis_name, moments: list):
+    """The batch-norm layer in front of `layer`, or `x` itself where the
+    layer has none. Training mode normalises by the moments of ALL of x's
+    rows (every axis but the last; the biased variance), the global batch's
+    under a data axis (`axis_name`: two pmeans a layer), and appends them
+    to `moments`; evaluation mode by the running statistics."""
+    if "bn_scale" not in layer:
+        return x
+    with device_scope("norm"):
+        if train:
+            rows = tuple(range(x.ndim - 1))
+            mean = _global_mean(jnp.mean(x, axis=rows), axis_name)
+            var = _global_mean(jnp.mean(jnp.square(x - mean), axis=rows), axis_name)
+            moments.append((mean, var))
+        else:
+            mean, var = layer["bn_mean"], layer["bn_var"]
+        return (x - mean) * (
+            layer["bn_scale"] * jax.lax.rsqrt(var + BN_EPS)
+        ) + layer["bn_shift"]
+
+
+def norm_moved(params: Params, moments) -> Params:
+    """`params` with each layer's running statistics moved a step of
+    1 - BN_MOMENTUM towards `moments` (a training-mode pass's, one (mean,
+    var) a layer; stacked nets' carry the stack's leading axis)."""
+    with device_scope("norm"):
+        return tuple(
+            {
+                **layer,
+                "bn_mean": BN_MOMENTUM * layer["bn_mean"] + (1.0 - BN_MOMENTUM) * mean,
+                "bn_var": BN_MOMENTUM * layer["bn_var"] + (1.0 - BN_MOMENTUM) * var,
+            }
+            for layer, (mean, var) in zip(params, moments)
+        )
+
+
+def norm_stat_gap(params: Params, moments):
+    """Mean over the normalised features of |batch mean - running mean| /
+    running standard deviation: how far an evaluation-mode pass is from the
+    training-mode pass that gave `moments`."""
+    with device_scope("norm"):
+        gaps = [
+            jnp.abs(mean - layer["bn_mean"]) * jax.lax.rsqrt(layer["bn_var"] + BN_EPS)
+            for layer, (mean, _) in zip(params, moments)
+        ]
+        return sum(jnp.sum(g) for g in gaps) / sum(g.size for g in gaps)
+
+
+def fold_norm(params):
+    """A normalised net in evaluation mode as a plain {w, b} MLP: each
+    batch-norm layer is an affine map in front of a dense layer, x -> x * k
+    + t with k = scale / sqrt(var + eps) and t = shift - mean * k, so the
+    dense layer becomes w' = diag(k) w and b' = b + t w. Host side (numpy
+    leaves in, numpy out): what leaves the learner for the actors, the
+    evaluator and the serving engine is a plain MLP, and their layouts do
+    not know the normalisation exists. A plain net comes back as it is."""
+    import numpy as np
+
+    if "bn_scale" not in params[0]:
+        return params
+    out = []
+    for layer in params:
+        f = {name: np.asarray(leaf, np.float64) for name, leaf in layer.items()}
+        k = f["bn_scale"] / np.sqrt(f["bn_var"] + BN_EPS)
+        t = f["bn_shift"] - f["bn_mean"] * k
+        out.append({
+            "w": (k[:, None] * f["w"]).astype(np.float32),
+            "b": (f["b"] + t @ f["w"]).astype(np.float32),
+        })
+    return tuple(out)
 
 
 def _dense(x, layer, mm_dtype):
@@ -87,7 +202,8 @@ def actor_apply(params: Params, obs, action_scale, action_offset=0.0, mm_dtype=N
 
 
 def actor_gaussian_apply(
-    params: Params, obs, log_std_min: float, log_std_max: float, mm_dtype=None
+    params: Params, obs, log_std_min: float, log_std_max: float, mm_dtype=None,
+    train: bool = False, axis_name=None,
 ):
     """SAC stochastic head: the final layer outputs [mean | log_std]
     (2*act_dim wide — build params with actor_init(act_dim=2*act_dim)).
@@ -95,15 +211,21 @@ def actor_gaussian_apply(
     correction live in ops/losses.py so this stays a pure network apply.
     log_std is soft-clamped onto [min, max] with a tanh map — a hard clip
     would zero its gradient exactly where autotuned-alpha training tends
-    to push it."""
-    x = obs
+    to push it. A normalised net (`with_norm`) runs in evaluation mode
+    unless `train`, which returns ((mean, log_std), moments) for
+    `norm_moved`."""
+    x, moments = obs, []
     for layer in params[:-1]:
-        x = jax.nn.relu(_dense(x, layer, mm_dtype))
-    x = _dense(x, params[-1], mm_dtype)
+        x = jax.nn.relu(
+            _dense(_norm(x, layer, train, axis_name, moments), layer, mm_dtype)
+        )
+    x = _dense(_norm(x, params[-1], train, axis_name, moments), params[-1], mm_dtype)
     mean, log_std_raw = jnp.split(x, 2, axis=-1)
     log_std = log_std_min + 0.5 * (log_std_max - log_std_min) * (
         jnp.tanh(log_std_raw) + 1.0
     )
+    if train:
+        return (mean, log_std), tuple(moments)
     return mean, log_std
 
 
@@ -115,6 +237,7 @@ def critic_init(
     action_insert_layer: int = 1,
     num_outputs: int = 1,
     dtype=jnp.float32,
+    norm: bool = False,
 ) -> Params:
     """Critic params. The layer at index `action_insert_layer` takes
     [features, action] concatenated as its input (classic DDPG).
@@ -132,21 +255,25 @@ def critic_init(
     for i in range(n):
         in_dim = dims[i] + (act_dim if i == action_insert_layer else 0)
         layers.append(_linear_init(keys[i], in_dim, dims[i + 1], final=(i == n - 1), dtype=dtype))
-    return tuple(layers)
+    return with_norm(tuple(layers)) if norm else tuple(layers)
 
 
 def critic_apply(
-    params: Params, obs, action, action_insert_layer: int = 1, mm_dtype=None
+    params: Params, obs, action, action_insert_layer: int = 1, mm_dtype=None,
+    train: bool = False, axis_name=None,
 ) -> Any:
-    """Q(s, a) -> f32[B] (or f32[B, num_atoms] logits when distributional)."""
-    x = obs
+    """Q(s, a) -> f32[B] (or f32[B, num_atoms] logits when distributional).
+    A normalised net (`with_norm`) runs in evaluation mode unless `train`,
+    which returns (Q, moments) for `norm_moved`; its batch is then every
+    leading axis of `obs` (CrossQ's joint pass: [2, B, obs])."""
+    x, moments = obs, []
     n = len(params)
     for i, layer in enumerate(params):
         if i == action_insert_layer:
             x = jnp.concatenate([x, action], axis=-1)
-        x = _dense(x, layer, mm_dtype)
+        x = _dense(_norm(x, layer, train, axis_name, moments), layer, mm_dtype)
         if i < n - 1:
             x = jax.nn.relu(x)
     if x.shape[-1] == 1:
-        return jnp.squeeze(x, axis=-1)
-    return x
+        x = jnp.squeeze(x, axis=-1)
+    return (x, tuple(moments)) if train else x

@@ -258,7 +258,10 @@ def supported(config: DDPGConfig) -> bool:
     return (
         config.action_insert_layer == 1
         and config.critic_l2 == 0.0
-        and not (config.fused_update or config.redq)  # REDQ: no kernel branch
+        # REDQ, CrossQ: no kernel branch; the kernel's Adam holds beta_1
+        # as the constant B1
+        and not (config.fused_update or config.redq or config.crossq)
+        and config.adam_b1 == B1
         and config.compute_dtype in ("float32", "bfloat16")
         # The hand-written backward assumes the action-insert layer (1) is
         # not the critic's output layer, i.e. at least 2 hidden layers.

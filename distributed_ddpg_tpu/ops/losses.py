@@ -237,6 +237,62 @@ def sac_critic_loss(
     return loss, jnp.mean(td, axis=0)
 
 
+def crossq_critic_loss(
+    critic_params,
+    actor_params,
+    batch: Batch,
+    action_scale,
+    eps,
+    alpha,
+    log_std_min: float,
+    log_std_max: float,
+    action_insert_layer: int = 1,
+    l2: float = 0.0,
+    action_offset=0.0,
+    mm_dtype=None,
+    axis_name=None,
+):
+    """CrossQ's critic loss (arXiv 1902.05605): sac_critic_loss without
+    target networks. a' ~ pi(.|s') from the actor in evaluation mode; each
+    batch-normalised critic runs ONCE, in training mode, on the joint batch
+    [(s, a); (s', a')] (stacked on a leading axis of 2, so a data mesh
+    shards both halves alike), whose 2B rows give the one set of batch
+    moments that normalises both halves; the prediction is the first half,
+    the Bellman target y = r + discount * stop_gradient(min_i q'_i - alpha *
+    log pi(a'|s')) the second. Returns (loss, (td_proxy[B], mean_q,
+    bn_stat_gap, moments)): the critics' mean Q(s, a), mlp.norm_stat_gap of
+    the pass, and its moments for mlp.norm_moved, all of what this loss
+    holds anyway."""
+    from distributed_ddpg_tpu.models.mlp import actor_gaussian_apply, norm_stat_gap
+
+    mean, log_std = actor_gaussian_apply(
+        actor_params, batch.next_obs, log_std_min, log_std_max, mm_dtype
+    )
+    next_action, next_lp = sac_sample(mean, log_std, eps, action_scale, action_offset)
+    obs = jnp.stack([batch.obs, batch.next_obs])
+    action = jnp.stack([batch.action, next_action])
+    joint, moments = jax.vmap(
+        lambda cp: critic_apply(
+            cp, obs, action, action_insert_layer, mm_dtype,
+            train=True, axis_name=axis_name,
+        )
+    )(critic_params)  # [N, 2, B]
+    q, next_q = joint[:, 0], joint[:, 1]
+    y = jax.lax.stop_gradient(
+        td_targets(batch, jnp.min(next_q, axis=0) - alpha * next_lp)
+    )
+    td = y[None, :] - q
+    loss = jnp.mean(batch.weight[None, :] * jnp.square(td))
+    if l2 > 0.0:
+        loss = loss + l2 * sum(
+            jnp.sum(jnp.square(layer["w"])) for layer in critic_params
+        )
+    return loss, (
+        jnp.mean(td, axis=0), jnp.mean(q),
+        norm_stat_gap(critic_params, moments), moments,
+    )
+
+
 def sac_actor_loss(
     actor_params,
     critic_params,
@@ -250,6 +306,8 @@ def sac_actor_loss(
     action_offset=0.0,
     mm_dtype=None,
     reduce=jnp.min,
+    train_norm: bool = False,
+    axis_name=None,
 ):
     """Reparameterized actor objective E[alpha * log pi(a|s) - min_i Q_i(s, a)],
     a drawn with the standard normals `eps` (f32[B, act]).
@@ -258,12 +316,17 @@ def sac_actor_loss(
     the 1812.05905 convention; REDQ, whose target draws a subset, against
     the ensemble MEAN (`reduce=jnp.mean`; 2101.05982, Algorithm 1).
     Returns (loss, mean_log_prob) — the aux feeds the alpha (temperature)
-    update."""
+    update. CrossQ (`train_norm`): the batch-normalised actor runs in
+    training mode on its own B rows, the critics under it in evaluation
+    mode (running statistics), and the aux is (mean_log_prob, the actor's
+    moments for mlp.norm_moved)."""
     from distributed_ddpg_tpu.models.mlp import actor_gaussian_apply
 
-    mean, log_std = actor_gaussian_apply(
-        actor_params, batch.obs, log_std_min, log_std_max, mm_dtype
+    head = actor_gaussian_apply(
+        actor_params, batch.obs, log_std_min, log_std_max, mm_dtype,
+        train=train_norm, axis_name=axis_name,
     )
+    (mean, log_std), moments = head if train_norm else (head, None)
     action, lp = sac_sample(mean, log_std, eps, action_scale, action_offset)
     q = reduce(
         jax.vmap(
@@ -271,6 +334,8 @@ def sac_actor_loss(
         )(critic_params),
         axis=0,
     )
+    if train_norm:
+        return jnp.mean(alpha * lp - q), (jnp.mean(lp), moments)
     return jnp.mean(alpha * lp - q), jnp.mean(lp)
 
 

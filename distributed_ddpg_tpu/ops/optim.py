@@ -27,14 +27,15 @@ B2 = 0.999
 EPS = 1e-8
 
 
-def adam_update(params, grads, opt: OptState, lr):
-    """One Adam step. Returns (new_params, new_opt)."""
+def adam_update(params, grads, opt: OptState, lr, b1: float = B1):
+    """One Adam step with beta_1 `b1` (config.adam_b1). Returns
+    (new_params, new_opt)."""
     with device_scope("optim"):
         count = opt.count + 1
         c = count.astype(jnp.float32)
-        bc1 = 1.0 - B1 ** c
+        bc1 = 1.0 - b1 ** c
         bc2 = 1.0 - B2 ** c
-        mu = jax.tree.map(lambda m, g: B1 * m + (1.0 - B1) * g, opt.mu, grads)
+        mu = jax.tree.map(lambda m, g: b1 * m + (1.0 - b1) * g, opt.mu, grads)
         nu = jax.tree.map(
             lambda v, g: B2 * v + (1.0 - B2) * (g * g), opt.nu, grads
         )

@@ -43,6 +43,7 @@ from distributed_ddpg_tpu.learner import (
     metric_keys,
     noise_per_row,
 )
+from distributed_ddpg_tpu.models.mlp import fold_norm
 from distributed_ddpg_tpu.parallel import mesh as mesh_lib
 from distributed_ddpg_tpu.types import (
     Batch,
@@ -1076,12 +1077,14 @@ class ShardedLearner:
         """Numpy actor params for broadcast to CPU rollout workers. The
         span matters: this d2h syncs the in-flight chunk, so the timeline
         shows it as the learner-thread gap before every param refresh /
-        eval snapshot."""
+        eval snapshot. A batch-normalised actor (CrossQ) leaves as the plain
+        MLP it is in evaluation mode (mlp.fold_norm): the workers' layout,
+        the evaluator and the serving engine never see the normalisation."""
         def fetch():
             with trace.span("params_d2h"):
-                return jax.tree.map(
+                return fold_norm(jax.tree.map(
                     np.asarray, jax.device_get(self.state.actor_params)
-                )
+                ))
 
         if self.transfer is None:
             return fetch()
@@ -1245,17 +1248,26 @@ def program_specs():
     ENSEMBLE = dict(
         sac=True, critic_ensemble=5, target_subset=2, policy_delay=3
     )
+    # CrossQ's chunk (no targets, batch-normalised nets, the joint critic
+    # pass, the same cond), as the benchmark's cell runs it: the partitioner
+    # shards it. (Under explicit shard_map every normalised layer stages two
+    # pmeans for its batch moments; tests/test_reference_crossq.py runs that.)
+    CROSSQ = dict(
+        sac=True, crossq=True, policy_delay=3, adam_b1=0.5,
+        action_insert_layer=0,
+    )
 
     def learner(
         guard: bool = False, sharded: bool = False, tp: bool = False,
-        ensemble: bool = False, mode: str = "auto",
+        ensemble: bool = False, mode: str = "auto", crossq: bool = False,
     ) -> ShardedLearner:
-        key = (guard, sharded, tp, ensemble, mode)
+        key = (guard, sharded, tp, ensemble, mode, crossq)
         if key not in cache:
             cache[key] = ShardedLearner(
                 probe_config(
                     guardrails=guard, model_axis=2 if tp else 1,
                     **(ENSEMBLE if ensemble else {}),
+                    **(CROSSQ if crossq else {}),
                 ),
                 obs_dim=3,
                 act_dim=1,
@@ -1392,4 +1404,10 @@ def program_specs():
             uniform(False, sharded=False, ensemble=True, mode="explicit"),
         ),
     ])
+    specs.append(
+        ProgramSpec(
+            "learner.chunk.uniform.crossq", OWNER,
+            uniform(False, sharded=False, crossq=True),
+        )
+    )
     return specs

@@ -81,6 +81,9 @@ class PartitionRuleError(ValueError):
 # as a regex over the layer index's last decimal digit. Final-layer
 # replication is depth-dependent and prepended by mlp_rules().
 DEFAULT_MLP_RULES: Tuple[Rule, ...] = (
+    # a batch-norm layer's four vectors (models/mlp.with_norm) replicate:
+    # they run over the layer's INPUT features, whatever its parity
+    (r"(^|/)\d+/bn_(scale|shift|mean|var)$", P(None)),
     # even layers: column-parallel (shard the output dim; bias shards too)
     (r"(^|/)\d*[02468]/w$", P(None, "model")),
     (r"(^|/)\d*[02468]/b$", P("model")),
@@ -187,8 +190,9 @@ def state_pspec(
     return TrainState(
         actor_params=actor,
         critic_params=critic,
-        target_actor_params=actor,
-        target_critic_params=critic,
+        # None (CrossQ has no targets) is an empty pytree node, as below.
+        target_actor_params=None if state.target_actor_params is None else actor,
+        target_critic_params=None if state.target_critic_params is None else critic,
         actor_opt=OptState(mu=actor, nu=actor, count=P()),
         critic_opt=OptState(mu=critic, nu=critic, count=P()),
         step=P(),

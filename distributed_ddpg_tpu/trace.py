@@ -457,7 +457,9 @@ CHUNK_SCOPES = (
     "noise",          # the launch's noise; REDQ's subsets
     "update",         # the K updates: the lax.scan, or the pallas_call
     "update/critic",  # critic loss, forward and backward
+    "update/critic/norm",  # its batch norm: moments, normalising, running step
     "update/actor",   # actor loss, forward and backward
+    "update/actor/norm",   # its own batch norm, and the critics' under it
     "update/optim",   # Adam
     "update/polyak",  # target updates
     "metrics",        # the chunk's metrics out of the K updates'

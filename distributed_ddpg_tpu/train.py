@@ -1363,6 +1363,9 @@ def _train_jax_impl(
             # many of them each update's target draws.
             facts["critic_ensemble"] = config.critic_ensemble
             facts["target_subset"] = config.target_subset
+        if config.crossq:
+            # CrossQ runs only, and from the state: no target net is held.
+            facts["crossq"] = learner.state.target_critic_params is None
         return facts
 
     log = MetricsLogger(config.log_path, header=run_facts())
@@ -1583,11 +1586,12 @@ def _train_jax_impl(
         return megastep.snapshot() if megastep is not None else {}
 
     def delay_fields() -> Dict[str, int]:
-        """`td3_actor_updates` (twin-critic runs) or `redq_policy_updates`
-        (ensemble runs, config.redq) beside `learner_steps` on every
+        """`td3_actor_updates` (twin-critic runs), `redq_policy_updates`
+        (ensemble runs, config.redq) or `crossq_policy_updates` (CrossQ runs
+        with a delay) beside `learner_steps` on every
         train/final record (docs/OBSERVABILITY.md): how many of the
         learner's updates moved the actor (with TD3's targets, or REDQ's
-        temperature), cumulative from step 0. Host arithmetic on the step
+        and CrossQ's temperature), cumulative from step 0. Host arithmetic on the step
         count the branch already carries (learner.delayed_updates, the rule
         the step's cond, the kernel's schedule and the actor's Adam count
         follow): no update pays for it. No other family's records have
@@ -1596,6 +1600,8 @@ def _train_jax_impl(
             key = "td3_actor_updates"
         elif config.redq:
             key = "redq_policy_updates"
+        elif config.crossq and config.policy_delay > 1:
+            key = "crossq_policy_updates"
         else:
             return {}
         return {key: delayed_updates(learn_steps, config.policy_delay)}

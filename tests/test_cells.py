@@ -37,6 +37,7 @@ RING_LAYOUT = {
     "sac-humanoid": "row_major",
     "td3-halfcheetah": "packed",
     "redq-humanoid": "row_major",
+    "crossq-humanoid": "row_major",
 }
 
 

@@ -68,9 +68,9 @@ def test_live_tree_clean_with_committed_goldens(tmp_path):
     # can't red it — the report's own wall-clock `elapsed_s` read 29 s
     # alone and over 45 s beside five other test workers. 45 s since the
     # six .tp program variants (PR 15, docs/MESH.md) grew the registry
-    # 26 -> 32.
+    # 26 -> 32; 65 s at 49 programs (PR 38 read 48 s beside five workers).
     assert rep["elapsed_s"] > 0
-    assert cpu_s < 45.0, (cpu_s, rep["elapsed_s"])
+    assert cpu_s < 65.0, (cpu_s, rep["elapsed_s"])
 
 
 def test_every_spec_module_is_watched_by_changed_only():

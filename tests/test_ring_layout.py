@@ -521,3 +521,61 @@ def test_v5e_redq_chunk_draws_before_the_loop_and_holds_the_policy_under_a_condi
     assert [line for line in text.splitlines() if drawn.search(line)]
     conditionals = [line for name in inside for line in comps[name] if re.search(r"\bconditional\(", line)]
     assert len(conditionals) == 4  # one an update, four unrolled updates a trip
+
+
+def test_v5e_crossq_chunk_holds_no_target_update_and_the_policy_under_a_conditional(v5e_sharding):
+    """`crossq-humanoid`'s scan chunk at the configuration's own sizes (twin
+    2x2048 critics on the joint 512-row batch, actor 2x256, obs 376, act 17,
+    K 800, unroll 4), compiled for the described v5e: the chip's compiler
+    takes it; 10.2 M parameters with both Adam moments are 123 MB of state
+    and no target doubles them; no instruction was traced under `polyak`;
+    the batch norm's instructions read `update/critic/norm` and
+    `update/actor/norm`; the noise is drawn in front of the loop; and the
+    policy's half is a conditional in the body."""
+    import json
+    import os
+
+    from distributed_ddpg_tpu import learner as learner_lib
+    from distributed_ddpg_tpu import trace
+    from distributed_ddpg_tpu.config import DDPGConfig
+    from distributed_ddpg_tpu.parallel.learner import scan_chunk
+    from distributed_ddpg_tpu.types import unpack_batch
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    conf = json.load(open(os.path.join(root, "benchmarks", "configs", "crossq-humanoid.json")))
+    cfg = DDPGConfig.from_flags([f for f in conf["flags"] if not f.startswith("--replay_capacity")])
+    env, chunk = conf["env"], 800
+    obs, act = env["obs_dim"], env["act_dim"]
+    assert (cfg.crossq, cfg.batch_size, cfg.critic_hidden, cfg.policy_delay, cfg.adam_b1) == (
+        True, 256, (2048, 2048), 3, 0.5)
+    step = learner_lib.make_learner_step(cfg, env["action_scale"], action_offset=env["action_offset"])
+
+    def run(s, packed):
+        noise = learner_lib.chunk_noise(cfg, s.step, chunk, cfg.batch_size, act)
+        return scan_chunk(step, s, unpack_batch(packed, obs, act), noise, unroll=4)
+
+    replicated = NamedSharding(v5e_sharding.mesh, P())
+    state = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=replicated),
+        jax.eval_shape(lambda: learner_lib.init_train_state(cfg, obs, act, 0)),
+    )
+    assert state.target_critic_params is None and state.critic_params[1]["w"].shape == (2, 2048, 2048)
+    packed = jax.ShapeDtypeStruct((chunk, cfg.batch_size, 2 * obs + act + 3), jnp.float32, sharding=replicated)
+    compiled = jax.jit(run, donate_argnums=(0,)).lower(state, packed).compile()
+    # the donated state comes back in place: parameters and both moments, three
+    # copies of 10.2 M values and no fourth
+    assert 120e6 < compiled.memory_analysis().alias_size_in_bytes < 126e6
+    text = compiled.as_text()
+
+    scopes = set(trace.chunk_ops_table(text)["ops"].values())
+    assert {"update/critic/norm", "update/actor/norm", "update/optim"} <= scopes
+    assert "update/polyak" not in scopes and "polyak" not in text
+    comps = _computations(text)
+    whiles = [line for lines in comps.values() for line in lines if re.search(r"\bwhile\(", line)]
+    body = re.search(r"body=%?([\w.\-]+)", max(whiles, key=lambda w: len(comps[re.search(r"body=%?([\w.\-]+)", w).group(1)]))).group(1)
+    inside = [body, *_called_from(comps, body)]
+    drawn = re.compile(r'op_name="[^"]*(threefry|_normal)')
+    assert not [line for name in inside for line in comps[name] if drawn.search(line)]
+    assert [line for line in text.splitlines() if drawn.search(line)]
+    conditionals = [line for name in inside for line in comps[name] if re.search(r"\bconditional\(", line)]
+    assert len(conditionals) == 4  # one an update, four unrolled updates a trip
