@@ -52,6 +52,7 @@ The static half (jit-key hazards) lives in progrules.py instead.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import fnmatch
 import hashlib
@@ -116,7 +117,8 @@ class ProgramSpec:
 class ProgramFinding:
     program: str
     check: str    # donation-aliasing | collective-order | beat-group |
-                  # host-callback | build-error | stale-golden
+                  # host-callback | build-error | stale-golden |
+                  # seed-constant (progrules.seed_constant_findings)
     message: str
 
     def to_json(self) -> Dict[str, object]:
@@ -175,6 +177,22 @@ def probe_mesh(model_axis: int = 1):
     )
 
 
+_probe_seed = 0
+
+
+@contextlib.contextmanager
+def probe_seed(seed: int):
+    """Every probe_config() built inside takes `seed`: how the
+    seed-constant rule (progrules.seed_constant_findings) builds the
+    registry a second time."""
+    global _probe_seed
+    prev, _probe_seed = _probe_seed, int(seed)
+    try:
+        yield
+    finally:
+        _probe_seed = prev
+
+
 def probe_config(**overrides):
     """Tiny-but-real DDPGConfig for spec builds: every dimension shrunk
     so tracing is milliseconds, nothing else changed — the program
@@ -187,7 +205,7 @@ def probe_config(**overrides):
         actor_hidden=(16, 16),
         critic_hidden=(16, 16),
         replay_capacity=64,
-        seed=0,
+        seed=_probe_seed,
     )
     base.update(overrides)
     return DDPGConfig(**base)

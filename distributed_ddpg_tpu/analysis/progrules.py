@@ -35,12 +35,22 @@ idle 40% of the time":
 Registered into the same registry as rules.py, so `tools.lint`, the
 suppression grammar, and `--rules recompile-hazard` all apply; the
 proganalyze CLI runs it alongside the traced checks.
+
+A fifth shape of the same hazard cannot be seen in the source, so it is
+no lint rule and `seed_constant_findings` below lowers the registered
+programs to find it (importing jax only when called; the CLI runs it
+beside the static rule): a value derived from `config.seed` traced into
+a hot program as a CONSTANT. The persistent compile cache keys on the
+program's text, so such a program compiles anew for every seed of one
+configuration — 10 to 41 s of every run of a sweep, where a seed that
+reaches the program as an argument (the sampling key, the noise
+stream's base key: parallel/learner.py) costs a load from the cache.
 """
 
 from __future__ import annotations
 
 import ast
-from typing import Dict, Iterable, List, Optional, Set, Tuple
+from typing import Dict, Iterable, List, Optional, Sequence, Set, Tuple
 
 from distributed_ddpg_tpu.analysis.engine import (
     Finding,
@@ -362,3 +372,59 @@ class RecompileHazard(Rule):
                     "(dispatch raises TypeError the first time this path "
                     "runs); pass a tuple / frozen value instead",
                 )
+
+
+# -- shape 5: a seed in a hot program's text (lowered, not static) ------
+
+
+def seed_constant_findings(make_specs=None, seeds: Tuple[int, int] = (0, 1),
+                           only: Optional[Sequence[str]] = None) -> List:
+    """`seed-constant` findings (analysis.programs.ProgramFinding) over a
+    program registry: `make_specs()` (default: the live default_specs())
+    is built once under each of `seeds` (programs.probe_seed: every
+    probe_config takes it) and each program is lowered, never compiled;
+    a program whose two texts differ holds a constant derived from the
+    seed. `only` filters by program name (exact or fnmatch glob)."""
+    import fnmatch
+
+    from distributed_ddpg_tpu.analysis import programs as prog_lib
+
+    make_specs = make_specs or prog_lib.default_specs
+    texts: List[Dict[str, str]] = []
+    for seed in seeds:
+        by_name: Dict[str, str] = {}
+        with prog_lib.probe_seed(seed):
+            for spec in make_specs():
+                if only is not None and not any(
+                    fnmatch.fnmatch(spec.name, pat) for pat in only
+                ):
+                    continue
+                try:
+                    built = spec.build()
+                    by_name[spec.name] = built.fn.lower(*built.args).as_text()
+                except Exception:
+                    # analyze() gates on a spec that cannot build; there
+                    # is no text of it to compare.
+                    continue
+        texts.append(by_name)
+    first, second = texts
+    findings = []
+    for name, text in first.items():
+        other = second.get(name)
+        if other is None or other == text:
+            continue
+        a, b = text.splitlines(), other.splitlines()
+        where = next(
+            (x.strip() for x, y in zip(a, b) if x != y),
+            f"{len(a)} lines against {len(b)}",
+        )
+        findings.append(prog_lib.ProgramFinding(
+            name, "seed-constant",
+            f"the program's lowered text differs between seed {seeds[0]} "
+            f"and seed {seeds[1]} (first at: {where:.200}) — a value "
+            "derived from config.seed is a constant of the program, so "
+            "the persistent compile cache misses for every new seed; "
+            "build the value on the host and hand it to the program as "
+            "an argument (ShardedLearner._noise_key is the pattern)",
+        ))
+    return findings

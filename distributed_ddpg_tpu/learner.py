@@ -123,10 +123,14 @@ def _maybe_psum_mean(tree, axis_name: Optional[str]):
 
 # --- the learner's noise streams ---
 # TD3's target-smoothing noise and SAC's sampling noise are keyed by
-# fold_in(seed-derived base, state.step): no key threads through the step
-# signature, the stream is deterministic/replayable, and every data-parallel
-# replica derives the identical key from the replicated state.step, so
-# replicas cannot fork. One definition of each stream: a single step draws
+# fold_in(seed-derived base, state.step): the stream is deterministic and
+# replayable, and every data-parallel replica derives the identical key from
+# the replicated state.step, so replicas cannot fork. The base
+# (`noise_base_key`) never changes during a run and is no part of the
+# TrainState; a chunk program takes it as an ARGUMENT (ShardedLearner holds
+# it replicated on the mesh beside its sampling key), so the program's text
+# holds nothing derived from config.seed and one compiled chunk serves every
+# seed of a configuration. One definition of each stream: a single step draws
 # its own (`step_noise`), and every chunk program, on the scan leg and the
 # kernel leg alike, pre-draws its K steps' worth in front of its loop
 # (`chunk_noise`) — the same bits, because the draw does not depend on the
@@ -152,12 +156,15 @@ def draws_noise(config: DDPGConfig) -> bool:
 
 
 def noise_base_key(config: DDPGConfig):
-    """The base key of `config`'s one noise stream (None: it has none)."""
-    if config.sac:
-        return jax.random.PRNGKey(config.seed ^ 0x5AC0)
-    if config.twin_critic:
-        return jax.random.PRNGKey(config.seed ^ 0x7D3AF)
-    return None
+    """The base key of `config`'s one noise stream (None, an empty pytree:
+    it has none). The only value the learner derives from config.seed
+    besides its initial state and its sampling key, and like them handed to
+    the chunk programs, not traced into them."""
+    if not draws_noise(config):
+        return None
+    return jax.random.PRNGKey(
+        config.seed ^ (0x5AC0 if config.sac else 0x7D3AF)
+    )
 
 
 def step_noise(config: DDPGConfig, base, step, batch: int, act_dim: int,
@@ -210,13 +217,14 @@ def noise_per_row(config: DDPGConfig):
     return (True, True, False) if draws_subset(config) else (True, True)
 
 
-def chunk_noise(config: DDPGConfig, step0, chunk: int, batch: int,
+def chunk_noise(config: DDPGConfig, base, step0, chunk: int, batch: int,
                 act_dim: int, device_fold=None):
     """step_noise for the K steps from `step0`, stacked [K, ...]: drawn once
-    a launch, in front of the loop that scans over it."""
+    a launch, in front of the loop that scans over it. `base` is the
+    stream's base key (noise_base_key), a traced argument of the chunk
+    program; None where the algorithm draws none."""
     if not draws_noise(config):
         return None
-    base = noise_base_key(config)
     with device_scope("noise"):
         return jax.vmap(
             lambda s: step_noise(config, base, s, batch, act_dim, device_fold)
@@ -333,7 +341,10 @@ def make_learner_step(
         if config.distributional
         else None
     )
-    # Made here, so that a step that draws for itself holds it as a constant.
+    # Only a step handed no noise draws for itself, and holds the base key
+    # as a constant of its program: the single-step programs (agent.py,
+    # ondevice.py, ShardedLearner.step). Every chunk program pre-draws from
+    # the base it takes as an argument (chunk_noise) and passes `noise`.
     base_key = noise_base_key(config)
 
     def own_noise(state: TrainState, batch: Batch):

@@ -79,7 +79,12 @@ from jax.experimental.pallas import tpu as pltpu
 import math
 
 from distributed_ddpg_tpu.config import DDPGConfig
-from distributed_ddpg_tpu.learner import chunk_noise, delayed_updates, metric_keys
+from distributed_ddpg_tpu.learner import (
+    chunk_noise,
+    delayed_updates,
+    metric_keys,
+    noise_base_key,
+)
 from distributed_ddpg_tpu.ops.optim import B1, B2, EPS
 from distributed_ddpg_tpu.trace import device_scope
 from distributed_ddpg_tpu.types import TrainState, OptState
@@ -1065,9 +1070,14 @@ def make_fused_chunk_fn(
             # pre-scaled and pre-clipped; SAC: the (eps_next, eps_cur)
             # standard normals), from the stream the scan leg's chunks
             # pre-draw from too (learner.chunk_noise); it streams into the
-            # kernel like the minibatches (~KB per step). Callers with a
-            # device axis (fused-mesh) pass their own axis-folded eps instead.
-            eps = chunk_noise(config, state.step, K, B, a)
+            # kernel like the minibatches (~KB per step). Only a caller
+            # outside the learner (a test, tools/kernel_bundles.py) leaves
+            # the draw to this line and gets the base key as a constant of
+            # its program: ShardedLearner passes eps drawn from the key its
+            # chunk program takes as an argument (fused-mesh: axis-folded).
+            eps = chunk_noise(
+                config, noise_base_key(config), state.step, K, B, a
+            )
         elif not (has_noise or sac):
             eps = None
 

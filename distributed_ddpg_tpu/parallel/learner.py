@@ -41,6 +41,7 @@ from distributed_ddpg_tpu.learner import (
     init_train_state,
     make_learner_step,
     metric_keys,
+    noise_base_key,
     noise_per_row,
 )
 from distributed_ddpg_tpu.models.mlp import fold_norm
@@ -88,6 +89,26 @@ def _shape_of(x):
     return jax.ShapeDtypeStruct(
         x.shape, x.dtype, sharding=x.format, weak_type=x.weak_type
     )
+
+
+class _ChunkProgram:
+    """A jitted chunk program as the learner holds it. Called, lowered or
+    traced with a launch's arguments, it hands the program one more, its
+    last: the base key of the noise stream (ShardedLearner._noise_key). The
+    key is an argument and not a constant of the program, so the text the
+    compile cache keys on is the same for every seed."""
+
+    def __init__(self, program, noise_key):
+        self.program, self.noise_key = program, noise_key
+
+    def __call__(self, *args):
+        return self.program(*args, self.noise_key)
+
+    def lower(self, *args):
+        return self.program.lower(*args, _shape_of(self.noise_key))
+
+    def trace(self, *args):
+        return self.program.trace(*args, self.noise_key)
 
 
 def scan_chunk(step, s: TrainState, batches: Batch, noise, unroll: int):
@@ -207,6 +228,14 @@ class ShardedLearner:
         # d2h class — absolute priority (no queueing on the hot path) but
         # full bytes/latency accounting in the transfer_* family.
         self.transfer = None
+        # The base key of the learner's noise stream (learner.noise_base_key;
+        # None, an empty pytree, where the algorithm draws none): every chunk
+        # program's LAST argument (_ChunkProgram), so no program's text holds
+        # a value derived from config.seed and the compile cache serves
+        # every seed of a configuration.
+        self._noise_key = jax.device_put(
+            noise_base_key(config), NamedSharding(self.mesh, P())
+        )
         self._build_programs()
         self._key = jax.device_put(
             jax.random.PRNGKey(config.seed),
@@ -281,6 +310,11 @@ class ShardedLearner:
         replicated = NamedSharding(self.mesh, P())
         td_sharding = NamedSharding(self.mesh, P("data"))
 
+        def chunk_program(fn, **jit_args):
+            # Every chunk body below ends in `nkey`, the noise stream's base
+            # key, which the learner binds here and passes at each launch.
+            return _ChunkProgram(jax.jit(fn, **jit_args), self._noise_key)
+
         def packed_step(s: TrainState, packed):
             return step(s, unpack_batch(packed, obs_dim, act_dim))
 
@@ -300,8 +334,9 @@ class ShardedLearner:
         # scanned over beside the batches, so no threefry runs inside the
         # K-update loop; None (an empty pytree: the scan's operands are
         # the batches alone) where the algorithm draws none. Inside the
-        # chunk's own jitted program: the launch pays for its draw.
-        def draw_chunk_noise(s: TrainState, batches: Batch):
+        # chunk's own jitted program: the launch pays for its draw. `nkey`
+        # is the stream's base key, an argument of every chunk program.
+        def draw_chunk_noise(s: TrainState, batches: Batch, nkey):
             if not draws_noise(config):
                 return None
             K, B, _ = batches.action.shape
@@ -313,37 +348,40 @@ class ShardedLearner:
                     lambda x, rows: jax.lax.with_sharding_constraint(
                         x, self._chunk_sharding if rows else replicated
                     ),
-                    chunk_noise(config, s.step, K, B, act_dim),
+                    chunk_noise(config, nkey, s.step, K, B, act_dim),
                     noise_per_row(config),
                 )
             # Explicit mode folds the shard's index into the key, as the
             # step under shard_map does when it draws for itself.
             return mesh_lib.shard_map(
-                lambda step0: chunk_noise(
-                    config, step0, K, B // self.data_size, act_dim,
+                lambda base, step0: chunk_noise(
+                    config, base, step0, K, B // self.data_size, act_dim,
                     device_fold=jax.lax.axis_index("data"),
                 ),
-                mesh=self.mesh, in_specs=P(),
+                mesh=self.mesh, in_specs=(P(), P()),
                 out_specs=jax.tree.map(
                     lambda rows: P(None, "data", None) if rows else P(),
                     noise_per_row(config),
                 ),
-            )(s.step)
+            )(nkey, s.step)
 
         # The shared chunk body of the host-fed and the fused-sampling paths.
-        def scan_steps(s: TrainState, batches: Batch) -> StepOutput:
+        def scan_steps(s: TrainState, batches: Batch, nkey) -> StepOutput:
             return scan_chunk(
-                step, s, batches, draw_chunk_noise(s, batches), self.unroll
+                step, s, batches, draw_chunk_noise(s, batches, nkey),
+                self.unroll,
             )
 
         # K-steps-per-dispatch scan over host-fed packed batches.
-        def chunk_fn(s: TrainState, packed):
-            return scan_steps(s, unpack_batch(packed, obs_dim, act_dim))
+        def chunk_fn(s: TrainState, packed, nkey):
+            return scan_steps(s, unpack_batch(packed, obs_dim, act_dim), nkey)
 
         td_chunk_sharding = NamedSharding(self.mesh, P(None, "data"))
-        self._chunk_step = jax.jit(
+        self._chunk_step = chunk_program(
             chunk_fn,
-            in_shardings=(self._state_sharding, self._chunk_sharding),
+            in_shardings=(
+                self._state_sharding, self._chunk_sharding, replicated,
+            ),
             out_shardings=StepOutput(
                 state=self._state_sharding,
                 td_errors=td_chunk_sharding,
@@ -408,12 +446,13 @@ class ShardedLearner:
             key, idx = draw_chunk_idx(key, size)
             return key, gather_rows(storage, idx)
 
-        def sample_chunk_fn(s: TrainState, key, storage, size):
+        def sample_chunk_fn(s: TrainState, key, storage, size, nkey):
             key, packed = draw_chunk(key, storage, size)
             packed = jax.lax.with_sharding_constraint(
                 packed, NamedSharding(self.mesh, P(None, "data", None))
             )
-            return scan_steps(s, unpack_batch(packed, obs_dim, act_dim)), key
+            batches = unpack_batch(packed, obs_dim, act_dim)
+            return scan_steps(s, batches, nkey), key
 
         # Pallas megakernel path (ops/fused_chunk.py): the whole chunk in one
         # kernel, params VMEM-resident.
@@ -484,11 +523,17 @@ class ShardedLearner:
                 config, obs_dim, act_dim, action_scale, action_offset,
                 chunk_size=self.chunk_size,
             )
-            fused_run = run_fused
 
-            def fused_sample_chunk_fn(s: TrainState, key, storage, size):
+            def fused_run(s: TrainState, packed, nkey):
+                # The kernel's noise streams in pre-drawn, from the base key
+                # the program takes as an argument (None where it draws none).
+                K, B, _ = packed.shape
+                eps = chunk_noise(config, nkey, s.step, K, B, act_dim)
+                return run_fused(s, packed, eps=eps)
+
+            def fused_sample_chunk_fn(s: TrainState, key, storage, size, nkey):
                 key, packed = draw_chunk(key, storage, size)
-                new_s, tds, ms = run_fused(s, packed)
+                new_s, tds, ms = fused_run(s, packed, nkey)
                 return StepOutput(state=new_s, td_errors=tds, metrics=ms), key
 
             sample_chunk_fn = fused_sample_chunk_fn
@@ -548,7 +593,7 @@ class ShardedLearner:
             )(priorities, idx_flat, vals_flat)
 
         def per_sample_chunk_fn(s, key, storage, size, priorities, maxp,
-                                beta, alpha, eps):
+                                beta, alpha, eps, nkey):
             key, sub = jax.random.split(key)
             idx, weights = per_draw(
                 sub, priorities, size, (self.chunk_size, batch_size), beta
@@ -563,7 +608,7 @@ class ShardedLearner:
             batches = unpack_batch(packed, obs_dim, act_dim)._replace(
                 weight=weights
             )
-            out = scan_steps(s, batches)
+            out = scan_steps(s, batches, nkey)
             priorities, maxp = write_back(
                 priorities, maxp, idx, out.td_errors, alpha, eps
             )
@@ -578,12 +623,12 @@ class ShardedLearner:
         )
 
         def _jit_per_chunk(fn):
-            return jax.jit(
+            return chunk_program(
                 fn,
                 in_shardings=(
                     self._state_sharding, replicated, storage_sharding,
                     replicated, prio_sharding, replicated, replicated,
-                    replicated, replicated,
+                    replicated, replicated, replicated,
                 ),
                 out_shardings=(
                     StepOutput(
@@ -611,7 +656,7 @@ class ShardedLearner:
             # shapes), so the two paths are bit-comparable and the fused
             # path inherits the same priority semantics.
             def fused_per_sample_chunk_fn(s, key, storage, size, priorities,
-                                          maxp, beta, alpha, eps):
+                                          maxp, beta, alpha, eps, nkey):
                 key, sub = jax.random.split(key)
                 idx, weights = per_draw(
                     sub, priorities, size, (self.chunk_size, batch_size), beta
@@ -619,7 +664,7 @@ class ShardedLearner:
                 packed = gather_rows(storage, idx)
                 with trace.device_scope("cut"):
                     packed = packed.at[..., -1].set(weights)
-                new_s, tds, ms = fused_run(s, packed)
+                new_s, tds, ms = fused_run(s, packed, nkey)
                 out = StepOutput(state=new_s, td_errors=tds, metrics=ms)
                 priorities, maxp = write_back(
                     priorities, maxp, idx, tds, alpha, eps
@@ -633,10 +678,11 @@ class ShardedLearner:
             self._per_sample_chunk_step = _jit_per_chunk(per_sample_chunk_fn)
 
         def _jit_sample_chunk(fn):
-            return jax.jit(
+            return chunk_program(
                 fn,
                 in_shardings=(
-                    self._state_sharding, replicated, storage_sharding, replicated
+                    self._state_sharding, replicated, storage_sharding,
+                    replicated, replicated,
                 ),
                 out_shardings=(
                     StepOutput(
@@ -670,14 +716,14 @@ class ShardedLearner:
                 inject=self._numeric_inject,
             )
 
-            def guarded_scan(s, g, batches, pre_bad):
+            def guarded_scan(s, g, batches, pre_bad, nkey):
                 # A dropped update still advances state.step, so the
                 # pre-drawn noise stays aligned with the steps.
                 def body(carry, x):
                     ns, ng, td, ms = gstep(*carry, *x)
                     return (ns, ng), (td, ms)
 
-                noise = draw_chunk_noise(s, batches)
+                noise = draw_chunk_noise(s, batches, nkey)
                 with trace.device_scope("update"):
                     (s, g), (tds, ms) = jax.lax.scan(
                         body, (s, g), (batches, pre_bad, noise),
@@ -689,7 +735,7 @@ class ShardedLearner:
                     metrics=chunk_metrics(ms),
                 ), g
 
-            def guard_chunk_fn(s: TrainState, packed, g):
+            def guard_chunk_fn(s: TrainState, packed, g, nkey):
                 # Host-fed path: the sampler owns replay indices, so the
                 # row screen reports counts only (bad_idx rides as -1s).
                 pre_bad, bad_count, _ = guard_lib.batch_row_health(
@@ -697,14 +743,16 @@ class ShardedLearner:
                 )
                 g = g._replace(bad_rows=g.bad_rows + bad_count)
                 out, g = guarded_scan(
-                    s, g, unpack_batch(packed, obs_dim, act_dim), pre_bad
+                    s, g, unpack_batch(packed, obs_dim, act_dim), pre_bad,
+                    nkey,
                 )
                 return out, g, guard_lib.health_vector(g)
 
-            self._chunk_step = jax.jit(
+            self._chunk_step = chunk_program(
                 guard_chunk_fn,
                 in_shardings=(
                     self._state_sharding, self._chunk_sharding, replicated,
+                    replicated,
                 ),
                 out_shardings=(
                     StepOutput(
@@ -718,7 +766,8 @@ class ShardedLearner:
                 donate_argnums=(0, 2),
             )
 
-            def guard_sample_chunk_fn(s: TrainState, key, storage, size, g):
+            def guard_sample_chunk_fn(s: TrainState, key, storage, size, g,
+                                      nkey):
                 key, idx = draw_chunk_idx(key, size)
                 packed = gather_rows(storage, idx)
                 packed = jax.lax.with_sharding_constraint(
@@ -729,7 +778,8 @@ class ShardedLearner:
                 )
                 g = g._replace(bad_rows=g.bad_rows + bad_count)
                 out, g = guarded_scan(
-                    s, g, unpack_batch(packed, obs_dim, act_dim), pre_bad
+                    s, g, unpack_batch(packed, obs_dim, act_dim), pre_bad,
+                    nkey,
                 )
                 return out, key, g, guard_lib.health_vector(g), bad_idx
 
@@ -744,18 +794,18 @@ class ShardedLearner:
                 replicated,  # health word
                 replicated,  # bad replay indices
             )
-            self._sample_chunk_step = jax.jit(
+            self._sample_chunk_step = chunk_program(
                 guard_sample_chunk_fn,
                 in_shardings=(
                     self._state_sharding, replicated, storage_sharding,
-                    replicated, replicated,
+                    replicated, replicated, replicated,
                 ),
                 out_shardings=guard_out,
                 donate_argnums=(0, 1, 4),
             )
 
             def guard_per_sample_chunk_fn(s, key, storage, size, priorities,
-                                          maxp, beta, alpha, eps, g):
+                                          maxp, beta, alpha, eps, g, nkey):
                 key, sub = jax.random.split(key)
                 idx, weights = per_draw(
                     sub, priorities, size, (self.chunk_size, batch_size),
@@ -775,7 +825,7 @@ class ShardedLearner:
                 batches = unpack_batch(packed, obs_dim, act_dim)._replace(
                     weight=weights
                 )
-                out, g = guarded_scan(s, g, batches, pre_bad)
+                out, g = guarded_scan(s, g, batches, pre_bad, nkey)
                 # A bad step's td errors are zeroed by the probe, so its
                 # sampled rows re-stamp at the (eps)^alpha floor instead
                 # of inheriting NaN priorities that would poison every
@@ -788,12 +838,12 @@ class ShardedLearner:
                     guard_lib.health_vector(g), bad_idx,
                 )
 
-            self._per_sample_chunk_step = jax.jit(
+            self._per_sample_chunk_step = chunk_program(
                 guard_per_sample_chunk_fn,
                 in_shardings=(
                     self._state_sharding, replicated, storage_sharding,
                     replicated, prio_sharding, replicated, replicated,
-                    replicated, replicated, replicated,
+                    replicated, replicated, replicated, replicated,
                 ),
                 out_shardings=(
                     StepOutput(
@@ -858,7 +908,7 @@ class ShardedLearner:
         state_spec = mesh_lib.state_pspec(self.state, mesh)
         keys = metric_keys(self.config)
 
-        def local_chunk(s, sub, storage, size):
+        def local_chunk(s, sub, storage, size, nkey):
             axis_idx = jax.lax.axis_index("data")
             with trace.device_scope("draw"):
                 dkey = jax.random.fold_in(sub, axis_idx)
@@ -869,7 +919,7 @@ class ShardedLearner:
             # device index folded on top, as the step under shard_map
             # folds it (learner.chunk_noise).
             eps = chunk_noise(
-                self.config, s.step, K, b_local, self.act_dim,
+                self.config, nkey, s.step, K, b_local, self.act_dim,
                 device_fold=axis_idx,
             )
             with trace.device_scope("gather"):
@@ -911,7 +961,7 @@ class ShardedLearner:
         sharded = mesh_lib.shard_map(
             local_chunk,
             mesh=mesh,
-            in_specs=(state_spec, P(), P(None, None), P()),
+            in_specs=(state_spec, P(), P(None, None), P(), P()),
             out_specs=(
                 state_spec,
                 P(None, "data"),
@@ -919,9 +969,10 @@ class ShardedLearner:
             ),
         )
 
-        def fused_mesh_sample_chunk_fn(s: TrainState, key, storage, size):
+        def fused_mesh_sample_chunk_fn(s: TrainState, key, storage, size,
+                                       nkey):
             key, sub = jax.random.split(key)
-            new_s, tds, ms = sharded(s, sub, storage, size)
+            new_s, tds, ms = sharded(s, sub, storage, size, nkey)
             return StepOutput(state=new_s, td_errors=tds, metrics=ms), key
 
         return fused_mesh_sample_chunk_fn
@@ -1048,9 +1099,10 @@ class ShardedLearner:
 
     def pure_scan_sample_fn(self, per: bool):
         """The pure scan-path sampling-chunk body matching this learner's
-        guard mode — uniform: (state, key, storage, size[, guard]);
+        guard mode — uniform: (state, key, storage, size[, guard], nkey);
         PER: (state, key, storage, size, priorities, maxp, beta, alpha,
-        eps[, guard]). The fused megastep composes it with the rollout and
+        eps[, guard], nkey), `nkey` the noise stream's base key
+        (self._noise_key). The fused megastep composes it with the rollout and
         ring insert into one beat program; using the identical body is
         what makes fused-vs-separate dispatch bit-identity hold."""
         key = ("per" if per else "uniform") + (

@@ -112,25 +112,28 @@ def build_beat_body(learner, pool, replay, per: bool, guard: bool,
     # the current ring, roll out with the updated params, scatter.
     # `ptr` is threaded through untouched by the learner leg; PER
     # stamps from the PRE-insert pointer (the insert_device_rows
-    # ordering).
+    # ordering). `nkey`, every variant's last argument, is the base key
+    # of the learner's noise stream (ShardedLearner._noise_key; None, an
+    # empty pytree, where the algorithm draws none), handed on to the
+    # chunk body: a beat's text holds nothing derived from config.seed.
     if not per and not guard:
 
-        def beat(state, key, storage, ptr, size, carry):
-            out, key = sample_fn(state, key, storage, size)
+        def beat(state, key, storage, ptr, size, carry, nkey):
+            out, key = sample_fn(state, key, storage, size, nkey)
             carry, rows = rollout_fn(out.state.actor_params, carry)
             storage, ptr, size = insert_fn(storage, rows, ptr, size)
             return out, key, storage, ptr, size, carry
 
         in_sh = (L._state_sharding, replicated, storage_sharding,
-                 replicated, replicated, carry_sharding)
+                 replicated, replicated, carry_sharding, replicated)
         out_sh = (out_step, replicated, storage_sharding,
                   replicated, replicated, carry_sharding)
         donate = (0, 1, 2, 3, 4, 5)
     elif not per and guard:
 
-        def beat(state, key, storage, ptr, size, carry, g):
+        def beat(state, key, storage, ptr, size, carry, g, nkey):
             out, key, g, health, bad_idx = sample_fn(
-                state, key, storage, size, g
+                state, key, storage, size, g, nkey
             )
             carry, rows = rollout_fn(out.state.actor_params, carry)
             storage, ptr, size = insert_fn(storage, rows, ptr, size)
@@ -138,7 +141,8 @@ def build_beat_body(learner, pool, replay, per: bool, guard: bool,
                     bad_idx)
 
         in_sh = (L._state_sharding, replicated, storage_sharding,
-                 replicated, replicated, carry_sharding, replicated)
+                 replicated, replicated, carry_sharding, replicated,
+                 replicated)
         out_sh = (out_step, replicated, storage_sharding, replicated,
                   replicated, carry_sharding, replicated, replicated,
                   replicated)
@@ -146,10 +150,10 @@ def build_beat_body(learner, pool, replay, per: bool, guard: bool,
     elif per and not guard:
 
         def beat(state, key, storage, ptr, size, carry, priorities,
-                 maxp, beta, alpha, eps):
+                 maxp, beta, alpha, eps, nkey):
             out, key, priorities, maxp = sample_fn(
                 state, key, storage, size, priorities, maxp, beta,
-                alpha, eps,
+                alpha, eps, nkey,
             )
             carry, rows = rollout_fn(out.state.actor_params, carry)
             old_ptr = ptr
@@ -160,7 +164,8 @@ def build_beat_body(learner, pool, replay, per: bool, guard: bool,
 
         in_sh = (L._state_sharding, replicated, storage_sharding,
                  replicated, replicated, carry_sharding, prio_sharding,
-                 replicated, replicated, replicated, replicated)
+                 replicated, replicated, replicated, replicated,
+                 replicated)
         out_sh = (out_step, replicated, storage_sharding, replicated,
                   replicated, carry_sharding, prio_sharding,
                   replicated)
@@ -168,10 +173,10 @@ def build_beat_body(learner, pool, replay, per: bool, guard: bool,
     else:
 
         def beat(state, key, storage, ptr, size, carry, priorities,
-                 maxp, beta, alpha, eps, g):
+                 maxp, beta, alpha, eps, g, nkey):
             out, key, priorities, maxp, g, health, bad_idx = sample_fn(
                 state, key, storage, size, priorities, maxp, beta,
-                alpha, eps, g,
+                alpha, eps, g, nkey,
             )
             carry, rows = rollout_fn(out.state.actor_params, carry)
             old_ptr = ptr
@@ -183,7 +188,7 @@ def build_beat_body(learner, pool, replay, per: bool, guard: bool,
         in_sh = (L._state_sharding, replicated, storage_sharding,
                  replicated, replicated, carry_sharding, prio_sharding,
                  replicated, replicated, replicated, replicated,
-                 replicated)
+                 replicated, replicated)
         out_sh = (out_step, replicated, storage_sharding, replicated,
                   replicated, carry_sharding, prio_sharding,
                   replicated, replicated, replicated, replicated)
@@ -258,6 +263,7 @@ class FusedMegastep:
                             L.state, L._key, replay.storage, replay.ptr,
                             replay.size, pool._carry, replay.priorities,
                             replay.max_priority, *scalars, L._guard,
+                            L._noise_key,
                         )
                         L.note_fused_health(g, health, bad_idx)
                     else:
@@ -265,7 +271,7 @@ class FusedMegastep:
                          maxp) = self._beat(
                             L.state, L._key, replay.storage, replay.ptr,
                             replay.size, pool._carry, replay.priorities,
-                            replay.max_priority, *scalars,
+                            replay.max_priority, *scalars, L._noise_key,
                         )
                     replay.set_per_state(prios, maxp)
                 else:
@@ -274,12 +280,13 @@ class FusedMegastep:
                          bad_idx) = self._beat(
                             L.state, L._key, replay.storage, replay.ptr,
                             replay.size, pool._carry, L._guard,
+                            L._noise_key,
                         )
                         L.note_fused_health(g, health, bad_idx)
                     else:
                         out, key, storage, ptr, size, carry = self._beat(
                             L.state, L._key, replay.storage, replay.ptr,
-                            replay.size, pool._carry,
+                            replay.size, pool._carry, L._noise_key,
                         )
                 L.state = out.state
                 L._key = key
@@ -309,6 +316,7 @@ class FusedMegastep:
                      np.float32(replay.eps)]
         if self.guard:
             args.append(L._guard)
+        args.append(L._noise_key)
         return tuple(args)
 
 

@@ -152,15 +152,17 @@ class FusedSuperstep:
 
         if not self.per and not self.guard:
 
-            def superstep(state, key, storage, ptr, size, carry):
+            def superstep(state, key, storage, ptr, size, carry, nkey):
                 shapes = jax.eval_shape(
-                    beat, state, key, storage, ptr, size, carry
+                    beat, state, key, storage, ptr, size, carry, nkey
                 )
                 out0 = init_out(shapes, state)
 
                 def body(i, acc):
                     out, key, storage, ptr, size, carry = acc
-                    return beat(out.state, key, storage, ptr, size, carry)
+                    return beat(
+                        out.state, key, storage, ptr, size, carry, nkey
+                    )
 
                 return jax.lax.fori_loop(
                     0, B, body, (out0, key, storage, ptr, size, carry)
@@ -168,9 +170,9 @@ class FusedSuperstep:
 
         elif not self.per and self.guard:
 
-            def superstep(state, key, storage, ptr, size, carry, g):
+            def superstep(state, key, storage, ptr, size, carry, g, nkey):
                 shapes = jax.eval_shape(
-                    beat, state, key, storage, ptr, size, carry, g
+                    beat, state, key, storage, ptr, size, carry, g, nkey
                 )
                 out0 = init_out(shapes, state)
                 # Stacked per-beat stats carry: health rows land at [i],
@@ -181,8 +183,9 @@ class FusedSuperstep:
 
                 def body(i, acc):
                     out, key, storage, ptr, size, carry, g, hs, bs = acc
-                    (out, key, storage, ptr, size, carry, g, h,
-                     b) = beat(out.state, key, storage, ptr, size, carry, g)
+                    (out, key, storage, ptr, size, carry, g, h, b) = beat(
+                        out.state, key, storage, ptr, size, carry, g, nkey
+                    )
                     return (out, key, storage, ptr, size, carry, g,
                             hs.at[i].set(h), bs.at[i].set(b))
 
@@ -194,10 +197,10 @@ class FusedSuperstep:
         elif self.per and not self.guard:
 
             def superstep(state, key, storage, ptr, size, carry,
-                          priorities, maxp, betas, alpha, eps):
+                          priorities, maxp, betas, alpha, eps, nkey):
                 shapes = jax.eval_shape(
                     beat, state, key, storage, ptr, size, carry,
-                    priorities, maxp, betas[0], alpha, eps,
+                    priorities, maxp, betas[0], alpha, eps, nkey,
                 )
                 out0 = init_out(shapes, state)
 
@@ -205,7 +208,7 @@ class FusedSuperstep:
                     (out, key, storage, ptr, size, carry, priorities,
                      maxp) = acc
                     return beat(out.state, key, storage, ptr, size, carry,
-                                priorities, maxp, betas[i], alpha, eps)
+                                priorities, maxp, betas[i], alpha, eps, nkey)
 
                 return jax.lax.fori_loop(
                     0, B, body,
@@ -216,10 +219,10 @@ class FusedSuperstep:
         else:
 
             def superstep(state, key, storage, ptr, size, carry,
-                          priorities, maxp, betas, alpha, eps, g):
+                          priorities, maxp, betas, alpha, eps, g, nkey):
                 shapes = jax.eval_shape(
                     beat, state, key, storage, ptr, size, carry,
-                    priorities, maxp, betas[0], alpha, eps, g,
+                    priorities, maxp, betas[0], alpha, eps, g, nkey,
                 )
                 out0 = init_out(shapes, state)
                 hs = jnp.zeros((B,) + shapes[9].shape, shapes[9].dtype)
@@ -231,7 +234,7 @@ class FusedSuperstep:
                     (out, key, storage, ptr, size, carry, priorities, maxp,
                      g, h, b) = beat(
                         out.state, key, storage, ptr, size, carry,
-                        priorities, maxp, betas[i], alpha, eps, g,
+                        priorities, maxp, betas[i], alpha, eps, g, nkey,
                     )
                     return (out, key, storage, ptr, size, carry, priorities,
                             maxp, g, hs.at[i].set(h), bs.at[i].set(b))
@@ -298,6 +301,7 @@ class FusedSuperstep:
                             L.state, L._key, replay.storage, replay.ptr,
                             replay.size, pool._carry, replay.priorities,
                             replay.max_priority, *scalars, L._guard,
+                            L._noise_key,
                         )
                         L.note_fused_health(g, health, bad_idx)
                     else:
@@ -305,7 +309,7 @@ class FusedSuperstep:
                          maxp) = self._superstep(
                             L.state, L._key, replay.storage, replay.ptr,
                             replay.size, pool._carry, replay.priorities,
-                            replay.max_priority, *scalars,
+                            replay.max_priority, *scalars, L._noise_key,
                         )
                     replay.set_per_state(prios, maxp)
                 else:
@@ -314,13 +318,14 @@ class FusedSuperstep:
                          bad_idx) = self._superstep(
                             L.state, L._key, replay.storage, replay.ptr,
                             replay.size, pool._carry, L._guard,
+                            L._noise_key,
                         )
                         L.note_fused_health(g, health, bad_idx)
                     else:
                         (out, key, storage, ptr, size,
                          carry) = self._superstep(
                             L.state, L._key, replay.storage, replay.ptr,
-                            replay.size, pool._carry,
+                            replay.size, pool._carry, L._noise_key,
                         )
                 L.state = out.state
                 L._key = key
@@ -355,6 +360,7 @@ class FusedSuperstep:
                      np.float32(replay.alpha), np.float32(replay.eps)]
         if self.guard:
             args.append(L._guard)
+        args.append(L._noise_key)
         return tuple(args)
 
 

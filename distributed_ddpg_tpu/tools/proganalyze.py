@@ -12,9 +12,11 @@ but it never compiles or executes one: `jax.make_jaxpr` + `.lower()`
 only, so a full live-tree run stays inside a 30 s CPU budget.
 
 On the default registry the CLI also runs the static `recompile-hazard`
-rule (analysis/progrules.py) over the package, so one command covers all
-four program-contract checks; `scripts/proganalyze_gate.sh` wraps this
-as the CI gate and `tools.runs programs` renders the JSON artifact.
+rule (analysis/progrules.py) over the package, and on every registry that
+module's `seed-constant` rule (each program lowered at two seeds: the text
+the compile cache keys on may not hold the seed), so one command covers
+all five program-contract checks; `scripts/proganalyze_gate.sh` wraps
+this as the CI gate and `tools.runs programs` renders the JSON artifact.
 """
 
 from __future__ import annotations
@@ -267,6 +269,18 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
             report.findings.append(prog_lib.ProgramFinding(
                 f"{f.path}:{f.line}", "recompile-hazard", f.message,
             ))
+
+    if not args.update_golden:
+        # The fifth check (analysis/progrules.py): the registry built
+        # again under another seed, every program's lowered text equal.
+        from distributed_ddpg_tpu.analysis.progrules import (
+            seed_constant_findings,
+        )
+
+        report.findings.extend(seed_constant_findings(
+            (lambda: _load_specs(args.specs)) if args.specs else None,
+            only=only,
+        ))
 
     if args.json is not None:
         prog_lib.write_report(report, args.json)

@@ -21,6 +21,11 @@ an exact finding count:
                               different collective orders.
 - `clean_specs`             — a well-formed donating + collective
                               program for golden roundtrip tests.
+- `seed_constant_specs`     — one program that traces PRNGKey(config.seed)
+                              into its body and one that takes the same
+                              key as an argument: the seed-constant rule
+                              (analysis/progrules.py) must flag the first
+                              and only the first.
 
 These run under the same probe mesh as the live registries; everything
 is traced/lowered only — nothing here ever executes.
@@ -33,6 +38,7 @@ from jax.sharding import PartitionSpec as P
 from distributed_ddpg_tpu.analysis.programs import (
     BuiltProgram,
     ProgramSpec,
+    probe_config,
     probe_mesh,
 )
 from distributed_ddpg_tpu.parallel.mesh import shard_map
@@ -154,3 +160,27 @@ def _clean_program() -> BuiltProgram:
 
 def clean_specs():
     return [ProgramSpec("fixture.clean", OWNER, _clean_program)]
+
+
+# -- a seed traced into the program as a constant --------------------------
+
+
+def _seed_in_the_text() -> BuiltProgram:
+    seed = probe_config().seed
+    fn = jax.jit(
+        lambda x: x + jax.random.normal(jax.random.PRNGKey(seed ^ 0x5AC0), x.shape)
+    )
+    return BuiltProgram(fn, (np.zeros(3, np.float32),))
+
+
+def _seed_as_an_argument() -> BuiltProgram:
+    key = jax.random.PRNGKey(probe_config().seed ^ 0x5AC0)
+    fn = jax.jit(lambda x, k: x + jax.random.normal(k, x.shape))
+    return BuiltProgram(fn, (np.zeros(3, np.float32), key))
+
+
+def seed_constant_specs():
+    return [
+        ProgramSpec("fixture.seed.constant", OWNER, _seed_in_the_text),
+        ProgramSpec("fixture.seed.argument", OWNER, _seed_as_an_argument),
+    ]

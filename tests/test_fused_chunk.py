@@ -352,7 +352,7 @@ def test_auto_mode_kernel_failure_raises(monkeypatch):
     monkeypatch.setattr(fc, "runs_native", lambda: True)
 
     def broken_make(*args, **kwargs):
-        def run(state, batches):
+        def run(state, batches, eps=None):
             raise RuntimeError("mosaic boom")
 
         return run

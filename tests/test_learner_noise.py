@@ -16,8 +16,14 @@
   to the bit everywhere (the first test, with and without the device fold);
 - a guarded chunk that drops an update stays aligned with the stream;
 - the structure: no RNG primitive inside the scan body of a noise-bearing
-  chunk program, and DDPG / D4PG scan over their batches alone.
+  chunk program, and DDPG / D4PG scan over their batches alone;
+- the stream's base key is an ARGUMENT of every chunk program that draws
+  (ShardedLearner._noise_key), so a program lowers to the same text at
+  every seed, and the bits a seed gives are the ones a constant key gave;
+  a program that draws nothing has no such parameter.
 """
+
+import re
 
 import jax
 import jax.numpy as jnp
@@ -43,6 +49,15 @@ ALGOS = {
     "sac": dict(sac=True),
     "td3": dict(twin_critic=True, target_noise=0.2, policy_delay=2),
 }
+# the families whose stream has a third member or a state of another shape
+FAMILIES = {
+    **ALGOS,
+    "redq": dict(sac=True, critic_ensemble=5, target_subset=2, policy_delay=3),
+    "crossq": dict(
+        sac=True, crossq=True, policy_delay=3, adam_b1=0.5,
+        action_insert_layer=0,
+    ),
+}
 QUIET = {
     "ddpg": dict(),
     "d4pg": dict(distributional=True, num_atoms=11, v_min=-5.0, v_max=5.0),
@@ -64,12 +79,13 @@ RNG_PRIMITIVES = {
 
 
 def _cfg(algo, guarded=False, per=False, **kw):
-    return DDPGConfig(
+    fields = dict(
         actor_hidden=(16, 16), critic_hidden=(16, 16), batch_size=B, seed=7,
         fused_chunk="off", scale_batch_with_data=False, guardrails=guarded,
         guardrail_warmup_steps=10_000, prioritized=per,
-        **{**ALGOS, **QUIET}[algo], **kw,
+        **{**FAMILIES, **QUIET}[algo],
     )
+    return DDPGConfig(**{**fields, **kw})
 
 
 def _learner(algo, variant, chunk=K, **kw):
@@ -236,7 +252,9 @@ def test_chunk_noise_is_the_step_stream_row_by_row(algo, device):
     assert learner_lib.draws_noise(cfg) and cfg.target_noise_clip == 0.5
     step0 = 37
     got = jax.jit(
-        lambda s: learner_lib.chunk_noise(cfg, s, K, B, ACT, device)
+        lambda s: learner_lib.chunk_noise(
+            cfg, learner_lib.noise_base_key(cfg), s, K, B, ACT, device
+        )
     )(jnp.int32(step0))
     for k in range(K):
         want = _by_hand_jit(algo, cfg.seed, step0 + k, device)
@@ -253,8 +271,9 @@ def test_chunk_noise_is_the_step_stream_row_by_row(algo, device):
 def test_quiet_algorithms_draw_nothing(algo):
     cfg = _cfg(algo)
     assert not learner_lib.draws_noise(cfg)
-    assert learner_lib.chunk_noise(cfg, 0, K, B, ACT) is None
     base = learner_lib.noise_base_key(cfg)
+    assert base is None
+    assert learner_lib.chunk_noise(cfg, base, 0, K, B, ACT) is None
     assert learner_lib.step_noise(cfg, base, 0, B, ACT) is None
 
 
@@ -379,3 +398,124 @@ def test_hostfed_chunk_draws_before_its_scan_too():
     rng = [(p, inside) for p, inside in found["prims"] if p in RNG_PRIMITIVES]
     assert rng and not any(inside for _, inside in rng)
     assert found["scan_xs"] == [8]
+
+
+# --- the base key is an argument: one text for every seed, the same bits ---
+
+HOSTFED = dict(hostfed=True)
+KERNEL = dict(fused_chunk="on")  # the megakernel, in interpret mode here
+# case -> (algo, variant, what else the learner is built with)
+SEEDLESS = {
+    "sac-uniform": ("sac", "uniform", {}),
+    "sac-explicit": ("sac", "explicit", {}),
+    "sac-auto_mesh2": ("sac", "auto_mesh2", {}),
+    "redq-uniform": ("redq", "uniform", {}),
+    "redq-explicit": ("redq", "explicit", {}),
+    "crossq-uniform": ("crossq", "uniform", {}),
+    "crossq-explicit": ("crossq", "explicit", {}),
+    "sac-guarded": ("sac", "guarded", {}),
+    "sac-per": ("sac", "per", {}),
+    "td3-per_guarded": ("td3", "per_guarded", {}),
+    "td3-uniform": ("td3", "uniform", {}),
+    "td3-kernel": ("td3", "uniform", KERNEL),
+    "td3-kernel-per": ("td3", "per", KERNEL),
+    "sac-kernel": ("sac", "uniform", KERNEL),
+    "td3-fused_mesh": ("td3", "auto_mesh2", KERNEL),
+    "sac-hostfed": ("sac", "uniform", HOSTFED),
+    "td3-hostfed-guarded": ("td3", "guarded", HOSTFED),
+}
+CONTROLS = {
+    "ddpg-uniform": ("ddpg", "uniform", {}),
+    "ddpg-kernel": ("ddpg", "uniform", KERNEL),
+    "ddpg-hostfed": ("ddpg", "uniform", HOSTFED),
+    "d4pg-uniform": ("d4pg", "uniform", {}),
+    "d4pg-kernel": ("d4pg", "uniform", KERNEL),
+    "d4pg-explicit": ("d4pg", "explicit", {}),
+}
+
+
+def _lowered(case, seed):
+    """(the text the case's chunk program lowers to at `seed`, the leaves of
+    the arguments a launch hands it, the learner)."""
+    algo, variant, extra = {**SEEDLESS, **CONTROLS}[case]
+    extra = dict(extra)
+    hostfed = extra.pop("hostfed", False)
+    learner = _learner(algo, variant, seed=seed, **extra)
+    assert learner.fused_chunk_active == (extra.get("fused_chunk") == "on")
+    if hostfed:
+        packed = jnp.zeros(
+            (K, learner.global_batch, 2 * OBS + ACT + 3), jnp.float32
+        )
+        guard = (learner._guard,) if learner.guard_enabled else ()
+        fn, args = learner._chunk_step, (learner.state, packed) + guard
+    else:
+        fn, args = _chunk_program(learner, variant)
+    return fn.lower(*args).as_text(), len(jax.tree.leaves(args)), learner
+
+
+def _main_parameters(text):
+    """The types of the lowered module's public parameters."""
+    from distributed_ddpg_tpu.analysis.programs import _main_signature
+
+    return re.findall(r"%arg\d+: tensor<([^>]*)>", _main_signature(text)[0])
+
+
+@pytest.mark.parametrize("case", list(SEEDLESS))
+def test_a_chunk_program_that_draws_lowers_to_one_text_at_every_seed(case):
+    """Fails on a tree whose chunk programs hold PRNGKey(seed ^ const) as a
+    constant: the persistent compile cache keys on this text."""
+    one, leaves, learner = _lowered(case, seed=1)
+    two, _, other = _lowered(case, seed=2)
+    assert one == two
+    # The base keys differ, and reach the program as its last parameter.
+    assert not np.array_equal(learner._noise_key, other._noise_key)
+    np.testing.assert_array_equal(
+        learner._noise_key, learner_lib.noise_base_key(learner.config)
+    )
+    params = _main_parameters(one)
+    assert len(params) == leaves + 1 and params[-1] == "2xui32"
+    assert params.count("2xui32") == 1 + ("hostfed" not in case)
+
+
+@pytest.mark.parametrize("case", list(CONTROLS))
+def test_a_chunk_program_that_draws_nothing_has_no_key_parameter(case):
+    """DDPG and D4PG keep the signature they had: None is an empty pytree,
+    so the text is the one the call gives with no key argument at all."""
+    one, leaves, learner = _lowered(case, seed=1)
+    two, _, _ = _lowered(case, seed=2)
+    assert learner._noise_key is None and one == two
+    params = _main_parameters(one)
+    # No key but the sampling key (the chunk fed from the host has none).
+    assert len(params) == leaves
+    assert params.count("2xui32") == ("hostfed" not in case)
+
+
+STREAMS = {**ALGOS, "redq": FAMILIES["redq"]}
+
+
+@pytest.mark.parametrize("device", [None, 1])
+@pytest.mark.parametrize("seed", [7, 2_000_000_011])
+@pytest.mark.parametrize("algo", list(STREAMS))
+def test_the_stream_of_a_traced_base_key_is_the_stream_of_the_seed(
+    algo, seed, device
+):
+    """chunk_noise with the base handed in under jit, as the chunk programs
+    hand it in, against the step's own draw from the constant key, row by
+    row, to the bit: the stream did not move when the key became an
+    argument."""
+    cfg = _cfg(algo, seed=seed)
+    base = learner_lib.noise_base_key(cfg)
+    step0 = 37
+    got = jax.jit(
+        lambda key, s: learner_lib.chunk_noise(cfg, key, s, K, B, ACT, device)
+    )(base, jnp.int32(step0))
+    assert len(jax.tree.leaves(got)) == (1 if algo == "td3" else 2 + (algo == "redq"))
+    for k in range(K):
+        want = jax.jit(
+            lambda: learner_lib.step_noise(cfg, base, step0 + k, B, ACT, device)
+        )()
+        _assert_same(jax.tree.map(lambda x: x[k], got), want, exact=True)
+        if algo != "redq":
+            _assert_same(
+                want, _by_hand_jit(algo, seed, step0 + k, device), exact=True
+            )

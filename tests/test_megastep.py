@@ -127,6 +127,56 @@ def test_fused_beat_bit_identical_to_separate_dispatches(per, sharded):
         assert _leaves_equal(rf.max_priority, ru.max_priority)
 
 
+TD3 = dict(twin_critic=True, target_noise=0.2, policy_delay=2)
+
+
+def test_fused_beat_that_draws_noise_matches_separate_dispatches():
+    """TD3's smoothing noise inside a beat: the base key of the stream is
+    an argument of the beat (ShardedLearner._noise_key), handed on to the
+    chunk body, and gives the bits the standalone chunk draws."""
+    from distributed_ddpg_tpu.parallel.megastep import FusedMegastep
+
+    config = _cfg(fused_beat="on", **TD3)
+    lf, pf, rf = _setup(config, sharded=False)
+    ms = FusedMegastep(config, lf, pf, rf)
+    for _ in range(3):
+        ms.run_beat()
+
+    lu, pu, ru = _setup(config, sharded=False)
+    for _ in range(3):
+        lu.run_sample_chunk(ru)
+        pu.set_params(lu.state.actor_params)
+        pu.run_chunk(ru)
+
+    assert _leaves_equal(rf.storage, ru.storage)
+    assert _leaves_equal(lf.state, lu.state)
+    assert _leaves_equal(lf._key, lu._key)
+
+
+@pytest.mark.parametrize("program", ["beat", "superstep"])
+def test_a_beat_that_draws_noise_lowers_to_one_text_at_every_seed(program):
+    """The fused programs compose the chunk bodies, so they take the noise
+    key as their last argument too: nothing derived from config.seed is in
+    the text the persistent compile cache keys on."""
+    from distributed_ddpg_tpu.parallel.megastep import FusedMegastep
+    from distributed_ddpg_tpu.parallel.superstep import FusedSuperstep
+
+    def lowered(seed):
+        config = _cfg(fused_beat="on", seed=seed, **TD3)
+        stack = _setup(config, sharded=False)
+        if program == "beat":
+            fused = FusedMegastep(config, *stack)
+            fn = fused._beat
+        else:
+            fused = FusedSuperstep(config, *stack, beats=2)
+            fn = fused._superstep
+        args = fused.example_args()
+        assert args[-1] is stack[0]._noise_key is not None
+        return fn.lower(*args).as_text()
+
+    assert lowered(1) == lowered(2)
+
+
 def test_guarded_fused_beat_matches_guarded_dispatches():
     """The guarded composition is the same seam: guarded fused beats ==
     guarded separate dispatches, health word included."""

@@ -69,8 +69,11 @@ def test_live_tree_clean_with_committed_goldens(tmp_path):
     # alone and over 45 s beside five other test workers. 45 s since the
     # six .tp program variants (PR 15, docs/MESH.md) grew the registry
     # 26 -> 32; 65 s at 49 programs (PR 38 read 48 s beside five workers).
+    # Since PR 39 the CLI also builds the registry under two more seeds
+    # and lowers every program (the seed-constant rule): 49 s alone, 97 s
+    # of CPU for the whole command beside five workers; 150 s.
     assert rep["elapsed_s"] > 0
-    assert cpu_s < 65.0, (cpu_s, rep["elapsed_s"])
+    assert cpu_s < 150.0, (cpu_s, rep["elapsed_s"])
 
 
 def test_every_spec_module_is_watched_by_changed_only():
@@ -179,6 +182,41 @@ def test_callback_leak_drives_exit_2(tmp_path):
     assert f["check"] == "host-callback"
     assert f["program"] == "fixture.callback.leak"
     assert "pure_callback" in f["message"]  # names the primitive
+
+
+def test_seed_constant_drives_exit_2(tmp_path):
+    """A program that traces PRNGKey(config.seed) into its body compiles
+    anew for every seed (the persistent cache keys on the text); the same
+    key as an argument does not."""
+    g = tmp_path / "g"
+    spec = f"{FIXMOD}:seed_constant_specs"
+    assert cli(["--specs", spec, "--golden", str(g), "--update-golden"],
+               tmp_path)[0] == 0  # writing goldens runs no second build
+    rc, rep = cli(["--specs", spec, "--golden", str(g)], tmp_path)
+    assert rc == 2
+    assert [(f["check"], f["program"]) for f in rep["findings"]] == [
+        ("seed-constant", "fixture.seed.constant")
+    ]
+    assert "compile cache" in rep["findings"][0]["message"]
+
+
+def test_seed_constant_rule_builds_under_both_seeds_and_restores():
+    from distributed_ddpg_tpu.analysis import progrules
+
+    seen = []
+
+    def specs():
+        seen.append(prog_lib.probe_config().seed)
+        return fx.seed_constant_specs()
+
+    found = progrules.seed_constant_findings(specs, seeds=(3, 11))
+    assert seen == [3, 11] and prog_lib.probe_config().seed == 0
+    assert [f.program for f in found] == ["fixture.seed.constant"]
+    assert "seed 3 and seed 11" in found[0].message
+    # scoped to the clean program: silent
+    assert progrules.seed_constant_findings(
+        specs, only=["fixture.seed.arg*"]
+    ) == []
 
 
 def test_collective_reorder_drives_exit_2(tmp_path):

@@ -127,7 +127,7 @@ def test_the_subset_is_without_replacement_and_uniform_over_the_45_pairs():
     indices by a tenth, reads in the hundreds."""
     cfg = _cfg(**ENSEMBLES["the-papers"])
     steps = 10_000
-    subset = np.asarray(chunk_noise(cfg, jnp.asarray(0, jnp.int32), steps, 1, ACT)[2])
+    subset = np.asarray(chunk_noise(cfg, noise_base_key(cfg), jnp.asarray(0, jnp.int32), steps, 1, ACT)[2])
     assert subset.shape == (steps, 2) and subset.dtype == np.int32
     assert subset.min() == 0 and subset.max() == 9
     assert np.all(subset[:, 0] != subset[:, 1])
@@ -141,7 +141,8 @@ def test_the_subset_is_without_replacement_and_uniform_over_the_45_pairs():
     first_lower = np.mean(subset[:, 0] < subset[:, 1])
     assert abs(first_lower - 0.5) < 0.02
     # M = 3 of 5: three distinct members every step
-    three = np.asarray(chunk_noise(_cfg(critic_ensemble=5, target_subset=3), jnp.asarray(0), 500, 1, ACT)[2])
+    cfg3 = _cfg(critic_ensemble=5, target_subset=3)
+    three = np.asarray(chunk_noise(cfg3, noise_base_key(cfg3), jnp.asarray(0), 500, 1, ACT)[2])
     assert all(len(set(row)) == 3 for row in three.tolist())
 
 

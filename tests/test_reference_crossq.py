@@ -29,6 +29,7 @@ from distributed_ddpg_tpu.learner import (
     init_train_state,
     make_learner_step,
     metric_keys,
+    noise_base_key,
 )
 from distributed_ddpg_tpu.parallel import mesh as mesh_lib
 from distributed_ddpg_tpu.parallel.learner import ShardedLearner
@@ -319,7 +320,7 @@ def test_the_reference_draws_the_programs_streams(crossq, seed, step0):
     streams and no third member, for a seed past 2**31 too."""
     cfg = config().replace(seed=seed)
     b, a, k = HP["batch_size"], ENV["act_dim"], 5
-    ours = chunk_noise(cfg, jnp.asarray(step0, jnp.int32), k, b, a)
+    ours = chunk_noise(cfg, noise_base_key(cfg), jnp.asarray(step0, jnp.int32), k, b, a)
     assert len(ours) == 2
     key = crossq.init(seed, ENV, HP)["noise_key"]
     theirs = [crossq.draws(key, jnp.asarray(step0 + i, jnp.int32), HP, (b, a)) for i in range(k)]

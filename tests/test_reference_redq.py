@@ -28,6 +28,7 @@ from distributed_ddpg_tpu.learner import (
     init_train_state,
     make_learner_step,
     metric_keys,
+    noise_base_key,
 )
 from distributed_ddpg_tpu.parallel import mesh as mesh_lib
 from distributed_ddpg_tpu.parallel.learner import ShardedLearner
@@ -288,7 +289,7 @@ def test_the_reference_draws_the_programs_streams(redq, seed, step0):
     and the same set, for a seed past 2**31 too."""
     cfg = config().replace(seed=seed)
     b, a, k = HP["batch_size"], ENV["act_dim"], 5
-    ours = chunk_noise(cfg, jnp.asarray(step0, jnp.int32), k, b, a)
+    ours = chunk_noise(cfg, noise_base_key(cfg), jnp.asarray(step0, jnp.int32), k, b, a)
     assert len(ours) == 3 and ours[2].shape == (k, HP["target_subset"]) and ours[2].dtype == jnp.int32
     key = redq.init(seed, ENV, HP)["noise_key"]
     theirs = [redq.draws(key, jnp.asarray(step0 + i, jnp.int32), HP, (b, a)) for i in range(k)]

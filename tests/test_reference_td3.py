@@ -25,6 +25,7 @@ from distributed_ddpg_tpu.learner import (
     init_train_state,
     make_learner_step,
     metric_keys,
+    noise_base_key,
 )
 from distributed_ddpg_tpu.ops import fused_chunk
 from distributed_ddpg_tpu.types import unpack_batch
@@ -216,7 +217,7 @@ def test_the_reference_draws_the_programs_noise_stream(td3, seed, step0):
     same bits, for a seed past 2**31 too."""
     cfg = config().replace(seed=seed)
     b, a, k = HP["batch_size"], ENV["act_dim"], 5
-    ours = chunk_noise(cfg, jnp.asarray(step0, jnp.int32), k, b, a)
+    ours = chunk_noise(cfg, noise_base_key(cfg), jnp.asarray(step0, jnp.int32), k, b, a)
     key = td3.init(seed, ENV, HP)["noise_key"]
     theirs = jnp.stack([td3.smoothing_noise(key, jnp.asarray(step0 + i, jnp.int32), HP, (b, a)) for i in range(k)])
     np.testing.assert_array_equal(np.asarray(ours), np.asarray(theirs))
@@ -227,7 +228,7 @@ def test_the_noise_is_clipped_where_the_normal_tail_says(td3):
     draws sit on the clip. 48,000 draws put the sampling error of that
     share at 0.05 points; a sigma of 0.1 or a clip at 1.0 reads under 0.01%."""
     cfg = config()
-    eps = np.asarray(chunk_noise(cfg, jnp.asarray(0, jnp.int32), 80, 100, ENV["act_dim"]))
+    eps = np.asarray(chunk_noise(cfg, noise_base_key(cfg), jnp.asarray(0, jnp.int32), 80, 100, ENV["act_dim"]))
     assert np.abs(eps).max() == pytest.approx(HP["target_noise_clip"])
     share = np.mean(np.abs(eps) == np.float32(HP["target_noise_clip"]))
     assert share == pytest.approx(0.0124, abs=0.0025)

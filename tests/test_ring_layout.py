@@ -451,7 +451,10 @@ def test_v5e_scan_chunk_draws_its_noise_before_the_loop(v5e_sharding):
     step = learner_lib.make_learner_step(cfg, env["action_scale"], action_offset=env["action_offset"])
 
     def run(s, packed):
-        noise = learner_lib.chunk_noise(cfg, s.step, chunk, cfg.batch_size, act)
+        noise = learner_lib.chunk_noise(
+            cfg, learner_lib.noise_base_key(cfg), s.step, chunk,
+            cfg.batch_size, act,
+        )
         return scan_chunk(step, s, unpack_batch(packed, obs, act), noise, unroll=4)
 
     replicated = NamedSharding(v5e_sharding.mesh, P())
@@ -499,7 +502,10 @@ def test_v5e_redq_chunk_draws_before_the_loop_and_holds_the_policy_under_a_condi
     step = learner_lib.make_learner_step(cfg, env["action_scale"], action_offset=env["action_offset"])
 
     def run(s, packed):
-        noise = learner_lib.chunk_noise(cfg, s.step, chunk, cfg.batch_size, act)
+        noise = learner_lib.chunk_noise(
+            cfg, learner_lib.noise_base_key(cfg), s.step, chunk,
+            cfg.batch_size, act,
+        )
         return scan_chunk(step, s, unpack_batch(packed, obs, act), noise, unroll=4)
 
     replicated = NamedSharding(v5e_sharding.mesh, P())
@@ -551,7 +557,10 @@ def test_v5e_crossq_chunk_holds_no_target_update_and_the_policy_under_a_conditio
     step = learner_lib.make_learner_step(cfg, env["action_scale"], action_offset=env["action_offset"])
 
     def run(s, packed):
-        noise = learner_lib.chunk_noise(cfg, s.step, chunk, cfg.batch_size, act)
+        noise = learner_lib.chunk_noise(
+            cfg, learner_lib.noise_base_key(cfg), s.step, chunk,
+            cfg.batch_size, act,
+        )
         return scan_chunk(step, s, unpack_batch(packed, obs, act), noise, unroll=4)
 
     replicated = NamedSharding(v5e_sharding.mesh, P())
