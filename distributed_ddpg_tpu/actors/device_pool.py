@@ -69,7 +69,12 @@ from distributed_ddpg_tpu import trace
 from distributed_ddpg_tpu.config import DDPGConfig
 from distributed_ddpg_tpu.envs.jax_envs import make_jax_env
 from distributed_ddpg_tpu.metrics import DevActorStats
-from distributed_ddpg_tpu.ops.exploration import vector_env_step
+from distributed_ddpg_tpu.ops.exploration import (
+    nstep_fold,
+    nstep_window,
+    sigma_ladder,
+    vector_env_step,
+)
 
 
 class DeviceActorError(RuntimeError):
@@ -104,6 +109,12 @@ class ActorCarry(NamedTuple):
     episodes: jnp.ndarray    # i32[] cumulative finished episodes
     ret_sum: jnp.ndarray     # f32[] cumulative sum of finished returns
     key: jnp.ndarray         # PRNG key
+    # n_step > 1 only (None otherwise: no leaf, and the 1-step program is
+    # the one it always was): the rows each env has begun and not yet
+    # emitted (ops/exploration.NStepWindow), and how many emitted rows held
+    # fewer than n steps.
+    window: object = None
+    short_rows: object = None  # i32[] cumulative
 
 
 class DeviceActorPool:
@@ -134,6 +145,20 @@ class DeviceActorPool:
         self._max_restarts = 3
         self._dispatches = 0
         self._steps = 0
+        # n-step rows (docs/DEVICE_ACTORS.md): each env emits, at every
+        # step, the row it began n - 1 steps earlier, so after the n - 1
+        # priming steps (set_params) every dispatch yields K * E whole rows
+        # and (n - 1) * E steps are always taken and not yet in the ring.
+        self.n_step = n = int(config.n_step)
+        self._primed = n == 1
+        # Learner updates between the parameters a dispatch read and the
+        # newest the learner held then (staleness): sum, count, max of the
+        # interval.
+        self._params_version = 0
+        self._stale = [0, 0, 0]
+        self._short_seen = 0
+        self._rows_emitted = 0
+        self._rows_seen = 0
         # Interval episode accounting: snapshot() differences the carry's
         # cumulative device counters against these host mirrors.
         self._eps_seen = 0
@@ -179,6 +204,13 @@ class DeviceActorPool:
                     else None
                 ),
             )
+            window, short_rows = carry.window, carry.short_rows
+            if n > 1:
+                with trace.device_scope("fold"):
+                    window, rows, short = nstep_fold(
+                        window, rows, out, cfg.gamma, obs_dim, act_dim
+                    )
+                    short_rows = short_rows + short.sum().astype(jnp.int32)
             ep_ret = carry.ep_ret + out.reward
             done_ret = jnp.where(out.done, ep_ret, 0.0)
             new_carry = ActorCarry(
@@ -190,17 +222,30 @@ class DeviceActorPool:
                 episodes=carry.episodes + out.done.sum().astype(jnp.int32),
                 ret_sum=carry.ret_sum + done_ret.sum(),
                 key=key,
+                window=window,
+                short_rows=short_rows,
             )
             return new_carry, rows
 
-        def rollout(params, carry: ActorCarry):
-            carry, rows = jax.lax.scan(
-                lambda c, _: env_step(params, c), carry, None, length=K
-            )
+        # Its name is the program's in a device trace (`jit_devactor_rollout`
+        # on the XLA Modules line): the benchmark's actors readers find it.
+        def devactor_rollout(params, carry: ActorCarry):
+            with trace.device_scope("rollout"):
+                carry, rows = jax.lax.scan(
+                    lambda c, _: env_step(params, c), carry, None, length=K
+                )
             # [K, E, D] -> [K*E, D], step-major: row order matches K serial
             # E-wide inserts, so the ring layout is what a per-step insert
             # sequence would have produced.
             return carry, rows.reshape(K * E, rows.shape[-1])
+
+        def prime(params, carry: ActorCarry):
+            """The run's first n - 1 steps: they fill the window and the
+            rows they emit (the empty window's zeros) are dropped."""
+            carry, _ = jax.lax.scan(
+                lambda c, _: env_step(params, c), carry, None, length=n - 1
+            )
+            return carry._replace(short_rows=jnp.zeros((), jnp.int32))
 
         # --- shardings + initial carry ---
         key = jax.random.PRNGKey(config.seed + 0xDA)
@@ -208,13 +253,20 @@ class DeviceActorPool:
         env_state = jax.vmap(env.init)(jax.random.split(k_init, E))
         carry = ActorCarry(
             env_state=env_state,
-            obs=jax.vmap(env.observe)(env_state),
+            # A copy: an env whose observation IS its state would hand the
+            # donating rollout one buffer under two leaves.
+            obs=jnp.copy(jax.vmap(env.observe)(env_state)),
             ou=jnp.zeros((E, act_dim), jnp.float32),
             ep_ret=jnp.zeros((E,), jnp.float32),
             steps=jnp.zeros((), jnp.int32),
             episodes=jnp.zeros((), jnp.int32),
             ret_sum=jnp.zeros((), jnp.float32),
             key=k_run,
+            window=(
+                nstep_window(E, n, 2 * obs_dim + act_dim + 3)
+                if n > 1 else None
+            ),
+            short_rows=jnp.zeros((), jnp.int32) if n > 1 else None,
         )
         carry_spec = ActorCarry(
             env_state=jax.tree.map(lambda _: P(env_axis), env_state),
@@ -225,6 +277,10 @@ class DeviceActorPool:
             episodes=P(),
             ret_sum=P(),
             key=P(),
+            window=jax.tree.map(
+                lambda x: P(env_axis, *([None] * (x.ndim - 1))), carry.window
+            ),
+            short_rows=None if n == 1 else P(),
         )
         self._carry_sharding = mesh_lib.to_named(self.mesh, carry_spec)
         # Rows come out REPLICATED: that is the block sharding
@@ -236,34 +292,78 @@ class DeviceActorPool:
         # beat calls it on the freshly-updated actor params in the same
         # program, so its rows land with zero extra dispatches. The jitted
         # wrapper below stays the standalone (warmup / unfused) path.
-        self._rollout_fn = rollout
+        self._rollout_fn = devactor_rollout
         # Params keep whatever sharding the learner's live tree carries
         # (replicated, or TP-sharded under model_axis > 1): no in_shardings
         # pin, so the pointer-swap refresh never pays a resharding copy.
         self._rollout = jax.jit(
-            rollout,
+            devactor_rollout,
             out_shardings=(self._carry_sharding, rows_sharding),
             donate_argnums=(1,),
         )
+        self._prime = jax.jit(
+            prime, out_shardings=self._carry_sharding, donate_argnums=(1,)
+        )
         self._carry: ActorCarry = jax.device_put(carry, self._carry_sharding)
+        # The exploration scales' ends as the program holds them (header
+        # fields devactor_sigma_min / _max); None under OU or SAC.
+        self.sigma_ends = None
+        if cfg.exploration == "gaussian" and not cfg.sac:
+            ladder = np.asarray(sigma_ladder(cfg, E))
+            self.sigma_ends = (float(ladder[0]), float(ladder[-1]))
 
     # --- param refresh (device-side pointer swap) ---
 
-    def set_params(self, actor_params) -> None:
+    def set_params(self, actor_params, version: int = 0) -> None:
         """Swap in the learner's LIVE actor params (a device-resident
         pytree reference — nothing is copied or transferred). Callers must
         re-swap after every learner dispatch that donates the TrainState:
         the previously-stored tree is deleted by that donation, and
         dispatching a rollout against it would raise. train.py does this
-        at the top of every after_chunk."""
+        at the top of every after_chunk. `version`: the learner's update
+        count these parameters stand at (staleness). The first swap of a
+        pool that folds n > 1 steps also takes the n - 1 priming steps,
+        unless a restored carry brought its window along."""
         self._params = actor_params
+        self._params_version = int(version)
+        if not self._primed:
+            self._carry = self._prime(actor_params, self._carry)
+            self._primed = True
+            self._steps += (self.n_step - 1) * self.num_envs
+
+    @property
+    def pending_rows(self) -> int:
+        """Env steps taken whose rows are still in the envs' n-step windows:
+        in no ring yet, and counted by steps_done."""
+        return (self.n_step - 1) * self.num_envs if self._primed else 0
+
+    def note_dispatch(self, newest_version: int) -> None:
+        """One rollout is about to read the stored parameters while the
+        learner stands at `newest_version` updates (run_chunk and the fused
+        beat's caller both say so)."""
+        lag = max(0, int(newest_version) - self._params_version)
+        self._stale[0] += lag
+        self._stale[1] += 1
+        self._stale[2] = max(self._stale[2], lag)
+
+    def staleness(self) -> dict:
+        """The host pool's two keys, for a run whose only actors are these:
+        learner updates between the parameters a rollout read and the newest
+        when it was dispatched, over the interval's dispatches."""
+        total, n, worst = self._stale
+        self._stale = [0, 0, 0]
+        return {
+            "staleness_mean": total / n if n else 0.0,
+            "staleness_max": worst,
+        }
 
     # --- driving ---
 
-    def run_chunk(self, replay) -> int:
+    def run_chunk(self, replay, newest_version: int = 0) -> int:
         """One rollout dispatch: K scan steps x E envs -> [K*E, D] rows ->
         donated scatter into `replay` (DeviceReplay.insert_device_rows).
-        Returns rows produced. Dispatch-time failures with the carry
+        Returns rows produced. `newest_version`: the learner's update count
+        now (note_dispatch). Dispatch-time failures with the carry
         intact restart bounded (module docstring failure contract)."""
         if self._params is None:
             raise DeviceActorError(
@@ -293,8 +393,10 @@ class DeviceActorPool:
                     ) from e
                 continue
             self._stats.record_chunk(self.rows_per_chunk, dt)
+            self.note_dispatch(newest_version)
             self._dispatches += 1
             self._steps += self.rows_per_chunk
+            self._rows_emitted += self.rows_per_chunk
             return self.rows_per_chunk
 
     def _recoverable(self, exc: Exception) -> bool:
@@ -349,8 +451,10 @@ class DeviceActorPool:
         superstep time — the amortization IS the point)."""
         self._carry = carry
         self._stats.record_chunk(self.rows_per_chunk * beats, dur_s)
+        self.note_dispatch(self._params_version)  # the beat's own update
         self._dispatches += 1
         self._steps += self.rows_per_chunk * beats
+        self._rows_emitted += self.rows_per_chunk * beats
 
     # --- rollout-state checkpointing (docs/DEVICE_ACTORS.md) ---
 
@@ -419,8 +523,11 @@ class DeviceActorPool:
             return False
         carry = jax.tree.unflatten(treedef, [jnp.asarray(a) for a in restored])
         self._carry = jax.device_put(carry, self._carry_sharding)
+        self._primed = True  # the window came with the carry
         self._eps_seen = int(jax.device_get(self._carry.episodes))
         self._ret_seen = float(jax.device_get(self._carry.ret_sum))
+        if self.n_step > 1:
+            self._short_seen = int(jax.device_get(self._carry.short_rows))
         # NOTE: the host step mirror (steps_done) stays at 0 — restored
         # production is already counted by the trainer's env_steps_offset,
         # and double-counting would eat the remaining env-step budget. The
@@ -451,8 +558,11 @@ class DeviceActorPool:
         plus the episode stats differenced from the carry's cumulative
         device counters — a two-scalar d2h, paid only at log cadence."""
         out = self._stats.snapshot()
-        eps = int(jax.device_get(self._carry.episodes))
-        ret = float(jax.device_get(self._carry.ret_sum))
+        # One d2h for the carry's counters (short_rows: None at n_step 1).
+        eps, ret, short = jax.device_get(
+            (self._carry.episodes, self._carry.ret_sum, self._carry.short_rows)
+        )
+        eps, ret = int(eps), float(ret)
         d_eps = eps - self._eps_seen
         d_ret = ret - self._ret_seen
         self._eps_seen, self._ret_seen = eps, ret
@@ -461,6 +571,16 @@ class DeviceActorPool:
         if d_eps > 0:
             out["devactor_episode_return"] = round(d_ret / d_eps, 6)
         out["devactor_restarts"] = self._restarts
+        if self.n_step > 1:
+            # Rows emitted in the interval that hold fewer than n steps
+            # (episode ends).
+            short, rows = int(short), self._rows_emitted
+            d_rows = rows - self._rows_seen
+            out["devactor_nstep_short_pct"] = (
+                round(100.0 * (short - self._short_seen) / d_rows, 4)
+                if d_rows > 0 else 0.0
+            )
+            self._short_seen, self._rows_seen = short, rows
         return out
 
 
@@ -482,11 +602,12 @@ def program_specs():
         probe_mesh,
     )
 
-    def build(tp: bool = False):
+    def build(tp: bool = False, **kw):
         def _build():
             config = probe_config(
                 device_actor_envs=4, device_actor_chunk=2,
-                model_axis=2 if tp else 1,
+                model_axis=2 if tp else 1, actor_backend="device",
+                num_actors=0, **kw,
             )
             mesh = probe_mesh(2 if tp else 1)
             pool = DeviceActorPool(config, mesh=mesh)
@@ -509,9 +630,17 @@ def program_specs():
             return BuiltProgram(pool._rollout, (params, pool._carry), (1,))
         return _build
 
+    # PQL's rollout: the 3-step window inside the scan (its rows and flags
+    # donated and aliased through with the rest of the carry) and the
+    # Gaussian ladder in the OU process's place.
+    nstep = dict(n_step=3, exploration="gaussian")
+
     return [
         ProgramSpec("devactor.rollout", "actors/device_pool.py", build()),
         ProgramSpec(
             "devactor.rollout.tp", "actors/device_pool.py", build(tp=True)
+        ),
+        ProgramSpec(
+            "devactor.rollout.nstep", "actors/device_pool.py", build(**nstep)
         ),
     ]

@@ -336,6 +336,16 @@ class DDPGConfig:
     ou_theta: float = 0.15
     ou_sigma: float = 0.2
     ou_dt: float = 1.0
+    # The device pool's exploration process (ops/exploration.py). "ou"
+    # (default): Ornstein-Uhlenbeck with the three fields above. "gaussian":
+    # PQL's mixed exploration (arXiv 2307.12983): environment i of E adds
+    # sigma_i * N(0, I) of its own fixed scale, sigma_i spaced evenly from
+    # explore_sigma_min (environment 0) to explore_sigma_max (the last), in
+    # units of the action box's half-width. Device backend only: host
+    # workers beside the pool keep OU.
+    exploration: str = "ou"
+    explore_sigma_min: float = 0.05
+    explore_sigma_max: float = 0.8
 
     # --- distributed topology ---
     num_actors: int = 1
@@ -1026,6 +1036,23 @@ class DDPGConfig:
             )
         if self.device_actor_envs < 1:
             raise ValueError("device_actor_envs must be >= 1")
+        if self.exploration not in ("ou", "gaussian"):
+            raise ValueError(
+                f"exploration must be 'ou' or 'gaussian', got "
+                f"{self.exploration!r}"
+            )
+        if self.exploration == "gaussian":
+            if self.actor_backend != "device" or self.sac:
+                raise ValueError(
+                    "exploration='gaussian' is the device pool's ladder of "
+                    "per-environment scales (ops/exploration.py): it needs "
+                    "actor_backend='device', and SAC samples its own policy"
+                )
+            if not 0.0 <= self.explore_sigma_min <= self.explore_sigma_max:
+                raise ValueError(
+                    "explore_sigma_min/explore_sigma_max must satisfy "
+                    "0 <= min <= max"
+                )
         if self.device_actor_chunk < 0:
             raise ValueError("device_actor_chunk must be >= 0 (0 = auto)")
         if self.num_actors < 0 or (
@@ -1035,6 +1062,18 @@ class DDPGConfig:
                 "num_actors must be >= 1 (0 is allowed only with "
                 "actor_backend='device', where the on-device rollout loop "
                 "is the experience source and the host pool runs empty)"
+            )
+        from distributed_ddpg_tpu.envs.registry import DEVICE_ONLY
+
+        if (
+            self.env_id in DEVICE_ONLY
+            and self.backend != "jax_ondevice"
+            and (self.actor_backend != "device" or self.num_actors > 0)
+        ):
+            raise ValueError(
+                f"{self.env_id!r} has JAX dynamics only "
+                "(envs/jax_envs.py): a host worker cannot step it — use "
+                "actor_backend='device' with num_actors=0"
             )
         if self.actor_backend == "device":
             if self.backend != "jax_tpu":
@@ -1065,12 +1104,6 @@ class DDPGConfig:
                     "runs inside the rollout program. Disable serve_actors "
                     "(or serve a host pool alongside via actor_backend="
                     "'host')"
-                )
-            if self.n_step != 1:
-                raise ValueError(
-                    "actor_backend='device' stores 1-step transitions "
-                    "(the n-step window is a host-side accumulator, "
-                    "replay/nstep.py); use the host pool for n_step > 1"
                 )
             if self.host_replay:
                 raise ValueError(
@@ -1109,7 +1142,7 @@ class DDPGConfig:
             # The fused megastep composes the device-actor rollout, the
             # device-replay insert, and the learner chunk into one program
             # (docs/FUSED_BEAT.md); every leg must exist. The device-actor
-            # validation above already rejects n_step > 1, serve_actors,
+            # validation above already rejects serve_actors,
             # host_replay, and strict_sync for actor_backend='device', so
             # those combinations fail through their own messages.
             if self.backend != "jax_tpu":

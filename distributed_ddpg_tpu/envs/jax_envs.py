@@ -180,7 +180,96 @@ class JaxMountainCar:
         )
 
 
+class StandInState(NamedTuple):
+    x: jnp.ndarray        # f32[obs_dim] the state, which is the observation
+    t: jnp.ndarray        # i32[] step-in-episode counter
+
+
+class IsaacHumanoidStandIn:
+    """A STAND-IN for Isaac Gym's Humanoid, at its interface and nothing
+    more (obs 108, act 21, actions in [-1, 1], 1,000-step limit): Isaac Gym
+    cannot run here and `mujoco.mjx` is not installed, so the dynamics are a
+    seeded synthetic control system, x' = A x + B u + noise, with A a fixed
+    random rotation scaled by `RHO` < 1 (stable: every direction decays at
+    the same rate, so the inputs accumulate), B a fixed random input map of
+    spectral norm `INPUT_GAIN` and the noise drawn from the step's key. The reward is an alive
+    bonus less a quadratic cost on state and action; the episode TERMINATES
+    when any state component leaves the box |x_i| <= `BOX` (no bootstrap) and
+    is TRUNCATED at the step limit (bootstrap from the pre-reset
+    observation), so a replay fed by it sees both kinds of episode end.
+    A and B come from `MATRIX_SEED`, a constant of the environment, never
+    from a run's seed: every run steps the same system. One step is two
+    matrix-vector products, ~28 kFLOP: far below a physics step's cost, so a
+    run on this environment leaves the simulator's share of the device out
+    (docs/DEVICE_ACTORS.md)."""
+
+    obs_dim = 108
+    act_dim = 21
+    max_episode_steps = 1000
+    action_low = np.full((21,), -1.0, np.float32)
+    action_high = np.full((21,), 1.0, np.float32)
+
+    MATRIX_SEED = 0x15AAC
+    RHO = 0.97           # every singular value of A
+    INPUT_GAIN = 0.3     # spectral norm of B: uniform random actions end
+    #                      about two episodes in three by termination
+    NOISE = 0.02         # process noise, per component and step
+    BOX = 1.0            # |x_i| beyond it ends the episode for good
+    INIT = 0.1           # x_0 ~ U(-INIT, INIT)
+    ALIVE = 1.0
+    STATE_COST = 2.0     # on mean(x^2)
+    ACTION_COST = 0.05   # on mean(u^2)
+
+    def __init__(self):
+        rng = np.random.RandomState(self.MATRIX_SEED)
+        a, _ = np.linalg.qr(rng.standard_normal((self.obs_dim, self.obs_dim)))
+        b = rng.standard_normal((self.act_dim, self.obs_dim))
+        self.a = (self.RHO * a).astype(np.float32)
+        self.b = (self.INPUT_GAIN * b / np.linalg.norm(b, 2)).astype(np.float32)
+
+    def init(self, key) -> StandInState:
+        x = jax.random.uniform(
+            key, (self.obs_dim,), jnp.float32, -self.INIT, self.INIT
+        )
+        return StandInState(x=x, t=jnp.zeros((), jnp.int32))
+
+    def observe(self, s: StandInState) -> jnp.ndarray:
+        return s.x
+
+    def step(self, s: StandInState, action, key):
+        u = jnp.clip(action, -1.0, 1.0)
+        k_noise, k_reset = jax.random.split(key)
+        x = (
+            s.x @ self.a + u @ self.b
+            + self.NOISE * jax.random.normal(k_noise, s.x.shape, jnp.float32)
+        )
+        reward = (
+            self.ALIVE
+            - self.STATE_COST * jnp.mean(jnp.square(x))
+            - self.ACTION_COST * jnp.mean(jnp.square(u))
+        )
+        t = s.t + 1
+        terminated = jnp.max(jnp.abs(x)) > self.BOX
+        done = terminated | (t >= self.max_episode_steps)
+        stepped = StandInState(x=x, t=t)
+        fresh = self.init(k_reset)
+        nxt = StandInState(
+            x=jnp.where(done, fresh.x, x), t=jnp.where(done, fresh.t, t)
+        )
+        return StepOut(
+            state=nxt,
+            obs=self.observe(nxt),
+            boot_obs=self.observe(stepped),
+            reward=reward.astype(jnp.float32),
+            done=done,
+            terminated=terminated,
+        )
+
+
+STAND_IN_ID = "IsaacHumanoidStandIn-v0"
+
 _JAX_ENVS = {
+    STAND_IN_ID: IsaacHumanoidStandIn,
     "Pendulum-v1": JaxPendulum,
     "builtin/Pendulum-v1": JaxPendulum,
     "MountainCarContinuous-v0": JaxMountainCar,

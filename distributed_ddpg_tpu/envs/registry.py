@@ -32,6 +32,12 @@ _VERSION_ALIASES = {
 }
 
 
+# Ids with JAX dynamics only (envs/jax_envs.py): `make` wraps them for the
+# trainer's own use (the spec, an evaluation); a worker process, which must
+# never import JAX, cannot step them (config.py refuses that at parse).
+DEVICE_ONLY = frozenset({"IsaacHumanoidStandIn-v0"})
+
+
 class EnvSpec(NamedTuple):
     obs_dim: int
     act_dim: int
@@ -88,7 +94,59 @@ class _GymnasiumAdapter:
         self._env.close()
 
 
+class _JaxEnvAdapter:
+    """A functional JAX env (envs/jax_envs.py) behind the gymnasium 5-tuple
+    API, stepped on the host's CPU backend whatever the default device is."""
+
+    def __init__(self, env_id: str, seed: int = 0):
+        import jax
+
+        from distributed_ddpg_tpu.envs.jax_envs import make_jax_env
+
+        self._jax = jax
+        self._env = make_jax_env(env_id)
+        self._cpu = jax.devices("cpu")[0]
+        self._step = jax.jit(self._env.step)
+        with jax.default_device(self._cpu):
+            self._key = jax.random.PRNGKey(seed)
+        self._state = None
+        self.observation_dim = self._env.obs_dim
+        self.action_dim = self._env.act_dim
+        self.action_low = np.asarray(self._env.action_low, np.float32)
+        self.action_high = np.asarray(self._env.action_high, np.float32)
+
+    def _next_key(self):
+        self._key, sub = self._jax.random.split(self._key)
+        return sub
+
+    def reset(self, seed: int | None = None):
+        with self._jax.default_device(self._cpu):
+            if seed is not None:
+                self._key = self._jax.random.PRNGKey(seed)
+            self._state = self._env.init(self._next_key())
+            return np.asarray(self._env.observe(self._state)), {}
+
+    def step(self, action):
+        with self._jax.default_device(self._cpu):
+            out = self._step(
+                self._state, np.asarray(action, np.float32), self._next_key()
+            )
+        # Auto-reset has already happened inside `out.state`: the caller
+        # resets at an episode's end, as with any gymnasium env.
+        self._state = out.state
+        terminated = bool(out.terminated)
+        return (
+            np.asarray(out.boot_obs), float(out.reward), terminated,
+            bool(out.done) and not terminated, {},
+        )
+
+    def close(self):
+        pass
+
+
 def make(env_id: str, seed: int = 0, prefer_builtin: bool = False):
+    if env_id in DEVICE_ONLY:
+        return _JaxEnvAdapter(env_id, seed=seed)
     if env_id in _BUILTIN and (prefer_builtin or not _has_gymnasium()):
         return _BUILTIN[env_id](seed=seed)
     if _has_gymnasium():

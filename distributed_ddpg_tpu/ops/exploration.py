@@ -24,10 +24,83 @@ backends.
 
 from __future__ import annotations
 
+from typing import NamedTuple
+
 import jax
 import jax.numpy as jnp
 
 from distributed_ddpg_tpu.models.mlp import actor_apply
+from distributed_ddpg_tpu.trace import device_scope
+
+
+def sigma_ladder(cfg, num_envs: int):
+    """PQL's mixed exploration (arXiv 2307.12983): environment i of E keeps
+    its own fixed Gaussian scale, evenly spaced from explore_sigma_min
+    (environment 0) to explore_sigma_max (environment E - 1): f32[E]."""
+    return jnp.linspace(
+        cfg.explore_sigma_min, cfg.explore_sigma_max, num_envs,
+        dtype=jnp.float32,
+    )
+
+
+class NStepWindow(NamedTuple):
+    """The n - 1 rows each environment has begun and not yet emitted,
+    oldest first, in the packed row layout: a row's reward column holds the
+    return folded so far, its discount column gamma^m over the m steps
+    folded (0 once a step truly terminated), its next_obs the newest
+    bootstrap observation."""
+
+    rows: jnp.ndarray    # f32[E, n - 1, D]
+    closed: jnp.ndarray  # bool[E, n - 1] the row's episode ended: it folds no more
+
+
+def nstep_window(num_envs: int, n_step: int, width: int) -> NStepWindow:
+    """The window before any step: every slot closed over zeros, rows that
+    the pool's priming steps emit and drop (actors/device_pool.py)."""
+    return NStepWindow(
+        rows=jnp.zeros((num_envs, n_step - 1, width), jnp.float32),
+        closed=jnp.ones((num_envs, n_step - 1), bool),
+    )
+
+
+def nstep_fold(window: NStepWindow, rows, out, gamma, obs_dim, act_dim):
+    """One step of the n-step fold over E environments: the rows the host
+    accumulator emits (replay/nstep.py with the worker's truncation flush),
+    one a step and environment. `rows` are this step's packed 1-step rows
+    and `out` its StepOut. Every open row of the window takes the step in
+    (return += discount * reward, discount *= gamma, or 0 at a true
+    termination, next_obs = the step's bootstrap observation) and closes
+    where the episode ended; the oldest row leaves, n steps after it began
+    whether it holds n steps or fewer, and this step's row joins.
+
+    Returns (window, emitted f32[E, D], short bool[E]: the emitted row
+    holds fewer than n steps)."""
+    r_col = obs_dim + act_dim
+    pend, closed = window.rows, window.closed
+    disc = pend[..., r_col + 1]
+    alive = 1.0 - out.terminated.astype(jnp.float32)
+    taken = jnp.concatenate(
+        [
+            pend[..., :r_col],
+            (pend[..., r_col] + disc * out.reward[:, None])[..., None],
+            (disc * gamma * alive[:, None])[..., None],
+            jnp.broadcast_to(
+                out.boot_obs[:, None, :], (*closed.shape, obs_dim)
+            ),
+            pend[..., -1:],
+        ],
+        axis=-1,
+    )
+    pend = jnp.where(closed[..., None], pend, taken)
+    every = jnp.concatenate([pend, rows[:, None, :]], axis=1)
+    ended = jnp.concatenate(
+        [closed | out.done[:, None], out.done[:, None]], axis=1
+    )
+    return (
+        NStepWindow(rows=every[:, 1:], closed=ended[:, 1:]),
+        every[:, 0],
+        closed[:, 0],
+    )
 
 
 def vector_env_step(
@@ -57,42 +130,58 @@ def vector_env_step(
     mean, and `rows` is the packed f32[num_envs, D] transition block."""
     E = num_envs
     next_key, k_ou, k_env, k_uni = jax.random.split(key, 4)
-    if cfg.sac:
-        # SAC explores by sampling its own tanh-Gaussian on device; the
-        # OU state rides along untouched (zeros — worker.py parity).
-        from distributed_ddpg_tpu.models.mlp import actor_gaussian_apply
-        from distributed_ddpg_tpu.ops import losses as losses_lib
+    with device_scope("policy"):
+        if cfg.sac:
+            # SAC explores by sampling its own tanh-Gaussian on device; the
+            # OU state rides along untouched (zeros — worker.py parity).
+            from distributed_ddpg_tpu.models.mlp import actor_gaussian_apply
+            from distributed_ddpg_tpu.ops import losses as losses_lib
 
-        mean, log_std = actor_gaussian_apply(
-            params, obs, cfg.sac_log_std_min, cfg.sac_log_std_max
+            mean, log_std = actor_gaussian_apply(
+                params, obs, cfg.sac_log_std_min, cfg.sac_log_std_max
+            )
+            sampled, _ = losses_lib.sac_sample(
+                mean, log_std, jax.random.normal(k_ou, mean.shape), scale, offset
+            )
+            action = jnp.clip(sampled, low, high)
+            new_ou = ou
+        elif cfg.exploration == "gaussian":
+            # PQL's ladder: a fixed scale per environment, no state between
+            # steps (the OU state rides along untouched).
+            action = jnp.clip(
+                actor_apply(params, obs, scale, offset)
+                + sigma_ladder(cfg, E)[:, None]
+                * jax.random.normal(k_ou, ou.shape, jnp.float32)
+                * scale,
+                low,
+                high,
+            )
+            new_ou = ou
+        else:
+            new_ou = (
+                ou
+                + cfg.ou_theta * (0.0 - ou) * cfg.ou_dt
+                + cfg.ou_sigma
+                * jnp.sqrt(cfg.ou_dt)
+                * jax.random.normal(k_ou, ou.shape, jnp.float32)
+            )
+            action = jnp.clip(
+                actor_apply(params, obs, scale, offset) + new_ou * scale,
+                low,
+                high,
+            )
+        if warmup_active is not None:
+            action = jnp.where(
+                warmup_active,
+                jax.random.uniform(
+                    k_uni, action.shape, jnp.float32, minval=low, maxval=high
+                ),
+                action,
+            )
+    with device_scope("env"):
+        out = jax.vmap(env.step)(
+            env_state, action, jax.random.split(k_env, E)
         )
-        sampled, _ = losses_lib.sac_sample(
-            mean, log_std, jax.random.normal(k_ou, mean.shape), scale, offset
-        )
-        action = jnp.clip(sampled, low, high)
-        new_ou = ou
-    else:
-        new_ou = (
-            ou
-            + cfg.ou_theta * (0.0 - ou) * cfg.ou_dt
-            + cfg.ou_sigma
-            * jnp.sqrt(cfg.ou_dt)
-            * jax.random.normal(k_ou, ou.shape, jnp.float32)
-        )
-        action = jnp.clip(
-            actor_apply(params, obs, scale, offset) + new_ou * scale,
-            low,
-            high,
-        )
-    if warmup_active is not None:
-        action = jnp.where(
-            warmup_active,
-            jax.random.uniform(
-                k_uni, action.shape, jnp.float32, minval=low, maxval=high
-            ),
-            action,
-        )
-    out = jax.vmap(env.step)(env_state, action, jax.random.split(k_env, E))
     # Packed rows in types.pack_batch_np order; discount 0 where the env
     # truly terminated, truncation keeps bootstrapping.
     discount = cfg.gamma * (

@@ -1309,17 +1309,29 @@ def program_specs():
         action_insert_layer=0,
     )
 
+    # PQL's chunk (twin critics, no smoothing noise so no noise operand,
+    # the actor and all three targets under the delay's cond, the action
+    # at the critics' input, three hidden layers), as the benchmark's cell
+    # runs it.
+    PQL = dict(
+        twin_critic=True, policy_delay=2, target_noise=0.0, n_step=3,
+        action_insert_layer=0, actor_hidden=(16, 16, 8),
+        critic_hidden=(16, 16, 8), tau=0.05,
+    )
+
     def learner(
         guard: bool = False, sharded: bool = False, tp: bool = False,
         ensemble: bool = False, mode: str = "auto", crossq: bool = False,
+        pql: bool = False,
     ) -> ShardedLearner:
-        key = (guard, sharded, tp, ensemble, mode, crossq)
+        key = (guard, sharded, tp, ensemble, mode, crossq, pql)
         if key not in cache:
             cache[key] = ShardedLearner(
                 probe_config(
                     guardrails=guard, model_axis=2 if tp else 1,
                     **(ENSEMBLE if ensemble else {}),
                     **(CROSSQ if crossq else {}),
+                    **(PQL if pql else {}),
                 ),
                 obs_dim=3,
                 act_dim=1,
@@ -1460,6 +1472,12 @@ def program_specs():
         ProgramSpec(
             "learner.chunk.uniform.crossq", OWNER,
             uniform(False, sharded=False, crossq=True),
+        )
+    )
+    specs.append(
+        ProgramSpec(
+            "learner.chunk.uniform.pql", OWNER,
+            uniform(False, sharded=False, pql=True),
         )
     )
     return specs

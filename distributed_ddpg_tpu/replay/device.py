@@ -662,6 +662,11 @@ class DeviceReplay:
         )
         self._src_fifo: deque = deque()  # mutable [source, rows] run-lengths
         self._host_ptr = 0
+        # Rows landed since this ring was built or restored, counted on the
+        # host at each successful ship, and the write pointer it began at:
+        # `ring_wraps` (ingest_snapshot) reads them, with no d2h.
+        self._rows_landed = 0
+        self._ptr_start = 0
         self._proc_idx = jax.process_index() if self._procs > 1 else 0
 
         # Background shipper (single-process only: multi-host rows may
@@ -800,6 +805,11 @@ class DeviceReplay:
                 fill=len(self),
             )
         )
+        # Times the write pointer has passed the ring's end in this run: 0
+        # while the ring fills, 1 from the moment a draw ranges over all of it.
+        out["ring_wraps"] = (
+            (self._ptr_start + self._rows_landed) // self.capacity
+        )
         out["replay_ring_layout"] = self.ring_layout
         out["replay_row_bytes_device"] = self.row_bytes_device
         return out
@@ -916,6 +926,7 @@ class DeviceReplay:
         offsets from the pre-ship pointer) get `srcs`, everything else in
         the advanced range is marked untracked (-1) — other processes'
         interleave slots, padding."""
+        self._rows_landed += advance
         if not self._track_sources:
             return
         pos_all = (self._host_ptr + np.arange(advance)) % self.capacity
@@ -1840,6 +1851,8 @@ class DeviceReplay:
                 int(state["ptr"]) % self.capacity
             )
             self.size = self._replicated_scalar(n)
+            self._ptr_start = int(state["ptr"]) % self.capacity
+            self._rows_landed = 0
             if self._track_sources:
                 self._source_map.fill(-1)
                 self._src_fifo.clear()
@@ -1872,6 +1885,8 @@ class DeviceReplay:
                 scalar = NamedSharding(self._mesh, P())
                 self.ptr = jax.device_put(self.ptr, scalar)
                 self.size = jax.device_put(self.size, scalar)
+            self._ptr_start = int(state["ptr"]) % self.capacity
+            self._rows_landed = 0
             if self._track_sources:
                 # Restored rows carry no attribution; re-sync the pointer
                 # mirror with the restored device ptr.

@@ -465,11 +465,21 @@ CHUNK_SCOPES = (
     "metrics",        # the chunk's metrics out of the K updates'
     "priority",       # PER's priority write-back and maximum
 )
+# The device pool's rollout program (actors/device_pool.py) brackets its
+# parts in the same manner, under a vocabulary of its own: the op table
+# above is the chunk program's and reads none of these words.
+ROLLOUT_SCOPES = (
+    "rollout",         # the K-step scan over E environments
+    "rollout/policy",  # mu(s) and the exploration noise
+    "rollout/env",     # the vmapped environment step, auto-reset included
+    "rollout/fold",    # the n-step window: fold, flush, the emitted row
+)
 # What a collective instruction reads as, whatever scope it served.
 COLLECTIVE = "collective"
 CHUNK_OPS_FILE = "chunk_ops.json"
 
 _SCOPE_WORDS = frozenset(w for s in CHUNK_SCOPES for w in s.split("/"))
+_ROLLOUT_WORDS = frozenset(w for s in ROLLOUT_SCOPES for w in s.split("/"))
 _COLLECTIVE_OPCODES = frozenset({
     "all-reduce", "all-gather", "reduce-scatter", "collective-permute",
     "all-to-all",
@@ -491,11 +501,14 @@ _RUN = re.compile(
 
 
 def device_scope(word: str):
-    """`jax.named_scope(word)` for one word of CHUNK_SCOPES: the bracket
-    the chunk programs' parts are traced under. Nested brackets join with
+    """`jax.named_scope(word)` for one word of CHUNK_SCOPES (or of
+    ROLLOUT_SCOPES, in the rollout program): the bracket the chunk
+    programs' parts are traced under. Nested brackets join with
     `/` (`optim` entered inside `update` reads `update/optim`)."""
-    if word not in _SCOPE_WORDS:
-        raise ValueError(f"{word!r} is no word of trace.CHUNK_SCOPES")
+    if word not in _SCOPE_WORDS and word not in _ROLLOUT_WORDS:
+        raise ValueError(
+            f"{word!r} is no word of trace.CHUNK_SCOPES or ROLLOUT_SCOPES"
+        )
     import jax  # traced code only: this module's importers may not load JAX
 
     return jax.named_scope(word)

@@ -1144,14 +1144,19 @@ def _train_jax_impl(
             ),
             warmup_offset=env_steps_offset,
         )
-        device_pool.set_params(learner.state.actor_params)
         if "devactor_carry" in ckpt_meta:
             # Rollout-state resume (docs/DEVICE_ACTORS.md): restore the
-            # pool's env carry + OU state so a resumed device-actor run
-            # CONTINUES its episodes instead of restarting E fresh ones
-            # (shape-validated; a changed E/env falls back to fresh).
+            # pool's env carry + OU state (+ n-step window) so a resumed
+            # device-actor run CONTINUES its episodes instead of restarting
+            # E fresh ones (shape-validated; a changed E/env falls back to
+            # fresh). Before the first swap, which primes a window that no
+            # carry brought.
             device_pool.load_carry_state(ckpt_meta["devactor_carry"])
+        device_pool.set_params(learner.state.actor_params, learn_steps)
         _beat()  # rollout-program construction survived
+    # Whether any host worker acts: with none (a device-only run) nobody
+    # reads a broadcast, and the refresh is the pool's pointer swap.
+    host_actors = config.num_actors > 0
 
     # --- fused training megastep (parallel/megastep.py; docs/FUSED_BEAT.md) ---
     # config.fused_beat: compile rollout + ring scatter + sample + the K
@@ -1366,6 +1371,11 @@ def _train_jax_impl(
         if config.crossq:
             # CrossQ runs only, and from the state: no target net is held.
             facts["crossq"] = learner.state.target_critic_params is None
+        if device_pool is not None and device_pool.sigma_ends is not None:
+            # The Gaussian ladder's ends, as the rollout program holds them.
+            facts["devactor_sigma_min"], facts["devactor_sigma_max"] = (
+                device_pool.sigma_ends
+            )
         return facts
 
     log = MetricsLogger(config.log_path, header=run_facts())
@@ -1759,12 +1769,13 @@ def _train_jax_impl(
             # race (a crash before the next clean save would otherwise
             # restore exactly the state just rolled away from).
             ckpt_lib.discard_above(config.checkpoint_dir, step)
-        with phases.phase("refresh"):
-            pool.broadcast(learner.actor_params_to_host(), learn_steps)
+        if host_actors:
+            with phases.phase("refresh"):
+                pool.broadcast(learner.actor_params_to_host(), learn_steps)
         if device_pool is not None:
             # The restored state is a fresh tree; swap the rollout's live
             # param pointer so the repaired policy acts immediately.
-            device_pool.set_params(learner.state.actor_params)
+            device_pool.set_params(learner.state.actor_params, learn_steps)
             if "devactor_carry" in ckpt_meta:
                 # Roll the rollout state back with the learner: episodes
                 # continue from the restored point, not from E resets.
@@ -1989,6 +2000,10 @@ def _train_jax_impl(
             basis = budget_now
             if basis is None:
                 basis = cached_global[0] if is_multi else env_steps()
+            # The allowance is in rows: steps still in the envs' n-step
+            # windows have written none yet (and a warm-up that counted
+            # them could close the gate short of min_fill for good).
+            basis -= device_pool.pending_rows
             # Any remaining allowance admits ONE chunk (bounded overshoot
             # of rows_per_chunk - 1, the host drain's one-queue-batch
             # semantics): an all-or-nothing gate would wedge warmup
@@ -2003,7 +2018,7 @@ def _train_jax_impl(
             # docs/TRANSFER.md token protocol). No-op when none pending.
             wait_beat()
         with phases.phase("devactor"):
-            rows = device_pool.run_chunk(device_replay)
+            rows = device_pool.run_chunk(device_replay, learn_steps)
         env_timer.tick(rows)
         return rows
 
@@ -2092,7 +2107,17 @@ def _train_jax_impl(
             # re-done every chunk because the dispatch above DONATED the
             # previous TrainState (the stale tree is deleted — dispatching
             # a rollout against it would raise). Free: no copy, no d2h.
-            device_pool.set_params(learner.state.actor_params)
+            # With no host worker this swap IS the topology's refresh, so
+            # the `refresh` phase brackets it and the broadcast below (a
+            # d2h that waits out the launch queue, for nobody) is not
+            # issued.
+            with (
+                phases.phase("refresh", learner_step=learn_steps)
+                if not host_actors else contextlib.nullcontext()
+            ):
+                device_pool.set_params(
+                    learner.state.actor_params, learn_steps
+                )
         if guard_on and _guardrail_monitor():
             # Rolled back (or numeric-aborted): this chunk's `out` is
             # moot, the rollback already rebroadcast params, and skipping
@@ -2134,7 +2159,7 @@ def _train_jax_impl(
         # chunks log) a function of host timing instead of the config,
         # breaking the bit-identical-two-runs contract.
         now = time.perf_counter()
-        if learn_steps >= next_refresh and (
+        if host_actors and learn_steps >= next_refresh and (
             config.strict_sync
             or now - last_refresh_t >= config.param_refresh_interval_s
         ):
@@ -2262,7 +2287,7 @@ def _train_jax_impl(
                 actor_steps_per_sec=env_timer.rate(),
                 buffer_fill=buffer_fill(),
                 episode_return=mean_ret,
-                **pool.staleness(),
+                **(pool if host_actors else device_pool).staleness(),
                 **pool.nstep_counters(),
                 **recovery_fields(),
                 **chunk_metrics,
@@ -2927,6 +2952,16 @@ def _train_jax_impl(
         "buffer_fill": buffer_fill(),
         **ingest_final,
         **mesh_final,
+        # ... or, for the device pool's steps, still in the envs' n-step
+        # windows: taken, counted, and in no ring yet.
+        **(
+            {
+                "ingest_queue_rows": ingest_final.get("ingest_queue_rows", 0)
+                + device_pool.pending_rows
+            }
+            if device_pool is not None and device_pool.pending_rows
+            else {}
+        ),
         # A pod abort reuses the preemption machinery but is its OWN
         # documented exit (76 vs 75) — report exactly one of the two.
         "preempted": preempt.is_set() and pod_lost[0] is None,

@@ -38,6 +38,7 @@ RING_LAYOUT = {
     "td3-halfcheetah": "packed",
     "redq-humanoid": "row_major",
     "crossq-humanoid": "row_major",
+    "pql-isaac-humanoid": "row_major",
 }
 
 

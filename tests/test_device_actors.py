@@ -206,8 +206,7 @@ def test_config_validation_rejects_unsupported_combos():
         _small_cfg(serve_actors=True, num_actors=1)
     with pytest.raises(ValueError, match="jax_tpu"):
         DDPGConfig(actor_backend="device", backend="native")
-    with pytest.raises(ValueError, match="n_step"):
-        _small_cfg(n_step=3)
+    assert _small_cfg(n_step=3).n_step == 3  # PR 40: the pool folds n steps itself
     with pytest.raises(ValueError, match="host_replay"):
         _small_cfg(host_replay=True)
     with pytest.raises(ValueError, match="strict_sync"):

@@ -253,10 +253,10 @@ def test_fused_beat_config_validation():
     # The ratio gates need independently dispatchable phases.
     with pytest.raises(ValueError, match="ratio"):
         _cfg(fused_beat="on", max_ingest_ratio=1.0, max_learn_ratio=1.0)
-    # n_step > 1 / serve_actors fail through the device-actor validation
-    # the fused beat builds on.
-    with pytest.raises(ValueError, match="n_step"):
-        _cfg(fused_beat="on", n_step=3)
+    # serve_actors fails through the device-actor validation the fused
+    # beat builds on; n_step > 1 no longer does (PR 40): the window folds
+    # inside the rollout body the beat composes.
+    assert _cfg(fused_beat="on", n_step=3).n_step == 3
     with pytest.raises(ValueError, match="serve"):
         _cfg(fused_beat="on", serve_actors=True)
     # The native backend has no device programs to fuse.
