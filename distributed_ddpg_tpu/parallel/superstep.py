@@ -330,7 +330,7 @@ class FusedSuperstep:
                 L.state = out.state
                 L._key = key
                 replay.storage, replay.ptr, replay.size = storage, ptr, size
-                replay.note_device_rows(B * self.rows_per_beat)
+                replay.note_device_rows(self.rows_per_beat, inserts=B)
             dt = time.perf_counter() - t0
         pool.absorb_fused_chunk(carry, dt, beats=B)
         self._stats.record_beat(

@@ -9,9 +9,11 @@ WHOLE buffer fits HBM trivially
 (1M transitions x 43 f32 = 172MB on a 16GB v5e), so this module keeps the
 packed [capacity, D] ring in device memory:
 
-  - `insert`: one jitted scatter (mod-capacity wraparound) of a packed
-    [M, D] block; the only steady-state h2d traffic is fresh actor data,
-    in bulk, ~1 transfer per thousands of env steps.
+  - `insert`: one jitted, donated write of a packed [M, D] block at the
+    ring's pointer (ring_write: a slice update where the block ends inside
+    the ring, a mod-capacity scatter where it wraps); the only steady-state
+    h2d traffic is fresh actor data, in bulk, ~1 transfer per thousands of
+    env steps.
   - sampling: fused INTO the scanned learner chunk (parallel/learner.py
     sample_chunk path) — jax.random indices + gather per scan step, so a
     K-step chunk needs ZERO transfers in and only td/metrics out.
@@ -261,15 +263,49 @@ class PackedRing:
         return PackedRing(self.lines.at[line].set(overlaid), self.width, cap)
 
 
+def run_fits(ptr, m: int, capacity: int, in_order: bool = True):
+    """ring_write's rule for a plain ring, in the one place both its program
+    and the host's count of what it did (DeviceReplay._note_shipped) read
+    it: False where an m-row block is no run of consecutive ring rows (its
+    rows permuted, or more of them than the ring holds), else whether the
+    run that starts at `ptr` ends at or before the ring's last row: a traced
+    bool of a traced pointer, a Python bool of the host's mirror."""
+    if not in_order or m > capacity:
+        return False
+    return ptr + m <= capacity
+
+
 def ring_write(storage, block, ptr, offset=None):
     """The one ring insert: `storage` with block row j at logical row (ptr +
     offset[j]) % capacity (offset: a permutation of arange(m), default
-    itself), for a PackedRing and for a plain [capacity, width] array."""
+    itself), for a PackedRing and for a plain [capacity, width] array.
+
+    A plain ring takes a block in ring order (no `offset`, m <= capacity)
+    that ends at or before the ring's last row as what it is, one run of
+    consecutive rows: a dynamic-update-slice at `ptr`, the speed of a copy,
+    where m computed indices make a row scatter (12 GB/s for 1 KB rows on a
+    v5e; PERF.md PR 41). Only a block that passes the ring's end is
+    scattered, under the other branch of a `cond` on `ptr`; XLA updates the
+    donated ring in place under either branch."""
     if isinstance(storage, PackedRing):
         return storage.write(block, ptr, offset)
-    if offset is None:
-        offset = jnp.arange(block.shape[0], dtype=jnp.int32)
-    return storage.at[(ptr + offset) % storage.shape[0]].set(block)
+    m, capacity = block.shape[0], storage.shape[0]
+    in_order = offset is None
+    if in_order:
+        offset = jnp.arange(m, dtype=jnp.int32)
+    fits = run_fits(ptr, m, capacity, in_order)
+
+    def scatter(storage):
+        return storage.at[(ptr + offset) % capacity].set(block)
+
+    if fits is False:
+        return scatter(storage)
+    return jax.lax.cond(
+        fits,
+        lambda storage: jax.lax.dynamic_update_slice(storage, block, (ptr, 0)),
+        scatter,
+        storage,
+    )
 
 
 def ring_format(sharding, width: int):
@@ -664,9 +700,19 @@ class DeviceReplay:
         self._host_ptr = 0
         # Rows landed since this ring was built or restored, counted on the
         # host at each successful ship, and the write pointer it began at:
-        # `ring_wraps` (ingest_snapshot) reads them, with no d2h.
+        # `ring_wraps` (ingest_snapshot) reads them, with no d2h. From the
+        # same two, at each landed insert (_note_shipped): how many passed
+        # the ring's end (`replay_insert_wrapped`, any layout) and how many
+        # ring_write wrote as one run (`replay_insert_runs`, by its own
+        # rule, run_fits, where the insert programs go through its plain
+        # branch: the sharded ones do not, and a packed ring has its own).
         self._rows_landed = 0
         self._ptr_start = 0
+        self._insert_runs = 0
+        self._inserts_wrapped = 0
+        self._plain_ring_write = not (
+            self.sharded or isinstance(self.storage, PackedRing)
+        )
         self._proc_idx = jax.process_index() if self._procs > 1 else 0
 
         # Background shipper (single-process only: multi-host rows may
@@ -810,6 +856,8 @@ class DeviceReplay:
         out["ring_wraps"] = (
             (self._ptr_start + self._rows_landed) // self.capacity
         )
+        out["replay_insert_runs"] = self._insert_runs
+        out["replay_insert_wrapped"] = self._inserts_wrapped
         out["replay_ring_layout"] = self.ring_layout
         out["replay_row_bytes_device"] = self.row_bytes_device
         return out
@@ -920,12 +968,19 @@ class DeviceReplay:
         return out
 
     def _note_shipped(self, srcs: Optional[np.ndarray],
-                      offsets: Optional[np.ndarray], advance: int) -> None:
+                      offsets: Optional[np.ndarray], advance: int,
+                      in_order: bool = True) -> None:
         """Advance the host insert-pointer mirror past one SUCCESSFUL ship
-        of `advance` rows and stamp the landed positions: `offsets` (row
+        of `advance` rows (ONE insert program; `in_order`: it handed
+        ring_write no `offset`) and stamp the landed positions: `offsets` (row
         offsets from the pre-ship pointer) get `srcs`, everything else in
         the advanced range is marked untracked (-1) — other processes'
         interleave slots, padding."""
+        start = (self._ptr_start + self._rows_landed) % self.capacity
+        self._inserts_wrapped += start + advance > self.capacity
+        self._insert_runs += self._plain_ring_write and run_fits(
+            start, advance, self.capacity, in_order
+        )
         self._rows_landed += advance
         if not self._track_sources:
             return
@@ -1015,8 +1070,7 @@ class DeviceReplay:
             # like the device ptr, so the mirror can never drift on the
             # bounded-restart path (the popped rows AND their source tags
             # are lost together).
-            if srcs is not None:
-                self._note_shipped(srcs, np.arange(n), n)
+            self._note_shipped(srcs, None if srcs is None else np.arange(n), n)
             if buf is not None:
                 # Fence on the insert's OUTPUT: the buffer recirculates
                 # only after the op that read the transferred chunk has
@@ -1128,7 +1182,7 @@ class DeviceReplay:
 
     def insert_device_rows(self, rows) -> int:
         """Land an ALREADY-DEVICE-RESIDENT [M, D] block with the donated
-        jitted scatter — the device-actor path (actors/device_pool.py;
+        jitted insert — the device-actor path (actors/device_pool.py;
         docs/DEVICE_ACTORS.md). The rows never touch the host: no staging
         ring, no transfer-scheduler ingest class, no IngestStats traffic —
         the devactor_* family accounts for this source instead, and a
@@ -1213,15 +1267,15 @@ class DeviceReplay:
                 with trace.span("ingest_flush", rows=n):
                     self._ship(chunk)
                 self._stats.record_ship(n, 1, time.perf_counter() - t0)
-                if srcs is not None:
-                    # Padding repeats real rows, so the copies inherit the
-                    # originals' source tags (a poisoned row's duplicate
-                    # is just as attributable).
-                    self._note_shipped(
-                        np.tile(srcs, reps)[: self.block_size],
-                        np.arange(self.block_size),
-                        self.block_size,
-                    )
+                # Padding repeats real rows, so the copies inherit the
+                # originals' source tags (a poisoned row's duplicate is
+                # just as attributable).
+                self._note_shipped(
+                    None if srcs is None
+                    else np.tile(srcs, reps)[: self.block_size],
+                    np.arange(self.block_size),
+                    self.block_size,
+                )
 
     def sync_ship(self, force: bool = False) -> int:
         """Multi-host-safe ingest step. ALL processes must call this at the
@@ -1335,6 +1389,7 @@ class DeviceReplay:
                     self._stats.record_ship(
                         k * self.block_size, k, time.perf_counter() - t0
                     )
+                    offsets = None
                     if srcs is not None:
                         # This process's k blocks land interleaved at
                         # offsets j*(procs*bs) + p*bs + r (the permuted
@@ -1349,7 +1404,10 @@ class DeviceReplay:
                             + p * bs
                             + np.arange(bs)[None, :]
                         ).reshape(-1)
-                        self._note_shipped(srcs, offsets, procs * k * bs)
+                    self._note_shipped(
+                        srcs, offsets, self._procs * k * self.block_size,
+                        in_order=k == 1,  # _get_global_insert's offset
+                    )
                     moved += k * self.block_size
                     remaining -= k
                 if force and m % self.block_size:
@@ -1369,15 +1427,14 @@ class DeviceReplay:
                     self._stats.record_ship(
                         take, 1, time.perf_counter() - t0
                     )
-                    if srcs is not None:
-                        bs, procs, p = (
-                            self.block_size, self._procs, self._proc_idx,
-                        )
-                        self._note_shipped(
-                            np.tile(srcs, reps)[:bs],
-                            p * bs + np.arange(bs),
-                            procs * bs,
-                        )
+                    bs, procs, p = (
+                        self.block_size, self._procs, self._proc_idx,
+                    )
+                    self._note_shipped(
+                        None if srcs is None else np.tile(srcs, reps)[:bs],
+                        p * bs + np.arange(bs),
+                        procs * bs,
+                    )
                     moved += take
         return moved
 
@@ -1490,12 +1547,14 @@ class DeviceReplay:
             )
         return self._make_insert_replrows_body(m)
 
-    def note_device_rows(self, m: int) -> None:
-        """Advance the host-side source-attribution mirror past m device-
-        produced rows landed by an EXTERNAL program's in-program insert
-        (the fused megastep) — the same bookkeeping insert_device_rows
-        does after its own scatter. Caller holds dispatch_lock."""
-        self._note_shipped(None, None, m)
+    def note_device_rows(self, m: int, inserts: int = 1) -> None:
+        """Advance the host-side source-attribution mirror past `inserts`
+        in-program inserts of m device-produced rows each, landed by an
+        EXTERNAL program (the fused megastep) — the same bookkeeping
+        insert_device_rows does after its own insert. Caller holds
+        dispatch_lock."""
+        for _ in range(inserts):
+            self._note_shipped(None, None, m)
 
     def _get_insert_replrows(self, m: int):
         """Compiled sharded insert for an m-row REPLICATED device block

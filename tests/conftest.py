@@ -10,6 +10,8 @@ this is the "CPU was asked for" that train.require_platform honours.
 
 import os
 
+import pytest
+
 flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in flags:
     os.environ["XLA_FLAGS"] = (
@@ -23,3 +25,18 @@ jax.config.update("jax_platforms", "cpu")
 # parallel/mesh.py): set here too so tests that touch jax.random before
 # importing parallel.mesh trace under the same scheme.
 jax.config.update("jax_threefry_partitionable", True)
+
+
+@pytest.fixture
+def one_chip(monkeypatch):
+    """train() on the first device alone, the deployment's topology: the
+    virtual 8-device mesh would put collectives into the rollout and the chunk
+    programs, and XLA:CPU can deadlock two such programs in flight when the
+    host's threads are scarce (a rendezvous of 8 that never fills, and the
+    process aborts after 40 s; PERF.md §7, 24). For tests that drive train()
+    with a device pool and the unfused loop; not autouse."""
+    from distributed_ddpg_tpu.parallel import mesh as mesh_lib
+
+    make = mesh_lib.make_mesh
+    monkeypatch.setattr(
+        mesh_lib, "make_mesh", lambda data_axis=-1, model_axis=1, devices=None: make(1, 1, jax.devices()[:1]))

@@ -45,17 +45,6 @@ def one_device_mesh():
     return mesh_lib.make_mesh(data_axis=1, model_axis=1, devices=jax.devices()[:1])
 
 
-@pytest.fixture
-def one_chip(monkeypatch):
-    """train() on the first device alone, the deployment's topology: the
-    virtual 8-device mesh would put collectives into the rollout and the chunk
-    programs, and XLA:CPU can deadlock two such programs in flight when the
-    host's threads are scarce (a rendezvous of 8 that never fills)."""
-    make = mesh_lib.make_mesh
-    monkeypatch.setattr(
-        mesh_lib, "make_mesh", lambda data_axis=-1, model_axis=1, devices=None: make(1, 1, jax.devices()[:1]))
-
-
 def pool_and_ring(config, mesh):
     pool = DeviceActorPool(config, mesh=mesh)
     pool.set_params(init_train_state(config, OBS, ACT, config.seed).actor_params)

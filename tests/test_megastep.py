@@ -313,14 +313,21 @@ def test_train_fused_beat_with_guardrails(tmp_path):
     assert out["devactor_env_steps"] > 0
 
 
-def test_train_fused_vs_unfused_identical_end_state(tmp_path):
+def test_train_fused_vs_unfused_identical_end_state(tmp_path, one_chip):
     """TRAIN-LEVEL parity (the seam the unit parity above cannot see —
     loop accounting, cadences, warmup handoff): the same config run with
     fused_beat='on' and 'off' must finish with the same learner-step
     count, the same env-step production, and a bit-identical param
     checksum. Pins the whole dispatch-gating wiring — e.g. a fused beat
     that ALSO fell through to the unfused after_chunk would double the
-    step accounting and extra-roll the envs, and only this test sees it."""
+    step accounting and extra-roll the envs, and only this test sees it.
+    On one device (conftest.one_chip): the unfused loop keeps a rollout and
+    a chunk program in flight, and on the 8 virtual devices XLA:CPU aborted
+    this test about once in seven under six workers (PERF.md §7, 24). On
+    the 8 devices, one beat's parity is the unit tests' above and the loop's
+    accounting is the next test's; what nothing holds there is the unfused
+    loop itself with collectives in its programs, and so the two modes' bit
+    parity over a whole run on a mesh."""
     outs = {}
     for mode in ("on", "off"):
         cfg = _train_cfg(tmp_path, fused_beat=mode,
@@ -335,9 +342,30 @@ def test_train_fused_vs_unfused_identical_end_state(tmp_path):
     assert outs["on"]["param_checksum"] == outs["off"]["param_checksum"]
 
 
-def test_train_fused_beat_off_keeps_dispatch_per_phase(tmp_path):
+def test_train_fused_on_the_mesh_counts_what_unfused_counts_on_one_chip(tmp_path, request):
+    """The same seam on the 8 virtual devices, as far as it can be held
+    without the unfused loop running there (the test above says why): the
+    fused run on the mesh ends with the learner steps and the environment
+    steps of the unfused run on one device. A beat that fell through to
+    after_chunk as well, or a cadence that read the mesh's size, shows
+    here; the parameters agree only as far as the order of a reduction
+    over 8 devices lets them."""
+    cfg = _train_cfg(tmp_path, fused_beat="on", log_path=str(tmp_path / "run_on.jsonl"))
+    on = train_jax(cfg)
+    assert on["fused_beat_active"] is True and on["mesh_data_axis"] == 8
+    request.getfixturevalue("one_chip")  # from here on, train() takes one device
+    cfg = _train_cfg(tmp_path, fused_beat="off", log_path=str(tmp_path / "run_off.jsonl"))
+    off = train_jax(cfg)
+    assert off["fused_beat_active"] is False and off["mesh_data_axis"] == 1
+    for key in ("learner_steps", "env_steps", "devactor_env_steps"):
+        assert on[key] == off[key] > 0, key
+    assert on["param_checksum"] == pytest.approx(off["param_checksum"], rel=1e-2)
+
+
+def test_train_fused_beat_off_keeps_dispatch_per_phase(tmp_path, one_chip):
     """fused_beat='off' pins the dispatch-per-phase loop; the summary
-    reports the gating fact and no fused_* fields ride the records."""
+    reports the gating fact and no fused_* fields ride the records. On one
+    device, as the parity test above and for its reason."""
     cfg = _train_cfg(tmp_path, fused_beat="off")
     out = train_jax(cfg)
     assert out["fused_beat_active"] is False

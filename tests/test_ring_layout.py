@@ -23,7 +23,7 @@ from distributed_ddpg_tpu.replay.device import (
     ring_row_bytes,
     ring_write,
 )
-from ring_layout_util import humanoid_ring_programs, ring_sized_copies
+from ring_layout_util import assert_reads_back, fill_past_a_wrap, humanoid_ring_programs, ring_sized_copies
 
 
 @pytest.mark.parametrize(
@@ -100,14 +100,94 @@ def test_fill_wrap_save_restore_rows(with_mesh):
     np.testing.assert_array_equal(np.asarray(r2.storage), want)
 
 
+def _shard_pointers(array):
+    return [shard.data.unsafe_buffer_pointer() for shard in array.addressable_shards]
+
+
 def test_no_ring_sized_copy_in_the_programs_that_take_the_ring():
     """`jit_ring_insert` and the scan `sample_chunk_fn` at Humanoid width:
     no `copy` or `transpose` with the ring's shape in the optimised HLO
-    (chip_smoke.py asserts the same on the v5e at 1.4e6 rows)."""
+    (chip_smoke.py asserts the same on the v5e at 1.4e6 rows), but for the
+    two that XLA:CPU, and only it (the v5e cases below), leaves at the edges
+    of the insert's two branches: exactly one in each branch computation of
+    the conditional, on the ring as it comes in or as it goes out, and none
+    in ENTRY or anywhere else. Neither moves a ring: the program holds one
+    ring-sized buffer, the donated one, which is the result's (the alias, the
+    temporaries' size, the buffers' addresses before and after an insert
+    under each branch), so each is a copy of that buffer onto itself, which
+    XLA:CPU does not run (test_cpu_insert_costs_a_block_not_a_ring)."""
     replay, copies = humanoid_ring_programs(capacity=4096, chunk=2)
-    assert replay.storage.shape == (4096, 772)
+    shape = replay.storage.shape
+    assert shape == (4096, 772)
     assert set(copies) == {"jit_ring_insert", "jit_sample_chunk_fn"}
-    assert copies == {"jit_ring_insert": [], "jit_sample_chunk_fn": []}
+    assert copies["jit_sample_chunk_fn"] == []
+
+    block = np.zeros((replay.block_size, replay.width), np.float32)
+    compiled = replay._insert.lower(replay.storage, block, replay.ptr, replay.size).compile()
+    text = compiled.as_text()
+    comps = _computations(text)
+    entry = re.search(r"^ENTRY %?([\w.\-]+) ", text, re.M).group(1)
+    (branches,) = re.findall(r"conditional\(.*branch_computations=\{([^}]*)\}", "\n".join(comps[entry]))
+    branches = [b.strip().lstrip("%") for b in branches.split(",")]
+    assert len(branches) == 2
+    found = {name: ring_sized_copies("\n".join(lines), shape) for name, lines in comps.items()}
+    assert {name: len(at) for name, at in found.items() if at} == {branches[0]: 1, branches[1]: 1}
+    assert sorted(copies["jit_ring_insert"]) == sorted(found[branches[0]] + found[branches[1]])
+    for branch in branches:
+        (line,) = [l for l in comps[branch] if re.search(r"\] copy\(|\} copy\(", l)]
+        name, operand = re.match(r"\s*%?([\w.\-]+) = \S+ copy\(%?([\w.\-]+)\)", line).groups()
+        (root,) = [l for l in comps[branch] if l.lstrip().startswith("ROOT ")]
+        ring_out = re.search(r"\(%?" + re.escape(name) + r"\)", root) is not None
+        ring_in = any(re.match(r"\s*%?" + re.escape(operand) + r" = \S+ get-tuple-element\(", l) for l in comps[branch])
+        assert ring_in != ring_out, line
+    assert "input_output_alias={ {0}: (0, {}, may-alias) }" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 4096 * 4 * 772 // 8
+
+    at = _shard_pointers(replay.storage)
+    for m in (1024, 1024, 1024, 1536):  # three runs, then a block that passes the ring's end
+        replay.insert_device_rows(jnp.ones((m, replay.width), jnp.float32))
+        assert _shard_pointers(replay.storage) == at
+    snap = replay.ingest_snapshot()
+    assert (snap["replay_insert_runs"], snap["replay_insert_wrapped"]) == (3, 1)
+
+
+def test_cpu_insert_costs_a_block_not_a_ring():
+    """What the two `copy` instructions of the test above cost when the
+    program runs: nothing. An insert of 256 rows into a ring of 202 MB,
+    under either branch, against one copy of that ring in the same process
+    (0.3 ms against 137 ms on an idle sandbox; the bound leaves load its room:
+    the best of ten against the best of three, and a factor of ten)."""
+    import time
+
+    cap = 65536
+    r = DeviceReplay(cap, 376, 17, block_size=256)
+    assert r.ring_layout == "compact"
+    rows = jnp.ones((256, r.width), jnp.float32)
+
+    def best_of(n, run):
+        took = []
+        for _ in range(n):
+            t0 = time.perf_counter()
+            jax.block_until_ready(run())
+            took.append(time.perf_counter() - t0)
+        return min(took)
+
+    def insert_at(ptr):
+        ptr = jax.device_put(np.int32(ptr), r.ptr.sharding)
+
+        def run():
+            r.storage, _, _ = r._insert(r.storage, rows, ptr, r.size)
+            return r.storage
+
+        return run
+
+    insert_at(0)()  # compiled
+    a_copy = best_of(3, lambda: jnp.copy(r.storage))
+    assert 10 * best_of(10, insert_at(1000)) < a_copy  # a run
+    assert 10 * best_of(10, insert_at(cap - 100)) < a_copy  # passes the ring's end
+    landed = np.zeros(cap, bool)
+    landed[np.r_[0:256, 1000:1256, cap - 100 : cap]] = True  # 0, 1000 and cap - 100: the last one's 156 wrapped rows lie in the first's
+    np.testing.assert_array_equal(np.asarray(r.storage[:, 0]), landed.astype(np.float32))
 
 
 def test_ring_sized_copies_reads_hlo_text():
@@ -185,6 +265,117 @@ def test_ring_program_only_where_the_ring_is_row_major():
     packed = DeviceReplay(64, 17, 6, block_size=16)
     assert packed.ring_layout == "packed" and packed.storage_format is None
     assert packed.ring_program(jitted) is jitted  # packed lines: the jit itself everywhere
+
+
+# --- the plain ring's insert (ring_write): a block in ring order that ends
+# inside the ring is one dynamic-update-slice, and only a block that passes
+# the ring's end is scattered (PERF.md PR 41). The rows land where the
+# scatter of computed indices put them, which is written out here. ---
+
+RUN_CAP = 200
+_ring_write = jax.jit(ring_write, donate_argnums=(0,))
+
+
+@pytest.mark.parametrize("width", [65, 240, 772])
+@pytest.mark.parametrize("m", [1, 8, 96, RUN_CAP])
+@pytest.mark.parametrize("at", ["0", "8", "13", "exact_fit", "wraps_by_one_row", "last_row"])
+def test_ring_write_puts_every_row_where_the_scatter_put_it(width, m, at):
+    ptr = {
+        "0": 0, "8": 8, "13": 13, "exact_fit": RUN_CAP - m, "wraps_by_one_row": RUN_CAP - m + 1, "last_row": RUN_CAP - 1,
+    }[at] % RUN_CAP
+    rng = np.random.default_rng(width * 1000 + m)
+    storage = rng.standard_normal((RUN_CAP, width)).astype(np.float32)
+    block = rng.standard_normal((m, width)).astype(np.float32)
+    want = jnp.asarray(storage).at[(ptr + jnp.arange(m)) % RUN_CAP].set(block)
+    got = _ring_write(jnp.asarray(storage), jnp.asarray(block), jnp.int32(ptr))
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("with_mesh", [False, True])
+@pytest.mark.parametrize("obs_dim,act_dim,layout", [(376, 17, "compact"), (17, 6, "packed")])
+def test_device_rows_three_times_round_a_small_ring(obs_dim, act_dim, layout, with_mesh):
+    """insert_device_rows in blocks that do not divide the ring: rows,
+    pointer, size and the host's counts of what each insert did, against a
+    numpy ring. A packed ring has its own whole-line write and counts no run."""
+    mesh = Mesh(np.array(jax.devices()[:1]).reshape(1, 1), ("data", "model")) if with_mesh else None
+    cap, m = 64, 24
+    r = DeviceReplay(cap, obs_dim, act_dim, mesh=mesh, block_size=16)
+    assert r.ring_layout == layout
+    rng = np.random.default_rng(3)
+    want, ptr, wrapped = np.zeros((cap, r.width), np.float32), 0, 0
+    for i in range(1, 12):  # 264 rows: four times round and a bit
+        rows = rng.standard_normal((m, r.width)).astype(np.float32)
+        want[(ptr + np.arange(m)) % cap] = rows
+        wrapped += ptr + m > cap
+        ptr = (ptr + m) % cap
+        assert r.insert_device_rows(jnp.asarray(rows)) == m
+        snap = r.ingest_snapshot()
+        assert (int(r.ptr), len(r)) == (ptr, min(i * m, cap))
+        assert snap["ring_wraps"] == i * m // cap
+        assert snap["replay_insert_wrapped"] == wrapped
+        assert snap["replay_insert_runs"] == (0 if layout == "packed" else i - wrapped)
+        np.testing.assert_array_equal(np.asarray(r.storage), want)
+    assert wrapped == 3  # 40 + 24 = 64 is an exact fit: a run, not a wrap
+
+
+def test_host_ships_count_their_runs_and_wraps():
+    """The shipper's super-blocks go through the same insert and the same
+    host arithmetic, tracked sources or not."""
+    r = DeviceReplay(96, 376, 17, block_size=16, max_coalesce=4)
+    want = fill_past_a_wrap(r, 160, push=40)
+    assert_reads_back(r, want)
+    snap = r.ingest_snapshot()
+    assert snap["ring_wraps"] == 1
+    assert snap["replay_insert_runs"] + snap["replay_insert_wrapped"] == snap["ingest_ship_calls"]
+    assert snap["replay_insert_wrapped"] <= 1 and snap["replay_insert_runs"] >= 3
+
+
+def _primitives(jaxpr):
+    return [eqn.primitive.name for eqn in jaxpr.eqns]
+
+
+def test_plain_insert_is_a_slice_update_and_scatters_only_under_the_wraps_branch():
+    r = DeviceReplay(4096, 376, 17, block_size=256)
+    block = jnp.zeros((256, r.width), jnp.float32)
+    args = (r.storage, block, r.ptr, r.size)
+    top = jax.make_jaxpr(r._insert_pure)(*args).jaxpr
+    assert _primitives(top).count("cond") == 1 and "scatter" not in _primitives(top)
+    (cond,) = [eqn for eqn in top.eqns if eqn.primitive.name == "cond"]
+    wraps, fits = (_primitives(branch.jaxpr) for branch in cond.params["branches"])
+    assert "scatter" in wraps and "dynamic_update_slice" not in wraps
+    assert "dynamic_update_slice" in fits and "scatter" not in fits
+    lowered = r._insert.lower(*args)
+    text = lowered.as_text()
+    assert text.count("stablehlo.dynamic_update_slice") == 1 and text.count('"stablehlo.scatter"') == 1
+    assert "stablehlo.case" in text
+    # The donated ring comes back in the buffer it went in with.
+    compiled = lowered.compile()
+    assert "input_output_alias={ {0}: (0, {}, may-alias) }" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 4096 * 4 * r.width // 8
+    # Not a run: a permuted block, a block longer than the ring, a packed ring.
+    permuted = jax.make_jaxpr(lambda s, b, p: ring_write(s, b, p, jnp.arange(256)[::-1]))(r.storage, block, r.ptr)
+    longer = jax.make_jaxpr(ring_write)(jnp.zeros((128, r.width)), block, r.ptr)
+    for jaxpr in (permuted, longer):
+        assert "scatter" in _primitives(jaxpr.jaxpr) and "cond" not in _primitives(jaxpr.jaxpr)
+    packed = DeviceReplay(4096, 17, 6, block_size=256)
+    packed_jaxpr = jax.make_jaxpr(packed._insert_pure)(
+        packed.storage, jnp.zeros((256, packed.width)), packed.ptr, packed.size
+    )
+    assert "cond" not in _primitives(packed_jaxpr.jaxpr)
+
+
+@pytest.mark.parametrize("name", ["replay.insert.packed", "replay.insert.sharded", "replay.insert.devrows.sharded"])
+def test_the_inserts_that_are_no_runs_hold_no_branch(name):
+    """PackedRing.write and the sharded inserts do not go through
+    ring_write's plain branch: the registry's programs hold their scatter
+    at the top, under no `cond`, and no slice update (their lowered text is
+    the parent's to the bit: PERF.md PR 41 has the hashes)."""
+    from distributed_ddpg_tpu.replay.device import program_specs
+
+    built = next(s for s in program_specs() if s.name == name).build()
+    text = built.fn.lower(*built.args).as_text()
+    assert "stablehlo.case" not in text and "stablehlo.dynamic_update_slice" not in text
+    assert '"stablehlo.scatter"' in text
 
 
 # --- compiled for a described v5e: the layout itself. The TPU's compiler is
@@ -327,6 +518,30 @@ def test_v5e_programs_hold_no_ring_sized_copy(v5e_sharding, width):
         assert _ring_sized_ops(gathered.as_text(), shape) == ["parameter"]
         assert inserted.memory_analysis().temp_size_in_bytes < 2**20
         assert gathered.memory_analysis().temp_size_in_bytes < 2**21  # the [8 x 256, 128] lines gathered
+
+
+@pytest.mark.parametrize("width", [240, 772])
+def test_v5e_plain_insert_holds_its_scatter_under_the_wraps_branch(v5e_sharding, width):
+    """The row-major ring's insert as the chip's compiler leaves it (240
+    floats a row: the PQL cell's ring): ENTRY holds the `conditional` and no
+    op of the ring's size of its own; one branch is the in-place
+    dynamic-update-slice, the other the scatter; the ring's buffer is the
+    result's, with no second ring beside it."""
+    fmt = ring_format(v5e_sharding, width)
+    assert isinstance(fmt, Format)
+    inserted, _ = _compiled_for(v5e_sharding, width, fmt)
+    text = inserted.as_text()
+    assert "input_output_alias={ {0}: (0, {}, may-alias) }" in text
+    assert inserted.memory_analysis().temp_size_in_bytes < CAPACITY * 4 * width // 8
+    assert not {"scatter", "fusion", "copy", "dynamic-update-slice"} & set(_ring_sized_ops(text, (CAPACITY, width)))
+    comps = _computations(text)
+    entry = text.split("ENTRY ", 1)[1].split("\n}", 1)[0]
+    (branches,) = re.findall(r"conditional\(.*branch_computations=\{([^}]*)\}", entry)
+    found = []
+    for name in (b.strip().lstrip("%") for b in branches.split(",")):
+        lines = [l for c in {name} | _called_from(comps, name) for l in comps[c]]
+        found.append((any(" dynamic-update-slice(" in l for l in lines), any(" scatter(" in l for l in lines)))
+    assert sorted(found) == [(False, True), (True, False)]
 
 
 def test_v5e_default_layout_is_what_the_rule_replaces(v5e_sharding):
