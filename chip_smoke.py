@@ -17,6 +17,11 @@ HalfCheetah-wide ring of the papers' 1e6 rows, packed two rows to a
 128-lane line, filled through the ingest path past one wrap: every row
 must read back identical to the host's copy (the body
 tests/test_packed_ring.py runs), with no ring-sized copy in its programs.
+Last, the scan leg's front (ops/chunk_front.py): rows read back through
+the one-pass kernel at the two row-major cells' sizes (1.4e6 x 772 and
+5e6 x 240) against the host's, and one launch of the SAC chunk from one
+state and one ring under both fronts, whose TrainStates must be equal bit
+for bit.
 
 Exit 0 only if every check held. Stdout then ends with two JSON lines: the
 facts of the run (`{"facts": {...}}`: versions, legs, compile cache, ...)
@@ -65,6 +70,9 @@ OBS, ACT = 17, 6  # HalfCheetah-v4
 INGEST_BLOCK = 1024  # train_jax's DeviceReplay block: one padded flush at most
 RING_ROWS = 1_400_000  # the sac-humanoid cell's ring
 PACKED_RING_ROWS = 1_000_000  # the papers' ring, at HalfCheetah's 43 floats a row
+# (ring rows, obs, act, updates a launch, batch) of the cells whose launches
+# take the cut front: sac-humanoid.free and pql-isaac-humanoid.devactors
+FRONT_SHAPES = {"humanoid": (RING_ROWS, 376, 17, 800, 256), "pql": (5_000_000, 108, 21, 96, 8192)}
 
 
 class SmokeFailure(Exception):
@@ -192,6 +200,94 @@ def run_packed_ring():
     }
 
 
+def run_chunk_front(n_devices):
+    """ops/chunk_front.py on the chip. (a) A ring of each cell's size in
+    ring_format's layout, every value a function of its row and column that
+    the host computes too; one launch's rows (row 0, the last row and
+    duplicates among them) through `cut_rows(storage[idx])` against the
+    host's cut of the host's rows: float32 fields bit for bit, and the
+    rounded ones equal to the host's rounding to nearest even. (b) Two SAC
+    learners at Humanoid width, one built with `unpack_batch` in front and
+    one as the rule builds it here, one launch each from the same state, key
+    and ring: the same TrainState and TD errors to the last bit."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.sharding import SingleDeviceSharding
+    from ring_layout_util import HUMANOID_ACT, HUMANOID_OBS, HUMANOID_SCALE, SAC_HUMANOID_FLAGS
+
+    from distributed_ddpg_tpu.config import DDPGConfig
+    from distributed_ddpg_tpu.ops import chunk_front
+    from distributed_ddpg_tpu.parallel.learner import ShardedLearner, resolve_learner_chunk
+    from distributed_ddpg_tpu.parallel.mesh import make_mesh
+    from distributed_ddpg_tpu.replay.device import DeviceReplay, ring_format
+    from distributed_ddpg_tpu.types import packed_width, unpack_batch
+
+    def value(h):  # 24 hashed bits as a float32 in [-0.5, 0.5): exact on both sides
+        return (h >> 8).astype(np.float32) * np.float32(2.0**-24) - np.float32(0.5)
+
+    facts = {}
+    one = SingleDeviceSharding(jax.devices()[0])
+    for name, (capacity, obs, act, K, B) in FRONT_SHAPES.items():
+        width = packed_width(obs, act)
+
+        def fill():
+            r = jax.lax.broadcasted_iota(jnp.uint32, (capacity, width), 0)
+            c = jax.lax.broadcasted_iota(jnp.uint32, (capacity, width), 1)
+            return value((r * jnp.uint32(width) + c) * jnp.uint32(2654435761))
+
+        storage = jax.jit(fill, out_shardings=ring_format(one, width))()
+        idx = np.random.default_rng(42).integers(0, capacity, (K, B)).astype(np.int32)
+        idx[0, :5] = [0, capacity - 1, 7, 7, 0]
+        cells = idx.reshape(-1, 1).astype(np.uint32) * np.uint32(width) + np.arange(width, dtype=np.uint32)
+        host = value(cells * np.uint32(2654435761)).reshape(K, B, width)  # uint32 wraps, as on the device
+        want = unpack_batch(host, obs, act)
+        for rounded in (True, False):
+            got = jax.jit(
+                lambda s, i: chunk_front.cut_rows(s[i], obs, act, rounded)
+            )(storage, jax.device_put(idx, one))
+            for field in want._fields:
+                w, g = getattr(want, field), np.asarray(getattr(got, field))
+                if rounded and field in ("obs", "action", "next_obs"):
+                    w = w.astype(jnp.bfloat16).astype(np.float32)
+                check(
+                    g.dtype == np.float32 and np.array_equal(g.view(np.uint32), w.view(np.uint32)),
+                    f"front: {name} ring, rounded={rounded}: {field} differs from the host's rows",
+                )
+        facts[name] = {"ring": [capacity, width], "rows_read": K * B, "format": str(storage.format.layout)}
+        del storage, got
+
+    cfg = DDPGConfig.from_flags(SAC_HUMANOID_FLAGS + [f"--replay_capacity={RING_ROWS}"])
+    chunk = resolve_learner_chunk(cfg)
+    mesh = make_mesh(-1, 1)
+    replay = DeviceReplay(RING_ROWS, HUMANOID_OBS, HUMANOID_ACT, mesh=mesh, block_size=INGEST_BLOCK)
+    replay.add_packed(np.random.default_rng(9).standard_normal((64 * INGEST_BLOCK, replay.width)).astype(np.float32))
+    replay.flush()
+
+    def launch(front):
+        rule = chunk_front.front_for
+        if front == "xla":
+            chunk_front.front_for = lambda **seen: "xla"
+        try:
+            learner = ShardedLearner(cfg, HUMANOID_OBS, HUMANOID_ACT, HUMANOID_SCALE, mesh=mesh, chunk_size=chunk)
+        finally:
+            chunk_front.front_for = rule
+        check(learner.chunk_front == front, f"front: the rule gave {learner.chunk_front!r} for {front!r}")
+        out = learner.run_sample_chunk(replay)
+        return jax.device_get((learner.state, out.td_errors)), learner.chunk_hlo()
+
+    (state_x, td_x), _ = launch("xla")
+    (state_c, td_c), text = launch("cut")
+    check("tpu_custom_call" in text, "front: the cut front's chunk holds no Mosaic custom call")
+    leaves_x, leaves_c = jax.tree.leaves(state_x), jax.tree.leaves(state_c)
+    same = [np.array_equal(np.asarray(x), np.asarray(y)) for x, y in zip(leaves_x, leaves_c)]
+    check(all(same) and len(leaves_x) == len(leaves_c), f"front: {same.count(False)} of {len(same)} state leaves differ between the fronts")
+    check(np.array_equal(td_x.view(np.uint32), td_c.view(np.uint32)), "front: TD errors differ between the fronts")
+    check(np.isfinite(td_c).all() and float(np.abs(td_c).max()) > 0, "front: the launch's TD errors are not finite or all zero")
+    facts["state_parity"] = {"leaves": len(same), "updates": chunk, "rows": int(td_c.size), "n_devices": n_devices}
+    return facts
+
+
 def kernel_lowering(chunk):
     """What the default leg's kernel lowers to on this backend, read from
     the lowered program text: 'tpu_custom_call' is Mosaic, anything else
@@ -313,6 +409,7 @@ def run_checks(device, cache_dir):
     )
     facts["ring"] = run_ring_layout()
     facts["packed_ring"] = run_packed_ring()
+    facts["chunk_front"] = run_chunk_front(n_devices)
     facts["compile_cache"]["entries_after"] = cache_entries(cache_dir)
     left = mp.active_children()
     for p in left:

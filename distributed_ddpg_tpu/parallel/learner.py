@@ -24,6 +24,7 @@ steps.
 from __future__ import annotations
 
 import contextlib
+from functools import partial
 from typing import Dict, Optional
 
 import jax
@@ -51,6 +52,7 @@ from distributed_ddpg_tpu.types import (
     OptState,
     TrainState,
     pack_batch_np,
+    packed_width,
     unpack_batch,
 )
 
@@ -446,13 +448,47 @@ class ShardedLearner:
             key, idx = draw_chunk_idx(key, size)
             return key, gather_rows(storage, idx)
 
+        # The uniform scan chunk's front (ops/chunk_front.py): where the
+        # rule says 'cut', one Pallas pass over the gathered rows hands the
+        # scan its fields cut, rounded and feature-major, each chip its own
+        # rows; elsewhere unpack_batch as ever. The PER and guarded chunks
+        # (which screen and overwrite the gathered rows) and the host-fed
+        # chunk keep unpack_batch.
+        from distributed_ddpg_tpu.ops import chunk_front as front_lib
+        from distributed_ddpg_tpu.ops.fused_chunk import runs_native
+        from distributed_ddpg_tpu.replay.device import ring_layout
+
+        width = packed_width(obs_dim, act_dim)
+        scan_front = front_lib.front_for(
+            width=width,
+            batch=batch_size // n_shards,
+            layout=ring_layout(width, self._replay_sharded),
+            replay_sharded=self._replay_sharded,
+            model_axis=self.mesh.shape["model"],
+            native=runs_native(),
+        )
+
+        def cut_chunk(packed) -> Batch:
+            if scan_front == "xla":
+                return unpack_batch(packed, obs_dim, act_dim)
+            cut = partial(
+                front_lib.cut_rows, obs_dim=obs_dim, act_dim=act_dim,
+                rounded=front_lib.rounds_inputs(config),
+            )
+            if n_shards == 1:
+                return cut(packed)
+            rows, row = P(None, "data", None), P(None, "data")
+            return mesh_lib.shard_map(
+                cut, self.mesh, in_specs=rows,
+                out_specs=Batch(rows, rows, row, row, rows, row),
+            )(packed)
+
         def sample_chunk_fn(s: TrainState, key, storage, size, nkey):
             key, packed = draw_chunk(key, storage, size)
             packed = jax.lax.with_sharding_constraint(
                 packed, NamedSharding(self.mesh, P(None, "data", None))
             )
-            batches = unpack_batch(packed, obs_dim, act_dim)
-            return scan_steps(s, batches, nkey), key
+            return scan_steps(s, cut_chunk(packed), nkey), key
 
         # Pallas megakernel path (ops/fused_chunk.py): the whole chunk in one
         # kernel, params VMEM-resident.
@@ -506,6 +542,15 @@ class ShardedLearner:
             fused_chunk_lib.state_tiles(config, obs_dim, act_dim)
             if self.fused_chunk_active
             else None
+        )
+        # The run fact `chunk_front`: how run_sample_chunk's program turns a
+        # launch's gathered rows into the update's operands ('cut': the
+        # kernel above; 'xla': unpack_batch, which is also what the
+        # megakernel's own cuts and the guarded chunk read).
+        self.chunk_front = (
+            "xla"
+            if self.fused_chunk_active or self.guard_enabled
+            else scan_front
         )
         if config.fused_chunk == "on" and not self.fused_chunk_active:
             raise ValueError(

@@ -1354,6 +1354,15 @@ def _train_jax_impl(
             "learner_chunk": chunk,
             "fused_chunk_active": learner.fused_chunk_active,
             "kernel_state_tiles": learner.kernel_state_tiles,
+            # How a launch's gathered rows reach the update: 'cut' where the
+            # uniform scan chunk runs ops/chunk_front.py's one-pass kernel,
+            # 'xla' for unpack_batch (the megakernel's own cuts, PER, the
+            # guarded and the host-fed chunks).
+            "chunk_front": (
+                learner.chunk_front
+                if use_device_replay and not config.prioritized
+                else "xla"
+            ),
             "state_devices": min(
                 len(leaf.sharding.device_set)
                 for leaf in jax.tree.leaves(learner.state)

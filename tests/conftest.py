@@ -40,3 +40,19 @@ def one_chip(monkeypatch):
     make = mesh_lib.make_mesh
     monkeypatch.setattr(
         mesh_lib, "make_mesh", lambda data_axis=-1, model_axis=1, devices=None: make(1, 1, jax.devices()[:1]))
+
+
+@pytest.fixture
+def as_on_a_tpu(monkeypatch):
+    """as_on_a_tpu(native=True): the platform input of the scan front's rule
+    (ops/chunk_front.front_for) alone, so that a learner builds the program a
+    real TPU would get while the kernel itself still runs interpreted here."""
+    from distributed_ddpg_tpu.ops import chunk_front
+
+    rule = chunk_front.front_for
+
+    def patch(native=True):
+        monkeypatch.setattr(chunk_front, "front_for", lambda **seen: rule(**{**seen, "native": native}))
+
+    return patch
+

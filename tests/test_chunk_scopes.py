@@ -348,3 +348,47 @@ def test_train_writes_no_table_without_a_records_file(tmp_path, monkeypatch):
     assert summary["learner_steps"] > 0
     assert summary["log_path"] == "" and summary["chunk_ops_path"] == ""
     assert not list(tmp_path.rglob(trace.CHUNK_OPS_FILE))
+
+
+# --- the run fact `chunk_front` (ops/chunk_front.py) and the front's scope ---
+
+
+def run_stand_in(log_path, **config):
+    """train() with the device pool on the stand-in env at the source's
+    shapes (obs 108, act 21: 240 floats a row, row-major by the width rule)
+    and a batch of 128, on the scan leg."""
+    from distributed_ddpg_tpu.envs.jax_envs import STAND_IN_ID
+    from distributed_ddpg_tpu.train import train_jax
+
+    cfg = DDPGConfig(**{**dict(
+        backend="jax_tpu", env_id=STAND_IN_ID, actor_backend="device", num_actors=0, device_actor_envs=8,
+        device_actor_chunk=2, actor_hidden=(32, 16, 8), critic_hidden=(32, 16, 8), batch_size=128,
+        replay_capacity=4096, twin_critic=True, policy_delay=2, action_insert_layer=0, exploration="gaussian",
+        learner_chunk=2, max_ingest_ratio=8.0, replay_min_size=128, warmup_uniform_steps=128,
+        total_env_steps=128 + 16 * 6, eval_every=0, fused_chunk="off", seed=5, log_path=str(log_path),
+    ), **config})
+    return train_jax(cfg)
+
+
+@pytest.mark.parametrize("native,config,front", [
+    (True, {}, "cut"),  # as on a real TPU: the kernel, interpreted here
+    (True, dict(prioritized=True), "xla"),  # the PER chunk overwrites the gathered rows' weights
+    (False, {}, "xla"),  # off the TPU
+])
+def test_train_names_the_front_its_launches_took(tmp_path, as_on_a_tpu, one_chip, native, config, front):
+    as_on_a_tpu(native)
+    log = tmp_path / "run" / "records.jsonl"
+    log.parent.mkdir()
+    summary = run_stand_in(log, **config)
+    assert summary["learner_steps"] > 0 and not summary["fused_chunk_active"]
+    records = [json.loads(line) for line in open(log)]
+    header, final = records[0], records[-1]
+    assert header["kind"] == "header" and final["kind"] == "final"
+    for rec in (header, final, summary):
+        assert rec["chunk_front"] == front
+    assert np.isfinite(final["critic_loss"])
+    table = json.loads((log.parent / trace.CHUNK_OPS_FILE).read_text())
+    # the kernel's instructions read under `cut` (the CPU's compiler fuses
+    # unpack_batch's slices into their readers and leaves nothing there)
+    assert {"draw", "gather", "update"} <= set(table["ops"].values())
+    assert ("cut" in table["ops"].values()) == (front == "cut")

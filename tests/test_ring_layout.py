@@ -803,3 +803,89 @@ def test_v5e_crossq_chunk_holds_no_target_update_and_the_policy_under_a_conditio
     assert [line for line in text.splitlines() if drawn.search(line)]
     conditionals = [line for name in inside for line in comps[name] if re.search(r"\bconditional\(", line)]
     assert len(conditionals) == 4  # one an update, four unrolled updates a trip
+
+
+# --- the scan leg's front (ops/chunk_front.py), compiled for the same
+# described v5e inside the chunk it feeds: the gathered block has one
+# reader, the scan takes the kernel's outputs as they are, and Mosaic
+# compiles the kernel under its default scoped VMEM (nothing is passed). ---
+
+
+@pytest.mark.parametrize(
+    "name,extra,chunk,capacity,rounded",
+    [
+        ("sac-humanoid", [], 800, 1_400_000, True),
+        # the cell's mix brings the device pool's flags (traffic/devactors.json)
+        ("pql-isaac-humanoid", ["--actor_backend=device", "--num_actors=0"], 96, 5_000_000, True),
+        # batch statistics read the observations in float32: nothing is rounded
+        ("crossq-humanoid", [], 800, 1_400_000, False),
+    ],
+)
+def test_v5e_scan_chunk_reads_its_gathered_block_once(v5e_sharding, name, extra, chunk, capacity, rounded):
+    """The cell's chunk at its own sizes with `storage[idx]` and the cut
+    kernel in front, the ring in ring_format's layout. With unpack_batch the
+    SAC program held four readers of f32[204800,772] (a cut-and-round fusion,
+    a slice-reduce fusion, a slice, and their three relayout copies behind
+    them): 2.8 GB moved a launch for 0.33 GB of operands (PERF.md PR 42)."""
+    import json
+    import os
+
+    from distributed_ddpg_tpu import learner as learner_lib
+    from distributed_ddpg_tpu import trace
+    from distributed_ddpg_tpu.config import DDPGConfig
+    from distributed_ddpg_tpu.ops import chunk_front
+    from distributed_ddpg_tpu.parallel.learner import scan_chunk
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    conf = json.load(open(os.path.join(root, "benchmarks", "configs", name + ".json")))
+    cfg = DDPGConfig.from_flags(conf["flags"] + extra)
+    env = conf["env"]
+    obs, act = env["obs_dim"], env["act_dim"]
+    width, batch = 2 * obs + act + 3, cfg.batch_size
+    assert cfg.replay_capacity == capacity and chunk_front.rounds_inputs(cfg) == rounded
+    assert chunk_front.front_for(
+        width=width, batch=batch, layout=ring_layout(width), replay_sharded=False, model_axis=1, native=True,
+    ) == "cut"
+    step = learner_lib.make_learner_step(cfg, env["action_scale"], action_offset=env["action_offset"])
+
+    def run(s, storage, idx):
+        noise = None
+        if learner_lib.draws_noise(cfg):
+            noise = learner_lib.chunk_noise(cfg, learner_lib.noise_base_key(cfg), s.step, chunk, batch, act)
+        batches = chunk_front.cut_rows(storage[idx], obs, act, rounded, interpret=False)
+        return scan_chunk(step, s, batches, noise, unroll=4)
+
+    replicated = NamedSharding(v5e_sharding.mesh, P())
+    state = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=replicated),
+        jax.eval_shape(lambda: learner_lib.init_train_state(cfg, obs, act, 0)),
+    )
+    ring = jax.ShapeDtypeStruct((capacity, width), jnp.float32, sharding=ring_format(v5e_sharding, width))
+    idx = jax.ShapeDtypeStruct((chunk, batch), jnp.int32, sharding=replicated)
+    compiled = jax.jit(run, donate_argnums=(0,)).lower(state, ring, idx).compile()  # raises what Mosaic would
+    text = compiled.as_text()
+
+    entry = [line for line in text.split("ENTRY ", 1)[1].splitlines()[1:] if " = " in line]
+    rows = chunk * batch
+    block = re.compile(r"f32\[(%d,%d|%d,%d,%d)\]" % (rows, width, chunk, batch, width))
+    made = [line for line in entry if re.match(r"\s*%?[\w.\-]+ = " + block.pattern, line)]
+    # the gather's fusion and nothing else makes the block (a bitcast of it apart)
+    assert len([line for line in made if " bitcast(" not in line]) == 1 and " fusion(" in made[0]
+    names = {re.match(r"\s*%?([\w.\-]+) = ", line).group(1) for line in made}
+    readers = [
+        line for line in entry
+        if any(re.search(r"[(, ]%?" + re.escape(n) + r"[,)]", line.split(" = ", 1)[1]) for n in names)
+        and " bitcast(" not in line
+    ]
+    assert len(readers) == 1 and "tpu_custom_call" in readers[0], readers
+    # no relayout copy of the observations, the next observations or the action
+    fields = re.compile(r"(bf16|f32)\[%d,(%d,(%d|%d)|(%d|%d),%d)\]" % (chunk, batch, obs, act, obs, act, batch))
+    assert not [line for line in entry if re.search(r" = " + fields.pattern + r"\{[^}]*\} (copy|transpose)\(", line)]
+    assert ring_sized_copies(text, (capacity, width)) == []
+    # the custom call reads under `cut`, so chunk.unscoped_pct cannot jump
+    table = trace.chunk_ops_table(text)
+    call = re.match(r"\s*%?([\w.\-]+) = ", readers[0]).group(1)
+    assert table["ops"][call] == "cut"
+    # the launch's temporaries: the block, the kernel's outputs, the noise
+    outs = rows * (2 * obs + act) * (2 if rounded else 4) + rows * 3 * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < rows * 4 * (-(-width // 128) * 128) + 2 * outs + 2**28
