@@ -28,8 +28,7 @@ experience path entirely:
     previous chunk's dispatch DONATED the old TrainState, so the stale
     reference must never be dispatched again).
 
-Unlike `backend='jax_ondevice'` (the fused env+replay+learner monolith),
-the learner keeps its full feature set — PER, guardrails, serving,
+The learner keeps its full feature set — PER, guardrails, serving,
 multi-host — because replay stays an ordinary DeviceReplay and the learner
 programs are unchanged; this module only replaces WHO produces the rows.
 The host pool can run alongside (num_actors > 0): both sources feed the
@@ -181,8 +180,8 @@ class DeviceActorPool:
             0, cfg.resolved_warmup_uniform() - int(warmup_offset)
         )
 
-        # Envs shard over 'data' when divisible; replicate otherwise (the
-        # ondevice.py rule — physics FLOPs are negligible either way).
+        # Envs shard over 'data' when divisible; replicate otherwise
+        # (physics FLOPs are negligible either way).
         data_size = self.mesh.shape["data"]
         env_axis = "data" if E % data_size == 0 else None
 
@@ -192,9 +191,8 @@ class DeviceActorPool:
             splits 4 ways so the host-stepped parity reference in the
             tests can replay the exact stream) plus this pool's episode
             accounting. The warmup gate reads the pool's OWN cumulative
-            step counter (the ondevice monolith gates on its ring fill;
-            this pool shares the ring with other sources, so it counts
-            its own production instead)."""
+            step counter, not the ring's fill: the pool shares the ring
+            with other sources, so it counts its own production."""
             key, ou, action, out, rows = vector_env_step(
                 cfg, env, E, params, carry.env_state, carry.obs, carry.ou,
                 carry.key, scale, offset, low, high,

@@ -89,8 +89,10 @@ class Suppression:
 
 class Module:
     """One parsed source file: path (relative to the lint root), raw text,
-    line list, and the ast.Module tree (None when the file failed to
-    parse — the engine reports `parse-error` and rules skip it).
+    line list, the ast.Module tree (None when the file failed to parse —
+    the engine reports `parse-error` and rules skip it) and `nodes`, every
+    node of the tree in `ast.walk` order: the module is walked once, and a
+    rule that reads the whole module reads that list.
 
     `relpath` (root-relative) is what findings report; `rulepath` is what
     path-scoped rules key on: the path relative to the innermost
@@ -110,10 +112,12 @@ class Module:
         self.text = path.read_text(encoding="utf-8", errors="replace")
         self.lines = self.text.splitlines()
         self.tree: Optional[ast.Module] = None
+        self.nodes: List[ast.AST] = []
         self.parse_error: Optional[SyntaxError] = None
         self._stmt_spans: Optional[List[Tuple[int, int]]] = None
         try:
             self.tree = ast.parse(self.text, filename=str(path))
+            self.nodes = list(ast.walk(self.tree))
         except SyntaxError as e:
             self.parse_error = e
 
@@ -128,14 +132,11 @@ class Module:
         exactly what the class-header anchoring of observability-drift
         findings exists to prevent."""
         if self._stmt_spans is None:
-            spans: List[Tuple[int, int]] = []
-            if self.tree is not None:
-                for node in ast.walk(self.tree):
-                    if isinstance(node, ast.stmt) and not hasattr(node, "body"):
-                        spans.append(
-                            (node.lineno, node.end_lineno or node.lineno)
-                        )
-            self._stmt_spans = spans
+            self._stmt_spans = [
+                (node.lineno, node.end_lineno or node.lineno)
+                for node in self.nodes
+                if isinstance(node, ast.stmt) and not hasattr(node, "body")
+            ]
         best, best_size = (line, line), None
         for a, b in self._stmt_spans:
             if a <= line <= b and (best_size is None or b - a < best_size):

@@ -36,7 +36,7 @@ executing or compiling anything — and checks the artifact:
 
 Program specs come from cheap `program_specs()` hooks on each subsystem
 that owns a jitted program (parallel/learner.py, replay/device.py,
-actors/device_pool.py, serve/server.py, ondevice.py) — each builds its
+actors/device_pool.py, serve/server.py) — each builds its
 hot programs tiny (8-wide batches, 16-wide hiddens, chunks of 2) under
 the 2-device CPU probe mesh. jit is lazy, so building costs tracing
 only; the whole live-tree run stays under a 30 s CPU budget
@@ -588,7 +588,6 @@ SPEC_MODULES = (
     "distributed_ddpg_tpu.replay.device",
     "distributed_ddpg_tpu.actors.device_pool",
     "distributed_ddpg_tpu.serve.server",
-    "distributed_ddpg_tpu.ondevice",
 )
 
 

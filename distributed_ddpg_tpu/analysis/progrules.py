@@ -95,9 +95,9 @@ class _StaticJitScan:
     narrow the same way (plain/annotated assigns, no alias chasing;
     the binding shapes come from _DonationScan._binding)."""
 
-    def __init__(self, tree: ast.Module):
+    def __init__(self, nodes: List[ast.AST]):
         self.static: Dict[str, Tuple[int, ...]] = {}
-        for node in ast.walk(tree):
+        for node in nodes:
             bind = _DonationScan._binding(node)
             if bind is None:
                 continue
@@ -170,15 +170,15 @@ class RecompileHazard(Rule):
     def check_module(self, module: Module, ctx: LintContext) -> Iterable[Finding]:
         if module.tree is None:
             return
-        statics = _StaticJitScan(module.tree).static
+        statics = _StaticJitScan(module.nodes).static
         fndefs: Dict[str, ast.FunctionDef] = {
             n.name: n
-            for n in ast.walk(module.tree)
+            for n in module.nodes
             if isinstance(n, (ast.FunctionDef, ast.AsyncFunctionDef))
         }
 
         def findings():
-            for node in ast.walk(module.tree):
+            for node in module.nodes:
                 if isinstance(node, (ast.For, ast.While)):
                     yield from self._scan_loop(module, node)
                 elif isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef)):

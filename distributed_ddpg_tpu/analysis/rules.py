@@ -120,7 +120,7 @@ class CollectiveDiscipline(Rule):
         # spelled-out `lax.psum` match.
         direct: Set[str] = set()
         lax_mods: Set[str] = {"lax", "jax.lax"}
-        for node in ast.walk(module.tree):
+        for node in module.nodes:
             if isinstance(node, ast.ImportFrom):
                 if node.module == "jax.lax":
                     for a in node.names:
@@ -247,7 +247,7 @@ class TimeoutDiscipline(Rule):
         # sleep`, `from concurrent.futures import wait`): same semantics
         # as their attribute forms, same rule.
         bare: Dict[str, str] = {}
-        for node in ast.walk(module.tree):
+        for node in module.nodes:
             if isinstance(node, ast.ImportFrom):
                 if node.module == "time":
                     for a in node.names:
@@ -257,7 +257,7 @@ class TimeoutDiscipline(Rule):
                     for a in node.names:
                         if a.name == "wait":
                             bare[a.asname or a.name] = "futures_wait"
-        for node in ast.walk(module.tree):
+        for node in module.nodes:
             if not isinstance(node, ast.Call):
                 continue
             func = node.func
@@ -393,12 +393,12 @@ class _DonationScan:
             return [node.target], node.value
         return None
 
-    def __init__(self, tree: ast.Module):
+    def __init__(self, nodes: List[ast.AST]):
         self.donated: Dict[str, Tuple[int, ...]] = {}
         factories: Dict[str, Tuple[int, ...]] = {}
         # Two passes so a factory defined after first use still resolves
         # (order in a class body is not execution order).
-        for node in ast.walk(tree):
+        for node in nodes:
             # Local-def factory: a helper whose own `return` hands back a
             # jax.jit(..., donate_argnums=...) — `_jit_per_chunk` in
             # parallel/learner.py. Calling it binds the target to the
@@ -429,7 +429,7 @@ class _DonationScan:
                             tn = dotted(t)
                             if tn:
                                 factories[tn] = pos
-        for node in ast.walk(tree):
+        for node in nodes:
             bind = self._binding(node)
             if bind is None:
                 continue
@@ -472,11 +472,11 @@ class DonationSafety(Rule):
     def check_module(self, module: Module, ctx: LintContext) -> Iterable[Finding]:
         if module.tree is None:
             return ()
-        scan = _DonationScan(module.tree)
+        scan = _DonationScan(module.nodes)
         if not scan.donated:
             return ()
         findings: List[Finding] = []
-        for node in ast.walk(module.tree):
+        for node in module.nodes:
             if isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef)):
                 self._scan_function(module, node, scan.donated, findings)
         return findings
@@ -643,7 +643,7 @@ class TypedErrorContract(Rule):
         family = _TYPED_ERROR_DIRS.get(subsystem)
         if family is None or "/" not in module.rulepath:
             return
-        for node in ast.walk(module.tree):
+        for node in module.nodes:
             if not isinstance(node, ast.Raise) or node.exc is None:
                 continue
             exc = node.exc
@@ -696,7 +696,7 @@ class LockDiscipline(Rule):
         # one is visited both by the outer scan's recursion and by its own
         # ast.walk hit — the same blocking call must report once.
         seen: Set[Tuple[int, int, str]] = set()
-        for node in ast.walk(module.tree):
+        for node in module.nodes:
             if not isinstance(node, ast.With):
                 continue
             if not any(self._is_dispatch_lock(i.context_expr)
@@ -1017,7 +1017,7 @@ class ExitCodeLiteral(Rule):
     def check_module(self, module: Module, ctx: LintContext) -> Iterable[Finding]:
         if module.tree is None or module.rulepath == _EXITS_MODULE:
             return
-        for node in ast.walk(module.tree):
+        for node in module.nodes:
             if isinstance(node, ast.Call):
                 name = dotted(node.func) or ""
                 leaf = name.rsplit(".", 1)[-1]

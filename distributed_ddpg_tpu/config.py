@@ -123,9 +123,9 @@ class DDPGConfig:
     # adam_b1 0.5, action_insert_layer 0, both learning rates 1e-3.
     crossq: bool = False
     # Adam's beta_1, for every net's optimiser and the temperature's
-    # (ops/optim.py; 0.9 there where unset). The megakernel, fused_update
-    # and the native backend hold 0.9 as a constant: another value takes
-    # the scan leg and is refused by the other two.
+    # (ops/optim.py; 0.9 there where unset). The megakernel and the native
+    # backend hold 0.9 as a constant: another value takes the scan leg on
+    # the one and is refused by the other.
     adam_b1: float = 0.9
 
     # --- replay (SURVEY.md §2 #5/#7) ---
@@ -160,16 +160,15 @@ class DDPGConfig:
     # single-writer snapshot spans the shards).
     replay_sharding: str = "replicated"
     # Device-replay ingest pipeline (replay/device.py; docs/INGEST.md).
-    # ingest_async moves single-process host->HBM shipping onto a
-    # background shipper thread (bounded by the staging ring; a full ring
-    # blocks the drain — backpressure) so insert dispatch overlaps learner
-    # compute. Forced off under strict_sync (row-landing timing would make
-    # the sampled stream a function of host scheduling, breaking the
-    # bit-identical-two-runs contract) and on multi-host (rows leave only
-    # via the lockstep sync_ship collective). ingest_coalesce caps how
-    # many staged blocks fold into one device_put + jitted scatter
-    # (power-of-two groups; 1 = the seed's serial block-at-a-time ships).
-    ingest_async: bool = True
+    # A single-process run ships host->HBM off the learner thread (bounded
+    # by the staging ring; a full ring blocks the drain — backpressure) so
+    # insert dispatch overlaps learner compute; not under strict_sync
+    # (row-landing timing would make the sampled stream a function of host
+    # scheduling, breaking the bit-identical-two-runs contract) nor on
+    # multi-host (rows leave only via the lockstep sync_ship collective).
+    # ingest_coalesce caps how many staged blocks fold into one device_put
+    # + jitted scatter (power-of-two groups; 1 = the seed's serial
+    # block-at-a-time ships).
     ingest_coalesce: int = 8
     # --- unified transfer scheduler (transfer/; docs/TRANSFER.md) ---
     # One dispatch thread owns every host<->device stream — replay-ingest
@@ -178,22 +177,18 @@ class DDPGConfig:
     # classes fair-queued by bytes so prefetch never starves under an
     # ingest flood (and vice versa). Forced off under strict_sync: the
     # scheduler thread's dispatch timing would make the metrics stream a
-    # function of host scheduling.
+    # function of host scheduling. With the scheduler run two policies of
+    # its ingest lane: the adaptive coalesce controller
+    # (transfer/adaptive.py: the EFFECTIVE cap grows x2, up to
+    # ingest_coalesce, while the staging queue trends up and shrinks on
+    # dispatch stall; replay contents are bit-identical to the serial path
+    # for ANY cap sequence, but the trajectory is wall-clock-driven;
+    # single-process shipping only, since the lockstep collective keeps
+    # the static cap so every process computes the identical k sequence)
+    # and the staged host-buffer pool (transfer/hostbuf.py: the per-ship
+    # staging copy recycles long-lived buffers fenced on the consuming
+    # insert).
     transfer_scheduler: bool = True
-    # Adaptive ingest_coalesce controller (transfer/adaptive.py): the
-    # EFFECTIVE coalesce cap grows (x2, up to ingest_coalesce) while the
-    # staging queue trends up and shrinks on dispatch stall. Replay
-    # contents are bit-identical to the serial path for ANY cap sequence;
-    # strict_sync disables it anyway because the cap trajectory (hence
-    # the ingest_coalesce_mean metric) is wall-clock-driven. Single-
-    # process shipping only — the lockstep collective keeps the static
-    # cap so every process computes the identical k sequence.
-    ingest_coalesce_adaptive: bool = True
-    # Staged host-buffer pool for super-block device_put
-    # (transfer/hostbuf.py): recycles the per-ship staging copy through
-    # long-lived buffers fenced on the consuming insert, cutting the
-    # pageable alloc+copy churn out of ingest_ship_ms.
-    transfer_host_pool: bool = True
     # Multi-host: run the lockstep sync_ship collective as BACKGROUND
     # beats on the scheduler's ordered lane (replay/device.py
     # sync_ship_begin) instead of blocking the learner thread at every
@@ -286,10 +281,10 @@ class DDPGConfig:
     # donated insert: no host staging, no transfer-scheduler ingest class,
     # zero host<->device bytes on the experience path. Param refresh is a
     # device-side pointer swap from the learner's live params. Requires a
-    # JAX env implementation (has_jax_env), validated at parse. Unlike
-    # backend='jax_ondevice' (the fused monolith), the learner keeps its
-    # full feature set — PER, guardrails, serving, multi-host — and the
-    # host pool can run alongside (num_actors > 0) feeding the same replay.
+    # JAX env implementation (has_jax_env), validated at parse. The
+    # learner keeps its full feature set — PER, guardrails, serving,
+    # multi-host — and the host pool can run alongside (num_actors > 0)
+    # feeding the same replay.
     actor_backend: str = "host"
     # E: vectorized envs advanced per device-actor chunk (the rollout's
     # vmap width). Thousands are cheap on a TPU — env physics is a few
@@ -357,9 +352,8 @@ class DDPGConfig:
     # of production smoothing, not to buffer stalls: a full ring BLOCKS its
     # worker (worker.py flush), mirroring the queue transport's backpressure.
     shm_ring_rows: int = 4096
-    # {"native", "jax_tpu", "jax_ondevice"} (BASELINE.json:5). jax_ondevice
-    # runs env physics + replay + learner fused in one XLA program
-    # (ondevice.py); num_actors then means on-device vector envs.
+    # {"native", "jax_tpu"} (BASELINE.json:5): the numpy CPU reference,
+    # or the sharded JAX learner with host or on-device actors.
     backend: str = "jax_tpu"
     data_axis: int = -1              # -1: all devices on data axis
     # Tensor-parallel degree over hidden dims (the mesh's 'model' axis).
@@ -412,7 +406,6 @@ class DDPGConfig:
     # that overhead to a fixed fraction of wall time while
     # param_refresh_every keeps the learner-step semantics.
     param_refresh_interval_s: float = 0.1
-    prefetch_depth: int = 2          # host->HBM double-buffer depth
     # Learner steps per dispatch (lax.scan / megakernel chunk length) in
     # train_jax. 0 = auto: 800 on kernel-native TPU backends — the length
     # all three benchmark cells run (PERF.md §5: 4.2, 17.5 and 48.5 ms a
@@ -424,7 +417,6 @@ class DDPGConfig:
 
     # --- precision ---
     compute_dtype: str = "float32"   # bit-comparability oracle needs f32
-    fused_update: bool = False       # pallas fused Adam+Polyak kernel
     # Pallas megakernel: the whole K-step chunk in one kernel launch, params
     # VMEM-resident across the chunk (ops/fused_chunk.py). "auto" uses it on
     # the single-device TPU sample-chunk path whenever the config is in the
@@ -672,10 +664,12 @@ class DDPGConfig:
         return math.isnan(self.v_min)
 
     def __post_init__(self):
-        if self.backend not in ("native", "jax_tpu", "jax_ondevice"):
+        if self.backend not in ("native", "jax_tpu"):
             raise ValueError(
-                "backend must be 'native', 'jax_tpu', or 'jax_ondevice', "
-                f"got {self.backend!r}"
+                f"backend must be 'native' or 'jax_tpu', got {self.backend!r}"
+                " (envs, replay and learner all on the chip is "
+                "backend='jax_tpu' with actor_backend='device', and "
+                "fused_beat for one program a beat)"
             )
         if self.n_step < 1:
             raise ValueError("n_step must be >= 1")
@@ -709,8 +703,8 @@ class DDPGConfig:
             if self.backend != "jax_tpu":
                 raise ValueError(
                     "replay_sharding='sharded' partitions the DeviceReplay "
-                    "HBM ring over the jax_tpu mesh; the native/ondevice "
-                    "backends have no sharded ring"
+                    "HBM ring over the jax_tpu mesh; the native backend "
+                    "has no sharded ring"
                 )
             if self.host_replay:
                 raise ValueError(
@@ -768,8 +762,7 @@ class DDPGConfig:
                 raise ValueError(
                     "model_axis > 1 shards params over a jax mesh; the "
                     "native numpy backend has no mesh — use "
-                    "backend='jax_tpu' (or 'jax_ondevice'), or set "
-                    "model_axis=1"
+                    "backend='jax_tpu', or set model_axis=1"
                 )
             if self.fused_chunk == "on":
                 raise ValueError(
@@ -835,12 +828,6 @@ class DDPGConfig:
                 "atoms over a near-infinite range cannot resolve real "
                 "returns — pass concrete bounds for undiscounted setups"
             )
-        if v_min_auto and self.backend == "jax_ondevice":
-            raise ValueError(
-                "v_min/v_max='auto' sizes the support from host-visible "
-                "warmup replay rewards; the fused on-device backend has no "
-                "such window — pass concrete bounds"
-            )
         if not v_min_auto and self.distributional and self.v_min >= self.v_max:
             raise ValueError(
                 f"v_min ({self.v_min}) must be < v_max ({self.v_max})"
@@ -873,17 +860,10 @@ class DDPGConfig:
             )
         if not 0.0 <= self.adam_b1 < 1.0:
             raise ValueError("adam_b1 must be in [0, 1)")
-        if self.adam_b1 != 0.9 and (self.backend == "native" or self.fused_update):
+        if self.adam_b1 != 0.9 and self.backend == "native":
             raise ValueError(
                 "adam_b1 is read by the tree-level Adam (ops/optim.py) only: "
-                "the native backend and the fused_update kernel hold 0.9 as "
-                "a constant"
-            )
-        if self.sac and self.fused_update:
-            raise ValueError(
-                "sac composes with the stock Adam+Polyak tree update (the "
-                "alpha scalar rides the same path), not the fused_update "
-                "kernel"
+                "the native backend's formulas hold 0.9 as a constant"
             )
         if self.sac and self.backend == "native":
             raise ValueError(
@@ -894,11 +874,6 @@ class DDPGConfig:
             raise ValueError("sac_alpha must be > 0 (it is exp(log_alpha))")
         if self.sac_log_std_min >= self.sac_log_std_max:
             raise ValueError("sac_log_std_min must be < sac_log_std_max")
-        if self.twin_critic and self.fused_update:
-            raise ValueError(
-                "twin_critic composes with the stock Adam+Polyak tree update"
-                " (delayed via lax.cond), not the fused_update kernel"
-            )
         if self.twin_critic and self.backend == "native":
             raise ValueError(
                 "twin_critic requires a JAX backend: the native numpy "
@@ -919,8 +894,7 @@ class DDPGConfig:
                 raise ValueError(
                     "strict_sync is a train_jax (jax_tpu backend) debug "
                     "mode; the native backend is already single-threaded "
-                    "and deterministic, and the fused on-device backend "
-                    "has no host actor loop to make lockstep"
+                    "and deterministic"
                 )
             if self.max_learn_ratio <= 0 or self.max_ingest_ratio <= 0:
                 raise ValueError(
@@ -972,8 +946,7 @@ class DDPGConfig:
             if self.backend != "jax_tpu":
                 raise ValueError(
                     "serve_actors serves the actor POOL (jax_tpu backend); "
-                    "the native/ondevice backends have no worker fleet to "
-                    "serve"
+                    "the native backend has no worker fleet to serve"
                 )
             if self.strict_sync:
                 raise ValueError(
@@ -1067,7 +1040,6 @@ class DDPGConfig:
 
         if (
             self.env_id in DEVICE_ONLY
-            and self.backend != "jax_ondevice"
             and (self.actor_backend != "device" or self.num_actors > 0)
         ):
             raise ValueError(
@@ -1080,8 +1052,7 @@ class DDPGConfig:
                 raise ValueError(
                     "actor_backend='device' runs the vectorized rollout "
                     "loop inside the jax_tpu trainer; the native backend "
-                    "has no device, and jax_ondevice already fuses its "
-                    "envs into the learner monolith — use backend='jax_tpu'"
+                    "has no device — use backend='jax_tpu'"
                 )
             # Lazy import: jax_envs pulls in jax, which config parsing must
             # not pay for on the (default) host path.
@@ -1148,8 +1119,7 @@ class DDPGConfig:
             if self.backend != "jax_tpu":
                 raise ValueError(
                     "fused_beat='on' fuses the jax_tpu training loop; the "
-                    "native backend has no device programs and "
-                    "jax_ondevice is already a fused monolith (ondevice.py)"
+                    "native backend has no device programs"
                 )
             if self.actor_backend != "device":
                 raise ValueError(
@@ -1211,8 +1181,8 @@ class DDPGConfig:
             if self.backend != "jax_tpu":
                 raise ValueError(
                     "guardrails instrument the sharded-learner chunk "
-                    "programs (jax_tpu backend); the native/ondevice "
-                    "backends have no probe slot"
+                    "programs (jax_tpu backend); the native backend has "
+                    "no probe slot"
                 )
             if self.fused_chunk == "on":
                 raise ValueError(

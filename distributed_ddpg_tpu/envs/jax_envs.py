@@ -4,9 +4,11 @@
 The reference (and the `jax_tpu` backend here) steps CPU envs in worker
 processes. For envs whose dynamics are a few FLOPs of arithmetic, that
 topology leaves the accelerator idle between batches; these implementations
-express the dynamics as pure JAX functions so the WHOLE actor-learner loop —
-policy forward, exploration noise, env physics, replay insert, learner
-update — compiles into one XLA program (ondevice.py). vmap supplies the
+express the dynamics as pure JAX functions so the rollout — policy forward,
+exploration noise, env physics — compiles into one XLA program
+(actors/device_pool.py) whose rows land in the replay ring without leaving
+the device, and the fused beat (parallel/megastep.py) puts the insert and
+the learner's chunk in the same program. vmap supplies the
 batch dimension: one `step` call advances E envs in lockstep on the MXU/VPU.
 
 API (functional, scan/vmap-friendly; no Python state):
@@ -127,7 +129,7 @@ class JaxMountainCar:
     with gymnasium's continuous_mountain_car (power=0.0015, gravity term
     0.0025*cos(3x), goal at x>=0.45 with vel>=0, +100 terminal reward,
     -0.1*a^2 action cost, 999-step time limit) — asserted against the real
-    gymnasium env by tests/test_ondevice.py. Unlike Pendulum this env truly
+    gymnasium env by tests/test_jax_envs.py. Unlike Pendulum this env truly
     TERMINATES, exercising the terminated/truncated split end to end."""
 
     power = 0.0015

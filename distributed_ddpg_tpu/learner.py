@@ -343,7 +343,7 @@ def make_learner_step(
     )
     # Only a step handed no noise draws for itself, and holds the base key
     # as a constant of its program: the single-step programs (agent.py,
-    # ondevice.py, ShardedLearner.step). Every chunk program pre-draws from
+    # ShardedLearner.step). Every chunk program pre-draws from
     # the base it takes as an argument (chunk_noise) and passes `noise`.
     base_key = noise_base_key(config)
 
@@ -688,24 +688,6 @@ def make_learner_step(
                 _delayed_update,
                 _skip_update,
                 operand=None,
-            )
-        elif config.fused_update:
-            with device_scope("actor"):
-                aloss, agrads = jax.value_and_grad(actor_loss_fn)(
-                    state.actor_params
-                )
-                agrads = _maybe_psum_mean(agrads, axis_name)
-            actor_grad_norm = optree_norm(agrads)
-            # Pallas kernel: Adam + Polyak in one VPU pass (ops/fused_update.py).
-            from distributed_ddpg_tpu.ops.fused_update import fused_adam_polyak
-
-            new_critic, critic_opt, new_target_critic = fused_adam_polyak(
-                state.critic_params, cgrads, state.critic_opt,
-                state.target_critic_params, config.critic_lr, config.tau,
-            )
-            new_actor, actor_opt, new_target_actor = fused_adam_polyak(
-                state.actor_params, agrads, state.actor_opt,
-                state.target_actor_params, config.actor_lr, config.tau,
             )
         else:
             with device_scope("actor"):

@@ -1,25 +1,22 @@
-"""Shared vectorized exploration + env-step body for the two on-device
-rollout loops — the fused monolith (`ondevice.py`) and the device-actor
-pool (`actors/device_pool.py`).
+"""The vectorized exploration + env-step body of the on-device rollout
+loop, the device-actor pool (`actors/device_pool.py`).
 
-Both backends advance E vmapped JAX envs per scan iteration with the same
-semantics: per-env OU noise (or SAC's on-device tanh-Gaussian sampling),
+The pool advances E vmapped JAX envs per scan iteration: per-env OU noise
+(or SAC's on-device tanh-Gaussian sampling),
 a = clip(mu(s) + ou * scale, bounds), optional uniform-warmup override,
 vmapped `env.step` with auto-reset, and the packed transition rows in
 `types.pack_batch_np` column order with the bootstrap discount folding
 TRUE termination (`gamma * (1 - terminated)`; time-limit truncation keeps
-bootstrapping — the jax_envs.StepOut contract). Keeping the body in one
-place means an exploration fix or a wire-format change cannot silently
-diverge the two backends; only the params source and the warmup-gate
-basis (replay-ring fill vs the pool's own step counter) differ, and both
-ride in as arguments.
+bootstrapping — the jax_envs.StepOut contract). The body is apart from
+the pool's episode accounting so that a host-stepped reference can call
+it one step at a time; the params and the warmup gate ride in as
+arguments.
 
 PRNG discipline: the caller's `key` ALWAYS splits 4 ways
 (next, ou/sac-sample, env, uniform) in this order, whether or not the
 SAC/warmup branches consume their splits — that is what lets a
 host-stepped parity reference (tests/test_device_actors.py) replay the
-exact stream, and it keeps existing seeds' streams stable across both
-backends.
+exact stream, and it keeps existing seeds' streams stable.
 """
 
 from __future__ import annotations
@@ -122,8 +119,8 @@ def vector_env_step(
 
     `warmup_active`: None = no uniform-warmup override compiled in
     (static off); else a traced bool[] — where True, actions are drawn
-    uniformly from the action box instead of the policy (each backend
-    supplies its own gate basis).
+    uniformly from the action box instead of the policy (the pool gates on
+    its own cumulative step counter).
 
     Returns `(next_key, new_ou, action, out, rows)` where `out` is the
     vmapped StepOut, `new_ou` is the OU state with done envs reset to the

@@ -265,7 +265,7 @@ def supported(config: DDPGConfig) -> bool:
         and config.critic_l2 == 0.0
         # REDQ, CrossQ: no kernel branch; the kernel's Adam holds beta_1
         # as the constant B1
-        and not (config.fused_update or config.redq or config.crossq)
+        and not (config.redq or config.crossq)
         and config.adam_b1 == B1
         and config.compute_dtype in ("float32", "bfloat16")
         # The hand-written backward assumes the action-insert layer (1) is
@@ -955,8 +955,8 @@ def _make_kernel(
 def runs_native() -> bool:
     """True when the current backend compiles pallas TPU kernels with
     Mosaic; elsewhere they run in interpret mode (correct, far slower).
-    The one platform predicate both kernels (this file, fused_update.py)
-    and every auto-sized chunk length key on."""
+    The one platform predicate the kernels and every auto-sized chunk
+    length key on."""
     return jax.default_backend() == "tpu"
 
 
@@ -976,7 +976,7 @@ def make_fused_chunk_fn(
     if not supported(config):
         raise ValueError(
             "fused chunk kernel envelope: action_insert_layer=1, "
-            "critic_l2=0, fused_update=False, >=2 critic hidden layers, "
+            "critic_l2=0, >=2 critic hidden layers, "
             ">=1 actor hidden, num_atoms<=256 when distributional"
         )
     if not fits_vmem(config, obs_dim, act_dim):

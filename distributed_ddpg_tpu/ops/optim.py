@@ -1,12 +1,10 @@
 """Explicit Adam, tree-level.
 
-Written out (rather than hidden behind an optimizer-library object) for three
+Written out (rather than hidden behind an optimizer-library object) for two
 reasons tied to this framework's contract:
 1. the numpy `native` backend must produce bit-comparable updates
    (BASELINE.json:5) — same formulas, same order of operations;
-2. the pallas fused Adam+Polyak kernel (ops/fused_update.py) needs the
-   scalar math exposed;
-3. the whole update lives inside the one jitted learner step — there is no
+2. the whole update lives inside the one jitted learner step — there is no
    optimizer.apply_gradients host round trip like the reference's
    parameter-server path (SURVEY.md §3.3).
 

@@ -163,9 +163,10 @@ class ShardedLearner:
         self.mode = mode
         self.chunk_size = int(chunk_size)
         # Scan-body unroll factor. Each learner step is ~25 small (<=64x256x256)
-        # ops, so per-iteration scan overhead is material: unroll=4 measured
-        # 89.5k vs 59.5k steps/s (v5e-1, chunk=800, pre-gathered batches).
-        # lax.scan handles unroll > length, so no clamping to chunk sizes.
+        # ops, so per-iteration scan overhead is material and 4 steps share
+        # one; the factor has not been swept on the chip since the benchmark
+        # (the five scan-leg cells run 4). lax.scan handles unroll > length,
+        # so no clamping to chunk sizes.
         # (Rejecting <1 rather than clamping: lax.scan gives unroll=0 its own
         # meaning — full unroll — which a silent clamp would invert.)
         if int(unroll) < 1:
@@ -401,9 +402,10 @@ class ShardedLearner:
         # them in ONE [K*B]-row gather. Storage is immutable for the whole
         # dispatch (ingest lands between chunks), so the distribution is
         # identical to sampling inside the scan body — but one fused gather
-        # replaces K tiny ones: 59.5k -> 89.5k steps/s with unroll=4
-        # (v5e-1, chunk=800). Shared by the scan and megakernel paths so
-        # their index streams stay bit-identical (parity tests rely on it).
+        # replaces K tiny ones (`chunk.sample_ms` in the ledger is the draw
+        # and the gather of a launch). Shared by the scan and megakernel
+        # paths so their index streams stay bit-identical (parity tests rely
+        # on it).
         def draw_chunk_idx(key, size):
             with trace.device_scope("draw"):
                 key, sub = jax.random.split(key)
@@ -558,8 +560,8 @@ class ShardedLearner:
                 "envelope: needs mode='auto', a single-device or data-only "
                 "mesh (model_axis == 1, and fused_mesh != 'off' for "
                 "multi-device), plus action_insert_layer=1, critic_l2=0, "
-                "fused_update=False, >=2 critic hidden layers, and nets "
-                "small enough for VMEM (ops/fused_chunk.fits_vmem)"
+                ">=2 critic hidden layers, and nets small enough for VMEM "
+                "(ops/fused_chunk.fits_vmem)"
             )
         scan_sample_chunk_fn = sample_chunk_fn
         fused_run = None  # set on the single-device kernel path; PER reuses it

@@ -42,7 +42,6 @@ _OWNER_FILES = (
     "distributed_ddpg_tpu/replay/device.py",
     "distributed_ddpg_tpu/actors/device_pool.py",
     "distributed_ddpg_tpu/serve/server.py",
-    "distributed_ddpg_tpu/ondevice.py",
 )
 _WATCH_PREFIXES = (
     "distributed_ddpg_tpu/analysis/",

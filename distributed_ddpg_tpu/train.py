@@ -137,8 +137,6 @@ def train(config: DDPGConfig) -> Dict[str, float]:
     if config.backend == "native":
         return train_native(config)
     require_platform()
-    if config.backend == "jax_ondevice":
-        return train_ondevice(config)
     return train_jax(config, entered_at=entered_at)
 
 
@@ -239,170 +237,6 @@ def train_native(config: DDPGConfig) -> Dict[str, float]:
         "learner_steps": learn_steps,
         "final_return": final_return,
     }
-
-
-# ---------------------------------------------------------------------------
-# --backend jax_ondevice: env + replay + learner fused in one XLA program
-# ---------------------------------------------------------------------------
-
-
-def train_ondevice(config: DDPGConfig) -> Dict[str, float]:
-    import jax
-
-    from distributed_ddpg_tpu import checkpoint as ckpt_lib
-    from distributed_ddpg_tpu.actors.policy import NumpyPolicy, actor_head_dim, flatten_params, param_layout
-    from distributed_ddpg_tpu.ondevice import OnDeviceDDPG
-    from distributed_ddpg_tpu.parallel import multihost
-
-    if config.checkpoint_dir:
-        ckpt_lib.warm()
-    multihost.initialize()
-    trainer = OnDeviceDDPG(config)
-    log = MetricsLogger(config.log_path)
-
-    # Resume: the checkpoint contract matches the other backends (TrainState
-    # + replay contents + env-step offset), via a thin adapter for the
-    # carry-resident replay ring.
-    class _ReplayView:
-        def state_dict(self):
-            return trainer.replay_state_dict()
-
-        def load_state_dict(self, d):
-            trainer.load_replay_state(d)
-
-    env_steps_offset = 0
-    last_ckpt = 0
-    if (
-        config.resume
-        and config.checkpoint_dir
-        and ckpt_lib.latest_step(config.checkpoint_dir) is not None
-    ):
-        restored, step, env_steps_offset = ckpt_lib.restore(
-            config.checkpoint_dir,
-            jax.device_get(trainer.state),
-            _ReplayView(),
-            config=config,
-        )
-        trainer.load_train_state(restored)
-        trainer._learn_steps = step
-        last_ckpt = step
-        print(
-            f"resumed from {config.checkpoint_dir} at learner step {step}, "
-            f"env step {env_steps_offset}"
-        )
-
-    spec = _jax_env_spec(trainer)
-    eval_policy = NumpyPolicy(
-        param_layout(
-            spec.obs_dim,
-            actor_head_dim(spec.act_dim, config.sac),
-            tuple(config.actor_hidden),
-        ),
-        spec.action_scale,
-        spec.action_offset,
-        gaussian=config.sac,
-    )
-    profile_cm = (
-        jax.profiler.trace(config.profile_dir)
-        if config.profile_dir
-        else contextlib.nullcontext()
-    )
-    env_timer, learn_timer = Timer(), Timer()
-    last_eval = 0
-    eval_return = None
-
-    def env_steps() -> int:
-        return env_steps_offset + trainer.env_steps
-
-    # Episode stats are per-chunk and sparse (an episode boundary may fall in
-    # any chunk); aggregate across chunks between log events.
-    episodes_acc, return_acc = 0, []
-
-    # superstep_beats > 1: B chunks per dispatch (ondevice.run_superstep),
-    # one stats device_get per superstep instead of per chunk. The log
-    # cadence below becomes a crossing test so a B that doesn't divide
-    # the 10-chunk stride still logs on every stride crossed.
-    beats = max(1, trainer.superstep_beats)
-    rows_per_dispatch = trainer.chunk_size * trainer.num_envs * beats
-    log_stride = trainer.chunk_size * trainer.num_envs * 10
-    with profile_cm:
-        while env_steps() < config.total_env_steps:
-            before = trainer.learn_steps
-            stats = (
-                trainer.run_superstep() if beats > 1 else trainer.run_chunk()
-            )
-            host = trainer.finalize_stats(stats)
-            env_timer.tick(rows_per_dispatch)
-            learn_timer.tick(trainer.learn_steps - before)
-            episodes_acc += host.pop("episodes", 0)
-            if "episode_return" in host:
-                return_acc.append(host.pop("episode_return"))
-            log_now = (
-                trainer.env_steps // log_stride
-                != (trainer.env_steps - rows_per_dispatch) // log_stride
-            )
-            if env_steps() - last_eval >= config.eval_every:
-                eval_policy.load_flat(flatten_params(trainer.actor_params_to_host()))
-                eval_return = _eval_numpy(eval_policy, config, spec)
-                last_eval = env_steps()
-                log.log("eval", env_steps(), eval_return=eval_return)
-            if log_now:
-                log.log(
-                    "train", env_steps(),
-                    learner_steps=trainer.learn_steps,
-                    env_steps_per_sec=env_timer.rate(),
-                    learner_steps_per_sec=learn_timer.rate(),
-                    episodes=episodes_acc,
-                    episode_return=(
-                        float(np.mean(return_acc)) if return_acc else None
-                    ),
-                    **host,
-                )
-                episodes_acc, return_acc = 0, []
-            if (
-                config.checkpoint_dir
-                and trainer.learn_steps - last_ckpt >= config.checkpoint_every
-            ):
-                ckpt_lib.save(
-                    config.checkpoint_dir, trainer.learn_steps,
-                    jax.device_get(trainer.state), _ReplayView(), config,
-                    env_steps=env_steps(),
-                    keep=config.checkpoint_keep,
-                )
-                last_ckpt = trainer.learn_steps
-
-    eval_policy.load_flat(flatten_params(trainer.actor_params_to_host()))
-    final_return = _eval_numpy(eval_policy, config, spec)
-    rate = env_timer.rate()
-    ckpt_fields = ckpt_lib.import_fields()
-    log.log(
-        "final", env_steps(),
-        learner_steps=trainer.learn_steps,
-        env_steps_per_sec=rate,
-        learner_steps_per_sec=learn_timer.rate(),
-        final_return=final_return,
-        **ckpt_fields,
-    )
-    log.close()
-    return {
-        "env_steps_per_sec": rate,
-        "learner_steps_per_sec": learn_timer.rate(),
-        "learner_steps": trainer.learn_steps,
-        "final_return": final_return,
-        **ckpt_fields,
-    }
-
-
-def _jax_env_spec(trainer):
-    from distributed_ddpg_tpu.envs.registry import EnvSpec
-
-    env = trainer.env
-    return EnvSpec(
-        obs_dim=env.obs_dim,
-        act_dim=env.act_dim,
-        action_low=np.asarray(env.action_low, np.float32),
-        action_high=np.asarray(env.action_high, np.float32),
-    )
 
 
 # ---------------------------------------------------------------------------
@@ -836,9 +670,7 @@ def _train_jax_impl(
         replay_kwargs = dict(
             mesh=learner.mesh,
             block_size=1024,
-            async_ship=(
-                config.ingest_async and not is_multi and not config.strict_sync
-            ),
+            async_ship=not is_multi and not config.strict_sync,
             max_coalesce=config.ingest_coalesce,
             fault=(
                 fault_plan.site("shipper", "ship") if fault_plan else None
@@ -852,13 +684,8 @@ def _train_jax_impl(
             # it run under strict_sync would break the bit-identical-
             # metrics contract).
             scheduler=transfer_sched,
-            adaptive_coalesce=(
-                transfer_sched is not None
-                and config.ingest_coalesce_adaptive
-            ),
-            host_pool=(
-                transfer_sched is not None and config.transfer_host_pool
-            ),
+            adaptive_coalesce=transfer_sched is not None,
+            host_pool=transfer_sched is not None,
             background_sync=config.sync_ship_background,
             # Guardrail bad-row attribution: map storage positions back
             # to the actor slot that produced them (guardrails.py).
@@ -2560,7 +2387,9 @@ def _train_jax_impl(
         if not use_device_replay and not preempt.is_set():
             prefetch = ChunkPrefetcher(
                 replay, learner.put_chunk, learner.global_batch, chunk,
-                depth=config.prefetch_depth, lock=replay_lock,
+                # Double buffer: one chunk sampled and put while the
+                # learner consumes the other.
+                depth=2, lock=replay_lock,
                 fault=(
                     fault_plan.site("prefetch", "sample")
                     if fault_plan else None

@@ -137,6 +137,38 @@ def test_device_actor_transition_parity_with_host_reference():
     np.testing.assert_allclose(landed, expected, rtol=1e-5, atol=1e-5)
 
 
+def test_mountain_car_rows_at_the_goal_carry_zero_discount():
+    """A built-in environment that truly TERMINATES, through the pool and
+    into the ring: every environment planted just under the goal and moving
+    fast ends on its first step, so the first E rows hold discount 0 and the
+    PRE-reset next observation (past the goal), and the rows of the fresh
+    episodes behind them hold gamma."""
+    cfg = _small_cfg(env_id="MountainCarContinuous-v0")
+    mesh = _one_device_mesh()
+    pool, _ = _pool_with_params(cfg, mesh)
+    env, carry = pool.env, pool._carry
+    planted = carry.env_state._replace(
+        pos=jnp.full_like(carry.env_state.pos, 0.449),
+        vel=jnp.full_like(carry.env_state.vel, 0.07),
+    )
+    pool._carry = jax.device_put(
+        carry._replace(env_state=planted, obs=jax.vmap(env.observe)(planted)),
+        pool._carry_sharding,
+    )
+    replay = DeviceReplay(cfg.replay_capacity, pool.obs_dim, pool.act_dim,
+                          mesh=mesh, block_size=64, async_ship=False)
+    assert pool.run_chunk(replay) == K * E
+    rows = np.asarray(jax.device_get(replay.storage))[: K * E]
+    obs_dim, act_dim = pool.obs_dim, pool.act_dim
+    discount = rows[:, obs_dim + act_dim + 1]
+    next_pos = rows[:, obs_dim + act_dim + 2]
+    np.testing.assert_allclose(rows[:E, 0], 0.449, atol=1e-6)
+    assert np.all(discount[:E] == 0.0)
+    assert np.all(next_pos[:E] >= env.goal_position)
+    assert np.all(discount[E:] == np.float32(cfg.gamma))
+    assert np.all(next_pos[E:] < env.goal_position)
+
+
 def test_insert_device_rows_wraparound_and_per_stamp():
     """The donated device insert honors ring wraparound, and the PER
     subclass stamps landed rows with the running max priority (the

@@ -287,12 +287,8 @@ def test_sac_config_gates():
         DDPGConfig(sac=True, twin_critic=True)
     with pytest.raises(ValueError, match="family"):
         DDPGConfig(sac=True, distributional=True)
-    with pytest.raises(ValueError, match="fused_update"):
-        DDPGConfig(sac=True, fused_update=True)
     with pytest.raises(ValueError, match="backend"):
         DDPGConfig(sac=True, backend="native")
-    # ondevice composes (tests/test_ondevice.py::test_ondevice_runs_all_families).
-    DDPGConfig(sac=True, backend="jax_ondevice")
     with pytest.raises(ValueError, match="sac_alpha"):
         DDPGConfig(sac=True, sac_alpha=0.0)
     with pytest.raises(ValueError, match="log_std"):

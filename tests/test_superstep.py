@@ -231,88 +231,6 @@ def test_superstep_config_validation():
     assert _cfg(superstep_beats=4).superstep_beats == 4
 
 
-def _ondevice_cfg(**kw):
-    base = dict(
-        env_id="Pendulum-v1",
-        backend="jax_ondevice",
-        num_actors=8,
-        batch_size=32,
-        replay_capacity=4096,
-        replay_min_size=64,
-        actor_hidden=(32, 32),
-        critic_hidden=(32, 32),
-        total_env_steps=2048,
-        seed=0,
-    )
-    base.update(kw)
-    return DDPGConfig(**base)
-
-
-def test_ondevice_superstep_bit_identical_and_stacked_stats():
-    """The whole-run-fusion rung rides the same oracle: one B=2
-    ondevice superstep == two sequential chunk dispatches (full Carry:
-    train state, env state, ring, RNG), and the stacked ChunkStats
-    finalize to a host dict with the same schema and the summed
-    learn-step count. Pinned to a SINGLE-device mesh: that is where the
-    loop-body isolation argument gives exact codegen parity; the
-    multi-device SPMD path drifts at the ULP level from collective
-    scheduling and is covered (at tolerance) by the test below."""
-    from distributed_ddpg_tpu.ondevice import OnDeviceDDPG
-    from distributed_ddpg_tpu.parallel import mesh as mesh_lib
-
-    mesh = mesh_lib.make_mesh(1, 1, devices=jax.devices("cpu")[:1])
-    t_sup = OnDeviceDDPG(
-        _ondevice_cfg(superstep_beats=2), mesh=mesh, chunk_size=4
-    )
-    t_seq = OnDeviceDDPG(_ondevice_cfg(), mesh=mesh, chunk_size=4)
-
-    # Three rounds so later supersteps run fully past the learn gate.
-    # EVERY chunk's stats are finalized (the counter accumulates there).
-    for _ in range(3):
-        stats = t_sup.run_superstep()
-        host_sup = t_sup.finalize_stats(stats)
-        for _ in range(2):
-            host_seq = t_seq.finalize_stats(t_seq.run_chunk())
-
-    assert t_sup.env_steps == t_seq.env_steps
-    assert t_sup.learn_steps == t_seq.learn_steps
-    assert _leaves_equal(t_sup.carry, t_seq.carry)
-    # Stacked finalize: same schema as the scalar path, finite metrics.
-    assert set(host_sup) == set(host_seq)
-    for k, v in host_sup.items():
-        assert np.isfinite(v), f"{k} not finite in stacked finalize"
-
-
-def test_ondevice_superstep_spmd_matches_at_tolerance():
-    """The SPMD (8 virtual device) ondevice superstep: integer/
-    bookkeeping state (step counters, ring ptr/size, RNG key) stays
-    EXACT vs sequential chunks, and every float leaf agrees to float32
-    tolerance. Bitwise parity is a single-device property — under a
-    multi-device mesh XLA schedules the collectives differently inside
-    the fori_loop body than in the standalone chunk program, an
-    ULP-level reassociation the oracle above cannot demand here."""
-    from distributed_ddpg_tpu.ondevice import OnDeviceDDPG
-
-    t_sup = OnDeviceDDPG(_ondevice_cfg(superstep_beats=2), chunk_size=4)
-    t_seq = OnDeviceDDPG(_ondevice_cfg(), chunk_size=4)
-    for _ in range(3):
-        t_sup.finalize_stats(t_sup.run_superstep())
-        for _ in range(2):
-            t_seq.finalize_stats(t_seq.run_chunk())
-
-    assert t_sup.env_steps == t_seq.env_steps
-    assert t_sup.learn_steps == t_seq.learn_steps
-    for a, b in zip(
-        jax.tree.leaves(t_sup.carry), jax.tree.leaves(t_seq.carry)
-    ):
-        a = np.asarray(jax.device_get(a))
-        b = np.asarray(jax.device_get(b))
-        if a.dtype.kind == "f":
-            np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5)
-        else:
-            assert np.array_equal(a, b)
-
-
 def _train_cfg(tmp_path, **kw):
     base = dict(
         env_id="Pendulum-v1",

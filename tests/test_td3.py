@@ -132,8 +132,6 @@ def test_td3_config_gates():
         DDPGConfig(twin_critic=True, distributional=True)
     with pytest.raises(ValueError, match="oracle"):
         DDPGConfig(twin_critic=True, backend="native")
-    with pytest.raises(ValueError, match="fused_update"):
-        DDPGConfig(twin_critic=True, fused_update=True)
     # TD3 knobs without twin_critic would silently do nothing.
     with pytest.raises(ValueError, match="silently"):
         DDPGConfig(policy_delay=2)

@@ -343,6 +343,32 @@ def test_learner_chunk_resolution():
         DDPGConfig(actor_throttle_s=-0.1)
 
 
+def test_backend_jax_ondevice_is_refused_with_where_it_went():
+    with pytest.raises(ValueError, match="backend must be") as refused:
+        DDPGConfig(backend="jax_ondevice")
+    said = str(refused.value)
+    assert "'jax_ondevice'" in said
+    assert "backend='jax_tpu' with actor_backend='device'" in said
+    assert "fused_beat" in said
+
+
+def test_every_config_field_is_read_outside_config():
+    # A knob whose last reader was deleted must not linger in DDPGConfig.
+    import dataclasses
+    import pathlib
+    import re
+
+    import distributed_ddpg_tpu
+
+    package = pathlib.Path(distributed_ddpg_tpu.__file__).parent
+    words = set()
+    for path in package.rglob("*.py"):
+        if path != package / "config.py":
+            words.update(re.findall(r"\w+", path.read_text(encoding="utf-8")))
+    fields = [f.name for f in dataclasses.fields(DDPGConfig)]
+    assert [name for name in fields if name not in words] == []
+
+
 @pytest.mark.slow
 def test_train_jax_max_learn_ratio_caps_learner(tmp_path):
     """max_learn_ratio: the learner may not run ahead of
