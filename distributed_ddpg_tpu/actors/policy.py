@@ -8,6 +8,19 @@ can't contend for the TPU, and can't deadlock a forked XLA runtime.
 Params travel learner -> workers as ONE flat f32 array in shared memory
 (pool.py); `param_layout`/`flatten_params`/`NumpyPolicy.load_flat` define
 the stable layout (layer order, w-then-b, C order).
+
+The layout says what each entry of the block is. A plain MLP's entries are
+pairs `(w_shape, b_shape)`, relu between them and the last one linear: the
+layout and the block every family but one has, unchanged. A residual net's
+(models/mlp.simba_init, `param_layout(..., residual=True)`) entries are
+triples whose third element is the entry's kind (`KINDS`), in the order the
+block holds them: the embedding `linear`; per block `block_ln` (LayerNorm's
+scale then shift, entering the branch), `relu` (w1, b1) and `add` (w2, b2,
+added back onto the stream); then `ln` (the post-LayerNorm) and the head
+`linear`. Its first entry's w is still (obs_dim, width) and its last entry's
+(width, head), so what reads those two shapes reads them as before. The
+input normaliser is not in the block: the learner folds it into the
+embedding at every refresh (models/mlp.fold_rsnorm).
 """
 
 from __future__ import annotations
@@ -16,7 +29,17 @@ from typing import List, Sequence, Tuple
 
 import numpy as np
 
-Layout = List[Tuple[Tuple[int, ...], Tuple[int, ...]]]  # [(w_shape, b_shape)]
+Layout = List[tuple]  # [(w_shape, b_shape)] or [(w_shape, b_shape, kind)]
+
+# A layered entry's kinds. x is the stream, r the value saved at `block_ln`.
+KINDS = ("linear", "block_ln", "relu", "add", "ln")
+# models/mlp.py's LN_EPS and SIMBA_EXPANSION (a worker never imports that
+# module: it loads JAX; tests/test_simba.py holds the two files together)
+LN_EPS = 1e-6
+EXPANSION = 4
+# The leaves of one layer of the learner's tree, in the block's order; a
+# layer holds the ones its kind has.
+LEAF_ORDER = ("ln_scale", "ln_shift", "w1", "b1", "w2", "b2", "w", "b")
 
 
 def encode_version(version: int) -> np.float32:
@@ -37,14 +60,32 @@ def actor_head_dim(act_dim: int, sac: bool) -> int:
     return 2 * act_dim if sac else act_dim
 
 
-def param_layout(obs_dim: int, act_dim: int, hidden: Sequence[int]) -> Layout:
-    """`act_dim` here is the HEAD width — pass actor_head_dim(...) for SAC."""
+def param_layout(
+    obs_dim: int, act_dim: int, hidden: Sequence[int], residual: bool = False
+) -> Layout:
+    """`act_dim` here is the HEAD width — pass actor_head_dim(...) for SAC.
+    `residual` (config.simba): one pre-LayerNorm block per entry of
+    `hidden`, the module docstring's layered layout."""
+    if residual:
+        h = hidden[0]
+        layout = [((obs_dim, h), (h,), "linear")]
+        for _ in hidden:
+            layout += [
+                ((h,), (h,), "block_ln"),
+                ((h, EXPANSION * h), (EXPANSION * h,), "relu"),
+                ((EXPANSION * h, h), (h,), "add"),
+            ]
+        return layout + [((h,), (h,), "ln"), ((h, act_dim), (act_dim,), "linear")]
     dims = [obs_dim, *hidden, act_dim]
     return [((dims[i], dims[i + 1]), (dims[i + 1],)) for i in range(len(dims) - 1)]
 
 
 def layout_size(layout: Layout) -> int:
-    return sum(int(np.prod(w)) + int(np.prod(b)) for w, b in layout)
+    return sum(int(np.prod(w)) + int(np.prod(b)) for w, b, *_ in layout)
+
+
+def is_layered(layout: Layout) -> bool:
+    return len(layout[0]) > 2
 
 
 def seqlock_snapshot(shared, version, out: np.ndarray, seen_version: int):
@@ -68,11 +109,16 @@ def seqlock_snapshot(shared, version, out: np.ndarray, seen_version: int):
 
 def flatten_params(params, out: np.ndarray | None = None) -> np.ndarray:
     """Flatten a (tuple of {'w','b'}) tree into one f32 vector (w then b,
-    layer order). Writes into `out` when given (the shared-memory buffer)."""
-    chunks = []
-    for layer in params:
-        chunks.append(np.asarray(layer["w"], np.float32).ravel())
-        chunks.append(np.asarray(layer["b"], np.float32).ravel())
+    layer order). Writes into `out` when given (the shared-memory buffer).
+    A residual net's layers (as models/mlp.fold_rsnorm hands them on) hold
+    other leaves: each layer's go in LEAF_ORDER, which is the layered
+    layout's order."""
+    chunks = [
+        np.asarray(layer[name], np.float32).ravel()
+        for layer in params
+        for name in LEAF_ORDER
+        if name in layer
+    ]
     flat = np.concatenate(chunks)
     if out is not None:
         out[: flat.size] = flat
@@ -86,7 +132,10 @@ class NumpyPolicy:
     `gaussian=True` mirrors the SAC head (models/mlp.actor_gaussian_apply):
     the final layer is [mean | log_std]; deterministic mode acts on
     tanh(mean), `stochastic=True` samples the tanh-Gaussian with a local
-    numpy RNG (workers explore by sampling the policy — no OU noise)."""
+    numpy RNG (workers explore by sampling the policy — no OU noise).
+
+    A layered layout (`is_layered`) runs the residual net its kinds spell
+    out, LayerNorm and all, on the same block discipline."""
 
     def __init__(
         self,
@@ -107,14 +156,16 @@ class NumpyPolicy:
         self.log_std_min = log_std_min
         self.log_std_max = log_std_max
         self._rng = np.random.default_rng(seed) if stochastic else None
+        # a LayerNorm entry's "w" is its scale and its "b" its shift
         self.layers = [
             {"w": np.zeros(w, np.float32), "b": np.zeros(b, np.float32)}
-            for w, b in layout
+            for w, b, *_ in layout
         ]
+        self.kinds = [e[2] for e in layout] if is_layered(layout) else None
 
     def load_flat(self, flat: np.ndarray) -> None:
         i = 0
-        for layer, (w_shape, b_shape) in zip(self.layers, self.layout):
+        for layer, (w_shape, b_shape, *_) in zip(self.layers, self.layout):
             n = int(np.prod(w_shape))
             layer["w"] = flat[i : i + n].reshape(w_shape).copy()
             i += n
@@ -128,15 +179,49 @@ class NumpyPolicy:
         (serve/server.py): the server ships head rows and applies the
         squash/sampling itself, with per-client keys."""
         x = np.atleast_2d(obs)
+        if self.kinds is not None:
+            return self._layered(x)
         for layer in self.layers[:-1]:
             x = np.maximum(x @ layer["w"] + layer["b"], 0.0)
         return x @ self.layers[-1]["w"] + self.layers[-1]["b"]
 
+    def _layered(self, x: np.ndarray) -> np.ndarray:
+        """The residual net of a layered layout (module docstring)."""
+        saved = None
+        for layer, kind in zip(self.layers, self.kinds):
+            if kind in ("ln", "block_ln"):
+                if kind == "block_ln":
+                    saved = x
+                mean = x.mean(axis=-1, keepdims=True)
+                var = np.square(x - mean).mean(axis=-1, keepdims=True)
+                x = (x - mean) / np.sqrt(var + np.float32(LN_EPS)) * layer["w"] + layer["b"]
+                continue
+            x = x @ layer["w"] + layer["b"]
+            if kind == "relu":
+                x = np.maximum(x, 0.0)
+            elif kind == "add":
+                x = saved + x
+        return x
+
+    def tree(self):
+        """The loaded block as the learner's tree (what `flatten_params`
+        took): a tuple of {w, b} for a plain net, fold_rsnorm's layers for a
+        layered one. The jax serving engine ships it to the device."""
+        if self.kinds is None:
+            return tuple(dict(layer) for layer in self.layers)
+        embed, *rest = self.layers
+        out, i = [dict(embed)], 0
+        while self.kinds[1 + i] == "block_ln":
+            ln, up, down = rest[i : i + 3]
+            out.append({"ln_scale": ln["w"], "ln_shift": ln["b"], "w1": up["w"],
+                        "b1": up["b"], "w2": down["w"], "b2": down["b"]})
+            i += 3
+        ln, head = rest[i:]
+        out.append({"ln_scale": ln["w"], "ln_shift": ln["b"], **head})
+        return tuple(out)
+
     def __call__(self, obs: np.ndarray) -> np.ndarray:
-        x = np.atleast_2d(obs)
-        for layer in self.layers[:-1]:
-            x = np.maximum(x @ layer["w"] + layer["b"], 0.0)
-        x = x @ self.layers[-1]["w"] + self.layers[-1]["b"]
+        x = self.head(obs)
         if self.gaussian:
             mean, log_std_raw = np.split(x, 2, axis=-1)
             if not self.stochastic:

@@ -54,7 +54,7 @@ from distributed_ddpg_tpu.actors.policy import (
 from distributed_ddpg_tpu.actors.worker import run_worker
 from distributed_ddpg_tpu.config import DDPGConfig
 from distributed_ddpg_tpu.envs.registry import EnvSpec
-from distributed_ddpg_tpu.metrics import nstep_counters
+from distributed_ddpg_tpu.metrics import ForwardMeter, nstep_counters
 
 # Reap bound for a worker we just terminate()d: long enough for the OS to
 # deliver SIGTERM and tear the process down, short enough that a zombie
@@ -92,6 +92,7 @@ class ActorPool:
             spec.obs_dim,
             actor_head_dim(spec.act_dim, config.sac),
             tuple(config.actor_hidden),
+            residual=config.simba,
         )
         self._shared = self._ctx.Array("f", layout_size(self.layout), lock=False)
         self._version = self._ctx.Value("l", 0)
@@ -153,6 +154,14 @@ class ActorPool:
             if config.n_step > 1
             else None
         )
+        # host time of the layered policy's forwards (metrics.ForwardMeter):
+        # [seconds, forwards] per worker slot, added to by the worker
+        self._forward_times = (
+            self._ctx.Array("d", 2 * self.num_actors, lock=False)
+            if config.simba
+            else None
+        )
+        self._forward_meter = ForwardMeter()
         self._episodes = self._ctx.Queue(maxsize=16 * self.num_actors)
         self._heartbeat = self._ctx.Array("d", self.num_actors, lock=False)
         self._stop = self._ctx.Value("b", 0)
@@ -265,6 +274,7 @@ class ActorPool:
                 serve_timeout_s=self.config.serve_timeout_s,
                 serve_fallback_s=self.config.serve_fallback_s,
                 nstep_counts=self._nstep_counts,
+                forward_times=self._forward_times,
                 # Flight recorder: workers are separate processes, so each
                 # keeps its OWN ring and exports trace_actor<k>.json on
                 # clean exit; Perfetto merges the files by pid.
@@ -338,6 +348,13 @@ class ActorPool:
         summed over workers since the run began; empty at n_step 1, where
         every row is one step."""
         return {} if self._nstep_counts is None else nstep_counters(self._nstep_counts)
+
+    def policy_forward(self) -> Dict[str, float]:
+        """`policy_forward_us` since the last call (metrics.ForwardMeter);
+        empty outside config.simba."""
+        if self._forward_times is None:
+            return {}
+        return self._forward_meter.snapshot(self._forward_times)
 
     # --- param broadcast (learner -> workers) ---
 

@@ -101,7 +101,7 @@ class SyncActorPool:
     """Drop-in ActorPool replacement with deterministic inline stepping.
     Same driver-facing surface (train.py uses: start/stop/broadcast/
     drain_batches/drain_into/steps_received/monitor/episode_stats/
-    staleness/nstep_counters/env_steps_offset)."""
+    staleness/nstep_counters/policy_forward/env_steps_offset)."""
 
     def __init__(self, config: DDPGConfig, spec: EnvSpec,
                  num_actors: Optional[int] = None):
@@ -112,6 +112,7 @@ class SyncActorPool:
             spec.obs_dim,
             actor_head_dim(spec.act_dim, config.sac),
             tuple(config.actor_hidden),
+            residual=config.simba,
         )
         self._policy = NumpyPolicy(
             self.layout,
@@ -160,6 +161,10 @@ class SyncActorPool:
         # Lockstep: experience is produced synchronously under the latest
         # broadcast params — staleness is zero by construction.
         return {"staleness_mean": 0.0, "staleness_max": 0}
+
+    def policy_forward(self) -> Dict[str, float]:
+        """ActorPool.policy_forward: the inline actors time nothing."""
+        return {}
 
     def nstep_counters(self) -> Dict[str, int]:
         """ActorPool.nstep_counters, over the inline actors."""

@@ -61,6 +61,7 @@ def run_worker(
     serve_timeout_s: float = 1.0,
     serve_fallback_s: float = 5.0,
     nstep_counts=None,          # mp.Array('l', 2 * num_workers), or None
+    forward_times=None,         # mp.Array('d', 2 * num_workers), or None
 ) -> None:
     # Workers are CPU-only by construction; make BLAS behave in many procs.
     os.environ.setdefault("OMP_NUM_THREADS", "1")
@@ -134,6 +135,18 @@ def run_worker(
 
     pending: list = []
     carry = None  # rows the ring had no room for on the last flush
+
+    def local_mu(o: np.ndarray) -> np.ndarray:
+        """One forward of the local policy; a layered policy's is a span
+        of the flight recorder and is timed (pool.policy_forward)."""
+        if forward_times is None:
+            return policy(o)[0]
+        t0 = time.perf_counter()
+        with trace.span("policy_forward"):
+            mu = policy(o)[0]
+        forward_times[2 * worker_id] += time.perf_counter() - t0
+        forward_times[2 * worker_id + 1] += 1
+        return mu
 
     def maybe_refresh():
         """Seqlock read (policy.seqlock_snapshot; see ActorPool.broadcast):
@@ -256,7 +269,7 @@ def run_worker(
         local mirror answers whenever the served path cannot."""
         nonlocal serve_rid
         if time.time() < serve_down_until:
-            return policy(o)[0]
+            return local_mu(o)
         serve_rid += 1
         try:
             serve_request_queue.put_nowait(
@@ -264,11 +277,11 @@ def run_worker(
             )
         except serve_queue_mod.Full:
             _serve_degrade()
-            return policy(o)[0]
+            return local_mu(o)
         deadline = time.time() + serve_timeout_s
         while time.time() < deadline and not stop_flag.value:
             if parent_pid and os.getppid() != parent_pid:
-                return policy(o)[0]  # orphaned: server is gone
+                return local_mu(o)  # orphaned: server is gone
             try:
                 rid, action = serve_response_queue.get(timeout=0.05)
             except serve_queue_mod.Empty:
@@ -280,10 +293,10 @@ def run_worker(
                 continue  # stale reply from a request we already gave up on
             if action is None:
                 _serve_degrade()  # server shed or failed this request
-                return policy(o)[0]
+                return local_mu(o)
             return np.asarray(action, np.float32)
         _serve_degrade()
-        return policy(o)[0]
+        return local_mu(o)
 
     # --- scripted faults (faults.py; see module docstring) ---
     faults = sorted(fault_specs, key=lambda t: t[1])
@@ -365,7 +378,7 @@ def run_worker(
             mu = (
                 served_mu(obs)
                 if serve_request_queue is not None
-                else policy(obs)[0]
+                else local_mu(obs)
             )
             action = mu + noise() * np.asarray(action_scale, np.float32)
         action = np.clip(action, action_low, action_high).astype(np.float32)

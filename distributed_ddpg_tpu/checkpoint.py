@@ -59,6 +59,7 @@ COMPAT_FIELDS = (
     "sac",  # double-width Gaussian head + twin leaves + log_alpha node
     "sac_autotune",  # alpha_opt presence changes the TrainState tree
     "crossq",  # no target nodes; batch-norm leaves in every layer
+    "simba",  # residual nets: blocks, LayerNorm and input-statistics leaves
     "num_atoms",
     "v_min",
     "v_max",
@@ -588,7 +589,7 @@ def check_config_compatible(directory: str, step: int, config: DDPGConfig) -> No
         return
     with open(path) as f:
         # a checkpoint from before the field existed was not a crossq run's
-        saved = {"crossq": False, **json.load(f)}
+        saved = {"crossq": False, "simba": False, **json.load(f)}
     current = dataclasses.asdict(config)
     mismatches = [
         f"{k}: checkpoint={saved[k]!r} run={_listify(current[k])!r}"

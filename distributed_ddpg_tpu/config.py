@@ -128,6 +128,31 @@ class DDPGConfig:
     # the one and is refused by the other.
     adam_b1: float = 0.9
 
+    # --- SimBa (arXiv 2410.09754; sac only) ---
+    # simba: actor and critics are residual nets (models/mlp.py:simba_init):
+    # a running-statistics normaliser on the observation (RSNorm), a linear
+    # embedding, one pre-LayerNorm residual block per entry of *_hidden
+    # (each h -> 4h -> h, so every entry of a net's list is the same h: the
+    # width of its residual stream), a post-LayerNorm and the head. The
+    # action joins the critics at their input, not normalised
+    # (action_insert_layer must be 0). The learner moves the statistics by
+    # the moments of the `obs` rows of each update's batch; the policy that
+    # leaves the learner carries them folded into its embedding, and is a
+    # layered net that no chain of dense layers can stand for
+    # (actors/policy.py). The source's other settings are plain flags:
+    # weight_decay 1e-2, sac_alpha 1e-2, target_entropy_scale 0.5, both
+    # learning rates 1e-4, tau 0.005.
+    simba: bool = False
+    # Decoupled weight decay (AdamW) on every trained leaf of actor and
+    # critics: p <- p - lr * (adam's step + weight_decay * p). Not on the
+    # temperature. 0 is plain Adam, program for program. The megakernel and
+    # the native backend have no such term: another value takes the scan
+    # leg on the one and is refused by the other.
+    weight_decay: float = 0.0
+    # The automatic entropy target is -target_entropy_scale * dim(A) (+ the
+    # action box's log scale, as target_entropy says); 1 is 1812.05905's.
+    target_entropy_scale: float = 1.0
+
     # --- replay (SURVEY.md §2 #5/#7) ---
     replay_capacity: int = 1_000_000
     replay_min_size: int = 1_000     # warmup before learning starts
@@ -858,6 +883,34 @@ class DDPGConfig:
                 "critics: there are no target critics to draw an ensemble "
                 "subset from — leave critic_ensemble/target_subset at 2"
             )
+        if self.simba:
+            if not self.sac or self.crossq or self.redq:
+                raise ValueError(
+                    "simba is plain sac (twin critics, targets, no delay) on "
+                    "residual nets — set sac=True and leave crossq and the "
+                    "REDQ knobs off"
+                )
+            if self.action_insert_layer != 0:
+                raise ValueError(
+                    "a simba critic takes the action at its input, beside "
+                    "the normalised observation: set action_insert_layer=0"
+                )
+            for knob in ("actor_hidden", "critic_hidden"):
+                if len(set(getattr(self, knob))) != 1:
+                    raise ValueError(
+                        f"simba reads {knob} as one residual block per entry, "
+                        "all of the stream's one width (512,512 is two blocks "
+                        f"of 512 <-> 2048); got {tuple(getattr(self, knob))}"
+                    )
+        if self.weight_decay < 0:
+            raise ValueError("weight_decay must be >= 0 (0 = plain Adam)")
+        if self.weight_decay and self.backend == "native":
+            raise ValueError(
+                "weight_decay is read by the tree-level Adam (ops/optim.py) "
+                "only: the native backend's formulas have no such term"
+            )
+        if self.target_entropy_scale <= 0:
+            raise ValueError("target_entropy_scale must be > 0")
         if not 0.0 <= self.adam_b1 < 1.0:
             raise ValueError("adam_b1 must be in [0, 1)")
         if self.adam_b1 != 0.9 and self.backend == "native":

@@ -34,7 +34,9 @@ from distributed_ddpg_tpu.ops.optim import adam_update
 from distributed_ddpg_tpu.ops.polyak import polyak_update
 from distributed_ddpg_tpu.trace import device_scope
 from distributed_ddpg_tpu.types import Batch, OptState, TrainState
-from distributed_ddpg_tpu.models.mlp import actor_init, critic_init, norm_moved
+from distributed_ddpg_tpu.models.mlp import (
+    actor_init, critic_init, norm_moved, rs_merged, rs_written, simba_init,
+)
 
 
 class StepOutput(NamedTuple):
@@ -70,9 +72,14 @@ def metric_keys(config: DDPGConfig) -> tuple:
     `bn_stat_gap` besides: the mean over the critics' normalised features of
     |batch mean - running mean| / running standard deviation, how far the
     evaluation-mode critics the actor climbs are from the training-mode
-    critics the loss fits. A chunk reports its last update's of each
-    (chunk_metrics). Only those branches have the keys, so every other
-    family's programs and records are what they were."""
+    critics the loss fits. A residual (SimBa) run, config.simba, reports
+    `resid_share` (the mean over the critics' blocks and the batch of
+    |f(LN(x))| / |x + f(LN(x))|: how much of the stream each block
+    rewrites), `rsnorm_count` (the rows the input normaliser has seen) and
+    `rsnorm_drift` (the mean over the features of |batch mean - running
+    mean| / running standard deviation). A chunk reports its last update's
+    of each (chunk_metrics). Only those branches have the keys, so every
+    other family's programs and records are what they were."""
     if config.distributional:  # config.py: never with twin_critic or sac
         return METRIC_KEYS + ("c51_edge_mass",)
     if config.twin_critic:
@@ -81,11 +88,16 @@ def metric_keys(config: DDPGConfig) -> tuple:
         return METRIC_KEYS + ("redq_q_spread",)
     if config.crossq:
         return METRIC_KEYS + ("bn_stat_gap",)
+    if config.simba:
+        return METRIC_KEYS + SIMBA_KEYS
     return METRIC_KEYS
 
 
+SIMBA_KEYS = ("resid_share", "rsnorm_count", "rsnorm_drift")
 # Metrics a chunk reports for its LAST update, not as a mean over the K.
-LAST_UPDATE_KEYS = ("c51_edge_mass", "td3_twin_gap", "redq_q_spread", "bn_stat_gap")
+LAST_UPDATE_KEYS = (
+    "c51_edge_mass", "td3_twin_gap", "redq_q_spread", "bn_stat_gap", *SIMBA_KEYS,
+)
 
 
 def chunk_metrics(ms: dict) -> dict:
@@ -234,7 +246,8 @@ def chunk_noise(config: DDPGConfig, base, step0, chunk: int, batch: int,
 def init_train_state(config: DDPGConfig, obs_dim: int, act_dim: int, seed: int) -> TrainState:
     """Build initial params + hard-copied targets (SURVEY.md §3.4) + Adam
     state. CrossQ (config.crossq): batch-normalised nets and no targets,
-    the two slots None (empty pytree nodes, as log_alpha is outside sac)."""
+    the two slots None (empty pytree nodes, as log_alpha is outside sac).
+    SimBa (config.simba): residual nets (models/mlp.simba_init)."""
     key = jax.random.PRNGKey(seed)
     k_actor, k_critic = jax.random.split(key)
     num_outputs = config.num_atoms if config.distributional else 1
@@ -243,13 +256,19 @@ def init_train_state(config: DDPGConfig, obs_dim: int, act_dim: int, seed: int) 
     # pool sizes its shared-memory layout with the same helper).
     from distributed_ddpg_tpu.actors.policy import actor_head_dim
 
-    actor_params = actor_init(
-        k_actor,
-        obs_dim,
-        actor_head_dim(act_dim, config.sac),
-        tuple(config.actor_hidden),
-        norm=config.crossq,
-    )
+    if config.simba:
+        actor_params = simba_init(
+            k_actor, obs_dim, obs_dim, actor_head_dim(act_dim, True),
+            tuple(config.actor_hidden),
+        )
+    else:
+        actor_params = actor_init(
+            k_actor,
+            obs_dim,
+            actor_head_dim(act_dim, config.sac),
+            tuple(config.actor_hidden),
+            norm=config.crossq,
+        )
     if config.twin_critic or config.sac:
         # TD3 / SAC ensemble: independently-initialized critics stacked on a
         # leading axis (two, or config.critic_ensemble under sac) — the
@@ -259,7 +278,11 @@ def init_train_state(config: DDPGConfig, obs_dim: int, act_dim: int, seed: int) 
         critic_params = jax.tree.map(
             lambda *members: jnp.stack(members),
             *(
-                critic_init(
+                simba_init(
+                    k, obs_dim, obs_dim + act_dim, 1, tuple(config.critic_hidden)
+                )
+                if config.simba
+                else critic_init(
                     k, obs_dim, act_dim, tuple(config.critic_hidden),
                     config.action_insert_layer, num_outputs,
                     norm=config.crossq,
@@ -363,13 +386,17 @@ def make_learner_step(
         is this step with N critics, a drawn in-target subset and the
         policy's half under a cond; CrossQ (config.crossq) this step with
         the joint batch-normalised critic pass, the same cond, and no
-        target: no polyak_update is traced."""
+        target: no polyak_update is traced; SimBa (config.simba) this step
+        on residual nets, with AdamW's decay and the input normaliser's
+        statistics moved by the batch's `obs` rows: every net of this
+        update reads them as they stood when it began."""
         eps_next, eps_cur, *subset = (
             own_noise(state, batch) if noise is None else noise
         )
         subset = subset[0] if subset else None
         alpha = jnp.exp(state.log_alpha)
         crossq, b1 = config.crossq, config.adam_b1
+        simba, decay = config.simba, config.weight_decay
 
         def critic_loss_fn(cp):
             if crossq:
@@ -383,7 +410,7 @@ def make_learner_step(
                 scale, eps_next, alpha,
                 config.sac_log_std_min, config.sac_log_std_max,
                 ail, config.critic_l2, offset, mm,
-                subset=subset, ensemble_stats=config.redq,
+                subset=subset, ensemble_stats=config.redq, resid_share=simba,
             )
 
         with device_scope("critic"):
@@ -393,12 +420,22 @@ def make_learner_step(
             cgrads = _maybe_psum_mean(cgrads, axis_name)
         if crossq:
             td, joint_mean_q, stat_gap, critic_moments = td
+        if simba:
+            td, resid_share = td
+            with device_scope("critic"):
+                # one merge for the state: every net is handed its result
+                rs_stats, rs_drift = rs_merged(
+                    state.actor_params[0], batch.obs, axis_name
+                )
 
         def critic_adam():
             new, opt = adam_update(
                 state.critic_params, cgrads, state.critic_opt,
-                config.critic_lr, b1,
+                config.critic_lr, b1, decay,
             )
+            if simba:
+                with device_scope("critic"):
+                    new = rs_written(new, rs_stats)
             if crossq:
                 # Adam left the running statistics where they were (their
                 # gradient is zero): the joint pass's moments move them.
@@ -433,11 +470,15 @@ def make_learner_step(
 
         def actor_adam(agrads, moments):
             new, opt = adam_update(
-                state.actor_params, agrads, state.actor_opt, config.actor_lr, b1
+                state.actor_params, agrads, state.actor_opt, config.actor_lr,
+                b1, decay,
             )
             if crossq:
                 with device_scope("actor"):
                     new = norm_moved(new, moments)
+            if simba:
+                with device_scope("actor"):
+                    new = rs_written(new, rs_stats)
             return new, opt
 
         def temperature_adam(mean_lp):
@@ -451,7 +492,8 @@ def make_learner_step(
             # the fused kernel wrapper. act_dim is static under jit from
             # the batch's action shape.
             tgt_h = losses.sac_target_entropy(
-                config.target_entropy, batch.action.shape[-1], action_scale
+                config.target_entropy, batch.action.shape[-1], action_scale,
+                config.target_entropy_scale,
             )
             alpha_grad = -(jax.lax.stop_gradient(mean_lp) + tgt_h)
             return adam_update(
@@ -525,7 +567,9 @@ def make_learner_step(
             # mean_q recovered exactly: aloss = E[alpha*lp - minQ]
             # => E[minQ] = alpha * mean_lp - aloss.
             mean_q = alpha * mean_lp - aloss
-            branch_metrics = ()
+            branch_metrics = (
+                (resid_share, rs_stats[2], rs_drift) if simba else ()
+            )
         metrics = dict(
             zip(
                 keys,
@@ -649,7 +693,7 @@ def make_learner_step(
                 aloss = actor_loss_fn(state.actor_params)
             new_critic, critic_opt = adam_update(
                 state.critic_params, cgrads, state.critic_opt, config.critic_lr,
-                config.adam_b1,
+                config.adam_b1, config.weight_decay,
             )
 
             def _delayed_update(_):
@@ -658,7 +702,7 @@ def make_learner_step(
                     agrads = _maybe_psum_mean(agrads, axis_name)
                 na, aopt = adam_update(
                     state.actor_params, agrads, state.actor_opt, config.actor_lr,
-                    config.adam_b1,
+                    config.adam_b1, config.weight_decay,
                 )
                 return (
                     na,
@@ -698,11 +742,11 @@ def make_learner_step(
             actor_grad_norm = optree_norm(agrads)
             new_critic, critic_opt = adam_update(
                 state.critic_params, cgrads, state.critic_opt, config.critic_lr,
-                config.adam_b1,
+                config.adam_b1, config.weight_decay,
             )
             new_actor, actor_opt = adam_update(
                 state.actor_params, agrads, state.actor_opt, config.actor_lr,
-                config.adam_b1,
+                config.adam_b1, config.weight_decay,
             )
 
             # --- Polyak target updates, fused in (SURVEY.md §3.4) ---

@@ -585,6 +585,25 @@ def nstep_counters(counts) -> Dict[str, int]:
     }
 
 
+class ForwardMeter:
+    """`policy_forward_us` on the `train` records of a run whose host
+    workers step a layered policy (config.simba): the mean host time of one
+    policy forward, in microseconds, over the forwards all workers made
+    since the last record. `counts` is a flat [seconds, forwards] pair per
+    worker, the pool's shared array, which each worker adds to after every
+    forward (one writer a slot, no lock: a torn read is one forward off).
+    An interval without a forward has no key."""
+
+    def __init__(self):
+        self._seconds = self._calls = 0.0
+
+    def snapshot(self, counts) -> Dict[str, float]:
+        seconds, calls = sum(counts[0::2]), sum(counts[1::2])
+        d_seconds, d_calls = seconds - self._seconds, calls - self._calls
+        self._seconds, self._calls = seconds, calls
+        return {"policy_forward_us": 1e6 * d_seconds / d_calls} if d_calls > 0 else {}
+
+
 class DevActorStats:
     """Counters for the device-actor subsystem (actors/device_pool.py;
     docs/DEVICE_ACTORS.md) — the `devactor_*` family every train/final

@@ -157,9 +157,13 @@ def fold_norm(params):
     dense layer becomes w' = diag(k) w and b' = b + t w. Host side (numpy
     leaves in, numpy out): what leaves the learner for the actors, the
     evaluator and the serving engine is a plain MLP, and their layouts do
-    not know the normalisation exists. A plain net comes back as it is."""
+    not know the normalisation exists. A plain net comes back as it is; a
+    residual net (`simba_init`) with its input statistics folded into its
+    embedding (`fold_rsnorm`), which is as far as it folds."""
     import numpy as np
 
+    if "rs_mean" in params[0]:
+        return fold_rsnorm(params)
     if "bn_scale" not in params[0]:
         return params
     out = []
@@ -191,6 +195,161 @@ def _dense(x, layer, mm_dtype):
     )
 
 
+# --- residual pre-LayerNorm nets behind a running-statistics input
+# normaliser (SimBa, arXiv 2410.09754) ---
+# A residual net is a tuple of dicts too, of three kinds. params[0] is the
+# embedding {w, b} with the normaliser's statistics beside it: `rs_mean` and
+# `rs_var` over the OBSERVATION's features (a critic's action columns, which
+# join at the input, are not normalised) and the row count `rs_count`.
+# params[1:-1] are the blocks {ln_scale, ln_shift, w1, b1, w2, b2}:
+# x + w2 relu(w1 LN(x) + b1) + b2, w1 [h, 4h], w2 [4h, h]. params[-1] is the
+# head {ln_scale, ln_shift, w, b}: the post-LayerNorm, then the output layer.
+# The statistics are leaves of the parameter tree for batch norm's reasons
+# above: no gradient reaches them, the optimiser's step on them is
+# overwritten (`rs_written`), and whatever carries parameters carries them.
+# `rs_count` is a float32 like every other leaf (Adam's moments mirror the
+# tree, and an integer leaf has no gradient): it counts rows in whole
+# batches, so it is exact up to 2**24 batches (4.3e9 rows at batch 256, some
+# 2.3 hours at 2,000 updates a second); beyond that a further batch may
+# round away, by which time one batch moves a statistic by under 2**-24 of
+# its distance anyway.
+RS_EPS = 1e-8
+LN_EPS = 1e-6
+SIMBA_EXPANSION = 4
+RS_STATS = ("rs_mean", "rs_var", "rs_count")
+
+
+def is_simba(params) -> bool:
+    """Whether `params` is a residual net (folded for the host or not)."""
+    return "ln_scale" in params[-1]
+
+
+def simba_init(
+    key, obs_dim: int, in_dim: int, out_dim: int, hidden: Sequence[int],
+    dtype=jnp.float32,
+) -> Params:
+    """A residual net on `in_dim` inputs, the first `obs_dim` of them the
+    observation: one block per entry of `hidden`, all of one width
+    (config.py holds that). Initialisers are this tree's (mlp_init's)."""
+    h = hidden[0]
+    keys = jax.random.split(key, len(hidden) + 2)
+    ln = lambda: {"ln_scale": jnp.ones((h,), dtype), "ln_shift": jnp.zeros((h,), dtype)}
+    embed = {
+        **_linear_init(keys[0], in_dim, h, final=False, dtype=dtype),
+        "rs_mean": jnp.zeros((obs_dim,), dtype),
+        "rs_var": jnp.ones((obs_dim,), dtype),
+        "rs_count": jnp.zeros((), dtype),
+    }
+    blocks = []
+    for k in keys[1:-1]:
+        k1, k2 = jax.random.split(k)
+        up = _linear_init(k1, h, SIMBA_EXPANSION * h, final=False, dtype=dtype)
+        down = _linear_init(k2, SIMBA_EXPANSION * h, h, final=False, dtype=dtype)
+        blocks.append({**ln(), "w1": up["w"], "b1": up["b"], "w2": down["w"], "b2": down["b"]})
+    head = {**ln(), **_linear_init(keys[-1], h, out_dim, final=True, dtype=dtype)}
+    return (embed, *blocks, head)
+
+
+def _layer_norm(x, layer):
+    """LayerNorm over the last axis with `layer`'s learned scale and shift;
+    moments, division and the affine map in x's float32."""
+    with device_scope("lnorm"):
+        mean = jnp.mean(x, axis=-1, keepdims=True)
+        var = jnp.mean(jnp.square(x - mean), axis=-1, keepdims=True)
+        return (x - mean) * jax.lax.rsqrt(var + LN_EPS) * layer["ln_scale"] + layer["ln_shift"]
+
+
+def simba_apply(params: Params, obs, action=None, mm_dtype=None, resid: bool = False):
+    """The residual net's output on `obs` (with `action` beside the
+    normalised observation, for a critic). A net folded for the host
+    (`fold_rsnorm`) has no statistics and takes `obs` as it is. With `resid`
+    returns (output, share[rows]): the mean over the blocks of
+    |f(LN(x))| / |x + f(LN(x))|, row by row."""
+    embed = params[0]
+    x = obs
+    if "rs_mean" in embed:
+        with device_scope("rsnorm"):
+            # no gradient reaches the statistics: the optimiser's moments
+            # for them stay zero
+            mean, var = jax.lax.stop_gradient((embed["rs_mean"], embed["rs_var"]))
+            x = (obs - mean) * jax.lax.rsqrt(var + RS_EPS)
+    if action is not None:
+        x = jnp.concatenate([x, action], axis=-1)
+    x = _dense(x, embed, mm_dtype)
+    shares = []
+    for block in params[1:-1]:
+        f = jax.nn.relu(
+            _dense(_layer_norm(x, block), {"w": block["w1"], "b": block["b1"]}, mm_dtype)
+        )
+        f = _dense(f, {"w": block["w2"], "b": block["b2"]}, mm_dtype)
+        if resid:
+            shares.append(
+                jnp.linalg.norm(f, axis=-1) / jnp.linalg.norm(x + f, axis=-1)
+            )
+        x = x + f
+    out = _dense(_layer_norm(x, params[-1]), params[-1], mm_dtype)
+    return (out, sum(shares) / len(shares)) if resid else out
+
+
+def rs_merged(embed, obs, axis_name=None):
+    """The normaliser's statistics after `obs`'s rows (the global batch's
+    under a data axis) have joined them, by Chan's merge of two sets'
+    moments (biased variances): ((mean, var, count), drift), drift the mean
+    over the features of |batch mean - running mean| / running standard
+    deviation BEFORE the merge. After k batches the statistics are the
+    moments of all their rows."""
+    with device_scope("rsnorm"):
+        rows = obs.shape[0]
+        if axis_name is not None:
+            # lint: ok(collective-discipline): traced only inside the jitted
+            # learner step under shard_map (see _global_mean)
+            rows = rows * jax.lax.psum(1, axis_name)
+        b_mean = _global_mean(jnp.mean(obs, axis=0), axis_name)
+        b_var = _global_mean(jnp.mean(jnp.square(obs - b_mean), axis=0), axis_name)
+        n0, mean0, var0 = embed["rs_count"], embed["rs_mean"], embed["rs_var"]
+        n = n0 + rows
+        delta = b_mean - mean0
+        mean = mean0 + delta * (rows / n)
+        var = (n0 * var0 + rows * b_var) / n + jnp.square(delta) * (n0 * rows / (n * n))
+        drift = jnp.mean(jnp.abs(delta) * jax.lax.rsqrt(var0 + RS_EPS))
+        return (mean, var, n), drift
+
+
+def rs_written(params: Params, stats) -> Params:
+    """`params` with the normaliser's statistics overwritten by `stats`
+    (rs_merged's), spread over a stack's leading axis where there is one:
+    every net of a state holds the same statistics by construction."""
+    with device_scope("rsnorm"):
+        embed = params[0]
+        new = {
+            name: jnp.broadcast_to(value, embed[name].shape)
+            for name, value in zip(RS_STATS, stats)
+        }
+        return ({**embed, **new}, *params[1:])
+
+
+def fold_rsnorm(params):
+    """A residual net for the host: the input normaliser is an affine map in
+    front of the embedding, o -> (o - mean) * k with k = 1 / sqrt(var + eps),
+    so the embedding's observation rows become diag(k) w and its bias b -
+    (mean * k) w. LayerNorm depends on the row and does not fold: the blocks
+    and the head leave as they are. Numpy in, numpy out."""
+    import numpy as np
+
+    f = {name: np.asarray(leaf, np.float64) for name, leaf in params[0].items()}
+    k = 1.0 / np.sqrt(f["rs_var"] + RS_EPS)
+    w = f["w"].copy()
+    n = k.shape[-1]
+    w[..., :n, :] *= k[..., :, None]
+    b = f["b"] - np.einsum("...i,...io->...o", f["rs_mean"] * k, f["w"][..., :n, :])
+    embed = {"w": w.astype(np.float32), "b": b.astype(np.float32)}
+    rest = tuple(
+        {name: np.asarray(leaf, np.float32) for name, leaf in layer.items()}
+        for layer in params[1:]
+    )
+    return (embed, *rest)
+
+
 def actor_apply(params: Params, obs, action_scale, action_offset=0.0, mm_dtype=None) -> Any:
     """mu(s): relu hiddens, tanh output mapped onto the action box
     [offset - scale, offset + scale] (offset != 0 for asymmetric spaces)."""
@@ -213,13 +372,16 @@ def actor_gaussian_apply(
     would zero its gradient exactly where autotuned-alpha training tends
     to push it. A normalised net (`with_norm`) runs in evaluation mode
     unless `train`, which returns ((mean, log_std), moments) for
-    `norm_moved`."""
+    `norm_moved`. A residual net (`simba_init`) runs `simba_apply`."""
     x, moments = obs, []
-    for layer in params[:-1]:
-        x = jax.nn.relu(
-            _dense(_norm(x, layer, train, axis_name, moments), layer, mm_dtype)
-        )
-    x = _dense(_norm(x, params[-1], train, axis_name, moments), params[-1], mm_dtype)
+    if is_simba(params):
+        x = simba_apply(params, obs, None, mm_dtype)
+    else:
+        for layer in params[:-1]:
+            x = jax.nn.relu(
+                _dense(_norm(x, layer, train, axis_name, moments), layer, mm_dtype)
+            )
+        x = _dense(_norm(x, params[-1], train, axis_name, moments), params[-1], mm_dtype)
     mean, log_std_raw = jnp.split(x, 2, axis=-1)
     log_std = log_std_min + 0.5 * (log_std_max - log_std_min) * (
         jnp.tanh(log_std_raw) + 1.0
@@ -260,12 +422,19 @@ def critic_init(
 
 def critic_apply(
     params: Params, obs, action, action_insert_layer: int = 1, mm_dtype=None,
-    train: bool = False, axis_name=None,
+    train: bool = False, axis_name=None, resid: bool = False,
 ) -> Any:
     """Q(s, a) -> f32[B] (or f32[B, num_atoms] logits when distributional).
     A normalised net (`with_norm`) runs in evaluation mode unless `train`,
     which returns (Q, moments) for `norm_moved`; its batch is then every
-    leading axis of `obs` (CrossQ's joint pass: [2, B, obs])."""
+    leading axis of `obs` (CrossQ's joint pass: [2, B, obs]). A residual net
+    (`simba_init`) runs `simba_apply`, and with `resid` returns (Q, its
+    blocks' residual share row by row)."""
+    if is_simba(params):
+        if resid:
+            q, share = simba_apply(params, obs, action, mm_dtype, resid=True)
+            return jnp.squeeze(q, axis=-1), share
+        return jnp.squeeze(simba_apply(params, obs, action, mm_dtype), axis=-1)
     x, moments = obs, []
     n = len(params)
     for i, layer in enumerate(params):

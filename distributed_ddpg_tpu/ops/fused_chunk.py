@@ -263,10 +263,11 @@ def supported(config: DDPGConfig) -> bool:
     return (
         config.action_insert_layer == 1
         and config.critic_l2 == 0.0
-        # REDQ, CrossQ: no kernel branch; the kernel's Adam holds beta_1
-        # as the constant B1
-        and not (config.redq or config.crossq)
+        # REDQ, CrossQ, SimBa: no kernel branch; the kernel's Adam holds
+        # beta_1 as the constant B1 and has no decay term
+        and not (config.redq or config.crossq or config.simba)
         and config.adam_b1 == B1
+        and config.weight_decay == 0.0
         and config.compute_dtype in ("float32", "bfloat16")
         # The hand-written backward assumes the action-insert layer (1) is
         # not the critic's output layer, i.e. at least 2 hidden layers.
@@ -1012,7 +1013,9 @@ def make_fused_chunk_fn(
     if sac:
         from distributed_ddpg_tpu.ops.losses import sac_target_entropy
 
-        tgt_h = sac_target_entropy(config.target_entropy, a, action_scale)
+        tgt_h = sac_target_entropy(
+            config.target_entropy, a, action_scale, config.target_entropy_scale
+        )
     else:
         tgt_h = None
 

@@ -188,6 +188,7 @@ def sac_critic_loss(
     mm_dtype=None,
     subset=None,
     ensemble_stats: bool = False,
+    resid_share: bool = False,
 ):
     """Entropy-regularized clipped double-Q TD loss:
     y = r + discount * (min_i Q'_i(s', a') - alpha * log pi(a'|s')),
@@ -204,7 +205,11 @@ def sac_critic_loss(
     critic regresses on that one y. With `ensemble_stats` the aux is
     (td_proxy, q_spread, mean_q): the batch mean of the standard deviation
     over the N online Q_i(s, a), and their mean, both of the q this loss
-    holds anyway."""
+    holds anyway. Residual critics (`resid_share`; models/mlp.simba_apply):
+    the aux is (td_proxy, the mean over critics, blocks and rows of
+    |f(LN(x))| / |x + f(LN(x))| in the online pass at (s, a)). Their target
+    critics normalise with their own statistics leaves, which polyak_update
+    keeps equal to the online nets'."""
     from distributed_ddpg_tpu.models.mlp import actor_gaussian_apply
 
     mean, log_std = actor_gaussian_apply(
@@ -222,7 +227,15 @@ def sac_critic_loss(
         ensemble(target_critic_params, batch.next_obs, next_action), axis=0
     )
     y = jax.lax.stop_gradient(td_targets(batch, next_q - alpha * next_lp))
-    q = ensemble(critic_params, batch.obs, batch.action)  # [N, B]
+    if resid_share:
+        q, share = jax.vmap(
+            lambda cp: critic_apply(
+                cp, batch.obs, batch.action, action_insert_layer, mm_dtype,
+                resid=True,
+            )
+        )(critic_params)
+    else:
+        q = ensemble(critic_params, batch.obs, batch.action)  # [N, B]
     td = y[None, :] - q
     loss = jnp.mean(batch.weight[None, :] * jnp.square(td))
     if l2 > 0.0:
@@ -234,6 +247,8 @@ def sac_critic_loss(
         return loss, (
             jnp.mean(td, axis=0), jnp.mean(jnp.std(q, axis=0)), jnp.mean(q)
         )
+    if resid_share:
+        return loss, (jnp.mean(td, axis=0), jax.lax.stop_gradient(jnp.mean(share)))
     return loss, jnp.mean(td, axis=0)
 
 
@@ -339,11 +354,14 @@ def sac_actor_loss(
     return jnp.mean(alpha * lp - q), jnp.mean(lp)
 
 
-def sac_target_entropy(target_entropy: float, act_dim: int, action_scale):
+def sac_target_entropy(
+    target_entropy: float, act_dim: int, action_scale, scale: float = 1.0,
+):
     """Resolve the temperature target as a trace-time Python float (jnp
     here would yield a tracer under jit): an explicit `target_entropy`
     wins; nan (the config sentinel) means auto — the 1812.05905 -act_dim
-    heuristic, which is stated for UNIT-box log-probs, shifted by
+    heuristic (times `scale`, config.target_entropy_scale: SimBa's 1/2),
+    which is stated for UNIT-box log-probs, shifted by
     +sum(log scale) because sac_sample's densities live in env action
     units (without the shift any env with scale > 1 gets a LOWER-entropy
     target than standard SAC and alpha collapses — measured on Pendulum,
@@ -355,7 +373,7 @@ def sac_target_entropy(target_entropy: float, act_dim: int, action_scale):
 
     if not math.isnan(target_entropy):
         return float(target_entropy)
-    return -float(act_dim) + float(
+    return -scale * float(act_dim) + float(
         np.sum(
             np.log(
                 np.broadcast_to(

@@ -25,9 +25,11 @@ B2 = 0.999
 EPS = 1e-8
 
 
-def adam_update(params, grads, opt: OptState, lr, b1: float = B1):
-    """One Adam step with beta_1 `b1` (config.adam_b1). Returns
-    (new_params, new_opt)."""
+def adam_update(params, grads, opt: OptState, lr, b1: float = B1, weight_decay: float = 0.0):
+    """One Adam step with beta_1 `b1` (config.adam_b1); with `weight_decay`
+    (config.weight_decay, a Python float) AdamW's decoupled decay on every
+    leaf, p <- p - lr * (adam's step + weight_decay * p). 0 traces plain
+    Adam, op for op. Returns (new_params, new_opt)."""
     with device_scope("optim"):
         count = opt.count + 1
         c = count.astype(jnp.float32)
@@ -37,10 +39,11 @@ def adam_update(params, grads, opt: OptState, lr, b1: float = B1):
         nu = jax.tree.map(
             lambda v, g: B2 * v + (1.0 - B2) * (g * g), opt.nu, grads
         )
-        new_params = jax.tree.map(
-            lambda p, m, v: p - lr * (m / bc1) / (jnp.sqrt(v / bc2) + EPS),
-            params,
-            mu,
-            nu,
-        )
+        if weight_decay:
+            step = lambda p, m, v: p - lr * (
+                (m / bc1) / (jnp.sqrt(v / bc2) + EPS) + weight_decay * p
+            )
+        else:
+            step = lambda p, m, v: p - lr * (m / bc1) / (jnp.sqrt(v / bc2) + EPS)
+        new_params = jax.tree.map(step, params, mu, nu)
     return new_params, OptState(mu=mu, nu=nu, count=count)

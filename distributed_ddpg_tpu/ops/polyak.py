@@ -13,8 +13,19 @@ import jax
 from distributed_ddpg_tpu.trace import device_scope
 
 
+def _is_statistic(path) -> bool:
+    """A running input statistic of a residual net (models/mlp.RS_STATS)."""
+    return bool(path) and str(getattr(path[-1], "key", "")).startswith("rs_")
+
+
 def polyak_update(online, target, tau):
+    """The averaged target. A residual net's input statistics are COPIED
+    from the online net, not averaged: a state has one normaliser, and its
+    targets read what the online nets read."""
     with device_scope("polyak"):
-        return jax.tree.map(
-            lambda o, t: tau * o + (1.0 - tau) * t, online, target
+        return jax.tree_util.tree_map_with_path(
+            lambda path, o, t: (
+                o if _is_statistic(path) else tau * o + (1.0 - tau) * t
+            ),
+            online, target,
         )

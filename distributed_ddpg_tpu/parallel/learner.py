@@ -1366,12 +1366,21 @@ def program_specs():
         critic_hidden=(16, 16, 8), tau=0.05,
     )
 
+    # SimBa's chunk (residual nets, the input statistics' merge, AdamW, the
+    # statistics copied into the targets), as the benchmark's cell runs it.
+    # (Under explicit shard_map the merge stages two pmeans and a psum for
+    # the batch's moments and rows; tests/test_reference_simba.py runs that.)
+    SIMBA = dict(
+        sac=True, simba=True, action_insert_layer=0, weight_decay=1e-2,
+        actor_hidden=(16,), critic_hidden=(16,), target_entropy_scale=0.5,
+    )
+
     def learner(
         guard: bool = False, sharded: bool = False, tp: bool = False,
         ensemble: bool = False, mode: str = "auto", crossq: bool = False,
-        pql: bool = False,
+        pql: bool = False, simba: bool = False,
     ) -> ShardedLearner:
-        key = (guard, sharded, tp, ensemble, mode, crossq, pql)
+        key = (guard, sharded, tp, ensemble, mode, crossq, pql, simba)
         if key not in cache:
             cache[key] = ShardedLearner(
                 probe_config(
@@ -1379,6 +1388,7 @@ def program_specs():
                     **(ENSEMBLE if ensemble else {}),
                     **(CROSSQ if crossq else {}),
                     **(PQL if pql else {}),
+                    **(SIMBA if simba else {}),
                 ),
                 obs_dim=3,
                 act_dim=1,
@@ -1525,6 +1535,12 @@ def program_specs():
         ProgramSpec(
             "learner.chunk.uniform.pql", OWNER,
             uniform(False, sharded=False, pql=True),
+        )
+    )
+    specs.append(
+        ProgramSpec(
+            "learner.chunk.uniform.simba", OWNER,
+            uniform(False, sharded=False, simba=True),
         )
     )
     return specs

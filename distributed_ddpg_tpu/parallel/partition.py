@@ -63,6 +63,7 @@ from typing import Optional, Sequence, Tuple
 import jax
 from jax.sharding import Mesh, PartitionSpec as P
 
+from distributed_ddpg_tpu.models.mlp import is_simba
 from distributed_ddpg_tpu.types import OptState, TrainState
 
 # One rule: (regex over the '/'-joined tree path, PartitionSpec). The
@@ -91,6 +92,20 @@ DEFAULT_MLP_RULES: Tuple[Rule, ...] = (
     # it adds after the partial-sum reduction)
     (r"(^|/)\d*[13579]/w$", P("model", None)),
     (r"(^|/)\d*[13579]/b$", P(None)),
+)
+
+
+# A residual net (models/mlp.simba_init): the residual stream is replicated,
+# so the embedding and the head (the {w, b} layers at its two ends) and the
+# vectors that act on the stream replicate; inside a block w1 is
+# column-parallel and w2 row-parallel, one reduction a block.
+SIMBA_RULES: Tuple[Rule, ...] = (
+    (r"(^|/)\d+/(ln_scale|ln_shift|rs_mean|rs_var|rs_count)$", P(None)),
+    (r"(^|/)\d+/w1$", P(None, "model")),
+    (r"(^|/)\d+/b1$", P("model")),
+    (r"(^|/)\d+/w2$", P("model", None)),
+    (r"(^|/)\d+/(b2|b)$", P(None)),
+    (r"(^|/)\d+/w$", P(None, None)),
 )
 
 
@@ -165,12 +180,11 @@ def match_partition_rules(rules: Sequence[Rule], tree, model_size: int):
 
 def net_pspec(params, model_size: int, rules: Optional[Sequence[Rule]] = None):
     """Spec tree for one {w, b}-layer param list. Default rules are the
-    per-depth MLP table (mlp_rules); pass `rules` for non-MLP nets."""
-    return match_partition_rules(
-        mlp_rules(len(params)) if rules is None else rules,
-        params,
-        model_size,
-    )
+    per-depth MLP table (mlp_rules), or SIMBA_RULES for a residual net;
+    pass `rules` for any other."""
+    if rules is None:
+        rules = SIMBA_RULES if is_simba(params) else mlp_rules(len(params))
+    return match_partition_rules(rules, params, model_size)
 
 
 def state_pspec(

@@ -310,10 +310,9 @@ class InferenceServer:
         import jax
         import jax.numpy as jnp
 
-        params = tuple(
-            {"w": jnp.asarray(l["w"]), "b": jnp.asarray(l["b"])}
-            for l in self._policy.layers
-        )
+        # the learner's tree again (a residual policy's blocks and
+        # LayerNorms with it), so the learner's own apply runs it
+        params = jax.tree.map(jnp.asarray, self._policy.tree())
         if self._mesh is None:
             self._jax_params = jax.device_put(params)
             return

@@ -458,8 +458,12 @@ CHUNK_SCOPES = (
     "update",         # the K updates: the lax.scan, or the pallas_call
     "update/critic",  # critic loss, forward and backward
     "update/critic/norm",  # its batch norm: moments, normalising, running step
+    "update/critic/lnorm",   # a residual critic's LayerNorms, forward and backward
+    "update/critic/rsnorm",  # its input normaliser, and the statistics' merge
     "update/actor",   # actor loss, forward and backward
     "update/actor/norm",   # its own batch norm, and the critics' under it
+    "update/actor/lnorm",    # a residual actor's LayerNorms, and the critics' under it
+    "update/actor/rsnorm",   # its input normaliser, and the critics' under it
     "update/optim",   # Adam
     "update/polyak",  # target updates
     "metrics",        # the chunk's metrics out of the K updates'

@@ -1207,6 +1207,16 @@ def _train_jax_impl(
         if config.crossq:
             # CrossQ runs only, and from the state: no target net is held.
             facts["crossq"] = learner.state.target_critic_params is None
+        if config.simba:
+            # Residual runs only, from the state's own shapes: blocks and
+            # stream width of each net, and the decay its optimiser applies.
+            for net, params in (
+                ("actor", learner.state.actor_params),
+                ("critic", learner.state.critic_params),
+            ):
+                facts[f"simba_{net}_blocks"] = len(params) - 2
+                facts[f"simba_{net}_width"] = int(params[0]["w"].shape[-1])
+            facts["weight_decay"] = config.weight_decay
         if device_pool is not None and device_pool.sigma_ends is not None:
             # The Gaussian ladder's ends, as the rollout program holds them.
             facts["devactor_sigma_min"], facts["devactor_sigma_max"] = (
@@ -1276,6 +1286,7 @@ def _train_jax_impl(
             spec.obs_dim,
             actor_head_dim(spec.act_dim, config.sac),
             tuple(config.actor_hidden),
+            residual=config.simba,
         ),
         spec.action_scale,
         spec.action_offset,
@@ -1304,6 +1315,7 @@ def _train_jax_impl(
                         spec.obs_dim,
                         actor_head_dim(spec.act_dim, config.sac),
                         tuple(config.actor_hidden),
+                        residual=config.simba,
                     ),
                     spec.action_scale,
                     spec.action_offset,
@@ -2125,6 +2137,7 @@ def _train_jax_impl(
                 episode_return=mean_ret,
                 **(pool if host_actors else device_pool).staleness(),
                 **pool.nstep_counters(),
+                **pool.policy_forward(),
                 **recovery_fields(),
                 **chunk_metrics,
                 **support_metrics,

@@ -39,6 +39,7 @@ RING_LAYOUT = {
     "redq-humanoid": "row_major",
     "crossq-humanoid": "row_major",
     "pql-isaac-humanoid": "row_major",
+    "simba-humanoid": "row_major",
 }
 
 

@@ -68,13 +68,18 @@ def _owned_by(module):
 # case's CPU seconds on an 8-core sandbox alone, and the most of three
 # runs beside five busy test workers (one of them the whole of tier-1).
 # By the share of programs the ten of parallel/superstep.py would have
-# 30.6 s, which they take alone.
+# 30.6 s, which they take alone. PR 44: 175 s, the learner's share 45 ->
+# 64: its seventeenth program (the residual chunk) costs 6 s, and on the
+# sandbox of that day the PARENT's sixteen read 41.5 alone where this
+# table has 34.5 (the change's seventeen 47.3 to 49.4 alone, 43.7 beside
+# five workers): 1.3 times the most, as the others; the device pool's 6
+# -> 12 for the same reason (the parent's three read 9.3 alone that day).
 _CPU_BUDGET_S = {
-    "distributed_ddpg_tpu.parallel.learner": 45.0,     # 34.5; 34.3
+    "distributed_ddpg_tpu.parallel.learner": 64.0,     # 49.4; 43.7
     "distributed_ddpg_tpu.parallel.megastep": 36.0,    # 23.0; 27.4
     "distributed_ddpg_tpu.parallel.superstep": 55.0,   # 30.3; 42.0
     "distributed_ddpg_tpu.replay.device": 5.0,         # 3.1; 3.7
-    "distributed_ddpg_tpu.actors.device_pool": 6.0,    # 3.5; 4.5
+    "distributed_ddpg_tpu.actors.device_pool": 12.0,   # 9.3; 4.5
     "distributed_ddpg_tpu.serve.server": 3.0,          # 1.7; 2.4
 }
 
@@ -100,7 +105,7 @@ def test_every_program_has_a_golden_and_no_golden_outlives_its_program():
     names = {s.name for s in prog_lib.default_specs()}
     assert names == {p.stem for p in GOLDEN.glob("*.json")}
     assert set(_CPU_BUDGET_S) == set(prog_lib.SPEC_MODULES)
-    assert sum(_CPU_BUDGET_S.values()) == 150.0
+    assert sum(_CPU_BUDGET_S.values()) == 175.0
     assert len(names) >= 18
 
 
