@@ -9,6 +9,27 @@ Two execution modes over the same pure step function (learner.py):
   (or TP-shard over 'model', mesh.py). XLA's SPMD partitioner inserts the
   gradient AllReduce over ICI — the collective that replaces the
   reference's async gRPC parameter-server push/pull (SURVEY.md §3.3).
+  Where the scan chunk reduces over more than one chip and the TPU's
+  compiler builds it, `chunk_program` hands jit two of that compiler's
+  options (`mesh_compiler_options`, PR 45; no flag, and nothing on one chip
+  or off the TPU): `xla_enable_async_all_reduce` and
+  `xla_tpu_enable_async_collective_fusion_fuse_all_reduce`. With them the
+  scheduler sees each update's gradient all-reduce as a start and a done it
+  may place other work between. What libtpu 0.0.34 makes of that on a v5e
+  (PERF.md §5, §6 PR 45): it can cut only a ONE-operand all-reduce into
+  steps that ride other fusions, and the partitioner's reduce here is a
+  tuple of the critics' leaves, the actor's and `mean_lp` (the combiner
+  joins them), so each is written back as a plain all-reduce
+  (`async_collective_name` stays on it) and the wire's 39.9 us an update
+  stay exposed; the schedule it reached on the way fuses the update's own
+  compute 2.8 us an update shorter, which is the whole gain. One flat
+  buffer a net under `shard_map` does get the steps, and loses: the
+  flatten costs 20 us an update and the fusions that carry a step run 14 us
+  longer to hide 9. Tried and dropped, each without effect on the compiled
+  text: `xla_tpu_enable_async_collective_fusion`, `..._multiple_steps`,
+  `xla_tpu_overlap_compute_collective_tc`,
+  `xla_tpu_enable_data_parallel_all_reduce_opt`,
+  `xla_tpu_data_parallel_opt_different_sized_ops`.
 - "explicit": `jax.shard_map` over the 'data' axis with a hand-written
   `jax.lax.pmean` in the step (axis_name plumbed through
   make_learner_step). Data-parallel only; exists to make the collective
@@ -80,6 +101,31 @@ def resolve_learner_chunk(config: DDPGConfig) -> int:
     from distributed_ddpg_tpu.ops.fused_chunk import runs_native
 
     return 800 if runs_native() else 8
+
+
+# What the TPU compiler is told for a scan chunk over a data mesh, beside
+# jit's own arguments: the per-update gradient all-reduces as asynchronous
+# instructions its scheduler may run other operations beside. The only two
+# of MaxText's data-parallel family that change this program's text
+# (libtpu 0.0.34; PERF.md §5 has each option tried and what it did):
+_MESH_COMPILER_OPTIONS = {
+    # an all-reduce becomes a start and a done the scheduler places apart
+    "xla_enable_async_all_reduce": True,
+    # and its steps may ride the fusions between them
+    "xla_tpu_enable_async_collective_fusion_fuse_all_reduce": True,
+}
+
+
+def mesh_compiler_options(data_size: int, native: bool) -> Optional[dict]:
+    """The `compiler_options` a scan chunk program is jitted with: the two
+    above where the program reduces gradients over more than one chip and
+    the TPU's compiler builds it; None, and jit is handed no such argument,
+    on one chip (no `data` axis to reduce over: the program's text stays
+    what it was) and off the TPU (XLA:CPU knows none of these names, and an
+    unknown option fails the compile)."""
+    if data_size > 1 and native:
+        return dict(_MESH_COMPILER_OPTIONS)
+    return None
 
 
 def _shape_of(x):
@@ -313,9 +359,18 @@ class ShardedLearner:
         replicated = NamedSharding(self.mesh, P())
         td_sharding = NamedSharding(self.mesh, P("data"))
 
-        def chunk_program(fn, **jit_args):
+        from distributed_ddpg_tpu.ops.fused_chunk import runs_native
+
+        mesh_options = mesh_compiler_options(self.data_size, runs_native())
+
+        def chunk_program(fn, scan=True, **jit_args):
             # Every chunk body below ends in `nkey`, the noise stream's base
             # key, which the learner binds here and passes at each launch.
+            # A scan chunk over a data mesh on the chip is compiled with
+            # mesh_compiler_options; the fused-mesh kernel (`scan` False),
+            # whose one pmean stands at the chunk's end, is not.
+            if scan and mesh_options:
+                jit_args["compiler_options"] = mesh_options
             return _ChunkProgram(jax.jit(fn, **jit_args), self._noise_key)
 
         def packed_step(s: TrainState, packed):
@@ -457,7 +512,6 @@ class ShardedLearner:
         # (which screen and overwrite the gathered rows) and the host-fed
         # chunk keep unpack_batch.
         from distributed_ddpg_tpu.ops import chunk_front as front_lib
-        from distributed_ddpg_tpu.ops.fused_chunk import runs_native
         from distributed_ddpg_tpu.replay.device import ring_layout
 
         width = packed_width(obs_dim, act_dim)
@@ -727,6 +781,7 @@ class ShardedLearner:
         def _jit_sample_chunk(fn):
             return chunk_program(
                 fn,
+                scan=not self.fused_chunk_active,
                 in_shardings=(
                     self._state_sharding, replicated, storage_sharding,
                     replicated, replicated,
@@ -917,6 +972,7 @@ class ShardedLearner:
         # version counter below lets the megastep detect staleness and
         # rebuild its beat program in step.
         self._launched = None  # chunk_ops: no launch of these programs yet
+        self._chunk_ops = None
         self._pure_scan_fns = {
             "uniform": scan_sample_chunk_fn,
             "per": per_sample_chunk_fn,
@@ -1047,9 +1103,27 @@ class ShardedLearner:
     def chunk_ops(self) -> Optional[dict]:
         """The table from instruction name to scope (trace.chunk_ops_table)
         of the chunk program this learner launches, from the text of the
-        executable that ran. None before any launch."""
-        text = self.chunk_hlo()
-        return None if text is None else trace.chunk_ops_table(text)
+        executable that ran. None before any launch. Read once a build:
+        the run fact below and train.write_chunk_ops both ask."""
+        if self._launched is None:
+            return None
+        if self._chunk_ops is None:
+            self._chunk_ops = trace.chunk_ops_table(self.chunk_hlo())
+        return self._chunk_ops
+
+    def chunk_collectives(self) -> Optional[dict]:
+        """The run fact `chunk_collectives`: how many collective instructions
+        the launched chunk executable holds (`instructions`: the table's
+        `served`, and the fusions that carry a step of one) and how many of
+        them the device runs beside other operations (`asynchronous`). None
+        on one device, whose program has none, and before any launch."""
+        table = None if self.mesh.size == 1 else self.chunk_ops()
+        if table is None:
+            return None
+        return {
+            "instructions": len({*table["served"], *table["asynchronous"]}),
+            "asynchronous": len(table["asynchronous"]),
+        }
 
     # --- single step ---
 

@@ -498,6 +498,9 @@ _INLINED = re.compile(r"(?:calls|to_apply)=%?([\w.\-]+)")
 _FUSED = re.compile(r"calls=%?([\w.\-]+)")
 # Instructions no device runs: a trace has no event for them.
 _NO_OP = frozenset({"parameter", "constant", "tuple", "get-tuple-element"})
+# What a fusion of nothing but a collective's step holds beside the step:
+# the compiler's glue between one step and the next.
+_NO_COMPUTE = _NO_OP | {"bitcast", "custom-call"}
 _RUN = re.compile(
     r"(?:body|condition|true_computation|false_computation|calls|to_apply)"
     r"=%?([\w.\-]+)|branch_computations=\{([^}]*)\}"
@@ -519,8 +522,8 @@ def device_scope(word: str):
 
 
 def _instructions(hlo_text: str):
-    """([(name, opcode, scope, in the entry computation?)], fused) of a
-    compiled module's text. The list holds every instruction that runs as
+    """([(name, opcode, scope, in the entry computation?)], fused, carriers)
+    of a compiled module's text. The list holds every instruction that runs as
     an operation of its own: the entry computation's, `while` bodies' and
     conditions', `conditional` branches' and `call` targets'. What is
     inlined into another instruction (a fusion's body, a reducer) is left
@@ -542,7 +545,16 @@ def _instructions(hlo_text: str):
     weight-gradient matmul that feeds them), and the device's time for the
     fusion cannot be split. `fused` is {fusion: the other scopes the
     instructions fused into it were traced under}, so that a reader of
-    `update/critic` can tell what else it paid for."""
+    `update/critic` can tell what else it paid for.
+
+    `carriers` is {fusion: does it compute?} of the fusions whose body
+    holds a collective instruction: the TPU compiler's asynchronous
+    collective (libtpu 0.0.34 writes no `-start` / `-done` opcode for it).
+    An all-reduce it overlaps is cut into steps, the transfer in flight
+    between them: the first and the last are fusions of nothing but the
+    step (`async-collective-start`, `-done`: False, they ARE the
+    collective), those between ride fusions of the computations the reduce
+    does not feed (True: compute, and read as their own scope)."""
     found, inlined, runs, computation, entry = [], set(), {}, "", False
     bodies, within = {}, {}  # fusion -> its body; body -> {scope: instructions}
     for line in hlo_text.splitlines():
@@ -601,7 +613,15 @@ def _instructions(hlo_text: str):
         others = set(within.get(bodies.get(name), ())) - {scope}
         if others:
             fused[name] = sorted(others)
-    return instructions, fused
+    holds = {comp for comp, _, opcode, _, _ in found if _is_collective(opcode)}
+    computes = {
+        comp for comp, _, opcode, _, _ in found
+        if not _is_collective(opcode) and opcode not in _NO_COMPUTE
+    }
+    carriers = {
+        name: body in computes for name, body in bodies.items() if body in holds
+    }
+    return instructions, fused, carriers
 
 
 def _scope_of(op_name: str) -> str:
@@ -615,17 +635,28 @@ def _scope_of(op_name: str) -> str:
     )
 
 
+_ASYNC_HALVES = ("-start", "-done")
+
+
 def _is_collective(opcode: str) -> bool:
-    for suffix in ("-start", "-done"):
+    for suffix in _ASYNC_HALVES:
         if opcode.endswith(suffix):
             opcode = opcode[: -len(suffix)]
     return opcode in _COLLECTIVE_OPCODES
 
 
-def _scopes(instructions) -> Dict[str, str]:
+def _collectives(instructions, carriers) -> set:
+    """Names of the instructions that are collectives and nothing else."""
+    return {
+        name for name, opcode, _, _ in instructions
+        if _is_collective(opcode) or carriers.get(name) is False
+    }
+
+
+def _scopes(instructions, collectives) -> Dict[str, str]:
     table = {}
-    for name, opcode, scope, _ in instructions:
-        scope = COLLECTIVE if _is_collective(opcode) else scope
+    for name, _, scope, _ in instructions:
+        scope = COLLECTIVE if name in collectives else scope
         if scope:
             table[name] = scope
     return table
@@ -636,26 +667,37 @@ def op_scopes(hlo_text: str) -> Dict[str, str]:
     (`compiled.as_text()`): the scope `_instructions` reads; COLLECTIVE for
     a collective instruction, whatever its path (`chunk_ops_table` keeps
     that as `served`); an instruction under no bracket is absent."""
-    return _scopes(_instructions(hlo_text)[0])
+    instructions, _, carriers = _instructions(hlo_text)
+    return _scopes(instructions, _collectives(instructions, carriers))
 
 
 def chunk_ops_table(hlo_text: str) -> Dict[str, Any]:
     """What `chunk_ops.json` holds, from the text of the executable a run
     launched: the module's name as a device trace names its launches, the
     vocabulary, `ops` (op_scopes), `served` (each collective's scope),
-    `fused` (what else each fusion holds: _instructions) and `loops`, the
-    `while` instructions: a device trace nests a loop's body under the
-    loop's own event, and a reader tells the loop's time under no body
-    operation by the names here."""
+    `asynchronous` (the collectives the device runs beside other
+    operations, each with its scope: a `-start` and its `-done`, which read
+    `collective` in `ops`, and the fusions that carry a step of one, which
+    read as the compute they are: _instructions; a plain `all-reduce` holds
+    the core until it has landed and is not among them), `fused` (what else
+    each fusion holds) and `loops`, the `while` instructions: a device trace
+    nests a loop's body under the loop's own event, and a reader tells the
+    loop's time under no body operation by the names here."""
     module = re.match(r"HloModule ([\w.\-]+)", hlo_text)
-    instructions, fused = _instructions(hlo_text)
+    instructions, fused, carriers = _instructions(hlo_text)
+    collectives = _collectives(instructions, carriers)
     return {
         "module": module.group(1) if module else "",
         "scopes": list(CHUNK_SCOPES),
-        "ops": _scopes(instructions),
+        "ops": _scopes(instructions, collectives),
         "served": {
+            name: scope for name, _, scope, _ in instructions
+            if name in collectives
+        },
+        "asynchronous": {
             name: scope for name, opcode, scope, _ in instructions
-            if _is_collective(opcode)
+            if name in carriers
+            or (name in collectives and opcode.endswith(_ASYNC_HALVES))
         },
         "fused": fused,
         "loops": [

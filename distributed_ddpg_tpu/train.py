@@ -1190,6 +1190,10 @@ def _train_jax_impl(
                 if use_device_replay and not config.prioritized
                 else "xla"
             ),
+            # The launched chunk executable's collective instructions and
+            # how many of them are asynchronous (trace.chunk_ops_table);
+            # null on one chip and, on the header, before the first launch.
+            "chunk_collectives": learner.chunk_collectives(),
             "state_devices": min(
                 len(leaf.sharding.device_set)
                 for leaf in jax.tree.leaves(learner.state)

@@ -138,6 +138,110 @@ def test_every_collective_opcode_reads_collective_whatever_it_served(opcode):
     assert trace.chunk_ops_table(text)["served"]["all-reduce.330"] == "update/critic"
 
 
+# Both forms of an asynchronous collective, as libtpu 0.0.34 writes them for
+# the x4 scan chunk (PERF.md §5) and as XLA's other backends do: an
+# `all-reduce-start` / `-done` pair; and the TPU's, an all-reduce cut into
+# steps under the opcode `fusion`: `async-collective-start` and `-done` hold
+# a step and the compiler's glue and nothing that computes, the step between
+# them rides the fusion of a matmul the reduce does not feed. Beside them one
+# plain all-reduce, which holds the core until it has landed.
+ASYNC_HLO = """\
+HloModule jit_sample_chunk_fn, is_scheduled=true
+
+%add.3 (a.1: f32[], b.1: f32[]) -> f32[] {
+  %a.1 = f32[] parameter(0)
+  %b.1 = f32[] parameter(1)
+  ROOT %add.4 = f32[] add(%a.1, %b.1)
+}
+
+%fused_computation.20 (param_0.1: f32[64]) -> (f32[64], u32[]) {
+  %param_0.1 = f32[64]{0} parameter(0)
+  %all-reduce.39 = f32[64]{0} all-reduce(%param_0.1), channel_id=1, replica_groups={{0,1,2,3}}, to_apply=%add.3, metadata={op_name="jit(sample_chunk_fn)/update/while/body/closed_call/critic/psum"}
+  ROOT %custom-call.10 = (f32[64]{0}, u32[]) custom-call(%param_0.1, %all-reduce.39), custom_call_target="ContinuationStart"
+}
+
+%async_collective_fusion.7 (param_0.2: f32[64], param_1.2: f32[4,4]) -> (f32[4,4], f32[64]) {
+  %param_0.2 = f32[64]{0} parameter(0)
+  %param_1.2 = f32[4,4]{1,0} parameter(1)
+  %convolution.9 = f32[4,4]{1,0} convolution(%param_1.2, %param_1.2), dim_labels=bf_io->bf, metadata={op_name="jit(sample_chunk_fn)/update/while/body/closed_call/actor/jvp()/dot_general"}
+  %all-reduce.40 = f32[64]{0} all-reduce(%param_0.2), channel_id=1, replica_groups={{0,1,2,3}}, to_apply=%add.3, metadata={op_name="jit(sample_chunk_fn)/update/while/body/closed_call/critic/psum"}
+  ROOT %tuple.5 = (f32[4,4]{1,0}, f32[64]{0}) tuple(%convolution.9, %all-reduce.40)
+}
+
+%fused_computation.21 (param_0.3: f32[64], param_1.3: u32[]) -> f32[64] {
+  %param_0.3 = f32[64]{0} parameter(0)
+  %param_1.3 = u32[] parameter(1)
+  %all-reduce.41 = f32[64]{0} all-reduce(%param_0.3), channel_id=1, replica_groups={{0,1,2,3}}, to_apply=%add.3, metadata={op_name="jit(sample_chunk_fn)/update/while/body/closed_call/critic/psum"}
+  ROOT %custom-call.11 = f32[64]{0} custom-call(%param_0.3, %all-reduce.41, %param_1.3), custom_call_target="ContinuationDone"
+}
+
+%body.2 (w.1: (f32[64], f32[4,4])) -> (f32[64], f32[4,4]) {
+  %w.1 = (f32[64]{0}, f32[4,4]{1,0}) parameter(0)
+  %g.0 = f32[64]{0} get-tuple-element(%w.1), index=0
+  %g.1 = f32[4,4]{1,0} get-tuple-element(%w.1), index=1
+  %async-collective-start = (f32[64]{0}, u32[]) fusion(%g.0), kind=kCustom, calls=%fused_computation.20
+  %gte.1 = f32[64]{0} get-tuple-element(%async-collective-start), index=0
+  %fusion.7 = (f32[4,4]{1,0}, f32[64]{0}) fusion(%gte.1, %g.1), kind=kOutput, calls=%async_collective_fusion.7, metadata={op_name="jit(sample_chunk_fn)/update/while/body/closed_call/actor/jvp()/dot_general"}
+  %gte.2 = f32[64]{0} get-tuple-element(%fusion.7), index=1
+  %gte.3 = u32[] get-tuple-element(%async-collective-start), index=1
+  %async-collective-done = f32[64]{0} fusion(%gte.2, %gte.3), kind=kCustom, calls=%fused_computation.21, metadata={op_name="jit(sample_chunk_fn)/update/while/body/closed_call/critic/psum"}
+  %all-reduce-start.1 = f32[64]{0} all-reduce-start(%async-collective-done), channel_id=2, replica_groups={{0,1,2,3}}, to_apply=%add.3, metadata={op_name="jit(sample_chunk_fn)/update/while/body/closed_call/actor/psum"}
+  %negate.1 = f32[64]{0} negate(%async-collective-done), metadata={op_name="jit(sample_chunk_fn)/update/while/body/closed_call/optim/neg"}
+  %all-reduce-done.1 = f32[64]{0} all-reduce-done(%all-reduce-start.1), metadata={op_name="jit(sample_chunk_fn)/update/while/body/closed_call/actor/psum"}
+  %all-reduce.5 = f32[64]{0} all-reduce(%negate.1), channel_id=3, replica_groups={{0,1,2,3}}, to_apply=%add.3, metadata={op_name="jit(sample_chunk_fn)/update/while/body/closed_call/psum"}
+  %gte.4 = f32[4,4]{1,0} get-tuple-element(%fusion.7), index=0
+  ROOT %tuple.9 = (f32[64]{0}, f32[4,4]{1,0}) tuple(%all-reduce.5, %gte.4)
+}
+
+%cond.2 (w.2: (f32[64], f32[4,4])) -> pred[] {
+  %w.2 = (f32[64]{0}, f32[4,4]{1,0}) parameter(0)
+  ROOT %constant.1 = pred[] constant(true)
+}
+
+ENTRY %main.1 (Arg_0.1: (f32[64], f32[4,4])) -> (f32[64], f32[4,4]) {
+  %Arg_0.1 = (f32[64]{0}, f32[4,4]{1,0}) parameter(0)
+  ROOT %while.3 = (f32[64]{0}, f32[4,4]{1,0}) while(%Arg_0.1), condition=%cond.2, body=%body.2, metadata={op_name="jit(sample_chunk_fn)/update/while"}
+}
+"""
+
+
+def test_the_table_tells_asynchronous_collectives_from_those_that_hold_the_core():
+    table = trace.chunk_ops_table(ASYNC_HLO)
+    # a start, a done and a plain all-reduce are collectives and nothing else
+    # (what `chunk.collective_ms` reads: the time the wire holds the core);
+    # the fusion that carries a step between them is the matmul it is
+    assert table["ops"] == {
+        "while.3": "update",
+        "async-collective-start": "collective",
+        "fusion.7": "update/actor",
+        "async-collective-done": "collective",
+        "all-reduce-start.1": "collective",
+        "negate.1": "update/optim",
+        "all-reduce-done.1": "collective",
+        "all-reduce.5": "collective",
+    }
+    # `served` keeps what each was for: a nameless start takes its reduce's scope
+    assert table["served"] == {
+        "async-collective-start": "update/critic",
+        "async-collective-done": "update/critic",
+        "all-reduce-start.1": "update/actor",
+        "all-reduce-done.1": "update/actor",
+        "all-reduce.5": "update",
+    }
+    assert table["asynchronous"] == {
+        "async-collective-start": "update/critic",
+        "fusion.7": "update/actor",
+        "async-collective-done": "update/critic",
+        "all-reduce-start.1": "update/actor",
+        "all-reduce-done.1": "update/actor",
+    }
+    assert table["fused"]["fusion.7"] == ["update/critic"]  # the step it carries
+    # a program of plain all-reduces has none
+    assert trace.chunk_ops_table(HLO)["asynchronous"] == {}
+    assert set(trace.chunk_ops_table(HLO)["served"]) == {"all-reduce.330"}
+    json.dumps(table)
+
+
 def test_a_bracket_takes_only_the_vocabularys_words():
     with pytest.raises(ValueError, match="CHUNK_SCOPES"):
         trace.device_scope("sample")
@@ -259,6 +363,23 @@ def test_on_a_data_mesh_the_scan_programs_all_reduces_read_collective():
     # each keeps the scope it served: a gradient's half of an update (XLA
     # may combine the two halves' all-reduces under one's name)
     assert set(table["served"].values()) <= {"update/critic", "update/actor"}
+
+
+def test_the_run_fact_counts_the_launched_executables_collectives():
+    """`chunk_collectives` (ShardedLearner.chunk_collectives, train.run_facts):
+    null on one device and before a launch; on a 4x1 mesh the instructions
+    the executable that ran holds and how many of them are asynchronous:
+    none here, XLA:CPU being handed no option and writing plain all-reduces."""
+    assert launched("sac", "scan").chunk_collectives() is None
+    cfg = DDPGConfig(actor_hidden=(16, 16), critic_hidden=(16, 16), batch_size=B, seed=5, fused_chunk="off", sac=True)
+    mesh = mesh_lib.make_mesh(4, 1, devices=jax.devices()[:4])
+    assert ShardedLearner(cfg, OBS, ACT, action_scale=1.0, mesh=mesh, chunk_size=K).chunk_collectives() is None
+    learner = launched("sac", "scan", devices=4)
+    counts = learner.chunk_collectives()
+    assert counts == {"instructions": len(learner.chunk_ops()["served"]), "asynchronous": 0}
+    assert counts["instructions"] >= 1
+    learner._build_programs()  # a rebuilt program (LR backoff) has not run yet
+    assert learner.chunk_collectives() is None
 
 
 # --- the compile cache must answer with the executable of THIS source ---
@@ -386,6 +507,7 @@ def test_train_names_the_front_its_launches_took(tmp_path, as_on_a_tpu, one_chip
     assert header["kind"] == "header" and final["kind"] == "final"
     for rec in (header, final, summary):
         assert rec["chunk_front"] == front
+        assert rec["chunk_collectives"] is None  # one chip reduces nothing
     assert np.isfinite(final["critic_loss"])
     table = json.loads((log.parent / trace.CHUNK_OPS_FILE).read_text())
     # the kernel's instructions read under `cut` (the CPU's compiler fuses

@@ -814,6 +814,13 @@ def test_train_run_keys_are_documented(tmp_path):
         assert rec["fused_chunk_active"] is False
         assert rec["kernel_state_tiles"] is None  # the scan leg holds no tiles
     assert records[0]["kind"] == "header" and records[-1]["kind"] == "final"
+    # The mesh's chunk executable holds collectives, counted once it has run
+    # (the header is written before the first launch); XLA:CPU is handed no
+    # compile option and writes none of them asynchronous.
+    assert records[0]["chunk_collectives"] is None
+    for rec in (records[-1], out):
+        assert rec["chunk_collectives"]["instructions"] >= 1
+        assert rec["chunk_collectives"]["asynchronous"] == 0
     assert np.isfinite(records[-1]["critic_loss"])
     assert records[-1]["first_chunk_s"] > 0 and records[-1]["steady_s"] > 0
     undocumented = sorted({

@@ -247,3 +247,104 @@ def test_scale_batch_with_data(scaled, want_batch):
     out = lrn.run_sample_chunk_per(per, beta=0.5)
     assert out.td_errors.shape == (K, want_batch)
     assert np.isfinite(float(out.metrics["critic_loss"]))
+
+
+# --- the scan chunk on a data mesh (PR 45): the programs a mesh gets are the
+# algorithm a single chip runs, and only a mesh on the chip is compiled with
+# the TPU compiler's own options ---
+
+SAC_FAMILIES = {
+    "sac": dict(sac=True),
+    # the policy's half, its all-reduce with it, under the `cond`
+    "redq": dict(sac=True, critic_ensemble=3, target_subset=2, policy_delay=2),
+}
+
+
+@pytest.mark.parametrize("family", SAC_FAMILIES)
+def test_mesh_scan_chunk_equals_k_single_steps_and_the_one_device_learner(family):
+    """K updates in one launch of the 4x1 mesh's scan chunk, against the
+    same K as single steps on the mesh and as one chunk of the one-device
+    learner on the same global batch: one algorithm, whatever reduces."""
+    cfg = _cfg(**SAC_FAMILIES[family])
+    K = 4
+    rng = np.random.default_rng(7)
+    batches = [_np_batch(rng) for _ in range(K)]
+    stacked = {k: np.stack([nb[k] for nb in batches]) for k in batches[0]}
+    four = mesh_lib.make_mesh(4, 1, devices=jax.devices()[:4])
+    one = mesh_lib.make_mesh(1, 1, devices=jax.devices()[:1])
+
+    chunked = ShardedLearner(cfg, OBS, ACT, action_scale=1.0, mesh=four, chunk_size=K)
+    assert chunked.data_size == 4 and not chunked.fused_chunk_active
+    out = chunked.run_chunk(stacked)
+    assert np.asarray(out.td_errors).shape == (K, B)
+    stepped = ShardedLearner(cfg, OBS, ACT, action_scale=1.0, mesh=four)
+    for nb in batches:
+        stepped.step(nb)
+    alone = ShardedLearner(cfg, OBS, ACT, action_scale=1.0, mesh=one, chunk_size=K)
+    out_alone = alone.run_chunk(stacked)
+
+    assert int(chunked.state.step) == K
+    # the policy stepped on the updates the rule names, on every replica alike
+    assert int(chunked.state.actor_opt.count) == -(-K // cfg.policy_delay)
+    for other in (stepped, alone):
+        for a, b in zip(
+            jax.tree.leaves(jax.device_get(chunked.state)),
+            jax.tree.leaves(jax.device_get(other.state)),
+        ):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-4, atol=2e-6)
+    np.testing.assert_allclose(
+        np.asarray(out.td_errors), np.asarray(out_alone.td_errors), rtol=1e-3, atol=1e-5
+    )
+    np.testing.assert_allclose(
+        float(out.metrics["critic_loss"]), float(out_alone.metrics["critic_loss"]), rtol=1e-4
+    )
+
+
+def test_mesh_compiler_options_rule():
+    from distributed_ddpg_tpu.parallel.learner import mesh_compiler_options
+
+    assert mesh_compiler_options(1, True) is None  # one chip: nothing to reduce over
+    assert mesh_compiler_options(4, False) is None  # XLA:CPU knows none of the names
+    assert mesh_compiler_options(1, False) is None
+    on = mesh_compiler_options(4, True)
+    assert on == {
+        "xla_enable_async_all_reduce": True,
+        "xla_tpu_enable_async_collective_fusion_fuse_all_reduce": True,
+    }
+    on.clear()  # a copy: a caller cannot edit the rule
+    assert mesh_compiler_options(2, True)
+
+
+@pytest.mark.parametrize("devices,native,optioned", [
+    (4, True, True),  # a data mesh on the chip
+    (4, False, False),  # this CPU mesh: every program builds as it did
+    (1, True, False),  # one chip: jit is handed what it was handed before
+])
+def test_only_a_data_mesh_on_the_chip_hands_jit_compile_options(monkeypatch, devices, native, optioned):
+    """What _build_programs hands jax.jit, program by program (jit is lazy:
+    nothing compiles, so the TPU's option names meet no compiler here)."""
+    from distributed_ddpg_tpu.ops import fused_chunk
+    from distributed_ddpg_tpu.parallel import learner as learner_lib
+
+    monkeypatch.setattr(fused_chunk, "runs_native", lambda: native)
+    handed = {}
+    jit = jax.jit
+
+    def recording(fn, **kw):
+        handed[fn.__name__] = kw.get("compiler_options")
+        return jit(fn, **kw)
+
+    monkeypatch.setattr(learner_lib.jax, "jit", recording)
+    mesh = mesh_lib.make_mesh(devices, 1, devices=jax.devices()[:devices])
+    lrn = ShardedLearner(
+        _cfg(sac=True, fused_chunk="off"), OBS, ACT, action_scale=1.0, mesh=mesh, chunk_size=2
+    )
+    monkeypatch.setattr(learner_lib.jax, "jit", jit)
+    assert not lrn.fused_chunk_active
+    chunks = {"chunk_fn", "sample_chunk_fn", "per_sample_chunk_fn"}
+    assert chunks <= set(handed) and "packed_step" in handed
+    want = learner_lib.mesh_compiler_options(devices, native)
+    assert (want is not None) == optioned
+    for name in chunks:
+        assert handed[name] == want, name
+    assert handed["packed_step"] is None  # the single step is no scan chunk
