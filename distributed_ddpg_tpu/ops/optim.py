@@ -10,6 +10,17 @@ reasons tied to this framework's contract:
 
 Formulation matches optax.adam defaults (b1=0.9, b2=0.999, eps=1e-8,
 eps_root=0): bias-corrected moments, eps added outside the sqrt.
+
+The two bias corrections are computed inside every call, on scalars (two
+`power`s, two subtracts, a convert, the count's add): in the scan chunk's
+loop on the TPU that is 19 unfused instructions an update under SAC's three
+Adams, and they cost nothing measurable there. Computed once a launch in
+front of the scan and handed in (tried on the chip, PR 46: PERF.md §6), the
+loop's own time stood (5.87 -> 5.94 ms a launch of 800 updates) and every
+fusion that holds an Adam took 0.7 us longer to read the pair out of an
+array than it takes from the scalar core (the launch 39.5 -> 46.1 ms). The
+scalar core runs such a chain beside the vector unit's fusions; they stay
+where they are.
 """
 
 from __future__ import annotations

@@ -208,11 +208,16 @@ class ShardedLearner:
             raise ValueError("explicit (shard_map) mode is data-parallel only")
         self.mode = mode
         self.chunk_size = int(chunk_size)
-        # Scan-body unroll factor. Each learner step is ~25 small (<=64x256x256)
-        # ops, so per-iteration scan overhead is material and 4 steps share
-        # one; the factor has not been swept on the chip since the benchmark
-        # (the five scan-leg cells run 4). lax.scan handles unroll > length,
-        # so no clamping to chunk sizes.
+        # Scan-body unroll factor: four updates a trip of the `while`. What
+        # the loop costs beyond its body's fusions (`chunk.update_gap_pct`:
+        # 17.7% of `update` in the SAC cell before PR 46, 18 to 72 us a trip
+        # between the cells' bodies at this one factor) is no per-trip
+        # overhead that more steps a trip would share: it is the loop
+        # waiting for scalars that cross between its fusions and the scalar
+        # core, 0.35-0.7 us a crossing (PERF.md §6, PR 46). The factor
+        # itself has not been swept on the chip (the six scan-leg cells run
+        # 4). lax.scan handles unroll > length, so no clamping to chunk
+        # sizes.
         # (Rejecting <1 rather than clamping: lax.scan gives unroll=0 its own
         # meaning — full unroll — which a silent clamp would invert.)
         if int(unroll) < 1:
@@ -1124,6 +1129,14 @@ class ShardedLearner:
             "instructions": len({*table["served"], *table["asynchronous"]}),
             "asynchronous": len(table["asynchronous"]),
         }
+
+    def chunk_body_scalars(self) -> Optional[int]:
+        """The run fact `chunk_body_scalars`: the unfused arithmetic
+        instructions on a `[]` shape that one trip of the launched scan
+        chunk's loop issues (the table's `scalars`). None on the kernel
+        leg, which scans nothing, and before any launch."""
+        table = None if self.fused_chunk_active else self.chunk_ops()
+        return None if table is None else table["scalars"]
 
     # --- single step ---
 

@@ -501,6 +501,24 @@ _NO_OP = frozenset({"parameter", "constant", "tuple", "get-tuple-element"})
 # What a fusion of nothing but a collective's step holds beside the step:
 # the compiler's glue between one step and the next.
 _NO_COMPUTE = _NO_OP | {"bitcast", "custom-call"}
+# A `while`'s body, and what counts as arithmetic there: XLA's elementwise
+# opcodes. The TPU's compiler fuses none of them on a `[]` shape, so in a
+# loop's body each is an instruction of the loop by itself, run on the
+# scalar core. Those that wait for nothing cost nothing measurable; one
+# that reads a fusion's `f32[]` result makes the loop wait for it (PERF.md
+# §6, PR 46), and the count is where to look for such chains.
+_BODY = re.compile(r"body=%?([\w.\-]+)")
+_SCALAR = re.compile(r"\w+\[\]")
+_ELEMENTWISE = frozenset({
+    "abs", "add", "and", "atan2", "bitcast-convert", "cbrt", "ceil", "clamp",
+    "clz", "compare", "convert", "cosine", "divide", "erf", "exponential",
+    "exponential-minus-one", "floor", "is-finite", "log", "log-plus-one",
+    "logistic", "maximum", "minimum", "multiply", "negate", "not", "or",
+    "popcnt", "power", "remainder", "round-nearest-afz",
+    "round-nearest-even", "rsqrt", "select", "shift-left",
+    "shift-right-arithmetic", "shift-right-logical", "sign", "sine", "sqrt",
+    "subtract", "tan", "tanh", "xor",
+})
 _RUN = re.compile(
     r"(?:body|condition|true_computation|false_computation|calls|to_apply)"
     r"=%?([\w.\-]+)|branch_computations=\{([^}]*)\}"
@@ -522,10 +540,10 @@ def device_scope(word: str):
 
 
 def _instructions(hlo_text: str):
-    """([(name, opcode, scope, in the entry computation?)], fused, carriers)
-    of a compiled module's text. The list holds every instruction that runs as
-    an operation of its own: the entry computation's, `while` bodies' and
-    conditions', `conditional` branches' and `call` targets'. What is
+    """([(name, opcode, scope, in the entry computation?)], fused, carriers,
+    scalars) of a compiled module's text. The list holds every instruction
+    that runs as an operation of its own: the entry computation's, `while`
+    bodies' and conditions', `conditional` branches' and `call` targets'. What is
     inlined into another instruction (a fusion's body, a reducer) is left
     out, and so are parameters, constants and tuples: no trace has an event
     for them. Names are unique in a module.
@@ -554,8 +572,14 @@ def _instructions(hlo_text: str):
     between them: the first and the last are fusions of nothing but the
     step (`async-collective-start`, `-done`: False, they ARE the
     collective), those between ride fusions of the computations the reduce
-    does not feed (True: compute, and read as their own scope)."""
+    does not feed (True: compute, and read as their own scope).
+
+    `scalars` is {`while` instruction: the instructions of its body that
+    are unfused arithmetic (_ELEMENTWISE) on a `[]` shape}: what one trip
+    of the loop issues one by one between its fusions. A `conditional`'s
+    branches under the body are not the body's."""
     found, inlined, runs, computation, entry = [], set(), {}, "", False
+    loop_bodies, arithmetic = {}, {}  # while -> its body; computation -> count
     bodies, within = {}, {}  # fusion -> its body; body -> {scope: instructions}
     for line in hlo_text.splitlines():
         m = _COMPUTATION.match(line)
@@ -572,10 +596,14 @@ def _instructions(hlo_text: str):
                 depth += (ch == "(") - (ch == ")")
                 if depth == 0:
                     break
-            rest = rest[i + 1:]
+            shape, rest = "", rest[i + 1:]
         else:
-            rest = rest.partition(" ")[2]
+            shape, _, rest = rest.partition(" ")
         opcode = rest.lstrip().partition("(")[0]
+        if opcode in _ELEMENTWISE and _SCALAR.match(shape):
+            arithmetic[computation] = arithmetic.get(computation, 0) + 1
+        if opcode == "while":
+            loop_bodies[name] = _BODY.search(rest).group(1)
         if opcode != "call":
             inlined.update(_INLINED.findall(rest))
             bodies.update((name, body) for body in _FUSED.findall(rest))
@@ -621,7 +649,10 @@ def _instructions(hlo_text: str):
     carriers = {
         name: body in computes for name, body in bodies.items() if body in holds
     }
-    return instructions, fused, carriers
+    scalars = {
+        loop: arithmetic.get(body, 0) for loop, body in loop_bodies.items()
+    }
+    return instructions, fused, carriers, scalars
 
 
 def _scope_of(op_name: str) -> str:
@@ -667,7 +698,7 @@ def op_scopes(hlo_text: str) -> Dict[str, str]:
     (`compiled.as_text()`): the scope `_instructions` reads; COLLECTIVE for
     a collective instruction, whatever its path (`chunk_ops_table` keeps
     that as `served`); an instruction under no bracket is absent."""
-    instructions, _, carriers = _instructions(hlo_text)
+    instructions, _, carriers, _ = _instructions(hlo_text)
     return _scopes(instructions, _collectives(instructions, carriers))
 
 
@@ -680,11 +711,16 @@ def chunk_ops_table(hlo_text: str) -> Dict[str, Any]:
     `collective` in `ops`, and the fusions that carry a step of one, which
     read as the compute they are: _instructions; a plain `all-reduce` holds
     the core until it has landed and is not among them), `fused` (what else
-    each fusion holds) and `loops`, the `while` instructions: a device trace
+    each fusion holds), `loops`, the `while` instructions: a device trace
     nests a loop's body under the loop's own event, and a reader tells the
-    loop's time under no body operation by the names here."""
+    loop's time under no body operation by the names here; and `scalars`,
+    the unfused arithmetic instructions on a `[]` shape in the bodies of
+    `loops`, a trip of each: where to look for what that time is made of
+    (the TPU's compiler fuses no arithmetic on scalars, and a chain of it
+    behind a fusion's `f32[]` result makes the loop wait for the result:
+    PERF.md §6, PR 46)."""
     module = re.match(r"HloModule ([\w.\-]+)", hlo_text)
-    instructions, fused, carriers = _instructions(hlo_text)
+    instructions, fused, carriers, scalars = _instructions(hlo_text)
     collectives = _collectives(instructions, carriers)
     return {
         "module": module.group(1) if module else "",
@@ -703,4 +739,5 @@ def chunk_ops_table(hlo_text: str) -> Dict[str, Any]:
         "loops": [
             name for name, opcode, _, _ in instructions if opcode == "while"
         ],
+        "scalars": sum(scalars.values()),
     }

@@ -1194,6 +1194,10 @@ def _train_jax_impl(
             # how many of them are asynchronous (trace.chunk_ops_table);
             # null on one chip and, on the header, before the first launch.
             "chunk_collectives": learner.chunk_collectives(),
+            # What one trip of the launched scan chunk's loop issues as
+            # unfused arithmetic on scalars (the table's `scalars`); null on
+            # the kernel leg and, on the header, before the first launch.
+            "chunk_body_scalars": learner.chunk_body_scalars(),
             "state_devices": min(
                 len(leaf.sharding.device_set)
                 for leaf in jax.tree.leaves(learner.state)

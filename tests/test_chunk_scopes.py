@@ -2,8 +2,11 @@
 from instruction name to scope that `op_scopes` reads out of a compiled
 module's text, the tables of the programs `_build_programs` selects for the
 five families on both legs, the compile cache that must not answer a scoped
-program with an unscoped one's executable, and `chunk_ops.json` beside a
-run's records."""
+program with an unscoped one's executable, `chunk_ops.json` beside a
+run's records, and the table's `scalars` (PR 46: the unfused arithmetic on a
+`[]` shape in the scan's body; that it fell with the gradient norms' one
+sum a net is held where a TPU compiler's text can be had, in
+tests/test_ring_layout.py: XLA:CPU fuses scalar arithmetic and reads 0)."""
 
 import contextlib
 import json
@@ -126,6 +129,34 @@ def test_op_scopes_reads_fusions_loop_bodies_branches_and_collectives():
     }
     assert table["ops"] == trace.op_scopes(HLO)
     assert set(table["ops"].values()) <= set(trace.CHUNK_SCOPES) | {trace.COLLECTIVE}
+
+
+def test_the_table_counts_the_loop_bodys_unfused_scalar_arithmetic():
+    """`scalars`: elementwise instructions on a `[]` shape that stand in a
+    `while` body by themselves, one trip. Not a fusion's (one instruction,
+    whatever it holds), not an array's, not a copy or a slice, not what a
+    `conditional` under the body runs in its branches, not the entry's."""
+    assert trace.chunk_ops_table(HLO)["scalars"] == 0
+    body = '  %dynamic-slice.4 = f32[8,15]{1,0} dynamic-slice('
+    scalars = (
+        '  %add.77 = s32[] add(%w.1, %w.1), metadata={op_name="jit(sample_chunk_fn)/update/while/body/closed_call/optim/add"}\n'
+        '  %convert.7 = f32[] convert(%add.77), metadata={op_name="jit(sample_chunk_fn)/update/while/body/closed_call/optim/convert_element_type"}\n'
+        '  %power.7 = f32[]{:T(128)} power(%convert.7, %convert.7), metadata={op_name="jit(sample_chunk_fn)/update/while/body/closed_call/optim/pow"}\n'
+        '  %sqrt.7 = f32[] sqrt(%power.7), metadata={op_name="jit(sample_chunk_fn)/update/while/body/closed_call/sqrt"}\n'
+        '  %copy.7 = f32[] copy(%sqrt.7)\n'
+        '  %slice.7 = f32[1]{0} slice(%w.1), slice={[0:1]}\n'
+        '  %multiply.70 = f32[4,4]{1,0} multiply(%w.1, %w.1), metadata={op_name="jit(sample_chunk_fn)/update/while/body/closed_call/critic/mul"}\n'
+    )
+    text = HLO.replace(body, scalars + body)
+    text = text.replace(  # one in a branch, one in the entry computation
+        "  %multiply.7 = f32[4,4]{1,0} multiply(", "  %negate.70 = f32[] negate(%gte.5)\n  %multiply.7 = f32[4,4]{1,0} multiply("
+    ).replace("  %copy.63 = ", "  %add.78 = f32[] add(%Arg_0.1, %Arg_0.1)\n  %copy.63 = ")
+    table = trace.chunk_ops_table(text)
+    assert table["scalars"] == 4 and isinstance(table["scalars"], int)
+    assert table["loops"] == ["while.3"]
+    # the instructions are operations of their own all the same, each under its scope
+    assert table["ops"]["power.7"] == "update/optim" and table["ops"]["negate.70"] == "update"
+    assert trace.chunk_ops_table(ASYNC_HLO)["scalars"] == 0
 
 
 @pytest.mark.parametrize("opcode", [
@@ -382,6 +413,20 @@ def test_the_run_fact_counts_the_launched_executables_collectives():
     assert learner.chunk_collectives() is None
 
 
+def test_the_run_fact_counts_the_launched_scan_bodys_scalars():
+    """`chunk_body_scalars` (ShardedLearner.chunk_body_scalars,
+    train.run_facts): the table's `scalars` of the executable that ran, an
+    integer; null before a launch and on the kernel leg, which scans
+    nothing."""
+    learner = launched("sac", "scan")
+    count = learner.chunk_body_scalars()
+    assert isinstance(count, int) and count == learner.chunk_ops()["scalars"] >= 0
+    learner._build_programs()
+    assert learner.chunk_body_scalars() is None
+    kernel = launched("sac", "kernel")
+    assert kernel.chunk_body_scalars() is None and isinstance(kernel.chunk_ops()["scalars"], int)
+
+
 # --- the compile cache must answer with the executable of THIS source ---
 
 
@@ -458,6 +503,13 @@ def test_train_writes_chunk_ops_beside_its_records_and_names_it(tmp_path):
     assert table["module"] == "jit_sample_chunk_fn" and table["scopes"] == list(trace.CHUNK_SCOPES)
     assert {"draw", "gather", "cut", "update", "update/optim", "update/polyak"} <= set(table["ops"].values())
     assert any(table["ops"].get(loop) == "update" for loop in table["loops"])
+    # the run fact beside `chunk_front`: on the header, written before the
+    # first launch, null; on the final record and the summary the table's count
+    records = [json.loads(line) for line in open(log)]
+    assert records[0]["kind"] == "header" and records[0]["chunk_body_scalars"] is None
+    assert records[-1]["kind"] == "final"
+    assert records[-1]["chunk_body_scalars"] == summary["chunk_body_scalars"] == table["scalars"]
+    assert isinstance(table["scalars"], int)
     # with --trace_dir it lies beside trace.json too
     assert json.loads((tmp_path / "tr" / trace.CHUNK_OPS_FILE).read_text()) == table
     assert (tmp_path / "tr" / "trace.json").exists()

@@ -642,13 +642,11 @@ def _called_from(comps, name):
     return seen
 
 
-def test_v5e_scan_chunk_draws_its_noise_before_the_loop(v5e_sharding):
-    """`sac-humanoid`'s scan chunk at the configuration's own sizes (batch
-    256, obs 376, act 17, K 800, unroll 4), built as ShardedLearner's
-    scan_steps builds it (scan_chunk over learner.chunk_noise). With the draw
-    in the step the body was 1,539 instructions, 106 of them `xor` and 80
-    `shift-left` of key arithmetic, and its two sampling fusions held a
-    threefry each, 335 and 512 instructions."""
+def _v5e_scan_chunk(v5e_sharding, name, chunk):
+    """(cfg, state shapes, the compiled executable) of configuration `name`'s
+    scan chunk of `chunk` updates at its own widths, built as
+    ShardedLearner's scan_steps builds it (scan_chunk over
+    learner.chunk_noise; unroll 4) and compiled for the described v5e."""
     import json
     import os
 
@@ -658,11 +656,11 @@ def test_v5e_scan_chunk_draws_its_noise_before_the_loop(v5e_sharding):
     from distributed_ddpg_tpu.types import unpack_batch
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    conf = json.load(open(os.path.join(root, "benchmarks", "configs", "sac-humanoid.json")))
+    conf = json.load(open(os.path.join(root, "benchmarks", "configs", name + ".json")))
     cfg = DDPGConfig.from_flags([f for f in conf["flags"] if not f.startswith("--replay_capacity")])
-    env, chunk = conf["env"], 800
+    env = conf["env"]
     obs, act = env["obs_dim"], env["act_dim"]
-    assert (cfg.batch_size, obs, act) == (256, 376, 17) and cfg.sac
+    assert (obs, act) == (376, 17)
     step = learner_lib.make_learner_step(cfg, env["action_scale"], action_offset=env["action_offset"])
 
     def run(s, packed):
@@ -678,7 +676,19 @@ def test_v5e_scan_chunk_draws_its_noise_before_the_loop(v5e_sharding):
         jax.eval_shape(lambda: learner_lib.init_train_state(cfg, obs, act, 0)),
     )
     packed = jax.ShapeDtypeStruct((chunk, cfg.batch_size, 2 * obs + act + 3), jnp.float32, sharding=replicated)
-    text = jax.jit(run, donate_argnums=(0,)).lower(state, packed).compile().as_text()
+    return cfg, state, jax.jit(run, donate_argnums=(0,)).lower(state, packed).compile()
+
+
+def test_v5e_scan_chunk_draws_its_noise_before_the_loop(v5e_sharding):
+    """`sac-humanoid`'s scan chunk at the configuration's own sizes (batch
+    256, obs 376, act 17, K 800, unroll 4), built as ShardedLearner's
+    scan_steps builds it (_v5e_scan_chunk). With the draw in the step the
+    body was 1,539 instructions, 106 of them `xor` and 80
+    `shift-left` of key arithmetic, and its two sampling fusions held a
+    threefry each, 335 and 512 instructions."""
+    cfg, _, compiled = _v5e_scan_chunk(v5e_sharding, "sac-humanoid", 800)
+    assert cfg.batch_size == 256 and cfg.sac
+    text = compiled.as_text()
 
     comps = _computations(text)
     whiles = [line for lines in comps.values() for line in lines if re.search(r"\bwhile\(", line)]
@@ -693,6 +703,43 @@ def test_v5e_scan_chunk_draws_its_noise_before_the_loop(v5e_sharding):
     assert [line for line in text.splitlines() if drawn.search(line)]
 
 
+@pytest.mark.parametrize(
+    "name,before,ceiling",
+    [
+        # a trip of four unrolled updates: with a sum a leaf added up as
+        # scalars (the parent's norm) / the ceiling now (PR 46 read 229 ->
+        # 189 and 107 -> 87: PERF.md §6)
+        ("sac-humanoid", 229, 195),
+        ("redq-humanoid", 107, 92),
+    ],
+)
+def test_v5e_scan_body_issues_fewer_scalar_instructions_with_one_sum_a_norm(v5e_sharding, monkeypatch, name, before, ceiling):
+    """The TPU's compiler fuses no arithmetic on scalars: in the scan's body
+    each is an instruction of the loop by itself (the table's `scalars`,
+    trace.chunk_ops_table), and each scalar a fusion hands the scalar core
+    costs the loop a wait. learner.optree_norm hands it one a norm where a
+    sum a leaf handed it six; a later PR that puts a chain of scalar
+    arithmetic behind every leaf learns so here. The configuration's own
+    widths, K = 8 (two trips)."""
+    from distributed_ddpg_tpu import learner as learner_lib
+    from distributed_ddpg_tpu import trace
+
+    def table():
+        return trace.chunk_ops_table(_v5e_scan_chunk(v5e_sharding, name, 8)[2].as_text())
+
+    ours = table()
+    monkeypatch.setattr(
+        learner_lib, "optree_norm",
+        lambda tree: jnp.sqrt(sum(jnp.sum(jnp.square(x)) for x in jax.tree.leaves(tree))),
+    )
+    parents = table()
+    assert len(ours["loops"]) == len(parents["loops"]) == 1
+    assert isinstance(ours["scalars"], int)
+    assert parents["scalars"] >= before - 10  # the body as it was
+    assert ours["scalars"] <= ceiling
+    assert parents["scalars"] - ours["scalars"] >= 20
+
+
 def test_v5e_redq_chunk_draws_before_the_loop_and_holds_the_policy_under_a_conditional(v5e_sharding):
     """`redq-humanoid`'s scan chunk at the configuration's own sizes (ten
     critics, batch 256, obs 376, act 17, K 800, unroll 4), compiled for the
@@ -700,37 +747,10 @@ def test_v5e_redq_chunk_draws_before_the_loop_and_holds_the_policy_under_a_condi
     shuffle's sort and threefry) sits in front of the loop with the normals,
     none of it in the while body; and the policy's half is a conditional in
     the body, not a select over both branches' results."""
-    import json
-    import os
-
-    from distributed_ddpg_tpu import learner as learner_lib
-    from distributed_ddpg_tpu.config import DDPGConfig
-    from distributed_ddpg_tpu.parallel.learner import scan_chunk
-    from distributed_ddpg_tpu.types import unpack_batch
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    conf = json.load(open(os.path.join(root, "benchmarks", "configs", "redq-humanoid.json")))
-    cfg = DDPGConfig.from_flags([f for f in conf["flags"] if not f.startswith("--replay_capacity")])
-    env, chunk = conf["env"], 800
-    obs, act = env["obs_dim"], env["act_dim"]
+    cfg, state, compiled = _v5e_scan_chunk(v5e_sharding, "redq-humanoid", 800)
     assert (cfg.batch_size, cfg.critic_ensemble, cfg.target_subset, cfg.policy_delay) == (256, 10, 2, 20)
-    step = learner_lib.make_learner_step(cfg, env["action_scale"], action_offset=env["action_offset"])
-
-    def run(s, packed):
-        noise = learner_lib.chunk_noise(
-            cfg, learner_lib.noise_base_key(cfg), s.step, chunk,
-            cfg.batch_size, act,
-        )
-        return scan_chunk(step, s, unpack_batch(packed, obs, act), noise, unroll=4)
-
-    replicated = NamedSharding(v5e_sharding.mesh, P())
-    state = jax.tree.map(
-        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=replicated),
-        jax.eval_shape(lambda: learner_lib.init_train_state(cfg, obs, act, 0)),
-    )
     assert state.critic_params[0]["w"].shape == (10, 376, 256)
-    packed = jax.ShapeDtypeStruct((chunk, cfg.batch_size, 2 * obs + act + 3), jnp.float32, sharding=replicated)
-    text = jax.jit(run, donate_argnums=(0,)).lower(state, packed).compile().as_text()
+    text = compiled.as_text()
 
     comps = _computations(text)
     whiles = [line for lines in comps.values() for line in lines if re.search(r"\bwhile\(", line)]
@@ -753,39 +773,12 @@ def test_v5e_crossq_chunk_holds_no_target_update_and_the_policy_under_a_conditio
     the batch norm's instructions read `update/critic/norm` and
     `update/actor/norm`; the noise is drawn in front of the loop; and the
     policy's half is a conditional in the body."""
-    import json
-    import os
-
-    from distributed_ddpg_tpu import learner as learner_lib
     from distributed_ddpg_tpu import trace
-    from distributed_ddpg_tpu.config import DDPGConfig
-    from distributed_ddpg_tpu.parallel.learner import scan_chunk
-    from distributed_ddpg_tpu.types import unpack_batch
 
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    conf = json.load(open(os.path.join(root, "benchmarks", "configs", "crossq-humanoid.json")))
-    cfg = DDPGConfig.from_flags([f for f in conf["flags"] if not f.startswith("--replay_capacity")])
-    env, chunk = conf["env"], 800
-    obs, act = env["obs_dim"], env["act_dim"]
+    cfg, state, compiled = _v5e_scan_chunk(v5e_sharding, "crossq-humanoid", 800)
     assert (cfg.crossq, cfg.batch_size, cfg.critic_hidden, cfg.policy_delay, cfg.adam_b1) == (
         True, 256, (2048, 2048), 3, 0.5)
-    step = learner_lib.make_learner_step(cfg, env["action_scale"], action_offset=env["action_offset"])
-
-    def run(s, packed):
-        noise = learner_lib.chunk_noise(
-            cfg, learner_lib.noise_base_key(cfg), s.step, chunk,
-            cfg.batch_size, act,
-        )
-        return scan_chunk(step, s, unpack_batch(packed, obs, act), noise, unroll=4)
-
-    replicated = NamedSharding(v5e_sharding.mesh, P())
-    state = jax.tree.map(
-        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=replicated),
-        jax.eval_shape(lambda: learner_lib.init_train_state(cfg, obs, act, 0)),
-    )
     assert state.target_critic_params is None and state.critic_params[1]["w"].shape == (2, 2048, 2048)
-    packed = jax.ShapeDtypeStruct((chunk, cfg.batch_size, 2 * obs + act + 3), jnp.float32, sharding=replicated)
-    compiled = jax.jit(run, donate_argnums=(0,)).lower(state, packed).compile()
     # the donated state comes back in place: parameters and both moments, three
     # copies of 10.2 M values and no fourth
     assert 120e6 < compiled.memory_analysis().alias_size_in_bytes < 126e6
