@@ -68,6 +68,7 @@ from distributed_ddpg_tpu import trace
 from distributed_ddpg_tpu.config import DDPGConfig
 from distributed_ddpg_tpu.envs.jax_envs import make_jax_env
 from distributed_ddpg_tpu.metrics import DevActorStats
+from distributed_ddpg_tpu.types import ObsSpec
 from distributed_ddpg_tpu.ops.exploration import (
     nstep_fold,
     nstep_window,
@@ -164,7 +165,10 @@ class DeviceActorPool:
         self._ret_seen = 0.0
 
         env = self.env
-        obs_dim, act_dim = env.obs_dim, env.act_dim
+        # The float32 words of a row that one observation takes: its float
+        # count, or a byte frame stack's bytes over four (types.ObsSpec).
+        self.obs = ObsSpec.of_env(env)
+        obs_dim, act_dim = self.obs.words, env.act_dim
         self.obs_dim, self.act_dim = obs_dim, act_dim
         scale = ((env.action_high - env.action_low) / 2.0).astype(np.float32)
         offset = ((env.action_high + env.action_low) / 2.0).astype(np.float32)
@@ -322,12 +326,23 @@ class DeviceActorPool:
         count these parameters stand at (staleness). The first swap of a
         pool that folds n > 1 steps also takes the n - 1 priming steps,
         unless a restored carry brought its window along."""
-        self._params = actor_params
+        self._params = self.rollout_operand(
+            self.config, actor_params, version
+        )
         self._params_version = int(version)
         if not self._primed:
-            self._carry = self._prime(actor_params, self._carry)
+            self._carry = self._prime(self._params, self._carry)
             self._primed = True
             self._steps += (self.n_step - 1) * self.num_envs
+
+    @staticmethod
+    def rollout_operand(config: DDPGConfig, actor_params, version: int):
+        """What the rollout program takes as its parameters: the policy's
+        tree, and for a pixel configuration the learner step beside it (the
+        scheduled noise scale reads it: an argument, so no recompile)."""
+        if not config.pixels:
+            return actor_params
+        return {**actor_params, "learner_step": np.int32(version)}
 
     @property
     def pending_rows(self) -> int:
@@ -612,9 +627,17 @@ def program_specs():
             from distributed_ddpg_tpu.learner import init_train_state
             from distributed_ddpg_tpu.parallel import mesh as mesh_lib
 
-            params = init_train_state(
-                config, pool.env.obs_dim, pool.env.act_dim, config.seed
-            ).actor_params
+            state = init_train_state(
+                config, pool.obs, pool.env.act_dim, config.seed
+            )
+            params = state.actor_params
+            if config.pixels:
+                from distributed_ddpg_tpu.models.pixels import policy_params
+
+                # what set_params hands the rollout, without its priming run
+                params = DeviceActorPool.rollout_operand(
+                    config, policy_params(state.critic_params, params), 0
+                )
             if tp:
                 # The live tree's placement: TP-sharded kernels per the
                 # rule table, exactly what the pointer-swap refresh hands
@@ -633,6 +656,14 @@ def program_specs():
     # Gaussian ladder in the OU process's place.
     nstep = dict(n_step=3, exploration="gaussian")
 
+    # DrQ-v2's rollout: the convolutional policy on byte frames with the
+    # scheduled noise scale read at the learner step beside the parameters,
+    # the stand-in's renderer, and the 3-step window on rows of words.
+    pixels = dict(
+        pixels=True, twin_critic=True, n_step=3, action_insert_layer=0,
+        env_id="PixelHumanoidStandIn-v0", encoder_channels=4, feature_dim=8,
+    )
+
     return [
         ProgramSpec("devactor.rollout", "actors/device_pool.py", build()),
         ProgramSpec(
@@ -640,5 +671,9 @@ def program_specs():
         ),
         ProgramSpec(
             "devactor.rollout.nstep", "actors/device_pool.py", build(**nstep)
+        ),
+        ProgramSpec(
+            "devactor.rollout.pixels", "actors/device_pool.py",
+            build(**pixels),
         ),
     ]

@@ -55,6 +55,7 @@ from distributed_ddpg_tpu.actors.worker import run_worker
 from distributed_ddpg_tpu.config import DDPGConfig
 from distributed_ddpg_tpu.envs.registry import EnvSpec
 from distributed_ddpg_tpu.metrics import ForwardMeter, nstep_counters
+from distributed_ddpg_tpu.types import ObsSpec, packed_width
 
 # Reap bound for a worker we just terminate()d: long enough for the OS to
 # deliver SIGTERM and tear the process down, short enough that a zombie
@@ -88,12 +89,15 @@ class ActorPool:
                 "respawn every worker forever"
             )
         self._ctx = mp.get_context("spawn")
+        # A pool without workers (a run on device actors alone) shares no
+        # parameters: no layout, an empty array, and start() broadcasts
+        # nothing. Such a run's policy need not have a host layout at all.
         self.layout = param_layout(
             spec.obs_dim,
             actor_head_dim(spec.act_dim, config.sac),
             tuple(config.actor_hidden),
             residual=config.simba,
-        )
+        ) if self.num_actors else []
         self._shared = self._ctx.Array("f", layout_size(self.layout), lock=False)
         self._version = self._ctx.Value("l", 0)
         self._queue = self._ctx.Queue(maxsize=4 * self.num_actors)
@@ -114,7 +118,7 @@ class ActorPool:
             if config.transport in ("auto", "shm") and native.available()
             else "queue"
         )
-        self.row_width = 2 * spec.obs_dim + spec.act_dim + 3
+        self.row_width = packed_width(ObsSpec.of_env(spec), spec.act_dim)
         self._rings = []
         self._ring_bufs = []
         if self.transport == "shm":
@@ -305,7 +309,8 @@ class ActorPool:
         self._procs[worker_id] = p
 
     def start(self, actor_params) -> "ActorPool":
-        self.broadcast(actor_params)
+        if self.num_actors:
+            self.broadcast(actor_params)
         for i in range(self.num_actors):
             self._spawn(i)
         return self

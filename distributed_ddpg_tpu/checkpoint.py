@@ -60,6 +60,9 @@ COMPAT_FIELDS = (
     "sac_autotune",  # alpha_opt presence changes the TrainState tree
     "crossq",  # no target nodes; batch-norm leaves in every layer
     "simba",  # residual nets: blocks, LayerNorm and input-statistics leaves
+    "pixels",  # DrQ-v2's trees: an encoder in the critic's, no target actor
+    "encoder_channels",
+    "feature_dim",
     "num_atoms",
     "v_min",
     "v_max",
@@ -589,7 +592,7 @@ def check_config_compatible(directory: str, step: int, config: DDPGConfig) -> No
         return
     with open(path) as f:
         # a checkpoint from before the field existed was not a crossq run's
-        saved = {"crossq": False, "simba": False, **json.load(f)}
+        saved = {"crossq": False, "simba": False, "pixels": False, **json.load(f)}
     current = dataclasses.asdict(config)
     mismatches = [
         f"{k}: checkpoint={saved[k]!r} run={_listify(current[k])!r}"

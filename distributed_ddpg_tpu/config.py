@@ -153,6 +153,37 @@ class DDPGConfig:
     # action box's log scale, as target_entropy says); 1 is 1812.05905's.
     target_entropy_scale: float = 1.0
 
+    # --- DrQ-v2 (arXiv 2107.09645; DDPG from pixels, twin_critic only) ---
+    # pixels: the observation is a stack of byte frames, uint8[C, H, W] (the
+    # environment says which: envs/jax_envs.py), and the learner is DrQ-v2's
+    # (models/pixels.py, ops/pixels.py, learner.make_learner_step): a shared
+    # convolutional encoder of four 3x3 layers of encoder_channels (strides
+    # 2, 1, 1, 1) that the CRITIC's loss trains, a random shift of aug_pad
+    # pixels on every sampled image inside the update, a LayerNorm-tanh trunk
+    # of feature_dim in front of the twin critics' heads and another in front
+    # of the actor (on the encoder's features, detached), exploration and
+    # target-smoothing noise of one scheduled scale, clipped at
+    # target_noise_clip, and Polyak targets for the critics' trunk and heads
+    # alone. A ring row holds both images as bytes, four to a float32 word
+    # (types.ObsSpec). Device actors only; config.py refuses what this
+    # learner does not carry (host workers, prioritised or row-sharded
+    # replay, the fused beat, guardrails, bfloat16 compute), each with its
+    # ROADMAP item. The source's other settings are plain flags:
+    # twin_critic, n_step 3, tau 0.01, target_noise_clip 0.3, both learning
+    # rates 1e-4 (8e-5 on its hard tasks), hidden 1024,1024, batch 256.
+    pixels: bool = False
+    encoder_channels: int = 32
+    feature_dim: int = 50
+    aug_pad: int = 4
+    # The one noise scale's schedule, "initial,final,frames": linear from
+    # initial to final over `frames` environment frames, then final
+    # (the source's `linear(1.0,0.1,500000)`; 2,000,000 on its hard tasks).
+    # Update k of the learner reads it at 4 k frames (the source's action
+    # repeat 2 times its 2 agent steps an update: ops/pixels.py's
+    # FRAMES_PER_UPDATE), and a rollout at the newest update it was handed
+    # (ops/pixels.sigma_at).
+    explore_sigma_schedule: str = "1.0,0.1,500000"
+
     # --- replay (SURVEY.md §2 #5/#7) ---
     replay_capacity: int = 1_000_000
     replay_min_size: int = 1_000     # warmup before learning starts
@@ -682,11 +713,101 @@ class DDPGConfig:
         )
 
     @property
+    def sigma_schedule(self) -> tuple:
+        """explore_sigma_schedule as (initial, final, frames)."""
+        init, final, frames = self.explore_sigma_schedule.split(",")
+        return float(init), float(final), float(frames)
+
+    @property
     def v_support_auto(self) -> bool:
         """True when the C51 support is auto-sized (v_min/v_max = nan).
         Consumers must resolve concrete bounds (support_auto.initial_bounds)
         before building a learner step — linspace over nan is all-nan."""
         return math.isnan(self.v_min)
+
+    def _check_pixels(self):
+        """What the pixel learner needs, and what it refuses: each message
+        names the flag and where ROADMAP.md holds the missing piece."""
+        if not self.twin_critic or self.policy_delay != 1 or self.target_noise:
+            raise ValueError(
+                "pixels (DrQ-v2) is the deterministic twin-critic step with "
+                "every update moving the actor and a scheduled noise scale: "
+                "set twin_critic=True and leave policy_delay at 1 and "
+                "target_noise at 0 (explore_sigma_schedule is the scale)"
+            )
+        if self.action_insert_layer != 0:
+            raise ValueError(
+                "a pixel critic's heads take [features | action] at their "
+                "input: set action_insert_layer=0"
+            )
+        try:
+            init, final, frames = self.sigma_schedule
+            ok = init >= 0 and final >= 0 and frames > 0
+        except ValueError:
+            ok = False
+        if not ok:
+            raise ValueError(
+                "explore_sigma_schedule must read 'initial,final,frames' "
+                f"(e.g. 1.0,0.1,2000000), got {self.explore_sigma_schedule!r}"
+            )
+        if min(self.encoder_channels, self.feature_dim) < 1 or self.aug_pad < 0:
+            raise ValueError(
+                "encoder_channels and feature_dim must be >= 1 and "
+                "aug_pad >= 0"
+            )
+        if self.backend != "jax_tpu" or self.compute_dtype != "float32":
+            raise ValueError(
+                "pixels needs backend='jax_tpu' and compute_dtype='float32': "
+                "the native learner has no convolution, and the encoder has "
+                "no bfloat16 path of its own (the TPU already multiplies "
+                "float32 operands in one bfloat16 pass)"
+            )
+        if self.actor_backend != "device" or self.num_actors > 0:
+            raise ValueError(
+                "pixels runs device actors only (--actor_backend=device "
+                "--num_actors=0): the numpy policy of the host workers "
+                "(actors/policy.py) has no convolution, 85 MFLOP an image is "
+                "no actor there (ROADMAP.md R7: a convolutional policy for "
+                "host workers)"
+            )
+        if self.prioritized:
+            raise ValueError(
+                "pixels refuses --prioritized: the PER chunk overwrites the "
+                "weight column of rows whose other words are pixel bytes and "
+                "cuts them with unpack_batch (ROADMAP.md R1)"
+            )
+        if self.replay_sharding != "replicated" or self.host_replay:
+            raise ValueError(
+                "pixels refuses --replay_sharding=sharded and --host_replay: "
+                "the row-sharded gather adds float zeros to rows (x + 0.0), "
+                "arithmetic on words that hold pixel bytes, and the host "
+                "replay packs float observations (ROADMAP.md R7: a ring that "
+                "stores a frame once)"
+            )
+        if self.fused_beat == "on" or self.superstep_beats > 1:
+            raise ValueError(
+                "pixels refuses --fused_beat=on and superstep_beats > 1: the "
+                "fused beat composes the flat rollout and chunk bodies and "
+                "has no slot for the convolutional policy's parameters "
+                "(ROADMAP.md D1); the loop dispatches per phase"
+            )
+        if self.guardrails:
+            raise ValueError(
+                "pixels refuses --guardrails: the row screen reads every "
+                "gathered word as a float, and four pixel bytes can spell a "
+                "NaN (ROADMAP.md R7)"
+            )
+        if self.fused_chunk == "on":
+            raise ValueError(
+                "pixels refuses --fused_chunk=on: the megakernel has no "
+                "convolution (ops/fused_chunk.supported); the scan leg runs"
+            )
+        if self.serve_actors:
+            raise ValueError(
+                "pixels refuses --serve_actors: the serving engines batch "
+                "flat float observations for host workers, and there are "
+                "none (ROADMAP.md R5)"
+            )
 
     def __post_init__(self):
         if self.backend not in ("native", "jax_tpu"):
@@ -902,6 +1023,8 @@ class DDPGConfig:
                         "all of the stream's one width (512,512 is two blocks "
                         f"of 512 <-> 2048); got {tuple(getattr(self, knob))}"
                     )
+        if self.pixels:
+            self._check_pixels()
         if self.weight_decay < 0:
             raise ValueError("weight_decay must be >= 0 (0 = plain Adam)")
         if self.weight_decay and self.backend == "native":
@@ -1120,6 +1243,15 @@ class DDPGConfig:
                     f"implementation of {self.env_id!r}; available: "
                     f"{sorted(set(_JAX_ENVS))} — keep actor_backend='host' "
                     "for Gym/Mujoco envs (docs/DEVICE_ACTORS.md)"
+                )
+            if (
+                getattr(_JAX_ENVS[self.env_id], "obs_dtype", "float32") == "uint8"
+            ) != self.pixels:
+                raise ValueError(
+                    f"{self.env_id!r} and pixels={self.pixels}: an "
+                    "environment of byte frames needs --pixels=true (DrQ-v2's "
+                    "learner, models/pixels.py), and that learner reads "
+                    "nothing else"
                 )
             if self.serve_actors:
                 raise ValueError(

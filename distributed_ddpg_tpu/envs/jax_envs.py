@@ -223,9 +223,11 @@ class IsaacHumanoidStandIn:
     ACTION_COST = 0.05   # on mean(u^2)
 
     def __init__(self):
+        # the state's size: the observation's here, a subclass may differ
+        n = getattr(self, "state_dim", self.obs_dim)
         rng = np.random.RandomState(self.MATRIX_SEED)
-        a, _ = np.linalg.qr(rng.standard_normal((self.obs_dim, self.obs_dim)))
-        b = rng.standard_normal((self.act_dim, self.obs_dim))
+        a, _ = np.linalg.qr(rng.standard_normal((n, n)))
+        b = rng.standard_normal((self.act_dim, n))
         self.a = (self.RHO * a).astype(np.float32)
         self.b = (self.INPUT_GAIN * b / np.linalg.norm(b, 2)).astype(np.float32)
 
@@ -268,10 +270,132 @@ class IsaacHumanoidStandIn:
         )
 
 
+class PixelStandInState(NamedTuple):
+    x: jnp.ndarray        # f32[108] the system's state, which no policy sees
+    t: jnp.ndarray        # i32[] agent steps into the episode
+    frames: jnp.ndarray   # uint8[9, 84, 84] the last three rendered frames
+
+
+class PixelHumanoidStandIn(IsaacHumanoidStandIn):
+    """A STAND-IN for DeepMind Control's humanoid FROM PIXELS as DrQ-v2 runs
+    it (arXiv 2107.09645), at its interface and nothing more: observations
+    uint8[9, 84, 84] (three stacked RGB frames, the first frame three times
+    after a reset), 21 actions in [-1, 1], action repeat 2 inside `step`
+    (the same action twice, the rewards summed, the second sub-step skipped
+    where the first ended the episode), 500 agent steps an episode. dm_control
+    and its renderer cannot run here, so BOTH halves are stand-ins:
+
+    - the dynamics are IsaacHumanoidStandIn's seeded linear system (its A, B,
+      noise, box, reward), at 21 actions: no physics step;
+    - the renderer is a fixed smooth map of the state, no rasteriser: plane c
+      of a frame is 127.5 + 127.5 * mean_k tanh(U_ck x + a_ck)_i
+      tanh(V_ck x + b_ck)_j, rounded to a byte, with U, V, a, b drawn once
+      from `MATRIX_SEED` (`RENDER_RANK` = 8 terms a plane). Every pixel moves
+      with the state, so frames differ from step to step and from
+      environment to environment and a convolution's gradient on them is not
+      degenerate; a frame costs ~1.2 MFLOP (two [8 x 84, 108] projections a
+      plane and an [84, 8] @ [8, 84] product), far below a rasteriser's.
+
+    The simulator's and the renderer's share of the device are MISSING from a
+    run on this environment (docs/DEVICE_ACTORS.md), as PQL's simulator is.
+    `step` brackets its own parts (`env`, `render`: trace.ROLLOUT_SCOPES)."""
+
+    obs_shape = (9, 84, 84)
+    obs_dtype = "uint8"
+    obs_dim = 9 * 84 * 84   # elements of one observation
+    state_dim = 108
+    max_episode_steps = 500  # agent steps: 1,000 frames at action repeat 2
+    ACTION_REPEAT = 2
+    SIDE = 84
+    RENDER_RANK = 8
+    scopes_itself = True
+
+    def __init__(self):
+        super().__init__()
+        rng = np.random.RandomState(self.MATRIX_SEED ^ 0xF4A3E)
+        shape = (3, self.RENDER_RANK, self.SIDE)
+        self.render_u, self.render_v = (
+            rng.standard_normal((*shape, self.state_dim)).astype(np.float32)
+            for _ in range(2)
+        )
+        self.render_a, self.render_b = (
+            rng.uniform(-2.0, 2.0, shape).astype(np.float32) for _ in range(2)
+        )
+
+    def render(self, x) -> jnp.ndarray:
+        """One frame uint8[3, 84, 84] of state x."""
+        rows = jnp.tanh(self.render_u @ x + self.render_a)   # [3, K, 84]
+        cols = jnp.tanh(self.render_v @ x + self.render_b)
+        plane = jnp.einsum("cki,ckj->cij", rows, cols) / self.RENDER_RANK
+        return jnp.round(127.5 + 127.5 * plane).astype(jnp.uint8)
+
+    def init(self, key) -> PixelStandInState:
+        x = jax.random.uniform(
+            key, (self.state_dim,), jnp.float32, -self.INIT, self.INIT
+        )
+        return PixelStandInState(
+            x=x, t=jnp.zeros((), jnp.int32),
+            frames=jnp.tile(self.render(x), (3, 1, 1)),
+        )
+
+    def observe(self, s: PixelStandInState) -> jnp.ndarray:
+        return s.frames
+
+    def step(self, s: PixelStandInState, action, key):
+        # the words of trace.ROLLOUT_SCOPES (`rollout/env`, `rollout/render`):
+        # this layer imports nothing of the package, so it names them itself
+        device_scope = jax.named_scope
+        u = jnp.clip(action, -1.0, 1.0)
+        k_noise, k_reset = jax.random.split(key)
+        with device_scope("env"):
+            x, reward = s.x, jnp.zeros((), jnp.float32)
+            terminated = jnp.zeros((), bool)
+            for k in jax.random.split(k_noise, self.ACTION_REPEAT):
+                nx = (
+                    x @ self.a + u @ self.b
+                    + self.NOISE * jax.random.normal(k, x.shape, jnp.float32)
+                )
+                r = (
+                    self.ALIVE
+                    - self.STATE_COST * jnp.mean(jnp.square(nx))
+                    - self.ACTION_COST * jnp.mean(jnp.square(u))
+                )
+                reward = reward + jnp.where(terminated, 0.0, r)
+                x = jnp.where(terminated, x, nx)
+                terminated = terminated | (jnp.max(jnp.abs(x)) > self.BOX)
+                # (a sub-step behind the episode's end changes nothing)
+            t = s.t + 1
+            done = terminated | (t >= self.max_episode_steps)
+            fresh_x = jax.random.uniform(
+                k_reset, (self.state_dim,), jnp.float32, -self.INIT, self.INIT
+            )
+        with device_scope("render"):
+            # two renders a step, whichever is kept: the stepped state's
+            # frame (the bootstrap observation, also of an episode that
+            # ended) and a fresh episode's first
+            stepped = jnp.concatenate([s.frames[3:], self.render(x)])
+            first = jnp.tile(self.render(fresh_x), (3, 1, 1))
+        nxt = PixelStandInState(
+            x=jnp.where(done, fresh_x, x),
+            t=jnp.where(done, 0, t),
+            frames=jnp.where(done, first, stepped),
+        )
+        return StepOut(
+            state=nxt,
+            obs=nxt.frames,
+            boot_obs=stepped,
+            reward=reward.astype(jnp.float32),
+            done=done,
+            terminated=terminated,
+        )
+
+
 STAND_IN_ID = "IsaacHumanoidStandIn-v0"
+PIXEL_STAND_IN_ID = "PixelHumanoidStandIn-v0"
 
 _JAX_ENVS = {
     STAND_IN_ID: IsaacHumanoidStandIn,
+    PIXEL_STAND_IN_ID: PixelHumanoidStandIn,
     "Pendulum-v1": JaxPendulum,
     "builtin/Pendulum-v1": JaxPendulum,
     "MountainCarContinuous-v0": JaxMountainCar,

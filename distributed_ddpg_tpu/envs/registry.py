@@ -35,7 +35,7 @@ _VERSION_ALIASES = {
 # Ids with JAX dynamics only (envs/jax_envs.py): `make` wraps them for the
 # trainer's own use (the spec, an evaluation); a worker process, which must
 # never import JAX, cannot step them (config.py refuses that at parse).
-DEVICE_ONLY = frozenset({"IsaacHumanoidStandIn-v0"})
+DEVICE_ONLY = frozenset({"IsaacHumanoidStandIn-v0", "PixelHumanoidStandIn-v0"})
 
 
 class EnvSpec(NamedTuple):
@@ -43,6 +43,10 @@ class EnvSpec(NamedTuple):
     act_dim: int
     action_low: np.ndarray
     action_high: np.ndarray
+    # An observation that is no flat float vector (byte frames) names its
+    # shape and dtype; types.ObsSpec.of_env reads either kind.
+    obs_shape: tuple = ()
+    obs_dtype: str = "float32"
 
     @property
     def action_scale(self) -> np.ndarray:
@@ -111,6 +115,8 @@ class _JaxEnvAdapter:
             self._key = jax.random.PRNGKey(seed)
         self._state = None
         self.observation_dim = self._env.obs_dim
+        self.observation_shape = tuple(getattr(self._env, "obs_shape", ()))
+        self.observation_dtype = getattr(self._env, "obs_dtype", "float32")
         self.action_dim = self._env.act_dim
         self.action_low = np.asarray(self._env.action_low, np.float32)
         self.action_high = np.asarray(self._env.action_high, np.float32)
@@ -180,4 +186,6 @@ def spec_of(env) -> EnvSpec:
         act_dim=int(env.action_dim),
         action_low=np.asarray(env.action_low, np.float32),
         action_high=np.asarray(env.action_high, np.float32),
+        obs_shape=tuple(getattr(env, "observation_shape", ())),
+        obs_dtype=getattr(env, "observation_dtype", "float32"),
     )

@@ -34,10 +34,12 @@ from distributed_ddpg_tpu.ops import losses
 from distributed_ddpg_tpu.ops.optim import adam_update
 from distributed_ddpg_tpu.ops.polyak import polyak_update
 from distributed_ddpg_tpu.trace import device_scope
-from distributed_ddpg_tpu.types import Batch, OptState, TrainState
+from distributed_ddpg_tpu.types import Batch, ObsSpec, OptState, TrainState
+from distributed_ddpg_tpu.models import pixels as pixnet
 from distributed_ddpg_tpu.models.mlp import (
     actor_init, critic_init, norm_moved, rs_merged, rs_written, simba_init,
 )
+from distributed_ddpg_tpu.ops import pixels as pix
 
 
 class StepOutput(NamedTuple):
@@ -79,10 +81,17 @@ def metric_keys(config: DDPGConfig) -> tuple:
     rewrites), `rsnorm_count` (the rows the input normaliser has seen) and
     `rsnorm_drift` (the mean over the features of |batch mean - running
     mean| / running standard deviation). A chunk reports its last update's
-    of each (chunk_metrics). Only those branches have the keys, so every
+    of each (chunk_metrics). A pixel (DrQ-v2) run, config.pixels, reports
+    beside the twin gap `encoder_grad_norm` (the norm of the critic loss's
+    gradient on the encoder alone, the chunk's mean), `explore_sigma` (the
+    scheduled noise scale of the chunk's last update) and `aug_offset_mean`
+    (the mean of the launch's crop offsets: `aug_pad` in expectation, a
+    counter that says the draw is alive). Only those branches have the keys, so every
     other family's programs and records are what they were."""
     if config.distributional:  # config.py: never with twin_critic or sac
         return METRIC_KEYS + ("c51_edge_mass",)
+    if config.pixels:
+        return METRIC_KEYS + ("td3_twin_gap",) + PIXEL_KEYS
     if config.twin_critic:
         return METRIC_KEYS + ("td3_twin_gap",)
     if config.redq:
@@ -95,9 +104,11 @@ def metric_keys(config: DDPGConfig) -> tuple:
 
 
 SIMBA_KEYS = ("resid_share", "rsnorm_count", "rsnorm_drift")
+PIXEL_KEYS = ("encoder_grad_norm", "explore_sigma", "aug_offset_mean")
 # Metrics a chunk reports for its LAST update, not as a mean over the K.
 LAST_UPDATE_KEYS = (
     "c51_edge_mass", "td3_twin_gap", "redq_q_spread", "bn_stat_gap", *SIMBA_KEYS,
+    "explore_sigma",
 )
 
 
@@ -164,7 +175,8 @@ def draws_subset(config: DDPGConfig) -> bool:
 def draws_noise(config: DDPGConfig) -> bool:
     """Whether `config`'s learner step draws noise at all."""
     return bool(
-        config.sac or (config.twin_critic and config.target_noise > 0.0)
+        config.sac or config.pixels
+        or (config.twin_critic and config.target_noise > 0.0)
     )
 
 
@@ -176,7 +188,8 @@ def noise_base_key(config: DDPGConfig):
     if not draws_noise(config):
         return None
     return jax.random.PRNGKey(
-        config.seed ^ (0x5AC0 if config.sac else 0x7D3AF)
+        config.seed
+        ^ (0x5AC0 if config.sac else 0xD2C if config.pixels else 0x7D3AF)
     )
 
 
@@ -188,7 +201,11 @@ def step_noise(config: DDPGConfig, base, step, batch: int, act_dim: int,
     at s; with target_subset < critic_ensemble a third member, the update's
     in-target critics, int32[M], distinct and uniform over the subsets, the
     same on every device. TD3: the target-smoothing noise [B, act], scaled
-    and clipped. None
+    and clipped. Pixels (DrQ-v2): the triple (crop offsets int32[B, 4] =
+    (dy, dx) of `obs` then of `next_obs`, uniform in 0..2*aug_pad; the
+    target action's noise; the actor loss's), both noises sigma * N(0, I)
+    clipped at target_noise_clip with sigma the schedule at this step
+    (ops/pixels.sigma_at). None
     where the algorithm draws none (DDPG, D4PG, TD3 without smoothing).
     `device_fold` (lax.axis_index under shard_map) folds a per-device term
     AFTER the step fold, so that each shard of a global batch draws its own
@@ -212,6 +229,17 @@ def step_noise(config: DDPGConfig, base, step, batch: int, act_dim: int,
             config.critic_ensemble, (config.target_subset,), replace=False,
         )
         return (*eps, subset.astype(jnp.int32))
+    if config.pixels:
+        k_off, k_next, k_cur = jax.random.split(key, 3)
+        sigma = pix.sigma_at(config.sigma_schedule, step)
+        clip = config.target_noise_clip
+        return (
+            jax.random.randint(k_off, (batch, 4), 0, 2 * config.aug_pad + 1),
+            *(
+                jnp.clip(sigma * jax.random.normal(k, (batch, act_dim)), -clip, clip)
+                for k in (k_next, k_cur)
+            ),
+        )
     return jnp.clip(
         config.target_noise * jax.random.normal(key, (batch, act_dim)),
         -config.target_noise_clip,
@@ -225,6 +253,8 @@ def noise_per_row(config: DDPGConfig):
     is the same on every replica (REDQ's subset)."""
     if not draws_noise(config):
         return None
+    if config.pixels:
+        return (True, True, True)
     if not config.sac:
         return True
     return (True, True, False) if draws_subset(config) else (True, True)
@@ -244,11 +274,50 @@ def chunk_noise(config: DDPGConfig, base, step0, chunk: int, batch: int,
         )(step0 + jnp.arange(chunk))
 
 
-def init_train_state(config: DDPGConfig, obs_dim: int, act_dim: int, seed: int) -> TrainState:
+def _opt_init(params) -> OptState:
+    """Adam's state before any step: zero moments shaped like `params`."""
+    return OptState(
+        mu=jax.tree.map(jnp.zeros_like, params),
+        nu=jax.tree.map(jnp.zeros_like, params),
+        count=jnp.zeros((), jnp.int32),
+    )
+
+
+def init_pixel_state(config: DDPGConfig, obs: ObsSpec, act_dim: int, seed: int) -> TrainState:
+    """DrQ-v2's state (models/pixels.py): the encoder in the critic's tree
+    under the critic's Adam, a target for the critic's trunk and heads
+    alone, and no target actor (the slot None, as CrossQ's are)."""
+    critic = pixnet.critic_init(
+        seed, obs.shape, config.encoder_channels, config.feature_dim,
+        tuple(config.critic_hidden), act_dim,
+    )
+    actor = pixnet.actor_init(
+        seed, critic["trunk"]["w"].shape[0], config.feature_dim,
+        tuple(config.actor_hidden), act_dim,
+    )
+    return TrainState(
+        actor_params=actor,
+        critic_params=critic,
+        target_actor_params=None,
+        target_critic_params=jax.tree.map(
+            jnp.copy, pixnet.trained_with_target(critic)
+        ),
+        actor_opt=_opt_init(actor),
+        critic_opt=_opt_init(critic),
+        step=jnp.zeros((), jnp.int32),
+    )
+
+
+def init_train_state(config: DDPGConfig, obs_dim, act_dim: int, seed: int) -> TrainState:
     """Build initial params + hard-copied targets (SURVEY.md §3.4) + Adam
     state. CrossQ (config.crossq): batch-normalised nets and no targets,
     the two slots None (empty pytree nodes, as log_alpha is outside sac).
-    SimBa (config.simba): residual nets (models/mlp.simba_init)."""
+    SimBa (config.simba): residual nets (models/mlp.simba_init). `obs_dim`
+    is the observation's float count or its types.ObsSpec; a pixel
+    configuration (config.pixels) needs the spec and takes init_pixel_state."""
+    if config.pixels:
+        return init_pixel_state(config, ObsSpec.of(obs_dim), act_dim, seed)
+    obs_dim = ObsSpec.of(obs_dim).words
     key = jax.random.PRNGKey(seed)
     k_actor, k_critic = jax.random.split(key)
     num_outputs = config.num_atoms if config.distributional else 1
@@ -309,16 +378,8 @@ def init_train_state(config: DDPGConfig, obs_dim: int, act_dim: int, seed: int) 
         target_critic_params=(
             None if config.crossq else jax.tree.map(jnp.copy, critic_params)
         ),
-        actor_opt=OptState(
-            mu=jax.tree.map(jnp.zeros_like, actor_params),
-            nu=jax.tree.map(jnp.zeros_like, actor_params),
-            count=jnp.zeros((), jnp.int32),
-        ),
-        critic_opt=OptState(
-            mu=jax.tree.map(jnp.zeros_like, critic_params),
-            nu=jax.tree.map(jnp.zeros_like, critic_params),
-            count=jnp.zeros((), jnp.int32),
-        ),
+        actor_opt=_opt_init(actor_params),
+        critic_opt=_opt_init(critic_params),
         step=jnp.zeros((), jnp.int32),
         # SAC entropy temperature: learned log(alpha) scalar + its own Adam
         # state (None = empty pytree nodes for every other family).
@@ -605,6 +666,103 @@ def make_learner_step(
     if config.sac:
         return sac_step
 
+    def pixel_step(state: TrainState, batch: Batch, noise=None) -> StepOutput:
+        """DrQ-v2 (config.pixels; models/pixels.py, ops/pixels.py): the
+        deterministic twin-critic update on augmented byte images. `batch.obs`
+        and `batch.next_obs` are uint8[B, C, H, W]. One pass of the ONLINE
+        encoder on each (the second without gradient), clipped double Q on
+        the TARGET trunk and heads with the online actor's noisy action, the
+        critic's loss (the SUM of the two heads' weighted squared errors, as
+        the source writes it) moving encoder, trunk and heads under one Adam,
+        the actor's loss on the DETACHED features through the critic as it
+        stood before this update (file convention), Polyak on trunk and
+        heads. The encoder's passes read under `update/encoder`, outside the
+        critic's bracket, so their device time can be told apart."""
+        offsets, noise_next, noise_cur = (
+            own_noise(state, batch) if noise is None else noise
+        )
+        critic, lo, hi = state.critic_params, offset - scale, offset + scale
+        with device_scope("augment"):
+            obs = pix.random_shift(batch.obs, offsets[:, :2], config.aug_pad)
+            next_obs = pix.random_shift(
+                batch.next_obs, offsets[:, 2:], config.aug_pad
+            )
+        with device_scope("encoder"):
+            feat, encoder_vjp = jax.vjp(
+                lambda enc: pixnet.encoder_apply(enc, obs), critic["encoder"]
+            )
+            feat_next = pixnet.encoder_apply(critic["encoder"], next_obs)
+
+        def critic_loss_fn(heads, feat):
+            next_action = pix.clipped_action(
+                pixnet.actor_apply(state.actor_params, feat_next, scale, offset),
+                noise_next, lo, hi,
+            )
+            next_q = pixnet.critic_apply(
+                state.target_critic_params, feat_next, next_action
+            )
+            y = jax.lax.stop_gradient(
+                losses.td_targets(batch, jnp.min(next_q, axis=0))
+            )
+            td = y[None, :] - pixnet.critic_apply(heads, feat, batch.action)
+            loss = jnp.sum(jnp.mean(batch.weight[None, :] * jnp.square(td), axis=1))
+            twin_gap = jnp.mean(jnp.abs(next_q[0] - next_q[1]))
+            return loss, (jnp.mean(td, axis=0), twin_gap)
+
+        with device_scope("critic"):
+            (closs, (td, twin_gap)), (hgrads, gfeat) = jax.value_and_grad(
+                critic_loss_fn, argnums=(0, 1), has_aux=True
+            )(pixnet.trained_with_target(critic), feat)
+        with device_scope("encoder"):
+            (egrads,) = encoder_vjp(gfeat)
+        cgrads = _maybe_psum_mean({"encoder": egrads, **hgrads}, axis_name)
+
+        def actor_loss_fn(ap):
+            detached = jax.lax.stop_gradient(feat)
+            action = pix.clipped_action(
+                pixnet.actor_apply(ap, detached, scale, offset), noise_cur, lo, hi
+            )
+            return -jnp.mean(
+                jnp.min(pixnet.critic_apply(critic, detached, action), axis=0)
+            )
+
+        with device_scope("actor"):
+            aloss, agrads = jax.value_and_grad(actor_loss_fn)(state.actor_params)
+            agrads = _maybe_psum_mean(agrads, axis_name)
+        new_critic, critic_opt = adam_update(
+            critic, cgrads, state.critic_opt, config.critic_lr,
+            config.adam_b1, config.weight_decay,
+        )
+        new_actor, actor_opt = adam_update(
+            state.actor_params, agrads, state.actor_opt, config.actor_lr,
+            config.adam_b1, config.weight_decay,
+        )
+        new_target = polyak_update(
+            pixnet.trained_with_target(new_critic), state.target_critic_params,
+            config.tau,
+        )
+        metrics = dict(zip(keys, (
+            closs, aloss, -aloss, jnp.mean(jnp.abs(td)),
+            optree_norm(cgrads), optree_norm(agrads), twin_gap,
+            optree_norm(cgrads["encoder"]),
+            pix.sigma_at(config.sigma_schedule, state.step),
+            jnp.mean(offsets.astype(jnp.float32)),
+        )))
+        metrics = _maybe_psum_mean(metrics, axis_name)
+        new_state = TrainState(
+            actor_params=new_actor,
+            critic_params=new_critic,
+            target_actor_params=None,
+            target_critic_params=new_target,
+            actor_opt=actor_opt,
+            critic_opt=critic_opt,
+            step=state.step + 1,
+        )
+        return StepOutput(state=new_state, td_errors=td, metrics=metrics)
+
+    if config.pixels:
+        return pixel_step
+
     def step(state: TrainState, batch: Batch, noise=None) -> StepOutput:
         # --- critic update ---
         if config.twin_critic:
@@ -819,6 +977,13 @@ def make_act_fn(config: DDPGConfig, action_scale, action_offset=0.0):
 
     scale = jnp.asarray(action_scale, jnp.float32)
     offset = jnp.asarray(action_offset, jnp.float32)
+
+    if config.pixels:
+        # `actor_params` is models/pixels.policy_params (encoder + actor),
+        # `obs` byte frames uint8[B, C, H, W]: the learner's own apply.
+        return jax.jit(
+            lambda policy, obs: pixnet.policy_apply(policy, obs, scale, offset)
+        )
 
     if config.sac:
 

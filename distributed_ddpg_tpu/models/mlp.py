@@ -250,13 +250,13 @@ def simba_init(
     return (embed, *blocks, head)
 
 
-def _layer_norm(x, layer):
+def _layer_norm(x, layer, eps: float = LN_EPS):
     """LayerNorm over the last axis with `layer`'s learned scale and shift;
     moments, division and the affine map in x's float32."""
     with device_scope("lnorm"):
         mean = jnp.mean(x, axis=-1, keepdims=True)
         var = jnp.mean(jnp.square(x - mean), axis=-1, keepdims=True)
-        return (x - mean) * jax.lax.rsqrt(var + LN_EPS) * layer["ln_scale"] + layer["ln_shift"]
+        return (x - mean) * jax.lax.rsqrt(var + eps) * layer["ln_scale"] + layer["ln_shift"]
 
 
 def simba_apply(params: Params, obs, action=None, mm_dtype=None, resid: bool = False):

@@ -21,6 +21,7 @@ exact stream, and it keeps existing seeds' streams stable.
 
 from __future__ import annotations
 
+import contextlib
 from typing import NamedTuple
 
 import jax
@@ -128,7 +129,23 @@ def vector_env_step(
     E = num_envs
     next_key, k_ou, k_env, k_uni = jax.random.split(key, 4)
     with device_scope("policy"):
-        if cfg.sac:
+        if cfg.pixels:
+            # DrQ-v2: the convolutional policy on the byte frames, and noise
+            # of the ONE scheduled scale at the newest learner step the
+            # pool was handed (`params["learner_step"]`), unclipped but for
+            # the action box, as the source samples when it acts.
+            from distributed_ddpg_tpu.models.pixels import policy_apply
+            from distributed_ddpg_tpu.ops.pixels import sigma_at
+
+            sigma = sigma_at(cfg.sigma_schedule, params["learner_step"])
+            action = jnp.clip(
+                policy_apply(params, obs, scale, offset)
+                + sigma * jax.random.normal(k_ou, ou.shape, jnp.float32) * scale,
+                low,
+                high,
+            )
+            new_ou = ou
+        elif cfg.sac:
             # SAC explores by sampling its own tanh-Gaussian on device; the
             # OU state rides along untouched (zeros — worker.py parity).
             from distributed_ddpg_tpu.models.mlp import actor_gaussian_apply
@@ -175,22 +192,36 @@ def vector_env_step(
                 ),
                 action,
             )
-    with device_scope("env"):
+    # An environment that brackets its own parts (a pixel one: `env` and
+    # `render`) is not bracketed again.
+    with (
+        contextlib.nullcontext() if getattr(env, "scopes_itself", False)
+        else device_scope("env")
+    ):
         out = jax.vmap(env.step)(
             env_state, action, jax.random.split(k_env, E)
         )
     # Packed rows in types.pack_batch_np order; discount 0 where the env
-    # truly terminated, truncation keeps bootstrapping.
+    # truly terminated, truncation keeps bootstrapping. Byte frames ride
+    # the float32 row as words, bitcast and never computed on
+    # (ops/pixels.py); `out.boot_obs` leaves as those words too, which is
+    # what the n-step fold writes into the rows it holds.
     discount = cfg.gamma * (
         1.0 - jnp.broadcast_to(out.terminated, (E,)).astype(jnp.float32)
     )
+    boot_obs = out.boot_obs
+    if obs.dtype == jnp.uint8:
+        from distributed_ddpg_tpu.ops.pixels import words_of
+
+        obs, boot_obs = words_of(obs), words_of(boot_obs)
+        out = out._replace(boot_obs=boot_obs)
     rows = jnp.concatenate(
         [
             obs,
             action,
             out.reward[:, None],
             discount[:, None],
-            out.boot_obs,
+            boot_obs,
             jnp.ones((E, 1), jnp.float32),
         ],
         axis=-1,

@@ -266,6 +266,8 @@ def supported(config: DDPGConfig) -> bool:
         # REDQ, CrossQ, SimBa: no kernel branch; the kernel's Adam holds
         # beta_1 as the constant B1 and has no decay term
         and not (config.redq or config.crossq or config.simba)
+        # DrQ-v2: no convolution in the kernel
+        and not config.pixels
         and config.adam_b1 == B1
         and config.weight_decay == 0.0
         and config.compute_dtype in ("float32", "bfloat16")

@@ -66,10 +66,12 @@ from distributed_ddpg_tpu.learner import (
     noise_base_key,
     noise_per_row,
 )
+from distributed_ddpg_tpu.models import pixels as pixnet
 from distributed_ddpg_tpu.models.mlp import fold_norm
 from distributed_ddpg_tpu.parallel import mesh as mesh_lib
 from distributed_ddpg_tpu.types import (
     Batch,
+    ObsSpec,
     OptState,
     TrainState,
     pack_batch_np,
@@ -244,7 +246,18 @@ class ShardedLearner:
                 f"size {self.data_size}"
             )
 
-        self.obs_dim, self.act_dim = obs_dim, act_dim
+        # `obs_dim`: the observation's float count, or its types.ObsSpec (a
+        # pixel configuration's byte frames). self.obs_dim is the float32
+        # words of a ring row one observation takes, what every cut of a
+        # packed row reads; the nets' builders take the spec.
+        self.obs = ObsSpec.of(obs_dim)
+        if self.obs.pixels != bool(config.pixels):
+            raise ValueError(
+                f"observations of dtype {self.obs.dtype} and pixels="
+                f"{config.pixels}: byte frames need --pixels=true "
+                "(DrQ-v2's learner) and it reads nothing else"
+            )
+        self.obs_dim, self.act_dim = self.obs.words, act_dim
         # Numerical-health guardrails (guardrails.py): the chunk programs
         # thread a small replicated GuardState through the scan and emit a
         # per-chunk health word. Off (default) builds the exact pre-
@@ -265,7 +278,7 @@ class ShardedLearner:
         # LR cooldown hook (train.py rollback-repair): both LRs scale by
         # _lr_scale; set_lr_scale rebuilds the (lazily compiled) programs.
         self._lr_scale = 1.0
-        state = init_train_state(config, obs_dim, act_dim, config.seed)
+        state = init_train_state(config, self.obs, act_dim, config.seed)
         self._state_sharding = mesh_lib.to_named(
             self.mesh, mesh_lib.state_pspec(state, self.mesh)
         )
@@ -517,10 +530,14 @@ class ShardedLearner:
         # (which screen and overwrite the gathered rows) and the host-fed
         # chunk keep unpack_batch.
         from distributed_ddpg_tpu.ops import chunk_front as front_lib
+        from distributed_ddpg_tpu.ops.pixels import cut_pixels
         from distributed_ddpg_tpu.replay.device import ring_layout
 
         width = packed_width(obs_dim, act_dim)
-        scan_front = front_lib.front_for(
+        # A pixel launch has a cut of its own (ops/pixels.cut_pixels: the
+        # float fields as unpack_batch cuts them, the images bitcast to
+        # bytes), and its words must not pass through the kernel's rounding.
+        scan_front = "xla" if config.pixels else front_lib.front_for(
             width=width,
             batch=batch_size // n_shards,
             layout=ring_layout(width, self._replay_sharded),
@@ -530,6 +547,8 @@ class ShardedLearner:
         )
 
         def cut_chunk(packed) -> Batch:
+            if config.pixels:
+                return cut_pixels(packed, self.obs, act_dim)
             if scan_front == "xla":
                 return unpack_batch(packed, obs_dim, act_dim)
             cut = partial(
@@ -1259,18 +1278,32 @@ class ShardedLearner:
 
     # --- host-side views ---
 
+    def policy_params(self):
+        """The live, device-resident tree that acts: the actor's, and for a
+        pixel configuration the encoder (the critic's) in front of it
+        (models/pixels.policy_params). What the device pool's pointer swap
+        takes; donated away with the state by the next launch."""
+        if self.config.pixels:
+            return pixnet.policy_params(
+                self.state.critic_params, self.state.actor_params
+            )
+        return self.state.actor_params
+
     def actor_params_to_host(self):
         """Numpy actor params for broadcast to CPU rollout workers. The
         span matters: this d2h syncs the in-flight chunk, so the timeline
         shows it as the learner-thread gap before every param refresh /
         eval snapshot. A batch-normalised actor (CrossQ) leaves as the plain
         MLP it is in evaluation mode (mlp.fold_norm): the workers' layout,
-        the evaluator and the serving engine never see the normalisation."""
+        the evaluator and the serving engine never see the normalisation.
+        A pixel configuration's policy (policy_params) leaves as it is:
+        nothing on the host but the evaluator and the checksum reads it."""
         def fetch():
             with trace.span("params_d2h"):
-                return fold_norm(jax.tree.map(
-                    np.asarray, jax.device_get(self.state.actor_params)
-                ))
+                host = jax.tree.map(
+                    np.asarray, jax.device_get(self.policy_params())
+                )
+                return host if self.config.pixels else fold_norm(host)
 
         if self.transfer is None:
             return fetch()
@@ -1462,12 +1495,23 @@ def program_specs():
         actor_hidden=(16,), critic_hidden=(16,), target_entropy_scale=0.5,
     )
 
+    # DrQ-v2's chunk (byte images cut out of the gathered words, the crop
+    # offsets and both noises pre-drawn, the encoder's passes, one Adam over
+    # encoder, trunk and heads, no target actor), as the benchmark's cell
+    # runs it, on frames of 3 x 16 x 16 (the smallest the four layers take).
+    PIXELS = dict(
+        pixels=True, twin_critic=True, n_step=3, action_insert_layer=0,
+        env_id="PixelHumanoidStandIn-v0", actor_backend="device",
+        num_actors=0, device_actor_envs=4, encoder_channels=4, feature_dim=8,
+        tau=0.01, target_noise_clip=0.3,
+    )
+
     def learner(
         guard: bool = False, sharded: bool = False, tp: bool = False,
         ensemble: bool = False, mode: str = "auto", crossq: bool = False,
-        pql: bool = False, simba: bool = False,
+        pql: bool = False, simba: bool = False, pixels: bool = False,
     ) -> ShardedLearner:
-        key = (guard, sharded, tp, ensemble, mode, crossq, pql, simba)
+        key = (guard, sharded, tp, ensemble, mode, crossq, pql, simba, pixels)
         if key not in cache:
             cache[key] = ShardedLearner(
                 probe_config(
@@ -1476,8 +1520,9 @@ def program_specs():
                     **(CROSSQ if crossq else {}),
                     **(PQL if pql else {}),
                     **(SIMBA if simba else {}),
+                    **(PIXELS if pixels else {}),
                 ),
-                obs_dim=3,
+                obs_dim=ObsSpec((3, 16, 16), "uint8") if pixels else 3,
                 act_dim=1,
                 action_scale=np.ones(1, np.float32),
                 mesh=probe_mesh(2 if tp else 1),
@@ -1628,6 +1673,12 @@ def program_specs():
         ProgramSpec(
             "learner.chunk.uniform.simba", OWNER,
             uniform(False, sharded=False, simba=True),
+        )
+    )
+    specs.append(
+        ProgramSpec(
+            "learner.chunk.uniform.pixels", OWNER,
+            uniform(False, sharded=False, pixels=True),
         )
     )
     return specs

@@ -64,6 +64,7 @@ import jax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from distributed_ddpg_tpu.models.mlp import is_simba
+from distributed_ddpg_tpu.models.pixels import is_pixel
 from distributed_ddpg_tpu.types import OptState, TrainState
 
 # One rule: (regex over the '/'-joined tree path, PartitionSpec). The
@@ -107,6 +108,19 @@ SIMBA_RULES: Tuple[Rule, ...] = (
     (r"(^|/)\d+/(b2|b)$", P(None)),
     (r"(^|/)\d+/w$", P(None, None)),
 )
+
+
+def pixel_rules(params) -> Tuple[Rule, ...]:
+    """A pixel net (models/pixels.py: a dict of `encoder`, `trunk`, `heads`
+    or `mlp`): convolution kernels, their biases and the trunk (its wide
+    [features, feature_dim] product feeds a LayerNorm over the whole
+    feature axis) replicate; the dense layers behind it follow the MLP
+    table by their index, as every dense chain does."""
+    chain = params.get("heads", params.get("mlp", ()))
+    return (
+        (r"(^|/)encoder/\d+/(w|b)$", P(None)),
+        (r"(^|/)trunk/(w|b|ln_scale|ln_shift)$", P(None)),
+    ) + mlp_rules(len(chain))
 
 
 def mlp_rules(num_layers: int) -> Tuple[Rule, ...]:
@@ -183,7 +197,10 @@ def net_pspec(params, model_size: int, rules: Optional[Sequence[Rule]] = None):
     per-depth MLP table (mlp_rules), or SIMBA_RULES for a residual net;
     pass `rules` for any other."""
     if rules is None:
-        rules = SIMBA_RULES if is_simba(params) else mlp_rules(len(params))
+        if is_pixel(params):
+            rules = pixel_rules(params)
+        else:
+            rules = SIMBA_RULES if is_simba(params) else mlp_rules(len(params))
     return match_partition_rules(rules, params, model_size)
 
 
@@ -201,12 +218,16 @@ def state_pspec(
     m = mesh.shape["model"]
     actor = net_pspec(state.actor_params, m, rules=actor_rules)
     critic = net_pspec(state.critic_params, m, rules=critic_rules)
+    target_critic = critic
+    if state.target_critic_params is not None and is_pixel(critic):
+        # a pixel critic's target holds trunk and heads, not the encoder
+        target_critic = {k: critic[k] for k in state.target_critic_params}
     return TrainState(
         actor_params=actor,
         critic_params=critic,
         # None (CrossQ has no targets) is an empty pytree node, as below.
         target_actor_params=None if state.target_actor_params is None else actor,
-        target_critic_params=None if state.target_critic_params is None else critic,
+        target_critic_params=None if state.target_critic_params is None else target_critic,
         actor_opt=OptState(mu=actor, nu=actor, count=P()),
         critic_opt=OptState(mu=critic, nu=critic, count=P()),
         step=P(),

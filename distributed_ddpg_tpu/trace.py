@@ -454,8 +454,11 @@ CHUNK_SCOPES = (
     "draw",           # the launch's replay indices (uniform or PER)
     "gather",         # the rows behind them, out of the ring
     "cut",            # the gathered rows cut into fields / kernel streams
-    "noise",          # the launch's noise; REDQ's subsets
+    "prep/pixels",    # a pixel launch's byte images, bitcast out of the cut words
+    "noise",          # the launch's noise; REDQ's subsets; DrQ-v2's crop offsets
     "update",         # the K updates: the lax.scan, or the pallas_call
+    "update/augment", # DrQ-v2's random shift: pad, crop, the conversion to float
+    "update/encoder", # its convolutional encoder: both forward passes and the backward
     "update/critic",  # critic loss, forward and backward
     "update/critic/norm",  # its batch norm: moments, normalising, running step
     "update/critic/lnorm",   # a residual critic's LayerNorms, forward and backward
@@ -475,6 +478,7 @@ CHUNK_SCOPES = (
 ROLLOUT_SCOPES = (
     "rollout",         # the K-step scan over E environments
     "rollout/policy",  # mu(s) and the exploration noise
+    "rollout/render",  # a pixel environment's frames out of its state
     "rollout/env",     # the vmapped environment step, auto-reset included
     "rollout/fold",    # the n-step window: fold, flush, the emitted row
 )
@@ -498,9 +502,12 @@ _INLINED = re.compile(r"(?:calls|to_apply)=%?([\w.\-]+)")
 _FUSED = re.compile(r"calls=%?([\w.\-]+)")
 # Instructions no device runs: a trace has no event for them.
 _NO_OP = frozenset({"parameter", "constant", "tuple", "get-tuple-element"})
+_TUPLE_GLUE = frozenset({"tuple", "get-tuple-element", "bitcast"})
 # What a fusion of nothing but a collective's step holds beside the step:
 # the compiler's glue between one step and the next.
 _NO_COMPUTE = _NO_OP | {"bitcast", "custom-call"}
+# What an instruction names between its opcode's parentheses: its operands.
+_OPERAND = re.compile(r"%([\w.\-]+)")
 # A `while`'s body, and what counts as arithmetic there: XLA's elementwise
 # opcodes. The TPU's compiler fuses none of them on a `[]` shape, so in a
 # loop's body each is an instruction of the loop by itself, run on the
@@ -556,7 +563,16 @@ def _instructions(hlo_text: str):
     part of `update`, also where XLA cut its path short of the loop's
     bracket (`critic/jvp()/gather` in a body reads `update/critic`). A
     fusion the compiler left without an `op_name` takes the scope most of
-    its body's instructions were traced under.
+    its body's instructions were traced under. An instruction that is still
+    under none, stands between two that have one (something upstream of it
+    was traced under a bracket, and so was what it feeds) and carries no
+    `op_name` at all, is the compiler's own step on the way from the one to
+    the other and takes the scope of what it feeds: the TPU's compiler
+    takes a `bitcast_convert_type` of a launch's byte images apart into a
+    copy, a broadcast and a reshape of the whole block in front of the
+    fusion that keeps the name (`prep/pixels`: PERF.md §5). The copies of
+    the state round the launch have a parameter before them or the result
+    behind them, and stay under no scope.
 
     A fusion is one operation and carries one `op_name`, its root's: XLA
     fuses across brackets (Adam and Polyak into the epilogue of the
@@ -581,6 +597,7 @@ def _instructions(hlo_text: str):
     found, inlined, runs, computation, entry = [], set(), {}, "", False
     loop_bodies, arithmetic = {}, {}  # while -> its body; computation -> count
     bodies, within = {}, {}  # fusion -> its body; body -> {scope: instructions}
+    operands, nameless = {}, set()  # instruction -> its operands; no op_name
     for line in hlo_text.splitlines():
         m = _COMPUTATION.match(line)
         if m is not None:
@@ -613,6 +630,9 @@ def _instructions(hlo_text: str):
         op_name = _OP_NAME.search(rest)
         scope = _scope_of(op_name.group(1) if op_name else "")
         found.append((computation, name, opcode, scope, entry))
+        operands[name] = _operands(rest)
+        if op_name is None and opcode not in _NO_OP:
+            nameless.add(name)
         if scope:
             counts = within.setdefault(computation, {})
             counts[scope] = counts.get(scope, 0) + 1
@@ -631,8 +651,35 @@ def _instructions(hlo_text: str):
             return scope or outer
         return f"{top}/{scope}"  # a path XLA cut short: `critic/jvp()/gather`
 
+    scopes = {name: scope_of(name) for name in own}
+    opcodes = {name: opcode for _, name, opcode, _, _ in found}
+    users = {}
+    for name, names in operands.items():
+        for operand in names:
+            users.setdefault(operand, []).append(name)
+
+    def reach(name, step, seen):
+        """The scope of the first scoped instruction from `name` along
+        `step` (operands or users), through nameless instructions and, up
+        the operands, through the tuples' glue."""
+        for other in step.get(name, ()):
+            if other in seen or other not in own:
+                continue
+            seen.add(other)
+            through = other in nameless or (
+                step is operands and opcodes[other] in _TUPLE_GLUE
+            )
+            scope = scopes[other] or (through and reach(other, step, seen))
+            if scope:
+                return scope
+        return ""
+
+    scopes.update({
+        name: reach(name, users, {name}) for name in nameless
+        if not scopes[name] and reach(name, operands, {name})
+    })
     instructions = [
-        (name, opcode, scope_of(name), entry)
+        (name, opcode, scopes[name], entry)
         for comp, name, opcode, _, entry in found
         if comp not in inlined and opcode not in _NO_OP
     ]
@@ -653,6 +700,16 @@ def _instructions(hlo_text: str):
         loop: arithmetic.get(body, 0) for loop, body in loop_bodies.items()
     }
     return instructions, fused, carriers, scalars
+
+
+def _operands(rest: str):
+    start = rest.find("(")
+    depth = 0
+    for i in range(start, len(rest)):
+        depth += (rest[i] == "(") - (rest[i] == ")")
+        if depth == 0:
+            return _OPERAND.findall(rest[start:i])
+    return []
 
 
 def _scope_of(op_name: str) -> str:

@@ -375,7 +375,7 @@ def _train_jax_impl(
     from distributed_ddpg_tpu import checkpoint as ckpt_lib
     from distributed_ddpg_tpu.actors.policy import NumpyPolicy, actor_head_dim, flatten_params, param_layout
     from distributed_ddpg_tpu.actors.pool import ActorPool
-    from distributed_ddpg_tpu.learner import delayed_updates
+    from distributed_ddpg_tpu.learner import delayed_updates, make_act_fn
     from distributed_ddpg_tpu.parallel import multihost
     from distributed_ddpg_tpu.parallel.learner import (
         ShardedLearner,
@@ -387,7 +387,7 @@ def _train_jax_impl(
         DevicePrioritizedReplay,
         DeviceReplay,
     )
-    from distributed_ddpg_tpu.types import pack_batch_np
+    from distributed_ddpg_tpu.types import ObsSpec, pack_batch_np
 
     if config.checkpoint_dir:
         # Beside the backend's start and the first compile, not in front
@@ -538,6 +538,9 @@ def _train_jax_impl(
 
     env = make(config.env_id, seed=config.seed)
     spec = spec_of(env)
+    # The observation's shape and dtype: a flat float vector's `obs_dim`, or
+    # a pixel environment's byte frames (types.ObsSpec).
+    obs_spec = ObsSpec.of_env(spec)
     chunk = resolve_learner_chunk(config)
     min_fill = max(config.replay_min_size, config.batch_size)
     n_proc = jax.process_count()
@@ -648,7 +651,7 @@ def _train_jax_impl(
 
     learner = ShardedLearner(
         config,
-        spec.obs_dim,
+        obs_spec,
         spec.act_dim,
         spec.action_scale,
         spec.action_offset,
@@ -667,9 +670,14 @@ def _train_jax_impl(
         # and never under strict_sync — the shipper thread would make
         # row-landing timing (hence the sampled stream) a function of
         # host scheduling instead of the config.
+        # `obs_words`: the float32 words of a ring row one observation takes
+        # (its float count; a byte frame stack's bytes over four).
+        obs_words = obs_spec.words
         replay_kwargs = dict(
             mesh=learner.mesh,
-            block_size=1024,
+            # the host's staging blocks: 1,024 rows, or 16 of a pixel
+            # configuration's 127 KB rows, which no host worker fills
+            block_size=16 if config.pixels else 1024,
             async_ship=not is_multi and not config.strict_sync,
             max_coalesce=config.ingest_coalesce,
             fault=(
@@ -698,12 +706,12 @@ def _train_jax_impl(
         )
         device_replay = (
             DevicePrioritizedReplay(
-                config.replay_capacity, spec.obs_dim, spec.act_dim,
+                config.replay_capacity, obs_words, spec.act_dim,
                 alpha=config.per_alpha, eps=config.per_eps, **replay_kwargs,
             )
             if config.prioritized
             else DeviceReplay(
-                config.replay_capacity, spec.obs_dim, spec.act_dim,
+                config.replay_capacity, obs_words, spec.act_dim,
                 **replay_kwargs,
             )
         )
@@ -979,7 +987,7 @@ def _train_jax_impl(
             # fresh). Before the first swap, which primes a window that no
             # carry brought.
             device_pool.load_carry_state(ckpt_meta["devactor_carry"])
-        device_pool.set_params(learner.state.actor_params, learn_steps)
+        device_pool.set_params(learner.policy_params(), learn_steps)
         _beat()  # rollout-program construction survived
     # Whether any host worker acts: with none (a device-only run) nobody
     # reads a broadcast, and the refresh is the pool's pointer swap.
@@ -1003,6 +1011,9 @@ def _train_jax_impl(
         device_pool is not None
         and use_device_replay
         and config.fused_beat != "off"
+        # the beat composes the flat rollout and chunk bodies (config.py
+        # refuses fused_beat='on' with a pixel configuration)
+        and not config.pixels
         and (
             config.fused_beat == "on"
             or (
@@ -1225,6 +1236,15 @@ def _train_jax_impl(
                 facts[f"simba_{net}_blocks"] = len(params) - 2
                 facts[f"simba_{net}_width"] = int(params[0]["w"].shape[-1])
             facts["weight_decay"] = config.weight_decay
+        if config.pixels:
+            # Pixel runs only, from the state and the ring: the encoder's
+            # width, the trunk's, and the bytes one ring row holds.
+            critic = learner.state.critic_params
+            facts["pixels"] = True
+            facts["encoder_channels"] = int(critic["encoder"][0]["w"].shape[0])
+            facts["feature_dim"] = int(critic["trunk"]["w"].shape[1])
+            if use_device_replay:
+                facts["ring_row_bytes"] = device_replay.row_bytes_device
         if device_pool is not None and device_pool.sigma_ends is not None:
             # The Gaussian ladder's ends, as the rollout program holds them.
             facts["devactor_sigma_min"], facts["devactor_sigma_max"] = (
@@ -1289,17 +1309,37 @@ def _train_jax_impl(
             "ckpt_write_retries": saver.write_retries,
             "emergency_ckpt": emergency_ckpt[0],
         }
-    eval_policy = NumpyPolicy(
-        param_layout(
-            spec.obs_dim,
-            actor_head_dim(spec.act_dim, config.sac),
-            tuple(config.actor_hidden),
-            residual=config.simba,
-        ),
-        spec.action_scale,
-        spec.action_offset,
-        gaussian=config.sac,
+
+    # A pixel configuration's evaluator acts through the learner's own apply
+    # (encoder, trunk and head, no augmentation and no noise; the numpy
+    # policy of the host workers has no convolution), jitted once a run.
+    pixel_act = (
+        make_act_fn(config, spec.action_scale, spec.action_offset)
+        if config.pixels else None
     )
+
+    def eval_policy_of(host_params):
+        """The evaluator's policy on a host copy of the tree that acts
+        (learner.actor_params_to_host): the workers' numpy policy, or for a
+        pixel configuration the learner's own apply, one byte frame stack a
+        call."""
+        if config.pixels:
+            return lambda obs: np.asarray(
+                pixel_act(host_params, np.asarray(obs)[None])
+            )
+        policy = NumpyPolicy(
+            param_layout(
+                spec.obs_dim,
+                actor_head_dim(spec.act_dim, config.sac),
+                tuple(config.actor_hidden),
+                residual=config.simba,
+            ),
+            spec.action_scale,
+            spec.action_offset,
+            gaussian=config.sac,
+        )
+        policy.load_flat(flatten_params(host_params))
+        return policy
 
     # Periodic eval runs in a background thread on a PARAM SNAPSHOT
     # (SURVEY.md §5; VERDICT.md round-1 Weak #7: inline eval stalled the
@@ -1314,25 +1354,15 @@ def _train_jax_impl(
         if t is not None and t.is_alive():
             return
         with phases.phase("eval_snapshot"):
-            flat = flatten_params(learner.actor_params_to_host())
+            host_params = learner.actor_params_to_host()
 
         def _run():
             with trace.span("eval_rollout", step=at_step):
-                policy = NumpyPolicy(
-                    param_layout(
-                        spec.obs_dim,
-                        actor_head_dim(spec.act_dim, config.sac),
-                        tuple(config.actor_hidden),
-                        residual=config.simba,
-                    ),
-                    spec.action_scale,
-                    spec.action_offset,
-                    gaussian=config.sac,
-                )
-                policy.load_flat(flat)
                 log.log(
                     "eval", at_step,
-                    eval_return=_eval_numpy(policy, config, spec),
+                    eval_return=_eval_numpy(
+                        eval_policy_of(host_params), config, spec
+                    ),
                 )
 
         if config.strict_sync:
@@ -1631,7 +1661,7 @@ def _train_jax_impl(
         if device_pool is not None:
             # The restored state is a fresh tree; swap the rollout's live
             # param pointer so the repaired policy acts immediately.
-            device_pool.set_params(learner.state.actor_params, learn_steps)
+            device_pool.set_params(learner.policy_params(), learn_steps)
             if "devactor_carry" in ckpt_meta:
                 # Roll the rollout state back with the learner: episodes
                 # continue from the restored point, not from E resets.
@@ -1740,7 +1770,7 @@ def _train_jax_impl(
         its source attribution are exercised end to end."""
         base = ingested_rows[0]
         m = len(packed)
-        reward_col = spec.obs_dim + spec.act_dim
+        reward_col = obs_spec.words + spec.act_dim
         for at in numeric_replay_at:
             if base < at <= base + m:
                 packed[at - base - 1, reward_col] = np.inf
@@ -1972,7 +2002,7 @@ def _train_jax_impl(
                 if not host_actors else contextlib.nullcontext()
             ):
                 device_pool.set_params(
-                    learner.state.actor_params, learn_steps
+                    learner.policy_params(), learn_steps
                 )
         if guard_on and _guardrail_monitor():
             # Rolled back (or numeric-aborted): this chunk's `out` is
@@ -2730,8 +2760,9 @@ def _train_jax_impl(
         # so nothing here may wait on it.)
         final_return = None
     else:
-        eval_policy.load_flat(flatten_params(learner.actor_params_to_host()))
-        final_return = _eval_numpy(eval_policy, config, spec)
+        final_return = _eval_numpy(
+            eval_policy_of(learner.actor_params_to_host()), config, spec
+        )
         if last_out[0] is not None:
             last_metrics = learner.metrics_to_host(last_out[0])
     learn_timer.tick(launches.settle())

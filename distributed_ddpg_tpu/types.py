@@ -9,7 +9,7 @@ Polyak — jits into a single XLA program with no host round trips
 
 from __future__ import annotations
 
-from typing import Any, Dict, NamedTuple
+from typing import Any, Dict, NamedTuple, Tuple, Union
 
 import numpy as np
 
@@ -56,6 +56,55 @@ class TrainState(NamedTuple):
     alpha_opt: Any = None   # OptState over log_alpha (SAC autotune only)
 
 
+class ObsSpec(NamedTuple):
+    """An observation's shape and dtype: the one place that knows how many
+    float32 words of a ring row an observation takes and whether the nets
+    read it as a vector or as byte images. A flat observation of d floats is
+    `ObsSpec((d,))`: d words, and everything derived from it is what the
+    integer `obs_dim` gave. A byte observation (`uint8[C, H, W]`, the pixel
+    configuration's) rides the float32 ring four pixels to a word, C * H * W
+    / 4 words, as bits that nothing computes on (ops/pixels.py)."""
+
+    shape: Tuple[int, ...]
+    dtype: str = "float32"
+
+    @classmethod
+    def of(cls, obs: Union[int, "ObsSpec"]) -> "ObsSpec":
+        """`obs` itself, or the flat spec an integer `obs_dim` stands for."""
+        if isinstance(obs, ObsSpec):
+            return obs
+        return cls((int(obs),))
+
+    @classmethod
+    def of_env(cls, env) -> "ObsSpec":
+        """The observation of an environment or of its spec (anything with
+        `obs_dim`, and `obs_shape` and `obs_dtype` where the observation is no
+        flat float vector: envs/jax_envs.py, envs/registry.EnvSpec)."""
+        shape = tuple(getattr(env, "obs_shape", ()) or ())
+        return cls(shape, env.obs_dtype) if shape else cls.of(env.obs_dim)
+
+    @property
+    def size(self) -> int:
+        """Elements of one observation."""
+        return int(np.prod(self.shape))
+
+    @property
+    def pixels(self) -> bool:
+        return self.dtype == "uint8"
+
+    @property
+    def words(self) -> int:
+        """float32 words of a ring row that hold one observation."""
+        if not self.pixels:
+            return self.size
+        if self.size % 4:
+            raise ValueError(
+                f"a byte observation of shape {self.shape} does not fill "
+                "whole 32-bit words of the ring's row"
+            )
+        return self.size // 4
+
+
 def batch_from_numpy(arrays: Dict[str, np.ndarray]) -> Batch:
     return Batch(
         obs=arrays["obs"],
@@ -75,8 +124,10 @@ def batch_from_numpy(arrays: Dict[str, np.ndarray]) -> Batch:
 # axis in this fixed order; `unpack_batch` slices them apart inside jit,
 # where the slices fuse into the consumers for free.
 
-def packed_width(obs_dim: int, act_dim: int) -> int:
-    return 2 * obs_dim + act_dim + 3
+def packed_width(obs_dim: Union[int, ObsSpec], act_dim: int) -> int:
+    """float32 words of one packed row; `obs_dim` an observation's float
+    count or its ObsSpec (a byte observation counts its words)."""
+    return 2 * ObsSpec.of(obs_dim).words + act_dim + 3
 
 
 def pack_batch_np(arrays: Dict[str, np.ndarray]) -> np.ndarray:

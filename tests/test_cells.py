@@ -22,7 +22,7 @@ import pytest
 from distributed_ddpg_tpu.config import DDPGConfig
 from distributed_ddpg_tpu.ops import fused_chunk
 from distributed_ddpg_tpu.replay.device import ring_layout, ring_row_bytes
-from distributed_ddpg_tpu.types import packed_width
+from distributed_ddpg_tpu.types import ObsSpec, packed_width
 
 ROOT = pathlib.Path(__file__).resolve().parent.parent
 
@@ -40,7 +40,16 @@ RING_LAYOUT = {
     "crossq-humanoid": "row_major",
     "pql-isaac-humanoid": "row_major",
     "simba-humanoid": "row_major",
+    "drqv2-humanoid": "row_major",
 }
+
+
+def _obs(env):
+    """A configuration's observation: byte frames where its file names a
+    shape and a dtype, the flat vector `obs_dim` counts otherwise."""
+    if "obs_shape" in env:
+        return ObsSpec(tuple(env["obs_shape"]), env["obs_dtype"])
+    return ObsSpec.of(env["obs_dim"])
 
 
 def _load(*parts):
@@ -79,7 +88,7 @@ def test_cell_leg_is_what_the_file_expects(cell):
     cfg = DDPGConfig.from_flags(flags)
     env = config["env"]
     picks_kernel = fused_chunk.supported(cfg) and fused_chunk.fits_vmem(
-        cfg, env["obs_dim"], env["act_dim"]
+        cfg, _obs(env).words, env["act_dim"]
     )
     assert picks_kernel == config["expects"]["fused_chunk_active"]
 
@@ -89,7 +98,7 @@ def test_cell_ring_layout_and_size(cell):
     name, config, flags = _files(cell)
     cfg = DDPGConfig.from_flags(flags)
     env = config["env"]
-    width = packed_width(env["obs_dim"], env["act_dim"])
+    width = packed_width(_obs(env), env["act_dim"])
     layout = ring_layout(width)
     assert name in RING_LAYOUT, f"enter {name}'s ring layout ({layout}) in RING_LAYOUT"
     assert layout == RING_LAYOUT[name]

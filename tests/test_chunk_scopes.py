@@ -566,3 +566,52 @@ def test_train_names_the_front_its_launches_took(tmp_path, as_on_a_tpu, one_chip
     # unpack_batch's slices into their readers and leaves nothing there)
     assert {"draw", "gather", "update"} <= set(table["ops"].values())
     assert ("cut" in table["ops"].values()) == (front == "cut")
+
+
+# What the TPU's compiler makes of a pixel launch's `bitcast_convert_type`:
+# a copy, a broadcast and a reshape of the whole block, none with an
+# `op_name`, in front of the fusion that keeps the name; beside them the
+# copies of the state round the launch, which have no scope on one side.
+GLUE_HLO = """\
+HloModule jit_sample_chunk_fn, is_scheduled=true
+
+%body.1 (w: (f32[4], u8[4,4])) -> (f32[4], u8[4,4]) {
+  %w = (f32[4]{0}, u8[4,4]{1,0}) parameter(0)
+  %gte.1 = f32[4]{0} get-tuple-element(%w), index=0
+  %copy.9 = f32[4]{0} copy(%gte.1)
+  ROOT %tuple.1 = (f32[4]{0}, u8[4,4]{1,0}) tuple(%copy.9, %w)
+}
+
+%cond.1 (w.2: (f32[4], u8[4,4])) -> pred[] {
+  %w.2 = (f32[4]{0}, u8[4,4]{1,0}) parameter(0)
+  ROOT %compare.1 = pred[] compare(%w.2, %w.2), direction=LT, metadata={op_name="jit(sample_chunk_fn)/update/while/cond/lt"}
+}
+
+ENTRY %main.1 (state: f32[4], ring: f32[8,4]) -> (f32[4]) {
+  %state = f32[4]{0} parameter(0)
+  %ring = f32[8,4]{1,0} parameter(1)
+  %fusion.1 = (f32[4,4]{1,0}, f32[4]{0}) fusion(%ring), kind=kLoop, calls=%fused_computation.1, metadata={op_name="jit(sample_chunk_fn)/cut/slice"}
+  %get-tuple-element.1 = f32[4,4]{1,0} get-tuple-element(%fusion.1), index=0, metadata={op_name="jit(sample_chunk_fn)/cut/slice"}
+  %copy.1 = f32[4,4]{0,1} copy(%get-tuple-element.1)
+  %bitcast-convert.1 = u32[4,4]{0,1} bitcast-convert(%copy.1)
+  %broadcast.1 = u32[4,4,4]{1,0,2} broadcast(%bitcast-convert.1), dimensions={0,1}
+  %reshape.1 = u32[4,16]{1,0} reshape(%broadcast.1)
+  %and_convert_fusion.1 = u8[4,16]{1,0} fusion(%reshape.1), kind=kLoop, calls=%fused_computation.2, metadata={op_name="jit(sample_chunk_fn)/prep/pixels/bitcast_convert_type"}
+  %copy.2 = f32[4]{0} copy(%state)
+  %tuple.2 = (f32[4]{0}, u8[4,4]{1,0}) tuple(%copy.2, %and_convert_fusion.1)
+  %while.1 = (f32[4]{0}, u8[4,4]{1,0}) while(%tuple.2), condition=%cond.1, body=%body.1, metadata={op_name="jit(sample_chunk_fn)/update/while"}
+  %get-tuple-element.2 = f32[4]{0} get-tuple-element(%while.1), index=0
+  %copy.3 = f32[4]{0} copy(%get-tuple-element.2)
+  ROOT %tuple.3 = (f32[4]{0}) tuple(%copy.3)
+}
+"""
+
+
+def test_the_compilers_nameless_steps_between_two_scopes_read_as_what_they_feed():
+    assert trace.op_scopes(GLUE_HLO) == {
+        "fusion.1": "cut",
+        "copy.1": "prep/pixels", "bitcast-convert.1": "prep/pixels",
+        "broadcast.1": "prep/pixels", "reshape.1": "prep/pixels",
+        "and_convert_fusion.1": "prep/pixels",
+        "while.1": "update", "copy.9": "update", "compare.1": "update",
+    }  # copy.2 has a parameter before it, copy.3 the result behind it: the state's copies stay unscoped

@@ -1,0 +1,377 @@
+"""What the pixel configuration (DrQ-v2, config.pixels) added outside its
+update, at small sizes on the CPU: the observation's spec and what every flat
+configuration still derives from it; bytes through the float32 ring, every
+value in every position of a word, NaN words included, through insert, wrap,
+gather, cut and a save and restore; the crop against the source's
+`grid_sample` form; the stand-in environment's frame stack across a reset;
+the 3-step fold on rows of this width against the host accumulator; the
+partition rules of the new trees; each refusal's message."""
+
+import json
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from distributed_ddpg_tpu.actors.device_pool import DeviceActorPool
+from distributed_ddpg_tpu.actors.worker import _flush_truncated
+from distributed_ddpg_tpu.config import DDPGConfig
+from distributed_ddpg_tpu.envs import jax_envs
+from distributed_ddpg_tpu.envs.jax_envs import PIXEL_STAND_IN_ID, PixelHumanoidStandIn
+from distributed_ddpg_tpu.envs.registry import make, spec_of
+from distributed_ddpg_tpu.learner import init_train_state
+from distributed_ddpg_tpu.models import pixels as pixnet
+from distributed_ddpg_tpu.ops import pixels as pix
+from distributed_ddpg_tpu.parallel import mesh as mesh_lib
+from distributed_ddpg_tpu.parallel.partition import state_pspec
+from distributed_ddpg_tpu.replay.device import DeviceReplay, ring_layout, ring_row_bytes
+from distributed_ddpg_tpu.replay.nstep import NStepAccumulator
+from distributed_ddpg_tpu.types import ObsSpec, packed_width
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+SIDE, ACT = 28, PixelHumanoidStandIn.act_dim
+OBS = ObsSpec((9, SIDE, SIDE), "uint8")
+
+
+class Small(PixelHumanoidStandIn):
+    SIDE, obs_shape, obs_dim = SIDE, OBS.shape, OBS.size
+
+
+class Truncating(Small):
+    max_episode_steps, BOX = 4, 100.0
+
+
+class Brief(Small):
+    max_episode_steps, BOX = 2, 0.19  # some episodes end by termination
+
+
+def cfg(**kw):
+    base = dict(
+        backend="jax_tpu", env_id=PIXEL_STAND_IN_ID, pixels=True, twin_critic=True, action_insert_layer=0,
+        actor_backend="device", num_actors=0, device_actor_envs=4, device_actor_chunk=2, n_step=3,
+        actor_hidden=(32, 32), critic_hidden=(32, 32), encoder_channels=8, feature_dim=16, batch_size=8,
+        replay_capacity=256, tau=0.01, target_noise_clip=0.3, explore_sigma_schedule="1.0,0.1,2000", seed=5,
+    )
+    base.update(kw)
+    return DDPGConfig(**base)
+
+
+def one_device_mesh():
+    return mesh_lib.make_mesh(data_axis=1, model_axis=1, devices=jax.devices()[:1])
+
+
+# --- the observation's spec: what a flat configuration derives is what it did ---
+
+
+def cells():
+    bench = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
+    return [c["name"] for c in bench["configs"]]
+
+
+@pytest.mark.parametrize("name", cells())
+def test_every_configurations_widths_and_row_bytes(name):
+    """The nine flat configurations: the ring's width, layout and row bytes,
+    and the policy's input width, through ObsSpec are what `obs_dim` gave.
+    The pixel one: 15,876 words an image, 31,776 a row, 127,104 B of it
+    pixels and fields, 127,488 B on the device."""
+    env = json.load(open(os.path.join(ROOT, "benchmarks", "configs", name + ".json")))["env"]
+    if "obs_shape" in env:
+        obs = ObsSpec(tuple(env["obs_shape"]), env["obs_dtype"])
+        assert obs.pixels and obs.size == env["obs_dim"] == 63504 and obs.words == 15876
+        width = packed_width(obs, env["act_dim"])
+        assert (width, 4 * width) == (31776, 127104)
+        assert ring_layout(width) == "row_major" and ring_row_bytes(width, "row_major") == 127488
+        return
+    d, a = env["obs_dim"], env["act_dim"]
+    obs = ObsSpec.of(d)
+    assert obs == ObsSpec((d,), "float32") and not obs.pixels and obs.words == obs.size == d
+    assert packed_width(obs, a) == packed_width(d, a) == 2 * d + a + 3
+    assert ObsSpec.of(obs) is obs
+
+
+def test_env_spec_knows_both_kinds():
+    flat = spec_of(make("Pendulum-v1", prefer_builtin=True))
+    assert ObsSpec.of_env(flat) == ObsSpec((3,)) and ObsSpec.of_env(flat).words == flat.obs_dim == 3
+    frames = spec_of(make(PIXEL_STAND_IN_ID))
+    assert ObsSpec.of_env(frames) == ObsSpec((9, 84, 84), "uint8") and frames.obs_dim == 63504
+    assert ObsSpec.of_env(PixelHumanoidStandIn()) == ObsSpec.of_env(frames)
+    with pytest.raises(ValueError, match="whole 32-bit words"):
+        ObsSpec((3, 5, 5), "uint8").words
+
+
+# --- bytes through the float32 ring ---
+
+TINY = ObsSpec((1, 16, 16), "uint8")  # 64 words an image
+NASTY = np.array([0x7FC00000, 0x7F800001, 0xFFFFFFFF, 0x00000001, 0x80000000, 0xFF800000], np.uint32)
+
+
+def byte_rows(n, act=3):
+    """n >= 8 rows whose images hold every byte value in every position of
+    a word, the first words of each the NaNs, the subnormal and the
+    infinities of NASTY; the float fields ordinary floats."""
+    w = TINY.words
+    rows = np.zeros((n, packed_width(TINY, act)), np.float32)
+    rng = np.random.default_rng(n)
+    for which, at in ((0, 0), (1, w + act + 2)):
+        img = np.zeros((n, TINY.size), np.uint8)
+        j = np.arange(TINY.size)
+        for r in range(n):
+            img[r] = (r * 32 + j // 4 + 37 * (j % 4) + 11 * which) % 256
+        words = img.view(np.uint32)
+        words[:, : len(NASTY)] = np.roll(NASTY, which)[None]
+        rows[:, at : at + w] = words.view(np.float32)
+    rows[:, w : w + act + 2] = rng.normal(size=(n, act + 2)).astype(np.float32)
+    rows[:, -1] = 1.0
+    return rows
+
+
+def test_byte_rows_hold_every_value_in_every_position():
+    img = byte_rows(8).view(np.uint8)[:, 4 * len(NASTY) : 4 * TINY.words].reshape(8, -1, 4)
+    for p in range(4):
+        assert len(np.unique(img[:, :, p])) == 256
+    assert np.isnan(byte_rows(8)[:, 0]).all()  # a word of pixels may spell a NaN
+
+
+def bits(x):
+    return np.ascontiguousarray(np.asarray(x)).view(np.uint32)
+
+
+@pytest.mark.parametrize("capacity", [20, 24])  # 3 x 8 rows: a wrap inside a block, and an exact fit
+def test_bytes_come_back_bit_for_bit_through_insert_wrap_gather_and_cut(capacity):
+    act, mesh = 3, one_device_mesh()
+    ring = DeviceReplay(capacity, TINY.words, act, mesh=mesh, block_size=8, async_ship=False)
+    assert ring.ring_layout != "packed" and ring.width == packed_width(TINY, act)
+    blocks = [byte_rows(8) for _ in range(3)]
+    for k, b in enumerate(blocks):
+        b[:, TINY.words] = k  # the action's first column tells the blocks apart
+        ring.insert_device_rows(jnp.asarray(b))
+    host = np.zeros((capacity, ring.width), np.float32)
+    for k, b in enumerate(blocks):  # what a ring of `capacity` rows holds after them
+        host.view(np.uint32)[(np.arange(8) + 8 * k) % capacity] = bits(b)
+    storage, size = ring.device_state()
+    assert int(size) == min(capacity, 24)
+    np.testing.assert_array_equal(bits(storage), bits(host))
+    idx = jnp.arange(capacity).reshape(2, -1)
+    batch = jax.jit(lambda s: pix.cut_pixels(s[idx], TINY, act))(storage)
+    w = TINY.words
+    assert batch.obs.dtype == jnp.uint8 and batch.obs.shape == (2, capacity // 2, *TINY.shape)
+    np.testing.assert_array_equal(np.asarray(batch.obs).reshape(capacity, -1), host[:, :w].copy().view(np.uint8))
+    np.testing.assert_array_equal(
+        np.asarray(batch.next_obs).reshape(capacity, -1), host[:, w + act + 2 : 2 * w + act + 2].copy().view(np.uint8))
+    np.testing.assert_array_equal(np.asarray(batch.action).reshape(capacity, -1), host[:, w : w + act])
+    # and back into words, as the rollout program packs them
+    again = jax.jit(pix.words_of)(batch.obs)
+    np.testing.assert_array_equal(bits(again).reshape(capacity, -1), bits(host[:, :w]))
+    # a checkpoint of the ring: saved and restored to the bit
+    saved = ring.state_dict()
+    fresh = DeviceReplay(capacity, TINY.words, act, mesh=mesh, block_size=8, async_ship=False)
+    fresh.load_state_dict(saved)
+    np.testing.assert_array_equal(bits(fresh.device_state()[0]), bits(host))
+    assert int(fresh.ptr) == int(ring.ptr) and len(fresh) == len(ring)
+
+
+# --- the crop is the source's grid_sample at integer shifts ---
+
+
+def grid_sample(image, grid):
+    """torch.nn.functional.grid_sample on one [C, H, W] image: bilinear,
+    padding_mode zeros, align_corners False; grid [H', W', 2] of (x, y)."""
+    c, h, w = image.shape
+    x = ((grid[..., 0] + 1.0) * w - 1.0) / 2.0
+    y = ((grid[..., 1] + 1.0) * h - 1.0) / 2.0
+    x0, y0 = np.floor(x).astype(int), np.floor(y).astype(int)
+    out = np.zeros((c, *x.shape))
+    for dy in (0, 1):
+        for dx in (0, 1):
+            xi, yi = x0 + dx, y0 + dy
+            wgt = (1 - np.abs(x - xi)) * (1 - np.abs(y - yi))
+            inside = (xi >= 0) & (xi < w) & (yi >= 0) & (yi < h)
+            out += np.where(inside, wgt, 0.0) * image[:, np.clip(yi, 0, h - 1), np.clip(xi, 0, w - 1)]
+    return out
+
+
+def source_shift(image, dy, dx, pad):
+    """drqv2.py RandomShiftsAug for one image and one drawn shift."""
+    c, h, w = image.shape
+    padded = np.pad(image.astype(np.float64), ((0, 0), (pad, pad), (pad, pad)), mode="edge")
+    eps = 1.0 / (h + 2 * pad)
+    arange = np.linspace(-1.0 + eps, 1.0 - eps, h + 2 * pad)[:h]
+    base = np.stack(np.meshgrid(arange, arange), axis=-1)  # [..., 0] runs along the width
+    shift = np.array([dx, dy]) * 2.0 / (h + 2 * pad)
+    return grid_sample(padded, base + shift)
+
+
+@pytest.mark.parametrize("dy,dx", [(0, 0), (4, 4), (8, 8), (0, 8), (7, 2), (3, 5)])
+def test_the_crop_is_the_sources_grid_sample_at_integer_shifts(dy, dx):
+    pad, side = 4, 12
+    image = np.random.default_rng(dy * 9 + dx).integers(0, 256, (3, side, side), dtype=np.uint8)
+    want = source_shift(image, dy, dx, pad)
+    rows = np.clip(np.arange(side) + dy - pad, 0, side - 1)
+    cols = np.clip(np.arange(side) + dx - pad, 0, side - 1)
+    np.testing.assert_allclose(want, image[:, rows][:, :, cols], atol=1e-9)  # a crop, to rounding
+    got = pix.random_shift(jnp.asarray(image)[None], jnp.asarray([[dy, dx]]), pad)[0]
+    np.testing.assert_allclose((np.asarray(got) + 0.5) * 255.0, want, atol=1e-4)
+    assert got.dtype == jnp.float32 and float(jnp.max(jnp.abs(got))) <= 0.5
+
+
+def test_no_padding_is_no_shift_and_the_schedule_is_the_sources_linear():
+    image = jnp.arange(2 * 3 * 4 * 4, dtype=jnp.uint8).reshape(2, 3, 4, 4)
+    np.testing.assert_array_equal(
+        pix.random_shift(image, jnp.zeros((2, 2), jnp.int32), 0), image.astype(jnp.float32) / 255.0 - 0.5)
+    # an update stands for two agent steps of the environment's action repeat
+    assert pix.FRAMES_PER_UPDATE == 2 * PixelHumanoidStandIn.ACTION_REPEAT == 4
+    for frames, want in ((0, 1.0), (1_000_000, 0.55), (2_000_000, 0.1), (5_000_000, 0.1)):
+        assert float(pix.sigma_at((1.0, 0.1, 2_000_000.0), frames // 4)) == pytest.approx(want)
+
+
+# --- the stand-in environment and the device pool on rows of this width ---
+
+
+def test_frame_stack_repeats_the_first_frame_and_shifts_by_one_a_step():
+    env = Truncating()
+    s = env.init(jax.random.PRNGKey(0))
+    assert s.frames.shape == OBS.shape and s.frames.dtype == jnp.uint8
+    np.testing.assert_array_equal(s.frames[:3], s.frames[3:6])
+    np.testing.assert_array_equal(s.frames[:3], s.frames[6:])
+    step, key = jax.jit(env.step), jax.random.PRNGKey(1)
+    seen = [np.asarray(s.frames)]
+    for t in range(1, 5):
+        key, k = jax.random.split(key)
+        out = step(s, jnp.full((ACT,), 0.3), k)
+        boot = np.asarray(out.boot_obs)
+        np.testing.assert_array_equal(boot[:6], seen[-1][3:])  # the stack moved up by one frame
+        assert not np.array_equal(boot[6:], boot[3:6])  # and the new frame differs from the last
+        assert float(out.reward) > 1.0  # two sub-steps' alive bonuses: action repeat 2
+        if t < 4:
+            assert not bool(out.done)
+            np.testing.assert_array_equal(out.obs, boot)
+        else:  # the time limit: truncated, and the next observation is a fresh episode's
+            assert bool(out.done) and not bool(out.terminated) and int(out.state.t) == 0
+            fresh = np.asarray(out.obs)
+            np.testing.assert_array_equal(fresh[:3], fresh[3:6])
+            np.testing.assert_array_equal(fresh[:3], fresh[6:])
+            assert not np.array_equal(fresh, boot)
+        s = out.state
+        seen.append(boot)
+    a, b = (np.asarray(env.init(jax.random.PRNGKey(k)).frames) for k in (2, 3))
+    assert np.mean(a != b) > 0.5  # environments differ
+
+
+def pool_and_ring(config, mesh):
+    pool = DeviceActorPool(config, mesh=mesh)
+    s = init_train_state(config, OBS, ACT, config.seed)
+    pool.set_params(pixnet.policy_params(s.critic_params, s.actor_params), 0)
+    ring = DeviceReplay(config.replay_capacity, OBS.words, ACT, mesh=mesh, block_size=16, async_ship=False)
+    return pool, ring
+
+
+@pytest.mark.parametrize("env_cls", [Truncating, Brief])
+def test_device_fold_on_pixel_rows_is_the_host_accumulators(monkeypatch, env_cls):
+    """tests/test_device_nstep.py's comparison on rows of byte frames: two
+    pools on one seed (one trajectory), the 1-step pool's rows pushed through
+    replay/nstep.py as a worker would, against what the 3-step pool landed;
+    observations compared as the bits they are."""
+    monkeypatch.setitem(jax_envs._JAX_ENVS, PIXEL_STAND_IN_ID, env_cls)
+    mesh, chunks, n, e_n, k_n, w = one_device_mesh(), 3, 3, 4, 2, OBS.words
+    one, ring1 = pool_and_ring(cfg(n_step=1), mesh)
+    three, ring3 = pool_and_ring(cfg(n_step=n), mesh)
+    assert three.pending_rows == (n - 1) * e_n and ring3.width == packed_width(OBS, ACT)
+    for _ in range(chunks + 1):
+        one.run_chunk(ring1)
+    for _ in range(chunks):
+        three.run_chunk(ring3)
+    steps = n - 1 + chunks * k_n
+    flat = np.asarray(ring1.storage)[: steps * e_n].reshape(steps, e_n, -1)
+    got = np.asarray(ring3.storage)[: chunks * k_n * e_n].reshape(chunks * k_n, e_n, -1)
+    ends = 0
+    for e in range(e_n):
+        acc, want = NStepAccumulator(n, three.config.gamma), []
+        for t in range(steps):
+            row = flat[t, e]
+            obs, action, reward = row[:w], row[w : w + ACT], row[w + ACT]
+            boot = row[w + ACT + 2 : 2 * w + ACT + 2]
+            terminated = row[w + ACT + 1] == 0.0
+            truncated = not terminated and t + 1 < steps and not np.array_equal(bits(flat[t + 1, e, :w]), bits(boot))
+            want += list(acc.push(obs[None], action[None], [reward], [terminated], boot[None]))
+            if truncated:
+                want += _flush_truncated(acc, boot)
+            if terminated or truncated:
+                acc.reset()
+                ends += 1
+        emitted = got[:, e]
+        for t, (o, a, r, d, nobs) in enumerate(want[: len(emitted)]):
+            np.testing.assert_array_equal(bits(emitted[t, :w]), bits(o))
+            np.testing.assert_array_equal(emitted[t, w : w + ACT], a)
+            np.testing.assert_allclose(emitted[t, w + ACT], r, rtol=1e-6)
+            np.testing.assert_allclose(emitted[t, w + ACT + 1], d, rtol=1e-6)
+            np.testing.assert_array_equal(bits(emitted[t, w + ACT + 2 : 2 * w + ACT + 2]), bits(nobs))
+    assert ends >= 2  # episodes did end inside the rows compared
+    # a frame stack as the policy sees it is the one the row holds
+    first = pix.images_of(jnp.asarray(got[0, :, :w]), OBS)
+    np.testing.assert_array_equal(first[:, :3], first[:, 3:6])  # step 0 of every episode: the first frame thrice
+
+
+def test_rollout_noise_reads_the_learner_step_it_was_handed(monkeypatch):
+    monkeypatch.setitem(jax_envs._JAX_ENVS, PIXEL_STAND_IN_ID, Small)
+    pool, _ = pool_and_ring(cfg(n_step=1), one_device_mesh())
+    assert int(pool._params["learner_step"]) == 0 and "encoder" in pool._params
+    pool.set_params({k: v for k, v in pool._params.items() if k != "learner_step"}, 123)
+    assert int(pool._params["learner_step"]) == 123 and pool.obs == OBS and pool.obs_dim == OBS.words
+
+
+# --- the trees' partition rules ---
+
+
+def test_state_pspec_places_every_leaf_of_the_pixel_state():
+    s = init_train_state(cfg(), OBS, ACT, 0)
+    assert s.target_actor_params is None and set(s.target_critic_params) == {"trunk", "heads"}
+    assert s.critic_params["heads"][0]["w"].shape == (2, 16 + ACT, 32)
+    assert s.critic_params["trunk"]["w"].shape == (8 * pixnet.feature_side(SIDE) ** 2, 16)
+    for model in (1, 2):
+        mesh = mesh_lib.make_mesh(data_axis=2, model_axis=model, devices=jax.devices()[: 2 * model])
+        spec = state_pspec(s, mesh)
+        is_spec = lambda x: isinstance(x, jax.sharding.PartitionSpec)
+        assert jax.tree.structure(spec, is_leaf=is_spec) == jax.tree.structure(jax.tree.map(lambda _: 0, s))
+        enc = spec.critic_params["encoder"][0]["w"]
+        assert all(ax is None for ax in enc) and all(ax is None for ax in spec.actor_params["trunk"]["w"])
+    # hidden layers shard as every dense chain's do; the pair's leading axis replicates
+    assert tuple(spec.critic_params["heads"][0]["w"]) == (None, None, "model")
+    assert tuple(spec.actor_params["mlp"][1]["w"]) == ("model", None)
+    assert spec.target_critic_params["heads"] == spec.critic_params["heads"]
+
+
+# --- what is refused, and why ---
+
+REFUSED = {
+    "host workers": (dict(actor_backend="host", num_actors=2), "convolutional policy for\\s+host workers"),
+    "prioritised replay": (dict(prioritized=True), "--prioritized.*R1"),
+    "row-sharded replay": (dict(replay_sharding="sharded"), "--replay_sharding=sharded.*R7"),
+    "host replay": (dict(host_replay=True), "--host_replay"),
+    "the fused beat": (dict(fused_beat="on"), "--fused_beat=on.*D1"),
+    "the superstep": (dict(superstep_beats=2), "superstep_beats"),
+    "guardrails": (dict(guardrails=True), "--guardrails.*NaN"),
+    "the megakernel": (dict(fused_chunk="on"), "--fused_chunk=on"),
+    "bfloat16 compute": (dict(compute_dtype="bfloat16"), "compute_dtype='float32'"),
+    "a delayed actor": (dict(policy_delay=2), "policy_delay at 1"),
+    "a single critic": (dict(twin_critic=False), "twin_critic=True"),
+    "the action at layer 1": (dict(action_insert_layer=1), "action_insert_layer=0"),
+    "a schedule that is none": (dict(explore_sigma_schedule="0.3"), "initial,final,frames"),
+    "a flat environment": (dict(env_id="IsaacHumanoidStandIn-v0"), "byte frames"),
+}
+
+
+@pytest.mark.parametrize("what", sorted(REFUSED))
+def test_each_refusal_names_the_flag_and_the_reason(what):
+    changed, message = REFUSED[what]
+    with pytest.raises(ValueError, match=f"(?s){message}"):
+        cfg(**changed)
+
+
+def test_byte_frames_without_the_pixel_learner_are_refused_too():
+    with pytest.raises(ValueError, match="byte frames"):
+        DDPGConfig(env_id=PIXEL_STAND_IN_ID, actor_backend="device", num_actors=0, twin_critic=True)
+    from distributed_ddpg_tpu.ops import fused_chunk
+
+    assert cfg().pixels and not fused_chunk.supported(cfg())

@@ -74,12 +74,19 @@ def _owned_by(module):
 # table has 34.5 (the change's seventeen 47.3 to 49.4 alone, 43.7 beside
 # five workers): 1.3 times the most, as the others; the device pool's 6
 # -> 12 for the same reason (the parent's three read 9.3 alone that day).
+# PR 47: 197 s. The device pool's fourth program (the pixel rollout: a
+# convolutional policy, a renderer and a 3-step window on 127 KB rows,
+# traced under three seeds) 12 -> 23 (17.2 beside three workers); the
+# learner's eighteenth (the pixel chunk) 64 -> 75 (44.6 beside three).
+# The four budgets of programs PR 47 left alone stand as they were, though
+# that day's sandbox read some of them at their edge on parent and change
+# alike (CHANGES.md, PR 47).
 _CPU_BUDGET_S = {
-    "distributed_ddpg_tpu.parallel.learner": 64.0,     # 49.4; 43.7
+    "distributed_ddpg_tpu.parallel.learner": 75.0,     # 49.4; 43.7
     "distributed_ddpg_tpu.parallel.megastep": 36.0,    # 23.0; 27.4
     "distributed_ddpg_tpu.parallel.superstep": 55.0,   # 30.3; 42.0
     "distributed_ddpg_tpu.replay.device": 5.0,         # 3.1; 3.7
-    "distributed_ddpg_tpu.actors.device_pool": 12.0,   # 9.3; 4.5
+    "distributed_ddpg_tpu.actors.device_pool": 23.0,   # 9.3; 4.5; 17.2
     "distributed_ddpg_tpu.serve.server": 3.0,          # 1.7; 2.4
 }
 
@@ -105,7 +112,7 @@ def test_every_program_has_a_golden_and_no_golden_outlives_its_program():
     names = {s.name for s in prog_lib.default_specs()}
     assert names == {p.stem for p in GOLDEN.glob("*.json")}
     assert set(_CPU_BUDGET_S) == set(prog_lib.SPEC_MODULES)
-    assert sum(_CPU_BUDGET_S.values()) == 175.0
+    assert sum(_CPU_BUDGET_S.values()) == 197.0
     assert len(names) >= 18
 
 
