@@ -28,6 +28,7 @@ from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from distributed_ddpg_tpu.config import DDPGConfig
 from distributed_ddpg_tpu.ops import losses
@@ -405,6 +406,8 @@ def make_learner_step(
     action_scale,
     axis_name: Optional[str] = None,
     action_offset=0.0,
+    obs: Optional[ObsSpec] = None,
+    mesh=None,
 ):
     """Returns the pure (state, batch, noise=None) -> StepOutput function.
     Not jitted here: callers wrap it in jit-with-shardings, shard_map, or call
@@ -412,7 +415,14 @@ def make_learner_step(
     placement). `noise` is the step's own slice of chunk_noise (SAC: the pair
     (eps_next, eps_cur); TD3: the scaled and clipped smoothing noise), which
     every chunk program draws once before its scan; a single step passes
-    none and draws the same bits itself."""
+    none and draws the same bits itself. A pixel configuration's step needs
+    `obs`, the images' types.ObsSpec (its batches hold words, which carry no
+    shape), and under a jit over a `mesh` of several devices that mesh."""
+    if config.pixels and obs is None:
+        raise ValueError(
+            "a pixel configuration's step takes its images as ring words, "
+            "which carry no shape: pass obs=ObsSpec((C, H, W), 'uint8')"
+        )
     ail = config.action_insert_layer
     scale = jnp.asarray(action_scale, jnp.float32)
     offset = jnp.asarray(action_offset, jnp.float32)
@@ -666,10 +676,28 @@ def make_learner_step(
     if config.sac:
         return sac_step
 
+    def shift(words, offsets):
+        return pix.random_shift(words, offsets, config.aug_pad, obs)
+
+    if config.pixels and mesh is not None and mesh.size > 1:
+        # The crop is a Pallas kernel, which no partitioner splits: under a
+        # jit over a mesh each data shard runs it on its own rows.
+        shift = jax.shard_map(
+            shift, mesh=mesh, in_specs=(P(None, "data"), P("data")),
+            out_specs=P("data"), check_vma=False,
+        )
+
     def pixel_step(state: TrainState, batch: Batch, noise=None) -> StepOutput:
         """DrQ-v2 (config.pixels; models/pixels.py, ops/pixels.py): the
         deterministic twin-critic update on augmented byte images. `batch.obs`
-        and `batch.next_obs` are uint8[B, C, H, W]. One pass of the ONLINE
+        and `batch.next_obs` are the ring's WORDS, batch-minor as
+        ops/pixels.cut_pixels lays a launch, f32[obs.words, B], four pixels
+        each and nothing a float operation may read: the bytes come
+        out here, on this update's own rows, inside the random shift
+        (ops/pixels.random_shift: a reinterpretation at the word's own width
+        and integer shifts; a bitcast to bytes the TPU's compiler expands 32
+        bits a pixel, ops/pixels.py), which hands the encoder its float32
+        input f32[B, C, H, W]. One pass of the ONLINE
         encoder on each (the second without gradient), clipped double Q on
         the TARGET trunk and heads with the online actor's noisy action, the
         critic's loss (the SUM of the two heads' weighted squared errors, as
@@ -683,15 +711,13 @@ def make_learner_step(
         )
         critic, lo, hi = state.critic_params, offset - scale, offset + scale
         with device_scope("augment"):
-            obs = pix.random_shift(batch.obs, offsets[:, :2], config.aug_pad)
-            next_obs = pix.random_shift(
-                batch.next_obs, offsets[:, 2:], config.aug_pad
-            )
+            obs_in = shift(batch.obs, offsets[:, :2])
+            next_obs_in = shift(batch.next_obs, offsets[:, 2:])
         with device_scope("encoder"):
             feat, encoder_vjp = jax.vjp(
-                lambda enc: pixnet.encoder_apply(enc, obs), critic["encoder"]
+                lambda enc: pixnet.encoder_apply(enc, obs_in), critic["encoder"]
             )
-            feat_next = pixnet.encoder_apply(critic["encoder"], next_obs)
+            feat_next = pixnet.encoder_apply(critic["encoder"], next_obs_in)
 
         def critic_loss_fn(heads, feat):
             next_action = pix.clipped_action(

@@ -347,10 +347,14 @@ class ShardedLearner:
         keys = metric_keys(config)
 
         if mode == "auto":
-            step = make_learner_step(config, action_scale, action_offset=action_offset)
+            step = make_learner_step(
+                config, action_scale, action_offset=action_offset,
+                obs=self.obs, mesh=self.mesh,
+            )
         else:
             inner = make_learner_step(
-                config, action_scale, axis_name="data", action_offset=action_offset
+                config, action_scale, axis_name="data",
+                action_offset=action_offset, obs=self.obs,
             )
             state_spec = mesh_lib.state_pspec(state, self.mesh)
             bspec = mesh_lib.batch_pspec()
@@ -535,8 +539,9 @@ class ShardedLearner:
 
         width = packed_width(obs_dim, act_dim)
         # A pixel launch has a cut of its own (ops/pixels.cut_pixels: the
-        # float fields as unpack_batch cuts them, the images bitcast to
-        # bytes), and its words must not pass through the kernel's rounding.
+        # fields as unpack_batch cuts them, the images still words under
+        # `prep/pixels`), and its words must not pass through the kernel's
+        # rounding.
         scan_front = "xla" if config.pixels else front_lib.front_for(
             width=width,
             batch=batch_size // n_shards,

@@ -38,7 +38,7 @@ from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 CHUNK = 800  # the launch length every kernel cell runs (learner's auto size)
 
-_BUNDLE = re.compile(r"^\s*(0x[0-9a-f]+|0)\s+(LH|LB|LE|PB|PF|CT)?:\s*>?\s*\{")
+_BUNDLE = re.compile(r"^\s*(0x[0-9a-f]+|0)\s+(LH|LB|LE|PB|PF|CT)?:\s*>*\s*\{")
 _BRANCH = re.compile(r"sbr\.rel \(!?%\w+\) target bundleno = (\d+)")
 
 
@@ -130,7 +130,25 @@ def phases(bundles: Sequence[Bundle]) -> Dict[str, object]:
         "seed": seed,
         "update": body - seed,
         "branched": [(s, e - s) for s, e in inner[1:]],
+        "loops": inner_loops(bundles),
     }
+
+
+def inner_loops(bundles: Sequence[Bundle]) -> List[int]:
+    """Bundles a trip of every loop nested in the grid loop's body (the
+    pixel crop's loop over an image's rows; the megakernel has none): a
+    backward `sbr.rel` other than the grid loop's own, the last. Its target
+    is in an earlier numbering, so a trip is counted from the nested `LB`
+    line the branch closes: the nearest one above it."""
+    back = sorted(
+        b.addr for b in bundles
+        if b.branch_to is not None and b.branch_to <= b.addr
+    )[:-1]
+    heads = [b.addr for b in bundles if b.marker == "LB"][1:]
+    return [
+        end - max(h for h in heads if h <= end) + 1
+        for end in back if any(h <= end for h in heads)
+    ]
 
 
 def _means(rows: Sequence[Sequence[int]]) -> List[float]:
@@ -172,7 +190,9 @@ def saturated(sched: Schedule, means: Sequence[float], unit: str,
 
 def load_dump(directory: str) -> Schedule:
     """The Pallas call's schedule out of a dump directory: the one program
-    whose entry bundle is a custom call."""
+    whose entry bundle is a custom call and that holds the grid's loop (the
+    top-level program of a jit that only calls the kernel names the custom
+    call too, and loops over nothing)."""
     for path in sorted(glob.glob(os.path.join(directory, "*-final_bundles.txt"))):
         if "schedule-analysis" in os.path.basename(path):
             continue
@@ -181,6 +201,8 @@ def load_dump(directory: str) -> Schedule:
             if "= custom-call(" not in head:
                 continue
             text = head + f.read()
+        if not re.search(r"^\s*\S+\s+LB:", text, re.M):
+            continue
         stem = re.sub(r"-\d+-final_bundles\.txt$", "", path)
         (util,) = glob.glob(
             glob.escape(stem) + "-*-final_hlo-static-per-bundle-utilization.txt"
@@ -195,11 +217,17 @@ def load_dump(directory: str) -> Schedule:
 
 def report(sched: Schedule, window: int, min_len: int) -> str:
     ph = phases(sched.bundles)
+    head = f"bundles in all {ph['bundles']}, the grid loop's body {ph['loop_body']}"
+    if ph["loops"]:  # the pixel crop: a grid step is a loop over rows, no seed and no update
+        head += f" with one trip of each nested loop: {ph['loops']}"
+    else:
+        head += (
+            f": the k == 0 seed {ph['seed']}, an update {ph['update']}, of it "
+            "under a branch "
+            + (", ".join(f"{n} at {s}" for s, n in ph["branched"]) or "none")
+        )
     lines = [
-        f"bundles in all {ph['bundles']}, the grid loop's body "
-        f"{ph['loop_body']}: the k == 0 seed {ph['seed']}, an update "
-        f"{ph['update']}, of it under a branch "
-        + (", ".join(f"{n} at {s}" for s, n in ph["branched"]) or "none"),
+        head,
         "capacity " + " ".join(
             f"{n}={c}" for n, c in zip(sched.names, sched.capacity)
         ),
@@ -222,7 +250,8 @@ def report(sched: Schedule, window: int, min_len: int) -> str:
 
 
 def lower_chunk(conf: dict, replicated, chunk: int = CHUNK):
-    """The megakernel chunk of a benchmark configuration (its parsed file),
+    """The megakernel chunk of a benchmark configuration (its parsed file;
+    of a pixel one, which has no megakernel, its crop kernel: lower_crop),
     lowered natively for the devices `replicated` shards over: shapes only,
     so a described topology does (tests/test_ring_layout.py compiles the
     same)."""
@@ -235,8 +264,11 @@ def lower_chunk(conf: dict, replicated, chunk: int = CHUNK):
 
     cfg = DDPGConfig.from_flags(
         [f for f in conf["flags"] if not f.startswith("--replay_capacity")]
+        + (["--actor_backend=device", "--num_actors=0"] if "obs_shape" in conf["env"] else [])
     )
     env = conf["env"]
+    if cfg.pixels:
+        return lower_crop(cfg, env, replicated)
     obs, act = env["obs_dim"], env["act_dim"]
     assert fused_chunk.supported(cfg) and fused_chunk.fits_vmem(cfg, obs, act)
     run = fused_chunk.make_fused_chunk_fn(
@@ -254,8 +286,29 @@ def lower_chunk(conf: dict, replicated, chunk: int = CHUNK):
     return jax.jit(run).lower(state, batches)
 
 
+def lower_crop(cfg, env: dict, replicated):
+    """A pixel configuration's kernel, which is no megakernel: the crop of
+    an update's images (ops/pixels.random_shift) on its batch of rows."""
+    import jax
+    import jax.numpy as jnp
+
+    from distributed_ddpg_tpu.ops import pixels
+    from distributed_ddpg_tpu.types import ObsSpec
+
+    obs = ObsSpec(tuple(env["obs_shape"]), env["obs_dtype"])
+    words = jax.ShapeDtypeStruct(
+        (obs.words, cfg.batch_size), jnp.float32, sharding=replicated
+    )
+    offsets = jax.ShapeDtypeStruct(
+        (cfg.batch_size, 2), jnp.int32, sharding=replicated
+    )
+    return jax.jit(
+        lambda w, o: pixels.random_shift(w, o, cfg.aug_pad, obs, interpret=False)
+    ).lower(words, offsets)
+
+
 def _child(config_path: str) -> None:
-    """Compile the configuration's megakernel for a described v5e. Runs in
+    """Compile the configuration's kernel for a described v5e. Runs in
     the child: the dumper aborts this process before compile() returns."""
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     import numpy as np

@@ -454,10 +454,10 @@ CHUNK_SCOPES = (
     "draw",           # the launch's replay indices (uniform or PER)
     "gather",         # the rows behind them, out of the ring
     "cut",            # the gathered rows cut into fields / kernel streams
-    "prep/pixels",    # a pixel launch's byte images, bitcast out of the cut words
+    "prep/pixels",    # a pixel launch's image words cut for the scan, and their relayout
     "noise",          # the launch's noise; REDQ's subsets; DrQ-v2's crop offsets
     "update",         # the K updates: the lax.scan, or the pallas_call
-    "update/augment", # DrQ-v2's random shift: pad, crop, the conversion to float
+    "update/augment", # DrQ-v2's random shift: unpack, crop, the conversion to float
     "update/encoder", # its convolutional encoder: both forward passes and the backward
     "update/critic",  # critic loss, forward and backward
     "update/critic/norm",  # its batch norm: moments, normalising, running step
@@ -568,9 +568,10 @@ def _instructions(hlo_text: str):
     was traced under a bracket, and so was what it feeds) and carries no
     `op_name` at all, is the compiler's own step on the way from the one to
     the other and takes the scope of what it feeds: the TPU's compiler
-    takes a `bitcast_convert_type` of a launch's byte images apart into a
-    copy, a broadcast and a reshape of the whole block in front of the
-    fusion that keeps the name (`prep/pixels`: PERF.md §5). The copies of
+    took PR 47's `bitcast_convert_type` of a launch's byte images apart into
+    a copy, a broadcast and a reshape of the whole block in front of the
+    fusion that kept the name (`prep/pixels`: PERF.md §6, PR 47; the pixel
+    step makes no such bitcast since PR 48). The copies of
     the state round the launch have a parameter before them or the result
     behind them, and stay under no scope.
 

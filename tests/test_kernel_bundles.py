@@ -63,3 +63,24 @@ def test_parsers_skip_what_is_no_bundle():
         kb.parse_utilization("nothing here\n")
     with pytest.raises(FileNotFoundError):
         kb.load_dump(os.path.dirname(FIXTURES) + "/lint_fixtures")
+
+
+def test_a_loop_nested_in_the_grid_loop_is_counted_by_its_trip(sched):
+    """The pixel crop's kernel loops over an image's rows inside a grid
+    step; the compiler marks a nested loop's lines `>>`, and its back edge
+    names an older number than its `LB` line's."""
+    text = "\n".join([
+        "     0   :  { %7 = vsyncpa [#allocation5], 0 }",
+        "   0x1 LB: > { %s1 = sadd.s32 1, %s0 }",
+        "   0x2   : > { %1 = sbr.rel (%p1) target bundleno = 9 (0x9), region = 16 }",
+        "   0x3 LB: >> { %vm1 = vcmp.eq.s32.totalorder %v1, 1 }",
+        "   0x4   : >> { %v2 = vsel %vm1, %v3, %v4 }",
+        "   0x5   : >> { %2 = vst [vmem:[%s2] ss:$4 sm:$0xff] %v2  ;;  %3 = sbr.rel (!%p2) target bundleno = 4 (0x4), region = 100 }",
+        "   0x6 PF: > { %s3 = sadd.s32 1, %s2 }",
+        "   0x7   :  { %4 = sbr.rel (!%p3) target bundleno = 1 (0x1), region = 111 }",
+    ])
+    bundles = kb.parse_bundles(text)
+    assert [b.addr for b in bundles] == list(range(8)) and [b.marker for b in bundles].count("LB") == 2
+    assert kb.inner_loops(bundles) == [3]
+    assert kb.phases(bundles)["loop_body"] == 7 and kb.phases(bundles)["loops"] == [3]
+    assert kb.phases(sched.bundles)["loops"] == []  # the megakernel has none

@@ -2,13 +2,17 @@
 update, at small sizes on the CPU: the observation's spec and what every flat
 configuration still derives from it; bytes through the float32 ring, every
 value in every position of a word, NaN words included, through insert, wrap,
-gather, cut and a save and restore; the crop against the source's
-`grid_sample` form; the stand-in environment's frame stack across a reset;
+gather, cut and a save and restore; the update's image path (words in, the
+encoder's float input out) against the index-map crop of the byte images,
+every offset, and against the source's `grid_sample` form; what the lowered
+pixel chunk holds; the stand-in environment's frame stack across a reset;
 the 3-step fold on rows of this width against the host accumulator; the
 partition rules of the new trees; each refusal's message."""
 
+import functools
 import json
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -21,7 +25,7 @@ from distributed_ddpg_tpu.config import DDPGConfig
 from distributed_ddpg_tpu.envs import jax_envs
 from distributed_ddpg_tpu.envs.jax_envs import PIXEL_STAND_IN_ID, PixelHumanoidStandIn
 from distributed_ddpg_tpu.envs.registry import make, spec_of
-from distributed_ddpg_tpu.learner import init_train_state
+from distributed_ddpg_tpu.learner import init_train_state, make_learner_step
 from distributed_ddpg_tpu.models import pixels as pixnet
 from distributed_ddpg_tpu.ops import pixels as pix
 from distributed_ddpg_tpu.parallel import mesh as mesh_lib
@@ -156,13 +160,20 @@ def test_bytes_come_back_bit_for_bit_through_insert_wrap_gather_and_cut(capacity
     idx = jnp.arange(capacity).reshape(2, -1)
     batch = jax.jit(lambda s: pix.cut_pixels(s[idx], TINY, act))(storage)
     w = TINY.words
-    assert batch.obs.dtype == jnp.uint8 and batch.obs.shape == (2, capacity // 2, *TINY.shape)
-    np.testing.assert_array_equal(np.asarray(batch.obs).reshape(capacity, -1), host[:, :w].copy().view(np.uint8))
-    np.testing.assert_array_equal(
-        np.asarray(batch.next_obs).reshape(capacity, -1), host[:, w + act + 2 : 2 * w + act + 2].copy().view(np.uint8))
+    # the launch's images stay the words the gather produced, to the bit, the batch minor
+    assert batch.obs.dtype == jnp.float32 and batch.obs.shape == (2, w, capacity // 2)
+    rows_of = lambda field: bits(field).transpose(0, 2, 1).reshape(capacity, -1)
+    np.testing.assert_array_equal(rows_of(batch.obs), bits(host[:, :w]))
+    np.testing.assert_array_equal(rows_of(batch.next_obs), bits(host[:, w + act + 2 : 2 * w + act + 2]))
     np.testing.assert_array_equal(np.asarray(batch.action).reshape(capacity, -1), host[:, w : w + act])
+    # and the update unpacks them into the bytes the rows hold (no pad: no shift)
+    unpack = jax.jit(lambda words: pix.random_shift(words, jnp.zeros((words.shape[1], 2), jnp.int32), 0, TINY))
+    for field, at in ((batch.obs, 0), (batch.next_obs, w + act + 2)):
+        images = host[:, at : at + w].copy().view(np.uint8).reshape(capacity, *TINY.shape)
+        words = jnp.concatenate(list(field), axis=-1)  # [w, capacity]: the two launches' rows side by side
+        np.testing.assert_array_equal(np.asarray(unpack(words)), float_input(images))
     # and back into words, as the rollout program packs them
-    again = jax.jit(pix.words_of)(batch.obs)
+    again = jax.jit(pix.words_of)(jnp.asarray(host[:, :w].copy().view(np.uint8).reshape(capacity, *TINY.shape)))
     np.testing.assert_array_equal(bits(again).reshape(capacity, -1), bits(host[:, :w]))
     # a checkpoint of the ring: saved and restored to the bit
     saved = ring.state_dict()
@@ -170,6 +181,125 @@ def test_bytes_come_back_bit_for_bit_through_insert_wrap_gather_and_cut(capacity
     fresh.load_state_dict(saved)
     np.testing.assert_array_equal(bits(fresh.device_state()[0]), bits(host))
     assert int(fresh.ptr) == int(ring.ptr) and len(fresh) == len(ring)
+
+
+# --- the update's image path: words in, the encoder's float input out ---
+
+
+def float_input(images):
+    """What the first convolution reads of byte images, as the program that
+    converts them computes it (models/pixels.encoder_input, jitted: XLA
+    divides by a constant as it sees fit, the same way in every program)."""
+    return np.asarray(jax.jit(pixnet.encoder_input)(jnp.asarray(images)))
+
+
+def words_np(images):
+    """uint8[B, C, H, W] -> the ring's words f32[B, words], as numpy views them."""
+    return np.ascontiguousarray(images).reshape(images.shape[0], -1).view(np.float32)
+
+
+def index_map_crop(images, offsets, pad):
+    """uint8[B, C, H, W], int[B, 2] -> the replicate-padded crop at each
+    image's own (dy, dx), as an index map on the bytes."""
+    h, w = images.shape[-2:]
+    out = np.empty_like(images)
+    for b, (dy, dx) in enumerate(np.asarray(offsets)):
+        rows = np.clip(np.arange(h) + dy - pad, 0, h - 1)
+        cols = np.clip(np.arange(w) + dx - pad, 0, w - 1)
+        out[b] = images[b][:, rows][:, :, cols]
+    return out
+
+
+PAD = 4
+SHIFTS = [(dy, dx) for dy in range(2 * PAD + 1) for dx in range(2 * PAD + 1)]
+CROP = ObsSpec((2, 16, 16), "uint8")  # 128 words an image
+
+
+def every_byte_images(n):
+    """n >= 8 images of CROP's shape that hold every byte value in every
+    position of a word (byte_rows' pattern, without its NaN words: these
+    bytes are read, and a word of them may spell anything)."""
+    j = np.arange(CROP.size)
+    img = np.stack([(r * 32 + j // 4 + 37 * (j % 4)) % 256 for r in range(n)]).astype(np.uint8)
+    for p in range(4):
+        assert len(np.unique(img.reshape(n, -1, 4)[:8, :, p])) == 256
+    return img.reshape(n, *CROP.shape)
+
+
+@pytest.fixture(scope="module")
+def shift_words():
+    """The new path, jitted once: (words f32[B, words], offsets) -> f32[B, C, H, W];
+    random_shift takes the words batch-minor, as cut_pixels lays a launch."""
+    return jax.jit(lambda words, offsets: pix.random_shift(words.T, offsets, PAD, CROP))
+
+
+@pytest.mark.parametrize("dy,dx", SHIFTS)
+def test_words_to_float_input_is_the_index_map_crop_at_every_offset(shift_words, dy, dx):
+    """Entry 0 of the batch at (dy, dx), the seven others each at another
+    offset: all eight equal the crop of their own bytes, float32 to the bit."""
+    images = every_byte_images(8)
+    at = SHIFTS.index((dy, dx))
+    offsets = np.array([SHIFTS[(at + 10 * b) % len(SHIFTS)] for b in range(8)], np.int32)
+    assert tuple(offsets[0]) == (dy, dx)
+    got = shift_words(jnp.asarray(words_np(images)), jnp.asarray(offsets))
+    assert got.dtype == jnp.float32 and got.shape == (8, *CROP.shape)
+    np.testing.assert_array_equal(np.asarray(got), float_input(index_map_crop(images, offsets, PAD)))
+
+
+def test_batch_entries_with_different_offsets_do_not_leak(shift_words):
+    """All 81 offsets in one batch, and one image at one offset among 80 of
+    another image at another: every entry is its own image at its own offset."""
+    images = every_byte_images(81)
+    offsets = np.array(SHIFTS, np.int32)
+    got = np.asarray(shift_words(jnp.asarray(words_np(images)), jnp.asarray(offsets)))
+    np.testing.assert_array_equal(got, float_input(index_map_crop(images, offsets, PAD)))
+    lone = np.repeat(images[:1], 81, axis=0)
+    lone[40] = images[7]
+    offsets = np.tile(np.array([[8, 0]], np.int32), (81, 1))
+    offsets[40] = (1, 6)
+    got = np.asarray(shift_words(jnp.asarray(words_np(lone)), jnp.asarray(offsets)))
+    np.testing.assert_array_equal(got, float_input(index_map_crop(lone, offsets, PAD)))
+    np.testing.assert_array_equal(got[0], got[80])
+
+
+@pytest.mark.parametrize("pad", [1, 3, 5, 6, 9])
+def test_other_pads_move_rows_by_their_own_count_of_words(pad):
+    """The kernel's padded row and its windows follow ceil(pad / 4): every
+    offset of each pad, 16 images a batch."""
+    images = every_byte_images(16)
+    shifts = np.array([(dy, dx) for dy in range(2 * pad + 1) for dx in range(2 * pad + 1)], np.int32)
+    run = jax.jit(lambda words, offsets: pix.random_shift(words.T, offsets, pad, CROP))
+    for start in range(0, len(shifts), 16):
+        offsets = shifts[(np.arange(16) + start) % len(shifts)]
+        got = run(jnp.asarray(words_np(images)), jnp.asarray(offsets))
+        np.testing.assert_array_equal(np.asarray(got), float_input(index_map_crop(images, offsets, pad)))
+
+
+# --- what the lowered pixel chunk holds ---
+
+
+def test_the_lowered_pixel_chunk_gathers_once_and_makes_no_bytes(monkeypatch):
+    """`learner.chunk.uniform.pixels` (analysis/programs.py's spec: the chunk
+    ShardedLearner builds, on 3 x 16 x 16 frames) lowered for the TPU from
+    here, the crop kernel native as on the chip (interpreted, as this
+    process's CPU would run it, a kernel is loops of slices): one gather,
+    the ring's rows; no dynamic_slice and no gather on anything image-sized;
+    no `bitcast_convert` to a byte type, which the TPU's compiler takes apart
+    32 bits a pixel; each image's crop one `tpu_custom_call`, a data shard each."""
+    from distributed_ddpg_tpu.analysis.programs import default_specs
+
+    monkeypatch.setattr(pix, "random_shift", functools.partial(pix.random_shift, interpret=False))
+    built = {spec.name: spec for spec in default_specs()}["learner.chunk.uniform.pixels"].build()
+    text = built.fn.trace(*built.args).lower(lowering_platforms=("tpu",)).as_text()
+    storage = built.args[2]
+    gathers = [line for line in text.splitlines() if '"stablehlo.gather"(' in line]
+    assert len(gathers) == 1 and f"(tensor<{storage.shape[0]}x{storage.shape[1]}xf32>" in gathers[0]
+    sliced = re.findall(r"stablehlo\.dynamic_slice [^\n]*: \((tensor<[^>]*>)", text)
+    assert all(re.fullmatch(r"tensor<\d+x[a-z]+\d+>", operand) for operand in sliced), sliced  # Adam's scalars
+    converts = re.findall(r"stablehlo\.bitcast_convert [^\n]*-> tensor<([^>]*)>", text)
+    assert converts and not [t for t in converts if re.search(r"x[us]?i8$", t)]
+    assert len(re.findall(r"stablehlo\.custom_call @tpu_custom_call", text)) == 2  # an update's two images
+    assert "shard_map" in text or "sdy.manual_computation" in text
 
 
 # --- the crop is the source's grid_sample at integer shifts ---
@@ -211,15 +341,15 @@ def test_the_crop_is_the_sources_grid_sample_at_integer_shifts(dy, dx):
     rows = np.clip(np.arange(side) + dy - pad, 0, side - 1)
     cols = np.clip(np.arange(side) + dx - pad, 0, side - 1)
     np.testing.assert_allclose(want, image[:, rows][:, :, cols], atol=1e-9)  # a crop, to rounding
-    got = pix.random_shift(jnp.asarray(image)[None], jnp.asarray([[dy, dx]]), pad)[0]
+    got = pix.random_shift(jnp.asarray(words_np(image[None]).T), jnp.asarray([[dy, dx]]), pad, ObsSpec(image.shape, "uint8"))[0]
     np.testing.assert_allclose((np.asarray(got) + 0.5) * 255.0, want, atol=1e-4)
     assert got.dtype == jnp.float32 and float(jnp.max(jnp.abs(got))) <= 0.5
 
 
 def test_no_padding_is_no_shift_and_the_schedule_is_the_sources_linear():
-    image = jnp.arange(2 * 3 * 4 * 4, dtype=jnp.uint8).reshape(2, 3, 4, 4)
-    np.testing.assert_array_equal(
-        pix.random_shift(image, jnp.zeros((2, 2), jnp.int32), 0), image.astype(jnp.float32) / 255.0 - 0.5)
+    image = (np.arange(2 * 3 * 4 * 4) * 5 % 256).astype(np.uint8).reshape(2, 3, 4, 4)
+    unshifted = jax.jit(lambda words, offsets: pix.random_shift(words.T, offsets, 0, ObsSpec((3, 4, 4), "uint8")))
+    np.testing.assert_array_equal(unshifted(jnp.asarray(words_np(image)), jnp.zeros((2, 2), jnp.int32)), float_input(image))
     # an update stands for two agent steps of the environment's action repeat
     assert pix.FRAMES_PER_UPDATE == 2 * PixelHumanoidStandIn.ACTION_REPEAT == 4
     for frames, want in ((0, 1.0), (1_000_000, 0.55), (2_000_000, 0.1), (5_000_000, 0.1)):
@@ -309,7 +439,7 @@ def test_device_fold_on_pixel_rows_is_the_host_accumulators(monkeypatch, env_cls
             np.testing.assert_array_equal(bits(emitted[t, w + ACT + 2 : 2 * w + ACT + 2]), bits(nobs))
     assert ends >= 2  # episodes did end inside the rows compared
     # a frame stack as the policy sees it is the one the row holds
-    first = pix.images_of(jnp.asarray(got[0, :, :w]), OBS)
+    first = np.ascontiguousarray(got[0, :, :w]).view(np.uint8).reshape(e_n, *OBS.shape)
     np.testing.assert_array_equal(first[:, :3], first[:, 3:6])  # step 0 of every episode: the first frame thrice
 
 
@@ -375,3 +505,11 @@ def test_byte_frames_without_the_pixel_learner_are_refused_too():
     from distributed_ddpg_tpu.ops import fused_chunk
 
     assert cfg().pixels and not fused_chunk.supported(cfg())
+
+
+def test_a_pixel_step_without_the_images_spec_is_refused():
+    """The step's batches hold words, which carry no shape."""
+    with pytest.raises(ValueError, match="ring words.*ObsSpec"):
+        make_learner_step(cfg(), 1.0)
+    with pytest.raises(ValueError, match="whole words"):
+        pix.random_shift(jnp.zeros((27, 8)), jnp.zeros((8, 2), jnp.int32), 4, ObsSpec((3, 6, 6), "uint8"))

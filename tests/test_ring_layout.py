@@ -4,6 +4,7 @@ and that no program that takes the ring copies it — compiled here for the
 CPU and, through the TPU compiler the sandbox carries, for a described v5e.
 What a packed ring holds and reads back: tests/test_packed_ring.py."""
 
+import functools
 import re
 
 import numpy as np
@@ -398,6 +399,25 @@ CAPACITY = 1_000_000  # rows, the papers' ring: too large for XLA to move into f
 _TPU_LIBRARY_HELD = []  # v5e_sharding appends once this process has loaded libtpu
 
 
+def _static_phases(name):
+    """tools/kernel_bundles.phases of configuration `name`'s kernel, compiled
+    for a described v5e in a child process (or a skip where that cannot be)."""
+    import importlib.util
+    import os
+    import tempfile
+
+    from distributed_ddpg_tpu.tools import kernel_bundles as kb
+
+    if importlib.util.find_spec("libtpu") is None:
+        pytest.skip("no TPU compiler here: nothing to compile for")
+    if _TPU_LIBRARY_HELD and not os.environ.get("ALLOW_MULTIPLE_LIBTPU_LOAD"):
+        pytest.skip("this process already holds the TPU library: run the file from its top")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with tempfile.TemporaryDirectory() as directory:  # ~330 MB of compiler text, gone with the case
+        kb.compile_and_dump(os.path.join(root, "benchmarks", "configs", name + ".json"), directory)
+        return kb.phases(kb.load_dump(directory).bundles)
+
+
 @pytest.mark.parametrize(
     "name,update_ceiling,delayed_ceiling",
     [
@@ -413,23 +433,21 @@ _TPU_LIBRARY_HELD = []  # v5e_sharding appends once this process has loaded libt
 def test_megakernel_static_update_stays_under_its_ceiling(name, update_ceiling, delayed_ceiling):
     """A later PR that pads a head again, or spills a loop, learns so here
     and not on the chip. Bundles are issue cycles, not time."""
-    import importlib.util
-    import os
-    import tempfile
-
-    from distributed_ddpg_tpu.tools import kernel_bundles as kb
-
-    if importlib.util.find_spec("libtpu") is None:
-        pytest.skip("no TPU compiler here: nothing to compile for")
-    if _TPU_LIBRARY_HELD and not os.environ.get("ALLOW_MULTIPLE_LIBTPU_LOAD"):
-        pytest.skip("this process already holds the TPU library: run the file from its top")
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with tempfile.TemporaryDirectory() as directory:  # ~330 MB of compiler text, gone with the case
-        kb.compile_and_dump(os.path.join(root, "benchmarks", "configs", name + ".json"), directory)
-        ph = kb.phases(kb.load_dump(directory).bundles)
+    ph = _static_phases(name)
     delayed = max((n for _, n in ph["branched"]), default=0)
     assert ph["update"] - delayed <= update_ceiling, ph
     assert delayed <= delayed_ceiling, ph
+
+
+def test_pixel_crop_row_stays_under_its_ceiling():
+    """The pixel cell's kernel (ops/pixels.py: the crop of an update's 256
+    images, a channel of 128 a grid step) is a loop over an image's 84 rows,
+    six a trip: 197 bundles a trip at PR 48 (a row: nine selects of three
+    vregs, four sublane windows, the funnel shift, four strided stores; one
+    row a trip was 73, and 1.6% of the launch slower on the chip). Bundles
+    are issue cycles, not time."""
+    loops = _static_phases("drqv2-humanoid")["loops"]
+    assert len(loops) == 1 and loops[0] <= 230, loops
 
 
 @pytest.fixture(scope="module")
@@ -882,3 +900,96 @@ def test_v5e_scan_chunk_reads_its_gathered_block_once(v5e_sharding, name, extra,
     # the launch's temporaries: the block, the kernel's outputs, the noise
     outs = rows * (2 * obs + act) * (2 if rounded else 4) + rows * 3 * 4
     assert compiled.memory_analysis().temp_size_in_bytes < rows * 4 * (-(-width // 128) * 128) + 2 * outs + 2**28
+
+
+# --- the pixel cell's image path (ops/pixels.py), compiled for the same
+# described v5e: the crop kernel at the cell's shapes, and the whole chunk
+# with the ring in front of it. ---
+
+
+def _pixel_cell():
+    import json
+    import os
+
+    from distributed_ddpg_tpu.config import DDPGConfig
+    from distributed_ddpg_tpu.types import ObsSpec
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    conf = json.load(open(os.path.join(root, "benchmarks", "configs", "drqv2-humanoid.json")))
+    cfg = DDPGConfig.from_flags(conf["flags"] + ["--actor_backend=device", "--num_actors=0"])
+    env = conf["env"]
+    return cfg, env, ObsSpec(tuple(env["obs_shape"]), env["obs_dtype"])
+
+
+def test_v5e_pixel_crop_compiles_at_the_cells_shapes(v5e_sharding):
+    """An update's 256 rows of 15,876 words (batch-minor, as
+    ops/pixels.cut_pixels lays a launch) to the encoder's f32[256, 9, 84,
+    84] with the kernel native: Mosaic takes the strided stores, the
+    unaligned sublane windows and the per-lane shifts, and the program holds
+    the kernel once, no loop and no byte-wide array beside it."""
+    from distributed_ddpg_tpu.tools import kernel_bundles as kb
+
+    cfg, env, obs = _pixel_cell()
+    compiled = kb.lower_crop(cfg, env, NamedSharding(v5e_sharding.mesh, P())).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r" custom-call\(.*custom_call_target=\"tpu_custom_call\"", text)) == 1
+    assert not re.search(r"\bwhile\(", text) and not re.search(r" = [us]8\[", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * cfg.batch_size * obs.size * 4
+
+
+def test_v5e_pixel_chunk_holds_the_scans_loop_alone_and_no_expanded_bytes(v5e_sharding, monkeypatch):
+    """`drqv2-humanoid`'s launch at its own sizes (the 65,536-row ring in
+    ring_format's layout, 32 x 256 indices, unroll 4) as ShardedLearner's
+    sample chunk builds it: gather, ops/pixels.cut_pixels, scan_chunk. With
+    the byte images cut in front of the scan and a vmapped dynamic_slice an
+    update (PR 47) the text held nine `while`s, the scan's and eight crop
+    loops of 256 trips, and `u32[32,256,15876,4]`, 32 bits a pixel of the
+    launch's block, twice (PERF.md, PR 48)."""
+    from distributed_ddpg_tpu import learner as learner_lib
+    from distributed_ddpg_tpu.ops import pixels as pix
+    from distributed_ddpg_tpu.parallel.learner import scan_chunk
+    from distributed_ddpg_tpu.types import packed_width
+
+    cfg, env, obs = _pixel_cell()
+    act, chunk, batch = env["act_dim"], cfg.learner_chunk, cfg.batch_size
+    width = packed_width(obs, act)
+    assert (chunk, batch, width, cfg.replay_capacity) == (32, 256, 31776, 65536)
+    # the process runs on the CPU, where the kernel would be interpreted: compile it
+    monkeypatch.setattr(pix, "random_shift", functools.partial(pix.random_shift, interpret=False))
+    step = learner_lib.make_learner_step(cfg, env["action_scale"], action_offset=env["action_offset"], obs=obs)
+
+    def run(s, storage, idx, nkey):
+        noise = learner_lib.chunk_noise(cfg, nkey, s.step, chunk, batch, act)
+        return scan_chunk(step, s, pix.cut_pixels(storage[idx], obs, act), noise, unroll=4)
+
+    replicated = NamedSharding(v5e_sharding.mesh, P())
+    state = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=replicated),
+        jax.eval_shape(lambda: learner_lib.init_train_state(cfg, obs, act, 0)),
+    )
+    ring = jax.ShapeDtypeStruct((cfg.replay_capacity, width), jnp.float32, sharding=ring_format(v5e_sharding, width))
+    idx = jax.ShapeDtypeStruct((chunk, batch), jnp.int32, sharding=replicated)
+    nkey = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=replicated)
+    compiled = jax.jit(run, donate_argnums=(0,)).lower(state, ring, idx, nkey).compile()
+    text = compiled.as_text()
+
+    comps = _computations(text)
+    whiles = [line for lines in comps.values() for line in lines if re.search(r"\bwhile\(", line)]
+    assert len(whiles) == 1
+    body = re.search(r"body=%?([\w.\-]+)", whiles[0]).group(1)
+    # two images an update, four unrolled updates a trip
+    kernels = [line for line in comps[body] if "tpu_custom_call" in line]
+    assert len(kernels) == 8 and all("augment/pixel_crop" in line for line in kernels)
+    # no array holds an update's images as bytes (XLA's own byte masks are smaller), none is larger than the launch's cut words
+    big = chunk * batch * obs.words
+    shaped = re.compile(r" = (\w+)\[([\d,]+)\]")
+    for line in text.splitlines():
+        m = shaped.search(line)
+        size = np.prod([int(d) for d in m.group(2).split(",")]) if m else 0
+        if m and m.group(1) in ("u8", "s8"):
+            assert size < batch * obs.size, line
+        if size > big:
+            assert " parameter(" in line or "gather" in line or " bitcast(" in line, line
+    assert ring_sized_copies(text, (cfg.replay_capacity, width)) == []
+    # the launch's temporaries: the gathered block, the two cut fields' relayout, the noise
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.2 * chunk * batch * width * 4
