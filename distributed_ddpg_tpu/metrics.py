@@ -224,6 +224,8 @@ class LaunchQueue:
       poll()          before a dispatch: settle(), and note a starved
                       dispatch; returns the updates that finished
       add(leaf, n)    after it: the launch's leaf and its n updates
+      drain()         before a read-back: wait out every queued launch,
+                      one `launch_wait` span each, then settle()
       steps_done      updates of finished launches since the run began
                       (dispatched - in flight)
 
@@ -258,6 +260,22 @@ class LaunchQueue:
         if not self._q and self.n_dispatched:
             self._starved += 1
         return done
+
+    def drain(self) -> int:
+        """Wait for every queued launch, oldest first, each under its own
+        `launch_wait` span, then settle(). The span carries the index the
+        launch's `dispatch` span carried, so on the profiler's host plane
+        the k-th `launch_wait` ends when the host learns that the k-th
+        launch on the device plane has ended (about 2 ms behind it on a
+        v5e: PERF.md §5): no wait for the device is longer than one
+        launch, and the instant the host finds the queue dry is a span
+        boundary. Every read-back of the learner thread calls this first
+        (train.py's `read_back`)."""
+        first = self.n_dispatched - len(self._q)
+        for k, (leaf, _) in enumerate(self._q):
+            with trace.span("launch_wait", chunk=first + k):
+                leaf.block_until_ready()
+        return self.settle()
 
     def add(self, leaf, updates: int) -> None:
         self._q.append((leaf, updates))

@@ -1295,10 +1295,10 @@ class ShardedLearner:
         return self.state.actor_params
 
     def actor_params_to_host(self):
-        """Numpy actor params for broadcast to CPU rollout workers. The
-        span matters: this d2h syncs the in-flight chunk, so the timeline
-        shows it as the learner-thread gap before every param refresh /
-        eval snapshot. A batch-normalised actor (CrossQ) leaves as the plain
+        """Numpy actor params for broadcast to CPU rollout workers. Inside
+        the loop train.py's `read_back` has waited out the launches in flight
+        (one `launch_wait` span each), so `params_d2h` brackets the copy and
+        the fold alone. A batch-normalised actor (CrossQ) leaves as the plain
         MLP it is in evaluation mode (mlp.fold_norm): the workers' layout,
         the evaluator and the serving engine never see the normalisation.
         A pixel configuration's policy (policy_params) leaves as it is:

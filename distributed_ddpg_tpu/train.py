@@ -1296,6 +1296,23 @@ def _train_jax_impl(
     # Launches the device has not finished (metrics.LaunchQueue): what a
     # refresh waits out, and what learner_steps_per_sec must not count.
     launches = LaunchQueue()
+
+    @contextlib.contextmanager
+    def read_back(name: str, **args):
+        """One read-back of the learner thread: every `refresh`, `sync`,
+        `eval_snapshot` and `ckpt` site that fetches the state or the
+        metrics goes through here, as every launch goes through
+        `dispatch`. Inside the site's own phase the launches in flight
+        are waited out one by one under `<name>_drain` (LaunchQueue.drain:
+        a `launch_wait` span each, the device busy throughout), and only
+        then the body runs: its d2h, fold and broadcast find nothing in
+        flight, so their spans bracket the host's turnaround alone, and
+        the instant the host finds the device dry is a span boundary."""
+        with phases.phase(name, **args):
+            with phases.phase(f"{name}_drain"):
+                learn_timer.tick(launches.drain())
+            yield
+
     saver = ckpt_lib.AsyncSaver()
     last_ckpt = learn_steps
 
@@ -1353,7 +1370,7 @@ def _train_jax_impl(
         t = eval_thread["t"]
         if t is not None and t.is_alive():
             return
-        with phases.phase("eval_snapshot"):
+        with read_back("eval_snapshot"):
             host_params = learner.actor_params_to_host()
 
         def _run():
@@ -1656,7 +1673,7 @@ def _train_jax_impl(
             # restore exactly the state just rolled away from).
             ckpt_lib.discard_above(config.checkpoint_dir, step)
         if host_actors:
-            with phases.phase("refresh"):
+            with read_back("refresh"):
                 pool.broadcast(learner.actor_params_to_host(), learn_steps)
         if device_pool is not None:
             # The restored state is a fresh tree; swap the rollout's live
@@ -2049,7 +2066,7 @@ def _train_jax_impl(
             config.strict_sync
             or now - last_refresh_t >= config.param_refresh_interval_s
         ):
-            with phases.phase("refresh", learner_step=learn_steps):
+            with read_back("refresh", learner_step=learn_steps):
                 pool.broadcast(learner.actor_params_to_host(), learn_steps)
             next_refresh = learn_steps + config.param_refresh_every
             last_refresh_t = time.perf_counter()
@@ -2082,7 +2099,7 @@ def _train_jax_impl(
             # only and fork the mesh. Each expansion costs one XLA
             # recompile at the next dispatch, granted to the watchdog like
             # the initial compile.
-            with phases.phase("sync", learner_step=learn_steps):
+            with read_back("sync", learner_step=learn_steps):
                 chunk_metrics = learner.metrics_to_host(out)
             # data_bounds_fn: re-derive the rule-1 bound from the replay's
             # CURRENT rewards so a diverging critic can't drag the support
@@ -2162,7 +2179,7 @@ def _train_jax_impl(
                 float(np.mean([e[1] for e in episodes])) if episodes else None
             )
             if chunk_metrics is None:
-                with phases.phase("sync", learner_step=learn_steps):
+                with read_back("sync", learner_step=learn_steps):
                     chunk_metrics = learner.metrics_to_host(out)
             learn_timer.tick(launches.settle())
             log.log(
@@ -2218,7 +2235,7 @@ def _train_jax_impl(
             config.checkpoint_dir
             and learn_steps - last_ckpt >= config.checkpoint_every
         ):
-            with phases.phase("ckpt"):
+            with read_back("ckpt"):
                 # Learner state is replicated across processes, so ONE
                 # writer suffices for the orbax tree (and shared-FS
                 # writes must not collide). Async: only the HBM->host
@@ -2308,7 +2325,7 @@ def _train_jax_impl(
         write_replay_slices(learn_steps)
         if config.checkpoint_dir and i_write:
             if ckpt_lib.latest_step(my_dir) != learn_steps:
-                with phases.phase("ckpt"):
+                with read_back("ckpt"):
                     ckpt_lib.save(
                         my_dir, learn_steps,
                         learner.state,

@@ -290,12 +290,15 @@ def test_train_smoke_device_actors(tmp_path):
     assert any("t_devactor_ms" in r for r in recs)
 
 
-def test_device_only_warmup_with_ingest_ratio_gate(tmp_path):
+def test_device_only_warmup_with_ingest_ratio_gate(tmp_path, one_chip):
     """Regression: with max_ingest_ratio armed and rows_per_chunk larger
     than min_fill, the device gate must still admit a chunk while any
     allowance remains (bounded one-chunk overshoot) — an all-or-nothing
     gate wedged warmup forever in a device-only run (no host workers to
-    fill the buffer, learn_steps pinned at 0)."""
+    fill the buffer, learn_steps pinned at 0). On one device (conftest's
+    `one_chip`): the gate is host arithmetic, and on the 8 virtual devices
+    this run aborted in XLA:CPU's rendezvous about one time in fifteen
+    beside five other workers, parent and change alike (PERF.md §7, 24)."""
     from distributed_ddpg_tpu.train import train_jax
 
     cfg = _small_cfg(
