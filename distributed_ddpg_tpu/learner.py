@@ -687,9 +687,32 @@ def make_learner_step(
             out_specs=P("data"), check_vma=False,
         )
 
-    def pixel_step(state: TrainState, batch: Batch, noise=None) -> StepOutput:
+    def trunk_rows(state: TrainState, move) -> TrainState:
+        """`state` with the rows of every stored copy of a trunk's `w`
+        passed through `move` (pixnet.block_rows or stored_rows): the three
+        trunks' and Adam's two moments of the two that train. Adam, the
+        decay and Polyak are elementwise, so an update on moved rows is the
+        update on stored rows, moved."""
+        def on(tree):
+            return pixnet.with_trunk_rows(tree, move, config.encoder_channels)
+
+        def on_opt(opt):
+            return opt._replace(mu=on(opt.mu), nu=on(opt.nu))
+
+        return state._replace(
+            actor_params=on(state.actor_params),
+            critic_params=on(state.critic_params),
+            target_critic_params=on(state.target_critic_params),
+            actor_opt=on_opt(state.actor_opt),
+            critic_opt=on_opt(state.critic_opt),
+        )
+
+    def pixel_update(state: TrainState, batch: Batch, noise=None) -> StepOutput:
         """DrQ-v2 (config.pixels; models/pixels.py, ops/pixels.py): the
-        deterministic twin-critic update on augmented byte images. `batch.obs`
+        deterministic twin-critic update on augmented byte images, on a
+        state whose trunks' rows are in the encoder's block's order
+        (trunk_rows with pixnet.block_rows; pixel_step below takes a stored
+        state). `batch.obs`
         and `batch.next_obs` are the ring's WORDS, batch-minor as
         ops/pixels.cut_pixels lays a launch, f32[obs.words, B], four pixels
         each and nothing a float operation may read: the bytes come
@@ -704,8 +727,12 @@ def make_learner_step(
         the source writes it) moving encoder, trunk and heads under one Adam,
         the actor's loss on the DETACHED features through the critic as it
         stood before this update (file convention), Polyak on trunk and
-        heads. The encoder's passes read under `update/encoder`, outside the
-        critic's bracket, so their device time can be told apart."""
+        heads. The features are the last convolution's block f32[B, C, S, S]
+        wherever they go: `feat`, `feat_next`, the detached `feat` and the
+        block's gradient `gfeat`; the five trunk products an update contract
+        it in place (pixnet.trunk_apply). The encoder's passes read under
+        `update/encoder`, outside the critic's bracket, so their device time
+        can be told apart."""
         offsets, noise_next, noise_cur = (
             own_noise(state, batch) if noise is None else noise
         )
@@ -786,7 +813,21 @@ def make_learner_step(
         )
         return StepOutput(state=new_state, td_errors=td, metrics=metrics)
 
+    def pixel_step(state: TrainState, batch: Batch, noise=None) -> StepOutput:
+        """pixel_update on a stored state: the trunks' rows moved to the
+        block's order in front of it and back behind it, three weights and
+        four moments each way. A launch of K updates moves them once for all
+        K (`launch`, which parallel/learner.scan_chunk reads): a trunk's `w`
+        is as many bytes as a feature block, so an update that moved weights
+        would pay what moving the blocks did (every form that moved them an
+        update ran slower on the chip: PERF.md §6, PR 50)."""
+        out = pixel_update(enter(state), batch, noise)
+        return out._replace(state=leave(out.state))
+
     if config.pixels:
+        enter = functools.partial(trunk_rows, move=pixnet.block_rows)
+        leave = functools.partial(trunk_rows, move=pixnet.stored_rows)
+        pixel_step.launch = (enter, pixel_update, leave)
         return pixel_step
 
     def step(state: TrainState, batch: Batch, noise=None) -> StepOutput:

@@ -3,11 +3,21 @@ encoder over stacked byte frames, and the actor and the twin critics behind
 it, as plain functional pytrees like models/mlp.py's.
 
 - encoder f: four 3x3 convolutions of `channels` outputs, no padding,
-  strides 2, 1, 1, 1, a relu behind each, on x = image / 255 - 0.5
-  (ops/pixels.random_shift hands it that); the output flattened channel-major,
-  `channels * side**2` features (84 -> 41 -> 39 -> 37 -> 35: 39,200 at 32).
-- trunk: tanh(LayerNorm(W f + b)), `feature_dim` wide. The critics have one,
-  the actor its own.
+  strides 2, 1, 1, 1, a relu behind each (_relu: its mask kept as bytes for
+  the backward pass), on x = image / 255 - 0.5
+  (ops/pixels.random_shift hands it that); the output is the last
+  convolution's block f32[B, channels, side, side], `channels * side**2`
+  features an image (84 -> 41 -> 39 -> 37 -> 35: 39,200 at 32).
+- trunk: tanh(LayerNorm(W f + b)), `feature_dim` wide, f the block's
+  features. The critics have one, the actor its own. `w` is STORED
+  f32[channels * side**2, feature_dim] with its rows in (c, h, w) order, the
+  source's flatten; a trunk READS it with its rows in (h, w, c) order
+  (block_rows), the order the block holds its features in on the TPU (the
+  convolutions run batch-minor, channels next: f32[B, C, S, S] laid
+  {0,1,3,2}), so that the block reaches the product as a view and its
+  gradient returns as one. Who holds a stored tree moves the rows first:
+  policy_apply a call, the learner's update once a launch
+  (learner.pixel_step).
 - critic: {"encoder", "trunk", "heads"}: the encoder lives in the CRITIC's
   tree, because the critic's loss alone trains it and one Adam moves all
   three; `heads` is an MLP on [h | action] whose leaves carry a leading axis
@@ -144,17 +154,63 @@ def encoder_input(images):
     return images.astype(jnp.float32) / 255.0 - 0.5
 
 
+@jax.custom_vjp
+def _relu(x):
+    """max(x, 0), value and gradient jax.nn.relu's, whose backward pass reads
+    a MASK the forward pass kept: a byte an activation. jax.nn.relu keeps x
+    itself for it, and a convolution then writes its float32 pre-activation
+    beside the bfloat16 output the next layer reads, four bytes more an
+    activation forward and four read backward by EVERY fusion the compiler
+    folds the select into. The update is bound by HBM traffic: on the chip
+    the four layers' masks took the launch from 102.9 to 94.4 ms, and to
+    82.0 with the block handed to the trunks as it lies (PERF.md §6, PR 50).
+    The barrier is what keeps the mask: without it the compiler recomputes
+    `x > 0` from a kept x wherever the mask is read."""
+    return jnp.maximum(x, 0.0)
+
+
+_relu.defvjp(
+    lambda x: (jnp.maximum(x, 0.0), jax.lax.optimization_barrier(x > 0)),
+    lambda on, g: (jnp.where(on, g, 0.0),),
+)
+
+
 def encoder_apply(encoder, x):
-    """x f32[B, C, H, W] in [-0.5, 0.5] -> features f32[B, channels * side**2]."""
+    """x f32[B, C, H, W] in [-0.5, 0.5] -> the feature block f32[B, channels,
+    side, side], as the last convolution leaves it."""
     for layer, stride in zip(encoder, STRIDES):
         x = jax.lax.conv_general_dilated(
             x, layer["w"], (stride, stride), "VALID", dimension_numbers=_DIMS
         )
-        x = jax.nn.relu(x + layer["b"][None, :, None, None])
-    return x.reshape(x.shape[0], -1)
+        x = _relu(x + layer["b"][None, :, None, None])
+    return x
 
 
-def trunk_apply(trunk, features):
+def block_rows(w, channels: int):
+    """A trunk's stored `w`, rows (c, h, w), with its rows in the block's
+    order (h, w, c): a permutation of rows, the features stay where they are."""
+    return w.reshape(channels, -1, w.shape[-1]).swapaxes(0, 1).reshape(w.shape)
+
+
+def stored_rows(w, channels: int):
+    """block_rows' inverse: rows (h, w, c) back in the stored order."""
+    return w.reshape(-1, channels, w.shape[-1]).swapaxes(0, 1).reshape(w.shape)
+
+
+def with_trunk_rows(tree, move, channels: int):
+    """`tree` (a net with a trunk, or Adam's moments of one) with the trunk's
+    `w` passed through `move` (block_rows or stored_rows)."""
+    trunk = tree["trunk"]
+    return {**tree, "trunk": {**trunk, "w": move(trunk["w"], channels)}}
+
+
+def trunk_apply(trunk, block):
+    """tanh(LayerNorm(W f + b)) on the encoder's block f32[B, C, S, S];
+    `trunk["w"]` with its rows in the block's order (block_rows). On the TPU
+    the transposed view is the block as it lies, and the product a plain
+    matmul over all 39,200 features with no relayout in front of it or, for
+    the block's gradient, behind it."""
+    features = block.transpose(0, 2, 3, 1).reshape(block.shape[0], -1)
     return jnp.tanh(_layer_norm(features @ trunk["w"] + trunk["b"], trunk, LN_EPS))
 
 
@@ -164,22 +220,26 @@ def _mlp(params, x):
     return x @ params[-1]["w"] + params[-1]["b"]
 
 
-def actor_apply(actor, features, action_scale, action_offset=0.0):
-    """mu(features): tanh onto the action box. `actor` is the actor's tree
-    or `policy_params` (the encoder in it is the caller's to apply)."""
-    mu = jnp.tanh(_mlp(actor["mlp"], trunk_apply(actor["trunk"], features)))
+def actor_apply(actor, block, action_scale, action_offset=0.0):
+    """mu on the encoder's block: tanh onto the action box. `actor` is the
+    actor's tree or `policy_params` (the encoder in it is the caller's to
+    apply), its trunk's rows in the block's order."""
+    mu = jnp.tanh(_mlp(actor["mlp"], trunk_apply(actor["trunk"], block)))
     return mu * action_scale + action_offset
 
 
-def critic_apply(critic, features, action):
-    """Q_i(features, action) of every head: [ensemble, B]. `critic` holds
-    `trunk` and `heads` (the online tree, or a target)."""
-    x = jnp.concatenate([trunk_apply(critic["trunk"], features), action], axis=-1)
+def critic_apply(critic, block, action):
+    """Q_i(block, action) of every head: [ensemble, B]. `critic` holds
+    `trunk` and `heads` (the online tree, or a target), its trunk's rows in
+    the block's order."""
+    x = jnp.concatenate([trunk_apply(critic["trunk"], block), action], axis=-1)
     return jax.vmap(lambda head: _mlp(head, x)[..., 0])(critic["heads"])
 
 
 def policy_apply(policy, images, action_scale, action_offset=0.0):
-    """The acting policy on byte frames uint8[B, C, H, W], no augmentation:
-    what the rollout program, the evaluator and the host's copy call."""
-    features = encoder_apply(policy["encoder"], encoder_input(images))
-    return actor_apply(policy, features, action_scale, action_offset)
+    """The acting policy (stored: policy_params of a state between
+    launches) on byte frames uint8[B, C, H, W], no augmentation: what the
+    rollout program, the evaluator and the host's copy call."""
+    block = encoder_apply(policy["encoder"], encoder_input(images))
+    policy = with_trunk_rows(policy, block_rows, block.shape[1])
+    return actor_apply(policy, block, action_scale, action_offset)

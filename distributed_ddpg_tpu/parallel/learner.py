@@ -161,19 +161,28 @@ class _ChunkProgram:
         return self.program.trace(*args, self.noise_key)
 
 
+def _as_it_is(s: TrainState) -> TrainState:
+    return s
+
+
 def scan_chunk(step, s: TrainState, batches: Batch, noise, unroll: int):
     """K steps of `step` in one lax.scan over a [K, B, ...] Batch pytree and
     the chunk's pre-drawn `noise` (learner.chunk_noise; None, an empty
     pytree, where the algorithm draws none: the scan's operands are then
-    the batches alone), metrics reduced over the chunk."""
+    the batches alone), metrics reduced over the chunk. A step whose
+    updates run on a state laid out for them names the three as `launch`,
+    (enter, update, leave): the state enters once in front of the scan and
+    leaves once behind it (learner.pixel_step: the rows of its trunks)."""
+    enter, update, leave = getattr(step, "launch", (_as_it_is, step, _as_it_is))
+    s = enter(s)
 
     def body(carry, x):
-        out = step(carry, *x)
+        out = update(carry, *x)
         return out.state, (out.td_errors, out.metrics)
 
     with trace.device_scope("update"):
         s, (tds, ms) = jax.lax.scan(body, s, (batches, noise), unroll=unroll)
-    return StepOutput(state=s, td_errors=tds, metrics=chunk_metrics(ms))
+    return StepOutput(state=leave(s), td_errors=tds, metrics=chunk_metrics(ms))
 
 
 class ShardedLearner:
@@ -1159,8 +1168,18 @@ class ShardedLearner:
         instructions on a `[]` shape that one trip of the launched scan
         chunk's loop issues (the table's `scalars`). None on the kernel
         leg, which scans nothing, and before any launch."""
+        return self._scan_body_fact("scalars")
+
+    def chunk_body_copies(self) -> Optional[dict]:
+        """The run fact `chunk_body_copies`: the relayouts one trip of the
+        launched scan chunk's loop runs as operations of their own, their
+        `count` and the `bytes` of their results (the table's `copies`).
+        None on the kernel leg and before any launch."""
+        return self._scan_body_fact("copies")
+
+    def _scan_body_fact(self, key: str):
         table = None if self.fused_chunk_active else self.chunk_ops()
-        return None if table is None else table["scalars"]
+        return None if table is None else table[key]
 
     # --- single step ---
 

@@ -54,6 +54,7 @@ from __future__ import annotations
 
 import itertools
 import json
+import math
 import os
 import re
 import sys
@@ -526,6 +527,17 @@ _ELEMENTWISE = frozenset({
     "shift-right-arithmetic", "shift-right-logical", "sign", "sine", "sqrt",
     "subtract", "tan", "tanh", "xor",
 })
+# What moves an array and computes nothing: a relayout the compiler could not
+# fold into a neighbour's operand or result. A `reshape` is among them: what
+# the optimiser could make a `bitcast` it has, and one left in a compiled
+# text moves its array (the TPU's tiles pad the minor dimensions, so a
+# flatten is a compaction). A fusion is one where its root is one or its
+# body holds nothing else. An array's bytes as its shape states them
+# (`bf16[256,32,35,35]{...}`: the type's width times the dimensions, no
+# tile's padding).
+_RELAYOUT = frozenset({"copy", "transpose", "reshape"})
+_NO_ARITHMETIC = _NO_OP | _RELAYOUT | {"bitcast"}
+_ARRAY = re.compile(r"[a-z]+(\d*)\w*\[([\d,]*)\]")
 _RUN = re.compile(
     r"(?:body|condition|true_computation|false_computation|calls|to_apply)"
     r"=%?([\w.\-]+)|branch_computations=\{([^}]*)\}"
@@ -546,10 +558,26 @@ def device_scope(word: str):
     return jax.named_scope(word)
 
 
+def _is_relayout(root: Optional[str], opcodes) -> bool:
+    """Whether a fusion of these opcodes under this root only moves an array."""
+    opcodes = set(opcodes)
+    return root in _RELAYOUT or bool(
+        opcodes and opcodes <= _NO_ARITHMETIC and opcodes & _RELAYOUT
+    )
+
+
+def _array_bytes(shape: str) -> int:
+    m = _ARRAY.match(shape)
+    if m is None:  # a tuple (a fusion of several results), a token
+        return 0
+    width = int(m.group(1) or 8) // 8  # `pred` names no width: a byte
+    return width * math.prod(int(d) for d in m.group(2).split(",") if d)
+
+
 def _instructions(hlo_text: str):
     """([(name, opcode, scope, in the entry computation?)], fused, carriers,
-    scalars) of a compiled module's text. The list holds every instruction
-    that runs as an operation of its own: the entry computation's, `while`
+    scalars, copies) of a compiled module's text. The list holds every
+    instruction that runs as an operation of its own: the entry computation's, `while`
     bodies' and conditions', `conditional` branches' and `call` targets'. What is
     inlined into another instruction (a fusion's body, a reducer) is left
     out, and so are parameters, constants and tuples: no trace has an event
@@ -594,9 +622,18 @@ def _instructions(hlo_text: str):
     `scalars` is {`while` instruction: the instructions of its body that
     are unfused arithmetic (_ELEMENTWISE) on a `[]` shape}: what one trip
     of the loop issues one by one between its fusions. A `conditional`'s
-    branches under the body are not the body's."""
+    branches under the body are not the body's.
+
+    `copies` is {`while` instruction: (count, bytes)} of its body's
+    relayouts that run as operations of their own (_RELAYOUT): `copy`,
+    `transpose` and `reshape` instructions, and the fusions whose root is
+    one or that hold nothing else, each with its result's bytes. The TPU's
+    `copy-start` / `copy-done` pairs move an array between memory spaces
+    in its layout and are not among them."""
     found, inlined, runs, computation, entry = [], set(), {}, "", False
     loop_bodies, arithmetic = {}, {}  # while -> its body; computation -> count
+    roots, held = {}, {}  # computation -> its root's opcode; -> its opcodes
+    moved = {}  # computation -> [(fusion's body or None, bytes)], candidates
     bodies, within = {}, {}  # fusion -> its body; body -> {scope: instructions}
     operands, nameless = {}, set()  # instruction -> its operands; no op_name
     for line in hlo_text.splitlines():
@@ -620,6 +657,12 @@ def _instructions(hlo_text: str):
         opcode = rest.lstrip().partition("(")[0]
         if opcode in _ELEMENTWISE and _SCALAR.match(shape):
             arithmetic[computation] = arithmetic.get(computation, 0) + 1
+        if line.lstrip().startswith("ROOT "):
+            roots[computation] = opcode
+        held.setdefault(computation, set()).add(opcode)
+        if opcode in _RELAYOUT or opcode == "fusion":
+            callee = _FUSED.search(rest).group(1) if opcode == "fusion" else None
+            moved.setdefault(computation, []).append((callee, _array_bytes(shape)))
         if opcode == "while":
             loop_bodies[name] = _BODY.search(rest).group(1)
         if opcode != "call":
@@ -700,7 +743,14 @@ def _instructions(hlo_text: str):
     scalars = {
         loop: arithmetic.get(body, 0) for loop, body in loop_bodies.items()
     }
-    return instructions, fused, carriers, scalars
+    copies = {}
+    for loop, body in loop_bodies.items():
+        sizes = [
+            size for callee, size in moved.get(body, ())
+            if callee is None or _is_relayout(roots.get(callee), held.get(callee, ()))
+        ]
+        copies[loop] = (len(sizes), sum(sizes))
+    return instructions, fused, carriers, scalars, copies
 
 
 def _operands(rest: str):
@@ -756,7 +806,7 @@ def op_scopes(hlo_text: str) -> Dict[str, str]:
     (`compiled.as_text()`): the scope `_instructions` reads; COLLECTIVE for
     a collective instruction, whatever its path (`chunk_ops_table` keeps
     that as `served`); an instruction under no bracket is absent."""
-    instructions, _, carriers, _ = _instructions(hlo_text)
+    instructions, _, carriers, _, _ = _instructions(hlo_text)
     return _scopes(instructions, _collectives(instructions, carriers))
 
 
@@ -771,14 +821,19 @@ def chunk_ops_table(hlo_text: str) -> Dict[str, Any]:
     the core until it has landed and is not among them), `fused` (what else
     each fusion holds), `loops`, the `while` instructions: a device trace
     nests a loop's body under the loop's own event, and a reader tells the
-    loop's time under no body operation by the names here; and `scalars`,
+    loop's time under no body operation by the names here; `scalars`,
     the unfused arithmetic instructions on a `[]` shape in the bodies of
     `loops`, a trip of each: where to look for what that time is made of
     (the TPU's compiler fuses no arithmetic on scalars, and a chain of it
     behind a fusion's `f32[]` result makes the loop wait for the result:
-    PERF.md §6, PR 46)."""
+    PERF.md §6, PR 46); and `copies`, the relayouts those bodies run as
+    operations of their own, a trip of each: `count` of the `copy`,
+    `transpose` and `reshape` instructions and of the fusions that only move
+    an array, and the `bytes` of their results (an array handed from one
+    layer to the next in a layout the next cannot read is paid for here:
+    PERF.md §6, PR 50)."""
     module = re.match(r"HloModule ([\w.\-]+)", hlo_text)
-    instructions, fused, carriers, scalars = _instructions(hlo_text)
+    instructions, fused, carriers, scalars, copies = _instructions(hlo_text)
     collectives = _collectives(instructions, carriers)
     return {
         "module": module.group(1) if module else "",
@@ -798,4 +853,8 @@ def chunk_ops_table(hlo_text: str) -> Dict[str, Any]:
             name for name, opcode, _, _ in instructions if opcode == "while"
         ],
         "scalars": sum(scalars.values()),
+        "copies": {
+            "count": sum(count for count, _ in copies.values()),
+            "bytes": sum(size for _, size in copies.values()),
+        },
     }

@@ -1209,6 +1209,11 @@ def _train_jax_impl(
             # unfused arithmetic on scalars (the table's `scalars`); null on
             # the kernel leg and, on the header, before the first launch.
             "chunk_body_scalars": learner.chunk_body_scalars(),
+            # And as relayouts of their own: the `copy`, `transpose` and
+            # `reshape` instructions and the fusions that only move an
+            # array, their count and their results' bytes (the table's
+            # `copies`); null where `chunk_body_scalars` is.
+            "chunk_body_copies": learner.chunk_body_copies(),
             "state_devices": min(
                 len(leaf.sharding.device_set)
                 for leaf in jax.tree.leaves(learner.state)

@@ -159,6 +159,48 @@ def test_the_table_counts_the_loop_bodys_unfused_scalar_arithmetic():
     assert trace.chunk_ops_table(ASYNC_HLO)["scalars"] == 0
 
 
+def test_the_table_counts_the_loop_bodys_relayouts_and_their_bytes():
+    """`copies`: what a `while` body runs to move an array and compute
+    nothing, one trip: `copy`, `transpose` and `reshape` instructions, a
+    fusion whose root is one, a fusion that holds nothing else; each with
+    its result's bytes, the type's width times the dimensions. Not the TPU's
+    `copy-start` / `copy-done` (a move between memory spaces in one layout),
+    not a `bitcast`, not a fusion that computes under another root, not what
+    a `conditional`'s branch or the entry computation copies."""
+    assert trace.chunk_ops_table(HLO)["copies"] == {"count": 0, "bytes": 0}
+    fusions = (
+        '%fused_computation.9 (param_0.9: f32[4,4]) -> f32[4,4] {\n'
+        '  %param_0.9 = f32[4,4]{1,0} parameter(0)\n'
+        '  %add.9 = f32[4,4]{1,0} add(%param_0.9, %param_0.9)\n'
+        '  ROOT %copy.90 = f32[4,4]{0,1} copy(%add.9)\n'
+        '}\n\n'
+        '%fused_computation.10 (param_0.10: s32[2,8]) -> s32[16] {\n'
+        '  %param_0.10 = s32[2,8]{1,0} parameter(0)\n'
+        '  %copy.91 = s32[2,8]{0,1} copy(%param_0.10)\n'
+        '  ROOT %bitcast.91 = s32[16]{0} bitcast(%copy.91)\n'
+        '}\n\n'
+    )
+    body = '  %dynamic-slice.4 = f32[8,15]{1,0} dynamic-slice('
+    moves = (
+        '  %copy.9 = bf16[8,4,3,3]{0,3,2,1:T(8,128)(2,1)S(1)} copy(%w.1)\n'  # 8*4*3*3 * 2
+        '  %transpose.9 = f32[15,8]{1,0} transpose(%w.1), dimensions={1,0}\n'  # 15*8 * 4
+        '  %reshape.9 = pred[120]{0} reshape(%w.1)\n'  # 120 * 1
+        '  %copy_fusion.9 = f32[4,4]{0,1} fusion(%w.1), kind=kLoop, calls=%fused_computation.9\n'  # 16 * 4
+        '  %copy_bitcast_fusion.9 = s32[16]{0} fusion(%w.1), kind=kLoop, calls=%fused_computation.10\n'  # 16 * 4
+        '  %copy-start.9 = (f32[4,4]{1,0:S(1)}, f32[4,4]{1,0}, u32[]{:S(2)}) copy-start(%w.1)\n'
+        '  %copy-done.9 = f32[4,4]{1,0:S(1)} copy-done(%copy-start.9)\n'
+        '  %bitcast.9 = f32[120]{0} bitcast(%w.1)\n'
+    )
+    text = HLO.replace("%body.2 (w.1:", fusions + "%body.2 (w.1:").replace(body, moves + body)
+    text = text.replace(  # one in a branch; the entry computation has its copy.63
+        "  %multiply.7 = f32[4,4]{1,0} multiply(", "  %copy.70 = f32[4,4]{0,1} copy(%gte.5)\n  %multiply.7 = f32[4,4]{1,0} multiply("
+    )
+    table = trace.chunk_ops_table(text)
+    assert table["copies"] == {"count": 5, "bytes": 576 + 480 + 120 + 64 + 64}
+    assert table["loops"] == ["while.3"] and table["scalars"] == 0
+    assert trace.chunk_ops_table(ASYNC_HLO)["copies"] == {"count": 0, "bytes": 0}
+
+
 @pytest.mark.parametrize("opcode", [
     "all-reduce", "all-reduce-start", "all-reduce-done", "all-gather", "all-gather-start",
     "reduce-scatter", "collective-permute", "collective-permute-done", "all-to-all",
@@ -427,6 +469,20 @@ def test_the_run_fact_counts_the_launched_scan_bodys_scalars():
     assert kernel.chunk_body_scalars() is None and isinstance(kernel.chunk_ops()["scalars"], int)
 
 
+def test_the_run_fact_counts_the_launched_scan_bodys_relayouts():
+    """`chunk_body_copies` (ShardedLearner.chunk_body_copies,
+    train.run_facts): the table's `copies` of the executable that ran, a
+    count and its bytes; null where `chunk_body_scalars` is."""
+    learner = launched("sac", "scan")
+    copies = learner.chunk_body_copies()
+    assert copies == learner.chunk_ops()["copies"] and set(copies) == {"count", "bytes"}
+    assert all(isinstance(v, int) and v >= 0 for v in copies.values())
+    assert (copies["count"] == 0) == (copies["bytes"] == 0)
+    learner._build_programs()
+    assert learner.chunk_body_copies() is None
+    assert launched("sac", "kernel").chunk_body_copies() is None
+
+
 # --- the compile cache must answer with the executable of THIS source ---
 
 
@@ -509,6 +565,8 @@ def test_train_writes_chunk_ops_beside_its_records_and_names_it(tmp_path):
     assert records[0]["kind"] == "header" and records[0]["chunk_body_scalars"] is None
     assert records[-1]["kind"] == "final"
     assert records[-1]["chunk_body_scalars"] == summary["chunk_body_scalars"] == table["scalars"]
+    assert records[0]["chunk_body_copies"] is None
+    assert records[-1]["chunk_body_copies"] == summary["chunk_body_copies"] == table["copies"]
     assert isinstance(table["scalars"], int)
     # with --trace_dir it lies beside trace.json too
     assert json.loads((tmp_path / "tr" / trace.CHUNK_OPS_FILE).read_text()) == table

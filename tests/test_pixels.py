@@ -5,11 +5,14 @@ value in every position of a word, NaN words included, through insert, wrap,
 gather, cut and a save and restore; the update's image path (words in, the
 encoder's float input out) against the index-map crop of the byte images,
 every offset, and against the source's `grid_sample` form; what the lowered
-pixel chunk holds; the stand-in environment's frame stack across a reset;
+pixel chunk holds; the hand-over from the encoder's block to the trunks against flatten-and-matmul
+written out, and the seeded leaves it must not move; the stand-in
+environment's frame stack across a reset;
 the 3-step fold on rows of this width against the host accumulator; the
 partition rules of the new trees; each refusal's message."""
 
 import functools
+import hashlib
 import json
 import os
 import re
@@ -300,6 +303,162 @@ def test_the_lowered_pixel_chunk_gathers_once_and_makes_no_bytes(monkeypatch):
     assert converts and not [t for t in converts if re.search(r"x[us]?i8$", t)]
     assert len(re.findall(r"stablehlo\.custom_call @tpu_custom_call", text)) == 2  # an update's two images
     assert "shard_map" in text or "sdy.manual_computation" in text
+
+
+# --- the encoder's block reaches the trunks as it lies: the source's flatten, written out ---
+
+
+def written_out_trunk(trunk, block):
+    """The source's hand-over: the block flattened channel-major against the
+    stored weight, rows (c, h, w); LayerNorm at torch's eps; tanh."""
+    y = block.reshape(block.shape[0], -1) @ trunk["w"] + trunk["b"]
+    mean = jnp.mean(y, axis=-1, keepdims=True)
+    var = jnp.mean(jnp.square(y - mean), axis=-1, keepdims=True)
+    return jnp.tanh((y - mean) / jnp.sqrt(var + 1e-5) * trunk["ln_scale"] + trunk["ln_shift"])
+
+
+def written_out_policy(policy, images, scale, offset):
+    x = images.astype(jnp.float32) / 255.0 - 0.5
+    for layer, stride in zip(policy["encoder"], (2, 1, 1, 1)):
+        x = jax.lax.conv_general_dilated(x, layer["w"], (stride, stride), "VALID", dimension_numbers=("NCHW", "OIHW", "NCHW"))
+        x = jax.nn.relu(x + layer["b"][None, :, None, None])
+    h = written_out_trunk(policy["trunk"], x)
+    for layer in policy["mlp"][:-1]:
+        h = jax.nn.relu(h @ layer["w"] + layer["b"])
+    return jnp.tanh(h @ policy["mlp"][-1]["w"] + policy["mlp"][-1]["b"]) * scale + offset
+
+
+def close(got, want, rel=1e-5):
+    got, want = np.asarray(got), np.asarray(want)
+    assert got.shape == want.shape and got.dtype == want.dtype
+    np.testing.assert_allclose(got, want, rtol=0, atol=rel * np.abs(want).max())
+
+
+@pytest.mark.parametrize("b,c,s", [(4, 32, 35), (256, 32, 35), (3, 5, 7)])
+def test_the_hand_over_is_the_sources_flatten_and_matmul(b, c, s):
+    """trunk_apply on the encoder's block f32[B, C, S, S], against a STORED
+    weight moved to the block's row order (block_rows), is flatten-and-matmul
+    on the stored weight: the trunk's output, the gradient to the block and
+    the gradient to the stored `w`, in float32 on the CPU, where only the
+    order of a sum over C*S*S terms differs; and the move has an inverse."""
+    f = 100 if c == 32 else 6
+    rng = np.random.default_rng([b, c, s])
+    block = jnp.asarray(rng.standard_normal((b, c, s, s)), jnp.float32)
+    trunk = {
+        "w": jnp.asarray(rng.standard_normal((c * s * s, f)) / np.sqrt(c * s * s), jnp.float32),
+        "b": jnp.asarray(rng.standard_normal(f), jnp.float32),
+        "ln_scale": jnp.asarray(1.0 + 0.1 * rng.standard_normal(f), jnp.float32),
+        "ln_shift": jnp.asarray(0.1 * rng.standard_normal(f), jnp.float32),
+    }
+    weights = jnp.asarray(rng.standard_normal((b, f)), jnp.float32)
+
+    def ours(trunk, block):
+        return pixnet.trunk_apply({**trunk, "w": pixnet.block_rows(trunk["w"], c)}, block)
+
+    def outcome(apply):
+        loss = lambda trunk, block: jnp.sum(weights * apply(trunk, block))
+        (gtrunk, gblock) = jax.jit(jax.grad(loss, argnums=(0, 1)))(trunk, block)
+        return jax.jit(apply)(trunk, block), gblock, gtrunk["w"]
+
+    for got, want in zip(outcome(ours), outcome(written_out_trunk)):
+        close(got, want)
+    moved = pixnet.block_rows(trunk["w"], c)
+    assert moved.shape == trunk["w"].shape and not np.array_equal(moved, trunk["w"])
+    np.testing.assert_array_equal(pixnet.stored_rows(moved, c), trunk["w"])
+    # row (h, w, c) of the moved weight is row (c, h, w) of the stored one
+    np.testing.assert_array_equal(moved[(2 * s + 1) * c + 3], trunk["w"][3 * s * s + 2 * s + 1])
+
+
+def test_the_encoders_relu_is_jax_nn_relu_with_its_mask_kept_as_bytes():
+    """models/pixels._relu: jax.nn.relu's value and gradient bit for bit,
+    zero, the signed zeros, the smallest and the largest floats among the
+    inputs; what differs is what the forward pass keeps for the backward
+    one: a boolean behind an optimization barrier (the compiler then stores
+    the mask, a byte an activation, and no float32 pre-activation), where
+    jax.nn.relu keeps its input."""
+    x = jnp.asarray([-np.inf, -3.5, -1e-38, -0.0, 0.0, 1e-45, 1e-38, 2.25, 3e38, np.inf] * 3, jnp.float32).reshape(3, 10)
+    g = jnp.asarray(np.random.default_rng(0).standard_normal(x.shape), jnp.float32)
+    for ours, theirs in zip(jax.vjp(pixnet._relu, x), jax.vjp(jax.nn.relu, x)):
+        got, want = (ours, theirs) if not callable(ours) else (ours(g)[0], theirs(g)[0])
+        np.testing.assert_array_equal(np.asarray(got).view(np.uint32), np.asarray(want).view(np.uint32))
+    text = jax.jit(jax.grad(lambda x: jnp.sum(g * pixnet._relu(x)))).lower(x).as_text()
+    kept = re.findall(r"optimization_barrier[^\n]*tensor<3x10x(\w+)>", text)
+    assert kept and set(kept) == {"i1"}, text
+
+
+def digest(tree):
+    h = hashlib.sha256()
+    for path, leaf in jax.tree_util.tree_leaves_with_path(tree):
+        a = np.asarray(leaf)
+        for part in (jax.tree_util.keystr(path), str(a.shape), str(a.dtype)):
+            h.update(part.encode())
+        h.update(a.tobytes())
+    return h.hexdigest()
+
+
+def test_the_seeded_leaves_are_the_parents_bit_for_bit():
+    """critic_init / actor_init: every leaf's path, shape, dtype and bytes as
+    the tree before PR 50 made them (the digests were taken from it), the
+    trunks' `w` [C*S*S, F] with rows (c, h, w): what a checkpoint, the
+    published policy and the benchmark's `init_gap` read. At the cell's own
+    sizes the trunk's weight is f32[39200, 100], torch's orthogonal of
+    [100, 39200] transposed."""
+    critic = pixnet.critic_init(7, (9, 28, 28), 8, 16, (32, 32), 21)
+    actor = pixnet.actor_init(7, critic["trunk"]["w"].shape[0], 16, (32, 32), 21)
+    assert digest(critic) == "50a85a9352dbc2d278fb3bdd16942cffe5b06ca25b587847ba701f4bc85ea35f"
+    assert digest(actor) == "78a216868f384ae70ca5fc0e472b8778c0684aae1cef09c1985d9577c46ac975"
+    full = pixnet.trunk_init(3, 1, 32 * pixnet.feature_side(84) ** 2, 100)
+    assert full["w"].shape == (39200, 100) and full["w"].dtype == jnp.float32
+    np.testing.assert_array_equal(full["w"], pixnet.orthogonal(3, 1, 0, (100, 39200)).T)
+    assert set(full) == {"w", "b", "ln_scale", "ln_shift"}
+
+
+@pytest.mark.parametrize("batch", [1, 64])
+def test_the_acting_policy_on_byte_frames_is_the_written_out_form(batch):
+    """policy_apply on a STORED policy (the rollout program's, the
+    evaluator's and the host's call): the parent's actions to float32
+    rounding at any batch."""
+    s = init_train_state(cfg(), OBS, ACT, 3)
+    policy = pixnet.policy_params(s.critic_params, s.actor_params)
+    images = jnp.asarray(np.random.default_rng(batch).integers(0, 256, (batch, *OBS.shape), dtype=np.uint8))
+    got = jax.jit(lambda p, x: pixnet.policy_apply(p, x, 0.4, 0.1))(policy, images)
+    close(got, written_out_policy(policy, images, 0.4, 0.1))
+    assert np.ptp(np.asarray(got)) > 1e-3
+
+
+def test_a_launch_moves_the_trunks_rows_once_and_ends_where_single_steps_end():
+    """parallel/learner.scan_chunk reads `pixel_step.launch`: the state's
+    trunk rows enter the block's order in front of the scan and leave it
+    behind the scan. K updates that way end in the STORED state that K calls
+    of pixel_step end in, each of which moves the rows both ways itself."""
+    from distributed_ddpg_tpu.learner import chunk_noise, noise_base_key
+    from distributed_ddpg_tpu.parallel.learner import scan_chunk
+    from distributed_ddpg_tpu.types import Batch
+
+    config, k, b = cfg(), 3, 8
+    step = make_learner_step(config, 1.0, obs=OBS)
+    enter, update, leave = step.launch
+    state = init_train_state(config, OBS, ACT, 1)
+    rng = np.random.default_rng(0)
+    words = lambda: jnp.asarray(words_np(rng.integers(0, 256, (k * b, *OBS.shape), dtype=np.uint8)).reshape(k, b, -1).swapaxes(1, 2))
+    batches = Batch(
+        obs=words(), action=jnp.asarray(rng.uniform(-1, 1, (k, b, ACT)), jnp.float32),
+        reward=jnp.asarray(rng.standard_normal((k, b)), jnp.float32), discount=jnp.full((k, b), 0.97, jnp.float32),
+        next_obs=words(), weight=jnp.ones((k, b), jnp.float32),
+    )
+    noise = chunk_noise(config, noise_base_key(config), state.step, k, b, ACT)
+    chunk = jax.jit(lambda s: scan_chunk(step, s, batches, noise, unroll=4))(state)
+    single = state
+    for i in range(k):
+        single = jax.jit(step)(single, jax.tree.map(lambda x: x[i], batches), jax.tree.map(lambda x: x[i], noise)).state
+    moved = chunk.state.critic_params["trunk"]["w"]
+    assert not np.allclose(moved, state.critic_params["trunk"]["w"], atol=1e-6)  # it trained
+    jax.tree.map(lambda got, want: close(got, want, 1e-4), chunk.state, single)
+    # the moves are each other's inverse on every leaf they touch, and touch nothing else
+    jax.tree.map(np.testing.assert_array_equal, leave(enter(state)), state)
+    entered = enter(state)
+    changed = [jax.tree_util.keystr(path) for (path, a), c in zip(jax.tree_util.tree_leaves_with_path(state), jax.tree.leaves(entered)) if not np.array_equal(a, c)]
+    assert changed == [".actor_params['trunk']['w']", ".critic_params['trunk']['w']", ".target_critic_params['trunk']['w']"]  # the moments start at zero
 
 
 # --- the crop is the source's grid_sample at integer shifts ---

@@ -937,14 +937,12 @@ def test_v5e_pixel_crop_compiles_at_the_cells_shapes(v5e_sharding):
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * cfg.batch_size * obs.size * 4
 
 
-def test_v5e_pixel_chunk_holds_the_scans_loop_alone_and_no_expanded_bytes(v5e_sharding, monkeypatch):
+@pytest.fixture(scope="module")
+def v5e_pixel_chunk(v5e_sharding):
     """`drqv2-humanoid`'s launch at its own sizes (the 65,536-row ring in
     ring_format's layout, 32 x 256 indices, unroll 4) as ShardedLearner's
-    sample chunk builds it: gather, ops/pixels.cut_pixels, scan_chunk. With
-    the byte images cut in front of the scan and a vmapped dynamic_slice an
-    update (PR 47) the text held nine `while`s, the scan's and eight crop
-    loops of 256 trips, and `u32[32,256,15876,4]`, 32 bits a pixel of the
-    launch's block, twice (PERF.md, PR 48)."""
+    sample chunk builds it: gather, ops/pixels.cut_pixels, scan_chunk;
+    compiled once for the tests below: (cfg, obs, act, width, compiled)."""
     from distributed_ddpg_tpu import learner as learner_lib
     from distributed_ddpg_tpu.ops import pixels as pix
     from distributed_ddpg_tpu.parallel.learner import scan_chunk
@@ -954,14 +952,6 @@ def test_v5e_pixel_chunk_holds_the_scans_loop_alone_and_no_expanded_bytes(v5e_sh
     act, chunk, batch = env["act_dim"], cfg.learner_chunk, cfg.batch_size
     width = packed_width(obs, act)
     assert (chunk, batch, width, cfg.replay_capacity) == (32, 256, 31776, 65536)
-    # the process runs on the CPU, where the kernel would be interpreted: compile it
-    monkeypatch.setattr(pix, "random_shift", functools.partial(pix.random_shift, interpret=False))
-    step = learner_lib.make_learner_step(cfg, env["action_scale"], action_offset=env["action_offset"], obs=obs)
-
-    def run(s, storage, idx, nkey):
-        noise = learner_lib.chunk_noise(cfg, nkey, s.step, chunk, batch, act)
-        return scan_chunk(step, s, pix.cut_pixels(storage[idx], obs, act), noise, unroll=4)
-
     replicated = NamedSharding(v5e_sharding.mesh, P())
     state = jax.tree.map(
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=replicated),
@@ -970,15 +960,38 @@ def test_v5e_pixel_chunk_holds_the_scans_loop_alone_and_no_expanded_bytes(v5e_sh
     ring = jax.ShapeDtypeStruct((cfg.replay_capacity, width), jnp.float32, sharding=ring_format(v5e_sharding, width))
     idx = jax.ShapeDtypeStruct((chunk, batch), jnp.int32, sharding=replicated)
     nkey = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=replicated)
-    compiled = jax.jit(run, donate_argnums=(0,)).lower(state, ring, idx, nkey).compile()
-    text = compiled.as_text()
+    with pytest.MonkeyPatch.context() as patch:
+        # the process runs on the CPU, where the kernel would be interpreted: compile it
+        patch.setattr(pix, "random_shift", functools.partial(pix.random_shift, interpret=False))
+        step = learner_lib.make_learner_step(cfg, env["action_scale"], action_offset=env["action_offset"], obs=obs)
 
+        def run(s, storage, idx, nkey):
+            noise = learner_lib.chunk_noise(cfg, nkey, s.step, chunk, batch, act)
+            return scan_chunk(step, s, pix.cut_pixels(storage[idx], obs, act), noise, unroll=4)
+
+        compiled = jax.jit(run, donate_argnums=(0,)).lower(state, ring, idx, nkey).compile()
+    return cfg, obs, act, width, compiled
+
+
+def _scan_body(text):
+    """(the lines of the one `while`'s body, {computation: lines})."""
     comps = _computations(text)
     whiles = [line for lines in comps.values() for line in lines if re.search(r"\bwhile\(", line)]
     assert len(whiles) == 1
-    body = re.search(r"body=%?([\w.\-]+)", whiles[0]).group(1)
+    return comps[re.search(r"body=%?([\w.\-]+)", whiles[0]).group(1)], comps
+
+
+def test_v5e_pixel_chunk_holds_the_scans_loop_alone_and_no_expanded_bytes(v5e_pixel_chunk):
+    """With the byte images cut in front of the scan and a vmapped
+    dynamic_slice an update (PR 47) the text held nine `while`s, the scan's
+    and eight crop loops of 256 trips, and `u32[32,256,15876,4]`, 32 bits a
+    pixel of the launch's block, twice (PERF.md, PR 48)."""
+    cfg, obs, act, width, compiled = v5e_pixel_chunk
+    chunk, batch = cfg.learner_chunk, cfg.batch_size
+    text = compiled.as_text()
+    body, _ = _scan_body(text)
     # two images an update, four unrolled updates a trip
-    kernels = [line for line in comps[body] if "tpu_custom_call" in line]
+    kernels = [line for line in body if "tpu_custom_call" in line]
     assert len(kernels) == 8 and all("augment/pixel_crop" in line for line in kernels)
     # no array holds an update's images as bytes (XLA's own byte masks are smaller), none is larger than the launch's cut words
     big = chunk * batch * obs.words
@@ -993,3 +1006,48 @@ def test_v5e_pixel_chunk_holds_the_scans_loop_alone_and_no_expanded_bytes(v5e_sh
     assert ring_sized_copies(text, (cfg.replay_capacity, width)) == []
     # the launch's temporaries: the gathered block, the two cut fields' relayout, the noise
     assert compiled.memory_analysis().temp_size_in_bytes < 2.2 * chunk * batch * width * 4
+
+
+def test_v5e_pixel_chunk_moves_no_feature_block_between_the_encoder_and_the_trunks(v5e_pixel_chunk):
+    """The encoder's features reach the five trunk products an update, and
+    the block's gradient the last convolution's backward, as the
+    convolutions lay them (`[256,32,35,35]{0,1,3,2}`: batch-minor, channels
+    next; PR 50): the loop's body holds no `copy`, `transpose`, `reshape` or
+    relayout fusion whose operand or result is a feature block, in either
+    type, nor one of a trunk's weight, whose rows a launch moves to the
+    block's order once in front of the scan (learner.pixel_step.launch).
+    Flattened channel-major for `features @ w` the body held, a trip of four
+    updates, eight `copy` of `bf16[256,32,35,35]` to `{0,3,2,1}`, eight
+    `reshape` to `bf16[256,39200]` and four `reshape` of the gradient back:
+    7.5 ms of a 102.7 ms launch on the chip (PERF.md §6, PR 50). What
+    `chunk_ops_table` counts as `copies` is what is left, counted here by
+    hand: the crop's eight retiles of an update's words."""
+    from distributed_ddpg_tpu import trace
+    from distributed_ddpg_tpu.models import pixels as pixnet
+
+    cfg, obs, act, width, compiled = v5e_pixel_chunk
+    batch, c, f = cfg.batch_size, cfg.encoder_channels, cfg.feature_dim
+    side = pixnet.feature_side(obs.shape[-1])
+    assert (batch, c, side, f) == (256, 32, 35, 100)
+    text = compiled.as_text()
+    body, comps = _scan_body(text)
+    held = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = (\S+) ([\w\-]+)\((.*)$")
+    shapes = {m.group(1): m.group(2) for lines in comps.values() for m in map(held.match, lines) if m}
+    dims = lambda shape: tuple(int(d) for d in re.search(r"\[([\d,]*)\]", shape).group(1).split(",") if d)
+    blocks = {(batch, c, side, side), (batch, side, side, c), (batch, c * side * side), (batch, c, side * side), (batch, side * side, c)}
+    weights = {(c * side * side, f), (c, side, side, f), (side, side, c, f), (c, side * side, f), (side * side, c, f)}
+    moves, retiles = [], 0
+    for m in filter(None, map(held.match, body)):
+        name, shape, opcode, rest = m.groups()
+        callee = re.search(r"calls=%?([\w.\-]+)", rest)
+        inside = {held.match(line).group(3) for line in comps[callee.group(1)]} if opcode == "fusion" else {opcode}
+        if not inside <= {"parameter", "bitcast", "copy", "transpose", "reshape"} or not inside & {"copy", "transpose", "reshape"}:
+            continue
+        operands = [shapes[o] for o in re.findall(r"%([\w.\-]+)", rest.split("), ")[0]) if o in shapes]
+        touched = {dims(x) for x in (shape, *operands) if "[" in x}
+        moves.append((name, shape, touched))
+        retiles += dims(shape) == (obs.shape[0], obs.shape[1], obs.shape[2] // 4, batch)
+    assert not [move for move in moves if move[2] & (blocks | weights)], moves
+    # an update's two images' words, four unrolled updates a trip: s32[9,84,21,256]
+    assert retiles == len(moves) == 8
+    assert trace.chunk_ops_table(text)["copies"] == {"count": 8, "bytes": 8 * obs.words * batch * 4}
