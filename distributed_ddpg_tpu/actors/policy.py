@@ -20,7 +20,11 @@ added back onto the stream); then `ln` (the post-LayerNorm) and the head
 `linear`. Its first entry's w is still (obs_dim, width) and its last entry's
 (width, head), so what reads those two shapes reads them as before. The
 input normaliser is not in the block: the learner folds it into the
-embedding at every refresh (models/mlp.fold_rsnorm).
+embedding at every refresh (models/mlp.fold_rsnorm). A LayerNormMLP's
+(models/mlp.lnmlp_init, DMPO's policy; `param_layout(..., lnmlp=True)`)
+entries are `linear` (the first layer), `tanh` (the LayerNorm behind it, scale
+then shift, and the tanh on its output), one `elu` (w, b, then ELU) per
+further width and the head `linear`.
 """
 
 from __future__ import annotations
@@ -32,11 +36,17 @@ import numpy as np
 Layout = List[tuple]  # [(w_shape, b_shape)] or [(w_shape, b_shape, kind)]
 
 # A layered entry's kinds. x is the stream, r the value saved at `block_ln`.
-KINDS = ("linear", "block_ln", "relu", "add", "ln")
-# models/mlp.py's LN_EPS and SIMBA_EXPANSION (a worker never imports that
-# module: it loads JAX; tests/test_simba.py holds the two files together)
+KINDS = ("linear", "block_ln", "relu", "add", "ln", "tanh", "elu")
+# models/mlp.py's LN_EPS, LNMLP_EPS, SIMBA_EXPANSION and the Gaussian head's
+# two constants (a worker never imports that module: it loads JAX;
+# tests/test_simba.py and tests/test_dmpo.py hold the two files together)
 LN_EPS = 1e-6
+LNMLP_EPS = 1e-5
 EXPANSION = 4
+GAUSSIAN_INIT_SCALE = 0.7
+GAUSSIAN_MIN_SCALE = 1e-6
+# A LayerNorm entry's epsilon, by its kind.
+_NORM_EPS = {"ln": LN_EPS, "block_ln": LN_EPS, "tanh": LNMLP_EPS}
 # The leaves of one layer of the learner's tree, in the block's order; a
 # layer holds the ones its kind has.
 LEAF_ORDER = ("ln_scale", "ln_shift", "w1", "b1", "w2", "b2", "w", "b")
@@ -56,16 +66,46 @@ def decode_version(tag) -> int:
 
 
 def actor_head_dim(act_dim: int, sac: bool) -> int:
-    """Actor output width: SAC's Gaussian head is [mean | log_std]."""
+    """Actor output width: a Gaussian head (SAC's, MPO's:
+    config.gaussian_head) is [mean | log_std or raw scale]."""
     return 2 * act_dim if sac else act_dim
 
 
+def gaussian_scale(raw: np.ndarray) -> np.ndarray:
+    """models/mlp.gaussian_scale in numpy: MPO's head's scale from its raw
+    half."""
+    return GAUSSIAN_INIT_SCALE / np.log(2.0) * np.logaddexp(raw, 0.0) + GAUSSIAN_MIN_SCALE
+
+
+def layout_of(config, obs_dim: int, act_dim: int) -> Layout:
+    """The layout of `config`'s policy on an environment's two sizes: what
+    the pools, the evaluator and the serving engines build theirs from."""
+    return param_layout(
+        obs_dim,
+        actor_head_dim(act_dim, config.gaussian_head),
+        tuple(config.actor_hidden),
+        residual=config.simba,
+        lnmlp=config.mpo,
+    )
+
+
 def param_layout(
-    obs_dim: int, act_dim: int, hidden: Sequence[int], residual: bool = False
+    obs_dim: int, act_dim: int, hidden: Sequence[int], residual: bool = False,
+    lnmlp: bool = False,
 ) -> Layout:
     """`act_dim` here is the HEAD width — pass actor_head_dim(...) for SAC.
     `residual` (config.simba): one pre-LayerNorm block per entry of
-    `hidden`, the module docstring's layered layout."""
+    `hidden`; `lnmlp` (config.mpo): a LayerNorm and a tanh behind the first
+    layer, ELU behind the others: the module docstring's layered layouts."""
+    if lnmlp:
+        dims = [obs_dim, *hidden]
+        layout = [((dims[0], dims[1]), (dims[1],), "linear"),
+                  ((dims[1],), (dims[1],), "tanh")]
+        layout += [
+            ((dims[i], dims[i + 1]), (dims[i + 1],), "elu")
+            for i in range(1, len(dims) - 1)
+        ]
+        return layout + [((dims[-1], act_dim), (act_dim,), "linear")]
     if residual:
         h = hidden[0]
         layout = [((obs_dim, h), (h,), "linear")]
@@ -134,8 +174,12 @@ class NumpyPolicy:
     tanh(mean), `stochastic=True` samples the tanh-Gaussian with a local
     numpy RNG (workers explore by sampling the policy — no OU noise).
 
-    A layered layout (`is_layered`) runs the residual net its kinds spell
-    out, LayerNorm and all, on the same block discipline."""
+    `squash=False` (with `gaussian`) mirrors MPO's head
+    (models/mlp.gaussian_apply): [mean | raw scale] of a plain Gaussian on
+    the canonical box; it acts on the mean, or on a draw, CLIPPED to the box.
+
+    A layered layout (`is_layered`) runs the net its kinds spell out,
+    LayerNorm and all, on the same block discipline."""
 
     def __init__(
         self,
@@ -147,8 +191,10 @@ class NumpyPolicy:
         seed: int | None = None,
         log_std_min: float = -5.0,
         log_std_max: float = 2.0,
+        squash: bool = True,
     ):
         self.layout = layout
+        self.squash = squash
         self.scale = np.asarray(action_scale, np.float32)
         self.offset = np.asarray(action_offset, np.float32)
         self.gaussian = gaussian
@@ -186,19 +232,23 @@ class NumpyPolicy:
         return x @ self.layers[-1]["w"] + self.layers[-1]["b"]
 
     def _layered(self, x: np.ndarray) -> np.ndarray:
-        """The residual net of a layered layout (module docstring)."""
+        """The net of a layered layout (module docstring)."""
         saved = None
         for layer, kind in zip(self.layers, self.kinds):
-            if kind in ("ln", "block_ln"):
+            if kind in _NORM_EPS:
                 if kind == "block_ln":
                     saved = x
                 mean = x.mean(axis=-1, keepdims=True)
                 var = np.square(x - mean).mean(axis=-1, keepdims=True)
-                x = (x - mean) / np.sqrt(var + np.float32(LN_EPS)) * layer["w"] + layer["b"]
+                x = (x - mean) / np.sqrt(var + np.float32(_NORM_EPS[kind])) * layer["w"] + layer["b"]
+                if kind == "tanh":
+                    x = np.tanh(x)
                 continue
             x = x @ layer["w"] + layer["b"]
             if kind == "relu":
                 x = np.maximum(x, 0.0)
+            elif kind == "elu":
+                x = np.where(x > 0.0, x, np.expm1(np.minimum(x, 0.0)))
             elif kind == "add":
                 x = saved + x
         return x
@@ -209,6 +259,12 @@ class NumpyPolicy:
         layered one. The jax serving engine ships it to the device."""
         if self.kinds is None:
             return tuple(dict(layer) for layer in self.layers)
+        if "tanh" in self.kinds:  # a LayerNormMLP: one dict an entry
+            return tuple(
+                {"ln_scale": layer["w"], "ln_shift": layer["b"]}
+                if kind == "tanh" else dict(layer)
+                for layer, kind in zip(self.layers, self.kinds)
+            )
         embed, *rest = self.layers
         out, i = [dict(embed)], 0
         while self.kinds[1 + i] == "block_ln":
@@ -222,6 +278,13 @@ class NumpyPolicy:
 
     def __call__(self, obs: np.ndarray) -> np.ndarray:
         x = self.head(obs)
+        if self.gaussian and not self.squash:
+            u, raw = np.split(x, 2, axis=-1)
+            if self.stochastic:
+                u = u + gaussian_scale(raw) * self._rng.standard_normal(
+                    u.shape
+                ).astype(np.float32)
+            return (np.clip(u, -1.0, 1.0) * self.scale + self.offset).astype(np.float32)
         if self.gaussian:
             mean, log_std_raw = np.split(x, 2, axis=-1)
             if not self.stochastic:

@@ -45,11 +45,10 @@ import numpy as np
 
 from distributed_ddpg_tpu import trace
 from distributed_ddpg_tpu.actors.policy import (
-    actor_head_dim,
     decode_version,
     flatten_params,
     layout_size,
-    param_layout,
+    layout_of,
 )
 from distributed_ddpg_tpu.actors.worker import run_worker
 from distributed_ddpg_tpu.config import DDPGConfig
@@ -92,12 +91,10 @@ class ActorPool:
         # A pool without workers (a run on device actors alone) shares no
         # parameters: no layout, an empty array, and start() broadcasts
         # nothing. Such a run's policy need not have a host layout at all.
-        self.layout = param_layout(
-            spec.obs_dim,
-            actor_head_dim(spec.act_dim, config.sac),
-            tuple(config.actor_hidden),
-            residual=config.simba,
-        ) if self.num_actors else []
+        self.layout = (
+            layout_of(config, spec.obs_dim, spec.act_dim)
+            if self.num_actors else []
+        )
         self._shared = self._ctx.Array("f", layout_size(self.layout), lock=False)
         self._version = self._ctx.Value("l", 0)
         self._queue = self._ctx.Queue(maxsize=4 * self.num_actors)
@@ -263,7 +260,8 @@ class ActorPool:
                 gamma=self.config.gamma,
                 fault_specs=fault_specs,
                 throttle_s=self.config.actor_throttle_s,
-                gaussian_policy=self.config.sac,
+                gaussian_policy=self.config.gaussian_head,
+                squash_policy=not self.config.mpo,
                 log_std_min=self.config.sac_log_std_min,
                 log_std_max=self.config.sac_log_std_max,
                 warmup_uniform=self.warmup_budget_per_worker(),

@@ -27,9 +27,8 @@ import numpy as np
 
 from distributed_ddpg_tpu.actors.policy import (
     NumpyPolicy,
-    actor_head_dim,
     flatten_params,
-    param_layout,
+    layout_of,
 )
 from distributed_ddpg_tpu.config import DDPGConfig
 from distributed_ddpg_tpu.envs import make
@@ -49,7 +48,7 @@ class _InlineActor:
         self.noise = OUNoise(
             (spec.act_dim,),
             theta=config.ou_theta,
-            sigma=0.0 if config.sac else config.ou_sigma,
+            sigma=0.0 if config.gaussian_head else config.ou_sigma,
             dt=config.ou_dt,
             seed=seed,
         )
@@ -108,21 +107,17 @@ class SyncActorPool:
         self.config = config
         self.spec = spec
         self.num_actors = num_actors or config.num_actors
-        self.layout = param_layout(
-            spec.obs_dim,
-            actor_head_dim(spec.act_dim, config.sac),
-            tuple(config.actor_hidden),
-            residual=config.simba,
-        )
+        self.layout = layout_of(config, spec.obs_dim, spec.act_dim)
         self._policy = NumpyPolicy(
             self.layout,
             spec.action_scale,
             spec.action_offset,
-            gaussian=config.sac,
-            stochastic=config.sac,
+            gaussian=config.gaussian_head,
+            stochastic=config.gaussian_head,
             seed=config.seed + 1,
             log_std_min=config.sac_log_std_min,
             log_std_max=config.sac_log_std_max,
+            squash=not config.mpo,
         )
         self._actors: List[_InlineActor] = []
         self._episodes: List[tuple] = []

@@ -48,7 +48,8 @@ def run_worker(
     send_every: int = 32,
     fault_specs=(),         # (kind, at_step, duration_s) tuples, sorted by step
     throttle_s: float = 0.0,
-    gaussian_policy: bool = False,  # SAC: sample the policy, no OU noise
+    gaussian_policy: bool = False,  # SAC, MPO: sample the policy, no OU noise
+    squash_policy: bool = True,     # False: MPO's plain Gaussian, clipped to the box
     log_std_min: float = -5.0,
     log_std_max: float = 2.0,
     warmup_uniform: int = 0,  # uniform-random actions for the first N steps
@@ -100,6 +101,7 @@ def run_worker(
         seed=seed,
         log_std_min=log_std_min,
         log_std_max=log_std_max,
+        squash=squash_policy,
     )
     # SAC explores by sampling its own tanh-Gaussian; the OU process is
     # zeroed (sigma=0 keeps the loop shape identical at no cost).

@@ -45,10 +45,10 @@ class DDPGAgent:
         # SAC explores by sampling its own policy; OU noise stays unused.
         self._sample_fn = (
             make_sample_fn(config, spec.action_scale, action_offset=spec.action_offset)
-            if config.sac
+            if config.gaussian_head
             else None
         )
-        self._act_key = jax.random.PRNGKey(config.seed + 2) if config.sac else None
+        self._act_key = jax.random.PRNGKey(config.seed + 2) if config.gaussian_head else None
         # Uniform-random warmup (SAC start_steps; config.warmup_uniform_steps).
         self._warmup_uniform = config.resolved_warmup_uniform()
         self._warmup_rng = np.random.default_rng(config.seed + 3)
@@ -83,7 +83,7 @@ class DDPGAgent:
             return self._warmup_rng.uniform(
                 self.spec.action_low, self.spec.action_high
             ).astype(np.float32)
-        if explore and self.config.sac:
+        if explore and self.config.gaussian_head:
             self._act_key, k = jax.random.split(self._act_key)
             action = np.asarray(
                 self._sample_fn(self.state.actor_params, obs[None], k)

@@ -61,6 +61,7 @@ COMPAT_FIELDS = (
     "crossq",  # no target nodes; batch-norm leaves in every layer
     "simba",  # residual nets: blocks, LayerNorm and input-statistics leaves
     "pixels",  # DrQ-v2's trees: an encoder in the critic's, no target actor
+    "mpo",  # LayerNormMLP nets, a [mean | scale] head, the dual variables' tree
     "encoder_channels",
     "feature_dim",
     "num_atoms",
@@ -592,7 +593,7 @@ def check_config_compatible(directory: str, step: int, config: DDPGConfig) -> No
         return
     with open(path) as f:
         # a checkpoint from before the field existed was not a crossq run's
-        saved = {"crossq": False, "simba": False, "pixels": False, **json.load(f)}
+        saved = {"crossq": False, "simba": False, "pixels": False, "mpo": False, **json.load(f)}
     current = dataclasses.asdict(config)
     mismatches = [
         f"{k}: checkpoint={saved[k]!r} run={_listify(current[k])!r}"

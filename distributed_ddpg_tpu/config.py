@@ -184,6 +184,43 @@ class DDPGConfig:
     # (ops/pixels.sigma_at).
     explore_sigma_schedule: str = "1.0,0.1,500000"
 
+    # --- DMPO (Acme's distributional MPO: arXiv 2006.00979, agents/tf/dmpo;
+    # the policy step arXiv 1806.06920 in its decoupled form, 1812.02256) ---
+    # mpo: the policy is improved without a gradient through the critic
+    # (learner.make_learner_step's mpo_step; ops/losses.py): mpo_samples
+    # actions a state drawn from the TARGET policy at s', valued by the
+    # TARGET categorical critic (distributional must be on), a softmax over
+    # the samples at a learned temperature, and a weighted maximum
+    # likelihood fit of the online policy's mean and scale apart, each under
+    # a per-dimension KL bound held by a learned multiplier. The critic's
+    # target is the mixture of the samples' distributions. Both nets are
+    # Acme's LayerNormMLP (models/mlp.lnmlp_init: linear, LayerNorm, tanh,
+    # then linear and ELU per further entry of *_hidden), the policy's head a
+    # diagonal Gaussian with a softplus scale and no squashing, the critic
+    # on [obs | action clipped to the canonical box] (action_insert_layer
+    # must be 0). The four dual variables ride TrainState.log_alpha as a
+    # small tree under an Adam of their own rate (alpha_opt). The source's
+    # other settings are plain flags: distributional, num_atoms 51, n_step 5,
+    # batch 256, both learning rates 1e-4, target_update_period 100.
+    mpo: bool = False
+    mpo_samples: int = 20
+    mpo_epsilon: float = 0.1            # KL bound of the E-step's softmax
+    mpo_epsilon_penalty: float = 1e-3   # the same for the out-of-box penalty
+    mpo_epsilon_mean: float = 2.5e-3    # per-dimension KL bound on the mean
+    mpo_epsilon_stddev: float = 1e-6    # per-dimension KL bound on the scale
+    mpo_init_log_temperature: float = 10.0
+    mpo_init_log_alpha_mean: float = 10.0
+    mpo_init_log_alpha_stddev: float = 1000.0
+    dual_lr: float = 1e-2               # Adam's rate on the dual variables
+    # Targets copied whole from the online nets every target_update_period
+    # learner steps (after the update whose count ends a period), in place
+    # of the Polyak average: 0 (default) keeps tau's average, program for
+    # program. One rule (ops/polyak.target_update), any family of the scan
+    # leg with targets may set it; the megakernel and the native backend
+    # average only: a period takes the scan leg on the one and is refused
+    # by the other.
+    target_update_period: int = 0
+
     # --- replay (SURVEY.md §2 #5/#7) ---
     replay_capacity: int = 1_000_000
     replay_min_size: int = 1_000     # warmup before learning starts
@@ -713,6 +750,12 @@ class DDPGConfig:
         )
 
     @property
+    def gaussian_head(self) -> bool:
+        """Whether the policy's last layer is [mean | scale] (2 * act wide):
+        SAC's squashed Gaussian and MPO's plain one."""
+        return self.sac or self.mpo
+
+    @property
     def sigma_schedule(self) -> tuple:
         """explore_sigma_schedule as (initial, final, frames)."""
         init, final, frames = self.explore_sigma_schedule.split(",")
@@ -807,6 +850,69 @@ class DDPGConfig:
                 "pixels refuses --serve_actors: the serving engines batch "
                 "flat float observations for host workers, and there are "
                 "none (ROADMAP.md R5)"
+            )
+
+    def _check_mpo(self):
+        """What the MPO learner needs, and what it refuses, each with its
+        reason."""
+        if not self.distributional or self.twin_critic or self.sac:
+            raise ValueError(
+                "mpo (DMPO) values its sampled actions with the categorical "
+                "critic: set distributional=True; twin_critic and sac are "
+                "other families (a clipped minimum and an entropy term have "
+                "no place in the E-step's softmax)"
+            )
+        if self.pixels:
+            raise ValueError(
+                "mpo refuses --pixels: the pixel learner is DrQ-v2's "
+                "deterministic step and has no sampled-action pass"
+            )
+        if self.action_insert_layer != 0:
+            raise ValueError(
+                "an mpo critic takes [obs | clipped action] at its input "
+                "(Acme's CriticMultiplexer): set action_insert_layer=0"
+            )
+        if self.mpo_samples < 2:
+            raise ValueError(
+                "mpo_samples must be >= 2: a softmax over one sample weights "
+                "it 1 whatever its value"
+            )
+        for knob in (
+            "mpo_epsilon", "mpo_epsilon_penalty", "mpo_epsilon_mean",
+            "mpo_epsilon_stddev", "dual_lr",
+        ):
+            if getattr(self, knob) <= 0:
+                raise ValueError(f"{knob} must be > 0")
+        if self.backend != "jax_tpu":
+            raise ValueError(
+                "mpo requires backend='jax_tpu': the native numpy learner "
+                "has neither LayerNorm nets nor dual variables"
+            )
+        if self.fused_chunk == "on":
+            raise ValueError(
+                "mpo refuses --fused_chunk=on: the megakernel has no branch "
+                "for a pass on batch x samples rows (ops/fused_chunk."
+                "supported says no); the scan leg runs"
+            )
+        if self.prioritized:
+            raise ValueError(
+                "mpo refuses --prioritized: the critic's target is a mixture "
+                "over drawn actions, so the expectation gap that would be "
+                "written back as a priority carries the draw's noise, and "
+                "the source replays uniformly"
+            )
+        if self.actor_backend != "host" or self.fused_beat == "on" or self.superstep_beats > 1:
+            raise ValueError(
+                "mpo runs host actors only (--actor_backend=host): the "
+                "device pool's rollout explores with SAC's squashed draw or "
+                "a noise ladder on a tanh policy, and has no plain Gaussian "
+                "clipped to the box; the fused beat composes that rollout"
+            )
+        if self.exploration != "ou":
+            raise ValueError(
+                "mpo's actors explore by sampling the policy's own Gaussian: "
+                "leave --exploration at its default (no noise process is "
+                "added to the draw)"
             )
 
     def __post_init__(self):
@@ -1025,6 +1131,35 @@ class DDPGConfig:
                     )
         if self.pixels:
             self._check_pixels()
+        if self.mpo:
+            self._check_mpo()
+        if self.target_update_period < 0:
+            raise ValueError(
+                "target_update_period must be >= 0 (0 = Polyak with tau)"
+            )
+        if self.target_update_period and (
+            self.crossq or self.pixels or self.simba
+            or (self.twin_critic and self.policy_delay > 1)
+        ):
+            raise ValueError(
+                "target_update_period copies target networks whole, on the "
+                "learner's step count: crossq has none, the pixel learner's "
+                "target holds a part of its critic, a residual net's target "
+                "takes the input statistics on every update, and a delayed "
+                "twin-critic step moves its targets on the actor's schedule "
+                "(ROADMAP.md R10)"
+            )
+        if self.target_update_period and self.backend == "native":
+            raise ValueError(
+                "target_update_period is read by the jitted step "
+                "(ops/polyak.target_update) only: the native backend "
+                "averages its targets with tau"
+            )
+        if self.target_update_period and self.fused_chunk == "on":
+            raise ValueError(
+                "target_update_period refuses --fused_chunk=on: the "
+                "megakernel averages its targets with tau on every update"
+            )
         if self.weight_decay < 0:
             raise ValueError("weight_decay must be >= 0 (0 = plain Adam)")
         if self.weight_decay and self.backend == "native":

@@ -33,12 +33,13 @@ from jax.sharding import PartitionSpec as P
 from distributed_ddpg_tpu.config import DDPGConfig
 from distributed_ddpg_tpu.ops import losses
 from distributed_ddpg_tpu.ops.optim import adam_update
-from distributed_ddpg_tpu.ops.polyak import polyak_update
+from distributed_ddpg_tpu.ops.polyak import polyak_update, target_update
 from distributed_ddpg_tpu.trace import device_scope
 from distributed_ddpg_tpu.types import Batch, ObsSpec, OptState, TrainState
 from distributed_ddpg_tpu.models import pixels as pixnet
 from distributed_ddpg_tpu.models.mlp import (
-    actor_init, critic_init, norm_moved, rs_merged, rs_written, simba_init,
+    actor_init, critic_init, gaussian_apply, lnmlp_init, norm_moved,
+    rs_merged, rs_written, simba_init,
 )
 from distributed_ddpg_tpu.ops import pixels as pix
 
@@ -87,8 +88,17 @@ def metric_keys(config: DDPGConfig) -> tuple:
     gradient on the encoder alone, the chunk's mean), `explore_sigma` (the
     scheduled noise scale of the chunk's last update) and `aug_offset_mean`
     (the mean of the launch's crop offsets: `aug_pad` in expectation, a
-    counter that says the draw is alive). Only those branches have the keys, so every
-    other family's programs and records are what they were."""
+    counter that says the draw is alive). An MPO run, config.mpo, reports
+    beside the categorical critic's edge mass `mpo_weight_ess` (the batch
+    mean of 1 / sum_j w_j^2 over the E-step's value weights: how many of the
+    mpo_samples actions a state the improved policy is fitted to),
+    `mpo_kl_mean_ratio` (the mean over the action's dimensions of KL_mean /
+    mpo_epsilon_mean: above 1 the mean's bound is broken and its multiplier
+    grows) and `mpo_temperature` (eta out of log space). Only those branches
+    have the keys, so every other family's programs and records are what
+    they were."""
+    if config.mpo:
+        return METRIC_KEYS + ("c51_edge_mass",) + MPO_KEYS
     if config.distributional:  # config.py: never with twin_critic or sac
         return METRIC_KEYS + ("c51_edge_mass",)
     if config.pixels:
@@ -106,10 +116,11 @@ def metric_keys(config: DDPGConfig) -> tuple:
 
 SIMBA_KEYS = ("resid_share", "rsnorm_count", "rsnorm_drift")
 PIXEL_KEYS = ("encoder_grad_norm", "explore_sigma", "aug_offset_mean")
+MPO_KEYS = ("mpo_weight_ess", "mpo_kl_mean_ratio", "mpo_temperature")
 # Metrics a chunk reports for its LAST update, not as a mean over the K.
 LAST_UPDATE_KEYS = (
     "c51_edge_mass", "td3_twin_gap", "redq_q_spread", "bn_stat_gap", *SIMBA_KEYS,
-    "explore_sigma",
+    "explore_sigma", *MPO_KEYS,
 )
 
 
@@ -176,7 +187,7 @@ def draws_subset(config: DDPGConfig) -> bool:
 def draws_noise(config: DDPGConfig) -> bool:
     """Whether `config`'s learner step draws noise at all."""
     return bool(
-        config.sac or config.pixels
+        config.sac or config.pixels or config.mpo
         or (config.twin_critic and config.target_noise > 0.0)
     )
 
@@ -190,7 +201,10 @@ def noise_base_key(config: DDPGConfig):
         return None
     return jax.random.PRNGKey(
         config.seed
-        ^ (0x5AC0 if config.sac else 0xD2C if config.pixels else 0x7D3AF)
+        ^ (
+            0x5AC0 if config.sac else 0xD2C if config.pixels
+            else 0x3B0 if config.mpo else 0x7D3AF
+        )
     )
 
 
@@ -206,7 +220,9 @@ def step_noise(config: DDPGConfig, base, step, batch: int, act_dim: int,
     (dy, dx) of `obs` then of `next_obs`, uniform in 0..2*aug_pad; the
     target action's noise; the actor loss's), both noises sigma * N(0, I)
     clipped at target_noise_clip with sigma the schedule at this step
-    (ops/pixels.sigma_at). None
+    (ops/pixels.sigma_at). MPO: the standard normals of the E-step's draws,
+    f32[B, mpo_samples, act], the rows first so that a data mesh shards them
+    like the batch. None
     where the algorithm draws none (DDPG, D4PG, TD3 without smoothing).
     `device_fold` (lax.axis_index under shard_map) folds a per-device term
     AFTER the step fold, so that each shard of a global batch draws its own
@@ -230,6 +246,8 @@ def step_noise(config: DDPGConfig, base, step, batch: int, act_dim: int,
             config.critic_ensemble, (config.target_subset,), replace=False,
         )
         return (*eps, subset.astype(jnp.int32))
+    if config.mpo:
+        return jax.random.normal(key, (batch, config.mpo_samples, act_dim))
     if config.pixels:
         k_off, k_next, k_cur = jax.random.split(key, 3)
         sigma = pix.sigma_at(config.sigma_schedule, step)
@@ -257,7 +275,7 @@ def noise_per_row(config: DDPGConfig):
     if config.pixels:
         return (True, True, True)
     if not config.sac:
-        return True
+        return True  # TD3's smoothing noise, MPO's draws
     return (True, True, False) if draws_subset(config) else (True, True)
 
 
@@ -309,6 +327,46 @@ def init_pixel_state(config: DDPGConfig, obs: ObsSpec, act_dim: int, seed: int) 
     )
 
 
+def init_mpo_state(config: DDPGConfig, obs_dim: int, act_dim: int, k_actor, k_critic) -> TrainState:
+    """DMPO's state (models/mlp.lnmlp_init): a LayerNormMLP policy with a
+    [mean | scale] head, a LayerNormMLP categorical critic on [obs |
+    action], both targets, and the four dual variables as `log_alpha`'s
+    small tree (ops/losses.MPO_DUALS: two temperatures, f32[1] as the
+    source holds them, so that their arithmetic rides the vector unit's
+    fusions and not the scalar core (PERF.md §6, PR 46), and the mean's and
+    the scale's KL multipliers, one a dimension of the action) with their
+    own Adam's moments in `alpha_opt`."""
+    actor = lnmlp_init(k_actor, obs_dim, 2 * act_dim, tuple(config.actor_hidden))
+    critic = lnmlp_init(
+        k_critic, obs_dim + act_dim, config.num_atoms, tuple(config.critic_hidden)
+    )
+    duals = {
+        "log_temperature": jnp.full(
+            (1,), config.mpo_init_log_temperature, jnp.float32
+        ),
+        "log_penalty_temperature": jnp.full(
+            (1,), config.mpo_init_log_temperature, jnp.float32
+        ),
+        "log_alpha_mean": jnp.full(
+            (act_dim,), config.mpo_init_log_alpha_mean, jnp.float32
+        ),
+        "log_alpha_stddev": jnp.full(
+            (act_dim,), config.mpo_init_log_alpha_stddev, jnp.float32
+        ),
+    }
+    return TrainState(
+        actor_params=actor,
+        critic_params=critic,
+        target_actor_params=jax.tree.map(jnp.copy, actor),
+        target_critic_params=jax.tree.map(jnp.copy, critic),
+        actor_opt=_opt_init(actor),
+        critic_opt=_opt_init(critic),
+        step=jnp.zeros((), jnp.int32),
+        log_alpha=duals,
+        alpha_opt=_opt_init(duals),
+    )
+
+
 def init_train_state(config: DDPGConfig, obs_dim, act_dim: int, seed: int) -> TrainState:
     """Build initial params + hard-copied targets (SURVEY.md §3.4) + Adam
     state. CrossQ (config.crossq): batch-normalised nets and no targets,
@@ -327,6 +385,8 @@ def init_train_state(config: DDPGConfig, obs_dim, act_dim: int, seed: int) -> Tr
     # pool sizes its shared-memory layout with the same helper).
     from distributed_ddpg_tpu.actors.policy import actor_head_dim
 
+    if config.mpo:
+        return init_mpo_state(config, obs_dim, act_dim, k_actor, k_critic)
     if config.simba:
         actor_params = simba_init(
             k_actor, obs_dim, obs_dim, actor_head_dim(act_dim, True),
@@ -448,6 +508,13 @@ def make_learner_step(
         return step_noise(
             config, base_key, state.step, *batch.action.shape,
             None if axis_name is None else jax.lax.axis_index(axis_name),
+        )
+
+    def moved_target(online, target, step):
+        """The target net after the update of count `step`: Polyak's
+        average, or under config.target_update_period the whole copy."""
+        return target_update(
+            online, target, config.tau, step, config.target_update_period
         )
 
     def sac_step(state: TrainState, batch: Batch, noise=None) -> StepOutput:
@@ -615,14 +682,14 @@ def make_learner_step(
             # No target exists: the slots stay None and no Polyak pass runs.
             new_target_critic = new_target_actor = None
         else:
-            new_target_critic = polyak_update(
-                new_critic, state.target_critic_params, config.tau
+            new_target_critic = moved_target(
+                new_critic, state.target_critic_params, state.step
             )
             # SAC's math has no target actor; the slot still trails the actor
             # via the same polyak so the TrainState invariants (targets trail
             # params) and checkpoint shape stay uniform across families.
-            new_target_actor = polyak_update(
-                new_actor, state.target_actor_params, config.tau
+            new_target_actor = moved_target(
+                new_actor, state.target_actor_params, state.step
             )
         if config.policy_delay == 1:
             new_log_alpha, alpha_opt = temperature_adam(mean_lp)
@@ -675,6 +742,111 @@ def make_learner_step(
 
     if config.sac:
         return sac_step
+
+    def mpo_step(state: TrainState, batch: Batch, noise=None) -> StepOutput:
+        """DMPO (config.mpo; ops/losses.py's MPO section): no gradient
+        passes through the critic to the policy. E-step, no gradient:
+        mpo_samples actions a row from the TARGET policy at s', the TARGET
+        critic on all B x N (s', clipped action) rows, each distribution's
+        expectation, the samples' mixture, and the softmax weights at the
+        learned temperature (with the out-of-box penalty's beside them).
+        Critic: cross-entropy against the projected mixture at (s, a), the
+        ring's action mapped onto the canonical box. M-step: one forward of
+        the online policy at s' (jax.vjp), the decoupled weighted
+        likelihood with both KLs under their multipliers on its (mean,
+        scale), pulled back to the parameters. Duals: the four variables
+        (state.log_alpha's tree, floored at MPO_MIN_LOG_DUAL) on their own
+        loss under their own Adam (state.alpha_opt, config.dual_lr). Then
+        both nets' Adam and the targets (moved_target)."""
+        eps = own_noise(state, batch) if noise is None else noise
+        duals = jax.tree.map(
+            lambda x: jnp.maximum(x, losses.MPO_MIN_LOG_DUAL), state.log_alpha
+        )
+        next_obs = batch.next_obs
+        with device_scope("estep"):
+            actions, (mean_t, scale_t), probs, q = losses.mpo_estep(
+                state.target_actor_params, state.target_critic_params,
+                next_obs, eps, support, mm,
+            )
+            mixture = jnp.mean(probs, axis=1)
+            cost = losses.mpo_out_of_box_cost(actions)
+            dual_values = losses.mpo_dual_values(duals)
+            value_weights = losses.mpo_weights(q, dual_values["log_temperature"])
+            weights = value_weights + losses.mpo_weights(
+                cost, dual_values["log_penalty_temperature"]
+            )
+
+        with device_scope("critic"):
+            (closs, (td, edge_mass)), cgrads = jax.value_and_grad(
+                lambda cp: losses.mpo_critic_loss(
+                    cp, batch, (batch.action - offset) / scale, mixture,
+                    support, mm,
+                ),
+                has_aux=True,
+            )(state.critic_params)
+            cgrads = _maybe_psum_mean(cgrads, axis_name)
+
+        with device_scope("actor"):
+            (mean, std), to_params = jax.vjp(
+                lambda ap: gaussian_apply(ap, next_obs, mm), state.actor_params
+            )
+            (aloss, kls), head_grads = jax.value_and_grad(
+                lambda m, sd: losses.mpo_policy_loss(
+                    m, sd, mean_t, scale_t, actions, weights,
+                    dual_values["log_alpha_mean"], dual_values["log_alpha_stddev"],
+                ),
+                argnums=(0, 1), has_aux=True,
+            )(mean, std)
+            (agrads,) = to_params(head_grads)
+            agrads = _maybe_psum_mean(agrads, axis_name)
+            # every shard's multipliers must see the global batch's KLs
+            kl_mean, kl_std = _maybe_psum_mean(kls, axis_name)
+
+        with device_scope("duals"):
+            dgrads = jax.grad(losses.mpo_dual_loss)(
+                duals, q, cost, kl_mean, kl_std,
+                config.mpo_epsilon, config.mpo_epsilon_penalty,
+                config.mpo_epsilon_mean, config.mpo_epsilon_stddev,
+            )
+            dgrads = _maybe_psum_mean(dgrads, axis_name)
+            new_duals, dual_opt = adam_update(
+                duals, dgrads, state.alpha_opt, config.dual_lr, config.adam_b1
+            )
+        new_critic, critic_opt = adam_update(
+            state.critic_params, cgrads, state.critic_opt, config.critic_lr,
+            config.adam_b1, config.weight_decay,
+        )
+        new_actor, actor_opt = adam_update(
+            state.actor_params, agrads, state.actor_opt, config.actor_lr,
+            config.adam_b1, config.weight_decay,
+        )
+        metrics = dict(zip(keys, (
+            closs, aloss, jnp.mean(q), jnp.mean(jnp.abs(td)),
+            optree_norm(cgrads), optree_norm(agrads), edge_mass,
+            jnp.mean(1.0 / jnp.sum(jnp.square(value_weights), axis=1)),
+            jnp.mean(kl_mean) / config.mpo_epsilon_mean,
+            dual_values["log_temperature"][0],
+        )))
+        metrics = _maybe_psum_mean(metrics, axis_name)
+        new_state = TrainState(
+            actor_params=new_actor,
+            critic_params=new_critic,
+            target_actor_params=moved_target(
+                new_actor, state.target_actor_params, state.step
+            ),
+            target_critic_params=moved_target(
+                new_critic, state.target_critic_params, state.step
+            ),
+            actor_opt=actor_opt,
+            critic_opt=critic_opt,
+            step=state.step + 1,
+            log_alpha=new_duals,
+            alpha_opt=dual_opt,
+        )
+        return StepOutput(state=new_state, td_errors=td, metrics=metrics)
+
+    if config.mpo:
+        return mpo_step
 
     def shift(words, offsets):
         return pix.random_shift(words, offsets, config.aug_pad, obs)
@@ -976,8 +1148,8 @@ def make_learner_step(
             )
 
             # --- Polyak target updates, fused in (SURVEY.md §3.4) ---
-            new_target_actor = polyak_update(new_actor, state.target_actor_params, config.tau)
-            new_target_critic = polyak_update(new_critic, state.target_critic_params, config.tau)
+            new_target_actor = moved_target(new_actor, state.target_actor_params, state.step)
+            new_target_critic = moved_target(new_critic, state.target_critic_params, state.step)
 
         metrics = dict(
             zip(
@@ -1052,6 +1224,14 @@ def make_act_fn(config: DDPGConfig, action_scale, action_offset=0.0):
             lambda policy, obs: pixnet.policy_apply(policy, obs, scale, offset)
         )
 
+    if config.mpo:
+        # the Gaussian's mean, clipped to the canonical box and mapped on
+        return jax.jit(
+            lambda actor_params, obs: jnp.clip(
+                gaussian_apply(actor_params, obs)[0], -1.0, 1.0
+            ) * scale + offset
+        )
+
     if config.sac:
 
         @jax.jit
@@ -1071,12 +1251,23 @@ def make_act_fn(config: DDPGConfig, action_scale, action_offset=0.0):
 
 
 def make_sample_fn(config: DDPGConfig, action_scale, action_offset=0.0):
-    """Jitted stochastic SAC policy (exploration): a ~ pi(.|s)."""
+    """Jitted stochastic policy (exploration): a ~ pi(.|s), SAC's squashed
+    Gaussian or MPO's plain one clipped to the box."""
     from distributed_ddpg_tpu.models.mlp import actor_gaussian_apply
     from distributed_ddpg_tpu.ops import losses as losses_lib
 
     scale = jnp.asarray(action_scale, jnp.float32)
     offset = jnp.asarray(action_offset, jnp.float32)
+
+    if config.mpo:
+
+        @jax.jit
+        def sample_clipped(actor_params, obs, key):
+            mean, std = gaussian_apply(actor_params, obs)
+            draw = mean + std * jax.random.normal(key, mean.shape)
+            return jnp.clip(draw, -1.0, 1.0) * scale + offset
+
+        return sample_clipped
 
     @jax.jit
     def sample(actor_params, obs, key):

@@ -350,6 +350,62 @@ def fold_rsnorm(params):
     return (embed, *rest)
 
 
+# --- Acme's LayerNormMLP and its diagonal-Gaussian head (DMPO, arXiv
+# 2006.00979: acme/tf/networks LayerNormMLP, MultivariateNormalDiagHead) ---
+# A tuple of dicts again, of two kinds: params[1] is the LayerNorm behind the
+# first linear layer, {ln_scale, ln_shift} and nothing else (what tells this
+# form from the others: `is_lnmlp`), every other entry a {w, b} layer. The
+# net is linear -> LayerNorm -> tanh, then linear -> ELU for each further
+# width (the last one activated too), then the output layer. A policy's
+# output layer is [mean | scale_raw], the two linear heads of the source side
+# by side; a critic's takes [obs | action] at the first layer.
+LNMLP_EPS = 1e-5        # Sonnet's LayerNorm
+GAUSSIAN_INIT_SCALE = 0.7
+GAUSSIAN_MIN_SCALE = 1e-6
+
+
+def is_lnmlp(params) -> bool:
+    """Whether `params` is a LayerNormMLP (`lnmlp_init`)."""
+    return len(params) > 1 and set(params[1]) == {"ln_scale", "ln_shift"}
+
+
+def lnmlp_init(
+    key, in_dim: int, out_dim: int, hidden: Sequence[int], dtype=jnp.float32
+) -> Params:
+    """A LayerNormMLP on `in_dim` inputs with one layer per entry of
+    `hidden` and an output layer `out_dim` wide. Initialisers are this
+    tree's (mlp_init's)."""
+    first, *rest = mlp_init(key, [in_dim, *hidden, out_dim], dtype)
+    h = hidden[0]
+    ln = {"ln_scale": jnp.ones((h,), dtype), "ln_shift": jnp.zeros((h,), dtype)}
+    return (first, ln, *rest)
+
+
+def lnmlp_apply(params: Params, x, mm_dtype=None):
+    """The LayerNormMLP's output layer on `x`."""
+    x = jnp.tanh(_layer_norm(_dense(x, params[0], mm_dtype), params[1], LNMLP_EPS))
+    for layer in params[2:-1]:
+        x = jax.nn.elu(_dense(x, layer, mm_dtype))
+    return _dense(x, params[-1], mm_dtype)
+
+
+def gaussian_scale(raw):
+    """The head's scale from its raw half: init_scale * softplus(raw) /
+    softplus(0) + min_scale, the initial scale at raw 0."""
+    return (
+        GAUSSIAN_INIT_SCALE / math.log(2.0) * jax.nn.softplus(raw)
+        + GAUSSIAN_MIN_SCALE
+    )
+
+
+def gaussian_apply(params: Params, obs, mm_dtype=None):
+    """MPO's policy: (mean, scale) of a diagonal Gaussian over the CANONICAL
+    action box [-1, 1], no squashing: whoever acts on a draw clips it to the
+    box and maps it onto the environment's."""
+    mean, raw = jnp.split(lnmlp_apply(params, obs, mm_dtype), 2, axis=-1)
+    return mean, gaussian_scale(raw)
+
+
 def actor_apply(params: Params, obs, action_scale, action_offset=0.0, mm_dtype=None) -> Any:
     """mu(s): relu hiddens, tanh output mapped onto the action box
     [offset - scale, offset + scale] (offset != 0 for asymmetric spaces)."""
@@ -429,12 +485,16 @@ def critic_apply(
     which returns (Q, moments) for `norm_moved`; its batch is then every
     leading axis of `obs` (CrossQ's joint pass: [2, B, obs]). A residual net
     (`simba_init`) runs `simba_apply`, and with `resid` returns (Q, its
-    blocks' residual share row by row)."""
+    blocks' residual share row by row). A LayerNormMLP (`lnmlp_init`, DMPO's
+    categorical critic) takes [obs | action] at its input and returns its
+    logits."""
     if is_simba(params):
         if resid:
             q, share = simba_apply(params, obs, action, mm_dtype, resid=True)
             return jnp.squeeze(q, axis=-1), share
         return jnp.squeeze(simba_apply(params, obs, action, mm_dtype), axis=-1)
+    if is_lnmlp(params):
+        return lnmlp_apply(params, jnp.concatenate([obs, action], axis=-1), mm_dtype)
     x, moments = obs, []
     n = len(params)
     for i, layer in enumerate(params):

@@ -69,6 +69,14 @@ def rounds_inputs(config) -> bool:
     )
 
 
+def rounds_action(config) -> bool:
+    """Whether the action may leave the front rounded with the other two:
+    not under MPO, whose critic maps the ring's action onto the canonical box
+    (a subtraction and a division) in front of its first product: rounded
+    first and divided then, it would be rounded twice."""
+    return rounds_inputs(config) and not config.mpo
+
+
 def front_for(*, width: int, batch: int, layout: str, replay_sharded: bool,
               model_axis: int, native: bool) -> str:
     """'cut' or 'xla' for a scan-leg launch that gathers `batch` rows a chip
@@ -108,16 +116,21 @@ def _cut_kernel(obs_dim, act_dim, rows_ref, obs_ref, act_ref, nobs_ref,
 
 
 def cut_rows(packed, obs_dim: int, act_dim: int, rounded: bool,
-             interpret: bool | None = None) -> Batch:
+             interpret: bool | None = None,
+             action_rounded: bool | None = None) -> Batch:
     """`packed` f32[K, B, W] (a launch's gathered rows; B a multiple of 128)
     to the Batch `unpack_batch` cuts from it, in one pass: [K, B, d] float32
-    fields, obs, action and next_obs holding their bfloat16 rounding where
-    `rounded`. Interpreted off the TPU."""
+    fields, obs and next_obs holding their bfloat16 rounding where
+    `rounded`, the action where `action_rounded` (as `rounded` unless said:
+    rounds_action). Interpreted off the TPU."""
     K, B, W = packed.shape
     R = block_rows(B, W)
     nb = B // R
     wide = jnp.bfloat16 if rounded else jnp.float32
+    if action_rounded is None:
+        action_rounded = rounded
     dims = (obs_dim, act_dim, obs_dim)
+    dtypes = (wide, jnp.bfloat16 if action_rounded else jnp.float32, wide)
     with device_scope("cut"):
         obs_t, act_t, nobs_t, scal = pl.pallas_call(
             functools.partial(_cut_kernel, obs_dim, act_dim),
@@ -128,7 +141,7 @@ def cut_rows(packed, obs_dim: int, act_dim: int, rounded: bool,
                 for d in (*dims, 3)
             ],
             out_shape=[
-                *(jax.ShapeDtypeStruct((K, d, B), wide) for d in dims),
+                *(jax.ShapeDtypeStruct((K, d, B), t) for d, t in zip(dims, dtypes)),
                 jax.ShapeDtypeStruct((K, 3, B), jnp.float32),
             ],
             scratch_shapes=[pltpu.VMEM((_padded(W), R), jnp.float32)],

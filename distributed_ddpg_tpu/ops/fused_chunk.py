@@ -268,6 +268,10 @@ def supported(config: DDPGConfig) -> bool:
         and not (config.redq or config.crossq or config.simba)
         # DrQ-v2: no convolution in the kernel
         and not config.pixels
+        # DMPO: no pass on batch x samples rows, no dual variables; the
+        # kernel's targets are Polyak averages, never copies
+        and not config.mpo
+        and config.target_update_period == 0
         and config.adam_b1 == B1
         and config.weight_decay == 0.0
         and config.compute_dtype in ("float32", "bfloat16")

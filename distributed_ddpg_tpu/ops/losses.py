@@ -476,3 +476,150 @@ def distributional_actor_loss(
     logits = critic_apply(critic_params, batch.obs, action, action_insert_layer, mm_dtype)
     q = jnp.sum(jax.nn.softmax(logits, axis=-1) * support[None, :], axis=-1)
     return -jnp.mean(q)
+
+
+# ---------------------------------------------------------------------------
+# MPO on the categorical critic (DMPO: Acme, arXiv 2006.00979, agents/tf/dmpo
+# with tf/losses/mpo.py; the policy step arXiv 1806.06920, decoupled as in
+# 1812.02256)
+# ---------------------------------------------------------------------------
+# Actions here are CANONICAL: the policy's Gaussian lives on [-1, 1]^A, a
+# draw may leave the box, the critic reads it clipped, and the step maps the
+# ring's environment-unit actions in before it calls these.
+
+MPO_FLOAT_EPS = 1e-8
+MPO_MIN_LOG_DUAL = -18.0  # the source clips every dual variable from below
+MPO_DUALS = (
+    "log_temperature", "log_penalty_temperature",
+    "log_alpha_mean", "log_alpha_stddev",
+)
+
+
+def mpo_dual_values(duals):
+    """Each dual variable out of log space: softplus(x) + 1e-8."""
+    return jax.tree.map(lambda x: jax.nn.softplus(x) + MPO_FLOAT_EPS, duals)
+
+
+def mpo_estep(
+    target_actor_params, target_critic_params, next_obs, eps, support,
+    mm_dtype=None,
+):
+    """The E-step's forward passes, no gradient: N actions a row drawn from
+    the TARGET policy at s' with the standard normals `eps` f32[B, N, A],
+    and the TARGET critic on all B * N (s', clipped action) rows. Returns
+    (actions f32[B, N, A], unclipped; (mean', scale') of the target policy;
+    probs f32[B, N, atoms]; q f32[B, N], each distribution's expectation)."""
+    from distributed_ddpg_tpu.models.mlp import gaussian_apply
+
+    mean_t, scale_t = gaussian_apply(target_actor_params, next_obs, mm_dtype)
+    actions = mean_t[:, None, :] + scale_t[:, None, :] * eps
+    b, n, a = actions.shape
+    logits = critic_apply(
+        target_critic_params,
+        jnp.repeat(next_obs, n, axis=0),
+        jnp.clip(actions, -1.0, 1.0).reshape(b * n, a),
+        0, mm_dtype,
+    )
+    probs = jax.nn.softmax(logits, axis=-1).reshape(b, n, -1)
+    return actions, (mean_t, scale_t), probs, jnp.sum(probs * support, axis=-1)
+
+
+def mpo_out_of_box_cost(actions):
+    """The action penalty's value of each drawn action: minus its distance
+    from the box, 0 inside."""
+    return -jnp.linalg.norm(actions - jnp.clip(actions, -1.0, 1.0), axis=-1)
+
+
+def mpo_weights(values, temperature):
+    """softmax over a row's N samples of values / temperature (f32[1]):
+    f32[B, N]."""
+    return jax.nn.softmax(values / temperature, axis=1)
+
+
+def mpo_temperature_loss(values, epsilon: float, temperature):
+    """The dual of the E-step's KL bound: eta * (epsilon + mean_B
+    logsumexp_j(values / eta) - log N), eta f32[1]."""
+    lse = jax.nn.logsumexp(values / temperature, axis=1)
+    return jnp.sum(
+        temperature * (epsilon + jnp.mean(lse) - jnp.log(values.shape[1]))
+    )
+
+
+def mpo_critic_loss(
+    critic_params, batch: Batch, action, target_probs, support, mm_dtype=None,
+):
+    """distributional_critic_loss against the MIXTURE of the samples'
+    distributions, `target_probs` f32[B, atoms], on (s, `action`): returns
+    (loss, (td_error_proxy[B], edge_mass)) as it does."""
+    proj = jax.lax.stop_gradient(
+        categorical_projection(support, target_probs, batch.reward, batch.discount)
+    )
+    logits = critic_apply(critic_params, batch.obs, action, 0, mm_dtype)
+    ce = -jnp.sum(proj * jax.nn.log_softmax(logits, axis=-1), axis=-1)
+    mean_q = jnp.sum(jax.nn.softmax(logits, axis=-1) * support[None, :], axis=-1)
+    mean_target = jnp.sum(proj * support[None, :], axis=-1)
+    return jnp.mean(batch.weight * ce), (
+        mean_target - mean_q, jnp.mean(proj[:, 0] + proj[:, -1])
+    )
+
+
+def _gaussian_log_prob(actions, mean, scale):
+    """log N(a_j; mean, scale) summed over the action's dimensions:
+    actions f32[B, N, A], mean and scale f32[B, A] -> f32[B, N]."""
+    z = (actions - mean[:, None, :]) / scale[:, None, :]
+    return jnp.sum(
+        -0.5 * jnp.square(z) - jnp.log(scale)[:, None, :]
+        - 0.5 * jnp.log(2.0 * jnp.pi),
+        axis=-1,
+    )
+
+
+def mpo_kls(mean, scale, mean_t, scale_t):
+    """The decoupled M-step's two KLs from the target policy, each the
+    batch mean per action dimension, f32[A]: KL(N(mu', s') || N(mu, s')),
+    which only the mean moves, and KL(N(mu', s') || N(mu', s)), which only
+    the scale does."""
+    kl_mean = 0.5 * jnp.square((mean_t - mean) / scale_t)
+    kl_std = (
+        jnp.log(scale / scale_t)
+        + 0.5 * jnp.square(scale_t / scale) - 0.5
+    )
+    return jnp.mean(kl_mean, axis=0), jnp.mean(kl_std, axis=0)
+
+
+def mpo_policy_loss(
+    mean, scale, mean_t, scale_t, actions, weights, alpha_mean, alpha_stddev,
+):
+    """The decoupled M-step on the online head's (mean, scale): weighted
+    maximum likelihood of the drawn actions under N(mean, scale') and under
+    N(mean', scale), plus each KL times its multiplier (held fixed here:
+    the dual loss moves it). Returns (loss, (kl_mean[A], kl_std[A]))."""
+    weights = jax.lax.stop_gradient(weights)
+    fit_mean = -jnp.mean(
+        jnp.sum(weights * _gaussian_log_prob(actions, mean, scale_t), axis=1)
+    )
+    fit_std = -jnp.mean(
+        jnp.sum(weights * _gaussian_log_prob(actions, mean_t, scale), axis=1)
+    )
+    kl_mean, kl_std = mpo_kls(mean, scale, mean_t, scale_t)
+    penalty = jnp.sum(jax.lax.stop_gradient(alpha_mean) * kl_mean) + jnp.sum(
+        jax.lax.stop_gradient(alpha_stddev) * kl_std
+    )
+    return fit_mean + fit_std + penalty, (kl_mean, kl_std)
+
+
+def mpo_dual_loss(
+    duals, q, cost, kl_mean, kl_std, epsilon: float, epsilon_penalty: float,
+    epsilon_mean: float, epsilon_stddev: float,
+):
+    """What the four dual variables descend: both temperatures' losses and
+    sum_dim alpha * (epsilon - KL) for the mean's and the scale's bound,
+    values and KLs held fixed."""
+    d = mpo_dual_values(duals)
+    q, cost, kl_mean, kl_std = jax.lax.stop_gradient((q, cost, kl_mean, kl_std))
+    return (
+        mpo_temperature_loss(q, epsilon, d["log_temperature"])
+        + mpo_temperature_loss(cost, epsilon_penalty, d["log_penalty_temperature"])
+        + jnp.sum(d["log_alpha_mean"] * (epsilon_mean - kl_mean))
+        + jnp.sum(d["log_alpha_stddev"] * (epsilon_stddev - kl_std))
+    )

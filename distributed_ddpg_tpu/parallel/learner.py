@@ -568,6 +568,7 @@ class ShardedLearner:
             cut = partial(
                 front_lib.cut_rows, obs_dim=obs_dim, act_dim=act_dim,
                 rounded=front_lib.rounds_inputs(config),
+                action_rounded=front_lib.rounds_action(config),
             )
             if n_shards == 1:
                 return cut(packed)
@@ -1072,11 +1073,11 @@ class ShardedLearner:
             # chunk and pmeans at the boundary like every other float leaf.
             extra = {}
             if new_s.log_alpha is not None:
-                extra["log_alpha"] = avg(new_s.log_alpha)
+                extra["log_alpha"] = favg(new_s.log_alpha)  # a scalar or a tree
             if new_s.alpha_opt is not None:
                 extra["alpha_opt"] = OptState(
-                    mu=avg(new_s.alpha_opt.mu),
-                    nu=avg(new_s.alpha_opt.nu),
+                    mu=favg(new_s.alpha_opt.mu),
+                    nu=favg(new_s.alpha_opt.nu),
                     count=new_s.alpha_opt.count,
                 )
             new_s = TrainState(
@@ -1530,12 +1531,23 @@ def program_specs():
         tau=0.01, target_noise_clip=0.3,
     )
 
+    # DMPO's chunk (the E-step's draws pre-drawn [K, B, N, act], the target
+    # critic on B x N rows, the mixture target, the decoupled M-step, the
+    # dual variables' tree under its own Adam, the targets copied every
+    # second update), as the benchmark's cell runs it.
+    MPO = dict(
+        mpo=True, distributional=True, num_atoms=11, n_step=5,
+        action_insert_layer=0, mpo_samples=3, actor_hidden=(16, 16),
+        critic_hidden=(16, 16), target_update_period=2,
+    )
+
     def learner(
         guard: bool = False, sharded: bool = False, tp: bool = False,
         ensemble: bool = False, mode: str = "auto", crossq: bool = False,
         pql: bool = False, simba: bool = False, pixels: bool = False,
+        mpo: bool = False,
     ) -> ShardedLearner:
-        key = (guard, sharded, tp, ensemble, mode, crossq, pql, simba, pixels)
+        key = (guard, sharded, tp, ensemble, mode, crossq, pql, simba, pixels, mpo)
         if key not in cache:
             cache[key] = ShardedLearner(
                 probe_config(
@@ -1545,6 +1557,7 @@ def program_specs():
                     **(PQL if pql else {}),
                     **(SIMBA if simba else {}),
                     **(PIXELS if pixels else {}),
+                    **(MPO if mpo else {}),
                 ),
                 obs_dim=ObsSpec((3, 16, 16), "uint8") if pixels else 3,
                 act_dim=1,
@@ -1703,6 +1716,12 @@ def program_specs():
         ProgramSpec(
             "learner.chunk.uniform.pixels", OWNER,
             uniform(False, sharded=False, pixels=True),
+        )
+    )
+    specs.append(
+        ProgramSpec(
+            "learner.chunk.uniform.mpo", OWNER,
+            uniform(False, sharded=False, mpo=True),
         )
     )
     return specs

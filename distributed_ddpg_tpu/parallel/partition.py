@@ -63,7 +63,7 @@ from typing import Optional, Sequence, Tuple
 import jax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from distributed_ddpg_tpu.models.mlp import is_simba
+from distributed_ddpg_tpu.models.mlp import is_lnmlp, is_simba
 from distributed_ddpg_tpu.models.pixels import is_pixel
 from distributed_ddpg_tpu.types import OptState, TrainState
 
@@ -108,6 +108,18 @@ SIMBA_RULES: Tuple[Rule, ...] = (
     (r"(^|/)\d+/(b2|b)$", P(None)),
     (r"(^|/)\d+/w$", P(None, None)),
 )
+
+
+def lnmlp_rules(num_entries: int) -> Tuple[Rule, ...]:
+    """A LayerNormMLP (models/mlp.lnmlp_init): the first layer feeds a
+    LayerNorm over its whole output, so it and the LayerNorm's two vectors
+    replicate; the dense layers behind follow the MLP table by their index
+    in the tuple (the LayerNorm is entry 1, so they start column-parallel at
+    entry 2), the output layer replicated as every net's."""
+    return (
+        (r"(^|/)0/(w|b)$", P(None)),
+        (r"(^|/)1/(ln_scale|ln_shift)$", P(None)),
+    ) + mlp_rules(num_entries)
 
 
 def pixel_rules(params) -> Tuple[Rule, ...]:
@@ -194,13 +206,17 @@ def match_partition_rules(rules: Sequence[Rule], tree, model_size: int):
 
 def net_pspec(params, model_size: int, rules: Optional[Sequence[Rule]] = None):
     """Spec tree for one {w, b}-layer param list. Default rules are the
-    per-depth MLP table (mlp_rules), or SIMBA_RULES for a residual net;
-    pass `rules` for any other."""
+    per-depth MLP table (mlp_rules), SIMBA_RULES for a residual net, or
+    lnmlp_rules for a LayerNormMLP; pass `rules` for any other."""
     if rules is None:
         if is_pixel(params):
             rules = pixel_rules(params)
+        elif is_simba(params):
+            rules = SIMBA_RULES
+        elif is_lnmlp(params):
+            rules = lnmlp_rules(len(params))
         else:
-            rules = SIMBA_RULES if is_simba(params) else mlp_rules(len(params))
+            rules = mlp_rules(len(params))
     return match_partition_rules(rules, params, model_size)
 
 
@@ -231,12 +247,13 @@ def state_pspec(
         actor_opt=OptState(mu=actor, nu=actor, count=P()),
         critic_opt=OptState(mu=critic, nu=critic, count=P()),
         step=P(),
-        # SAC temperature scalars replicate; None (non-SAC) is an empty
-        # pytree node and needs no spec.
-        log_alpha=None if state.log_alpha is None else P(),
+        # SAC's temperature scalar, or MPO's small tree of dual variables,
+        # replicates leaf by leaf, and Adam's moments with it; None
+        # (neither family) is an empty pytree node and needs no spec.
+        log_alpha=(duals := jax.tree.map(lambda _: P(), state.log_alpha)),
         alpha_opt=(
             None
             if state.alpha_opt is None
-            else OptState(mu=P(), nu=P(), count=P())
+            else OptState(mu=duals, nu=duals, count=P())
         ),
     )

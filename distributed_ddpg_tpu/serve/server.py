@@ -43,7 +43,11 @@ from typing import Optional, Tuple
 
 import numpy as np
 
-from distributed_ddpg_tpu.actors.policy import NumpyPolicy, layout_size
+from distributed_ddpg_tpu.actors.policy import (
+    NumpyPolicy,
+    gaussian_scale,
+    layout_size,
+)
 from distributed_ddpg_tpu.metrics import ServeStats
 from distributed_ddpg_tpu.serve.batcher import Batcher
 
@@ -73,6 +77,7 @@ class InferenceServer:
         sac: bool = False,
         log_std_min: float = -5.0,
         log_std_max: float = 2.0,
+        squash: bool = True,
     ):
         if backend not in ("numpy", "jax"):
             raise ValueError(f"serve backend must be 'numpy' or 'jax', got {backend!r}")
@@ -100,7 +105,11 @@ class InferenceServer:
         # exploration stream keyed by (seed, tenant, request_id), so the
         # sampling RNG lives server-side without any cross-client
         # coupling (docs/SERVING.md 'SAC serve head').
+        # `squash=False` (with `sac`): MPO's head, [mean | log scale] of a
+        # plain Gaussian on the canonical box (models/mlp.gaussian_apply);
+        # `sample()` clips the draw to the box where SAC's squashes it.
         self.sac = bool(sac)
+        self.squash = bool(squash)
         if self.sac and self.head_dim % 2:
             raise ValueError(
                 "SAC head layout must be [mean | log_std] (even width); "
@@ -226,9 +235,12 @@ class InferenceServer:
         backends agree on the distribution `sample()` draws from."""
         raw = self._policy.head(row)
         mean, log_std_raw = np.split(raw, 2, axis=-1)
-        log_std = self.log_std_min + 0.5 * (
-            self.log_std_max - self.log_std_min
-        ) * (np.tanh(log_std_raw) + 1.0)
+        if self.squash:
+            log_std = self.log_std_min + 0.5 * (
+                self.log_std_max - self.log_std_min
+            ) * (np.tanh(log_std_raw) + 1.0)
+        else:  # MPO's head: the softplus scale, no clamp
+            log_std = np.log(gaussian_scale(log_std_raw))
         return np.concatenate([mean, log_std], axis=-1).astype(
             np.float32, copy=False
         )
@@ -259,8 +271,9 @@ class InferenceServer:
             u = mean + np.exp(log_std) * eps
         else:
             u = mean
+        onto_box = np.tanh(u) if self.squash else np.clip(u, -1.0, 1.0)
         return (
-            np.tanh(u) * self._policy.scale + self._policy.offset
+            onto_box * self._policy.scale + self._policy.offset
         ).astype(np.float32)
 
     def _build_jax_apply(self) -> None:
@@ -274,9 +287,16 @@ class InferenceServer:
         from distributed_ddpg_tpu.models.mlp import (
             actor_apply,
             actor_gaussian_apply,
+            gaussian_apply,
         )
 
-        if self.sac:
+        if self.sac and not self.squash:
+            import jax.numpy as jnp
+
+            def apply(params, obs):
+                mean, std = gaussian_apply(params, obs)
+                return jnp.concatenate([mean, jnp.log(std)], axis=-1)
+        elif self.sac:
             # Head rows out, same [mean | log_std] contract as the numpy
             # path; sampling stays host-side in sample() (per-client
             # keys are a host concern, not a device one).

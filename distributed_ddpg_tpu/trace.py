@@ -460,6 +460,8 @@ CHUNK_SCOPES = (
     "update",         # the K updates: the lax.scan, or the pallas_call
     "update/augment", # DrQ-v2's random shift: unpack, crop, the conversion to float
     "update/encoder", # its convolutional encoder: both forward passes and the backward
+    "update/estep",   # MPO's E-step: target policy, draws, target critic on batch x samples rows, weights
+    "update/estep/lnorm",    # the LayerNorms of its two target nets
     "update/critic",  # critic loss, forward and backward
     "update/critic/norm",  # its batch norm: moments, normalising, running step
     "update/critic/lnorm",   # a residual critic's LayerNorms, forward and backward
@@ -468,6 +470,8 @@ CHUNK_SCOPES = (
     "update/actor/norm",   # its own batch norm, and the critics' under it
     "update/actor/lnorm",    # a residual actor's LayerNorms, and the critics' under it
     "update/actor/rsnorm",   # its input normaliser, and the critics' under it
+    "update/duals",   # MPO's dual variables: their loss and its gradient
+    "update/duals/optim",    # their own Adam
     "update/optim",   # Adam
     "update/polyak",  # target updates
     "metrics",        # the chunk's metrics out of the K updates'

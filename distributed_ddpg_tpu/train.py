@@ -373,9 +373,10 @@ def _train_jax_impl(
         setup.add("setup_backend", time.perf_counter() - entered_at)
     setup.stage("setup_import")
     from distributed_ddpg_tpu import checkpoint as ckpt_lib
-    from distributed_ddpg_tpu.actors.policy import NumpyPolicy, actor_head_dim, flatten_params, param_layout
+    from distributed_ddpg_tpu.actors.policy import NumpyPolicy, flatten_params, layout_of
     from distributed_ddpg_tpu.actors.pool import ActorPool
     from distributed_ddpg_tpu.learner import delayed_updates, make_act_fn
+    from distributed_ddpg_tpu.ops.polyak import target_copies
     from distributed_ddpg_tpu.parallel import multihost
     from distributed_ddpg_tpu.parallel.learner import (
         ShardedLearner,
@@ -1096,9 +1097,10 @@ def _train_jax_impl(
             # [mean | log_std] rows and each client's action is sampled
             # server-side with a (seed, tenant, request_id) key —
             # serve_actors + sac is a supported pairing since PR 20.
-            sac=config.sac,
+            sac=config.gaussian_head,
             log_std_min=config.sac_log_std_min,
             log_std_max=config.sac_log_std_max,
+            squash=not config.mpo,
         ).start()
         serve_front = ServeFront(
             serve_server, *pool.serve_channels()
@@ -1128,9 +1130,10 @@ def _train_jax_impl(
                 max_queue=config.serve_queue,
                 backend=config.serve_backend,
                 seed=config.seed,
-                sac=config.sac,
+                sac=config.gaussian_head,
                 log_std_min=config.sac_log_std_min,
                 log_std_max=config.sac_log_std_max,
+                squash=not config.mpo,
             )
 
         try:
@@ -1231,6 +1234,14 @@ def _train_jax_impl(
         if config.crossq:
             # CrossQ runs only, and from the state: no target net is held.
             facts["crossq"] = learner.state.target_critic_params is None
+        if config.mpo:
+            # MPO runs only: the samples a state, how many dual values the
+            # state holds (2 + 2 x dim(A)), and how the targets move.
+            facts["mpo_samples"] = config.mpo_samples
+            facts["mpo_duals"] = sum(
+                int(np.size(x)) for x in jax.tree.leaves(learner.state.log_alpha)
+            )
+            facts["target_update_period"] = config.target_update_period
         if config.simba:
             # Residual runs only, from the state's own shapes: blocks and
             # stream width of each net, and the decay its optimiser applies.
@@ -1350,15 +1361,11 @@ def _train_jax_impl(
                 pixel_act(host_params, np.asarray(obs)[None])
             )
         policy = NumpyPolicy(
-            param_layout(
-                spec.obs_dim,
-                actor_head_dim(spec.act_dim, config.sac),
-                tuple(config.actor_hidden),
-                residual=config.simba,
-            ),
+            layout_of(config, spec.obs_dim, spec.act_dim),
             spec.action_scale,
             spec.action_offset,
-            gaussian=config.sac,
+            gaussian=config.gaussian_head,
+            squash=not config.mpo,
         )
         policy.load_flat(flatten_params(host_params))
         return policy
@@ -1513,7 +1520,13 @@ def _train_jax_impl(
         count the branch already carries (learner.delayed_updates, the rule
         the step's cond, the kernel's schedule and the actor's Adam count
         follow): no update pays for it. No other family's records have
-        either key."""
+        either key. A run whose targets are copied whole
+        (config.target_update_period) has `target_copies` besides, the
+        copies so far by the same arithmetic (ops/polyak.target_copies)."""
+        copies = (
+            {"target_copies": target_copies(learn_steps, config.target_update_period)}
+            if config.target_update_period else {}
+        )
         if config.twin_critic:
             key = "td3_actor_updates"
         elif config.redq:
@@ -1521,8 +1534,8 @@ def _train_jax_impl(
         elif config.crossq and config.policy_delay > 1:
             key = "crossq_policy_updates"
         else:
-            return {}
-        return {key: delayed_updates(learn_steps, config.policy_delay)}
+            return copies
+        return {key: delayed_updates(learn_steps, config.policy_delay), **copies}
 
     mesh_stats = MeshStats(
         learner.mesh.shape["data"], learner.mesh.shape["model"]

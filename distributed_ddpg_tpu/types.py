@@ -39,11 +39,16 @@ class OptState(NamedTuple):
 class TrainState(NamedTuple):
     """Everything owned by the learner, as one donated pytree.
 
-    log_alpha/alpha_opt exist only for the SAC family (learned entropy
-    temperature). They default to None — which JAX treats as an EMPTY
-    pytree node — so every non-SAC TrainState keeps its exact historical
-    leaf structure: checkpoints, sharding-spec trees, and tree.maps all
-    compose unchanged."""
+    log_alpha/alpha_opt hold the learned scalars beside the nets, for the
+    two families that have any. SAC: the entropy temperature, log_alpha one
+    f32 scalar and alpha_opt its Adam (under sac_autotune). MPO
+    (config.mpo): the four dual variables as a small tree, log_alpha a dict
+    of `log_temperature` and `log_penalty_temperature` (f32[1]) and
+    `log_alpha_mean` and `log_alpha_stddev` (f32[act_dim]), and alpha_opt
+    the OptState of an Adam at its own rate (config.dual_lr) over that tree.
+    They default to None — which JAX treats as an EMPTY pytree node — so
+    every other TrainState keeps its exact historical leaf structure:
+    checkpoints, sharding-spec trees, and tree.maps all compose unchanged."""
 
     actor_params: Any
     critic_params: Any
@@ -52,8 +57,8 @@ class TrainState(NamedTuple):
     actor_opt: OptState
     critic_opt: OptState
     step: Any         # i32
-    log_alpha: Any = None   # f32 scalar (SAC only)
-    alpha_opt: Any = None   # OptState over log_alpha (SAC autotune only)
+    log_alpha: Any = None   # SAC: f32 scalar; MPO: the dual variables' tree
+    alpha_opt: Any = None   # OptState over log_alpha (SAC autotune, MPO)
 
 
 class ObsSpec(NamedTuple):

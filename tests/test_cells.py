@@ -41,6 +41,7 @@ RING_LAYOUT = {
     "pql-isaac-humanoid": "row_major",
     "simba-humanoid": "row_major",
     "drqv2-humanoid": "row_major",
+    "dmpo-humanoid": "row_major",
 }
 
 

@@ -48,7 +48,10 @@ def specs():
 def test_the_file_names_the_parents_whole_registry(specs):
     assert set(HELD) <= set(AT_PARENT) and len(AT_PARENT) == 50
     # what this PR registered beside them, and nothing it took away
-    assert set(specs) - set(AT_PARENT) == {"learner.chunk.uniform.pixels", "devactor.rollout.pixels"}
+    assert set(specs) - set(AT_PARENT) == {
+        "learner.chunk.uniform.pixels", "devactor.rollout.pixels",  # PR 47
+        "learner.chunk.uniform.mpo",  # PR 51: DMPO's chunk; the texts below stand with it registered
+    }
     assert set(AT_PARENT) <= set(specs)
 
 

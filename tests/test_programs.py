@@ -80,9 +80,11 @@ def _owned_by(module):
 # learner's eighteenth (the pixel chunk) 64 -> 75 (44.6 beside three).
 # The four budgets of programs PR 47 left alone stand as they were, though
 # that day's sandbox read some of them at their edge on parent and change
-# alike (CHANGES.md, PR 47).
+# alike (CHANGES.md, PR 47). PR 51: 204 s, the learner's nineteenth program
+# (DMPO's chunk: two LayerNormMLPs, the E-step on batch x samples rows, a
+# third Adam over the dual tree, traced under three seeds) 75 -> 82.
 _CPU_BUDGET_S = {
-    "distributed_ddpg_tpu.parallel.learner": 75.0,     # 49.4; 43.7
+    "distributed_ddpg_tpu.parallel.learner": 82.0,     # 49.4; 43.7
     "distributed_ddpg_tpu.parallel.megastep": 36.0,    # 23.0; 27.4
     "distributed_ddpg_tpu.parallel.superstep": 55.0,   # 30.3; 42.0
     "distributed_ddpg_tpu.replay.device": 5.0,         # 3.1; 3.7
@@ -112,7 +114,7 @@ def test_every_program_has_a_golden_and_no_golden_outlives_its_program():
     names = {s.name for s in prog_lib.default_specs()}
     assert names == {p.stem for p in GOLDEN.glob("*.json")}
     assert set(_CPU_BUDGET_S) == set(prog_lib.SPEC_MODULES)
-    assert sum(_CPU_BUDGET_S.values()) == 197.0
+    assert sum(_CPU_BUDGET_S.values()) == 204.0
     assert len(names) >= 18
 
 
