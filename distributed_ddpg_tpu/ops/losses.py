@@ -397,8 +397,21 @@ def categorical_projection(support, target_probs, rewards, discounts):
     """Project the shifted/scaled target distribution back onto `support`.
 
     support: f32[A]; target_probs: f32[B, A]; rewards, discounts: f32[B].
-    Returns f32[B, A]. Standard C51 projection (vectorized, no Python loops —
-    traces to gathers/scatters XLA handles natively).
+    Returns f32[B, A], float32 throughout. Standard C51 projection: source
+    atom a's mass goes to the two atoms round its Bellman-updated position.
+
+    The mass is placed by comparison, not by indexing: a source atom's two
+    destinations are the masks `index == arange(A)`, and the masked masses
+    are summed over the source atoms. That is elementwise work and a
+    reduction over [B, A, A], which the TPU's compiler keeps inside two loop
+    fusions that read [B, A] operands: 1.0 us an update at [256, 51] in the
+    DMPO cell's scan body. Indexing a one-hot table (`jnp.eye(A)[lo]`, this
+    function until PR 52) compiled there to two gathers of B*A rows out of
+    the A x A table, 17 us each, with a copy (4.5 us) and a reshape (14 us)
+    of the pred[B*A, A] block behind each and a sum that read the block back:
+    81.7 of that update's 213 us (PERF.md §5, §6, PR 52). No `dot_general`
+    either: a dot of float32 operands runs in one bfloat16 pass on the TPU
+    and would round the probabilities to eight bits.
     """
     v_min, v_max = support[0], support[-1]
     num_atoms = support.shape[0]
@@ -414,12 +427,13 @@ def categorical_projection(support, target_probs, rewards, discounts):
     eq = (upper == lower).astype(target_probs.dtype)
     w_lower = (upper - b) + eq            # mass to the lower atom
     w_upper = b - lower
-    lo = lower.astype(jnp.int32)
-    up = upper.astype(jnp.int32)
-    onehot = jnp.eye(num_atoms, dtype=target_probs.dtype)
-    proj = jnp.einsum("ba,ba,baj->bj", target_probs, w_lower, onehot[lo])
-    proj = proj + jnp.einsum("ba,ba,baj->bj", target_probs, w_upper, onehot[up])
-    return proj
+    # [B, A source, A destination] masks. The division can leave b a rounding
+    # above A-1 and its ceil at A: the top atom's, as the gather clamped it.
+    atoms = jnp.arange(num_atoms, dtype=jnp.int32)
+    lo = lower.astype(jnp.int32)[:, :, None] == atoms
+    up = jnp.minimum(upper.astype(jnp.int32), num_atoms - 1)[:, :, None] == atoms
+    proj = jnp.where(lo, (target_probs * w_lower)[:, :, None], 0.0).sum(axis=1)
+    return proj + jnp.where(up, (target_probs * w_upper)[:, :, None], 0.0).sum(axis=1)
 
 
 def distributional_critic_loss(

@@ -1178,6 +1178,14 @@ class ShardedLearner:
         None on the kernel leg and before any launch."""
         return self._scan_body_fact("copies")
 
+    def chunk_body_gathers(self) -> Optional[int]:
+        """The run fact `chunk_body_gathers`: the gathers one trip of the
+        launched scan chunk's loop issues, `gather` instructions and the
+        fusions that hold one (the table's `gathers`). The ring's own row
+        gather stands in front of the loop and is not among them. None on
+        the kernel leg and before any launch."""
+        return self._scan_body_fact("gathers")
+
     def _scan_body_fact(self, key: str):
         table = None if self.fused_chunk_active else self.chunk_ops()
         return None if table is None else table[key]

@@ -580,7 +580,7 @@ def _array_bytes(shape: str) -> int:
 
 def _instructions(hlo_text: str):
     """([(name, opcode, scope, in the entry computation?)], fused, carriers,
-    scalars, copies) of a compiled module's text. The list holds every
+    scalars, copies, gathers) of a compiled module's text. The list holds every
     instruction that runs as an operation of its own: the entry computation's, `while`
     bodies' and conditions', `conditional` branches' and `call` targets'. What is
     inlined into another instruction (a fusion's body, a reducer) is left
@@ -633,11 +633,16 @@ def _instructions(hlo_text: str):
     `transpose` and `reshape` instructions, and the fusions whose root is
     one or that hold nothing else, each with its result's bytes. The TPU's
     `copy-start` / `copy-done` pairs move an array between memory spaces
-    in its layout and are not among them."""
+    in its layout and are not among them.
+
+    `gathers` is {`while` instruction: its body's `gather` instructions and
+    fusions that hold one}: the TPU's compiler runs a gather it cannot turn
+    into slices as a fusion of its own (`kind=kCustom`, the gather its
+    body's root)."""
     found, inlined, runs, computation, entry = [], set(), {}, "", False
     loop_bodies, arithmetic = {}, {}  # while -> its body; computation -> count
     roots, held = {}, {}  # computation -> its root's opcode; -> its opcodes
-    moved = {}  # computation -> [(fusion's body or None, bytes)], candidates
+    moved = {}  # computation -> [(opcode, fusion's body or None, bytes)], candidates
     bodies, within = {}, {}  # fusion -> its body; body -> {scope: instructions}
     operands, nameless = {}, set()  # instruction -> its operands; no op_name
     for line in hlo_text.splitlines():
@@ -664,9 +669,11 @@ def _instructions(hlo_text: str):
         if line.lstrip().startswith("ROOT "):
             roots[computation] = opcode
         held.setdefault(computation, set()).add(opcode)
-        if opcode in _RELAYOUT or opcode == "fusion":
+        if opcode in _RELAYOUT or opcode in ("fusion", "gather"):  # what `copies` and `gathers` choose from
             callee = _FUSED.search(rest).group(1) if opcode == "fusion" else None
-            moved.setdefault(computation, []).append((callee, _array_bytes(shape)))
+            moved.setdefault(computation, []).append(
+                (opcode, callee, _array_bytes(shape))
+            )
         if opcode == "while":
             loop_bodies[name] = _BODY.search(rest).group(1)
         if opcode != "call":
@@ -747,14 +754,19 @@ def _instructions(hlo_text: str):
     scalars = {
         loop: arithmetic.get(body, 0) for loop, body in loop_bodies.items()
     }
-    copies = {}
+    copies, gathers = {}, {}
     for loop, body in loop_bodies.items():
         sizes = [
-            size for callee, size in moved.get(body, ())
-            if callee is None or _is_relayout(roots.get(callee), held.get(callee, ()))
+            size for opcode, callee, size in moved.get(body, ())
+            if opcode in _RELAYOUT
+            or callee and _is_relayout(roots.get(callee), held.get(callee, ()))
         ]
         copies[loop] = (len(sizes), sum(sizes))
-    return instructions, fused, carriers, scalars, copies
+        gathers[loop] = sum(
+            opcode == "gather" or "gather" in held.get(callee, ())
+            for opcode, callee, _ in moved.get(body, ())
+        )
+    return instructions, fused, carriers, scalars, copies, gathers
 
 
 def _operands(rest: str):
@@ -810,7 +822,7 @@ def op_scopes(hlo_text: str) -> Dict[str, str]:
     (`compiled.as_text()`): the scope `_instructions` reads; COLLECTIVE for
     a collective instruction, whatever its path (`chunk_ops_table` keeps
     that as `served`); an instruction under no bracket is absent."""
-    instructions, _, carriers, _, _ = _instructions(hlo_text)
+    instructions, _, carriers, *_ = _instructions(hlo_text)
     return _scopes(instructions, _collectives(instructions, carriers))
 
 
@@ -835,9 +847,12 @@ def chunk_ops_table(hlo_text: str) -> Dict[str, Any]:
     `transpose` and `reshape` instructions and of the fusions that only move
     an array, and the `bytes` of their results (an array handed from one
     layer to the next in a layout the next cannot read is paid for here:
-    PERF.md §6, PR 50)."""
+    PERF.md §6, PR 50); and `gathers`, the `gather` instructions and the
+    fusions that hold one in those bodies, a trip of each (a table indexed
+    inside the loop is an operation of its own and a relayout behind it:
+    PERF.md §6, PR 52)."""
     module = re.match(r"HloModule ([\w.\-]+)", hlo_text)
-    instructions, fused, carriers, scalars, copies = _instructions(hlo_text)
+    instructions, fused, carriers, scalars, copies, gathers = _instructions(hlo_text)
     collectives = _collectives(instructions, carriers)
     return {
         "module": module.group(1) if module else "",
@@ -861,4 +876,5 @@ def chunk_ops_table(hlo_text: str) -> Dict[str, Any]:
             "count": sum(count for count, _ in copies.values()),
             "bytes": sum(size for _, size in copies.values()),
         },
+        "gathers": sum(gathers.values()),
     }

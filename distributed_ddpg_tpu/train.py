@@ -1217,6 +1217,9 @@ def _train_jax_impl(
             # array, their count and their results' bytes (the table's
             # `copies`); null where `chunk_body_scalars` is.
             "chunk_body_copies": learner.chunk_body_copies(),
+            # And as gathers: the `gather` instructions and the fusions that
+            # hold one (the table's `gathers`); null where the two above are.
+            "chunk_body_gathers": learner.chunk_body_gathers(),
             "state_devices": min(
                 len(leaf.sharding.device_set)
                 for leaf in jax.tree.leaves(learner.state)

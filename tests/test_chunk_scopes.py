@@ -201,6 +201,45 @@ def test_the_table_counts_the_loop_bodys_relayouts_and_their_bytes():
     assert trace.chunk_ops_table(ASYNC_HLO)["copies"] == {"count": 0, "bytes": 0}
 
 
+def test_the_table_counts_the_loop_bodys_gathers():
+    """`gathers`: the `gather` instructions a `while` body issues and its
+    fusions that hold one, whatever their kind (the TPU's compiler makes a
+    `kCustom` fusion of a gather, its body's root), one trip. Not the entry
+    computation's (the ring's rows are gathered in front of the loop: the
+    HLO's `%fusion`), not what a `conditional`'s branch gathers, not a
+    `dynamic-slice`, not an `all-gather`."""
+    assert trace.chunk_ops_table(HLO)["gathers"] == 0
+    fusions = (
+        '%fused_computation.11 (param_0.11: pred[11,11], param_1.11: s32[88]) -> pred[8,11,11] {\n'
+        '  %param_0.11 = pred[11,11]{1,0} parameter(0)\n'
+        '  %param_1.11 = s32[88]{0} parameter(1)\n'
+        '  ROOT %gather.11 = pred[8,11,11]{2,1,0} gather(%param_0.11, %param_1.11), offset_dims={2}, metadata={op_name="critic/jvp()/gather"}\n'
+        '}\n\n'
+        '%fused_computation.12 (param_0.12: f32[64,15], param_1.12: s32[8]) -> f32[8,15] {\n'
+        '  %param_0.12 = f32[64,15]{1,0} parameter(0)\n'
+        '  %param_1.12 = s32[8]{0} parameter(1)\n'
+        '  %gather.12 = f32[8,15]{1,0} gather(%param_0.12, %param_1.12), offset_dims={1}\n'
+        '  ROOT %negate.12 = f32[8,15]{1,0} negate(%gather.12)\n'
+        '}\n\n'
+    )
+    body = '  %dynamic-slice.4 = f32[8,15]{1,0} dynamic-slice('
+    gathers = (
+        '  %fusion.1208 = pred[8,11,11]{2,1,0} fusion(%w.1, %w.1), kind=kCustom, calls=%fused_computation.11, metadata={op_name="critic/jvp()/gather"}\n'
+        '  %gather_negate_fusion = f32[8,15]{1,0} fusion(%w.1, %w.1), kind=kLoop, calls=%fused_computation.12\n'
+        '  %gather.13 = f32[8,15]{1,0} gather(%w.1, %w.1), offset_dims={1}\n'
+        '  %all-gather.13 = f32[8,15]{1,0} all-gather(%w.1), dimensions={0}\n'
+    )
+    text = HLO.replace("%body.2 (w.1:", fusions + "%body.2 (w.1:").replace(body, gathers + body)
+    text = text.replace(  # one in a branch; the entry computation has its `%fusion`
+        "  %multiply.7 = f32[4,4]{1,0} multiply(", "  %gather.70 = f32[4,4]{1,0} gather(%gte.5, %gte.5), offset_dims={1}\n  %multiply.7 = f32[4,4]{1,0} multiply("
+    )
+    table = trace.chunk_ops_table(text)
+    assert table["gathers"] == 3 and isinstance(table["gathers"], int)
+    assert table["loops"] == ["while.3"] and table["copies"] == {"count": 0, "bytes": 0}
+    assert table["ops"]["fusion.1208"] == "update/critic"  # an operation of the critic's all the same
+    assert trace.chunk_ops_table(ASYNC_HLO)["gathers"] == 0
+
+
 @pytest.mark.parametrize("opcode", [
     "all-reduce", "all-reduce-start", "all-reduce-done", "all-gather", "all-gather-start",
     "reduce-scatter", "collective-permute", "collective-permute-done", "all-to-all",
@@ -483,6 +522,23 @@ def test_the_run_fact_counts_the_launched_scan_bodys_relayouts():
     assert launched("sac", "kernel").chunk_body_copies() is None
 
 
+@pytest.mark.parametrize("family", ["sac", "c51"])
+def test_the_run_fact_counts_the_launched_scan_bodys_gathers(family):
+    """`chunk_body_gathers` (ShardedLearner.chunk_body_gathers,
+    train.run_facts): the table's `gathers` of the executable that ran; null
+    where `chunk_body_scalars` is. 0 in SAC's body and, since PR 52, in the
+    categorical critic's: `ops/losses.categorical_projection` indexes no
+    table (its gather form read 8 here, two an unrolled update). The ring's
+    rows are gathered in front of the loop."""
+    learner = launched(family, "scan")
+    assert learner.chunk_body_gathers() == learner.chunk_ops()["gathers"] == 0
+    assert "gather" in set(learner.chunk_ops()["ops"].values())  # the ring's own, under its scope
+    learner._build_programs()
+    assert learner.chunk_body_gathers() is None
+    if family == "sac":
+        assert launched(family, "kernel").chunk_body_gathers() is None
+
+
 # --- the compile cache must answer with the executable of THIS source ---
 
 
@@ -567,6 +623,8 @@ def test_train_writes_chunk_ops_beside_its_records_and_names_it(tmp_path):
     assert records[-1]["chunk_body_scalars"] == summary["chunk_body_scalars"] == table["scalars"]
     assert records[0]["chunk_body_copies"] is None
     assert records[-1]["chunk_body_copies"] == summary["chunk_body_copies"] == table["copies"]
+    assert records[0]["chunk_body_gathers"] is None
+    assert records[-1]["chunk_body_gathers"] == summary["chunk_body_gathers"] == table["gathers"] == 0
     assert isinstance(table["scalars"], int)
     # with --trace_dir it lies beside trace.json too
     assert json.loads((tmp_path / "tr" / trace.CHUNK_OPS_FILE).read_text()) == table

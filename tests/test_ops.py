@@ -144,6 +144,95 @@ def test_projection_mass_conserved():
     np.testing.assert_allclose(np.asarray(out).sum(-1), 1.0, rtol=1e-5)
 
 
+def _projection_by_gather(support, target_probs, rewards, discounts):
+    """`losses.categorical_projection` as it stood until PR 52, the one-hots
+    indexed out of an A x A table: the oracle of the comparison form."""
+    v_min, v_max = support[0], support[-1]
+    num_atoms = support.shape[0]
+    dz = (v_max - v_min) / (num_atoms - 1)
+    tz = jnp.clip(rewards[:, None] + discounts[:, None] * support[None, :], v_min, v_max)
+    b = (tz - v_min) / dz
+    lower, upper = jnp.floor(b), jnp.ceil(b)
+    eq = (upper == lower).astype(target_probs.dtype)
+    w_lower, w_upper = (upper - b) + eq, b - lower
+    onehot = jnp.eye(num_atoms, dtype=target_probs.dtype)
+    proj = jnp.einsum("ba,ba,baj->bj", target_probs, w_lower, onehot[lower.astype(jnp.int32)])
+    return proj + jnp.einsum("ba,ba,baj->bj", target_probs, w_upper, onehot[upper.astype(jnp.int32)])
+
+
+# case -> [(reward in atom distances, discount)] on the support [-150, 150]:
+# dz is 6 at 51 atoms and 75 at 5, so a whole number of distances under a
+# discount of 0 or 1 lands every source atom on an atom to the bit.
+_PROJECTION_ROWS = {
+    "on_an_atom": [(0, 1.0), (2, 1.0), (-1, 1.0), (1, 0.0), (0, 0.0)],  # the `eq` rule; (0, 1) is the identity
+    "between_atoms": [(0.25, 1.0), (-1.6, 0.99 ** 5), (0.5, 0.5), (1 / 3, 0.99)],
+    "clipped_at_v_max": [(1e3, 1.0), (60, 0.99), (0.7, 1.0)],  # the last clips its upper atoms only
+    "clipped_at_v_min": [(-1e3, 1.0), (-60, 0.99), (-0.7, 1.0)],
+    "terminal_row": [(0.3, 0.0), (-0.5, 0.0), (1e3, 0.0), (-1e3, 0.0)],
+}
+
+
+@pytest.mark.parametrize("num_atoms", [51, 5])
+@pytest.mark.parametrize("case", sorted(_PROJECTION_ROWS))
+def test_categorical_projection_places_the_mass_where_the_gather_form_did(case, num_atoms):
+    """The comparison form against the parent's on the same float32 weights:
+    apart by the order of an A-term sum at most, every row a distribution."""
+    support = losses.categorical_support(-150.0, 150.0, num_atoms)
+    dz = 300.0 / (num_atoms - 1)
+    rows = _PROJECTION_ROWS[case] * 3  # each row under three draws of the probabilities
+    rewards = jnp.asarray([r * dz for r, _ in rows], jnp.float32)
+    discounts = jnp.asarray([d for _, d in rows], jnp.float32)
+    rng = np.random.default_rng(num_atoms)
+    probs = jax.nn.softmax(jnp.asarray(2.0 * rng.normal(size=(len(rows), num_atoms)), jnp.float32))
+    ours = jax.jit(losses.categorical_projection)(support, probs, rewards, discounts)
+    assert ours.dtype == jnp.float32 and ours.shape == probs.shape
+    np.testing.assert_allclose(ours, _projection_by_gather(support, probs, rewards, discounts), atol=1e-6, rtol=0)
+    np.testing.assert_allclose(np.asarray(ours).sum(-1), 1.0, atol=1e-6, rtol=0)
+    assert float(ours.min()) >= 0.0
+    if case == "on_an_atom":
+        np.testing.assert_allclose(ours[0], probs[0], atol=1e-6, rtol=0)  # reward 0, discount 1
+        assert np.count_nonzero(np.asarray(ours[3])) == 1  # a terminal return on an atom: one atom holds it all
+    if case.startswith("clipped"):
+        end = -1 if case.endswith("max") else 0
+        np.testing.assert_allclose(ours[:2, end], 1.0, atol=1e-6, rtol=0)
+
+
+def test_categorical_projection_keeps_the_mass_of_an_index_rounded_past_the_top():
+    """On [0, 4.1] with 51 atoms float32's (v_max - v_min) / dz reads
+    50.000004, whose ceil is 51: the gather clamped that index to the top
+    atom, and the comparison form does the same and drops nothing."""
+    support = losses.categorical_support(0.0, 4.1, 51)
+    assert float((support[-1] - support[0]) / ((support[-1] - support[0]) / 50)) > 50.0
+    probs = jax.nn.softmax(jnp.asarray(np.random.default_rng(0).normal(size=(4, 51)), jnp.float32))
+    rewards, discounts = jnp.asarray([0.0, 9.0, 4.1, 0.05]), jnp.asarray([1.0, 1.0, 0.0, 0.99])
+    ours = losses.categorical_projection(support, probs, rewards, discounts)
+    np.testing.assert_allclose(ours, _projection_by_gather(support, probs, rewards, discounts), atol=1e-6, rtol=0)
+    np.testing.assert_allclose(np.asarray(ours).sum(-1), 1.0, atol=1e-6, rtol=0)
+
+
+def _primitives(jaxpr):
+    """The names of a jaxpr's primitives, through every jaxpr it closes over."""
+    for eqn in jaxpr.eqns:
+        yield eqn.primitive.name
+        for param in eqn.params.values():
+            for inner in param if isinstance(param, (tuple, list)) else (param,):
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    yield from _primitives(inner)
+
+
+def test_categorical_projection_traces_to_no_gather_and_no_dot():
+    """The form: compare, select and reduce_sum. A gather of the one-hot
+    table runs on the TPU as an operation of its own with a relayout behind
+    it (PERF.md §6, PR 52), and a dot would multiply float32 probabilities in
+    one bfloat16 pass there. The oracle above is the counter-example."""
+    args = (jnp.linspace(-150.0, 150.0, 51), jnp.full((256, 51), 1 / 51), jnp.zeros(256), jnp.ones(256))
+    found = set(_primitives(jax.make_jaxpr(losses.categorical_projection)(*args).jaxpr))
+    assert {"eq", "select_n", "reduce_sum"} <= found
+    assert not found & {"gather", "dot_general", "scatter", "scatter-add"}
+    assert {"gather", "dot_general"} <= set(_primitives(jax.make_jaxpr(_projection_by_gather)(*args).jaxpr))
+
+
 def test_actor_offset_for_asymmetric_spaces():
     """tanh output must map onto [low, high] when the box is asymmetric."""
     params = ({"w": jnp.full((1, 1), 100.0), "b": jnp.zeros((1,))},)

@@ -822,6 +822,48 @@ def test_v5e_crossq_chunk_holds_no_target_update_and_the_policy_under_a_conditio
 # compiles the kernel under its default scoped VMEM (nothing is passed). ---
 
 
+# --- the categorical projection (ops/losses.py), compiled for the same
+# described v5e at the DMPO cell's [256, 51]: its one-hots are comparisons
+# inside two loop fusions. Indexed out of a 51 x 51 table they were, to this
+# compiler, two `kCustom` fusions of a gather of 13,056 rows, each with a
+# relayout of the pred[13056, 51] block behind it (PERF.md §6, PR 52). ---
+
+
+def test_v5e_categorical_projection_gathers_nothing_and_relays_no_one_hot_block(v5e_sharding):
+    from distributed_ddpg_tpu import trace
+    from distributed_ddpg_tpu.ops import losses
+
+    rows, atoms = 256, 51
+    replicated = NamedSharding(v5e_sharding.mesh, P())
+    shape = lambda *dims: jax.ShapeDtypeStruct(dims, jnp.float32, sharding=replicated)
+    compiled = jax.jit(losses.categorical_projection).lower(shape(atoms), shape(rows, atoms), shape(rows), shape(rows)).compile()
+    text = compiled.as_text()
+    assert " gather(" not in text
+    ran = {opcode for _, opcode, _, _ in trace._instructions(text)[0]}
+    assert not {"gather", "scatter", "convolution", "dot", "while"} & ran
+    # no operation hands another a one-hot block: the [256, 51, 51] masks live inside the fusions that sum them
+    block = re.compile(rf"^\s*(?:ROOT )?%?[\w.\-]+ = \w+\[(?:{rows * atoms},{atoms}|{rows},{atoms},{atoms})\]")
+    assert not [line for line in text.split("ENTRY ", 1)[1].splitlines() if block.search(line)]
+    assert compiled.cost_analysis()["bytes accessed"] < 2e6  # [256, 51] operands: 0.84 MB (48.7 MB with the gathers)
+
+
+def test_v5e_dmpo_chunk_gathers_nothing_in_its_body(v5e_sharding):
+    """`dmpo-humanoid`'s scan chunk at the configuration's own sizes (K 800,
+    unroll 4), compiled for the described v5e: a trip of its loop issues no
+    gather (8 with the projection's one-hots indexed out of a table, two an
+    unrolled update) and half the relayouts (69 -> 33 a trip of this chunk,
+    which unpacks its batch in XLA: the gathers' own pred[13056, 51] copies
+    and reshapes, and the [256, 51] copies round them)."""
+    from distributed_ddpg_tpu import trace
+
+    cfg, _, compiled = _v5e_scan_chunk(v5e_sharding, "dmpo-humanoid", 800)
+    assert cfg.mpo and cfg.distributional and (cfg.batch_size, cfg.num_atoms) == (256, 51)
+    table = trace.chunk_ops_table(compiled.as_text())
+    assert len(table["loops"]) == 1
+    assert table["gathers"] == 0
+    assert table["copies"]["count"] <= 40
+
+
 @pytest.mark.parametrize(
     "name,extra,chunk,capacity,rounded",
     [
