@@ -69,9 +69,12 @@ from distributed_ddpg_tpu.config import DDPGConfig
 from distributed_ddpg_tpu.envs.jax_envs import make_jax_env
 from distributed_ddpg_tpu.metrics import DevActorStats
 from distributed_ddpg_tpu.types import ObsSpec
+from distributed_ddpg_tpu.models import recurrent as recnet
 from distributed_ddpg_tpu.ops.exploration import (
     nstep_fold,
     nstep_window,
+    seq_fold,
+    seq_window,
     sigma_ladder,
     vector_env_step,
 )
@@ -115,6 +118,15 @@ class ActorCarry(NamedTuple):
     # fewer than n steps.
     window: object = None
     short_rows: object = None  # i32[] cumulative
+    # A recurrent configuration only (None otherwise, as above): each env's
+    # policy state between steps (models/recurrent.Memory: the cell's (h, c)
+    # and the previous action and reward, all zeroed where an episode ends),
+    # the last seq_len steps of its current episode (ops/exploration.
+    # SeqWindow: the row it writes every step), and how many episodes' ends
+    # have zeroed a memory.
+    memory: object = None
+    seq: object = None
+    state_resets: object = None  # i32[] cumulative
 
 
 class DeviceActorPool:
@@ -167,7 +179,9 @@ class DeviceActorPool:
         env = self.env
         # The float32 words of a row that one observation takes: its float
         # count, or a byte frame stack's bytes over four (types.ObsSpec).
-        self.obs = ObsSpec.of_env(env)
+        # A recurrent configuration's row is a window of seq_len steps.
+        self.obs = ObsSpec.of_env(env, config.window_steps)
+        recurrent = bool(config.recurrent)
         obs_dim, act_dim = self.obs.words, env.act_dim
         self.obs_dim, self.act_dim = obs_dim, act_dim
         scale = ((env.action_high - env.action_low) / 2.0).astype(np.float32)
@@ -197,6 +211,14 @@ class DeviceActorPool:
             accounting. The warmup gate reads the pool's OWN cumulative
             step counter, not the ring's fill: the pool shares the ring
             with other sources, so it counts its own production."""
+            mean_action, memory = None, carry.memory
+            if recurrent:
+                # One LSTM step of the learner's own tree, on the memory
+                # the carry holds (models/recurrent.actor_step).
+                with trace.device_scope("policy"):
+                    mean_action, (h, c) = recnet.actor_step(
+                        params, carry.obs, memory, scale, offset
+                    )
             key, ou, action, out, rows = vector_env_step(
                 cfg, env, E, params, carry.env_state, carry.obs, carry.ou,
                 carry.key, scale, offset, low, high,
@@ -205,7 +227,23 @@ class DeviceActorPool:
                     if warmup_uniform > 0
                     else None
                 ),
+                mean_action=mean_action,
             )
+            seq, state_resets = carry.seq, carry.state_resets
+            if recurrent:
+                # The row of this step is the env's window, not the 1-step
+                # row; the memory takes the action the ring holds (noise and
+                # warm-up's uniform draw included) and the reward, and all of
+                # it is zero again where the episode ended.
+                with trace.device_scope("fold"):
+                    seq, rows = seq_fold(seq, action, out)
+                keep = 1.0 - out.done.astype(jnp.float32)
+                memory = recnet.Memory(
+                    h=h * keep[:, None], c=c * keep[:, None],
+                    prev_action=action * keep[:, None],
+                    prev_reward=out.reward * keep,
+                )
+                state_resets = state_resets + out.done.sum().astype(jnp.int32)
             window, short_rows = carry.window, carry.short_rows
             if n > 1:
                 with trace.device_scope("fold"):
@@ -226,6 +264,9 @@ class DeviceActorPool:
                 key=key,
                 window=window,
                 short_rows=short_rows,
+                memory=memory,
+                seq=seq,
+                state_resets=state_resets,
             )
             return new_carry, rows
 
@@ -253,11 +294,12 @@ class DeviceActorPool:
         key = jax.random.PRNGKey(config.seed + 0xDA)
         k_init, k_run = jax.random.split(key)
         env_state = jax.vmap(env.init)(jax.random.split(k_init, E))
+        first_obs = jax.vmap(env.observe)(env_state)
         carry = ActorCarry(
             env_state=env_state,
             # A copy: an env whose observation IS its state would hand the
             # donating rollout one buffer under two leaves.
-            obs=jnp.copy(jax.vmap(env.observe)(env_state)),
+            obs=jnp.copy(first_obs),
             ou=jnp.zeros((E, act_dim), jnp.float32),
             ep_ret=jnp.zeros((E,), jnp.float32),
             steps=jnp.zeros((), jnp.int32),
@@ -269,7 +311,22 @@ class DeviceActorPool:
                 if n > 1 else None
             ),
             short_rows=jnp.zeros((), jnp.int32) if n > 1 else None,
+            memory=(
+                recnet.zero_memory(E, cfg.rnn_hidden, act_dim)
+                if recurrent else None
+            ),
+            seq=(
+                seq_window(first_obs, cfg.seq_len, act_dim)
+                if recurrent else None
+            ),
+            state_resets=jnp.zeros((), jnp.int32) if recurrent else None,
         )
+
+        def per_env(tree):
+            return jax.tree.map(
+                lambda x: P(env_axis, *([None] * (x.ndim - 1))), tree
+            )
+
         carry_spec = ActorCarry(
             env_state=jax.tree.map(lambda _: P(env_axis), env_state),
             obs=P(env_axis, None),
@@ -279,10 +336,11 @@ class DeviceActorPool:
             episodes=P(),
             ret_sum=P(),
             key=P(),
-            window=jax.tree.map(
-                lambda x: P(env_axis, *([None] * (x.ndim - 1))), carry.window
-            ),
+            window=per_env(carry.window),
             short_rows=None if n == 1 else P(),
+            memory=per_env(carry.memory),
+            seq=per_env(carry.seq),
+            state_resets=P() if recurrent else None,
         )
         self._carry_sharding = mesh_lib.to_named(self.mesh, carry_spec)
         # Rows come out REPLICATED: that is the block sharding
@@ -571,10 +629,12 @@ class DeviceActorPool:
         plus the episode stats differenced from the carry's cumulative
         device counters — a two-scalar d2h, paid only at log cadence."""
         out = self._stats.snapshot()
-        # One d2h for the carry's counters (short_rows: None at n_step 1).
-        eps, ret, short = jax.device_get(
-            (self._carry.episodes, self._carry.ret_sum, self._carry.short_rows)
-        )
+        # One d2h for the carry's counters (short_rows: None at n_step 1;
+        # state_resets: None but for a recurrent policy).
+        eps, ret, short, resets = jax.device_get((
+            self._carry.episodes, self._carry.ret_sum, self._carry.short_rows,
+            self._carry.state_resets,
+        ))
         eps, ret = int(eps), float(ret)
         d_eps = eps - self._eps_seen
         d_ret = ret - self._ret_seen
@@ -584,6 +644,9 @@ class DeviceActorPool:
         if d_eps > 0:
             out["devactor_episode_return"] = round(d_ret / d_eps, 6)
         out["devactor_restarts"] = self._restarts
+        if resets is not None:
+            # Episodes' ends at which the pool zeroed a policy memory.
+            out["policy_state_resets"] = int(resets)
         if self.n_step > 1:
             # Rows emitted in the interval that hold fewer than n steps
             # (episode ends).
@@ -664,6 +727,16 @@ def program_specs():
         env_id="PixelHumanoidStandIn-v0", encoder_channels=4, feature_dim=8,
     )
 
+    # Recurrent TD3's rollout: one LSTM step of the learner's tree on the
+    # memory the carry holds, the window fold that writes a row of the last
+    # seq_len steps every step, memory and window zeroed where an episode
+    # ends, all donated and aliased through with the rest of the carry.
+    recurrent = dict(
+        recurrent=True, twin_critic=True, action_insert_layer=0, seq_len=4,
+        rnn_hidden=8, obs_embed=4, action_embed=2, reward_embed=2,
+        actor_hidden=(8, 8), critic_hidden=(8, 8), exploration="gaussian",
+    )
+
     return [
         ProgramSpec("devactor.rollout", "actors/device_pool.py", build()),
         ProgramSpec(
@@ -675,5 +748,9 @@ def program_specs():
         ProgramSpec(
             "devactor.rollout.pixels", "actors/device_pool.py",
             build(**pixels),
+        ),
+        ProgramSpec(
+            "devactor.rollout.recurrent", "actors/device_pool.py",
+            build(**recurrent),
         ),
     ]

@@ -64,6 +64,12 @@ COMPAT_FIELDS = (
     "mpo",  # LayerNormMLP nets, a [mean | scale] head, the dual variables' tree
     "encoder_channels",
     "feature_dim",
+    "recurrent",  # recurrent nets: dicts of embedders, an LSTM, a shortcut and heads
+    "seq_len",  # the ring's row is a window of seq_len steps
+    "rnn_hidden",
+    "obs_embed",
+    "action_embed",
+    "reward_embed",
     "num_atoms",
     "v_min",
     "v_max",
@@ -593,7 +599,7 @@ def check_config_compatible(directory: str, step: int, config: DDPGConfig) -> No
         return
     with open(path) as f:
         # a checkpoint from before the field existed was not a crossq run's
-        saved = {"crossq": False, "simba": False, "pixels": False, "mpo": False, **json.load(f)}
+        saved = {"crossq": False, "simba": False, "pixels": False, "mpo": False, "recurrent": False, **json.load(f)}
     current = dataclasses.asdict(config)
     mismatches = [
         f"{k}: checkpoint={saved[k]!r} run={_listify(current[k])!r}"

@@ -184,6 +184,34 @@ class DDPGConfig:
     # (ops/pixels.sigma_at).
     explore_sigma_schedule: str = "1.0,0.1,500000"
 
+    # --- recurrent TD3 (Ni, Eysenbach and Salakhutdinov 2022, arXiv
+    # 2110.05038, code twni2016/pomdp-baselines; twin_critic only) ---
+    # recurrent: actor and twin critic each carry a memory of their own
+    # (models/recurrent.py): embedders of the observation (obs_embed wide),
+    # the previous action (action_embed) and the previous reward
+    # (reward_embed), ONE LSTM layer of rnn_hidden units over their
+    # concatenation, a shortcut embedder of the current input (obs_embed
+    # wide: the actor's of o_t, the critic's of [o_t | a]) and heads of
+    # actor_hidden / critic_hidden on [h_t | shortcut]; the critic has one
+    # LSTM and two heads. A ring row is a WINDOW: the last seq_len steps of
+    # one environment's current episode, left-aligned and masked where the
+    # episode is younger (types.ObsSpec.steps; ops/exploration.seq_fold),
+    # one row written an env step, and an update scans every net's memory
+    # over the window from a zero state (learner.make_learner_step's
+    # recurrent_update). The device pool carries each environment's policy
+    # state (h, c), previous action and reward between steps and zeroes them
+    # where an episode ends. Device actors only; _check_recurrent refuses
+    # what this learner does not carry. The source's other settings are
+    # plain flags: twin_critic, policy_delay 1, target_noise 0.2,
+    # target_noise_clip 0.5, tau 0.005, both learning rates 3e-4, batch 64,
+    # heads 128,128, exploration gaussian at sigma 0.1.
+    recurrent: bool = False
+    seq_len: int = 64
+    rnn_hidden: int = 128
+    obs_embed: int = 32
+    action_embed: int = 8
+    reward_embed: int = 8
+
     # --- DMPO (Acme's distributional MPO: arXiv 2006.00979, agents/tf/dmpo;
     # the policy step arXiv 1806.06920 in its decoupled form, 1812.02256) ---
     # mpo: the policy is improved without a gradient through the critic
@@ -756,6 +784,12 @@ class DDPGConfig:
         return self.sac or self.mpo
 
     @property
+    def window_steps(self) -> int:
+        """Steps a ring row holds: seq_len for a recurrent configuration
+        (a row is a window, types.ObsSpec.steps), 0 for a transition row."""
+        return self.seq_len if self.recurrent else 0
+
+    @property
     def sigma_schedule(self) -> tuple:
         """explore_sigma_schedule as (initial, final, frames)."""
         init, final, frames = self.explore_sigma_schedule.split(",")
@@ -850,6 +884,107 @@ class DDPGConfig:
                 "pixels refuses --serve_actors: the serving engines batch "
                 "flat float observations for host workers, and there are "
                 "none (ROADMAP.md R5)"
+            )
+
+    def _check_recurrent(self):
+        """What the recurrent learner needs, and what it refuses, each with
+        its reason (ROADMAP.md R7 holds the missing pieces by mechanism)."""
+        if not self.twin_critic or self.policy_delay != 1:
+            raise ValueError(
+                "recurrent is the twin-critic step with every update moving "
+                "the actor, as its source trains (arXiv 2110.05038): set "
+                "twin_critic=True and leave policy_delay at 1"
+            )
+        if self.pixels:
+            # (every other family is refused beside twin_critic by the
+            # families' own rule, before this check runs)
+            raise ValueError(
+                "recurrent refuses --pixels: the pixel learner's nets are "
+                "functions of one row's frames and have no memory; the "
+                "recurrent nets (models/recurrent.py) read flat observations"
+            )
+        if self.action_insert_layer != 0:
+            raise ValueError(
+                "a recurrent critic takes [obs | action] at its shortcut "
+                "embedder's input: set action_insert_layer=0"
+            )
+        if self.seq_len < 2 or min(
+            self.rnn_hidden, self.obs_embed, self.action_embed, self.reward_embed
+        ) < 1:
+            raise ValueError(
+                "seq_len must be >= 2 (a window of one step has no memory) "
+                "and rnn_hidden, obs_embed, action_embed and reward_embed "
+                ">= 1"
+            )
+        if self.n_step != 1:
+            raise ValueError(
+                "recurrent refuses n_step > 1: a window row holds single "
+                "steps (o_t, a_t, r_t, d_t) and the update's targets are "
+                "per step; an n-step fold inside a window is not built"
+            )
+        if self.backend != "jax_tpu" or self.compute_dtype != "float32":
+            raise ValueError(
+                "recurrent needs backend='jax_tpu' and compute_dtype="
+                "'float32': the native numpy learner has no LSTM, and the "
+                "recurrent nets have no bfloat16 path of their own (the TPU "
+                "already multiplies float32 operands in one bfloat16 pass)"
+            )
+        if self.actor_backend != "device" or self.num_actors > 0:
+            raise ValueError(
+                "recurrent runs device actors only (--actor_backend=device "
+                "--num_actors=0): the host workers' numpy policy "
+                "(actors/policy.py KINDS) has no recurrent kind and a worker "
+                "carries no policy state between steps (ROADMAP.md R7)"
+            )
+        if self.exploration != "gaussian":
+            raise ValueError(
+                "recurrent explores with Gaussian noise on the policy's "
+                "action (the source's N(0, 0.1^2)): set --exploration="
+                "gaussian with explore_sigma_min = explore_sigma_max = the "
+                "scale; the OU process would be a second state between steps"
+            )
+        if self.serve_actors or self.front_port or self.front_http_port:
+            raise ValueError(
+                "recurrent refuses --serve_actors and the network front: "
+                "both serving engines answer one stateless request at a "
+                "time and keep no session's memory (ROADMAP.md R7)"
+            )
+        if self.prioritized:
+            raise ValueError(
+                "recurrent refuses --prioritized: a window's TD errors are "
+                "per step [B, L] and the PER chunk writes one priority a "
+                "row back, and cuts its rows with unpack_batch"
+            )
+        if self.replay_sharding != "replicated" or self.host_replay:
+            raise ValueError(
+                "recurrent refuses --replay_sharding=sharded and "
+                "--host_replay: only the replicated device ring's uniform "
+                "chunk cuts window rows (types.unpack_windows)"
+            )
+        if self.fused_chunk == "on":
+            raise ValueError(
+                "recurrent refuses --fused_chunk=on: the megakernel has no "
+                "loop over time (ops/fused_chunk.supported says no); the "
+                "scan leg runs"
+            )
+        if self.fused_beat == "on" or self.superstep_beats > 1:
+            raise ValueError(
+                "recurrent refuses --fused_beat=on and superstep_beats > 1: "
+                "the fused beat composes the flat rollout and chunk bodies "
+                "and has no slot for the window rows or the policy state; "
+                "the loop dispatches per phase"
+            )
+        if self.guardrails:
+            raise ValueError(
+                "recurrent refuses --guardrails: the row screen and the "
+                "guarded chunk cut rows with unpack_batch, not windows"
+            )
+        if self.weight_decay or self.target_update_period or self.critic_l2:
+            raise ValueError(
+                "recurrent refuses --weight_decay, --target_update_period "
+                "and --critic_l2: its source is plain Adam with Polyak "
+                "targets every update, and the recurrent update traces "
+                "nothing else"
             )
 
     def _check_mpo(self):
@@ -1133,6 +1268,8 @@ class DDPGConfig:
             self._check_pixels()
         if self.mpo:
             self._check_mpo()
+        if self.recurrent:
+            self._check_recurrent()
         if self.target_update_period < 0:
             raise ValueError(
                 "target_update_period must be >= 0 (0 = Polyak with tau)"

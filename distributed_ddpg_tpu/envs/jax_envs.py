@@ -270,6 +270,31 @@ class IsaacHumanoidStandIn:
         )
 
 
+class OccludedHumanoidStandIn(IsaacHumanoidStandIn):
+    """IsaacHumanoidStandIn with half its state hidden: the system's state
+    has 108 components and the observation is the FIRST 54 of them, the
+    way the "-P" wrappers of arXiv 2110.05038's standard-POMDP tasks keep
+    the positions and hide the velocities (there on PyBullet's tasks, here
+    on a stand-in: no physics step). A is a rotation of the whole state, so
+    what the hidden half holds reaches the seen half a step later, and the
+    reward and the termination box read all 108: the state a policy needs
+    is not in one observation. Dynamics, reward, box, limits and
+    `MATRIX_SEED` are the parent's, so every run steps the same system as
+    PQL's cell does and sees half of it."""
+
+    obs_dim = 54
+    state_dim = 108
+
+    def init(self, key) -> StandInState:
+        x = jax.random.uniform(
+            key, (self.state_dim,), jnp.float32, -self.INIT, self.INIT
+        )
+        return StandInState(x=x, t=jnp.zeros((), jnp.int32))
+
+    def observe(self, s: StandInState) -> jnp.ndarray:
+        return s.x[: self.obs_dim]
+
+
 class PixelStandInState(NamedTuple):
     x: jnp.ndarray        # f32[108] the system's state, which no policy sees
     t: jnp.ndarray        # i32[] agent steps into the episode
@@ -392,10 +417,12 @@ class PixelHumanoidStandIn(IsaacHumanoidStandIn):
 
 STAND_IN_ID = "IsaacHumanoidStandIn-v0"
 PIXEL_STAND_IN_ID = "PixelHumanoidStandIn-v0"
+OCCLUDED_STAND_IN_ID = "OccludedHumanoidStandIn-v0"
 
 _JAX_ENVS = {
     STAND_IN_ID: IsaacHumanoidStandIn,
     PIXEL_STAND_IN_ID: PixelHumanoidStandIn,
+    OCCLUDED_STAND_IN_ID: OccludedHumanoidStandIn,
     "Pendulum-v1": JaxPendulum,
     "builtin/Pendulum-v1": JaxPendulum,
     "MountainCarContinuous-v0": JaxMountainCar,

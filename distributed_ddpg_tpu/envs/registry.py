@@ -35,7 +35,10 @@ _VERSION_ALIASES = {
 # Ids with JAX dynamics only (envs/jax_envs.py): `make` wraps them for the
 # trainer's own use (the spec, an evaluation); a worker process, which must
 # never import JAX, cannot step them (config.py refuses that at parse).
-DEVICE_ONLY = frozenset({"IsaacHumanoidStandIn-v0", "PixelHumanoidStandIn-v0"})
+DEVICE_ONLY = frozenset({
+    "IsaacHumanoidStandIn-v0", "PixelHumanoidStandIn-v0",
+    "OccludedHumanoidStandIn-v0",
+})
 
 
 class EnvSpec(NamedTuple):

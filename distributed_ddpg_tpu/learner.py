@@ -35,8 +35,9 @@ from distributed_ddpg_tpu.ops import losses
 from distributed_ddpg_tpu.ops.optim import adam_update
 from distributed_ddpg_tpu.ops.polyak import polyak_update, target_update
 from distributed_ddpg_tpu.trace import device_scope
-from distributed_ddpg_tpu.types import Batch, ObsSpec, OptState, TrainState
+from distributed_ddpg_tpu.types import Batch, ObsSpec, OptState, TrainState, Windows
 from distributed_ddpg_tpu.models import pixels as pixnet
+from distributed_ddpg_tpu.models import recurrent as recnet
 from distributed_ddpg_tpu.models.mlp import (
     actor_init, critic_init, gaussian_apply, lnmlp_init, norm_moved,
     rs_merged, rs_written, simba_init,
@@ -94,9 +95,14 @@ def metric_keys(config: DDPGConfig) -> tuple:
     mpo_samples actions a state the improved policy is fitted to),
     `mpo_kl_mean_ratio` (the mean over the action's dimensions of KL_mean /
     mpo_epsilon_mean: above 1 the mean's bound is broken and its multiplier
-    grows) and `mpo_temperature` (eta out of log space). Only those branches
+    grows) and `mpo_temperature` (eta out of log space). A recurrent run,
+    config.recurrent, reports beside the twin gap `seq_valid_frac`: the
+    share of the batch's B x L window steps that are real (mask 1), the
+    chunk's mean. Only those branches
     have the keys, so every other family's programs and records are what
     they were."""
+    if config.recurrent:
+        return METRIC_KEYS + ("td3_twin_gap",) + RECURRENT_KEYS
     if config.mpo:
         return METRIC_KEYS + ("c51_edge_mass",) + MPO_KEYS
     if config.distributional:  # config.py: never with twin_critic or sac
@@ -117,6 +123,7 @@ def metric_keys(config: DDPGConfig) -> tuple:
 SIMBA_KEYS = ("resid_share", "rsnorm_count", "rsnorm_drift")
 PIXEL_KEYS = ("encoder_grad_norm", "explore_sigma", "aug_offset_mean")
 MPO_KEYS = ("mpo_weight_ess", "mpo_kl_mean_ratio", "mpo_temperature")
+RECURRENT_KEYS = ("seq_valid_frac",)
 # Metrics a chunk reports for its LAST update, not as a mean over the K.
 LAST_UPDATE_KEYS = (
     "c51_edge_mass", "td3_twin_gap", "redq_q_spread", "bn_stat_gap", *SIMBA_KEYS,
@@ -222,7 +229,8 @@ def step_noise(config: DDPGConfig, base, step, batch: int, act_dim: int,
     clipped at target_noise_clip with sigma the schedule at this step
     (ops/pixels.sigma_at). MPO: the standard normals of the E-step's draws,
     f32[B, mpo_samples, act], the rows first so that a data mesh shards them
-    like the batch. None
+    like the batch. Recurrent (config.recurrent): TD3's smoothing noise for
+    every step of every window, f32[B, seq_len, act]. None
     where the algorithm draws none (DDPG, D4PG, TD3 without smoothing).
     `device_fold` (lax.axis_index under shard_map) folds a per-device term
     AFTER the step fold, so that each shard of a global batch draws its own
@@ -259,8 +267,12 @@ def step_noise(config: DDPGConfig, base, step, batch: int, act_dim: int,
                 for k in (k_next, k_cur)
             ),
         )
+    shape = (
+        (batch, config.seq_len, act_dim) if config.recurrent
+        else (batch, act_dim)
+    )
     return jnp.clip(
-        config.target_noise * jax.random.normal(key, (batch, act_dim)),
+        config.target_noise * jax.random.normal(key, shape),
         -config.target_noise_clip,
         config.target_noise_clip,
     )
@@ -367,6 +379,33 @@ def init_mpo_state(config: DDPGConfig, obs_dim: int, act_dim: int, k_actor, k_cr
     )
 
 
+def _with_targets(actor, critic) -> TrainState:
+    """The state of two nets with a copied target and a fresh Adam each."""
+    return TrainState(
+        actor_params=actor,
+        critic_params=critic,
+        target_actor_params=jax.tree.map(jnp.copy, actor),
+        target_critic_params=jax.tree.map(jnp.copy, critic),
+        actor_opt=_opt_init(actor),
+        critic_opt=_opt_init(critic),
+        step=jnp.zeros((), jnp.int32),
+    )
+
+
+@functools.partial(jax.jit, static_argnums=(2, 3, 4, 5, 6))
+def _recurrent_state(k_actor, k_critic, obs_dim, act_dim, widths, actor_hidden, critic_hidden):
+    """A recurrent configuration's seeded state (models/recurrent.py), as
+    ONE program: a recurrent net has seventeen leaves, and drawn one eager
+    operation at a time (two splits and two uniforms a layer) a cold compile
+    cache pays for some two hundred tiny programs, 96 s of `setup.build_s`
+    on the chip (PERF.md §6, PR 53). The bits are the eager draws' (threefry
+    and an affine map)."""
+    return _with_targets(
+        recnet.actor_init(k_actor, obs_dim, act_dim, widths, actor_hidden),
+        recnet.critic_init(k_critic, obs_dim, act_dim, widths, critic_hidden),
+    )
+
+
 def init_train_state(config: DDPGConfig, obs_dim, act_dim: int, seed: int) -> TrainState:
     """Build initial params + hard-copied targets (SURVEY.md §3.4) + Adam
     state. CrossQ (config.crossq): batch-normalised nets and no targets,
@@ -387,6 +426,11 @@ def init_train_state(config: DDPGConfig, obs_dim, act_dim: int, seed: int) -> Tr
 
     if config.mpo:
         return init_mpo_state(config, obs_dim, act_dim, k_actor, k_critic)
+    if config.recurrent:
+        return _recurrent_state(
+            k_actor, k_critic, obs_dim, act_dim, recnet.widths_of(config),
+            tuple(config.actor_hidden), tuple(config.critic_hidden),
+        )
     if config.simba:
         actor_params = simba_init(
             k_actor, obs_dim, obs_dim, actor_head_dim(act_dim, True),
@@ -506,7 +550,8 @@ def make_learner_step(
         """What a step handed no noise draws for itself; under shard_map
         (explicit mode) each shard for its OWN batch slice."""
         return step_noise(
-            config, base_key, state.step, *batch.action.shape,
+            config, base_key, state.step, batch.action.shape[0],
+            batch.action.shape[-1],
             None if axis_name is None else jax.lax.axis_index(axis_name),
         )
 
@@ -1002,6 +1047,104 @@ def make_learner_step(
         pixel_step.launch = (enter, pixel_update, leave)
         return pixel_step
 
+    def recurrent_update(state: TrainState, batch: Windows, noise=None) -> StepOutput:
+        """Recurrent TD3 (config.recurrent; models/recurrent.py) on a batch
+        of WINDOWS (types.Windows): obs f32[B, L + 1, o], action, reward,
+        terminated (the steps' flags d) and mask f32[B, L].
+        Every net's memory is scanned over the window's L + 1 observations
+        from a zero state, with a_{-1} = r_{-1} = 0: the two targets' without
+        gradient (`target/recur`), the critic's forward and back through
+        time under its loss (`critic/recur`), the actor's under its own
+        (`actor/recur`). Targets y_t = r_t + gamma (1 - d_t) min_k
+        Q'_k(t + 1, clip(pi'(t + 1) + noise_t)); the critic's loss the SUM of
+        the two heads' masked squared errors over the real steps, the
+        actor's -min_k Q_k(t, pi(t)) over them, through the critic's shortcut
+        alone (the critic's memory h^Q_t is a function of the ring's
+        actions, not of pi; it is the one the critic's loss computed, on the
+        critic as it stood before this update: file convention). Adam on
+        both, Polyak on every target leaf, the memories' among them.
+        td_errors are per step, f32[B, L]: the mean over the heads of
+        y - Q_k, 0 on a padded step."""
+        if noise is None:
+            noise = own_noise(state, batch)
+        obs, mask = batch.obs, batch.mask
+        lo, hi = offset - scale, offset + scale
+        zero_a, zero_r = jnp.zeros_like(batch.action[:, :1]), jnp.zeros_like(batch.reward[:, :1])
+        prev_action = jnp.concatenate([zero_a, batch.action], axis=1)
+        prev_reward = jnp.concatenate([zero_r, batch.reward], axis=1)
+        steps = jnp.maximum(jnp.sum(mask), 1.0)
+
+        with device_scope("target"):
+            h_ta = recnet.memory(state.target_actor_params, obs, prev_action, prev_reward)
+            h_tc = recnet.memory(state.target_critic_params, obs, prev_action, prev_reward)
+            next_action = jnp.clip(
+                recnet.actor_head(
+                    state.target_actor_params, h_ta[:, 1:], obs[:, 1:], scale, offset
+                ) + noise,
+                lo, hi,
+            )
+            next_q = recnet.critic_heads(
+                state.target_critic_params, h_tc[:, 1:], obs[:, 1:], next_action
+            )
+            y = batch.reward + config.gamma * (1.0 - batch.terminated) * jnp.min(next_q, axis=0)
+            twin_gap = jnp.sum(jnp.abs(next_q[0] - next_q[1]) * mask) / steps
+
+        def critic_loss_fn(cp):
+            h = recnet.memory(cp, obs, prev_action, prev_reward)
+            td = (y[None] - recnet.critic_heads(cp, h[:, :-1], obs[:, :-1], batch.action)) * mask[None]
+            return jnp.sum(jnp.square(td)) / steps, (
+                jnp.mean(td, axis=0), jax.lax.stop_gradient(h)
+            )
+
+        with device_scope("critic"):
+            (closs, (td, h_critic)), cgrads = jax.value_and_grad(
+                critic_loss_fn, has_aux=True
+            )(state.critic_params)
+            cgrads = _maybe_psum_mean(cgrads, axis_name)
+
+        def actor_loss_fn(ap):
+            h = recnet.memory(ap, obs, prev_action, prev_reward)
+            action = recnet.actor_head(ap, h[:, :-1], obs[:, :-1], scale, offset)
+            q = recnet.critic_heads(
+                state.critic_params, h_critic[:, :-1], obs[:, :-1], action
+            )
+            return -jnp.sum(jnp.min(q, axis=0) * mask) / steps
+
+        with device_scope("actor"):
+            aloss, agrads = jax.value_and_grad(actor_loss_fn)(state.actor_params)
+            agrads = _maybe_psum_mean(agrads, axis_name)
+        new_critic, critic_opt = adam_update(
+            state.critic_params, cgrads, state.critic_opt, config.critic_lr,
+            config.adam_b1,
+        )
+        new_actor, actor_opt = adam_update(
+            state.actor_params, agrads, state.actor_opt, config.actor_lr,
+            config.adam_b1,
+        )
+        metrics = dict(zip(keys, (
+            closs, aloss, -aloss, jnp.sum(jnp.abs(td)) / steps,
+            optree_norm(cgrads), optree_norm(agrads), twin_gap,
+            jnp.mean(mask),
+        )))
+        metrics = _maybe_psum_mean(metrics, axis_name)
+        new_state = TrainState(
+            actor_params=new_actor,
+            critic_params=new_critic,
+            target_actor_params=polyak_update(
+                new_actor, state.target_actor_params, config.tau
+            ),
+            target_critic_params=polyak_update(
+                new_critic, state.target_critic_params, config.tau
+            ),
+            actor_opt=actor_opt,
+            critic_opt=critic_opt,
+            step=state.step + 1,
+        )
+        return StepOutput(state=new_state, td_errors=td, metrics=metrics)
+
+    if config.recurrent:
+        return recurrent_update
+
     def step(state: TrainState, batch: Batch, noise=None) -> StepOutput:
         # --- critic update ---
         if config.twin_critic:
@@ -1222,6 +1365,16 @@ def make_act_fn(config: DDPGConfig, action_scale, action_offset=0.0):
         # `obs` byte frames uint8[B, C, H, W]: the learner's own apply.
         return jax.jit(
             lambda policy, obs: pixnet.policy_apply(policy, obs, scale, offset)
+        )
+
+    if config.recurrent:
+        # One step of the policy's memory: (params, obs f32[B, o], Memory)
+        # -> (action, (h, c)); the caller carries the Memory on
+        # (models/recurrent.actor_step: the rollout's own step).
+        return jax.jit(
+            lambda actor_params, obs, memory: recnet.actor_step(
+                actor_params, obs, memory, scale, offset
+            )
         )
 
     if config.mpo:

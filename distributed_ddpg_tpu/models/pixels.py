@@ -134,9 +134,10 @@ def critic_init(seed: int, obs_shape, channels: int, feature_dim: int,
 
 
 def is_pixel(params) -> bool:
-    """Whether `params` is one of this file's trees (a dict at its root;
-    every other net of this package is a tuple of layers)."""
-    return isinstance(params, dict)
+    """Whether `params` is one of this file's trees: a dict with a `trunk`
+    at its root (a recurrent net, models/recurrent.py, is a dict without
+    one; every other net of this package is a tuple of layers)."""
+    return isinstance(params, dict) and "trunk" in params
 
 
 def trained_with_target(critic):

@@ -101,6 +101,96 @@ def nstep_fold(window: NStepWindow, rows, out, gamma, obs_dim, act_dim):
     )
 
 
+class SeqWindow(NamedTuple):
+    """The last `count` <= L steps of each environment's CURRENT episode,
+    left-aligned: step j of the window in slot j, its observation in obs
+    slot j and the observation it led to in obs slot j + 1. Slots from
+    `count` on (obs slots from `count` + 1 on) hold what an older episode
+    left there, which `seq_fold` masks out of every row it emits."""
+
+    obs: jnp.ndarray         # f32[E, L + 1, o]
+    action: jnp.ndarray      # f32[E, L, a]
+    reward: jnp.ndarray      # f32[E, L]
+    terminated: jnp.ndarray  # f32[E, L]  1 where the step truly terminated
+    count: jnp.ndarray       # i32[E]     steps held, 0 .. L
+
+
+def seq_window(first_obs, steps: int, act_dim: int) -> SeqWindow:
+    """The window before any step of episodes that begin at `first_obs`
+    f32[E, o]: no step held, obs slot 0 the first observation."""
+    num_envs, obs_dim = first_obs.shape
+    obs = jnp.zeros((num_envs, steps + 1, obs_dim), jnp.float32)
+    return SeqWindow(
+        obs=obs.at[:, 0].set(first_obs),
+        action=jnp.zeros((num_envs, steps, act_dim), jnp.float32),
+        reward=jnp.zeros((num_envs, steps), jnp.float32),
+        terminated=jnp.zeros((num_envs, steps), jnp.float32),
+        count=jnp.zeros((num_envs,), jnp.int32),
+    )
+
+
+def seq_fold(window: SeqWindow, action, out):
+    """One step of the window fold over E environments: the step (o_t, which
+    the window already holds, `action`, out.reward, out.terminated,
+    out.boot_obs) joins each environment's window, the oldest step leaving
+    where L are held, and ONE row an environment is emitted (types.
+    unpack_windows' layout: [o_0 .. o_L | a | r | d | m], time-major, zeros
+    in every padded slot): the last <= L steps of the episode up to and
+    with this one, left-aligned, m = 1 on the real ones. The row's o_{j+1}
+    behind its newest step is the step's BOOTSTRAP observation (pre-reset:
+    what a truncated episode's target bootstraps from). Where the episode
+    ended (out.done) the window then empties and its obs slot 0 takes the
+    new episode's first observation (out.obs).
+
+    Returns (window, rows f32[E, D])."""
+    steps = window.action.shape[1]
+    full = window.count >= steps
+    at = jnp.minimum(window.count, steps - 1)  # the slot this step takes
+
+    def slid(x):  # the oldest step leaves where the window is full
+        lead = full.reshape(-1, *([1] * (x.ndim - 1)))
+        return jnp.where(lead, jnp.roll(x, -1, axis=1), x)
+
+    def put(x, slot, value):  # x[e, slot[e]] = value[e]
+        hit = jnp.arange(x.shape[1])[None, :] == slot[:, None]
+        hit = hit.reshape(*hit.shape, *([1] * (x.ndim - 2)))
+        return jnp.where(hit, value[:, None], x)
+
+    obs = put(slid(window.obs), at + 1, out.boot_obs)
+    action = put(slid(window.action), at, action)
+    reward = put(slid(window.reward), at, out.reward)
+    terminated = put(
+        slid(window.terminated), at, out.terminated.astype(jnp.float32)
+    )
+    count = jnp.minimum(window.count + 1, steps)
+    real = (jnp.arange(steps)[None, :] < count[:, None]).astype(jnp.float32)
+    seen = (jnp.arange(steps + 1)[None, :] <= count[:, None]).astype(jnp.float32)
+    num_envs = obs.shape[0]
+    rows = jnp.concatenate(
+        [
+            (obs * seen[..., None]).reshape(num_envs, -1),
+            (action * real[..., None]).reshape(num_envs, -1),
+            reward * real,
+            terminated * real,
+            real,
+        ],
+        axis=-1,
+    )
+    done = out.done
+    return (
+        SeqWindow(
+            obs=jnp.where(
+                done[:, None, None], put(obs, jnp.zeros_like(at), out.obs), obs
+            ),
+            action=action,
+            reward=reward,
+            terminated=terminated,
+            count=jnp.where(done, 0, count),
+        ),
+        rows,
+    )
+
+
 def vector_env_step(
     cfg,
     env,
@@ -115,8 +205,13 @@ def vector_env_step(
     low,
     high,
     warmup_active=None,
+    mean_action=None,
 ):
     """One vectorized exploration step over `num_envs` envs.
+
+    `mean_action`: the policy's action where the caller has computed it (a
+    recurrent policy's one step, which owns a memory: the pool's); the
+    Gaussian ladder's noise is added to it and no policy is applied here.
 
     `warmup_active`: None = no uniform-warmup override compiled in
     (static off); else a traced bool[] — where True, actions are drawn
@@ -163,7 +258,10 @@ def vector_env_step(
             # PQL's ladder: a fixed scale per environment, no state between
             # steps (the OU state rides along untouched).
             action = jnp.clip(
-                actor_apply(params, obs, scale, offset)
+                (
+                    actor_apply(params, obs, scale, offset)
+                    if mean_action is None else mean_action
+                )
                 + sigma_ladder(cfg, E)[:, None]
                 * jax.random.normal(k_ou, ou.shape, jnp.float32)
                 * scale,

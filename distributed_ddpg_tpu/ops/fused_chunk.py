@@ -271,6 +271,9 @@ def supported(config: DDPGConfig) -> bool:
         # DMPO: no pass on batch x samples rows, no dual variables; the
         # kernel's targets are Polyak averages, never copies
         and not config.mpo
+        # recurrent TD3: no loop over time in the kernel (action_insert_layer
+        # 0 already says no)
+        and not config.recurrent
         and config.target_update_period == 0
         and config.adam_b1 == B1
         and config.weight_decay == 0.0

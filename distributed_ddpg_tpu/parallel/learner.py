@@ -76,6 +76,7 @@ from distributed_ddpg_tpu.types import (
     TrainState,
     pack_batch_np,
     packed_width,
+    unpack_windows,
     unpack_batch,
 )
 
@@ -266,6 +267,13 @@ class ShardedLearner:
                 f"{config.pixels}: byte frames need --pixels=true "
                 "(DrQ-v2's learner) and it reads nothing else"
             )
+        if self.obs.steps != config.window_steps:
+            raise ValueError(
+                f"rows of {self.obs.steps} steps and recurrent="
+                f"{config.recurrent} (seq_len {config.seq_len}): a recurrent "
+                "learner reads windows of seq_len steps "
+                "(ObsSpec.of_env(env, config.window_steps)) and nothing else"
+            )
         self.obs_dim, self.act_dim = self.obs.words, act_dim
         # Numerical-health guardrails (guardrails.py): the chunk programs
         # thread a small replicated GuardState through the scan and emit a
@@ -428,7 +436,7 @@ class ShardedLearner:
         def draw_chunk_noise(s: TrainState, batches: Batch, nkey):
             if not draws_noise(config):
                 return None
-            K, B, _ = batches.action.shape
+            K, B = batches.action.shape[:2]
             if mode == "auto":
                 # On a mesh each chip draws its own rows of the global
                 # [K, B, act]; with the partitionable threefry the values
@@ -546,12 +554,15 @@ class ShardedLearner:
         from distributed_ddpg_tpu.ops.pixels import cut_pixels
         from distributed_ddpg_tpu.replay.device import ring_layout
 
-        width = packed_width(obs_dim, act_dim)
+        width = packed_width(self.obs, act_dim)
         # A pixel launch has a cut of its own (ops/pixels.cut_pixels: the
         # fields as unpack_batch cuts them, the images still words under
         # `prep/pixels`), and its words must not pass through the kernel's
-        # rounding.
-        scan_front = "xla" if config.pixels else front_lib.front_for(
+        # rounding. A recurrent launch's rows are windows
+        # (types.unpack_windows: five fields, each time-major), which the
+        # kernel's four cuts of a transition row do not spell; its gather is
+        # 1.3 MB an update beside hundreds of dependent LSTM steps.
+        scan_front = "xla" if config.pixels or config.recurrent else front_lib.front_for(
             width=width,
             batch=batch_size // n_shards,
             layout=ring_layout(width, self._replay_sharded),
@@ -563,6 +574,8 @@ class ShardedLearner:
         def cut_chunk(packed) -> Batch:
             if config.pixels:
                 return cut_pixels(packed, self.obs, act_dim)
+            if config.recurrent:
+                return unpack_windows(packed, obs_dim, act_dim, self.obs.steps)
             if scan_front == "xla":
                 return unpack_batch(packed, obs_dim, act_dim)
             cut = partial(
@@ -1329,14 +1342,17 @@ class ShardedLearner:
         the fold alone. A batch-normalised actor (CrossQ) leaves as the plain
         MLP it is in evaluation mode (mlp.fold_norm): the workers' layout,
         the evaluator and the serving engine never see the normalisation.
-        A pixel configuration's policy (policy_params) leaves as it is:
-        nothing on the host but the evaluator and the checksum reads it."""
+        A pixel configuration's policy (policy_params) and a recurrent one's
+        leave as they are: nothing on the host but the evaluator and the
+        checksum reads them."""
         def fetch():
             with trace.span("params_d2h"):
                 host = jax.tree.map(
                     np.asarray, jax.device_get(self.policy_params())
                 )
-                return host if self.config.pixels else fold_norm(host)
+                if self.config.pixels or self.config.recurrent:
+                    return host
+                return fold_norm(host)
 
         if self.transfer is None:
             return fetch()
@@ -1549,13 +1565,25 @@ def program_specs():
         critic_hidden=(16, 16), target_update_period=2,
     )
 
+    # Recurrent TD3's chunk (rows that are windows of 4 steps cut five ways,
+    # the smoothing noise pre-drawn [K, B, L, act], four memories scanned
+    # over time inside the scan over updates, backward through time for two,
+    # masked losses), as the benchmark's cell runs it.
+    RECURRENT = dict(
+        recurrent=True, twin_critic=True, action_insert_layer=0, seq_len=4,
+        rnn_hidden=8, obs_embed=4, action_embed=2, reward_embed=2,
+        actor_hidden=(8, 8), critic_hidden=(8, 8), target_noise=0.2,
+        target_noise_clip=0.5, exploration="gaussian", actor_backend="device",
+        num_actors=0, device_actor_envs=4,
+    )
+
     def learner(
         guard: bool = False, sharded: bool = False, tp: bool = False,
         ensemble: bool = False, mode: str = "auto", crossq: bool = False,
         pql: bool = False, simba: bool = False, pixels: bool = False,
-        mpo: bool = False,
+        mpo: bool = False, recurrent: bool = False,
     ) -> ShardedLearner:
-        key = (guard, sharded, tp, ensemble, mode, crossq, pql, simba, pixels, mpo)
+        key = (guard, sharded, tp, ensemble, mode, crossq, pql, simba, pixels, mpo, recurrent)
         if key not in cache:
             cache[key] = ShardedLearner(
                 probe_config(
@@ -1566,8 +1594,12 @@ def program_specs():
                     **(SIMBA if simba else {}),
                     **(PIXELS if pixels else {}),
                     **(MPO if mpo else {}),
+                    **(RECURRENT if recurrent else {}),
                 ),
-                obs_dim=ObsSpec((3, 16, 16), "uint8") if pixels else 3,
+                obs_dim=(
+                    ObsSpec((3, 16, 16), "uint8") if pixels
+                    else ObsSpec((3,), steps=4) if recurrent else 3
+                ),
                 act_dim=1,
                 action_scale=np.ones(1, np.float32),
                 mesh=probe_mesh(2 if tp else 1),
@@ -1578,7 +1610,7 @@ def program_specs():
         return cache[key]
 
     def storage_for(L: ShardedLearner):
-        width = 2 * L.obs_dim + L.act_dim + 3  # the packed replay row
+        width = packed_width(L.obs, L.act_dim)  # the packed replay row
         spec = P("data", None) if L._replay_sharded else P(None, None)
         storage = jax.device_put(
             np.zeros((64, width), np.float32), NamedSharding(L.mesh, spec)
@@ -1730,6 +1762,12 @@ def program_specs():
         ProgramSpec(
             "learner.chunk.uniform.mpo", OWNER,
             uniform(False, sharded=False, mpo=True),
+        )
+    )
+    specs.append(
+        ProgramSpec(
+            "learner.chunk.uniform.recurrent", OWNER,
+            uniform(False, sharded=False, recurrent=True),
         )
     )
     return specs

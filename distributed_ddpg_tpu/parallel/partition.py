@@ -65,6 +65,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from distributed_ddpg_tpu.models.mlp import is_lnmlp, is_simba
 from distributed_ddpg_tpu.models.pixels import is_pixel
+from distributed_ddpg_tpu.models.recurrent import is_recurrent
 from distributed_ddpg_tpu.types import OptState, TrainState
 
 # One rule: (regex over the '/'-joined tree path, PartitionSpec). The
@@ -133,6 +134,17 @@ def pixel_rules(params) -> Tuple[Rule, ...]:
         (r"(^|/)encoder/\d+/(w|b)$", P(None)),
         (r"(^|/)trunk/(w|b|ln_scale|ln_shift)$", P(None)),
     ) + mlp_rules(len(chain))
+
+
+# A recurrent net (models/recurrent.py: a dict of embedders, `lstm`,
+# `shortcut` and `head` or `heads`): every leaf replicates. The embedders
+# are 8 to 32 wide, the LSTM's [X + H, 4 H] matrix is read whole by every
+# one of a window's dependent steps, and the heads are 128 wide: no leaf is
+# worth a collective a step.
+RECURRENT_RULES: Tuple[Rule, ...] = (
+    (r"(^|/)(embed_obs|embed_act|embed_rew|lstm|shortcut)/(w|b)$", P(None)),
+    (r"(^|/)heads?/\d+/(w|b)$", P(None)),
+)
 
 
 def mlp_rules(num_layers: int) -> Tuple[Rule, ...]:
@@ -206,11 +218,14 @@ def match_partition_rules(rules: Sequence[Rule], tree, model_size: int):
 
 def net_pspec(params, model_size: int, rules: Optional[Sequence[Rule]] = None):
     """Spec tree for one {w, b}-layer param list. Default rules are the
-    per-depth MLP table (mlp_rules), SIMBA_RULES for a residual net, or
-    lnmlp_rules for a LayerNormMLP; pass `rules` for any other."""
+    per-depth MLP table (mlp_rules), SIMBA_RULES for a residual net,
+    lnmlp_rules for a LayerNormMLP, or RECURRENT_RULES for a recurrent net;
+    pass `rules` for any other."""
     if rules is None:
         if is_pixel(params):
             rules = pixel_rules(params)
+        elif is_recurrent(params):
+            rules = RECURRENT_RULES
         elif is_simba(params):
             rules = SIMBA_RULES
         elif is_lnmlp(params):

@@ -462,11 +462,15 @@ CHUNK_SCOPES = (
     "update/encoder", # its convolutional encoder: both forward passes and the backward
     "update/estep",   # MPO's E-step: target policy, draws, target critic on batch x samples rows, weights
     "update/estep/lnorm",    # the LayerNorms of its two target nets
+    "update/target",  # a recurrent update's targets y: both target nets, forward
+    "update/target/recur",   # their memories: the LSTM scanned over the window
     "update/critic",  # critic loss, forward and backward
+    "update/critic/recur",   # a recurrent critic's memory, forward and back through time
     "update/critic/norm",  # its batch norm: moments, normalising, running step
     "update/critic/lnorm",   # a residual critic's LayerNorms, forward and backward
     "update/critic/rsnorm",  # its input normaliser, and the statistics' merge
     "update/actor",   # actor loss, forward and backward
+    "update/actor/recur",    # a recurrent actor's memory, forward and back through time
     "update/actor/norm",   # its own batch norm, and the critics' under it
     "update/actor/lnorm",    # a residual actor's LayerNorms, and the critics' under it
     "update/actor/rsnorm",   # its input normaliser, and the critics' under it
@@ -483,9 +487,10 @@ CHUNK_SCOPES = (
 ROLLOUT_SCOPES = (
     "rollout",         # the K-step scan over E environments
     "rollout/policy",  # mu(s) and the exploration noise
+    "rollout/policy/recur",  # a recurrent policy's one LSTM step
     "rollout/render",  # a pixel environment's frames out of its state
     "rollout/env",     # the vmapped environment step, auto-reset included
-    "rollout/fold",    # the n-step window: fold, flush, the emitted row
+    "rollout/fold",    # the n-step window: fold, flush, the emitted row; a recurrent run's window row
 )
 # What a collective instruction reads as, whatever scope it served.
 COLLECTIVE = "collective"
@@ -785,9 +790,26 @@ def _scope_of(op_name: str) -> str:
     # made one instruction of several it joins their paths with `;`: the
     # first speaks.
     path = op_name.partition(";")[0]
-    return "/".join(
-        part for part in path.split("/")[:-1] if part in _SCOPE_WORDS
-    )
+    return "/".join(filter(None, map(_word, path.split("/")[:-1])))
+
+
+# A bracket entered inside a differentiated function reaches the text
+# wrapped in the transforms it was traced under (`jvp(recur)`, `transpose(
+# jvp(recur))`), and a wrapped word is not read: the backward pass of a
+# bracket inside a loss counts under the loss's own bracket, entered outside
+# (`update/critic`), and the tables of every program written so far stand on
+# that. The words of _THROUGH_TRANSFORMS are read through the wrappers: a
+# recurrent update's time is its memories' forward AND backward passes, all
+# of them inside the losses.
+_THROUGH_TRANSFORMS = frozenset({"recur"})
+_WRAPPED = re.compile(r"^(?:\w+\()+(\w+)\)+$")
+
+
+def _word(part: str) -> Optional[str]:
+    if part in _SCOPE_WORDS:
+        return part
+    m = _WRAPPED.match(part)
+    return m.group(1) if m and m.group(1) in _THROUGH_TRANSFORMS else None
 
 
 _ASYNC_HALVES = ("-start", "-done")

@@ -540,8 +540,9 @@ def _train_jax_impl(
     env = make(config.env_id, seed=config.seed)
     spec = spec_of(env)
     # The observation's shape and dtype: a flat float vector's `obs_dim`, or
-    # a pixel environment's byte frames (types.ObsSpec).
-    obs_spec = ObsSpec.of_env(spec)
+    # a pixel environment's byte frames (types.ObsSpec); a recurrent
+    # configuration's rows hold windows of seq_len of them.
+    obs_spec = ObsSpec.of_env(spec, config.window_steps)
     chunk = resolve_learner_chunk(config)
     min_fill = max(config.replay_min_size, config.batch_size)
     n_proc = jax.process_count()
@@ -671,14 +672,16 @@ def _train_jax_impl(
         # and never under strict_sync — the shipper thread would make
         # row-landing timing (hence the sampled stream) a function of
         # host scheduling instead of the config.
-        # `obs_words`: the float32 words of a ring row one observation takes
-        # (its float count; a byte frame stack's bytes over four).
-        obs_words = obs_spec.words
+        # `ring_obs`: the float32 words of a ring row one observation takes
+        # (its float count; a byte frame stack's bytes over four), or for a
+        # ring of windows the spec itself (types.packed_width).
+        ring_obs = obs_spec if obs_spec.steps else obs_spec.words
         replay_kwargs = dict(
             mesh=learner.mesh,
             # the host's staging blocks: 1,024 rows, or 16 of a pixel
-            # configuration's 127 KB rows, which no host worker fills
-            block_size=16 if config.pixels else 1024,
+            # configuration's 127 KB rows or a recurrent one's 20 KB
+            # windows, which no host worker fills
+            block_size=16 if config.pixels or config.recurrent else 1024,
             async_ship=not is_multi and not config.strict_sync,
             max_coalesce=config.ingest_coalesce,
             fault=(
@@ -707,12 +710,12 @@ def _train_jax_impl(
         )
         device_replay = (
             DevicePrioritizedReplay(
-                config.replay_capacity, obs_words, spec.act_dim,
+                config.replay_capacity, ring_obs, spec.act_dim,
                 alpha=config.per_alpha, eps=config.per_eps, **replay_kwargs,
             )
             if config.prioritized
             else DeviceReplay(
-                config.replay_capacity, obs_words, spec.act_dim,
+                config.replay_capacity, ring_obs, spec.act_dim,
                 **replay_kwargs,
             )
         )
@@ -1013,8 +1016,9 @@ def _train_jax_impl(
         and use_device_replay
         and config.fused_beat != "off"
         # the beat composes the flat rollout and chunk bodies (config.py
-        # refuses fused_beat='on' with a pixel configuration)
+        # refuses fused_beat='on' with a pixel or a recurrent configuration)
         and not config.pixels
+        and not config.recurrent
         and (
             config.fused_beat == "on"
             or (
@@ -1264,6 +1268,16 @@ def _train_jax_impl(
             facts["feature_dim"] = int(critic["trunk"]["w"].shape[1])
             if use_device_replay:
                 facts["ring_row_bytes"] = device_replay.row_bytes_device
+        if config.recurrent:
+            # Recurrent runs only, from the state and the ring: the window,
+            # the memory's width, and the bytes one window row holds.
+            facts["recurrent"] = True
+            facts["seq_len"] = obs_spec.steps
+            facts["rnn_hidden"] = int(
+                learner.state.actor_params["lstm"]["w"].shape[-1] // 4
+            )
+            if use_device_replay:
+                facts["ring_row_bytes"] = device_replay.row_bytes_device
         if device_pool is not None and device_pool.sigma_ends is not None:
             # The Gaussian ladder's ends, as the rollout program holds them.
             facts["devactor_sigma_min"], facts["devactor_sigma_max"] = (
@@ -1348,10 +1362,12 @@ def _train_jax_impl(
 
     # A pixel configuration's evaluator acts through the learner's own apply
     # (encoder, trunk and head, no augmentation and no noise; the numpy
-    # policy of the host workers has no convolution), jitted once a run.
+    # policy of the host workers has no convolution), jitted once a run; a
+    # recurrent one's through the learner's one-step apply, carrying the
+    # policy's memory over the episode (_RecurrentEvalPolicy).
     pixel_act = (
         make_act_fn(config, spec.action_scale, spec.action_offset)
-        if config.pixels else None
+        if config.pixels or config.recurrent else None
     )
 
     def eval_policy_of(host_params):
@@ -1362,6 +1378,10 @@ def _train_jax_impl(
         if config.pixels:
             return lambda obs: np.asarray(
                 pixel_act(host_params, np.asarray(obs)[None])
+            )
+        if config.recurrent:
+            return _RecurrentEvalPolicy(
+                pixel_act, host_params, config.rnn_hidden, spec.act_dim
             )
         policy = NumpyPolicy(
             layout_of(config, spec.obs_dim, spec.act_dim),
@@ -2973,15 +2993,51 @@ def _param_checksum(host_params) -> float:
     )
 
 
+class _RecurrentEvalPolicy:
+    """The evaluator's policy of a recurrent configuration: the learner's
+    own one-step apply (learner.make_act_fn) on a host copy of the actor's
+    tree, with the memory it carries over an episode (the cell's state, the
+    previous action and reward). `_eval_numpy` tells it each step's reward
+    (`observe`) and each episode's start (`reset`)."""
+
+    def __init__(self, act, params, units: int, act_dim: int):
+        from distributed_ddpg_tpu.models.recurrent import zero_memory
+
+        self._act, self._params = act, params
+        self._zero = zero_memory(1, units, act_dim)
+        self._memory = self._zero
+
+    def reset(self) -> None:
+        self._memory = self._zero
+
+    def __call__(self, obs):
+        action, (h, c) = self._act(
+            self._params, np.asarray(obs, np.float32)[None], self._memory
+        )
+        self._memory = self._memory._replace(h=h, c=c)
+        return np.asarray(action)
+
+    def observe(self, action, reward) -> None:
+        self._memory = self._memory._replace(
+            prev_action=np.asarray(action, np.float32)[None],
+            prev_reward=np.asarray([reward], np.float32),
+        )
+
+
 def _eval_numpy(policy, config: DDPGConfig, spec, episodes: Optional[int] = None) -> float:
     env = make(config.env_id, seed=config.seed + 777)
     returns = []
+    stateful = hasattr(policy, "observe")  # a recurrent policy
     for ep in range(episodes or config.eval_episodes):
         obs, _ = env.reset(seed=config.seed + 777 + ep)
+        if stateful:
+            policy.reset()
         done, total = False, 0.0
         while not done:
             action = np.clip(policy(obs)[0], spec.action_low, spec.action_high)
             obs, r, terminated, truncated, _ = env.step(action)
+            if stateful:
+                policy.observe(action, r)
             total += r
             done = terminated or truncated
         returns.append(total)

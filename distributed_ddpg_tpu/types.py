@@ -28,6 +28,19 @@ class Batch(NamedTuple):
     weight: Any       # f32[B]     (PER importance weights; ones if uniform)
 
 
+class Windows(NamedTuple):
+    """A replay minibatch of WINDOWS (a recurrent configuration's rows,
+    `unpack_windows`): L steps of one episode a row, left-aligned, a time
+    axis behind the batch's. o_{t+1} is `obs`' next slot; replay is uniform,
+    so there is no weight."""
+
+    obs: Any          # f32[B, L + 1, obs_dim]   o_0 .. o_L
+    action: Any       # f32[B, L, act_dim]
+    reward: Any       # f32[B, L]
+    terminated: Any   # f32[B, L]  1 where the step truly terminated (the learner multiplies gamma in)
+    mask: Any         # f32[B, L]  1 on a real step, 0 on a padded one
+
+
 class OptState(NamedTuple):
     """Adam state for one parameter tree (matches optax.adam semantics)."""
 
@@ -72,6 +85,11 @@ class ObsSpec(NamedTuple):
 
     shape: Tuple[int, ...]
     dtype: str = "float32"
+    # Steps a ring row holds. 0: a row is one folded transition (o, a, R, d,
+    # o', w). L > 0 (a recurrent configuration's config.seq_len): a row is a
+    # WINDOW of L steps of one episode, L + 1 observations, L actions and 3 L
+    # scalars (`packed_width`, `unpack_windows`).
+    steps: int = 0
 
     @classmethod
     def of(cls, obs: Union[int, "ObsSpec"]) -> "ObsSpec":
@@ -81,12 +99,14 @@ class ObsSpec(NamedTuple):
         return cls((int(obs),))
 
     @classmethod
-    def of_env(cls, env) -> "ObsSpec":
+    def of_env(cls, env, steps: int = 0) -> "ObsSpec":
         """The observation of an environment or of its spec (anything with
         `obs_dim`, and `obs_shape` and `obs_dtype` where the observation is no
-        flat float vector: envs/jax_envs.py, envs/registry.EnvSpec)."""
+        flat float vector: envs/jax_envs.py, envs/registry.EnvSpec), in rows
+        of `steps` steps (config.window_steps)."""
         shape = tuple(getattr(env, "obs_shape", ()) or ())
-        return cls(shape, env.obs_dtype) if shape else cls.of(env.obs_dim)
+        spec = cls(shape, env.obs_dtype) if shape else cls.of(env.obs_dim)
+        return spec._replace(steps=int(steps))
 
     @property
     def size(self) -> int:
@@ -131,8 +151,12 @@ def batch_from_numpy(arrays: Dict[str, np.ndarray]) -> Batch:
 
 def packed_width(obs_dim: Union[int, ObsSpec], act_dim: int) -> int:
     """float32 words of one packed row; `obs_dim` an observation's float
-    count or its ObsSpec (a byte observation counts its words)."""
-    return 2 * ObsSpec.of(obs_dim).words + act_dim + 3
+    count or its ObsSpec (a byte observation counts its words; a window of
+    L steps holds L + 1 observations, L actions and 3 L scalars)."""
+    obs = ObsSpec.of(obs_dim)
+    if obs.steps:
+        return (obs.steps + 1) * obs.words + obs.steps * (act_dim + 3)
+    return 2 * obs.words + act_dim + 3
 
 
 def pack_batch_np(arrays: Dict[str, np.ndarray]) -> np.ndarray:
@@ -165,4 +189,23 @@ def unpack_batch(packed, obs_dim: int, act_dim: int) -> Batch:
             discount=packed[..., o + a + 1],
             next_obs=packed[..., o + a + 2 : 2 * o + a + 2],
             weight=packed[..., 2 * o + a + 2],
+        )
+
+
+def unpack_windows(packed, obs_dim: int, act_dim: int, steps: int) -> Windows:
+    """[..., D] rows of WINDOWS -> Windows. A window row is [o_0 .. o_L | a_0
+    .. a_{L-1} | r_0 .. r_{L-1} | d_0 .. d_{L-1} | m_0 .. m_{L-1}], each field
+    time-major: L = `steps` steps of one episode, left-aligned, d the steps'
+    terminated flags and m 1 on a real step (ops/exploration.seq_fold writes
+    it)."""
+    o, a, n = obs_dim, act_dim, steps
+    lead = packed.shape[:-1]
+    at_a, at_r = (n + 1) * o, (n + 1) * o + n * a
+    with device_scope("cut"):
+        return Windows(
+            obs=packed[..., :at_a].reshape(*lead, n + 1, o),
+            action=packed[..., at_a:at_r].reshape(*lead, n, a),
+            reward=packed[..., at_r : at_r + n],
+            terminated=packed[..., at_r + n : at_r + 2 * n],
+            mask=packed[..., at_r + 2 * n : at_r + 3 * n],
         )

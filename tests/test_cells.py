@@ -42,6 +42,7 @@ RING_LAYOUT = {
     "simba-humanoid": "row_major",
     "drqv2-humanoid": "row_major",
     "dmpo-humanoid": "row_major",
+    "rtd3-isaac-humanoid-p": "row_major",  # a row is a window: 5,046 floats, 40 lines of 128
 }
 
 
@@ -99,7 +100,8 @@ def test_cell_ring_layout_and_size(cell):
     name, config, flags = _files(cell)
     cfg = DDPGConfig.from_flags(flags)
     env = config["env"]
-    width = packed_width(_obs(env), env["act_dim"])
+    # a recurrent configuration's row is a window of its seq_len steps
+    width = packed_width(_obs(env)._replace(steps=cfg.window_steps), env["act_dim"])
     layout = ring_layout(width)
     assert name in RING_LAYOUT, f"enter {name}'s ring layout ({layout}) in RING_LAYOUT"
     assert layout == RING_LAYOUT[name]

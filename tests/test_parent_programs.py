@@ -40,6 +40,17 @@ HELD = [
 ]
 
 
+# The three programs the PR 46 file lacks (registered by PRs 47 and 51), as they
+# lowered at PR 53's parent (5063af3, PR 52), taken the same way: the pixel and
+# the DMPO cell's chunks and the pixel rollout, which share types.ObsSpec,
+# learner.step_noise and ActorCarry with what PR 53 changed.
+AT_PR52 = {
+    "learner.chunk.uniform.pixels": "21b8922d38222303",
+    "learner.chunk.uniform.mpo": "cab64179c1e55c51",
+    "devactor.rollout.pixels": "be7f06c488b056d8",
+}
+
+
 @pytest.fixture(scope="module")
 def specs():
     return {spec.name: spec for spec in default_specs()}
@@ -51,12 +62,15 @@ def test_the_file_names_the_parents_whole_registry(specs):
     assert set(specs) - set(AT_PARENT) == {
         "learner.chunk.uniform.pixels", "devactor.rollout.pixels",  # PR 47
         "learner.chunk.uniform.mpo",  # PR 51: DMPO's chunk; the texts below stand with it registered
+        # PR 53: recurrent TD3's chunk and rollout (rows that are windows, a policy state in the carry);
+        # the texts below stand with them registered, ObsSpec.steps and ActorCarry's new leaves included
+        "learner.chunk.uniform.recurrent", "devactor.rollout.recurrent",
     }
     assert set(AT_PARENT) <= set(specs)
 
 
-@pytest.mark.parametrize("name", HELD)
+@pytest.mark.parametrize("name", HELD + sorted(AT_PR52))
 def test_program_lowers_to_the_parents_text(specs, name):
     built = specs[name].build()
     text = built.fn.lower(*built.args).as_text()
-    assert hashlib.sha256(text.encode()).hexdigest()[:16] == AT_PARENT[name]
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == {**AT_PARENT, **AT_PR52}[name]

@@ -1093,3 +1093,59 @@ def test_v5e_pixel_chunk_moves_no_feature_block_between_the_encoder_and_the_trun
     # an update's two images' words, four unrolled updates a trip: s32[9,84,21,256]
     assert retiles == len(moves) == 8
     assert trace.chunk_ops_table(text)["copies"] == {"count": 8, "bytes": 8 * obs.words * batch * 4}
+
+
+def test_v5e_recurrent_chunk_scans_time_inside_the_scan_over_updates(v5e_sharding):
+    """`rtd3-isaac-humanoid-p`'s launch at its own sizes (the 245,760-row ring
+    of 5,046-float windows in ring_format's layout, 128 x 64 indices, unroll
+    4) as ShardedLearner's sample chunk builds it: gather, types.
+    unpack_windows, scan_chunk. The TPU's compiler takes it; the body of the
+    scan over updates holds, an update, six loops over the window's steps (the
+    two targets' memories forward, the critic's and the actor's forward and
+    back through time), every one under a `recur` scope that the program's
+    table reads through `jvp` and `transpose`; what the launch holds beside
+    the ring (its 8,192 gathered windows, 165 MB, cut and relaid, and the
+    noise) stays under an eighth of it."""
+    import json
+    import os
+
+    from distributed_ddpg_tpu import learner as learner_lib
+    from distributed_ddpg_tpu import trace
+    from distributed_ddpg_tpu.config import DDPGConfig
+    from distributed_ddpg_tpu.parallel.learner import scan_chunk
+    from distributed_ddpg_tpu.types import ObsSpec, packed_width, unpack_windows
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    conf = json.load(open(os.path.join(root, "benchmarks", "configs", "rtd3-isaac-humanoid-p.json")))
+    cfg = DDPGConfig.from_flags(conf["flags"] + ["--actor_backend=device", "--num_actors=0"])
+    env = conf["env"]
+    obs = ObsSpec((env["obs_dim"],), steps=cfg.window_steps)
+    act, chunk, batch, unroll = env["act_dim"], cfg.learner_chunk, cfg.batch_size, 4
+    width = packed_width(obs, act)
+    assert (chunk, batch, width, cfg.replay_capacity) == (128, 64, 5046, 245760)
+    replicated = NamedSharding(v5e_sharding.mesh, P())
+    state = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=replicated),
+        jax.eval_shape(lambda: learner_lib.init_train_state(cfg, obs, act, 0)),
+    )
+    ring = jax.ShapeDtypeStruct((cfg.replay_capacity, width), jnp.float32, sharding=ring_format(v5e_sharding, width))
+    idx = jax.ShapeDtypeStruct((chunk, batch), jnp.int32, sharding=replicated)
+    nkey = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=replicated)
+    step = learner_lib.make_learner_step(cfg, env["action_scale"], action_offset=env["action_offset"], obs=obs)
+
+    def run(s, storage, idx, nkey):
+        noise = learner_lib.chunk_noise(cfg, nkey, s.step, chunk, batch, act)
+        return scan_chunk(step, s, unpack_windows(storage[idx], obs.words, act, obs.steps), noise, unroll=unroll)
+
+    compiled = jax.jit(run, donate_argnums=(0,)).lower(state, ring, idx, nkey).compile()
+    text = compiled.as_text()
+    scopes = trace.op_scopes(text)
+    loops = [name for name in scopes if name.startswith("while")]
+    by_scope = {s: sum(scopes[name] == s for name in loops) for s in set(scopes[name] for name in loops)}
+    assert by_scope == {
+        "update": 1, "update/target/recur": 2 * unroll, "update/critic/recur": 2 * unroll,
+        "update/actor/recur": 2 * unroll,
+    }, by_scope
+    ring_bytes = cfg.replay_capacity * 5120 * 4  # 40 lines of 128 words a row
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes > ring_bytes and memory.temp_size_in_bytes < ring_bytes // 8
